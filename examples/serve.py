@@ -24,6 +24,47 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+def read_config(weights_dir: str):
+    """``strom_config.json`` of a converted checkpoint dir."""
+    from nvme_strom_tpu.models.transformer import TransformerConfig
+
+    with open(os.path.join(weights_dir, "strom_config.json")) as f:
+        return TransformerConfig(**json.load(f))
+
+
+def load_weights(weights_dir: str, engine):
+    """Converted checkpoint dir → params on the first device, streamed
+    through ``engine`` (parallel/weights.py)."""
+    import jax
+
+    from nvme_strom_tpu.parallel.weights import LazyCheckpoint
+
+    return LazyCheckpoint(weights_dir).load_sharded(
+        lambda name, shape: jax.sharding.SingleDeviceSharding(
+            jax.devices()[0]),
+        engine=engine)
+
+
+def build_server(params, cfg, *, slots: int, max_len: int,
+                 paged: int = 0, block_len: int = 128,
+                 pallas: bool = False):
+    """The decode server ``main`` serves from: a shared paged KV pool of
+    ``paged`` blocks (Pallas paged attention), else fixed slots with the
+    fused decode-attention kernel (``pallas``) or XLA dense attention."""
+    from nvme_strom_tpu.models.serving import (DecodeServer,
+                                               PagedDecodeServer)
+    if paged:
+        return PagedDecodeServer(params, cfg, max_batch=slots,
+                                 max_len=max_len, total_blocks=paged,
+                                 block_len=block_len)
+    cache_attn = None
+    if pallas:
+        from nvme_strom_tpu.ops.decode_attention import make_decode_attn
+        cache_attn = make_decode_attn()
+    return DecodeServer(params, cfg, max_batch=slots, max_len=max_len,
+                        cache_attn=cache_attn)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--weights", required=True,
@@ -71,21 +112,18 @@ def main(argv=None) -> int:
         if args.paged < 1 or args.block_len < 1:
             ap.error("--paged and --block-len must be >= 1")
 
-    import jax
-    if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-
     from nvme_strom_tpu.io import StromEngine
-    from nvme_strom_tpu.models.serving import DecodeServer
-    from nvme_strom_tpu.models.transformer import TransformerConfig
-    from nvme_strom_tpu.parallel.weights import LazyCheckpoint
+    from nvme_strom_tpu.utils.compile_cache import enable_compile_cache
+    from nvme_strom_tpu.utils.device import device_line
+
+    print(device_line(), flush=True)
+    enable_compile_cache()
 
     cfg_path = os.path.join(args.weights, "strom_config.json")
     if not os.path.exists(cfg_path):
         ap.error(f"{cfg_path} not found — convert with "
                  "tools/convert_llama first")
-    with open(cfg_path) as f:
-        cfg = TransformerConfig(**json.load(f))
+    cfg = read_config(args.weights)
     max_len = args.max_len or cfg.max_seq
 
     reqs = []
@@ -117,27 +155,13 @@ def main(argv=None) -> int:
 
     engine = StromEngine()
     t0 = time.monotonic()
-    params = LazyCheckpoint(args.weights).load_sharded(
-        lambda name, shape: jax.sharding.SingleDeviceSharding(
-            jax.devices()[0]),
-        engine=engine)
+    params = load_weights(args.weights, engine)
     print(f"weights: {len(params)} tensors in "
           f"{time.monotonic() - t0:.2f}s", flush=True)
 
-    if args.paged:
-        from nvme_strom_tpu.models.serving import PagedDecodeServer
-        srv = PagedDecodeServer(params, cfg, max_batch=args.slots,
-                                max_len=max_len,
-                                total_blocks=args.paged,
-                                block_len=args.block_len)
-    else:
-        cache_attn = None
-        if args.pallas:
-            from nvme_strom_tpu.ops.decode_attention import (
-                make_decode_attn)
-            cache_attn = make_decode_attn()
-        srv = DecodeServer(params, cfg, max_batch=args.slots,
-                           max_len=max_len, cache_attn=cache_attn)
+    srv = build_server(params, cfg, slots=args.slots, max_len=max_len,
+                       paged=args.paged, block_len=args.block_len,
+                       pallas=args.pallas)
     for i, (rid, ids, max_new) in enumerate(reqs):
         srv.submit(rid, ids, max_new, eos_id=args.eos_id,
                    temperature=args.temperature, top_p=args.top_p,

@@ -72,9 +72,10 @@ def main(argv=None) -> int:
                          "exclude never leave the SSD")
     args = ap.parse_args(argv)
 
-    import jax
-    if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    from nvme_strom_tpu.utils.compile_cache import enable_compile_cache
+    from nvme_strom_tpu.utils.device import device_line
+    print(device_line(), flush=True)
+    enable_compile_cache()
     from nvme_strom_tpu.io import StromEngine
     from nvme_strom_tpu.sql import (ParquetScanner, sql_groupby,
                                     sql_groupby_str, sql_topk,

@@ -42,8 +42,11 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+
+    from nvme_strom_tpu.utils.compile_cache import enable_compile_cache
+    from nvme_strom_tpu.utils.device import device_line
+    print(device_line(), flush=True)
+    enable_compile_cache()
     import jax.numpy as jnp
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P

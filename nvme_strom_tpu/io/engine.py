@@ -190,8 +190,13 @@ def _load_lib() -> ctypes.CDLL:
         src_mtime = max((_CSRC / n).stat().st_mtime
                         for n in ("strom_io.cc", "strom_io.h"))
         if not _LIB_PATH.exists() or _LIB_PATH.stat().st_mtime < src_mtime:
-            subprocess.run(["make", "-C", str(_CSRC)], check=True,
-                           capture_output=True)
+            # the one way the library is ever built (it is git-ignored)
+            make = subprocess.run(["make", "-C", str(_CSRC)],
+                                  capture_output=True, text=True)
+            if make.returncode != 0:
+                raise ImportError(
+                    f"building {_LIB_PATH} failed (make exit "
+                    f"{make.returncode}):\n{make.stdout}{make.stderr}")
         lib = ctypes.CDLL(str(_LIB_PATH), use_errno=True)
         lib.strom_engine_create.restype = ctypes.c_void_p
         lib.strom_engine_create.argtypes = [

@@ -83,7 +83,7 @@ def _sample_slots(logits, temps, top_ps, seeds, pos):
 
     Jitted at this level because ``_first_token`` calls it EAGERLY once
     per admission: un-jitted, the ``lax.cond`` dispatch re-traced its
-    branches every call (~175 ms per admission on the CPU fallback —
+    branches every call (~175 ms per admission on a CPU run —
     it dominated the whole admission phase); inside the jitted step
     programs the wrapper is inlined and changes nothing.
 
@@ -174,20 +174,15 @@ def _batched_step_body(params: Dict, cfg: TransformerConfig, tok, pos,
     return (x @ wmat(params, "lm_head", x.dtype)).astype(jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 9),
-                   donate_argnums=(3, 4))
-def _serve_step(params: Dict, cfg: TransformerConfig, tok,
-                k_cache, v_cache, pos, temps, top_ps, seeds,
-                cache_attn=None):
-    """One decode step for every slot at its OWN position.
+def serve_logits(params: Dict, cfg: TransformerConfig, tok,
+                 k_cache, v_cache, pos, cache_attn=None):
+    """The decode step of every slot at its OWN position, before
+    sampling: (logits (B, vocab) f32, k_cache, v_cache).
 
-    tok (B,) int32, pos (B,) int32 → (next_tok (B,), k_cache,
-    v_cache).  Free slots compute too, but their frozen-pos writes land
-    in rows the next admission overwrites and the host ignores their
-    outputs — one compiled program for every batch mix.  ``cache_attn``
-    swaps the attention inner for the fused Pallas kernel
-    (ops/decode_attention supports the (B,) per-row pos form).
-    """
+    ``cache_attn`` swaps the attention inner for the fused Pallas kernel
+    (ops/decode_attention supports the (B,) per-row pos form); None is
+    the dense XLA path — the reference the kernels' logits are compared
+    with (chip_smoke.py)."""
     B = tok.shape[0]
     rows = jnp.arange(B)
     limit = pos[:, None]                                      # (B,1)
@@ -206,20 +201,35 @@ def _serve_step(params: Dict, cfg: TransformerConfig, tok,
 
     logits = _batched_step_body(params, cfg, tok, pos,
                                 write_and_attend)
+    return logits, caches["k"], caches["v"]
+
+
+@functools.partial(jax.jit, static_argnums=(1, 9),
+                   donate_argnums=(3, 4))
+def _serve_step(params: Dict, cfg: TransformerConfig, tok,
+                k_cache, v_cache, pos, temps, top_ps, seeds,
+                cache_attn=None):
+    """One decode step for every slot at its OWN position.
+
+    tok (B,) int32, pos (B,) int32 → (next_tok (B,), k_cache,
+    v_cache).  Free slots compute too, but their frozen-pos writes land
+    in rows the next admission overwrites and the host ignores their
+    outputs — one compiled program for every batch mix.
+    """
+    logits, k_cache, v_cache = serve_logits(
+        params, cfg, tok, k_cache, v_cache, pos, cache_attn)
     nxt = _sample_slots(logits, temps, top_ps, seeds, pos)
-    return nxt, caches["k"], caches["v"]
+    return nxt, k_cache, v_cache
 
 
-@functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(3, 4))
-def _paged_step(params: Dict, cfg: TransformerConfig, tok,
-                k_pool, v_pool, blk, off, table, pos, temps, top_ps,
-                seeds):
-    """One decode step against the shared block pool.
+def paged_logits(params: Dict, cfg: TransformerConfig, tok,
+                 k_pool, v_pool, blk, off, table, pos):
+    """The decode step against the shared block pool, before sampling:
+    (logits (B, vocab) f32, k_pool, v_pool).
 
     blk/off (B,) int32: each slot's write target (block id in the pool,
     row offset inside it); table (B, max_blocks) int32 + pos (B,) feed
-    the paged-attention kernel.  Returns (next_tok, k_pool, v_pool).
-    """
+    the paged-attention kernel."""
     from nvme_strom_tpu.ops.paged_attention import paged_attention
     pools = {"k": k_pool, "v": v_pool}
 
@@ -233,8 +243,19 @@ def _paged_step(params: Dict, cfg: TransformerConfig, tok,
 
     logits = _batched_step_body(params, cfg, tok, pos,
                                 write_and_attend)
+    return logits, pools["k"], pools["v"]
+
+
+@functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(3, 4))
+def _paged_step(params: Dict, cfg: TransformerConfig, tok,
+                k_pool, v_pool, blk, off, table, pos, temps, top_ps,
+                seeds):
+    """One decode step against the shared block pool: ``paged_logits``
+    then the per-slot sampler.  Returns (next_tok, k_pool, v_pool)."""
+    logits, k_pool, v_pool = paged_logits(
+        params, cfg, tok, k_pool, v_pool, blk, off, table, pos)
     nxt = _sample_slots(logits, temps, top_ps, seeds, pos)
-    return nxt, pools["k"], pools["v"]
+    return nxt, k_pool, v_pool
 
 
 class DecodeServer:

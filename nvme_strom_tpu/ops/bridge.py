@@ -15,6 +15,7 @@ The staging buffer is released back to the pool only after
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Callable, Iterator, Optional, Sequence
 
@@ -48,7 +49,12 @@ def _pallas_h2d(dev):
     (SNIPPETS.md [2]'s pinned-host→HBM ``pltpu.async_copy`` pattern).
     The copy runs on the device's DMA engines, asynchronously to the
     Python thread — which is what lets the NVMe read of chunk K+1
-    overlap the host→HBM hop of chunk K."""
+    overlap the host→HBM hop of chunk K.
+
+    The operand ref is declared in ``pltpu.HOST``: Mosaic refuses a
+    ``pinned_host`` operand behind ``pl.ANY`` ("Failed to convert a
+    memory space to MLIR"; tests/test_chip_compile.py holds the
+    compile for v5e)."""
     fn = _H2D_DMA_CACHE.get(dev)
     if fn is not None:
         return fn
@@ -58,17 +64,21 @@ def _pallas_h2d(dev):
 
     def _dma_kernel(x_ref, y_ref):
         def body(sem):
-            pltpu.make_async_copy(x_ref, y_ref, sem).wait()
+            copy = pltpu.make_async_copy(x_ref, y_ref, sem)
+            copy.start()
+            copy.wait()
 
         pl.run_scoped(body, pltpu.SemaphoreType.DMA)
 
-    @jax.jit
+    @functools.partial(
+        jax.jit, out_shardings=jax.sharding.SingleDeviceSharding(dev))
     def _call(x):
         return pl.pallas_call(
             _dma_kernel,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.HOST)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            name="strom_h2d_dma",
         )(x)
 
     _H2D_DMA_CACHE[dev] = _call
@@ -92,7 +102,8 @@ class OverlapStage:
     ``transfer(host_view, dtype, shape) -> device_array`` is injectable
     (tests, exotic transports); the default is the Pallas
     pinned-host→HBM DMA on a TPU device and the alias-safe
-    ``host_to_device`` everywhere else.
+    ``host_to_device`` everywhere else — chosen by the device's
+    platform, never by a failure at run time.
     """
 
     def __init__(self, engine: StromEngine, dev, chunk_bytes: int,
@@ -115,7 +126,6 @@ class OverlapStage:
         self._busy: list = [None, None]   # device array sourcing slot k
         self._k = 0
         self._transfer = transfer
-        self._pallas_ok = dev.platform == "tpu"
 
     # -- transfer backends -------------------------------------------------
 
@@ -124,22 +134,22 @@ class OverlapStage:
         arr = host if dtype is None else host.view(dtype)
         if shape is not None:
             arr = arr.reshape(shape)
-        if self._pallas_ok:
-            try:
-                # pinned-host residency first (one host copy at DRAM
-                # speed), then the Pallas DMA moves it to HBM on the
-                # device's own engines — fully async to this thread
-                sharding = jax.sharding.SingleDeviceSharding(
-                    self.dev, memory_kind="pinned_host")
-                pinned = jax.device_put(arr, sharding)
-                out = _pallas_h2d(self.dev)(pinned)
-                self.engine.stats.add(bytes_to_device=int(host.nbytes))
-                return out
-            except Exception:
-                # kernels/memory-kinds unavailable on this runtime:
-                # degrade once to the plain path, stay correct
-                self._pallas_ok = False
-        return host_to_device(self.engine, arr, self.dev)
+        if self.dev.platform != "tpu" or arr.size < 2:
+            # (a one-element pinned_host operand aborts the TPU
+            # compiler — "Unsupported operand memory space" — so such a
+            # view takes the plain put by its size, not by a failure)
+            return host_to_device(self.engine, arr, self.dev)
+        # pinned-host residency first (one host copy at DRAM speed),
+        # then the Pallas DMA moves it to HBM on the device's own
+        # engines — fully async to this thread.  A refusal (compile or
+        # run time) raises: on a TPU a kernel failure is an error, not
+        # a reason to take another path silently.
+        sharding = jax.sharding.SingleDeviceSharding(
+            self.dev, memory_kind="pinned_host")
+        pinned = jax.device_put(arr, sharding)
+        out = _pallas_h2d(self.dev)(pinned)
+        self.engine.stats.add(bytes_to_device=int(host.nbytes))
+        return out
 
     # -- the ping-pong rotation --------------------------------------------
 
@@ -341,7 +351,7 @@ class DeviceStream:
         self.klass = klass
         #: double-buffered host→HBM stage (docs/PERF.md §6).  None =
         #: auto: engage on a TPU device when STROM_BRIDGE_OVERLAP
-        #: allows (the CPU fallback keeps the current device_put path
+        #: allows (a CPU device keeps the plain device_put path
         #: bit-for-bit — an extra slab copy would only cost there).
         #: True forces the stage on any device (tests, measurements);
         #: False disables for this stream; STROM_BRIDGE_OVERLAP=0
@@ -455,15 +465,22 @@ class DeviceStream:
                         pass
                     pr.release()
                     raise
-            arr = (stage.put(view, dtype, shp)
-                   if stage is not None else None)
-            if arr is not None:
-                pr.release()   # staging recycles NOW — the overlap win
-                inflight.append((arr, None))
-            else:
-                # no stage, or the view outgrew the slabs: the classic
-                # path, source held until its transfer drains ready
-                inflight.append((self._put(view, dtype, shp), pr))
+            try:
+                arr = (stage.put(view, dtype, shp)
+                       if stage is not None else None)
+                if arr is None:
+                    # no stage, or the view outgrew the slabs: the
+                    # classic path, source held until its transfer
+                    # drains ready
+                    inflight.append((self._put(view, dtype, shp), pr))
+                    return
+            except BaseException:
+                # a refused transfer raises (no other path is tried);
+                # the entry already left ``pending``, so release here
+                pr.release()
+                raise
+            pr.release()   # staging recycles NOW — the overlap win
+            inflight.append((arr, None))
 
         ranges = list(ranges)
         shapes_l = list(shapes) if shapes is not None else None

@@ -54,10 +54,7 @@ def _vma(*arrays):
 
 
 def _struct(shape, dtype, vma):
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:  # older jax: no vma kwarg, no VMA checking either
-        return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _pick_block(seq: int, want: int) -> int:

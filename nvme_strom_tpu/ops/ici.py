@@ -16,11 +16,11 @@ Two layers live here:
     ``shard_map``-compatible all-gather of per-host byte rows.  On an
     all-TPU mesh the exchange is a Pallas ring collective built on
     ``pltpu.make_async_remote_copy`` (one-hop neighbour pushes around the
-    ring, DMA'd HBM→HBM on the device's own engines); ANY failure — no
-    TPU, kernels unavailable, runtime refuses the remote DMA — degrades
-    ONE-WAY to the ``jax.lax.all_gather`` collective, exactly the
-    ``ops/bridge.py`` ``OverlapStage`` discipline, which is also the
-    CPU/emulated-mesh path the tests pin.
+    ring, DMA'd HBM→HBM on the device's own engines); every other mesh
+    (the CPU/emulated mesh the tests pin) runs ``jax.lax.all_gather``.
+    The mesh's platform selects the backend once, at construction; a
+    kernel that fails to build or run raises — nothing degrades at run
+    time.
 
 :func:`scatter_engine`
     The consumer-facing orchestrator: partition a file set into per-host
@@ -49,9 +49,11 @@ from nvme_strom_tpu.parallel.mesh import exchange_mesh
 
 _log = logging.getLogger("nvme_strom_tpu.ici")
 
-#: lane-friendly padding of a host's share row: rows exchange as int32
-#: words and TPU tiles want multiples of a full (8, 128) tile
+#: lane-friendly padding of a host's share row: rows exchange as
+#: (tiles, 128) int32 words and TPU tiles want multiples of a full
+#: (8, 128) tile
 _ROW_ALIGN = 4096
+_LANES = 128
 
 #: default partition unit — share boundaries stay on O_DIRECT-friendly
 #: 1 MiB lines so each host's span submits as large aligned reads
@@ -95,13 +97,13 @@ class IciExchange:
     row; multi-process runs only need their own rows populated) and
     returns the fully-gathered array on this host.
 
-    TPU: Pallas ring all-gather — each device primes its own output slot,
-    then ``n-1`` lockstep steps push the freshest slot to the right
-    neighbour via ``make_async_remote_copy`` so every chunk DMAs straight
-    into its final HBM location.  Non-TPU meshes, or any Pallas failure,
-    take the one-way ``jax.lax.all_gather`` degrade (the bridge's
-    ``_pallas_ok`` discipline): correct everywhere, and the only path a
-    CPU-emulated mesh ever compiles.
+    TPU: Pallas ring all-gather — each device copies its own row into
+    its output slot, then ``n-1`` lockstep steps push the freshest slot
+    to the right neighbour via ``make_async_remote_copy`` so every chunk
+    DMAs straight into its final HBM location.  Non-TPU meshes run
+    ``jax.lax.all_gather`` — the only path a CPU-emulated mesh ever
+    compiles.  ``backend`` names which; it is fixed by the mesh's
+    platform and a failure of either raises.
     """
 
     def __init__(self, mesh=None, axis: str = "hosts", stats=None,
@@ -116,117 +118,111 @@ class IciExchange:
         self.stats = stats
         self.tracer = tracer
         devs = list(mesh.devices.flat)
-        self._pallas_ok = bool(devs) and all(
-            d.platform == "tpu" for d in devs)
-        self._fns: dict = {}    # (words, pallas) -> jitted gather
+        #: "pallas_ring" on an all-TPU mesh, else "lax_all_gather"
+        self.backend = ("pallas_ring" if devs and all(
+            d.platform == "tpu" for d in devs) else "lax_all_gather")
+        self._fns: dict = {}    # tiles -> jitted gather
 
     # -- the two exchange backends ------------------------------------
+    # Both take the (n, tiles, 128) int32 view of the rows, sharded over
+    # the axis, and return it whole on every device.
 
-    def _shard_map(self, fn, in_specs, out_specs):
-        try:
-            from jax import shard_map as sm          # jax >= 0.8
-        except ImportError:
-            from jax.experimental.shard_map import shard_map as sm
-        try:
-            return sm(fn, mesh=self.mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return sm(fn, mesh=self.mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    def _shard_map(self, fn):
+        import jax
+        from jax.sharding import PartitionSpec as P
+        return jax.jit(jax.shard_map(
+            fn, mesh=self.mesh, in_specs=P(self.axis, None, None),
+            out_specs=P(None, None, None), check_vma=False))
 
     def _lax_gather_fn(self):
         import jax
-        from jax.sharding import PartitionSpec as P
 
         axis = self.axis
 
-        def gather(block):          # (1, words) int32 per device
+        def gather(block):          # (1, tiles, 128) int32 per device
             return jax.lax.all_gather(block, axis, axis=0, tiled=True)
 
-        return jax.jit(self._shard_map(gather, P(axis, None),
-                                       P(None, None)))
+        return self._shard_map(gather)
 
-    def _pallas_gather_fn(self, words: int):
+    def _pallas_gather_fn(self, tiles: int):
         import jax
         import jax.numpy as jnp
         from jax import lax
         from jax.experimental import pallas as pl
         from jax.experimental.pallas import tpu as pltpu
-        from jax.sharding import PartitionSpec as P
 
         n, axis = self.n, self.axis
+        logical = pltpu.DeviceIdType.LOGICAL
 
-        def kernel(local_ref, out_ref, send_sem, recv_sem):
+        def kernel(local_ref, out_ref, copy_sem, send_sem, recv_sem):
             my_id = lax.axis_index(axis)
             right = lax.rem(my_id + 1, n)
             left = lax.rem(my_id + n - 1, n)
-            # both neighbours must have primed their output slots
-            # before any remote DMA lands in them
+            # both neighbours must have entered the kernel (their output
+            # buffers and semaphores live) before any remote DMA lands
             barrier = pltpu.get_barrier_semaphore()
-            pltpu.semaphore_signal(
-                barrier, inc=1, device_id=(left,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL)
-            pltpu.semaphore_signal(
-                barrier, inc=1, device_id=(right,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL)
-            out_ref[pl.ds(my_id, 1)] = local_ref[:]
+            pltpu.semaphore_signal(barrier, inc=1, device_id=left,
+                                   device_id_type=logical)
+            pltpu.semaphore_signal(barrier, inc=1, device_id=right,
+                                   device_id_type=logical)
+            # the refs live in HBM: the local row moves by DMA too (a
+            # load/store on an ANY-space ref does not lower).  Rows are
+            # (tiles, 128) so a slot is a slice of the untiled leading
+            # dim — Mosaic refuses a 1-row slice of a tiled dim.
+            own = pltpu.make_async_copy(
+                local_ref, out_ref.at[pl.ds(my_id, 1)], copy_sem)
+            own.start()
+            own.wait()
             pltpu.semaphore_wait(barrier, 2)
             # lockstep ring: at step k every device pushes the chunk
             # that originated k hops to its left straight into the
             # right neighbour's matching output slot — no staging
-            # buffer, each chunk DMAs once into its final location
+            # buffer, each chunk DMAs once into its final location.
+            # One receive semaphore per step: a left neighbour running
+            # ahead must not satisfy this step's wait with a later
+            # step's arrival.
             for step in range(n - 1):
                 src = lax.rem(my_id + n - step, n) if step else my_id
                 rdma = pltpu.make_async_remote_copy(
                     src_ref=out_ref.at[pl.ds(src, 1)],
                     dst_ref=out_ref.at[pl.ds(src, 1)],
-                    send_sem=send_sem.at[step % 2],
-                    recv_sem=recv_sem.at[step % 2],
-                    device_id=(right,),
-                    device_id_type=pltpu.DeviceIdType.LOGICAL,
+                    send_sem=send_sem,
+                    recv_sem=recv_sem.at[step],
+                    device_id=right,
+                    device_id_type=logical,
                 )
                 rdma.start()
                 rdma.wait()
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-            scratch_shapes=[pltpu.SemaphoreType.DMA((2,)),
-                            pltpu.SemaphoreType.DMA((2,))],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(()),
+                            pltpu.SemaphoreType.DMA(()),
+                            pltpu.SemaphoreType.DMA((n - 1,))],
         )
 
-        params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams")
-
-        def ring(block):            # (1, words) int32 per device
+        def ring(block):            # (1, tiles, 128) int32 per device
             return pl.pallas_call(
                 kernel,
-                out_shape=jax.ShapeDtypeStruct((n, words), jnp.int32),
+                out_shape=jax.ShapeDtypeStruct((n, tiles, _LANES),
+                                               jnp.int32),
                 grid_spec=grid_spec,
-                compiler_params=params_cls(
+                compiler_params=pltpu.CompilerParams(
                     has_side_effects=True, collective_id=0),
+                name="strom_ici_ring_all_gather",
             )(block)
 
-        return jax.jit(self._shard_map(ring, P(axis, None),
-                                       P(None, None)))
+        return self._shard_map(ring)
 
-    def _gather_fn(self, words: int):
-        key = (words, self._pallas_ok)
-        fn = self._fns.get(key)
-        if fn is not None:
-            return fn
-        if self._pallas_ok:
-            try:
-                fn = self._pallas_gather_fn(words)
-            except Exception as e:              # build/trace failure:
-                _log.warning("ici: pallas ring unavailable (%s: %s); "
-                             "degrading to lax all_gather",
-                             type(e).__name__, e)
-                self._pallas_ok = False         # degrade ONCE, stay there
+    def _gather_fn(self, tiles: int):
+        fn = self._fns.get(tiles)
         if fn is None:
-            fn = self._lax_gather_fn()
-        self._fns[(words, self._pallas_ok)] = fn
+            fn = (self._pallas_gather_fn(tiles)
+                  if self.backend == "pallas_ring"
+                  else self._lax_gather_fn())
+            self._fns[tiles] = fn
         return fn
 
     # -- the host-facing exchange -------------------------------------
@@ -245,40 +241,29 @@ class IciExchange:
         pad = (-nbytes) % _ROW_ALIGN
         if pad:
             rows = np.pad(rows, ((0, 0), (0, pad)))
-        words = rows.shape[1] // 4
+        tiles = rows.shape[1] // (4 * _LANES)
         t0 = time.monotonic_ns()
-        sharding = NamedSharding(self.mesh, P(self.axis, None))
-        wrows = np.ascontiguousarray(rows).view(np.int32)
+        sharding = NamedSharding(self.mesh, P(self.axis, None, None))
+        wrows = np.ascontiguousarray(rows).view(np.int32).reshape(
+            self.n, tiles, _LANES)
         if jax.process_count() > 1:
-            # wrows is the FULL (n, words) array with only this
+            # wrows is the FULL (n, tiles, 128) array with only this
             # process's row(s) populated, so global_shape must say so
             # explicitly: with it, each process's addressable row is
             # sliced from its local copy (row p belongs to process p —
             # exchange_mesh pins axis index == process index).  Without
             # it JAX treats the n local rows as this process's SHARD,
-            # infers an (n·n_proc, words) global array, and the gather
+            # infers an (n·n_proc, ...) global array, and the gather
             # silently returns zeros for every peer row instead of
             # raising.
             arr = jax.make_array_from_process_local_data(
                 sharding, wrows, global_shape=wrows.shape)
         else:
             arr = jax.device_put(wrows, sharding)
-        fn = self._gather_fn(words)
-        try:
-            out = fn(arr)
-            out.block_until_ready()
-        except Exception as e:
-            if not self._pallas_ok:
-                raise
-            # runtime refusal AFTER a successful trace: same one-way
-            # degrade, retried once on the collective path
-            _log.warning("ici: pallas ring failed at run time (%s: %s); "
-                         "degrading to lax all_gather",
-                         type(e).__name__, e)
-            self._pallas_ok = False
-            out = self._gather_fn(words)(arr)
-            out.block_until_ready()
-        got = np.asarray(jax.device_get(out)).view(np.uint8)
+        out = self._gather_fn(tiles)(arr)
+        out.block_until_ready()
+        got = np.asarray(jax.device_get(out)).view(np.uint8).reshape(
+            self.n, -1)
         if got.shape != rows.shape:
             # multi-process-semantics guard: a shape drift here means
             # the gather's global view disagrees with the exchange
@@ -294,7 +279,7 @@ class IciExchange:
                 "strom.ici.exchange", t0, time.monotonic_ns(),
                 category="strom.ici", hosts=self.n,
                 bytes=int(self.n * nbytes),
-                backend="pallas" if self._pallas_ok else "lax")
+                backend=self.backend)
         return got
 
 

@@ -44,16 +44,8 @@ _STACKED = ("attn_norm", "wq", "wk", "wv", "wo",
 def _shard_map(fn, mesh, in_specs, out_specs):
     """shard_map without VMA/replication checking (the schedule's masked
     psum broadcasts are replicated by construction, not by type)."""
-    try:
-        from jax import shard_map as sm  # jax >= 0.8
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 _ATTN = ("attn_norm", "wq", "wk", "wv", "wo")

@@ -36,11 +36,7 @@ _NEG = -1e30  # mask value: finite so exp() underflows instead of NaN-ing
 
 
 def _to_varying(x, axis_names: tuple):
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis_names, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_names)
-    return x  # pre-VMA jax: no cast needed
+    return jax.lax.pcast(x, axis_names, to="varying")
 
 
 def _ring_block(q, k, v, axis_name: str, n_sp: int, causal: bool,
@@ -56,8 +52,7 @@ def _ring_block(q, k, v, axis_name: str, n_sp: int, causal: bool,
     o0 = jnp.zeros((b, h, s_blk, d), jnp.float32)
     # The loop carry becomes varying over every manual mesh axis (it mixes
     # with q/k/v, which are), so the invariant initial values must be cast
-    # to varying for the new shard_map VMA type system; older jax spells
-    # pcast as pvary, oldest needs nothing.
+    # to varying for shard_map's VMA type system.
     vary = tuple(mesh_axes) or (axis_name,)
     m0, l0, o0 = (_to_varying(x, vary) for x in (m0, l0, o0))
     perm = [(i, (i + 1) % n_sp) for i in range(n_sp)]
@@ -180,11 +175,6 @@ def ring_attention(q, k, v, mesh, sp_axis: str = "sp",
     enough that a score block hurts; extra ``block_q``/``block_k`` kwargs
     pass through to the kernel).
     """
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
     n_sp = mesh.shape[sp_axis]
     dp = dp_axis if dp_axis in mesh.shape else None
     tp = tp_axis if tp_axis in mesh.shape else None
@@ -202,7 +192,7 @@ def ring_attention(q, k, v, mesh, sp_axis: str = "sp",
     # slice indices, which the VMA checker rejects (jax suggests exactly
     # this workaround); the dense inner keeps the check.
     extra = {"check_vma": False} if inner == "flash" else {}
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(block_fn, axis_name=sp_axis, n_sp=n_sp, causal=causal,
                 mesh_axes=manual, **inner_kw),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, **extra)

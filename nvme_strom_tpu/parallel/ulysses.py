@@ -57,11 +57,6 @@ def ulysses_attention(q, k, v, mesh, sp_axis: str = "sp",
     ``attn`` swaps the local attention inner (e.g. the Pallas flash
     kernel) — it sees the full sequence, so any causal kernel works.
     """
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
     n_sp = mesh.shape[sp_axis]
     dp = dp_axis if dp_axis in mesh.shape else None
     tp = tp_axis if tp_axis in mesh.shape else None
@@ -75,16 +70,10 @@ def ulysses_attention(q, k, v, mesh, sp_axis: str = "sp",
         raise ValueError(
             f"seq {q.shape[2]} not divisible by sp={n_sp}")
     spec = P(dp, tp, sp_axis, None)
-    try:
-        fn = shard_map(
-            partial(_ulysses_block, sp_axis=sp_axis, n_sp=n_sp, attn=attn),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False)
-    except TypeError:
-        fn = shard_map(
-            partial(_ulysses_block, sp_axis=sp_axis, n_sp=n_sp, attn=attn),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False)
+    fn = jax.shard_map(
+        partial(_ulysses_block, sp_axis=sp_axis, n_sp=n_sp, attn=attn),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     return fn(q, k, v)
 
 

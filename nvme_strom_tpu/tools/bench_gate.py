@@ -7,13 +7,14 @@ overhead bound) fails CI instead of landing:
 
     python bench.py > /tmp/new.json
     bench-gate /tmp/new.json                  # vs newest BENCH_*.json
-    bench-gate /tmp/new.json --baseline BENCH_r06.json --json
+    bench-gate /tmp/new.json --baseline BENCH_x.json --json
 
-Baselines may be RAW bench.py output or the driver's wrapper format
-(``{"tail": "...last line is the JSON..."}``, BENCH_r01–r05's shape).
-Platforms must match (``tpu`` vs ``cpu-fallback``): CPU-fallback
-numbers are not comparable to silicon and the gate refuses to pretend
-otherwise — a mismatch is reported and exits 0 unless ``--strict``.
+Baselines may be RAW bench.py output or a wrapper
+(``{"tail": "...last line is the JSON..."}``).  No baseline ships with
+the repository.  Platforms must match (``tpu`` vs ``cpu``): numbers from
+a JAX_PLATFORMS=cpu run are not comparable to the chip's and the gate
+refuses to pretend otherwise — a mismatch is reported and exits 0
+unless ``--strict``.
 
 Tolerances are deliberately wide (dev boxes are noisy VMs; the gate
 exists to catch step-function regressions, not 3% drift).  A metric

@@ -128,6 +128,18 @@ def config_from_hf(hf_cfg: dict):
     )
 
 
+def strom_config_dict(cfg) -> dict:
+    """``strom_config.json`` of a converted checkpoint: the
+    TransformerConfig keys the serving/training entry points rebuild the
+    model from."""
+    out = {k: getattr(cfg, k) for k in (
+        "vocab", "d_model", "n_layers", "n_heads", "n_kv_heads", "d_ff",
+        "max_seq", "rope_theta", "norm_eps")}
+    if cfg.rope_scaling:
+        out["rope_scaling"] = dict(cfg.rope_scaling)
+    return out
+
+
 def _iter_hf_tensors(hf_dir: str) -> Iterator[Tuple[str, np.ndarray]]:
     """Yield (hf_name, np array) across every safetensors shard of the
     checkpoint.  Shard discovery (dir / index.json / single file) is
@@ -219,11 +231,7 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
         seen.add("lm_head")
     flush()
 
-    cfg_out = {k: getattr(cfg, k) for k in (
-        "vocab", "d_model", "n_layers", "n_heads", "n_kv_heads", "d_ff",
-        "max_seq", "rope_theta", "norm_eps")}
-    if cfg.rope_scaling:
-        cfg_out["rope_scaling"] = dict(cfg.rope_scaling)
+    cfg_out = strom_config_dict(cfg)
     # Provenance marker: lets reuse logic (examples/train_lm.py --from-hf)
     # detect that an existing conversion came from a DIFFERENT source
     # checkpoint instead of silently serving stale weights.

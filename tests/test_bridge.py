@@ -371,11 +371,48 @@ def test_overlap_off_switch_bit_for_bit(engine, tmp_data_file,
 
 @pytest.mark.perf
 def test_overlap_auto_gate_stays_off_on_cpu(engine, tmp_data_file):
-    """overlap=None (auto) keeps the CPU fallback on the current
-    device_put path — the overlap stage is a TPU-platform engagement."""
+    """overlap=None (auto) keeps a CPU device on the plain device_put
+    path — the overlap stage is a TPU-platform engagement."""
     path, payload = tmp_data_file
     ds = DeviceStream(engine, depth=2)          # overlap=None
     got = b"".join(np.asarray(a).tobytes()
                    for a in ds.stream_file(path))
     assert got == payload
     assert engine.stats.overlap_chunks == 0
+
+
+class _FakeTpu:
+    """Stands in for a device whose platform is ``tpu``."""
+    platform = "tpu"
+
+
+@pytest.mark.perf
+def test_tpu_transfer_failure_raises_instead_of_degrading(
+        engine, tmp_data_file, monkeypatch):
+    """On a TPU the overlap stage auto-engages and its one transfer is
+    the Pallas DMA from the pinned slab.  When that kernel is refused
+    the stream raises: no second attempt, no other transfer path, no
+    byte counted as delivered, no staging buffer leaked."""
+    import jax
+    from nvme_strom_tpu.ops import bridge
+    path, _ = tmp_data_file
+    attempts = []
+
+    def refusing_kernel(dev):
+        def call(pinned):
+            attempts.append(pinned)
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+        return call
+
+    monkeypatch.setattr(bridge, "_pallas_h2d", refusing_kernel)
+    # the pinned_host residency step succeeds, as would any plain put
+    monkeypatch.setattr(jax.sharding, "SingleDeviceSharding",
+                        lambda dev, memory_kind=None: (dev, memory_kind))
+    monkeypatch.setattr(jax, "device_put", lambda arr, where: arr)
+    ds = DeviceStream(engine, device=_FakeTpu(), depth=2)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        list(ds.stream_file(path))
+    assert len(attempts) == 1
+    assert engine.stats.bytes_to_device == 0
+    info = engine.pool_info()
+    assert info["free_buffers"] == info["n_buffers"]

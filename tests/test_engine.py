@@ -34,6 +34,24 @@ def engine(request):
         yield e
 
 
+def test_failed_build_raises_with_compiler_output(tmp_path, monkeypatch):
+    """A checkout whose csrc/ does not compile fails at first use with
+    the compiler's own message, not a bare CalledProcessError."""
+    import shutil
+    from nvme_strom_tpu.io import engine as eng
+    src = tmp_path / "csrc"
+    src.mkdir()
+    shutil.copy(eng._CSRC / "Makefile", src)
+    (src / "strom_io.h").write_text("")
+    (src / "strom_io.cc").write_text(
+        "#error strom build is broken on purpose\n")
+    monkeypatch.setattr(eng, "_CSRC", src)
+    monkeypatch.setattr(eng, "_LIB_PATH", src / "libstrom_io.so")
+    monkeypatch.setattr(eng, "_lib", None)
+    with pytest.raises(ImportError, match="broken on purpose"):
+        eng._load_lib()
+
+
 def test_check_file(tmp_data_file):
     path, payload = tmp_data_file
     info = check_file(path)

@@ -83,13 +83,43 @@ def test_partition_balance_within_unit_slack():
 def test_exchange_roundtrip_unaligned_rows():
     ex = IciExchange(exchange_mesh(N))
     assert ex.n == N
-    assert not ex._pallas_ok        # CPU mesh: lax degrade is THE path
+    assert ex.backend == "lax_all_gather"   # a CPU mesh's one path
     rng = np.random.default_rng(1)
     for row_bytes in (1, 4096, 12_345):
         rows = rng.integers(0, 256, size=(N, row_bytes), dtype=np.uint8)
         got = ex.all_gather(rows)
         assert got.shape == rows.shape
         assert np.array_equal(got, rows)
+
+
+class _ClaimsTpu:
+    """A CPU mesh whose devices report ``platform == "tpu"``."""
+
+    class _Dev:
+        platform = "tpu"
+
+    def __init__(self, mesh):
+        self.shape = mesh.shape
+        self.devices = np.array([self._Dev() for _ in mesh.devices.flat])
+
+
+def test_exchange_kernel_failure_on_tpu_mesh_raises(monkeypatch):
+    """An all-TPU mesh selects the Pallas ring, once, by platform.  When
+    the kernel cannot be built (here: a compiled Pallas TPU kernel on
+    the CPU backend) the exchange raises — it never degrades to
+    ``lax.all_gather``."""
+    mesh = exchange_mesh(N)
+    ex = IciExchange(_ClaimsTpu(mesh))
+    assert ex.backend == "pallas_ring"
+    ex.mesh = mesh                          # real devices to shard over
+    monkeypatch.setattr(
+        IciExchange, "_lax_gather_fn",
+        lambda self: pytest.fail("degraded to lax.all_gather"))
+    rows = np.zeros((N, 4096), np.uint8)
+    with pytest.raises(Exception) as e:
+        ex.all_gather(rows)
+    assert not isinstance(e.value, pytest.fail.Exception)
+    assert ex.backend == "pallas_ring"      # and stays selected
 
 
 def test_exchange_rejects_bad_shape():

@@ -558,13 +558,13 @@ def test_arena_emits_occupancy_counter(tmp_path, monkeypatch):
 
 def test_bench_gate_compare_and_formats(tmp_path):
     from nvme_strom_tpu.tools import bench_gate
-    base = {"metric": "x", "platform": "cpu-fallback", "value": 1.0,
+    base = {"metric": "x", "platform": "cpu", "value": 1.0,
             "verify_overhead_pct": 5.0,
             "observability": {"flight_overhead_pct": 1.0}}
-    good = {"metric": "x", "platform": "cpu-fallback", "value": 0.9,
+    good = {"metric": "x", "platform": "cpu", "value": 0.9,
             "verify_overhead_pct": 6.0,
             "observability": {"flight_overhead_pct": 1.5}}
-    bad = {"metric": "x", "platform": "cpu-fallback", "value": 0.4,
+    bad = {"metric": "x", "platform": "cpu", "value": 0.4,
            "verify_overhead_pct": 50.0,
            "observability": {"flight_overhead_pct": 9.0}}
     _res, regs = bench_gate.compare(base, good)
@@ -593,17 +593,26 @@ def test_bench_gate_compare_and_formats(tmp_path):
                             "--strict"]) == 1
 
 
-def test_bench_gate_current_baseline_parses():
-    """The shipped trajectory datapoint must parse — the gate is armed
-    from this tree onward."""
+def test_bench_gate_latest_baseline_picks_newest_that_parses(tmp_path):
+    """No datapoint ships with the tree (a baseline is a chip run's
+    output): the gate finds none there, and in a directory that has
+    some it takes the newest by name that parses."""
     import os
     from nvme_strom_tpu.tools.bench_gate import (latest_baseline,
                                                  load_bench_json)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = latest_baseline(root)
-    assert path is not None
+    assert latest_baseline(root) is None
+    for name, body in (
+            ("BENCH_a.json", {"metric": "x", "platform": "tpu",
+                              "value": 1.0}),
+            ("BENCH_b.json", {"metric": "x", "platform": "cpu",
+                              "value": 2.0})):
+        (tmp_path / name).write_text(json.dumps(body))
+    (tmp_path / "BENCH_c.json").write_text("not json")
+    path = latest_baseline(str(tmp_path))
+    assert os.path.basename(path) == "BENCH_b.json"
     doc = load_bench_json(path)
-    assert "metric" in doc and "platform" in doc
+    assert doc["platform"] == "cpu" and doc["value"] == 2.0
 
 
 # -- flight recorder: attribution summary in dumps ---------------------------
