@@ -1,0 +1,13 @@
+"""The benchmark's own tests: CPU only, not part of tier-1.
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q"""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
