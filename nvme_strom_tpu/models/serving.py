@@ -356,10 +356,20 @@ class DecodeServer:
         self._finished_carry: Dict[object, List[int]] = {}
         #: cumulative phase timers (the serving-gap attribution the
         #: round-3 verdict asked for): admission+prefill, device
-        #: dispatch, and the host readback syncs
+        #: dispatch, and the host readback syncs.  Every key is numeric
+        #: and cumulative (a reader subtracts two snapshots key by
+        #: key).  Inside ``admit_s``: ``prefill_s`` (host seconds of
+        #: the admissions' prefill calls; the rest of ``admit_s`` is the
+        #: scatter, the first token and bookkeeping — spans only),
+        #: ``admits``, ``queue_wait_s`` (Σ admission start − submit),
+        #: ``prefill_tokens`` (padded tokens handed to prefill) and
+        #: ``prompt_tokens`` (prompt tokens those prefills had to
+        #: compute: past the cached prefix)
         self.timings: Dict[str, float] = {
             "admit_s": 0.0, "dispatch_s": 0.0, "readback_s": 0.0,
-            "steps": 0, "readbacks": 0}
+            "steps": 0, "readbacks": 0,
+            "admits": 0, "queue_wait_s": 0.0, "prefill_s": 0.0,
+            "prefill_tokens": 0, "prompt_tokens": 0}
         #: per-request serving metrics of RETIRED requests ({rid:
         #: {"ttft_ms", "admit_wait_ms"}}, newest last, bounded) plus
         #: the running aggregates stats() reports
@@ -427,8 +437,7 @@ class DecodeServer:
             # walks the exact pre-tenant path
             from nvme_strom_tpu.io.tenants import get_registry
             req.tenant = get_registry().get(tenant)
-        tracer = self._tracer()
-        if tracer is not None:
+        if self._tracer().enabled:
             from nvme_strom_tpu.utils.trace import TraceContext
             req.trace = TraceContext.new()
             req.t_submit_ns = time.monotonic_ns()
@@ -446,17 +455,36 @@ class DecodeServer:
     # store attached the two halves compose to the old _admit verbatim.
 
     def _tracer(self):
-        """The span sink of this server: the KV-store engine's tracer
-        when a store is attached (one file for the whole stack), else
-        the global tracer — None when tracing is off, so every call
-        site stays one cheap check."""
+        """The span sink of this server, enabled or not: the KV-store
+        engine's tracer when a store is attached (one file for the
+        whole stack), else the global tracer.  Call sites that build a
+        span's arguments by hand check ``.enabled`` first."""
         store = self.kv_store
         tracer = (getattr(getattr(store, "engine", None), "tracer",
                           None) if store is not None else None)
         if tracer is None:
-            from nvme_strom_tpu.utils.trace import global_tracer
-            tracer = global_tracer
-        return tracer if tracer.enabled else None
+            from nvme_strom_tpu.utils import trace
+            tracer = trace.global_tracer
+        return tracer
+
+    def _span(self, name: str, ctx=None, **args):
+        """One of this server's spans (``Tracer.span``: on the JAX
+        profiler's timeline inside a profiler session, in the tracer
+        when it is on).
+        Names are fixed — docs/OBSERVABILITY.md lists them; none is one
+        of the benchmark loop's bare phase names."""
+        return self._tracer().span(name, "strom.serve", ctx, **args)
+
+    def _prefill_span(self, padded: list, suffix: list, rid: str):
+        """The span of one admission's prefill call, with its tokens
+        counted: ``padded`` are handed to prefill, ``suffix`` of them
+        are prompt past the cached prefix (the rest pads to the
+        compiled shape).  The caller adds the host seconds to
+        ``timings["prefill_s"]``."""
+        self.timings["prefill_tokens"] += len(padded)
+        self.timings["prompt_tokens"] += len(suffix)
+        return self._span("strom.serve.prefill", tokens=len(padded),
+                          useful=len(suffix), rid=rid)
 
     def _admit(self, slot: int, req: _Request) -> None:
         """Single-request admission (compat path; step_many batches)."""
@@ -478,24 +506,19 @@ class DecodeServer:
             self._finish_traced_inner(plan, restored)
 
     def _finish_traced_inner(self, plan: dict, restored: dict) -> None:
-        tracer = self._tracer()
         req = plan["req"]
-        if tracer is None or req.trace is None:
-            self._admit_finish(plan, restored)
-            return
-        from nvme_strom_tpu.utils.trace import use_context
-        ctx = req.trace.child()
-        t0 = time.monotonic_ns()
-        with use_context(ctx):
-            self._admit_finish(plan, restored)
-        tracer.add_span("strom.serve.admit", t0, time.monotonic_ns(),
-                        category="strom.serve", ctx=ctx,
-                        rid=str(req.rid), slot=plan["slot"],
+        wait = time.monotonic() - req.t_submit
+        self.timings["admits"] += 1
+        self.timings["queue_wait_s"] += wait
+        ctx = req.trace.child() if req.trace is not None else None
+        # rid last: the profiler's encoding cuts the arguments at a ","
+        with self._span("strom.serve.admit", ctx, slot=plan["slot"],
                         prompt_tokens=len(req.prompt),
+                        cached_blocks=plan.get("c", 0),
                         restored_pages=len(restored),
-                        queue_wait_ms=round(
-                            1000.0 * (time.monotonic() - req.t_submit),
-                            3))
+                        queue_wait_ms=round(1000.0 * wait, 3),
+                        rid=str(req.rid)):
+            self._admit_finish(plan, restored)
 
     def _admit_plan(self, slot: int, req: _Request) -> dict:
         """Capacity decisions only — nothing is prefilled yet."""
@@ -551,27 +574,22 @@ class DecodeServer:
         # fills are quota-charged to an owner instead of nobody
         ten = next((by_slot[s].tenant for s in wants
                     if by_slot[s].tenant is not None), None)
-        tracer = self._tracer()
-        if tracer is None:
-            with tenant_context(ten):
-                return store.restore_many(wants)
         # ONE batched restore serves several admitting requests: scope
         # it under the FIRST participating request's tree (the single-
         # request case — the acceptance walkthrough — is exact) and
         # name every trace id so a multi-request step stays attributable
-        from nvme_strom_tpu.utils.trace import use_context
         traced = [by_slot[s].trace for s in wants
                   if by_slot[s].trace is not None]
         ctx = traced[0].child() if traced else None
-        t0 = time.monotonic_ns()
-        with use_context(ctx), tenant_context(ten):
-            restored = store.restore_many(wants)
-        tracer.add_span(
-            "strom.serve.kv_restore", t0, time.monotonic_ns(),
-            category="strom.serve", ctx=ctx, slots=len(wants),
-            pages=sum(len(k) for _s, k in wants.values()),
-            traces=[f"{t.trace_id:x}" for t in traced])
-        return restored
+        with self._span("strom.serve.kv_restore", ctx,
+                        slots=len(wants)) as span, tenant_context(ten):
+            if span:        # something records: build the rest
+                # traces: a string, not a list — the profiler's
+                # annotation takes scalars (docs/OBSERVABILITY.md)
+                span.set_metadata(
+                    pages=sum(len(k) for _s, k in wants.values()),
+                    traces=" ".join(f"{t.trace_id:x}" for t in traced))
+            return store.restore_many(wants)
 
     def _contiguous_from(self, restored: dict, start: int) -> list:
         """The restored pages usable as a prefix extension: chain
@@ -598,53 +616,63 @@ class DecodeServer:
         import numpy as np
         slot, req = plan["slot"], plan["req"]
         s = len(req.prompt)
+        rid = str(req.rid)
         store = self.kv_store
         use = self._contiguous_from(restored, 0) if restored else []
         if use:
             P = store.page_tokens
             c2 = len(use)
             n_pb = -(-s // P)
-            cache = _dec.init_cache(self.cfg, 1, n_pb * P)
-            k_head = jnp.asarray(np.concatenate(
-                [k for k, _ in use], axis=2))[:, None]
-            v_head = jnp.asarray(np.concatenate(
-                [v for _, v in use], axis=2))[:, None]
-            cache["k"] = jax.lax.dynamic_update_slice(
-                cache["k"], k_head.astype(cache["k"].dtype),
-                (0, 0, 0, 0, 0))
-            cache["v"] = jax.lax.dynamic_update_slice(
-                cache["v"], v_head.astype(cache["v"].dtype),
-                (0, 0, 0, 0, 0))
-            cache["pos"] = jnp.asarray(c2 * P, jnp.int32)
             suffix = req.prompt[c2 * P:]
             padded = suffix + [0] * ((n_pb - c2) * P - len(suffix))
-            logits, cache = _dec.block_step(
-                self.params, jnp.asarray([padded], jnp.int32),
-                self.cfg, cache, last=len(suffix) - 1)
         else:
             bucket = 16
             while bucket < s:
                 bucket *= 2
             bucket = min(bucket, self.max_len)
-            cache = _dec.init_cache(self.cfg, 1, bucket)
+            suffix = req.prompt
             padded = req.prompt + [0] * (bucket - s)
-            prompt = jnp.asarray([padded], jnp.int32)
-            logits, cache = _dec.prefill(self.params, prompt, self.cfg,
-                                         cache, last=s - 1)
-        self.k_cache, self.v_cache = _scatter_prefill(
-            jnp.asarray(slot, jnp.int32), self.k_cache, self.v_cache,
-            cache["k"], cache["v"])
-        if store is not None:
-            self._store_put(req, cache, len(use), store.page_tokens)
-        first = self._first_token(logits, req, s)
-        self._pending_first.append((slot, first))
-        self.slots[slot] = req
-        self._set_slot_params(slot, req)
-        req.t_admit = time.monotonic()
-        # pos[slot] = s - nothing decoded past the prompt yet; tok is
-        # the token entering the cache on the next step
-        self.pos = self.pos.at[slot].set(s)
-        self.tok = self.tok.at[slot].set(first)
+        t0 = time.monotonic()
+        with self._prefill_span(padded, suffix, rid):
+            if use:
+                cache = _dec.init_cache(self.cfg, 1, n_pb * P)
+                k_head = jnp.asarray(np.concatenate(
+                    [k for k, _ in use], axis=2))[:, None]
+                v_head = jnp.asarray(np.concatenate(
+                    [v for _, v in use], axis=2))[:, None]
+                cache["k"] = jax.lax.dynamic_update_slice(
+                    cache["k"], k_head.astype(cache["k"].dtype),
+                    (0, 0, 0, 0, 0))
+                cache["v"] = jax.lax.dynamic_update_slice(
+                    cache["v"], v_head.astype(cache["v"].dtype),
+                    (0, 0, 0, 0, 0))
+                cache["pos"] = jnp.asarray(c2 * P, jnp.int32)
+                logits, cache = _dec.block_step(
+                    self.params, jnp.asarray([padded], jnp.int32),
+                    self.cfg, cache, last=len(suffix) - 1)
+            else:
+                cache = _dec.init_cache(self.cfg, 1, bucket)
+                prompt = jnp.asarray([padded], jnp.int32)
+                logits, cache = _dec.prefill(self.params, prompt,
+                                             self.cfg, cache, last=s - 1)
+        self.timings["prefill_s"] += time.monotonic() - t0
+        with self._span("strom.serve.scatter", blocks=1, rid=rid):
+            self.k_cache, self.v_cache = _scatter_prefill(
+                jnp.asarray(slot, jnp.int32), self.k_cache,
+                self.v_cache, cache["k"], cache["v"])
+            if store is not None:
+                self._store_put(req, cache, len(use), store.page_tokens)
+        with self._span("strom.serve.first_token", rid=rid):
+            first = self._first_token(logits, req, s)
+            self._pending_first.append((slot, first))
+            self.slots[slot] = req
+            self._set_slot_params(slot, req)
+            req.t_admit = time.monotonic()
+            # pos[slot] = s - nothing decoded past the prompt yet; tok
+            # is the token entering the cache on the next step
+            self.pos = self.pos.at[slot].set(s)
+            self.tok = self.tok.at[slot].set(first)
+
 
     def _store_put(self, req: _Request, cache: Dict, have: int,
                    P: int) -> None:
@@ -746,7 +774,7 @@ class DecodeServer:
                    if req.t_first is not None else 0.0)
         wait_ms = 1000.0 * (req.t_admit - req.t_submit)
         tracer = self._tracer()
-        if tracer is not None and req.trace is not None:
+        if tracer.enabled and req.trace is not None:
             end_ns = time.monotonic_ns()
             # the request's ROOT span, submit → retirement: the tree
             # every admit/restore/queue/engine span hangs under
@@ -1115,6 +1143,10 @@ class DecodeServer:
         the next occupant overwrites-before-attending.  Admission
         happens once per batch, so a freed slot idles at most
         ``k_steps - 1`` sub-steps."""
+        with self._span("strom.serve.step", k=k_steps):
+            return self._step_many(k_steps)
+
+    def _step_many(self, k_steps: int) -> Dict[object, List[int]]:
         self._ensure_params()
         finished: Dict[object, List[int]] = {}
         if self._finished_carry:
@@ -1131,34 +1163,9 @@ class DecodeServer:
         # decided after the batch readback below, so admission
         # pipelines with the decode dispatches instead of paying a
         # link round trip per request
-        plans = []
-        # load shedding (docs/RESILIENCE.md "failure domains"): while
-        # the engine behind the KV store is degraded, new prefills
-        # DEFER — they stay queued (re-checked every step; nothing
-        # fails) and in-flight decode keeps its slots, so the sick
-        # device serves the work it already owes instead of taking more
-        if self.queue and self._draining:
-            # drain mode (io/handoff.py): the gate is closed for NEW
-            # prefills only — queued requests hold for export to the
-            # replacement's bundle while in-flight slots run out
-            self._note_drain_defer(min(sum(s is None
-                                           for s in self.slots),
-                                       len(self.queue)))
-        elif self.queue and self._shed_now():
-            self._note_shed(min(sum(s is None for s in self.slots),
-                                len(self.queue)))
-        elif any(r.tenant is not None for r in self.queue):
-            # at least one queued request carries a tenant: tier-aware
-            # admission (sheds by tier under pressure, token buckets);
-            # an all-untagged queue — STROM_TENANTS=0 always — never
-            # reaches this branch and runs the loop below verbatim
-            plans = self._admit_tenants()
-        else:
-            for slot in range(self.B):
-                if (self.slots[slot] is None and self.queue
-                        and self._can_admit(self.queue[0])):
-                    plans.append(self._admit_plan(slot,
-                                                  self.queue.pop(0)))
+        with self._span("strom.serve.plan") as plan_span:
+            plans = self._plan_admissions()
+            plan_span.set_metadata(admitted=len(plans))
         # everything from here to the batch readback runs with
         # _pending_first possibly non-empty; an exception must not
         # leak those entries into the next call (first tokens would
@@ -1189,28 +1196,31 @@ class DecodeServer:
             toks: List = []
             stepped: List[List[int]] = []
             t0 = time.monotonic()
-            for j in range(k_eff):
-                stepping = [b for b in active_slots if left[b] > j]
-                if not stepping:
-                    break
-                mask = jnp.asarray([left.get(b, 0) > j
-                                    for b in range(self.B)])
-                nxt = self._run_step()
-                # the step ingested tok at pos for every stepping slot;
-                # exhausted slots hold position (their next step
-                # rewrites the same row — self-overwrite, never another
-                # slot's)
-                self.pos = jnp.where(mask, self.pos + 1, self.pos)
-                self.tok = jnp.where(mask, nxt, self.tok)
-                self._advanced(stepping)
-                toks.append(nxt)
-                stepped.append(stepping)
+            with self._span("strom.serve.dispatch", steps=k_eff):
+                for j in range(k_eff):
+                    stepping = [b for b in active_slots if left[b] > j]
+                    if not stepping:
+                        break
+                    mask = jnp.asarray([left.get(b, 0) > j
+                                        for b in range(self.B)])
+                    nxt = self._run_step()
+                    # the step ingested tok at pos for every stepping
+                    # slot; exhausted slots hold position (their next
+                    # step rewrites the same row — self-overwrite, never
+                    # another slot's)
+                    self.pos = jnp.where(mask, self.pos + 1, self.pos)
+                    self.tok = jnp.where(mask, nxt, self.tok)
+                    self._advanced(stepping)
+                    toks.append(nxt)
+                    stepped.append(stepping)
             self.timings["dispatch_s"] += time.monotonic() - t0
             t0 = time.monotonic()
             pending, self._pending_first = self._pending_first, []
-            first_h, tok_h = jax.device_get((     # the ONE readback
-                [v for _, v in pending],
-                jnp.stack(toks) if toks else None))
+            with self._span("strom.serve.readback", steps=len(toks),
+                            first=len(pending)):
+                first_h, tok_h = jax.device_get((   # the ONE readback
+                    [v for _, v in pending],
+                    jnp.stack(toks) if toks else None))
         except BaseException:
             if pending:
                 # the batch readback itself failed AFTER the swap
@@ -1226,23 +1236,58 @@ class DecodeServer:
         self.timings["readbacks"] += 1
         # replay in generation order: deferred first tokens precede
         # this batch's sub-step tokens for their slots
-        t_now = time.monotonic()
-        for (slot, _), v in zip(pending, first_h):
-            self.slots[slot].t_first = t_now    # first token DELIVERED
-            self.slots[slot].out.append(int(v))
-            ret = self._retire_or_keep(slot)
-            if ret:
-                finished[ret[0]] = ret[1]
-        for j, stepping in enumerate(stepped):
-            for slot in stepping:
-                if self.slots[slot] is None:
-                    continue        # retired at an earlier sub-step:
-                                    # its surplus tokens are discarded
-                self.slots[slot].out.append(int(tok_h[j][slot]))
+        with self._span("strom.serve.replay") as replay_span:
+            t_now = time.monotonic()
+            for (slot, _), v in zip(pending, first_h):
+                self.slots[slot].t_first = t_now  # first token DELIVERED
+                self.slots[slot].out.append(int(v))
                 ret = self._retire_or_keep(slot)
                 if ret:
                     finished[ret[0]] = ret[1]
+            for j, stepping in enumerate(stepped):
+                for slot in stepping:
+                    if self.slots[slot] is None:
+                        continue    # retired at an earlier sub-step:
+                                    # its surplus tokens are discarded
+                    self.slots[slot].out.append(int(tok_h[j][slot]))
+                    ret = self._retire_or_keep(slot)
+                    if ret:
+                        finished[ret[0]] = ret[1]
+            replay_span.set_metadata(finished=len(finished))
         return finished
+
+    def _plan_admissions(self) -> list:
+        """This step's admission plans (capacity decisions only), or
+        none while the gate is closed."""
+        plans = []
+        # load shedding (docs/RESILIENCE.md "failure domains"): while
+        # the engine behind the KV store is degraded, new prefills
+        # DEFER — they stay queued (re-checked every step; nothing
+        # fails) and in-flight decode keeps its slots, so the sick
+        # device serves the work it already owes instead of taking more
+        if self.queue and self._draining:
+            # drain mode (io/handoff.py): the gate is closed for NEW
+            # prefills only — queued requests hold for export to the
+            # replacement's bundle while in-flight slots run out
+            self._note_drain_defer(min(sum(s is None
+                                           for s in self.slots),
+                                       len(self.queue)))
+        elif self.queue and self._shed_now():
+            self._note_shed(min(sum(s is None for s in self.slots),
+                                len(self.queue)))
+        elif any(r.tenant is not None for r in self.queue):
+            # at least one queued request carries a tenant: tier-aware
+            # admission (sheds by tier under pressure, token buckets);
+            # an all-untagged queue — STROM_TENANTS=0 always — never
+            # reaches this branch and runs the loop below verbatim
+            plans = self._admit_tenants()
+        else:
+            for slot in range(self.B):
+                if (self.slots[slot] is None and self.queue
+                        and self._can_admit(self.queue[0])):
+                    plans.append(self._admit_plan(slot,
+                                                  self.queue.pop(0)))
+        return plans
 
     def run(self, lookahead: int = 1) -> Dict[object, List[int]]:
         """Drain the queue: step until every request finishes.
@@ -1475,20 +1520,27 @@ class PagedDecodeServer(DecodeServer):
         # the step's batched decode-class read) scatter into this
         # request's own new blocks and REGISTER in the HBM cache — the
         # next same-prefix admission hits DRAM, not NVMe
+        rid = str(req.rid)
         use = self._contiguous_from(restored, c) if restored else []
         c2 = len(use)
         if use:
             import numpy as np
-            rows_k = jnp.asarray(np.stack([k for k, _ in use], axis=1))
-            rows_v = jnp.asarray(np.stack([v for _, v in use], axis=1))
-            self.k_pool, self.v_pool = _scatter_blocks(
-                self.k_pool, self.v_pool,
-                jnp.asarray(blks[c:c + c2], jnp.int32), rows_k, rows_v)
-            if keys:
-                # keys is empty with prefix_cache=False (store restores
-                # still work; there is just no HBM registry to join)
-                for j in range(c2):
-                    self._pc_register(keys[c + j], blks[c + j])
+            with self._span("strom.serve.scatter", blocks=c2,
+                            rid=rid):
+                rows_k = jnp.asarray(np.stack([k for k, _ in use],
+                                              axis=1))
+                rows_v = jnp.asarray(np.stack([v for _, v in use],
+                                              axis=1))
+                self.k_pool, self.v_pool = _scatter_blocks(
+                    self.k_pool, self.v_pool,
+                    jnp.asarray(blks[c:c + c2], jnp.int32), rows_k,
+                    rows_v)
+                if keys:
+                    # keys is empty with prefix_cache=False (store
+                    # restores still work; there is just no HBM
+                    # registry to join)
+                    for j in range(c2):
+                        self._pc_register(keys[c + j], blks[c + j])
         ct = c + c2
 
         # prefill: gathered cached prefix (HBM-shared + just-restored
@@ -1497,42 +1549,49 @@ class PagedDecodeServer(DecodeServer):
         # prefill); pad rows sit past pos and are overwritten before
         # the mask reaches them
         n_pb = -(-s // bk)
-        cache = _dec.init_cache(self.cfg, 1, n_pb * bk)
-        if ct:
-            k_d, v_d = _gather_prefix(self.k_pool, self.v_pool,
-                                      jnp.asarray(blks[:ct], jnp.int32),
-                                      n_pb * bk)
-            cache["k"], cache["v"] = k_d, v_d
-            cache["pos"] = jnp.asarray(ct * bk, jnp.int32)
         suffix = req.prompt[ct * bk:]
         padded = suffix + [0] * ((n_pb - ct) * bk - len(suffix))
-        logits, cache = _dec.block_step(
-            self.params, jnp.asarray([padded], jnp.int32), self.cfg,
-            cache, last=len(suffix) - 1)
-        L, nkv, hd = (self.cfg.n_layers, self.cfg.n_kv_heads,
-                      self.cfg.head_dim)
-        rows_k = (cache["k"][:, 0, :, ct * bk:n_pb * bk]
-                  .reshape(L, nkv, n_pb - ct, bk, hd))
-        rows_v = (cache["v"][:, 0, :, ct * bk:n_pb * bk]
-                  .reshape(L, nkv, n_pb - ct, bk, hd))
-        self.k_pool, self.v_pool = _scatter_blocks(
-            self.k_pool, self.v_pool,
-            jnp.asarray(blks[ct:n_pb], jnp.int32),
-            rows_k.transpose(0, 2, 1, 3, 4),
-            rows_v.transpose(0, 2, 1, 3, 4))
-        # newly computed FULL blocks join the cache for future requests
-        for i in range(ct, len(keys)):
-            self._pc_register(keys[i], blks[i])
-        if self.kv_store is not None:
-            self._store_put(req, cache, ct, bk)
-        first = self._first_token(logits, req, s)
-        self._pending_first.append((slot, first))
-        self.slots[slot] = req
-        self._set_slot_params(slot, req)
-        req.t_admit = time.monotonic()
-        self.pos = self.pos.at[slot].set(s)
-        self._pos_h[slot] = s
-        self.tok = self.tok.at[slot].set(first)
+        t0 = time.monotonic()
+        with self._prefill_span(padded, suffix, rid):
+            cache = _dec.init_cache(self.cfg, 1, n_pb * bk)
+            if ct:
+                k_d, v_d = _gather_prefix(
+                    self.k_pool, self.v_pool,
+                    jnp.asarray(blks[:ct], jnp.int32), n_pb * bk)
+                cache["k"], cache["v"] = k_d, v_d
+                cache["pos"] = jnp.asarray(ct * bk, jnp.int32)
+            logits, cache = _dec.block_step(
+                self.params, jnp.asarray([padded], jnp.int32), self.cfg,
+                cache, last=len(suffix) - 1)
+        self.timings["prefill_s"] += time.monotonic() - t0
+        with self._span("strom.serve.scatter", blocks=n_pb - ct,
+                        rid=rid):
+            L, nkv, hd = (self.cfg.n_layers, self.cfg.n_kv_heads,
+                          self.cfg.head_dim)
+            rows_k = (cache["k"][:, 0, :, ct * bk:n_pb * bk]
+                      .reshape(L, nkv, n_pb - ct, bk, hd))
+            rows_v = (cache["v"][:, 0, :, ct * bk:n_pb * bk]
+                      .reshape(L, nkv, n_pb - ct, bk, hd))
+            self.k_pool, self.v_pool = _scatter_blocks(
+                self.k_pool, self.v_pool,
+                jnp.asarray(blks[ct:n_pb], jnp.int32),
+                rows_k.transpose(0, 2, 1, 3, 4),
+                rows_v.transpose(0, 2, 1, 3, 4))
+            # newly computed FULL blocks join the cache for future
+            # requests
+            for i in range(ct, len(keys)):
+                self._pc_register(keys[i], blks[i])
+            if self.kv_store is not None:
+                self._store_put(req, cache, ct, bk)
+        with self._span("strom.serve.first_token", rid=rid):
+            first = self._first_token(logits, req, s)
+            self._pending_first.append((slot, first))
+            self.slots[slot] = req
+            self._set_slot_params(slot, req)
+            req.t_admit = time.monotonic()
+            self.pos = self.pos.at[slot].set(s)
+            self._pos_h[slot] = s
+            self.tok = self.tok.at[slot].set(first)
 
     def _can_admit(self, req: _Request) -> bool:
         # submit() bounds prompt+max_new by max_len, so need can never

@@ -37,10 +37,14 @@ Components (span-name mapping in ``NAME_TO_COMPONENT``):
   ``hedge``          hedge submissions/races (``strom.resilient.hedge*``)
   ``degraded``       buffered brown-out service (``strom.read.degraded``,
                      ``strom.health.*``)
-  ``bridge``         host→HBM hop (``strom.bridge.hop``, ``strom.h2d.*``)
+  ``bridge``         host→HBM hop (``strom.bridge.hop``, ``strom.h2d*``)
   ``ici_scatter``    read-once restore shard exchange over the
                      interconnect (``strom.ici.*`` — ops/ici.py)
-  ``unattributed``   wall time outside every component (compute)
+  ``prefill``        the admission's prefill call (``strom.serve.prefill``
+                     — models/serving.py), so a store-less request no
+                     longer folds whole into ``unattributed``
+  ``unattributed``   wall time outside every component (decode steps,
+                     host work, scheduling gaps)
 
 Activation: ``STROM_ATTRIB=1`` (default off) builds the process-wide
 collector; every engine attaches it to its tracer, serving folds at
@@ -58,7 +62,7 @@ from nvme_strom_tpu.utils.lockwitness import make_lock
 #: the fixed breakdown, in render order (``unattributed`` is derived,
 #: always last)
 COMPONENTS = ("sched_queue", "hostcache", "nvme_read", "retry_backoff",
-              "hedge", "degraded", "bridge", "ici_scatter")
+              "hedge", "degraded", "bridge", "ici_scatter", "prefill")
 
 #: span name → component.  Prefix matching (see :func:`component_of`)
 #: keeps future ``strom.resilient.*`` names in the right bucket.
@@ -77,15 +81,15 @@ NAME_TO_COMPONENT = {
     "strom.resilient.hedge": "hedge",
     "strom.resilient.hedge_won": "hedge",
     "strom.bridge.hop": "bridge",
-    "strom.h2d.dispatch": "bridge",
-    "strom.h2d.sync": "bridge",
+    "strom.serve.prefill": "prefill",
     "strom.ici.exchange": "ici_scatter",
     "strom.ici.scatter": "ici_scatter",
 }
 
 #: serving/root spans: structure, not a cost component — excluded from
 #: the fold so the admission span (which CONTAINS prefill + engine I/O)
-#: cannot shadow the whole window as one component
+#: cannot shadow the whole window as one component.  An exact entry of
+#: ``NAME_TO_COMPONENT`` (``strom.serve.prefill``) is looked up first.
 _STRUCTURAL = ("strom.serve.",)
 
 
@@ -100,6 +104,8 @@ def component_of(name: str) -> Optional[str]:
             return None
     if name.startswith("strom.resilient."):
         return "retry_backoff"
+    if name.startswith("strom.h2d"):
+        return "bridge"
     if name.startswith("strom.ici."):
         return "ici_scatter"
     return None

@@ -232,18 +232,21 @@ def host_to_device(engine: StromEngine, host: np.ndarray, dev,
     array (e.g. the KV host-cache tier), never recycled staging memory
     — aliasing is fine, so no protective copy and no bounce count.
 
-    Spans: the dispatch is recorded in the strom tracer AND annotated for
-    the JAX profiler, so chrome://tracing / Perfetto views line up
-    (both clocks are CLOCK_MONOTONIC).
+    Span ``strom.h2d``: the dispatch, through ``Tracer.span`` — on the
+    JAX profiler's timeline always (the benchmark's
+    ``h2d_dispatch_share`` reads it there) and in the strom tracer when
+    that is enabled.  The two clocks tick alike, a constant offset
+    apart (the profiler's xplane counts from its session's start; the
+    check and its numbers: utils/trace.py's docstring).
     """
     import jax
     if dev.platform == "cpu" and not alias_safe:
         host = np.array(host)
         engine.stats.add(bounce_bytes=int(host.nbytes))
-    with jax.profiler.TraceAnnotation("strom.h2d"), \
-            engine.tracer.span("strom.h2d.dispatch", bytes=int(host.nbytes)):
+    nbytes = int(host.nbytes)
+    with engine.tracer.span("strom.h2d", bytes=nbytes):
         arr = jax.device_put(host, dev)
-    engine.stats.add(bytes_to_device=int(host.nbytes))
+    engine.stats.add(bytes_to_device=nbytes)
     return arr
 
 

@@ -31,6 +31,9 @@ from nvme_strom_tpu.io.engine import StromEngine, wait_exact
 from nvme_strom_tpu.io.plan import join_pieces, plan_and_submit
 from nvme_strom_tpu.utils.config import EngineConfig
 
+#: category of the restore path's spans (names: docs/OBSERVABILITY.md)
+_CAT = "strom.restore"
+
 
 def _normalize_index(idx, shape):
     """Device index (tuple of slices) → ((r0, r1), tail_slices)."""
@@ -142,18 +145,20 @@ class LazyCheckpoint:
                 eng = served
         out: Dict[str, object] = {}
         try:
-            for name in self.keys():
-                get = (shardings.get if isinstance(shardings, dict)
-                       else None)
-                sh = (get(name) if get
-                      else shardings(name, self.shape(name)))
-                if sh is None:
-                    raise KeyError(f"no sharding for tensor {name}")
-                out[name] = self._load_tensor(eng, name, sh)
-            if dtype is not None:
-                cast = jax.jit(lambda x: x.astype(dtype),
-                               out_shardings=None)
-                out = {n: cast(a) for n, a in out.items()}
+            with eng.tracer.span("strom.restore.load", _CAT,
+                                 tensors=len(self._by_name)):
+                for name in self.keys():
+                    get = (shardings.get if isinstance(shardings, dict)
+                           else None)
+                    sh = (get(name) if get
+                          else shardings(name, self.shape(name)))
+                    if sh is None:
+                        raise KeyError(f"no sharding for tensor {name}")
+                    out[name] = self._load_tensor(eng, name, sh)
+                if dtype is not None:
+                    cast = jax.jit(lambda x: x.astype(dtype),
+                                   out_shardings=None)
+                    out = {n: cast(a) for n, a in out.items()}
             return out
         finally:
             if own:
@@ -161,12 +166,22 @@ class LazyCheckpoint:
 
     def _load_tensor(self, eng: StromEngine, name: str, sharding,
                      klass: str = "restore"):
-        import jax
-
+        """One tensor → its global array, under ``strom.restore.tensor``
+        (the spans of docs/OBSERVABILITY.md's restore rows nest in it)."""
         sf = self._by_name[name]
         info = sf.tensors[name]
         gshape = tuple(info["shape"])
         np_dt = _np_dtype(info["dtype"])
+        nbytes = int(np.prod(gshape, dtype=np.int64)) * np_dt.itemsize
+        with eng.tracer.span("strom.restore.tensor", _CAT, tensor=name,
+                             bytes=nbytes):
+            return self._load_tensor_inner(eng, sf, name, gshape, np_dt,
+                                           sharding, klass)
+
+    def _load_tensor_inner(self, eng: StromEngine, sf, name: str,
+                           gshape: tuple, np_dt, sharding, klass: str):
+        import jax
+
         idx_map = sharding.addressable_devices_indices_map(gshape)
 
         # Group devices by ROW SPAN only: rows are contiguous on disk, so a
@@ -207,6 +222,7 @@ class LazyCheckpoint:
             if stamps is None:
                 stamps = sf._strom_crcs = tensor_checksums(sf)
             stamp = stamps.get(name)
+        span = eng.tracer.span
         fh = eng.open(sf.path)
         device_arrays = {}
         # Deferred staging release (shared DeviceStream discipline):
@@ -233,7 +249,9 @@ class LazyCheckpoint:
                         eng, fh, sf, name, r0, r1, np_dt, gshape,
                         klass=klass):
                     if check:
-                        crc = crc32c(view, crc)
+                        with span("strom.restore.slice", _CAT,
+                                  bytes=int(view.nbytes)):
+                            crc = crc32c(view, crc)
                         eng.stats.add(bytes_verified=int(view.nbytes))
                     cache: Dict[tuple, np.ndarray] = {}
                     put = []
@@ -248,33 +266,41 @@ class LazyCheckpoint:
                             if tail and any(
                                     (s.start, s.stop) != (0, d)
                                     for s, d in zip(tail, gshape[1:])):
-                                sub = view[(slice(None),) + tail]
+                                cut = view[(slice(None),) + tail]
                                 # strided column shard: host gather copies
-                                sub = np.ascontiguousarray(sub)
+                                with span("strom.restore.slice", _CAT,
+                                          bytes=int(cut.nbytes)):
+                                    sub = np.ascontiguousarray(cut)
                                 eng.stats.add(
                                     bounce_bytes=int(sub.nbytes))
                             cache[tkey] = sub
                         arr = host_to_device(eng, sub, dev)
                         parts[dev].append(arr)
                         put.append(arr)
-                    retire.push(release, put)
+                    with span("strom.restore.retire", _CAT):
+                        retire.push(release, put)
                 if check and crc != stamp:
                     eng.stats.add(checksum_failures=1)
                     raise ChecksumError(
                         f"tensor {name} of {sf.path} fails its stamped "
                         f"CRC32C ({crc:#010x} != {stamp:#010x}) — "
                         f"corrupt weights must not reach the model")
-                for dev, _ in devs:
-                    ps = parts[dev]
-                    device_arrays[dev] = (
-                        ps[0] if len(ps) == 1 else jnp.concatenate(ps))
+                with span("strom.restore.join", _CAT,
+                          parts=sum(len(ps) for ps in parts.values())):
+                    for dev, _ in devs:
+                        ps = parts[dev]
+                        device_arrays[dev] = (
+                            ps[0] if len(ps) == 1
+                            else jnp.concatenate(ps))
         finally:
-            retire.flush()
+            with span("strom.restore.retire", _CAT):
+                retire.flush()
             eng.close(fh)
 
         arrays = [device_arrays[d] for d in idx_map]
-        return jax.make_array_from_single_device_arrays(
-            gshape, sharding, arrays)
+        with span("strom.restore.join", _CAT, parts=len(arrays)):
+            return jax.make_array_from_single_device_arrays(
+                gshape, sharding, arrays)
 
     def _stream_span(self, eng, fh, sf, name, r0, r1, np_dt, gshape,
                      klass: str = "restore"):
@@ -291,14 +317,16 @@ class LazyCheckpoint:
         the cold-start demand-fault lane (FaultingCheckpoint) passes
         ``decode`` so a request-blocking tensor overtakes the bulk
         stream in the scheduler."""
+        span = eng.tracer.span
         if not gshape:
-            ent = sf.plan([name]).entries[0]
-            (pieces,) = plan_and_submit(eng, [(fh, ent.offset,
-                                               ent.length)],
-                                        klass=klass)
-            # one piece pre-tier; the host tier's hit/miss split can
-            # return several — join_pieces keeps one view either way
-            p = join_pieces(pieces, eng.stats)
+            with span("strom.restore.plan", _CAT, slices=1):
+                ent = sf.plan([name]).entries[0]
+                (pieces,) = plan_and_submit(eng, [(fh, ent.offset,
+                                                   ent.length)],
+                                            klass=klass)
+                # one piece pre-tier; the host tier's hit/miss split can
+                # return several — join_pieces keeps one view either way
+                p = join_pieces(pieces, eng.stats)
             done = False
             try:
                 # ownership transfers at the yield: the consumer's
@@ -308,7 +336,10 @@ class LazyCheckpoint:
                 # buffer under an in-flight H2D read = wrong bytes on
                 # device).  The finally only covers never-yielded
                 # abandonment; release() is idempotent either way.
-                yield p.wait().view(np_dt).reshape(()), p.release
+                with span("strom.restore.read_wait", _CAT,
+                          bytes=ent.length):
+                    view = p.wait()
+                yield view.view(np_dt).reshape(()), p.release
                 done = True
             finally:
                 if not done:
@@ -324,16 +355,19 @@ class LazyCheckpoint:
             # (counted as bounce — resize the pool to avoid this).  The
             # planner owns the oversized-extent split.
             for r in range(r0, r1):
-                ent = sf.slice_plan(name, r, 1)
+                with span("strom.restore.plan", _CAT, slices=1):
+                    ent = sf.slice_plan(name, r, 1)
+                    (pend,) = plan_and_submit(
+                        eng, [(fh, ent.offset, ent.length)],
+                        chunk_bytes=eng.config.chunk_bytes, klass=klass)
                 buf = np.empty(ent.length, dtype=np.uint8)
                 pos = 0
-                (pend,) = plan_and_submit(
-                    eng, [(fh, ent.offset, ent.length)],
-                    chunk_bytes=eng.config.chunk_bytes, klass=klass)
                 for p in pend:
                     # cumulative assembly: a silently short view would
                     # leave a garbage tail that reshapes cleanly
-                    v = wait_exact(p)
+                    with span("strom.restore.read_wait", _CAT,
+                              bytes=p.length):
+                        v = wait_exact(p)
                     buf[pos:pos + v.nbytes] = v
                     pos += v.nbytes
                     p.release()
@@ -349,32 +383,37 @@ class LazyCheckpoint:
         # chunk.  The engine defers reads past its pool without
         # blocking, so submitting the span up front cannot deadlock —
         # buffers recycle oldest-first as the consumer retires views.
-        slices = []
-        for r in range(r0, r1, chunk_rows):
-            n = min(chunk_rows, r1 - r)
-            ent = sf.slice_plan(name, r, n)
-            slices.append(((fh, ent.offset, ent.length), ent.shape))
-        planned = plan_and_submit(eng, [s for s, _ in slices],
-                                  chunk_bytes=eng.config.chunk_bytes,
-                                  klass=klass)
-        pend = []
-        for ((_, _, ln), shp), pieces in zip(slices, planned):
-            if not pieces:    # zero-element slice: no I/O to wait on
-                pend.append((None, shp))
-                continue
-            # a nonzero slice fits one buffer, so pre-tier this is one
-            # zero-copy piece; a host-tier hit/miss split joins on host
-            pend.append((join_pieces(pieces, eng.stats), shp))
+        with span("strom.restore.plan", _CAT,
+                  slices=len(range(r0, r1, chunk_rows))):
+            slices = []
+            for r in range(r0, r1, chunk_rows):
+                n = min(chunk_rows, r1 - r)
+                ent = sf.slice_plan(name, r, n)
+                slices.append(((fh, ent.offset, ent.length), ent.shape))
+            planned = plan_and_submit(eng, [s for s, _ in slices],
+                                      chunk_bytes=eng.config.chunk_bytes,
+                                      klass=klass)
+            pend = []
+            for ((_, _, ln), shp), pieces in zip(slices, planned):
+                if not pieces:    # zero-element slice: no I/O to wait on
+                    pend.append((None, shp, 0))
+                    continue
+                # a nonzero slice fits one buffer, so pre-tier this is
+                # one zero-copy piece; a host-tier hit/miss split joins
+                # on host
+                pend.append((join_pieces(pieces, eng.stats), shp, ln))
         try:
             while pend:
-                p, shp = pend.pop(0)
+                p, shp, ln = pend.pop(0)
                 if p is None:
                     yield np.empty(0, np.uint8).view(np_dt).reshape(shp), \
                         None
                     continue
-                yield p.wait().view(np_dt).reshape(shp), p.release
+                with span("strom.restore.read_wait", _CAT, bytes=ln):
+                    view = p.wait()
+                yield view.view(np_dt).reshape(shp), p.release
         finally:
-            for p, _ in pend:  # abandoned mid-span: drain + free
+            for p, _, _ in pend:  # abandoned mid-span: drain + free
                 if p is not None:
                     p.release()
 

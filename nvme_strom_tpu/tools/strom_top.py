@@ -37,6 +37,7 @@ _COMPONENTS = (
     ("degraded", "degr"),
     ("bridge", "bridge"),
     ("ici_scatter", "ici"),
+    ("prefill", "prefill"),
     ("unattributed", "other"),
 )
 
