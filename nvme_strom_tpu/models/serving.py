@@ -12,9 +12,18 @@ TPU-first shape: the batch step is one jitted program with static
 shapes.  Per-slot sequence positions are data (a ``(B,)`` vector), not
 shapes: cache writes scatter to per-row positions, attention masks by
 ``pos[b]``, RoPE takes per-row positions (transformer._rope's 2-D
-form).  Admission prefills a single request through the standard dense
-prefill and scatters its KV rows into the slot — one compiled step
-program serves every mix of request states.
+form).  One compiled step program serves every mix of request states.
+
+Admission is one compiled program too (``_serve_prefill`` for the dense
+slots, ``_paged_prefill`` for the block pool): the cached prefix gathered
+into a dense cache, ``decode.block_step`` over the right-padded suffix,
+and the new KV rows written into the donated slot cache / pool blocks.
+It is keyed on shapes alone — (padded suffix length, dense cache length):
+multiples of ``block_len`` in the paged server, the power-of-two bucket
+(or the store's page multiple) in the dense one.  The true last row, the
+slot and the block ids are data, so prompts of 97 and 128 tokens share
+one program; ``timings["prefill_programs"]`` counts the shapes a server
+has used.  The call returns at dispatch: the logits stay on the device.
 
 Per-request decoding params: ``max_new``, ``eos_id``, and sampling —
 ``temperature``/``top_p``/``seed`` are per-SLOT vectors (data, like the
@@ -115,40 +124,96 @@ def _sample_slots(logits, temps, top_ps, seeds, pos):
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
 def _scatter_blocks(k_pool, v_pool, blks, k_rows, v_rows):
-    """Admission scatter: (L, n, nkv, bk, hd) prompt rows into pool
-    blocks ``blks`` (n,) — one donated program, no per-block pool
-    copies."""
+    """KV rows (L, n, nkv, bk, hd) into pool blocks ``blks`` (n,): the
+    tail of ``_paged_prefill``, and on its own (one donated program, no
+    per-block pool copies) for pages restored from the store."""
     k_pool = k_pool.at[:, blks].set(k_rows.astype(k_pool.dtype))
     v_pool = v_pool.at[:, blks].set(v_rows.astype(v_pool.dtype))
     return k_pool, v_pool
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def _gather_prefix(k_pool, v_pool, blks, total_len: int):
-    """Cached prefix blocks → the head of a dense (L, 1, nkv, S, hd)
-    cache pair, zero-padded to ``total_len`` positions (the suffix
-    block_step writes the rest).  One gather per admission — prefix
-    caching trades this HBM read for the prefix's quadratic prefill
-    compute."""
+@jax.jit
+def _gather_prefix(k_pool, v_pool, blks):
+    """Pool blocks ``blks`` (c,) → a dense (L, 1, nkv, c * bk, hd) cache
+    pair: the cached prefix at the head of an admission's prefill (one
+    gather per admission — prefix caching trades this HBM read for the
+    prefix's quadratic prefill compute), and the pages ``_store_put``
+    pulls."""
     def to_dense(pool):
         rows = pool[:, blks]                   # (L, c, nkv, bk, hd)
         L, c, nkv, bk, hd = rows.shape
-        dense = rows.transpose(0, 2, 1, 3, 4).reshape(L, nkv, c * bk,
-                                                      hd)
-        pad = total_len - c * bk
-        dense = jnp.pad(dense, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        return dense[:, None]                  # (L, 1, nkv, S, hd)
+        return rows.transpose(0, 2, 1, 3, 4).reshape(L, 1, nkv, c * bk,
+                                                     hd)
     return to_dense(k_pool), to_dense(v_pool)
 
 
-@functools.partial(jax.jit, donate_argnums=(1, 2))
-def _scatter_prefill(slot, k_cache, v_cache, k_new, v_new):
-    """Place a prefilled request's (L,1,nkv,s,hd) KV at slot rows."""
+def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
+                  k_head, v_head, last):
+    """The admission prefill, traced inside both servers' programs:
+    ``block_step`` of the right-padded suffix ``tokens`` (1, m) behind
+    the cached prefix ``k_head``/``v_head`` ((L, 1, nkv, c, hd), or None
+    when nothing is cached — block_step at pos 0 IS the dense prefill,
+    so every admission shares one math).
+
+    Returns (logits (1, vocab) f32 at suffix row ``last``, k, v dense
+    (L, 1, nkv, c + m, hd)).  The pad rows sit past ``last``: causality
+    keeps them out of the logits, and their cache entries are dead —
+    decode overwrites a position before its mask exposes it."""
+    m = tokens.shape[1]
+    cache = _dec.init_cache(cfg, 1, m)
+    if k_head is not None:
+        cache["k"] = jnp.concatenate(
+            [k_head.astype(cfg.dtype), cache["k"]], axis=3)
+        cache["v"] = jnp.concatenate(
+            [v_head.astype(cfg.dtype), cache["v"]], axis=3)
+        cache["pos"] = jnp.asarray(k_head.shape[3], jnp.int32)
+    logits, cache = _dec.block_step(params, tokens, cfg, cache, last=last)
+    return logits, cache["k"], cache["v"]
+
+
+@functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2, 3))
+def _serve_prefill(params: Dict, cfg: TransformerConfig, k_cache,
+                   v_cache, tokens, k_head, v_head, slot, last):
+    """One dense-slot admission: ``_prefill_rows``, then the request's
+    (L, 1, nkv, S, hd) KV placed at the head of row ``slot`` of the
+    donated slot caches.  ``slot`` and ``last`` are data: the program is
+    keyed on (tokens, head) shapes only.  Returns (logits, k_cache,
+    v_cache)."""
+    logits, k, v = _prefill_rows(params, cfg, tokens, k_head, v_head,
+                                 last)
     k_cache = jax.lax.dynamic_update_slice(
-        k_cache, k_new.astype(k_cache.dtype), (0, slot, 0, 0, 0))
+        k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0, 0))
     v_cache = jax.lax.dynamic_update_slice(
-        v_cache, v_new.astype(v_cache.dtype), (0, slot, 0, 0, 0))
-    return k_cache, v_cache
+        v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0, 0))
+    return logits, k_cache, v_cache
+
+
+@functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2, 3))
+def _paged_prefill(params: Dict, cfg: TransformerConfig, k_pool, v_pool,
+                   tokens, blks, last):
+    """One block-pool admission: ``blks`` (n,) are the pool blocks of
+    the whole padded prompt, ``tokens`` (1, m) its suffix past the
+    cached blocks — so the first ``n - m // block_len`` of ``blks`` are
+    gathered as the prefix (``_gather_prefix``), ``_prefill_rows`` runs
+    the suffix, and its rows land in the remaining blocks of the donated
+    pools.  Block ids and ``last`` are data: the program is keyed on
+    (m, n) only.  Returns (logits, k_pool, v_pool)."""
+    bk = k_pool.shape[3]
+    ct = blks.shape[0] - tokens.shape[1] // bk
+    k_head = v_head = None
+    if ct:
+        k_head, v_head = _gather_prefix(k_pool, v_pool, blks[:ct])
+    logits, k, v = _prefill_rows(params, cfg, tokens, k_head, v_head,
+                                 last)
+
+    def new_rows(dense):                   # → (L, n - ct, nkv, bk, hd)
+        L, _, nkv, _, hd = dense.shape
+        return (dense[:, 0, :, ct * bk:].reshape(L, nkv, -1, bk, hd)
+                .transpose(0, 2, 1, 3, 4))
+
+    k_pool, v_pool = _scatter_blocks(k_pool, v_pool, blks[ct:],
+                                     new_rows(k), new_rows(v))
+    return logits, k_pool, v_pool
 
 
 def _batched_step_body(params: Dict, cfg: TransformerConfig, tok, pos,
@@ -359,17 +424,25 @@ class DecodeServer:
         #: dispatch, and the host readback syncs.  Every key is numeric
         #: and cumulative (a reader subtracts two snapshots key by
         #: key).  Inside ``admit_s``: ``prefill_s`` (host seconds of
-        #: the admissions' prefill calls; the rest of ``admit_s`` is the
-        #: scatter, the first token and bookkeeping — spans only),
-        #: ``admits``, ``queue_wait_s`` (Σ admission start − submit),
-        #: ``prefill_tokens`` (padded tokens handed to prefill) and
+        #: the admissions' prefill calls — the DISPATCH of the compiled
+        #: program, or its compilation on a shape's first use; its
+        #: device time is paid where the host next waits, in
+        #: ``readback_s``; the rest of ``admit_s`` is store pages, the
+        #: first token and bookkeeping — spans only), ``admits``,
+        #: ``queue_wait_s`` (Σ admission start − submit),
+        #: ``prefill_tokens`` (padded tokens handed to prefill),
         #: ``prompt_tokens`` (prompt tokens those prefills had to
-        #: compute: past the cached prefix)
+        #: compute: past the cached prefix) and ``prefill_programs``
+        #: (distinct (suffix, cache) shapes this server has prefilled
+        #: with, each one compiled or fetched program: a handful on a
+        #: healthy server, a climbing count is a shape leak)
         self.timings: Dict[str, float] = {
             "admit_s": 0.0, "dispatch_s": 0.0, "readback_s": 0.0,
             "steps": 0, "readbacks": 0,
             "admits": 0, "queue_wait_s": 0.0, "prefill_s": 0.0,
-            "prefill_tokens": 0, "prompt_tokens": 0}
+            "prefill_tokens": 0, "prompt_tokens": 0,
+            "prefill_programs": 0}
+        self._prefill_shapes: set = set()
         #: per-request serving metrics of RETIRED requests ({rid:
         #: {"ttft_ms", "admit_wait_ms"}}, newest last, bounded) plus
         #: the running aggregates stats() reports
@@ -475,16 +548,21 @@ class DecodeServer:
         of the benchmark loop's bare phase names."""
         return self._tracer().span(name, "strom.serve", ctx, **args)
 
-    def _prefill_span(self, padded: list, suffix: list, rid: str):
+    def _prefill_span(self, padded: list, suffix: list, cache_len: int,
+                      rid: str):
         """The span of one admission's prefill call, with its tokens
-        counted: ``padded`` are handed to prefill, ``suffix`` of them
-        are prompt past the cached prefix (the rest pads to the
-        compiled shape).  The caller adds the host seconds to
+        and its program counted: ``padded`` are handed to prefill,
+        ``suffix`` of them are prompt past the cached prefix (the rest
+        pads to the compiled shape), ``cache_len`` is the dense cache
+        they run against.  The caller adds the host seconds to
         ``timings["prefill_s"]``."""
         self.timings["prefill_tokens"] += len(padded)
         self.timings["prompt_tokens"] += len(suffix)
+        self._prefill_shapes.add((len(padded), cache_len))
+        self.timings["prefill_programs"] = len(self._prefill_shapes)
         return self._span("strom.serve.prefill", tokens=len(padded),
-                          useful=len(suffix), rid=rid)
+                          useful=len(suffix),
+                          program=f"{len(padded)}x{cache_len}", rid=rid)
 
     def _admit(self, slot: int, req: _Request) -> None:
         """Single-request admission (compat path; step_many batches)."""
@@ -602,67 +680,51 @@ class DecodeServer:
         return use
 
     def _admit_finish(self, plan: dict, restored: dict) -> None:
-        """Prefill the request (suffix-only when pages restored),
-        scatter its KV into the slot.
+        """Prefill the request (suffix-only when pages restored) into
+        its slot: one ``_serve_prefill`` call.
 
         Without a store hit the prompt right-pads to a power-of-two
         bucket so admission compiles once per bucket, not once per
-        prompt length; the pad rows' cache entries are dead (decode
-        overwrites a position before its mask exposes it) and the
-        first-token logits read at the true last position.  With a hit,
-        the restored pages head a page-granular cache and block_step
-        prefills only the suffix (block_step at pos 0 IS the dense
-        prefill, so the two paths share one math)."""
+        prompt length; the first-token logits read at the true last
+        position.  With a hit, the restored pages head a page-granular
+        cache and only the suffix is computed."""
         import numpy as np
         slot, req = plan["slot"], plan["req"]
         s = len(req.prompt)
         rid = str(req.rid)
         store = self.kv_store
         use = self._contiguous_from(restored, 0) if restored else []
+        k_head = v_head = None
         if use:
             P = store.page_tokens
-            c2 = len(use)
-            n_pb = -(-s // P)
-            suffix = req.prompt[c2 * P:]
-            padded = suffix + [0] * ((n_pb - c2) * P - len(suffix))
+            cache_len = -(-s // P) * P
+            suffix = req.prompt[len(use) * P:]
+            k_head = np.concatenate([k for k, _ in use], axis=2)[:, None]
+            v_head = np.concatenate([v for _, v in use], axis=2)[:, None]
         else:
-            bucket = 16
-            while bucket < s:
-                bucket *= 2
-            bucket = min(bucket, self.max_len)
+            cache_len = 16
+            while cache_len < s:
+                cache_len *= 2
+            cache_len = min(cache_len, self.max_len)
             suffix = req.prompt
-            padded = req.prompt + [0] * (bucket - s)
+        padded = suffix + [0] * (cache_len - s)
         t0 = time.monotonic()
-        with self._prefill_span(padded, suffix, rid):
-            if use:
-                cache = _dec.init_cache(self.cfg, 1, n_pb * P)
-                k_head = jnp.asarray(np.concatenate(
-                    [k for k, _ in use], axis=2))[:, None]
-                v_head = jnp.asarray(np.concatenate(
-                    [v for _, v in use], axis=2))[:, None]
-                cache["k"] = jax.lax.dynamic_update_slice(
-                    cache["k"], k_head.astype(cache["k"].dtype),
-                    (0, 0, 0, 0, 0))
-                cache["v"] = jax.lax.dynamic_update_slice(
-                    cache["v"], v_head.astype(cache["v"].dtype),
-                    (0, 0, 0, 0, 0))
-                cache["pos"] = jnp.asarray(c2 * P, jnp.int32)
-                logits, cache = _dec.block_step(
-                    self.params, jnp.asarray([padded], jnp.int32),
-                    self.cfg, cache, last=len(suffix) - 1)
-            else:
-                cache = _dec.init_cache(self.cfg, 1, bucket)
-                prompt = jnp.asarray([padded], jnp.int32)
-                logits, cache = _dec.prefill(self.params, prompt,
-                                             self.cfg, cache, last=s - 1)
+        with self._prefill_span(padded, suffix, cache_len, rid):
+            logits, self.k_cache, self.v_cache = _serve_prefill(
+                self.params, self.cfg, self.k_cache, self.v_cache,
+                np.asarray([padded], np.int32), k_head, v_head, slot,
+                len(suffix) - 1)
         self.timings["prefill_s"] += time.monotonic() - t0
-        with self._span("strom.serve.scatter", blocks=1, rid=rid):
-            self.k_cache, self.v_cache = _scatter_prefill(
-                jnp.asarray(slot, jnp.int32), self.k_cache,
-                self.v_cache, cache["k"], cache["v"])
-            if store is not None:
-                self._store_put(req, cache, len(use), store.page_tokens)
-        with self._span("strom.serve.first_token", rid=rid):
+        if store is not None:
+            with self._span("strom.serve.scatter", blocks=1, rid=rid):
+                self._store_put(req, slot, len(use), store.page_tokens)
+        self._admit_first_token(slot, req, logits)
+
+    def _admit_first_token(self, slot: int, req: _Request, logits) -> None:
+        """The admitted slot's first token and decoding state — all
+        dispatches, nothing read back."""
+        s = len(req.prompt)
+        with self._span("strom.serve.first_token", rid=str(req.rid)):
             first = self._first_token(logits, req, s)
             self._pending_first.append((slot, first))
             self.slots[slot] = req
@@ -673,8 +735,13 @@ class DecodeServer:
             self.pos = self.pos.at[slot].set(s)
             self.tok = self.tok.at[slot].set(first)
 
+    def _kv_rows(self, slot: int, lo: int, hi: int):
+        """The slot's KV at prompt positions lo..hi, on the device:
+        (k, v), each (L, nkv, hi - lo, hd)."""
+        return (self.k_cache[:, slot, :, lo:hi],
+                self.v_cache[:, slot, :, lo:hi])
 
-    def _store_put(self, req: _Request, cache: Dict, have: int,
+    def _store_put(self, req: _Request, slot: int, have: int,
                    P: int) -> None:
         """Persist this admission's newly computed full prompt pages
         (chain indices ``have..``) — written once store-wide however
@@ -686,9 +753,9 @@ class DecodeServer:
         n_full = len(keys)
         if n_full <= have:
             return
-        # one device_get for the whole new-page range, then page slices
-        k_all = np.asarray(cache["k"][:, 0, :, have * P:n_full * P])
-        v_all = np.asarray(cache["v"][:, 0, :, have * P:n_full * P])
+        # one pull for the whole new-page range, then page slices
+        k_all, v_all = (np.asarray(a) for a in
+                        self._kv_rows(slot, have * P, n_full * P))
         pages = [(keys[i],
                   k_all[:, :, (i - have) * P:(i - have + 1) * P],
                   v_all[:, :, (i - have) * P:(i - have + 1) * P])
@@ -845,9 +912,9 @@ class DecodeServer:
     def stats(self) -> Dict[str, int]:
         """Point-in-time serving gauges (the STAT_INFO discipline for
         the inference tier): slot occupancy, queue depth, tokens
-        generated by in-flight requests, and the retired requests'
-        TTFT / admission-wait aggregates (per-request values live in
-        ``request_metrics``)."""
+        generated by in-flight requests, the retired requests' TTFT /
+        admission-wait aggregates (per-request values live in
+        ``request_metrics``), and the prefill programs (shapes) used."""
         agg = self._metrics_agg
         n = agg["n"]
         out = {
@@ -863,6 +930,7 @@ class DecodeServer:
             if n else 0.0,
             "admit_wait_ms_max": round(agg["wait_max"], 3),
             "admissions_shed": self.admissions_shed,
+            "prefill_programs": len(self._prefill_shapes),
         }
         if self.tenant_sheds:     # key appears only once tenancy acted
             out["tenant_sheds"] = dict(self.tenant_sheds)
@@ -1507,6 +1575,7 @@ class PagedDecodeServer(DecodeServer):
         return True    # restored pages land in already-reserved blocks
 
     def _admit_finish(self, plan: dict, restored: dict) -> None:
+        import numpy as np
         slot, req = plan["slot"], plan["req"]
         keys, c, blks = plan["keys"], plan["c"], plan["blks"]
         s = len(req.prompt)
@@ -1524,7 +1593,6 @@ class PagedDecodeServer(DecodeServer):
         use = self._contiguous_from(restored, c) if restored else []
         c2 = len(use)
         if use:
-            import numpy as np
             with self._span("strom.serve.scatter", blocks=c2,
                             rid=rid):
                 rows_k = jnp.asarray(np.stack([k for k, _ in use],
@@ -1543,55 +1611,37 @@ class PagedDecodeServer(DecodeServer):
                         self._pc_register(keys[c + j], blks[c + j])
         ct = c + c2
 
-        # prefill: gathered cached prefix (HBM-shared + just-restored
-        # blocks) + one block_step over the suffix (from an empty cache
-        # when nothing matched — block_step at pos 0 IS the dense
-        # prefill); pad rows sit past pos and are overwritten before
-        # the mask reaches them
+        # prefill: ONE program gathers the cached prefix (HBM-shared +
+        # just-restored blocks), runs block_step over the suffix and
+        # writes its rows into the new blocks; pad rows sit past pos and
+        # are overwritten before the mask reaches them
         n_pb = -(-s // bk)
         suffix = req.prompt[ct * bk:]
-        padded = suffix + [0] * ((n_pb - ct) * bk - len(suffix))
+        padded = suffix + [0] * (n_pb * bk - s)
         t0 = time.monotonic()
-        with self._prefill_span(padded, suffix, rid):
-            cache = _dec.init_cache(self.cfg, 1, n_pb * bk)
-            if ct:
-                k_d, v_d = _gather_prefix(
-                    self.k_pool, self.v_pool,
-                    jnp.asarray(blks[:ct], jnp.int32), n_pb * bk)
-                cache["k"], cache["v"] = k_d, v_d
-                cache["pos"] = jnp.asarray(ct * bk, jnp.int32)
-            logits, cache = _dec.block_step(
-                self.params, jnp.asarray([padded], jnp.int32), self.cfg,
-                cache, last=len(suffix) - 1)
+        with self._prefill_span(padded, suffix, n_pb * bk, rid):
+            logits, self.k_pool, self.v_pool = _paged_prefill(
+                self.params, self.cfg, self.k_pool, self.v_pool,
+                np.asarray([padded], np.int32),
+                np.asarray(blks[:n_pb], np.int32), len(suffix) - 1)
         self.timings["prefill_s"] += time.monotonic() - t0
         with self._span("strom.serve.scatter", blocks=n_pb - ct,
                         rid=rid):
-            L, nkv, hd = (self.cfg.n_layers, self.cfg.n_kv_heads,
-                          self.cfg.head_dim)
-            rows_k = (cache["k"][:, 0, :, ct * bk:n_pb * bk]
-                      .reshape(L, nkv, n_pb - ct, bk, hd))
-            rows_v = (cache["v"][:, 0, :, ct * bk:n_pb * bk]
-                      .reshape(L, nkv, n_pb - ct, bk, hd))
-            self.k_pool, self.v_pool = _scatter_blocks(
-                self.k_pool, self.v_pool,
-                jnp.asarray(blks[ct:n_pb], jnp.int32),
-                rows_k.transpose(0, 2, 1, 3, 4),
-                rows_v.transpose(0, 2, 1, 3, 4))
             # newly computed FULL blocks join the cache for future
             # requests
             for i in range(ct, len(keys)):
                 self._pc_register(keys[i], blks[i])
             if self.kv_store is not None:
-                self._store_put(req, cache, ct, bk)
-        with self._span("strom.serve.first_token", rid=rid):
-            first = self._first_token(logits, req, s)
-            self._pending_first.append((slot, first))
-            self.slots[slot] = req
-            self._set_slot_params(slot, req)
-            req.t_admit = time.monotonic()
-            self.pos = self.pos.at[slot].set(s)
-            self._pos_h[slot] = s
-            self.tok = self.tok.at[slot].set(first)
+                self._store_put(req, slot, ct, bk)
+        self._admit_first_token(slot, req, logits)
+        self._pos_h[slot] = s
+
+    def _kv_rows(self, slot: int, lo: int, hi: int):
+        bk = self.block_len
+        k, v = _gather_prefix(
+            self.k_pool, self.v_pool,
+            jnp.asarray(self.blocks[slot][lo // bk:hi // bk], jnp.int32))
+        return k[:, 0], v[:, 0]
 
     def _can_admit(self, req: _Request) -> bool:
         # submit() bounds prompt+max_new by max_len, so need can never

@@ -191,6 +191,30 @@ def test_decode_step_fits_one_chip(topo, monkeypatch, server):
     assert need < HBM_BYTES, m
 
 
+@pytest.mark.parametrize("suffix_blocks,blocks", [(4, 4), (1, 4)],
+                         ids=["no_hit", "prefix_hit"])
+def test_prefill_program_fits_one_chip(topo, suffix_blocks, blocks):
+    """The paged server's admission program at chip_smoke.py's widths,
+    depth and pool: it compiles, fits, and writes the donated pools in
+    place (no second copy of a pool is ever live)."""
+    from nvme_strom_tpu.models import serving
+    smoke, cfg = _smoke_cfg()
+    sh = _one(topo)
+    bk = smoke.BLOCK_LEN
+    params = _param_specs(cfg, lambda name: sh)
+    pool = _spec((cfg.n_layers, smoke.POOL_BLOCKS + 1, NKV, bk, HD),
+                 jnp.bfloat16, sh)
+    compiled = serving._paged_prefill.lower(
+        params, cfg, pool, pool, _spec((1, suffix_blocks * bk), jnp.int32, sh),
+        _spec((blocks,), jnp.int32, sh), _spec((), jnp.int32, sh)).compile()
+    m = compiled.memory_analysis()
+    pools = 2 * np.prod(pool.shape) * 2
+    assert m.alias_size_in_bytes >= pools, m
+    need = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert need < HBM_BYTES, m
+
+
 def test_sharded_forward_compiles_for_four_chips(topo):
     """chip_smoke.py --chips 4: the forward under the tp=4 shardings of
     ``load_sharded`` is one program across the four chips."""

@@ -659,3 +659,60 @@ def test_close_gates_restore_many(setup, engine, tmp_path):
     store.put([(key, k, k)])
     store.close()
     assert store.restore_many({0: (0, [key])}) == {}
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_store_restored_admission_runs_the_prefill_program(
+        setup, engine, tmp_path, paged, monkeypatch):
+    """An admission whose prefix comes back from the store prefills its
+    suffix through the same compiled program as any other (a (suffix,
+    cache) shape with suffix < cache), serves ``generate()``'s tokens,
+    and pulls from the device once: ``_store_put``'s new pages, K and V
+    (nothing at all when it has no new full page to put)."""
+    cfg, params = setup
+    rng = np.random.default_rng(23)
+    sys_prompt = rng.integers(0, cfg.vocab, 3 * PAGE).tolist()
+    store = _store(cfg, engine, tmp_path)
+
+    def make():
+        if paged:
+            return PagedDecodeServer(params, cfg, max_batch=1, max_len=64,
+                                     total_blocks=16, block_len=PAGE,
+                                     kv_store=store)
+        return DecodeServer(params, cfg, max_batch=1, max_len=64,
+                            kv_store=store)
+
+    pulls = []
+    to_numpy = np.asarray
+
+    def asarray(a, *args, **kw):
+        if isinstance(a, jax.Array):
+            pulls.append(a.shape)
+        return to_numpy(a, *args, **kw)
+
+    def admit(srv, rid, prompt):
+        srv.submit(rid, prompt, 5)
+        plans = srv._plan_admissions()
+        restored = srv._restore_prefixes(plans)
+        del pulls[:]
+        with monkeypatch.context() as m:
+            m.setattr(np, "asarray", asarray)
+            for plan in plans:
+                srv._finish_traced(plan, restored.get(plan["slot"], {}))
+        return srv.run()[rid]
+
+    a = sys_prompt + [7, 8, 9, 10, 11]        # 4 full pages + 1 token
+    assert admit(make(), "a", a) == _solo(params, cfg, a, 5)
+    L, nkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    assert pulls == [(L, nkv, 4 * PAGE, hd)] * 2      # K and V, once
+    store.flush()
+    # a fresh server (cold HBM tiers): 3 pages restore, the suffix of
+    # 5 tokens pads to 2 pages against the 5-page cache, and the one
+    # new full page is the whole pull
+    srv = make()
+    b = sys_prompt + [1, 2, 3, 4, 5]
+    assert admit(srv, "b", b) == _solo(params, cfg, b, 5)
+    assert engine.stats.kv_pages_restored == 3
+    assert srv._prefill_shapes == {(2 * PAGE, 5 * PAGE)}
+    assert pulls == [(L, nkv, PAGE, hd)] * 2
+    store.close()

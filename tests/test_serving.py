@@ -253,11 +253,12 @@ def test_server_stats_gauges(setup):
              "prefix_shared_blocks": 0, "requests_finished": 0,
              "ttft_ms_avg": 0.0, "ttft_ms_max": 0.0,
              "admit_wait_ms_avg": 0.0, "admit_wait_ms_max": 0.0,
-             "admissions_shed": 0}
+             "admissions_shed": 0, "prefill_programs": 0}
     assert s0 == want0
     srv.step()
     s1 = srv.stats()
     assert s1["slots_busy"] == 2 and s1["queued"] == 0
+    assert s1["prefill_programs"] == 1      # both prompts: one block
     assert s1["blocks_free"] == 2 and s1["inflight_tokens"] >= 2
     srv.run()
     s2 = srv.stats()
@@ -584,3 +585,91 @@ def test_pending_first_restored_on_readback_failure(setup, monkeypatch):
         got.update(srv.step_many(4))
     assert got["one"] == _solo(params, cfg, p0, 1)
     assert got["more"] == _solo(params, cfg, p1, 1)
+
+
+# -- admission's prefill as one compiled program ---------------------------
+
+def _server(kind, params, cfg, **kw):
+    from nvme_strom_tpu.models.serving import PagedDecodeServer
+    if kind == "paged":
+        return PagedDecodeServer(params, cfg, max_batch=2, max_len=64,
+                                 total_blocks=16, block_len=8, **kw)
+    return DecodeServer(params, cfg, max_batch=2, max_len=64, **kw)
+
+
+def _prefill_program(kind):
+    from nvme_strom_tpu.models import serving
+    return (serving._paged_prefill if kind == "paged"
+            else serving._serve_prefill)
+
+
+@pytest.mark.parametrize("kind,prompt_lens,shared,programs", [
+    # no hit: the bucket of 16 (dense) / two blocks of 8 (paged)
+    ("dense", (9, 16), 0, {(16, 16)}),
+    ("paged", (9, 16), 0, {(16, 16)}),
+    # HBM prefix-cache hit: the second prompt shares two full blocks and
+    # prefills its last block only, against the same 24-row cache
+    ("paged", (20, 19), 16, {(24, 24), (8, 24)}),
+])
+def test_served_tokens_match_generate_through_the_prefill_program(
+        setup, kind, prompt_lens, shared, programs):
+    """Greedy tokens out of the compiled admission are ``generate()``'s,
+    and the program is keyed on (padded suffix, cache) lengths alone:
+    prompts of different lengths inside one bucket build ONE program —
+    the true last row, the slot and the block ids do not retrace."""
+    cfg, params = setup
+    rng = np.random.default_rng(31)
+    head = rng.integers(0, cfg.vocab, shared).tolist()
+    prompts = [head + rng.integers(0, cfg.vocab, n - shared).tolist()
+               for n in prompt_lens]
+    fn = _prefill_program(kind)
+    fn.clear_cache()
+    srv = _server(kind, params, cfg)
+    for i, p in enumerate(prompts):
+        srv.submit(i, p, 5)
+        assert srv.run()[i] == _solo(params, cfg, p, 5)
+    assert srv._prefill_shapes == programs
+    assert srv.timings["prefill_programs"] == len(programs)
+    assert fn._cache_size() == len(programs)
+    if shared:
+        assert srv.stats()["prefix_hits"] == 1
+    # a second server of the same shapes compiles nothing new
+    srv = _server(kind, params, cfg)
+    srv.submit("again", prompts[0], 2)
+    srv.run()
+    assert fn._cache_size() == len(programs)
+    assert srv.timings["prefill_programs"] == 1
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged"])
+def test_admission_reads_nothing_back(setup, kind, monkeypatch):
+    """A store-less admission is dispatches only: no ``device_get`` and
+    no host conversion of any device array (the logits stay on the
+    device; the first token rides ``step_many``'s one readback)."""
+    cfg, params = setup
+    srv = _server(kind, params, cfg)
+    srv.submit("warm", [1, 2, 3], 2)        # compile outside the guard
+    srv.run()
+    pulled = []
+
+    def pull(*a, **k):
+        pulled.append(a)
+        raise AssertionError("admission read back from the device")
+
+    arr, to_numpy = type(srv.pos), np.asarray
+
+    def asarray(a, *args, **kw):
+        # numpy reads a CPU device array through the buffer protocol,
+        # past every attribute a test can patch
+        return (pull(a) if isinstance(a, jax.Array)
+                else to_numpy(a, *args, **kw))
+
+    srv.submit("r", [5, 6, 7, 8, 9], 4)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "device_get", pull)
+        m.setattr(np, "asarray", asarray)
+        m.setattr(arr, "_value", property(pull))  # int(), .tolist(), ...
+        for plan in srv._plan_admissions():
+            srv._finish_traced(plan, {})
+    assert not pulled and len(srv._pending_first) == 1
+    assert srv.run()["r"] == _solo(params, cfg, [5, 6, 7, 8, 9], 4)
