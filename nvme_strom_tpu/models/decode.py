@@ -24,7 +24,8 @@ from jax import lax
 
 from nvme_strom_tpu.models.transformer import (
     wmat,
-    TransformerConfig, attention, expand_gqa, mlp, qkv_project, rms_norm)
+    TransformerConfig, add_residual, attention, embed_tokens,
+    expand_gqa, lm_logits, mlp, qkv_project, rms_norm)
 from nvme_strom_tpu.models import moe as _moe
 
 
@@ -35,12 +36,19 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Dict:
     through prefill+decode_step — past that, dynamic_update_slice clamps
     and silently overwrites the last slot (generate() sizes the cache as
     prompt_len + max_new_tokens, exactly enough)."""
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, cfg.head_dim)
-    return {
+    shape = (len(cfg.attn_layers), batch, cfg.n_kv_heads, max_len,
+             cfg.head_dim)
+    cache = {
         "k": jnp.zeros(shape, cfg.dtype),
         "v": jnp.zeros(shape, cfg.dtype),
         "pos": jnp.zeros((), jnp.int32),
     }
+    if cfg.mamba_layers:
+        # K/V for the attention layers only; the recurrent layers carry a
+        # fixed-size state instead (models/ssm.py)
+        from nvme_strom_tpu.models.ssm import init_state
+        cache["ssm"] = init_state(cfg, batch)
+    return cache
 
 
 def cache_shardings(mesh, tp_axis: str = "tp", dp_axis: str = "dp"):
@@ -79,7 +87,12 @@ def prefill(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     them.
     """
     b, s = tokens.shape
-    x = params["tok_embed"].astype(cfg.dtype)[tokens]
+    if cfg.mamba_layers:
+        # block_step from an empty cache IS the prefill, state included
+        return block_step(params, tokens, cfg, cache,
+                          last=s - 1 if last is None else last,
+                          n_valid=None if last is None else last + 1)
+    x = embed_tokens(params, cfg, tokens)
     positions = jnp.arange(s, dtype=jnp.float32)
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
@@ -90,14 +103,14 @@ def prefill(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
             cache["k"], k[None].astype(cfg.dtype), (i, 0, 0, 0, 0))
         cache["v"] = lax.dynamic_update_slice(
             cache["v"], v[None].astype(cfg.dtype), (i, 0, 0, 0, 0))
-        x = x + a
+        x = add_residual(x, a, cfg)
         h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
-        x = (x + _mlp_block(h, params, L, cfg)).astype(cfg.dtype)
+        x = add_residual(x, _mlp_block(h, params, L, cfg),
+                         cfg).astype(cfg.dtype)
     cache["pos"] = jnp.asarray(s, jnp.int32)
     x = rms_norm(x[:, s - 1 if last is None else last],
                  params["final_norm"], cfg.norm_eps)
-    logits = (x @ wmat(params, "lm_head", x.dtype)).astype(jnp.float32)
-    return logits, cache
+    return lm_logits(params, cfg, x), cache
 
 
 def decode_step(params: Dict, token: jax.Array, cfg: TransformerConfig,
@@ -116,9 +129,10 @@ def decode_step(params: Dict, token: jax.Array, cfg: TransformerConfig,
         # implementation to maintain
         logits, cache = block_step(params, token[:, None], cfg, cache)
         return logits[:, 0], cache
+    cfg.require_no_recurrent("decode_step with a cache_attn kernel")
     b = token.shape[0]
     pos = cache["pos"]
-    x = params["tok_embed"].astype(cfg.dtype)[token[:, None]]  # (b, 1, d)
+    x = embed_tokens(params, cfg, token[:, None])              # (b, 1, d)
     positions = pos.astype(jnp.float32)[None]
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
@@ -133,13 +147,13 @@ def decode_step(params: Dict, token: jax.Array, cfg: TransformerConfig,
         # group maps to its kv head inside (no expanded HBM copy)
         a = cache_attn(q, cache["k"][i], cache["v"][i], pos)
         a = a.transpose(0, 2, 1, 3).reshape(b, 1, -1)
-        x = x + a @ wmat(params, L + "wo", a.dtype)
+        x = add_residual(x, a @ wmat(params, L + "wo", a.dtype), cfg)
         h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
-        x = (x + _mlp_block(h, params, L, cfg)).astype(cfg.dtype)
+        x = add_residual(x, _mlp_block(h, params, L, cfg),
+                         cfg).astype(cfg.dtype)
     cache["pos"] = pos + 1
     x = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
-    logits = (x @ wmat(params, "lm_head", x.dtype)).astype(jnp.float32)
-    return logits, cache
+    return lm_logits(params, cfg, x), cache
 
 
 def cache_attention(q, ck, cv, limit, cfg: TransformerConfig):
@@ -155,7 +169,10 @@ def cache_attention(q, ck, cv, limit, cfg: TransformerConfig):
     cve = expand_gqa(cv, cfg)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, cke,
                         preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(cfg.head_dim))
+    if cfg.attn_scale is None:
+        scores = scores / jnp.sqrt(jnp.float32(cfg.head_dim))
+    else:
+        scores = scores * jnp.float32(cfg.attn_scale)
     valid = jnp.arange(S)[None, None, None, :] <= limit[:, None, :, None]
     scores = jnp.where(valid, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(cve.dtype)
@@ -163,7 +180,8 @@ def cache_attention(q, ck, cv, limit, cfg: TransformerConfig):
 
 
 def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
-               cache: Dict, last=None) -> tuple[jax.Array, Dict]:
+               cache: Dict, last=None, n_valid=None
+               ) -> tuple[jax.Array, Dict]:
     """Multi-token incremental step: tokens (b, m) int32 enter the cache
     at positions pos..pos+m-1 and every position gets logits.
 
@@ -177,32 +195,53 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     admission-style callers that need one next-token distribution skip
     m-1 useless vocab projections (a 128k-vocab lm_head over thousands
     of pad rows is real FLOPs).
+
+    ``n_valid``: rows from it on are right padding.  Attention needs no
+    telling (pad rows sit past every valid row's mask and are overwritten
+    before a mask reaches them); a recurrent layer does — its state and
+    conv tail (``cache["ssm"]``) stop at row ``n_valid - 1``.
     """
     b, m = tokens.shape
     pos = cache["pos"]
-    x = params["tok_embed"].astype(cfg.dtype)[tokens]
+    x = embed_tokens(params, cfg, tokens)
     positions = pos.astype(jnp.float32) + jnp.arange(m, dtype=jnp.float32)
     # row t sees cache positions <= pos + t (same limit for every row)
     limit = jnp.broadcast_to(pos + jnp.arange(m), (b, m))
+    ssm = cache.get("ssm")
+    states, tails = (list(ssm["s"]), list(ssm["conv"])) if ssm else ([], [])
+    ai = mi = 0           # this layer's place among its kind's caches
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
         h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
-        q, k, v = qkv_project(h, params, L, cfg, positions=positions)
-        cache["k"] = lax.dynamic_update_slice(
-            cache["k"], k[None].astype(cfg.dtype), (i, 0, 0, pos, 0))
-        cache["v"] = lax.dynamic_update_slice(
-            cache["v"], v[None].astype(cfg.dtype), (i, 0, 0, pos, 0))
-        a = cache_attention(q, cache["k"][i], cache["v"][i], limit, cfg)
-        a = a.transpose(0, 2, 1, 3).reshape(b, m, -1)
-        x = x + a @ wmat(params, L + "wo", a.dtype)
+        if cfg.is_mamba_layer(i):
+            from nvme_strom_tpu.models.ssm import mamba_block
+            a, states[mi], tails[mi] = mamba_block(
+                h, params, L, cfg, states[mi], tails[mi], n_valid)
+            mi += 1
+        else:
+            q, k, v = qkv_project(h, params, L, cfg, positions=positions)
+            cache["k"] = lax.dynamic_update_slice(
+                cache["k"], k[None].astype(cfg.dtype), (ai, 0, 0, pos, 0))
+            cache["v"] = lax.dynamic_update_slice(
+                cache["v"], v[None].astype(cfg.dtype), (ai, 0, 0, pos, 0))
+            with jax.named_scope("strom.attn.paged"):
+                a = cache_attention(q, cache["k"][ai], cache["v"][ai],
+                                    limit, cfg)
+            a = a.transpose(0, 2, 1, 3).reshape(b, m, -1)
+            a = a @ wmat(params, L + "wo", a.dtype)
+            ai += 1
+        x = add_residual(x, a, cfg)
         h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
-        x = (x + _mlp_block(h, params, L, cfg)).astype(cfg.dtype)
+        with jax.named_scope("strom.mlp"):
+            f = _mlp_block(h, params, L, cfg)
+        x = add_residual(x, f, cfg).astype(cfg.dtype)
     cache["pos"] = pos + m
+    if ssm:
+        cache["ssm"] = {"s": tuple(states), "conv": tuple(tails)}
     if last is not None:
         x = x[:, last]
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ wmat(params, "lm_head", x.dtype)).astype(jnp.float32)
-    return logits, cache
+    return lm_logits(params, cfg, x), cache
 
 
 def nucleus_truncate(logits, top_p):

@@ -48,9 +48,11 @@ import jax.numpy as jnp
 from nvme_strom_tpu.io.tenants import (
     TokenBucket, tenant_context, tenants_enabled, tier_rank)
 from nvme_strom_tpu.models import decode as _dec
+from nvme_strom_tpu.models import ssm as _ssm
 from nvme_strom_tpu.models.decode import _mlp_block
 from nvme_strom_tpu.models.transformer import (
-    TransformerConfig, qkv_project, rms_norm, wmat)
+    TransformerConfig, add_residual, embed_tokens, lm_logits,
+    qkv_project, rms_norm, wmat)
 
 
 @dataclass
@@ -148,7 +150,7 @@ def _gather_prefix(k_pool, v_pool, blks):
 
 
 def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
-                  k_head, v_head, last):
+                  k_head, v_head, last, ssm=None):
     """The admission prefill, traced inside both servers' programs:
     ``block_step`` of the right-padded suffix ``tokens`` (1, m) behind
     the cached prefix ``k_head``/``v_head`` ((L, 1, nkv, c, hd), or None
@@ -156,19 +158,25 @@ def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
     so every admission shares one math).
 
     Returns (logits (1, vocab) f32 at suffix row ``last``, k, v dense
-    (L, 1, nkv, c + m, hd)).  The pad rows sit past ``last``: causality
-    keeps them out of the logits, and their cache entries are dead —
-    decode overwrites a position before its mask exposes it."""
+    (L, 1, nkv, c + m, hd), and for a config with recurrent layers their
+    state after row ``last`` — else None).  The pad rows sit past
+    ``last``: causality keeps them out of the logits, and their cache
+    entries are dead — decode overwrites a position before its mask
+    exposes it.  A recurrence has no mask to hide behind: ``n_valid``
+    tells it where the prompt ends."""
     m = tokens.shape[1]
     cache = _dec.init_cache(cfg, 1, m)
+    if ssm is not None:
+        cache["ssm"] = ssm
     if k_head is not None:
         cache["k"] = jnp.concatenate(
             [k_head.astype(cfg.dtype), cache["k"]], axis=3)
         cache["v"] = jnp.concatenate(
             [v_head.astype(cfg.dtype), cache["v"]], axis=3)
         cache["pos"] = jnp.asarray(k_head.shape[3], jnp.int32)
-    logits, cache = _dec.block_step(params, tokens, cfg, cache, last=last)
-    return logits, cache["k"], cache["v"]
+    logits, cache = _dec.block_step(params, tokens, cfg, cache, last=last,
+                                    n_valid=last + 1)
+    return logits, cache["k"], cache["v"], cache.get("ssm")
 
 
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2, 3))
@@ -179,8 +187,8 @@ def _serve_prefill(params: Dict, cfg: TransformerConfig, k_cache,
     donated slot caches.  ``slot`` and ``last`` are data: the program is
     keyed on (tokens, head) shapes only.  Returns (logits, k_cache,
     v_cache)."""
-    logits, k, v = _prefill_rows(params, cfg, tokens, k_head, v_head,
-                                 last)
+    logits, k, v, _ = _prefill_rows(params, cfg, tokens, k_head, v_head,
+                                    last)
     k_cache = jax.lax.dynamic_update_slice(
         k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0, 0))
     v_cache = jax.lax.dynamic_update_slice(
@@ -188,23 +196,38 @@ def _serve_prefill(params: Dict, cfg: TransformerConfig, k_cache,
     return logits, k_cache, v_cache
 
 
-@functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2, 3))
+@functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2, 3, 7))
 def _paged_prefill(params: Dict, cfg: TransformerConfig, k_pool, v_pool,
-                   tokens, blks, last):
+                   tokens, blks, last, state=None, slot=None):
     """One block-pool admission: ``blks`` (n,) are the pool blocks of
     the whole padded prompt, ``tokens`` (1, m) its suffix past the
     cached blocks — so the first ``n - m // block_len`` of ``blks`` are
     gathered as the prefix (``_gather_prefix``), ``_prefill_rows`` runs
     the suffix, and its rows land in the remaining blocks of the donated
     pools.  Block ids and ``last`` are data: the program is keyed on
-    (m, n) only.  Returns (logits, k_pool, v_pool)."""
+    (m, n) only.
+
+    With recurrent layers in ``cfg``, ``state`` is the server's state
+    pool (``models/ssm.init_state``, donated) and ``slot`` the admitted
+    slot's row: the prompt starts from an empty state (there is no prefix
+    to resume: a page without the state at its boundary is not one) and
+    the state after row ``last`` overwrites the row — which is why
+    releasing a slot clears nothing.  Returns (logits, k_pool, v_pool,
+    state); ``state`` stays None for a plain decoder, whose program this
+    leaves as it was."""
     bk = k_pool.shape[3]
     ct = blks.shape[0] - tokens.shape[1] // bk
     k_head = v_head = None
     if ct:
         k_head, v_head = _gather_prefix(k_pool, v_pool, blks[:ct])
-    logits, k, v = _prefill_rows(params, cfg, tokens, k_head, v_head,
-                                 last)
+    logits, k, v, ssm = _prefill_rows(
+        params, cfg, tokens, k_head, v_head, last,
+        None if state is None else _ssm.init_state(cfg, 1))
+    if state is not None:
+        state = {key: tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                pool, new.astype(pool.dtype), slot, axis=0)
+            for pool, new in zip(state[key], ssm[key])) for key in state}
 
     def new_rows(dense):                   # → (L, n - ct, nkv, bk, hd)
         L, _, nkv, _, hd = dense.shape
@@ -213,30 +236,42 @@ def _paged_prefill(params: Dict, cfg: TransformerConfig, k_pool, v_pool,
 
     k_pool, v_pool = _scatter_blocks(k_pool, v_pool, blks[ct:],
                                      new_rows(k), new_rows(v))
-    return logits, k_pool, v_pool
+    return logits, k_pool, v_pool, state
 
 
 def _batched_step_body(params: Dict, cfg: TransformerConfig, tok, pos,
-                       write_and_attend):
+                       write_and_attend, recur=None):
     """Shared per-step transformer wiring of the batched servers.
 
     ``write_and_attend(i, q, k, v) -> (B, nh, 1, hd)`` owns the cache
     write + attention for its storage layout (contiguous per-slot rows
-    or a block-table pool)."""
+    or a block-table pool); ``i`` counts the attention layers.
+    ``recur(j, h, L) -> (B, 1, d)`` is the mixer of the j-th recurrent
+    layer against its storage (the paged server's state pool)."""
     B = tok.shape[0]
-    x = params["tok_embed"].astype(cfg.dtype)[tok[:, None]]   # (B,1,d)
+    x = embed_tokens(params, cfg, tok[:, None])               # (B,1,d)
     positions = pos.astype(jnp.float32)[:, None]              # (B,1)
+    ai = mi = 0
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
         h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
-        q, k, v = qkv_project(h, params, L, cfg, positions=positions)
-        a = write_and_attend(i, q, k, v)
-        a = a.transpose(0, 2, 1, 3).reshape(B, 1, -1)
-        x = x + a @ wmat(params, L + "wo", a.dtype)
+        if cfg.is_mamba_layer(i):
+            a = recur(mi, h, L)
+            mi += 1
+        else:
+            q, k, v = qkv_project(h, params, L, cfg, positions=positions)
+            with jax.named_scope("strom.attn.paged"):
+                a = write_and_attend(ai, q, k, v)
+            a = a.transpose(0, 2, 1, 3).reshape(B, 1, -1)
+            a = a @ wmat(params, L + "wo", a.dtype)
+            ai += 1
+        x = add_residual(x, a, cfg)
         h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
-        x = (x + _mlp_block(h, params, L, cfg)).astype(cfg.dtype)
+        with jax.named_scope("strom.mlp"):
+            f = _mlp_block(h, params, L, cfg)
+        x = add_residual(x, f, cfg).astype(cfg.dtype)
     x = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
-    return (x @ wmat(params, "lm_head", x.dtype)).astype(jnp.float32)
+    return lm_logits(params, cfg, x)
 
 
 def serve_logits(params: Dict, cfg: TransformerConfig, tok,
@@ -288,13 +323,17 @@ def _serve_step(params: Dict, cfg: TransformerConfig, tok,
 
 
 def paged_logits(params: Dict, cfg: TransformerConfig, tok,
-                 k_pool, v_pool, blk, off, table, pos):
+                 k_pool, v_pool, blk, off, table, pos, state=None,
+                 sidx=None):
     """The decode step against the shared block pool, before sampling:
-    (logits (B, vocab) f32, k_pool, v_pool).
+    (logits (B, vocab) f32, k_pool, v_pool) — and the state pool after
+    them when ``cfg`` has recurrent layers.
 
     blk/off (B,) int32: each slot's write target (block id in the pool,
     row offset inside it); table (B, max_blocks) int32 + pos (B,) feed
-    the paged-attention kernel."""
+    the paged-attention kernel.  state/sidx: the recurrent layers' pool
+    (``models/ssm.init_state``) and each slot's row of it — a free slot's
+    is the sacrificial last row, as its ``blk`` is the trash block."""
     from nvme_strom_tpu.ops.paged_attention import paged_attention
     pools = {"k": k_pool, "v": v_pool}
 
@@ -304,23 +343,37 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
         pools["v"] = pools["v"].at[i, blk, :, off, :].set(
             v[:, :, 0].astype(pools["v"].dtype))
         return paged_attention(q, pools["k"][i], pools["v"][i], table,
-                               pos)
+                               pos, scale=cfg.attn_scale)
 
-    logits = _batched_step_body(params, cfg, tok, pos,
-                                write_and_attend)
-    return logits, pools["k"], pools["v"]
+    if state is None:
+        logits = _batched_step_body(params, cfg, tok, pos,
+                                    write_and_attend)
+        return logits, pools["k"], pools["v"]
+    s_pools, tails = list(state["s"]), list(state["conv"])
+
+    def recur(j, h, L):
+        out, s_pools[j], tails[j] = _ssm.mamba_step(
+            h, params, L, cfg, s_pools[j], tails[j], sidx)
+        return out
+
+    logits = _batched_step_body(params, cfg, tok, pos, write_and_attend,
+                                recur)
+    return (logits, pools["k"], pools["v"],
+            {"s": tuple(s_pools), "conv": tuple(tails)})
 
 
-@functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(3, 4))
+@functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(3, 4, 12))
 def _paged_step(params: Dict, cfg: TransformerConfig, tok,
                 k_pool, v_pool, blk, off, table, pos, temps, top_ps,
-                seeds):
+                seeds, state=None, sidx=None):
     """One decode step against the shared block pool: ``paged_logits``
-    then the per-slot sampler.  Returns (next_tok, k_pool, v_pool)."""
-    logits, k_pool, v_pool = paged_logits(
-        params, cfg, tok, k_pool, v_pool, blk, off, table, pos)
+    then the per-slot sampler.  Returns (next_tok, k_pool, v_pool, state);
+    ``state`` (donated, every layer's array updated in place) is None
+    for a plain decoder, whose program this leaves as it was."""
+    logits, k_pool, v_pool, *rest = paged_logits(
+        params, cfg, tok, k_pool, v_pool, blk, off, table, pos, state, sidx)
     nxt = _sample_slots(logits, temps, top_ps, seeds, pos)
-    return nxt, k_pool, v_pool
+    return nxt, k_pool, v_pool, (rest[0] if rest else None)
 
 
 class DecodeServer:
@@ -362,6 +415,8 @@ class DecodeServer:
         self.cfg = cfg
         self.B = max_batch
         self.max_len = max_len
+        if cfg.mamba_layers:
+            self._check_recurrent(kv_store)
         #: load-shedding probe (docs/RESILIENCE.md "failure domains"):
         #: a callable returning True while new prefill admissions should
         #: DEFER (requests wait queued; in-flight decode continues;
@@ -432,7 +487,9 @@ class DecodeServer:
         #: ``queue_wait_s`` (Σ admission start − submit),
         #: ``prefill_tokens`` (padded tokens handed to prefill),
         #: ``prompt_tokens`` (prompt tokens those prefills had to
-        #: compute: past the cached prefix) and ``prefill_programs``
+        #: compute: past the cached prefix), ``scan_tokens`` (valid
+        #: tokens through the recurrent layers' scan, pads excluded; 0
+        #: for a plain decoder) and ``prefill_programs``
         #: (distinct (suffix, cache) shapes this server has prefilled
         #: with, each one compiled or fetched program: a handful on a
         #: healthy server, a climbing count is a shape leak)
@@ -441,7 +498,7 @@ class DecodeServer:
             "steps": 0, "readbacks": 0,
             "admits": 0, "queue_wait_s": 0.0, "prefill_s": 0.0,
             "prefill_tokens": 0, "prompt_tokens": 0,
-            "prefill_programs": 0}
+            "prefill_programs": 0, "scan_tokens": 0}
         self._prefill_shapes: set = set()
         #: per-request serving metrics of RETIRED requests ({rid:
         #: {"ttft_ms", "admit_wait_ms"}}, newest last, bounded) plus
@@ -467,6 +524,12 @@ class DecodeServer:
         #: p99 window, fed to SloGovernor.observe_tenant at retire)
         self._tenant_ttft: Dict[str, List[float]] = {}
         self._alloc_storage()
+
+    def _check_recurrent(self, kv_store) -> None:
+        """What a config with recurrent layers may be served from: the
+        paged server overrides.  The dense slots gain no twin of its
+        state pool (ROADMAP C5)."""
+        self.cfg.require_no_recurrent("the dense DecodeServer")
 
     def _alloc_storage(self) -> None:
         cfg = self.cfg
@@ -1006,6 +1069,8 @@ class DecodeServer:
         ``pop`` removes exported sessions so the retiring server can
         reach ``idle`` — their results are now the replacement's to
         deliver."""
+        self.cfg.require_no_recurrent("export_sessions (the hand-off "
+                                      "bundle holds K/V page keys only)")
         out: List[dict] = []
         taken_slots: List[int] = []
         taken_q: List[_Request] = []
@@ -1431,9 +1496,22 @@ class PagedDecodeServer(DecodeServer):
                          shed_probe=shed_probe)
         self.max_blocks = -(-max_len // block_len)
 
+    def _check_recurrent(self, kv_store) -> None:
+        # pages without the state at their boundary are not a prefix, in
+        # the store as in the HBM prefix cache (_req_keys)
+        if kv_store is not None:
+            self.cfg.require_no_recurrent("a kv_store (PrefixStore)")
+        shardings = {getattr(w, "sharding", None)
+                     for w in (self.params or {}).values()
+                     if not isinstance(w, dict)}
+        if any(len(getattr(sh, "device_set", ())) > 1 for sh in shardings):
+            self.cfg.require_no_recurrent("a mesh (sharded params)")
+
     def _alloc_storage(self) -> None:
         cfg = self.cfg
-        L, nkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        nkv, hd = cfg.n_kv_heads, cfg.head_dim
+        # K/V for the layers that attend: all of them in a plain decoder
+        L = len(cfg.attn_layers)
         # +1: a sacrificial TRASH block — a free slot still computes a
         # (masked) step and its frozen-pos write must never land in a
         # block some live request owns
@@ -1441,6 +1519,12 @@ class PagedDecodeServer(DecodeServer):
         self.k_pool = jnp.zeros(shape, cfg.dtype)
         self.v_pool = jnp.zeros(shape, cfg.dtype)
         self._trash = self.total_blocks
+        # the second kind of cache: per recurrent layer a fixed-size
+        # state and conv tail for every slot, +1 sacrificial row that
+        # free slots step into (row B, as their K/V goes to the trash
+        # block).  None for a plain decoder.
+        self.state = (_ssm.init_state(cfg, self.B + 1)
+                      if cfg.mamba_layers else None)
         self.free: List[int] = list(range(self.total_blocks))
         self.blocks: List[List[int]] = [[] for _ in range(self.B)]
         self._pos_h: List[int] = [0] * self.B   # host mirror of pos
@@ -1493,7 +1577,10 @@ class PagedDecodeServer(DecodeServer):
         """The request's chain keys, hashed ONCE — _can_admit runs per
         step while a request queues, and per-wait rehashing of a long
         prompt is O(prompt) host work on the decode path."""
-        if not self.prefix_cache:
+        if not self.prefix_cache or self.state is not None:
+            # recurrent layers: a cached page is worthless without the
+            # state at its boundary, which nobody keeps — no keys, so no
+            # match (_pc_match) and nothing registered
             return []
         if req.chain_keys is None:
             req.chain_keys = self._chain_keys(req.prompt)
@@ -1620,11 +1707,17 @@ class PagedDecodeServer(DecodeServer):
         padded = suffix + [0] * (n_pb * bk - s)
         t0 = time.monotonic()
         with self._prefill_span(padded, suffix, n_pb * bk, rid):
-            logits, self.k_pool, self.v_pool = _paged_prefill(
+            # positional, so that the state pool is donated with the rest
+            recur = () if self.state is None else (self.state,
+                                                   np.int32(slot))
+            logits, self.k_pool, self.v_pool, self.state = _paged_prefill(
                 self.params, self.cfg, self.k_pool, self.v_pool,
                 np.asarray([padded], np.int32),
-                np.asarray(blks[:n_pb], np.int32), len(suffix) - 1)
+                np.asarray(blks[:n_pb], np.int32), len(suffix) - 1,
+                *recur)
         self.timings["prefill_s"] += time.monotonic() - t0
+        if self.state is not None:
+            self.timings["scan_tokens"] += len(suffix)
         with self._span("strom.serve.scatter", blocks=n_pb - ct,
                         rid=rid):
             # newly computed FULL blocks join the cache for future
@@ -1665,6 +1758,12 @@ class PagedDecodeServer(DecodeServer):
         out["prefix_evictable"] = len(self._pc_lru)
         out["prefix_hits"] = self._pc_hits
         out["prefix_shared_blocks"] = self._pc_shared_blocks
+        # the two kinds of cache: layers that keep K/V pages, and the
+        # recurrent layers' fixed state (bytes on the device, rows)
+        out["kv_layers"] = self.k_pool.shape[0]
+        state = jax.tree_util.tree_leaves(self.state)
+        out["state_bytes"] = sum(a.nbytes for a in state)
+        out["state_slots"] = self.B + 1 if state else 0
         return out
 
     def _retire_or_keep(self, slot: int):
@@ -1697,10 +1796,14 @@ class PagedDecodeServer(DecodeServer):
               if self.blocks[b] else self._trash)
              for b in range(self.B)], jnp.int32)
         off = self.pos % self.block_len
-        nxt, self.k_pool, self.v_pool = _paged_step(
+        recur = () if self.state is None else (
+            self.state,
+            jnp.asarray([b if self.slots[b] is not None else self.B
+                         for b in range(self.B)], jnp.int32))
+        nxt, self.k_pool, self.v_pool, self.state = _paged_step(
             self.params, self.cfg, self.tok, self.k_pool, self.v_pool,
             blk, off, self._table(), self.pos, self.temp, self.topp,
-            self.seed)
+            self.seed, *recur)
         return nxt
 
     def _advanced(self, active_slots: List[int]) -> None:

@@ -65,12 +65,45 @@ class TransformerConfig:
     # materialize (chunked_xent) — essential at Llama-vocab sizes.
     # 0/1 = the plain full-logits path.
     xent_chunks: int = 0
+    # Hybrid decoders (models/ssm.py): the mixer of every layer,
+    # "attention" or "mamba", read off the model's own config
+    # (tools/convert_llama.config_from_hf).  Empty == attention in every
+    # layer, which is every program this config built before the field
+    # existed.  The ssm_* sizes are Mamba-2's heads, head width, state
+    # width, causal-conv taps and scan chunk (one B/C group).
+    layer_kinds: tuple = ()
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    # Scalars some families put on the residual path (all 1 == absent):
+    # x0 = embed_mult·E[tok]; x += residual_mult·f(x); logits /=
+    # logits_div; attention scores × attn_scale (None: 1/√head_dim).
+    embed_mult: float = 1.0
+    residual_mult: float = 1.0
+    logits_div: float = 1.0
+    attn_scale: object = None
+    rope: bool = True               # False: no positional encoding
+    tie_embed: bool = False         # logits through tok_embedᵀ, no lm_head
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
             object.__setattr__(
                 self, "rope_scaling",
                 tuple(sorted(self.rope_scaling.items())))
+        kinds = tuple(self.layer_kinds)
+        object.__setattr__(self, "layer_kinds", kinds)
+        if kinds:
+            if len(kinds) != self.n_layers or set(kinds) - {"attention",
+                                                            "mamba"}:
+                raise ValueError(
+                    f"layer_kinds must name 'attention' or 'mamba' for each "
+                    f"of the {self.n_layers} layers, got {kinds}")
+            if "mamba" in kinds and not (self.ssm_heads and self.ssm_head_dim
+                                         and self.ssm_state):
+                raise ValueError("mamba layers need ssm_heads, ssm_head_dim "
+                                 "and ssm_state")
 
     @property
     def rope_scaling_dict(self):
@@ -84,6 +117,41 @@ class TransformerConfig:
     def is_moe_layer(self, i: int) -> bool:
         return (self.n_experts > 0
                 and i % self.moe_every == self.moe_every - 1)
+
+    def is_mamba_layer(self, i: int) -> bool:
+        return bool(self.layer_kinds) and self.layer_kinds[i] == "mamba"
+
+    @property
+    def mamba_layers(self) -> tuple:
+        """Indices of the recurrent layers (empty for a plain decoder)."""
+        return tuple(i for i, k in enumerate(self.layer_kinds)
+                     if k == "mamba")
+
+    @property
+    def attn_layers(self) -> tuple:
+        """Indices of the layers that keep K/V: all of them for a plain
+        decoder.  A cache holds ``len(attn_layers)`` layers of K/V; layer
+        ``i``'s are at ``attn_layers.index(i)``."""
+        return tuple(i for i in range(self.n_layers)
+                     if not self.is_mamba_layer(i))
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Width of what the causal conv sees: x | B | C."""
+        return self.ssm_inner + 2 * self.ssm_state
+
+    def require_no_recurrent(self, what: str) -> None:
+        """The one message of everything that holds K/V pages only."""
+        if self.mamba_layers:
+            raise NotImplementedError(
+                f"{what} does not carry the recurrent state of mamba "
+                f"layers (layer_kinds has {len(self.mamba_layers)}); serve "
+                f"this config from PagedDecodeServer on one device, "
+                f"without a kv_store, a mesh or session hand-off")
 
 
 def flagship_config() -> TransformerConfig:
@@ -120,15 +188,24 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
     p = {
         "tok_embed": dense(next(keys), 1.0, (cfg.vocab, cfg.d_model)),
         "final_norm": jnp.ones((cfg.d_model,), jnp.float32),
-        "lm_head": dense(next(keys), cfg.d_model, (cfg.d_model, cfg.vocab)),
     }
+    lm_key = next(keys)
+    if not cfg.tie_embed:
+        p["lm_head"] = dense(lm_key, cfg.d_model, (cfg.d_model, cfg.vocab))
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
         p[L + "attn_norm"] = jnp.ones((cfg.d_model,), jnp.float32)
-        p[L + "wq"] = dense(next(keys), cfg.d_model, (cfg.d_model, nh * hd))
-        p[L + "wk"] = dense(next(keys), cfg.d_model, (cfg.d_model, nkv * hd))
-        p[L + "wv"] = dense(next(keys), cfg.d_model, (cfg.d_model, nkv * hd))
-        p[L + "wo"] = dense(next(keys), nh * hd, (nh * hd, cfg.d_model))
+        if cfg.is_mamba_layer(i):
+            from nvme_strom_tpu.models.ssm import init_mamba_params
+            p.update(init_mamba_params(keys, cfg, L, dense))
+        else:
+            p[L + "wq"] = dense(next(keys), cfg.d_model,
+                                (cfg.d_model, nh * hd))
+            p[L + "wk"] = dense(next(keys), cfg.d_model,
+                                (cfg.d_model, nkv * hd))
+            p[L + "wv"] = dense(next(keys), cfg.d_model,
+                                (cfg.d_model, nkv * hd))
+            p[L + "wo"] = dense(next(keys), nh * hd, (nh * hd, cfg.d_model))
         p[L + "mlp_norm"] = jnp.ones((cfg.d_model,), jnp.float32)
         if cfg.is_moe_layer(i):
             p.update(_moe.init_moe_params(keys, cfg, L, dense))
@@ -254,6 +331,39 @@ def wmat(p: Dict, name: str, dtype):
     return w.astype(dtype)
 
 
+# --- the residual path's scalars (TransformerConfig: all 1 == absent) ------
+#
+# One copy each, used by every layer loop (forward_hidden here,
+# models/decode.py, models/serving.py), so that a loop cannot drop one.  At
+# the defaults each returns exactly the expression the loops had inline.
+
+def embed_tokens(params: Dict, cfg: "TransformerConfig", tokens):
+    x = params["tok_embed"].astype(cfg.dtype)[tokens]
+    if cfg.embed_mult != 1.0:
+        x = x * jnp.asarray(cfg.embed_mult, cfg.dtype)
+    return x
+
+
+def add_residual(x, f, cfg: "TransformerConfig"):
+    """x + residual_mult · f."""
+    if cfg.residual_mult != 1.0:
+        f = f * jnp.asarray(cfg.residual_mult, f.dtype)
+    return x + f
+
+
+def lm_logits(params: Dict, cfg: "TransformerConfig", x):
+    """Final-norm hidden (..., d) → float32 logits (..., vocab)."""
+    if cfg.tie_embed:
+        logits = jnp.einsum("...d,vd->...v", x,
+                            wmat(params, "tok_embed", x.dtype),
+                            preferred_element_type=jnp.float32)
+    else:
+        logits = (x @ wmat(params, "lm_head", x.dtype)).astype(jnp.float32)
+    if cfg.logits_div != 1.0:
+        logits = logits / jnp.float32(cfg.logits_div)
+    return logits
+
+
 # --- attention precision gates -------------------------------------------
 #
 # The two attention einsums with explicit VJPs that downcast the
@@ -323,14 +433,15 @@ def _pv_apply_bwd(res, g):
 pv_apply.defvjp(_pv_apply_fwd, _pv_apply_bwd)
 
 
-def dense_causal_attention(q, k, v):
+def dense_causal_attention(q, k, v, scale=None):
     """softmax(QKᵀ/√d)V with a causal mask; q/k/v (b, h, s, d), same head
     count (GQA already expanded).  The single-chip default ``attn_fn``.
     Built on the precision gates so the backward matmuls stay in the
     activation dtype (bf16 on TPU) — used directly and as the Ulysses
-    inner."""
+    inner.  ``scale`` replaces 1/√d (``TransformerConfig.attn_scale``)."""
     s, hd = q.shape[-2], q.shape[-1]
-    scores = qk_scores(q, k) / np.sqrt(hd)
+    scores = qk_scores(q, k)
+    scores = scores / np.sqrt(hd) if scale is None else scores * scale
     mask = jnp.tril(jnp.ones((s, s), bool))
     scores = jnp.where(mask, scores, -1e30)
     probs32 = jax.nn.softmax(scores, axis=-1)
@@ -428,8 +539,9 @@ def qkv_project(x, p, prefix, cfg: TransformerConfig, positions=None):
     k = (x @ wmat(p, prefix + "wk", x.dtype)).reshape(b, s, nkv, hd)
     v = (x @ wmat(p, prefix + "wv", x.dtype)).reshape(b, s, nkv, hd)
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # b h s d
-    q, k = _rope(q, k, cfg.rope_theta, positions=positions,
-                 scaling=cfg.rope_scaling_dict)
+    if cfg.rope:
+        q, k = _rope(q, k, cfg.rope_theta, positions=positions,
+                     scaling=cfg.rope_scaling_dict)
     return q, k, v
 
 
@@ -467,7 +579,13 @@ def attention(x, p, prefix, cfg: TransformerConfig, attn_fn=None,
     ``return_kv=True`` additionally returns the post-RoPE kv-width k/v for
     cache prefill."""
     b, s, _ = x.shape
-    if attn_fn is None and not return_kv:
+    # no rotary, or a config's own score scale: only qkv_project and
+    # dense_causal_attention know them
+    llama_like = cfg.rope and cfg.attn_scale is None
+    if attn_fn is not None and not llama_like:
+        raise NotImplementedError(
+            "attn_fn kernels assume rotary and 1/sqrt(head_dim)")
+    if attn_fn is None and not return_kv and llama_like:
         # default dense path: projection layout end-to-end + grouped
         # einsums — no transposes, no materialized GQA repeat (the
         # d2048 step's 69%-copy profile, see the grouped fn)
@@ -478,8 +596,11 @@ def attention(x, p, prefix, cfg: TransformerConfig, attn_fn=None,
     # explicit attn_fns (flash/ring/ulysses) and the cache-prefill path
     # take (b, h, s, d) with equal head counts
     q, k, v = qkv_project(x, p, prefix, cfg, positions=positions)
-    out = (attn_fn or dense_causal_attention)(
-        q, expand_gqa(k, cfg), expand_gqa(v, cfg))
+    if attn_fn is None:
+        out = dense_causal_attention(q, expand_gqa(k, cfg),
+                                     expand_gqa(v, cfg), cfg.attn_scale)
+    else:
+        out = attn_fn(q, expand_gqa(k, cfg), expand_gqa(v, cfg))
     out = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.head_dim)
     out = out @ wmat(p, prefix + "wo", x.dtype)
     return (out, k, v) if return_kv else out
@@ -507,19 +628,24 @@ def forward_hidden(params: Dict, tokens: jax.Array,
     activations, below remat="full"'s O(n_layers) (the engine's
     larger-than-device-memory identity applied to the activation
     axis)."""
-    x = params["tok_embed"].astype(cfg.dtype)[tokens]
+    x = embed_tokens(params, cfg, tokens)
     aux = jnp.zeros((), jnp.float32)
 
     def layer_body(p, x, i):
         L = f"layers.{i}."
-        x = x + attention(rms_norm(x, p[L + "attn_norm"], cfg.norm_eps),
-                          p, L, cfg, attn_fn)
+        h = rms_norm(x, p[L + "attn_norm"], cfg.norm_eps)
+        if cfg.is_mamba_layer(i):
+            from nvme_strom_tpu.models.ssm import mamba_block
+            h = mamba_block(h, p, L, cfg)[0]
+        else:
+            h = attention(h, p, L, cfg, attn_fn)
+        x = add_residual(x, h, cfg)
         h = rms_norm(x, p[L + "mlp_norm"], cfg.norm_eps)
         if cfg.is_moe_layer(i):
             h, a = _moe.moe_mlp(h, p, L, cfg)
         else:
             h, a = mlp(h, p, L), jnp.zeros((), jnp.float32)
-        return x + h, a
+        return add_residual(x, h, cfg), a
 
     def one_layer(x, i):
         return layer_body(params, x, i)
@@ -585,8 +711,7 @@ def forward_with_aux(params: Dict, tokens: jax.Array,
     """tokens (b, s) int32 → (logits (b, s, vocab) f32, aux_loss scalar)."""
     x, aux = forward_hidden(params, tokens, cfg, attn_fn,
                             act_store=act_store)
-    logits = (x @ wmat(params, "lm_head", x.dtype)).astype(jnp.float32)
-    return logits, aux
+    return lm_logits(params, cfg, x), aux
 
 
 def forward(params: Dict, tokens: jax.Array,

@@ -18,6 +18,7 @@ from nvme_strom_tpu.models.transformer import TransformerConfig
 
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, P]:
+    cfg.require_no_recurrent("a mesh (parallel/shardings.param_specs)")
     specs = {
         "tok_embed": P(None, "tp"),     # d_model sharded
         "final_norm": P(),

@@ -48,6 +48,20 @@ _LAYER_RULES: Tuple[Tuple[str, str, bool], ...] = (
     ("mlp.down_proj.weight", "w_down", True),
     ("input_layernorm.weight", "attn_norm", False),
     ("post_attention_layernorm.weight", "mlp_norm", False),
+    # granitemoehybrid: the Mamba-2 mixer (models/ssm.py) and the shared MLP.
+    # conv1d.weight is (conv_dim, 1, K): squeezed to (conv_dim, K), then
+    # transposed like a Linear to our (K, conv_dim).  input_linear stacks
+    # [gate; up] along its output rows: convert() splits "w_gate_up".
+    ("mamba.in_proj.weight", "ssm_in", True),
+    ("mamba.out_proj.weight", "ssm_out", True),
+    ("mamba.conv1d.weight", "ssm_conv_w", True),
+    ("mamba.conv1d.bias", "ssm_conv_b", False),
+    ("mamba.dt_bias", "ssm_dt_bias", False),
+    ("mamba.A_log", "ssm_A_log", False),
+    ("mamba.D", "ssm_D", False),
+    ("mamba.norm.weight", "ssm_norm", False),
+    ("shared_mlp.input_linear.weight", "w_gate_up", True),
+    ("shared_mlp.output_linear.weight", "w_down", True),
 )
 
 _TOP_RULES: Dict[str, Tuple[str, bool]] = {
@@ -78,8 +92,52 @@ def map_name(hf_name: str) -> Optional[Tuple[str, bool]]:
     return None
 
 
+def _hybrid_fields(hf_cfg: dict) -> dict:
+    """The TransformerConfig fields of a ``granitemoehybrid`` config
+    (Mamba-2 layers beside attention, no routed experts), beyond the dense
+    family's.  Raises on what the model does not implement."""
+    def refuse(what):
+        raise ValueError(f"unsupported granitemoehybrid config: {what}")
+    if hf_cfg.get("num_local_experts", 0) or hf_cfg.get("num_experts_per_tok",
+                                                         0):
+        refuse(f"num_local_experts={hf_cfg.get('num_local_experts')} "
+               "(routed experts; only the shared MLP is implemented)")
+    if hf_cfg.get("mamba_n_groups", 1) != 1:
+        refuse(f"mamba_n_groups={hf_cfg['mamba_n_groups']} (one B/C group)")
+    if hf_cfg.get("mamba_proj_bias"):
+        refuse("mamba_proj_bias=True (projections have no bias)")
+    if not hf_cfg.get("mamba_conv_bias", True):
+        refuse("mamba_conv_bias=False (the conv carries a bias)")
+    if hf_cfg.get("position_embedding_type", "rope") != "nope":
+        refuse(f"position_embedding_type="
+               f"{hf_cfg.get('position_embedding_type')!r} (only 'nope': "
+               "hybrid attention layers take no rotary)")
+    if hf_cfg.get("normalization_function", "rmsnorm") != "rmsnorm":
+        refuse(f"normalization_function="
+               f"{hf_cfg['normalization_function']!r}")
+    ff = hf_cfg.get("shared_intermediate_size", hf_cfg["intermediate_size"])
+    if ff != hf_cfg["intermediate_size"]:
+        refuse(f"shared_intermediate_size {ff} != intermediate_size "
+               f"{hf_cfg['intermediate_size']}")
+    heads, p = hf_cfg["mamba_n_heads"], hf_cfg["mamba_d_head"]
+    if heads * p != hf_cfg.get("mamba_expand", 2) * hf_cfg["hidden_size"]:
+        refuse(f"mamba_n_heads x mamba_d_head = {heads * p} is not "
+               "mamba_expand x hidden_size")
+    return dict(
+        layer_kinds=tuple(hf_cfg["layer_types"]),   # checked by the config
+        ssm_heads=heads, ssm_head_dim=p,
+        ssm_state=hf_cfg["mamba_d_state"], ssm_conv=hf_cfg["mamba_d_conv"],
+        ssm_chunk=hf_cfg.get("mamba_chunk_size", 256),
+        embed_mult=float(hf_cfg.get("embedding_multiplier", 1.0)),
+        residual_mult=float(hf_cfg.get("residual_multiplier", 1.0)),
+        logits_div=float(hf_cfg.get("logits_scaling", 1.0)),
+        attn_scale=float(hf_cfg["attention_multiplier"]),
+        rope=False, tie_embed=bool(hf_cfg.get("tie_word_embeddings", False)))
+
+
 def config_from_hf(hf_cfg: dict):
-    """HF ``config.json`` → TransformerConfig (dense Llama family).
+    """HF ``config.json`` → TransformerConfig: the dense Llama family, and
+    ``model_type`` granitemoehybrid (``_hybrid_fields``).
 
     Raises on architecture knobs the model does not implement — silently
     ignoring them (e.g. a non-SiLU activation) would convert into a model
@@ -93,6 +151,8 @@ def config_from_hf(hf_cfg: dict):
         if hf_cfg.get(knob):
             raise ValueError(f"unsupported {knob}=True (model has no "
                              "bias terms)")
+    hybrid = (_hybrid_fields(hf_cfg)
+              if hf_cfg.get("model_type") == "granitemoehybrid" else {})
     derived_hd = hf_cfg["hidden_size"] // hf_cfg["num_attention_heads"]
     if hf_cfg["hidden_size"] % hf_cfg["num_attention_heads"]:
         raise ValueError("hidden_size not divisible by num_attention_heads")
@@ -125,6 +185,7 @@ def config_from_hf(hf_cfg: dict):
         rope_theta=float(hf_cfg.get("rope_theta", 10000.0)),
         rope_scaling=scaling,
         norm_eps=float(hf_cfg.get("rms_norm_eps", 1e-5)),
+        **hybrid,
     )
 
 
@@ -137,6 +198,11 @@ def strom_config_dict(cfg) -> dict:
         "max_seq", "rope_theta", "norm_eps")}
     if cfg.rope_scaling:
         out["rope_scaling"] = dict(cfg.rope_scaling)
+    if cfg.layer_kinds:     # a hybrid: what _hybrid_fields read off the HF file
+        out.update({k: getattr(cfg, k) for k in (
+            "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_conv", "ssm_chunk",
+            "embed_mult", "residual_mult", "logits_div", "attn_scale", "rope",
+            "tie_embed")}, layer_kinds=list(cfg.layer_kinds))
     return out
 
 
@@ -216,13 +282,24 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
             skipped.append(hf_name)
             continue
         ours, transpose = mapped
+        if arr.ndim == 3:       # depthwise conv (channels, 1, taps)
+            arr = arr.reshape(arr.shape[0], arr.shape[2])
         # bf16 fields load as uint16 views via numpy; keep raw dtype
         out = np.ascontiguousarray(arr.T) if transpose else arr
         if ours == "tok_embed":
             embed = arr
+        if ours.endswith("w_gate_up"):      # (d, 2 ff) → gate | up
+            ff = out.shape[1] // 2
+            for leaf, half in (("w_gate", out[:, :ff]), ("w_up", out[:, ff:])):
+                name = ours[:-len("w_gate_up")] + leaf
+                seen.add(name)
+                emit(name, np.ascontiguousarray(half))
+            continue
         seen.add(ours)
         emit(ours, out)
 
+    if cfg.tie_embed:
+        seen.add("lm_head")     # the head IS tok_embed: nothing to write
     if "lm_head" not in seen:
         if not hf_cfg.get("tie_word_embeddings", False) or embed is None:
             raise ValueError("checkpoint has no lm_head.weight and "

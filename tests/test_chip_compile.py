@@ -135,8 +135,65 @@ def _ici(topo):
     return compiled
 
 
+# granite-4.0-h-micro's recurrent layer: 64 heads of 64, state 128, and the
+# benchmark's 64 slots
+SSM_B, SSM_H, SSM_P, SSM_N = 64, 64, 64, 128
+
+
+def _paged_hd64(topo):
+    """Paged attention at head_dim 64 (half a lane row), 4 queries a KV
+    head, with a scale that is passed in."""
+    from nvme_strom_tpu.ops.paged_attention import paged_attention
+    sh, blocks, bk = _one(topo), 640, 128
+    return _compile(
+        functools.partial(paged_attention, scale=1 / 64, interpret=False),
+        _spec((SSM_B, 32, 1, 64), jnp.bfloat16, sh),
+        _spec((blocks + 1, 8, bk, 64), jnp.bfloat16, sh),
+        _spec((blocks + 1, 8, bk, 64), jnp.bfloat16, sh),
+        _spec((SSM_B, 1280 // bk), jnp.int32, sh),
+        _spec((SSM_B,), jnp.int32, sh))
+
+
+def _ssm_update(topo):
+    """The state update: the pool (65 rows of 2 MiB) is aliased through the
+    call — no second copy of it is ever live."""
+    from nvme_strom_tpu.ops.ssm import ssm_update
+    sh = _one(topo)
+    pool = _spec((SSM_B + 1, SSM_H, SSM_P, SSM_N), jnp.float32, sh)
+    compiled = _compile(
+        functools.partial(ssm_update, interpret=False), pool,
+        _spec((SSM_B,), jnp.int32, sh),
+        _spec((SSM_B, SSM_H, SSM_P), jnp.bfloat16, sh),
+        _spec((SSM_B, SSM_H), jnp.float32, sh),
+        _spec((SSM_H,), jnp.float32, sh),
+        _spec((SSM_B, SSM_N), jnp.bfloat16, sh),
+        _spec((SSM_B, SSM_N), jnp.bfloat16, sh), donate_argnums=(0,))
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= np.prod(pool.shape) * 4, m
+    return compiled
+
+
+def _ssm_scan(topo, rows=1024):
+    from nvme_strom_tpu.ops.ssm import ssm_scan
+    sh = _one(topo)
+    return _compile(
+        functools.partial(ssm_scan, chunk=256, interpret=False),
+        _spec((1, rows, SSM_H, SSM_P), jnp.bfloat16, sh),
+        _spec((1, rows, SSM_H), jnp.float32, sh),
+        _spec((SSM_H,), jnp.float32, sh),
+        _spec((1, rows, SSM_N), jnp.bfloat16, sh),
+        _spec((1, rows, SSM_N), jnp.bfloat16, sh),
+        _spec((1, SSM_H, SSM_P, SSM_N), jnp.float32, sh),
+        _spec((1, rows), jnp.bool_, sh))
+
+
+def _ssm_scan_128(topo):
+    return _ssm_scan(topo, rows=128)       # a chunk shorter than 256
+
+
 @pytest.mark.parametrize("build", [_paged, _decode, _flash_fwd,
-                                   _flash_bwd, _bridge, _ici],
+                                   _flash_bwd, _bridge, _ici, _paged_hd64,
+                                   _ssm_update, _ssm_scan, _ssm_scan_128],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_kernel_compiles_for_v5e(topo, build):
     assert build(topo) is not None
@@ -213,6 +270,49 @@ def test_prefill_program_fits_one_chip(topo, suffix_blocks, blocks):
     need = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert need < HBM_BYTES, m
+
+
+def test_hybrid_step_updates_both_caches_in_place(topo, monkeypatch):
+    """The paged server's decode step for a hybrid at granite-4.0-h-micro's
+    widths, one period of its layer pattern (9 mamba + 1 attention), 64
+    slots: every recurrent layer's state pool, conv tail and the K/V pool
+    are aliased input to output — nothing pool-sized is copied."""
+    import json
+    from nvme_strom_tpu.models import serving, ssm
+    from nvme_strom_tpu.tools.convert_llama import config_from_hf
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        hf = json.load(f)
+    hf = dict(hf, num_hidden_layers=10, layer_types=hf["layer_types"][:10])
+    cfg = config_from_hf(hf)
+    sh = _one(topo)
+    B, blocks, bk = 64, 640, 128
+    from nvme_strom_tpu.models.transformer import init_params
+    params = {k: _spec(v.shape, jnp.bfloat16, sh) for k, v in jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)).items()}
+    pool = _spec((1, blocks + 1, cfg.n_kv_heads, bk, cfg.head_dim),
+                 jnp.bfloat16, sh)
+    state = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype, sh),
+        jax.eval_shape(lambda: ssm.init_state(cfg, B + 1)))
+    vec = lambda dt: _spec((B,), dt, sh)                    # noqa: E731
+    compiled = serving._paged_step.lower(
+        params, cfg, vec(jnp.int32), pool, pool, vec(jnp.int32),
+        vec(jnp.int32), _spec((B, 1280 // bk), jnp.int32, sh),
+        vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
+        vec(jnp.uint32), state, vec(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 10
+    donated = (2 * np.prod(pool.shape) * 2
+               + sum(np.prod(a.shape) * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(state)))
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= donated, m
+    # and no operation of the step copies a state array (65 x 2 MiB)
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and "= f32[65,64,64,128]" in line]
 
 
 def test_sharded_forward_compiles_for_four_chips(topo):

@@ -238,3 +238,69 @@ def test_generate_example_cli(hf_checkpoint, tmp_path):
     ids2 = (r2.stdout.split("output ids:")[1].strip().splitlines()[0]
             .split(","))
     assert ids2 == ids
+
+
+# -- granitemoehybrid: Mamba-2 layers beside attention ----------------------
+
+@pytest.fixture(scope="module")
+def hf_hybrid(tmp_path_factory):
+    if not hasattr(transformers, "GraniteMoeHybridForCausalLM"):
+        pytest.skip("this transformers has no GraniteMoeHybrid")
+    d = tmp_path_factory.mktemp("hf_hybrid")
+    cfg = transformers.GraniteMoeHybridConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        shared_intermediate_size=128, num_hidden_layers=4,
+        num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=128, rms_norm_eps=1e-5,
+        layer_types=["mamba", "mamba", "attention", "mamba"],
+        mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16, mamba_d_conv=4,
+        mamba_expand=2, mamba_n_groups=1, mamba_chunk_size=8,
+        mamba_conv_bias=True, mamba_proj_bias=False,
+        position_embedding_type="nope", embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=8.0,
+        attention_multiplier=1 / 16, tie_word_embeddings=True,
+        num_local_experts=0, num_experts_per_tok=0, attention_bias=False)
+    torch.manual_seed(0)
+    model = transformers.GraniteMoeHybridForCausalLM(cfg).eval()
+    with torch.no_grad():       # D, the norms and the conv bias off their init
+        for name, p in model.named_parameters():
+            if name.endswith(("mamba.D", "norm.weight", "layernorm.weight",
+                              "conv1d.bias")):
+                p.add_(0.1 * torch.randn_like(p))
+    model.save_pretrained(d, safe_serialization=True)
+    return str(d), model
+
+
+def test_map_name_covers_hybrid_tensors():
+    m = convert_llama.map_name
+    assert m("model.layers.1.mamba.in_proj.weight") == ("layers.1.ssm_in",
+                                                        True)
+    assert m("model.layers.1.mamba.conv1d.weight") == ("layers.1.ssm_conv_w",
+                                                       True)
+    assert m("model.layers.1.mamba.A_log") == ("layers.1.ssm_A_log", False)
+    assert m("model.layers.0.shared_mlp.input_linear.weight") == (
+        "layers.0.w_gate_up", True)
+
+
+def test_hybrid_logits_match_hf(hf_hybrid, tmp_path):
+    """Converted granitemoehybrid weights through ``forward`` (the Pallas
+    scan in interpret mode) against transformers' own forward: names,
+    the conv's layout, the fused MLP's split, gate-before-norm, no rotary,
+    the four multipliers and the tied head all line up."""
+    import jax.numpy as jnp
+    from nvme_strom_tpu.models.transformer import forward
+    hf_dir, model = hf_hybrid
+    out = str(tmp_path / "converted")
+    summary = convert_llama.convert(hf_dir, out)
+    assert summary["skipped"] == []
+    cfg, params = _load_converted(out)
+    assert cfg.layer_kinds == ("mamba", "mamba", "attention", "mamba")
+    assert cfg.tie_embed and "lm_head" not in params
+    toks = np.random.default_rng(0).integers(0, 256, (2, 21))
+    with torch.no_grad():
+        want = model(torch.tensor(toks)).logits.numpy()
+    got = np.asarray(forward({k: jnp.asarray(v, jnp.float32)
+                              for k, v in params.items()},
+                             jnp.asarray(toks), cfg))
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=2e-5 * np.abs(want).max())

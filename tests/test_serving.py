@@ -253,7 +253,10 @@ def test_server_stats_gauges(setup):
              "prefix_shared_blocks": 0, "requests_finished": 0,
              "ttft_ms_avg": 0.0, "ttft_ms_max": 0.0,
              "admit_wait_ms_avg": 0.0, "admit_wait_ms_max": 0.0,
-             "admissions_shed": 0, "prefill_programs": 0}
+             "admissions_shed": 0, "prefill_programs": 0,
+             # the two kinds of cache: every layer keeps K/V here, and no
+             # layer carries a recurrent state (tests/test_hybrid.py)
+             "kv_layers": cfg.n_layers, "state_bytes": 0, "state_slots": 0}
     assert s0 == want0
     srv.step()
     s1 = srv.stats()
