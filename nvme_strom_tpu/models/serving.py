@@ -13,6 +13,9 @@ shapes.  Per-slot sequence positions are data (a ``(B,)`` vector), not
 shapes: cache writes scatter to per-row positions, attention masks by
 ``pos[b]``, RoPE takes per-row positions (transformer._rope's 2-D
 form).  One compiled step program serves every mix of request states.
+The paged step moves nothing of the K/V pool's size: its new rows go into
+the donated pool where they lie and the kernel reads the 5-D pool by layer
+index (ops/paged_attention.py ``write_rows`` / ``paged_attention``).
 
 Admission is one compiled program too (``_serve_prefill`` for the dense
 slots, ``_paged_prefill`` for the block pool): the cached prefix gathered
@@ -334,16 +337,18 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     the paged-attention kernel.  state/sidx: the recurrent layers' pool
     (``models/ssm.init_state``) and each slot's row of it — a free slot's
     is the sacrificial last row, as its ``blk`` is the trash block."""
-    from nvme_strom_tpu.ops.paged_attention import paged_attention
+    from nvme_strom_tpu.ops.paged_attention import (paged_attention,
+                                                    write_rows)
     pools = {"k": k_pool, "v": v_pool}
 
     def write_and_attend(i, q, k, v):
-        pools["k"] = pools["k"].at[i, blk, :, off, :].set(
-            k[:, :, 0].astype(pools["k"].dtype))
-        pools["v"] = pools["v"].at[i, blk, :, off, :].set(
-            v[:, :, 0].astype(pools["v"].dtype))
-        return paged_attention(q, pools["k"][i], pools["v"][i], table,
-                               pos, scale=cfg.attn_scale)
+        # the new rows go into the (donated) pools where they lie and the
+        # kernel reads layer i of them in place: nothing pool-sized moves
+        pools["k"], pools["v"] = write_rows(
+            pools["k"], pools["v"], k[:, :, 0], v[:, :, 0], blk, off,
+            layer=i)
+        return paged_attention(q, pools["k"], pools["v"], table, pos,
+                               layer=i, scale=cfg.attn_scale)
 
     if state is None:
         logits = _batched_step_body(params, cfg, tok, pos,

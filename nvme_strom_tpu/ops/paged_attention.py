@@ -18,6 +18,19 @@ fused position-masked online softmax at kv-head width.
 Padding-table entries may point anywhere (block 0 convention): their
 columns sit past ``pos`` and are masked; their V rows are zeroed before
 use so garbage cannot ride a 0·NaN.
+
+The pool of EVERY layer is one array ``(layers, blocks, kv_heads, block,
+d)`` and both kernels here take it whole, with a static layer index in
+their index maps: a decode step never slices a layer out of it, and
+``write_rows`` (``strom_kv_write``) places the step's new rows in the
+donated buffer itself (``input_output_aliases``), one aligned tile read,
+patched and written back per slot.  Nothing pool-sized is copied.
+
+The device keeps an array whose minor dimension is narrower than a lane
+row with the next dimension on the lanes (``_tokens_on_lanes``): such a
+pool (head_dim 64 under block 128) is handed to the kernels with its last
+two axes swapped — a relabelling of the same bytes, no copy — and they
+read K/V blocks as ``(d, block)``.
 """
 
 from __future__ import annotations
@@ -33,8 +46,34 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
+def _interpret(interpret):
+    return jax.default_backend() != "tpu" if interpret is None else interpret
+
+
+def _tokens_on_lanes(pool_shape) -> bool:
+    """Whether the device holds this pool with the block's tokens, not the
+    head's features, along the lanes.  The TPU's compact layout of an array
+    whose minor dimension is no multiple of the 128 lanes while the next
+    one is puts that next dimension on the lanes (bf16 and f32 alike:
+    ``(…, 128, 64)`` lies as ``(…, 64, 128)``; ``(…, 128, 128)`` and
+    ``(…, 64, 64)`` as they read).  A Mosaic kernel takes its operands
+    row-major, so the kernels here see such a pool through
+    ``_kernel_view``.  A wrong answer costs copies, never results: XLA
+    then transposes for real (tests/test_chip_compile.py pins both cells'
+    shapes)."""
+    block, d = pool_shape[-2:]
+    return d % 128 != 0 and block % 128 == 0
+
+
+def _kernel_view(pool, lanes: bool):
+    """The pool as the kernels index it: as it is, or with ``(block, d)``
+    swapped to ``(d, block)`` — the same bytes in the device's layout, so
+    XLA makes the swap (and the swap back of a kernel's result) a bitcast."""
+    return jnp.swapaxes(pool, 3, 4) if lanes else pool
+
+
 def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale, block_k, n_blocks):
+                  m_ref, l_ref, acc_ref, *, scale, block_k, n_blocks, tok):
     bi = pl.program_id(0)
     ji = pl.program_id(2)
     g = q_ref.shape[2]
@@ -46,15 +85,16 @@ def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale          # (g, d)
-    k = k_ref[0, 0].astype(jnp.float32)                  # (bk, d)
-    v = v_ref[0, 0].astype(jnp.float32)
+    # (bk, d), tokens on axis ``tok`` = 0; (d, bk) and 1 on a swapped pool
+    k = k_ref[0, 0, 0].astype(jnp.float32)
+    v = v_ref[0, 0, 0].astype(jnp.float32)
     pos = pos_ref[bi]
     # rows past pos carry zero weight, but padded/foreign blocks may
     # hold garbage and 0·NaN = NaN — zero those V rows outright
     rows_ok = (ji * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, v.shape, 0)) <= pos
+        jnp.int32, v.shape, tok)) <= pos
     v = jnp.where(rows_ok, v, 0.0)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    s = jax.lax.dot_general(q, k, (((1,), (1 - tok,)), ((), ())),
                             preferred_element_type=jnp.float32)
     cols = ji * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (g, block_k), 1)
@@ -68,7 +108,7 @@ def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     m_ref[:, 0] = m_new
     l_ref[:, 0] = l * alpha + jnp.sum(p, axis=1)
     acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
+        p, v, (((1,), (tok,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(ji == n_blocks - 1)
@@ -76,11 +116,14 @@ def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def paged_attention(q, k_pool, v_pool, table, pos, *, scale=None,
-                    interpret: bool = None):
-    """q (b, n_heads, 1, d) attends to its block-table history.
+def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
+                    scale=None, interpret: bool = None):
+    """q (b, n_heads, 1, d) attends to its block-table history in one
+    layer of the pool.
 
-    k_pool/v_pool (n_blocks, n_kv_heads, block_k, d): the shared pool.
+    k_pool/v_pool (n_layers, n_blocks, n_kv_heads, block_k, d): the shared
+    pool of EVERY layer, read where it lies; ``layer`` (static) picks the
+    one this call attends to.
     table (b, max_blocks) int32: slot b's sequence lives in pool blocks
     ``table[b, 0] .. table[b, ·]`` (padding entries arbitrary — they
     are masked).  pos (b,) int32: index of slot b's newest entry in its
@@ -92,7 +135,12 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, scale=None,
     if q.ndim != 4 or q.shape[2] != 1:
         raise ValueError(f"expected q (b, h, 1, d), got {q.shape}")
     b, nh, _, d = q.shape
-    n_pool, nkv, block_k, _ = k_pool.shape
+    if k_pool.ndim != 5 or v_pool.shape != k_pool.shape:
+        raise ValueError("expected pools (layers, blocks, kv_heads, "
+                         f"block, d), got {k_pool.shape}, {v_pool.shape}")
+    n_layers, _, nkv, block_k, _ = k_pool.shape
+    if not 0 <= layer < n_layers:
+        raise ValueError(f"layer {layer} not in a pool of {n_layers}")
     if nh % nkv:
         raise ValueError(f"{nh} query heads not divisible by {nkv} "
                          "kv heads")
@@ -103,8 +151,10 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, scale=None,
     max_blocks = table.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(d)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    lanes = _tokens_on_lanes(k_pool.shape)
+    kv_spec = pl.BlockSpec(
+        (1, 1, 1, d, block_k) if lanes else (1, 1, 1, block_k, d),
+        lambda bi, hi, ji, tbl, ps: (layer, tbl[bi, ji], hi, 0, 0))
     qg = q.reshape(b, nkv, g, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -112,12 +162,7 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, scale=None,
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
                          lambda bi, hi, ji, tbl, ps: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, ji, tbl, ps:
-                         (tbl[bi, ji], hi, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, ji, tbl, ps:
-                         (tbl[bi, ji], hi, 0, 0)),
+            kv_spec, kv_spec,
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
                                lambda bi, hi, ji, tbl, ps:
@@ -130,10 +175,96 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, scale=None,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=float(scale),
-                          block_k=block_k, n_blocks=max_blocks),
+                          block_k=block_k, n_blocks=max_blocks,
+                          tok=int(lanes)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, d), q.dtype),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(jnp.asarray(table, jnp.int32), jnp.asarray(pos, jnp.int32),
-      qg, k_pool, v_pool)
+      qg, _kernel_view(k_pool, lanes), _kernel_view(v_pool, lanes))
     return out.reshape(b, nh, 1, d)
+
+
+def _write_kernel(blk_ref, off_ref, kn_ref, vn_ref, kp_ref, vp_ref,
+                  ko_ref, vo_ref, *, tile, tok):
+    """One slot: its tile of the pool with the slot's row replaced.  The
+    select runs in float32 (exact both ways for bf16), where every shape of
+    broadcast and compare is at home."""
+    bi = pl.program_id(0)
+    at = off_ref[bi] % tile
+    for new, old, out in ((kn_ref, kp_ref, ko_ref), (vn_ref, vp_ref, vo_ref)):
+        have = old[...].astype(jnp.float32)      # (nkv, tile, d) | (nkv, d, tile)
+        hit = jax.lax.broadcasted_iota(jnp.int32, have.shape, 1 + tok) == at
+        row = (new[...].astype(jnp.float32) if tok     # (nkv, d, tile), or
+               else new[:, pl.ds(bi, 1), :])           # (nkv, 1, d) of (nkv, b, d)
+        out[...] = jnp.where(hit, row, have).astype(out.dtype)
+
+
+def write_rows(k_pool, v_pool, k_new, v_new, blk, off, *, layer: int,
+               interpret: bool = None):
+    """Place one new K and V row per slot in layer ``layer`` of the pools,
+    IN the pools: ``pool[layer, blk[b], :, off[b], :] = new[b]``.
+
+    k_pool/v_pool (layers, blocks, kv_heads, block, d), aliased input to
+    output: under ``jit`` with the pools donated nothing pool-sized is
+    copied or re-laid-out (the ``.at[].set`` scatter this replaces had XLA
+    transpose the whole pool into the scatter's layout and back, every
+    step).  k_new/v_new (b, kv_heads, d); blk/off (b,) int32.  Each grid
+    step reads the aligned tile that holds its slot's row, replaces the
+    row and writes the tile back.  Two slots aimed at one row (free slots
+    and the trash block) leave one of their rows there, either one; the
+    slots of live requests never share a block they write.
+
+    Returns (k_pool, v_pool)."""
+    n_layers, _, nkv, block, d = k_pool.shape
+    if v_pool.shape != k_pool.shape or not 0 <= layer < n_layers:
+        raise ValueError(f"layer {layer} of pools {k_pool.shape}, "
+                         f"{v_pool.shape}")
+    b = k_new.shape[0]
+    if k_new.shape != (b, nkv, d) or v_new.shape != (b, nkv, d):
+        raise ValueError(f"expected new rows ({b}, {nkv}, {d}), got "
+                         f"{k_new.shape}, {v_new.shape}")
+    lanes = _tokens_on_lanes(k_pool.shape)
+    news = [x.astype(pool.dtype) for x, pool in ((k_new, k_pool),
+                                                 (v_new, v_pool))]
+    if lanes:
+        # tokens along the lanes: the tile is a lane row of them, and each
+        # slot's new row comes in already spread along it
+        tile = 128
+        news = [jnp.broadcast_to(x[..., None], (b, nkv, d, tile))
+                for x in news]
+        new_spec = pl.BlockSpec((None, nkv, d, tile),
+                                lambda bi, bl, of: (bi, 0, 0, 0))
+        tile_spec = pl.BlockSpec(
+            (None, None, nkv, d, tile),
+            lambda bi, bl, of: (layer, bl[bi], 0, 0, of[bi] // tile))
+    else:
+        # tokens along the sublanes: one packed sublane tile of them.  The
+        # new rows stay whole in VMEM, kv-head-major as the projections
+        # emit them (no re-layout between the matmul and this call) and in
+        # float32 (exact; a single row of a packed type cannot be loaded)
+        tile = min(block, 8 * 4 // k_pool.dtype.itemsize)
+        news = [jnp.swapaxes(x, 0, 1).astype(jnp.float32) for x in news]
+        new_spec = pl.BlockSpec((nkv, b, d), lambda bi, bl, of: (0, 0, 0))
+        tile_spec = pl.BlockSpec(
+            (None, None, nkv, tile, d),
+            lambda bi, bl, of: (layer, bl[bi], 0, of[bi] // tile, 0))
+    kv, vv = _kernel_view(k_pool, lanes), _kernel_view(v_pool, lanes)
+    kv, vv = pl.pallas_call(
+        functools.partial(_write_kernel, tile=tile, tok=int(lanes)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,),
+            in_specs=[new_spec, new_spec, tile_spec, tile_spec],
+            out_specs=[tile_spec, tile_spec]),
+        out_shape=[jax.ShapeDtypeStruct(kv.shape, kv.dtype),
+                   jax.ShapeDtypeStruct(vv.shape, vv.dtype)],
+        # operands 4 and 5 (after the two scalar-prefetch ones) are the
+        # pools, and so are the results
+        input_output_aliases={4: 0, 5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="strom_kv_write",
+        interpret=_interpret(interpret),
+    )(jnp.asarray(blk, jnp.int32), jnp.asarray(off, jnp.int32),
+      *news, kv, vv)
+    return _kernel_view(kv, lanes), _kernel_view(vv, lanes)

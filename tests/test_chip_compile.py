@@ -7,10 +7,19 @@ first, at no chip time.  Shapes are Llama-3.1-8B's head geometry (32 query
 / 8 KV heads, head_dim 128) and chip_smoke.py's own serving sizes; every
 kernel is compiled with ``interpret=False``.  Nothing runs: a compile
 that passes is not a chip run.
+
+Run as a script on the machine with the chip (``python
+tests/test_chip_compile.py``) it compiles the paged decode step for the
+ATTACHED device and applies the same guard as
+``test_step_moves_nothing_pool_sized``: that run, with the layouts the
+device really gives its arrays, is the one that means something.
 """
 
 import functools
+import json
 import os
+import re
+import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -64,14 +73,57 @@ def _compile(fn, *specs, **jit_kw):
     return compiled
 
 
+def pool_sized_ops(hlo_text: str, pool_shape) -> list:
+    """Operations of an optimised HLO module whose result has as many
+    elements as the K/V pool or as one layer of it, as "opcode shape".
+    Parameters, tuple plumbing, bitcasts (no bytes move) and the kernels
+    themselves (which alias the pool through) do not count; a copy, a
+    transpose, a slice, a scatter or a fusion of them does."""
+    sizes = {int(np.prod(pool_shape)), int(np.prod(pool_shape[1:]))}
+    free = {"parameter", "get-tuple-element", "tuple", "bitcast",
+            "custom-call"}
+    found = []
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\w+\[([\d,]+)\])\S* "
+                     r"([\w\-]+)\(", line)
+        if m and m.group(3) not in free and int(np.prod(
+                [int(n) for n in m.group(2).split(",")])) in sizes:
+            found.append(f"{m.group(3)} {m.group(1)}")
+    return found
+
+
+def _small_step(hd, sharding=None):
+    """``_paged_step`` of a two-layer decoder with 8 heads of ``hd`` over a
+    pool of 257 blocks of 128 rows (64 MiB a layer at 128: too large for
+    the compiler to stage through the chip's fast memory, as it does with
+    a pool of a megabyte), 8 slots: (compiled, pool shape)."""
+    from nvme_strom_tpu.models import serving
+    from nvme_strom_tpu.models.transformer import (TransformerConfig,
+                                                   init_params)
+    cfg = TransformerConfig(vocab=512, d_model=8 * hd, n_layers=2, n_heads=8,
+                            n_kv_heads=8, d_ff=512, max_seq=512)
+    spec = functools.partial(_spec, sharding=sharding)
+    params = {k: spec(v.shape, jnp.bfloat16) for k, v in jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)).items()}
+    B, bk = 8, 128
+    pool = spec((cfg.n_layers, 257, cfg.n_kv_heads, bk, hd), jnp.bfloat16)
+    vec = lambda dt: spec((B,), dt)                         # noqa: E731
+    compiled = serving._paged_step.lower(
+        params, cfg, vec(jnp.int32), pool, pool, vec(jnp.int32),
+        vec(jnp.int32), spec((B, cfg.max_seq // bk), jnp.int32),
+        vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
+        vec(jnp.uint32)).compile()
+    return compiled, pool.shape
+
+
 def _paged(topo):
     from nvme_strom_tpu.ops.paged_attention import paged_attention
     sh, b, blocks, bk = _one(topo), 8, 96, 128
     return _compile(
         functools.partial(paged_attention, interpret=False),
         _spec((b, NH, 1, HD), jnp.bfloat16, sh),
-        _spec((blocks + 1, NKV, bk, HD), jnp.bfloat16, sh),
-        _spec((blocks + 1, NKV, bk, HD), jnp.bfloat16, sh),
+        _spec((1, blocks + 1, NKV, bk, HD), jnp.bfloat16, sh),
+        _spec((1, blocks + 1, NKV, bk, HD), jnp.bfloat16, sh),
         _spec((b, 4096 // bk), jnp.int32, sh),
         _spec((b,), jnp.int32, sh))
 
@@ -148,10 +200,32 @@ def _paged_hd64(topo):
     return _compile(
         functools.partial(paged_attention, scale=1 / 64, interpret=False),
         _spec((SSM_B, 32, 1, 64), jnp.bfloat16, sh),
-        _spec((blocks + 1, 8, bk, 64), jnp.bfloat16, sh),
-        _spec((blocks + 1, 8, bk, 64), jnp.bfloat16, sh),
+        _spec((1, blocks + 1, 8, bk, 64), jnp.bfloat16, sh),
+        _spec((1, blocks + 1, 8, bk, 64), jnp.bfloat16, sh),
         _spec((SSM_B, 1280 // bk), jnp.int32, sh),
         _spec((SSM_B,), jnp.int32, sh))
+
+
+def _kv_write(topo, hd=HD, slots=16, blocks=256, layers=2):
+    """The row writer on the benchmark's pools: both pools aliased through
+    the call, nothing else of their size in the program."""
+    from nvme_strom_tpu.ops.paged_attention import write_rows
+    sh = _one(topo)
+    pool = _spec((layers, blocks + 1, 8, 128, hd), jnp.bfloat16, sh)
+    new = _spec((slots, 8, hd), jnp.bfloat16, sh)
+    compiled = _compile(
+        functools.partial(write_rows, layer=1, interpret=False), pool, pool,
+        new, new, _spec((slots,), jnp.int32, sh),
+        _spec((slots,), jnp.int32, sh), donate_argnums=(0, 1))
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * np.prod(pool.shape) * 2, m
+    assert not pool_sized_ops(compiled.as_text(), pool.shape)
+    return compiled
+
+
+def _kv_write_hd64(topo):
+    """...and on a pool the device keeps with the tokens on the lanes."""
+    return _kv_write(topo, hd=64, slots=SSM_B, blocks=640)
 
 
 def _ssm_update(topo):
@@ -193,7 +267,8 @@ def _ssm_scan_128(topo):
 
 @pytest.mark.parametrize("build", [_paged, _decode, _flash_fwd,
                                    _flash_bwd, _bridge, _ici, _paged_hd64,
-                                   _ssm_update, _ssm_scan, _ssm_scan_128],
+                                   _ssm_update, _ssm_scan, _ssm_scan_128,
+                                   _kv_write, _kv_write_hd64],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_kernel_compiles_for_v5e(topo, build):
     assert build(topo) is not None
@@ -242,10 +317,28 @@ def test_decode_step_fits_one_chip(topo, monkeypatch, server):
             *sampling, make_decode_attn())
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if server == "paged":
+        assert not pool_sized_ops(compiled.as_text(), pool.shape)
     m = compiled.memory_analysis()
     need = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert need < HBM_BYTES, m
+
+
+@pytest.mark.parametrize("hd", [128, 64])
+def test_step_moves_nothing_pool_sized(topo, monkeypatch, hd):
+    """The paged decode step compiled for a v5e holds no operation whose
+    result is the K/V pool or one layer of it: the new rows are written
+    into the donated pool and the kernel reads the pool where it lies, at
+    head_dim 128 and at 64 (which the device keeps with the tokens on the
+    lanes).  The same guard against the ATTACHED chip's own compile:
+    ``python tests/test_chip_compile.py`` on the machine with the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, pool_shape = _small_step(hd, _one(topo))
+    assert compiled.as_text().count("tpu_custom_call") == 4
+    assert not pool_sized_ops(compiled.as_text(), pool_shape)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * np.prod(pool_shape) * 2, m
 
 
 @pytest.mark.parametrize("suffix_blocks,blocks", [(4, 4), (1, 4)],
@@ -304,7 +397,8 @@ def test_hybrid_step_updates_both_caches_in_place(topo, monkeypatch):
         vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
         vec(jnp.uint32), state, vec(jnp.int32)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 10
+    assert text.count("tpu_custom_call") == 11   # 9 updates, write, attend
+    assert not pool_sized_ops(text, pool.shape)
     donated = (2 * np.prod(pool.shape) * 2
                + sum(np.prod(a.shape) * a.dtype.itemsize
                      for a in jax.tree_util.tree_leaves(state)))
@@ -331,3 +425,24 @@ def test_sharded_forward_compiles_for_four_chips(topo):
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes) < HBM_BYTES, m
     assert "all-reduce" in compiled.as_text()
+
+
+if __name__ == "__main__":
+    # on the machine with the chip: the attached device's own compile
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    found = {}
+    for head_dim in (128, 64):
+        step, shape = _small_step(head_dim)
+        found[f"hd{head_dim}"] = {
+            "pool": list(shape),
+            "pool_sized_ops": pool_sized_ops(step.as_text(), shape),
+            "kernels": step.as_text().count("tpu_custom_call")}
+    ok = (jax.default_backend() == "tpu"
+          and all(not f["pool_sized_ops"] and f["kernels"] == 4
+                  for f in found.values()))
+    print(json.dumps({"guard": "paged step moves nothing pool-sized",
+                      "platform": jax.default_backend(),
+                      "device": jax.devices()[0].device_kind, "ok": ok,
+                      **found}))
+    sys.exit(0 if ok else 1)

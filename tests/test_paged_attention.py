@@ -1,12 +1,16 @@
 """Paged-attention kernel (ops/paged_attention.py) vs dense
-block-gather reference, ragged slot lengths, interpret mode on CPU."""
+block-gather reference, ragged slot lengths, and the row writer against
+the scatter it replaced; interpret mode on CPU.  The pools are 5-D, every
+layer's in one array: a 4-D pool of the reference is a pool of one layer."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from nvme_strom_tpu.ops.paged_attention import paged_attention
+from nvme_strom_tpu.ops.paged_attention import paged_attention, write_rows
 
 
 def _reference(q, kp, vp, table, pos):
@@ -38,7 +42,7 @@ def test_paged_matches_dense_ragged():
                      np.int32)
     pos = np.array([20, 9, 31], np.int32)    # lengths 21, 10, 32
     got = np.asarray(paged_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), jnp.asarray(kp)[None], jnp.asarray(vp)[None],
         jnp.asarray(table), jnp.asarray(pos)))
     want = _reference(q, kp, vp, table, pos)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
@@ -58,7 +62,7 @@ def test_padding_blocks_hold_garbage_safely():
     table = np.array([[1, 2]], np.int32)     # second block = NaN pad
     pos = np.array([block_k - 1], np.int32)  # only block 1 visible
     got = np.asarray(paged_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), jnp.asarray(kp)[None], jnp.asarray(vp)[None],
         jnp.asarray(table), jnp.asarray(pos)))
     assert np.isfinite(got).all()
     want = _reference(q, kp[:2], vp[:2], np.array([[1]]), pos)
@@ -67,7 +71,7 @@ def test_padding_blocks_hold_garbage_safely():
 
 def test_validation():
     q = jnp.zeros((2, 4, 1, 8))
-    kp = jnp.zeros((4, 2, 8, 8))
+    kp = jnp.zeros((1, 4, 2, 8, 8))
     with pytest.raises(ValueError, match="table"):
         paged_attention(q, kp, kp, jnp.zeros((3, 2), jnp.int32),
                         jnp.zeros((2,), jnp.int32))
@@ -75,3 +79,100 @@ def test_validation():
         paged_attention(jnp.zeros((2, 4, 2, 8)), kp, kp,
                         jnp.zeros((2, 2), jnp.int32),
                         jnp.zeros((2,), jnp.int32))
+    with pytest.raises(ValueError, match="pools"):       # a layer's slice
+        paged_attention(q, kp[0], kp[0], jnp.zeros((2, 2), jnp.int32),
+                        jnp.zeros((2,), jnp.int32))
+    with pytest.raises(ValueError, match="layer"):
+        paged_attention(q, kp, kp, jnp.zeros((2, 2), jnp.int32),
+                        jnp.zeros((2,), jnp.int32), layer=1)
+    with pytest.raises(ValueError, match="new rows"):
+        write_rows(kp, kp, jnp.zeros((2, 2, 4)), jnp.zeros((2, 2, 4)),
+                   jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+                   layer=0)
+
+
+# (block, head_dim): head_dim 64 under block 128 is the shape the device
+# keeps with the tokens along the lanes (the kernels' swapped view)
+GEOMETRIES = [(8, 16), (16, 128), (128, 64)]
+
+
+@pytest.mark.parametrize("block_k,d", GEOMETRIES)
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_reads_only_its_layer(layer, block_k, d):
+    """Layer ``layer`` of a 3-layer pool: the other layers are NaN, and the
+    result is the one-layer reference's."""
+    rng = np.random.default_rng([layer, d])
+    b, nh, nkv, n_pool = 2, 4, 2, 6
+    kp = np.full((3, n_pool, nkv, block_k, d), np.nan, np.float32)
+    vp = kp.copy()
+    kp[layer] = rng.standard_normal(kp.shape[1:])
+    vp[layer] = rng.standard_normal(vp.shape[1:])
+    q = rng.standard_normal((b, nh, 1, d)).astype(np.float32)
+    table = np.array([[4, 1, 3], [2, 5, 0]], np.int32)
+    pos = np.array([2 * block_k + 3, block_k - 1], np.int32)
+    got = np.asarray(paged_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(table), jnp.asarray(pos), layer=layer))
+    want = _reference(q, kp[layer], vp[layer], table, pos)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def _pools(rng, shape, dtype):
+    return (jnp.asarray(rng.standard_normal(shape), dtype),
+            jnp.asarray(rng.standard_normal(shape), dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("block_k,d", [(16, 64), (128, 64), (16, 128),
+                                       (128, 128)])
+def test_write_rows_matches_the_scatter(block_k, d, dtype):
+    """``write_rows`` against the scatter it replaced,
+    ``pool.at[i, blk, :, off, :].set``, bit for bit: first and last row of
+    a block, rows of one packed tile pair, and two free slots aimed at the
+    same row of the trash block, where either row may win."""
+    rng = np.random.default_rng([block_k, d])
+    L, n_pool, nkv, layer = 3, 5, 2, 1
+    trash = n_pool - 1
+    kp, vp = _pools(rng, (L, n_pool, nkv, block_k, d), dtype)
+    k_new, v_new = _pools(rng, (5, nkv, d), dtype)
+    blk = np.array([2, 0, trash, 1, trash], np.int32)
+    off = np.array([0, block_k - 1, 6, 7, 6], np.int32)
+    k_got, v_got = write_rows(kp, vp, k_new, v_new, jnp.asarray(blk),
+                              jnp.asarray(off), layer=layer)
+    assert k_got.dtype == dtype and k_got.shape == kp.shape
+    for got, pool, new in ((k_got, kp, k_new), (v_got, vp, v_new)):
+        got = np.asarray(got.astype(jnp.float32))
+        new = np.asarray(new.astype(jnp.float32))
+        live = [0, 1, 3]
+        want = np.array(pool.at[layer, blk[live], :, off[live], :].set(
+            new[live].astype(dtype)).astype(jnp.float32))
+        row = got[layer, trash, :, 6, :]
+        assert (row == new[2]).all() or (row == new[4]).all()
+        want[layer, trash, :, 6, :] = row
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("block_k,d", [(16, 128), (128, 64)])
+def test_write_rows_updates_the_donated_pools_in_place(block_k, d):
+    """Under ``jit`` with the pools donated the result IS the buffer that
+    was passed in: the argument is consumed and no second pool exists."""
+    rng = np.random.default_rng(5)
+    kp, vp = _pools(rng, (2, 4, 2, block_k, d), jnp.bfloat16)
+    k_new, v_new = _pools(rng, (3, 2, d), jnp.bfloat16)
+    blk = jnp.asarray([0, 1, 2], jnp.int32)
+    off = jnp.asarray([1, 2, 3], jnp.int32)
+    step = jax.jit(functools.partial(write_rows, layer=1),
+                   donate_argnums=(0, 1))
+    compiled = step.lower(kp, vp, k_new, v_new, blk, off).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= kp.nbytes + vp.nbytes
+    want = np.asarray(kp.at[1, blk, :, off, :].set(k_new)
+                      .astype(jnp.float32))
+    where = kp.unsafe_buffer_pointer(), vp.unsafe_buffer_pointer()
+    k_got, v_got = step(kp, vp, k_new, v_new, blk, off)
+    assert kp.is_deleted() and vp.is_deleted()
+    assert (k_got.unsafe_buffer_pointer(),
+            v_got.unsafe_buffer_pointer()) == where
+    np.testing.assert_array_equal(np.asarray(k_got.astype(jnp.float32)),
+                                  want)

@@ -676,3 +676,110 @@ def test_admission_reads_nothing_back(setup, kind, monkeypatch):
             srv._finish_traced(plan, {})
     assert not pulled and len(srv._pending_first) == 1
     assert srv.run()["r"] == _solo(params, cfg, [5, 6, 7, 8, 9], 4)
+
+
+# -- the decode step writes the pool in place (PR 27) ------------------------
+
+#: what the server below served at PR 26 (commit 254ed1e: rows scattered
+#: into the pool with ``.at[].set`` and one layer sliced out for the kernel),
+#: recorded on this CPU; the step that writes the donated pool in place and
+#: reads it where it lies has to serve the very same tokens
+SERVED_AT_PR26 = {
+    "plain_f32": {
+        "r0": [42, 68, 50, 44, 54, 19, 40, 26, 58],
+        "r1": [75, 6, 45, 70, 68, 39],
+        "r2": [49, 123, 79, 117, 90, 58, 100, 67, 19, 40, 26],
+        "r3": [48, 99, 57, 49, 98],
+        "r4": [100, 125, 3, 107, 94, 26, 58, 100],
+    },
+    "plain_bf16": {
+        "r0": [42, 68, 50, 44, 54, 19, 40, 26, 58],
+        "r1": [75, 6, 53, 108, 40, 39],
+        "r2": [49, 123, 79, 117, 90, 58, 100, 67, 19, 40, 26],
+        "r3": [48, 99, 57, 49, 98],
+        "r4": [100, 125, 3, 107, 94, 26, 58, 100],
+    },
+    "hybrid": {
+        "r0": [93, 6, 5, 93, 72, 31, 5, 5, 27],
+        "r1": [12, 18, 41, 41, 73, 41],
+        "r2": [36, 88, 74, 83, 31, 93, 66, 62, 79, 3, 33],
+        "r3": [79, 26, 76, 43, 12],
+        "r4": [57, 79, 32, 93, 65, 6, 63, 53],
+    },
+}
+
+
+def _pr26_model(kind):
+    """(params, cfg, block_len, total_blocks) of the recorded runs: the tiny
+    decoder of this file in float32 and in bfloat16, and test_hybrid.py's
+    hybrid (its attention layer keeps K/V, its three mamba layers state)."""
+    if kind == "hybrid":
+        import dataclasses
+
+        import test_hybrid as H
+        cfg = dataclasses.replace(H.config_from_hf(H.HF), dtype=jnp.float32)
+        params = {k: v.astype(jnp.float32)
+                  for k, v in H.WH.make_params(H.HF, H.SEED).items()}
+        return params, cfg, 8, 12
+    dtype = jnp.float32 if kind == "plain_f32" else jnp.bfloat16
+    cfg = TransformerConfig(**{**tiny_config().__dict__, "dtype": dtype})
+    params = {k: v.astype(dtype)
+              for k, v in init_params(jax.random.key(0), cfg).items()}
+    return params, cfg, 4, 24
+
+
+@pytest.mark.parametrize("kind", sorted(SERVED_AT_PR26))
+def test_paged_server_serves_what_it_served_before_the_in_place_step(kind):
+    """Five requests behind one shared head on two slots, lookahead 2:
+    every slot is freed and admitted again, the plain decoder's later
+    admissions hit the prefix cache (a hybrid has no prefix reuse), free
+    slots write the trash block meanwhile — token for token what the
+    scatter-and-slice step served."""
+    from nvme_strom_tpu.models.serving import PagedDecodeServer
+    params, cfg, bk, blocks = _pr26_model(kind)
+    rng = np.random.default_rng(27)
+    shared = rng.integers(0, cfg.vocab, 3 * bk + 1).tolist()
+    reqs = [(f"r{i}", shared + rng.integers(0, cfg.vocab, n).tolist(), m)
+            for i, (n, m) in enumerate([(2, 9), (5, 6), (1, 11), (7, 5),
+                                        (3, 8)])]
+    srv = PagedDecodeServer(params, cfg, max_batch=2, max_len=64,
+                            total_blocks=blocks, block_len=bk)
+    for rid, prompt, budget in reqs:
+        srv.submit(rid, prompt, budget)
+    assert srv.run(lookahead=2) == SERVED_AT_PR26[kind]
+    assert srv.timings["admits"] == 5               # 2 slots: 3 re-admitted
+    assert srv.stats()["prefix_hits"] == (0 if kind == "hybrid" else 3)
+
+
+def _pool_sized_eqns(jaxpr, sizes, found):
+    """Equations outside the kernels that make something pool-sized."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pool_sized_eqns(sub, sizes, found)
+        if any(int(np.prod(v.aval.shape)) in sizes for v in eqn.outvars
+               if hasattr(v.aval, "shape")):
+            found.append(eqn.primitive.name)
+    return found
+
+
+@pytest.mark.parametrize("kind", ["plain_f32", "hybrid"])
+def test_decode_step_traces_no_pool_sized_op_outside_the_kernels(kind):
+    """The structure of the step (``paged_logits``) on any platform: nothing
+    but the two kernels produces a value of the pool's size or of one
+    layer's — no scatter, no slice, no gather.  What the TPU's compiler
+    makes of it is pinned by tests/test_chip_compile.py."""
+    from nvme_strom_tpu.models import serving, ssm
+    params, cfg, bk, blocks = _pr26_model(kind)
+    B, L = 2, len(cfg.attn_layers)
+    pool = jnp.zeros((L, blocks + 1, cfg.n_kv_heads, bk, cfg.head_dim),
+                     cfg.dtype)
+    state = ssm.init_state(cfg, B + 1) if cfg.mamba_layers else None
+    i32 = jnp.zeros((B,), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: serving.paged_logits(params, cfg, *a))(
+        i32, pool, pool, i32, i32, jnp.zeros((B, 64 // bk), jnp.int32), i32,
+        state, i32)
+    assert str(jaxpr).count("pallas_call") >= 2 * L
+    assert not _pool_sized_eqns(jaxpr.jaxpr, {pool.size, pool.size // L}, [])
