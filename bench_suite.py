@@ -1557,8 +1557,7 @@ def bench_serving(device=None) -> tuple[float, str]:
     wall-clock from first step to drain, admission prefills included —
     the end-to-end serving rate, not a per-step best case."""
     import jax
-    from nvme_strom_tpu.models.serving import (DecodeServer,
-                                               PagedDecodeServer)
+    from nvme_strom_tpu.models.serving import DecodeServer
     from nvme_strom_tpu.models.transformer import init_params
     cfg = _bench_cfg()
     if _tiny_compute():
@@ -1590,13 +1589,9 @@ def bench_serving(device=None) -> tuple[float, str]:
     total_blocks = sum(-(-w // block_len) for w in worst)
 
     def make():
-        if paged:
-            return PagedDecodeServer(params, cfg, max_batch=slots,
-                                     max_len=max_len,
-                                     total_blocks=total_blocks,
-                                     block_len=block_len)
-        return DecodeServer(params, cfg, max_batch=slots,
-                            max_len=max_len)
+        return DecodeServer(params, cfg, max_batch=slots, max_len=max_len,
+                            total_blocks=total_blocks if paged else None,
+                            block_len=block_len)
 
     def submit_all(srv):
         import numpy as np

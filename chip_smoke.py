@@ -18,10 +18,10 @@ Phases (any failure is fatal; nothing is caught and passed over):
            bytes that landed in HBM are compared with the file's
   weights  ``examples/serve.py``'s ``load_weights``; tensors compared
            bit-for-bit with what was generated
-  serve    ``examples/serve.py``'s ``build_server``: PagedDecodeServer
-           (Pallas paged attention) and DecodeServer with the Pallas
-           decode-attention kernel answer mixed requests; one decode
-           step's logits are compared with the plain-XLA attention path
+  serve    ``examples/serve.py``'s ``build_server``: DecodeServer (Pallas
+           paged attention over a block pool) answers mixed requests; one
+           decode step's logits are compared with ``models/decode``'s
+           plain-XLA attention path
 
 ``--chips 4`` runs instead, and only: data, ``load_sharded`` under a tp=4
 mesh with a per-device share check, one forward compared with the same
@@ -385,14 +385,14 @@ def _serve(label: str, srv, reqs: list, clock: CompileClock) -> None:
 
 
 def _compare_logits(params, cfg, seed: int, max_len: int) -> None:
-    """One decode step of all slots over the SAME params and cache
-    contents through: the paged-attention kernel on a block pool, the
-    decode-attention kernel on the gathered dense cache, and the plain
-    XLA attention path (``cache_attn=None``) on that dense cache."""
+    """One decode step of all slots through the paged-attention kernel on
+    a block pool, against ``models/decode.decode_step`` (plain XLA
+    attention) of each slot alone over the same params and the same cache
+    contents, gathered dense."""
     import jax
 
-    from nvme_strom_tpu.models.serving import paged_logits, serve_logits
-    from nvme_strom_tpu.ops.decode_attention import make_decode_attn
+    from nvme_strom_tpu.models.decode import decode_step
+    from nvme_strom_tpu.models.serving import paged_logits
 
     B, bk = SLOTS, BLOCK_LEN
     max_blocks = max_len // bk
@@ -413,48 +413,36 @@ def _compare_logits(params, cfg, seed: int, max_len: int) -> None:
     off = pos % bk
     tok = rng.integers(0, cfg.vocab, size=B).astype(np.int32)
 
-    def dense(pool):        # (L, B, nkv, max_len, hd) per-slot caches
-        g = pool[:, table]              # (L, B, max_blocks, nkv, bk, hd)
-        return g.transpose(0, 1, 3, 2, 4, 5).reshape(
-            L, B, nkv, max_blocks * bk, hd)
+    @jax.jit
+    def alone(params, tok, k_pool, v_pool, row, pos):
+        def dense(pool):    # one slot's blocks → (L, 1, nkv, max_len, hd)
+            return pool[:, row].transpose(0, 2, 1, 3, 4).reshape(
+                L, 1, nkv, max_blocks * bk, hd)
+        return decode_step(params, tok[None], cfg,
+                           {"k": dense(k_pool), "v": dense(v_pool),
+                            "pos": pos})[0][0]
 
-    gather = jax.jit(dense)
-    k_dense, v_dense = gather(k_pool), gather(v_pool)
-
-    def run(fn, *args):
-        lowered = jax.jit(fn, static_argnums=(1,)).lower(*args)
-        kernel = "tpu_custom_call" in lowered.as_text()
-        return np.asarray(lowered.compile()(args[0], *args[2:])[0]), kernel
-
-    paged, paged_kernel = run(paged_logits, params, cfg, tok, k_pool,
-                              v_pool, blk, off, table, pos)
-    attn = make_decode_attn()
-    fused, fused_kernel = run(
-        lambda p, c, *a: serve_logits(p, c, *a, cache_attn=attn),
-        params, cfg, tok, k_dense, v_dense, pos)
-    ref, ref_kernel = run(serve_logits, params, cfg, tok, k_dense,
-                          v_dense, pos)
-    require(not ref_kernel, "the XLA reference lowered a Pallas kernel")
+    ref = np.stack([np.asarray(alone(params, tok[b], k_pool, v_pool,
+                                     table[b], pos[b])) for b in range(B)])
+    lowered = jax.jit(paged_logits, static_argnums=(1,)).lower(
+        params, cfg, tok, k_pool, v_pool, blk, off, table, pos)
+    kernel = "tpu_custom_call" in lowered.as_text()
     if on_tpu():
         # interpret=False: the kernels lowered to Mosaic custom calls
-        require(paged_kernel and fused_kernel,
-                f"kernels compiled: paged={paged_kernel} "
-                f"decode_attention={fused_kernel}")
+        require(kernel, "the paged step lowered no Pallas kernel")
+    paged = np.asarray(lowered.compile()(
+        params, tok, k_pool, v_pool, blk, off, table, pos)[0])
     scale = float(np.max(np.abs(ref)))
     require(np.isfinite(ref).all() and scale > 0,
             "finite, non-zero reference logits")
-    errs = {}
-    for label, got in (("paged", paged), ("decode_attention", fused)):
-        require(got.shape == (B, cfg.vocab) and np.isfinite(got).all(),
-                f"finite ({B}, vocab) logits from {label}")
-        errs[label] = float(np.max(np.abs(got - ref))) / scale
+    require(paged.shape == (B, cfg.vocab) and np.isfinite(paged).all(),
+            f"finite ({B}, vocab) logits from the paged step")
+    err = float(np.max(np.abs(paged - ref))) / scale
     say(f"logits: one decode step, {B} slots at positions {pos.tolist()}"
-        f", vs plain XLA attention: max|diff|/max|ref| "
-        + ", ".join(f"{k}={v:.2e}" for k, v in errs.items())
-        + f" (tolerance {LOGITS_TOL:g}, bf16); kernels compiled "
-        f"(interpret=False): paged={paged_kernel} "
-        f"decode_attention={fused_kernel}")
-    require(all(e <= LOGITS_TOL for e in errs.values()), errs)
+        f", vs plain XLA attention: max|diff|/max|ref| {err:.2e} "
+        f"(tolerance {LOGITS_TOL:g}, bf16); kernels compiled "
+        f"(interpret=False): {kernel}")
+    require(err <= LOGITS_TOL, err)
 
 
 def phase_serve(params, cfg, seed: int, max_len: int,
@@ -464,13 +452,7 @@ def phase_serve(params, cfg, seed: int, max_len: int,
     reqs = _requests(cfg, seed)
     srv = build_server(params, cfg, slots=SLOTS, max_len=max_len,
                        paged=POOL_BLOCKS, block_len=BLOCK_LEN)
-    _serve("PagedDecodeServer, Pallas paged attention", srv, reqs, clock)
-    del srv
-    srv = build_server(params, cfg, slots=SLOTS, max_len=max_len,
-                       pallas=True)
-    require(srv.cache_attn is not None, "--pallas set a cache_attn")
-    _serve("DecodeServer --pallas, Pallas decode attention", srv, reqs,
-           clock)
+    _serve("DecodeServer, Pallas paged attention", srv, reqs, clock)
     del srv
     _compare_logits(params, cfg, seed, max_len)
 

@@ -2,9 +2,9 @@
 
 The inference-serving walkthrough: weights lazy-load through the
 O_DIRECT engine (parallel/weights.py), requests with different prompts
-and budgets share fixed slots (models/serving.py), and every step
-advances all active requests — freed slots admit queued work
-immediately.
+and budgets share the slots and the paged K/V pool of one decode server
+(models/serving.py), and every step advances all active requests — freed
+slots admit queued work immediately.
 
     python examples/serve.py --weights conv/ \
         --request 1,2,3:16 --request 7,8:32 --request 5:8
@@ -46,23 +46,13 @@ def load_weights(weights_dir: str, engine):
 
 
 def build_server(params, cfg, *, slots: int, max_len: int,
-                 paged: int = 0, block_len: int = 128,
-                 pallas: bool = False):
-    """The decode server ``main`` serves from: a shared paged KV pool of
-    ``paged`` blocks (Pallas paged attention), else fixed slots with the
-    fused decode-attention kernel (``pallas``) or XLA dense attention."""
-    from nvme_strom_tpu.models.serving import (DecodeServer,
-                                               PagedDecodeServer)
-    if paged:
-        return PagedDecodeServer(params, cfg, max_batch=slots,
-                                 max_len=max_len, total_blocks=paged,
-                                 block_len=block_len)
-    cache_attn = None
-    if pallas:
-        from nvme_strom_tpu.ops.decode_attention import make_decode_attn
-        cache_attn = make_decode_attn()
+                 paged: int = 0, block_len: int = 128):
+    """The decode server ``main`` serves from: ``slots`` slots over a
+    shared KV pool of ``paged`` blocks of ``block_len`` positions; 0 is the
+    pool the server works out (every slot's worst case)."""
+    from nvme_strom_tpu.models.serving import DecodeServer
     return DecodeServer(params, cfg, max_batch=slots, max_len=max_len,
-                        cache_attn=cache_attn)
+                        total_blocks=paged or None, block_len=block_len)
 
 
 def main(argv=None) -> int:
@@ -86,15 +76,12 @@ def main(argv=None) -> int:
                     help="nucleus truncation (with --temperature > 0)")
     ap.add_argument("--seed", type=int, default=0,
                     help="base sampling seed; request i uses seed+i")
-    ap.add_argument("--pallas", action="store_true",
-                    help="use the fused decode-attention kernel "
-                         "(wins past ~1k live positions)")
     ap.add_argument("--paged", type=int, default=0, metavar="BLOCKS",
-                    help="serve from a shared KV pool of BLOCKS blocks "
-                         "(paged attention; capacity = total live "
-                         "tokens, not slots×max-len)")
+                    help="size of the shared KV pool in blocks (capacity "
+                         "= total live tokens; default: every slot's "
+                         "worst case, slots × ceil(max-len / block-len))")
     ap.add_argument("--block-len", type=int, default=128,
-                    help="positions per pool block for --paged")
+                    help="positions per pool block")
     ap.add_argument("--lookahead", type=int, default=1,
                     help="decode steps per host readback (8-16 "
                          "amortizes a high-latency host<->device link; "
@@ -104,13 +91,9 @@ def main(argv=None) -> int:
         ap.error("at least one --request")
     if args.slots < 1:
         ap.error(f"--slots must be >= 1, got {args.slots}")
-    if args.paged:
-        # pure-argument conditions fail BEFORE the expensive weight load
-        if args.pallas:
-            ap.error("--paged always uses its own paged-attention "
-                     "kernel; drop --pallas")
-        if args.paged < 1 or args.block_len < 1:
-            ap.error("--paged and --block-len must be >= 1")
+    # pure-argument conditions fail BEFORE the expensive weight load
+    if args.paged < 0 or args.block_len < 1:
+        ap.error("--paged must be >= 0 and --block-len >= 1")
 
     from nvme_strom_tpu.io import StromEngine
     from nvme_strom_tpu.utils.compile_cache import enable_compile_cache
@@ -160,8 +143,7 @@ def main(argv=None) -> int:
           f"{time.monotonic() - t0:.2f}s", flush=True)
 
     srv = build_server(params, cfg, slots=args.slots, max_len=max_len,
-                       paged=args.paged, block_len=args.block_len,
-                       pallas=args.pallas)
+                       paged=args.paged, block_len=args.block_len)
     for i, (rid, ids, max_new) in enumerate(reqs):
         srv.submit(rid, ids, max_new, eos_id=args.eos_id,
                    temperature=args.temperature, top_p=args.top_p,

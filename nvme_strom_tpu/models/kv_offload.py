@@ -809,7 +809,7 @@ def offloaded_prefill(params: Dict, tokens, cfg: TransformerConfig,
 # decode-class batched read path (io/plan.py + io/sched.py) and pinned
 # hot in the host-DRAM tier (io/hostcache.py) so a popular prefix costs
 # one prefill fleet-wide and one NVMe read per cold restore.
-# models/serving.py's DecodeServer/PagedDecodeServer drive it at
+# models/serving.py's DecodeServer drives it at
 # admission; docs/PERF.md §5 documents knobs, counters, and policy.
 
 

@@ -1,32 +1,31 @@
-"""Continuous batching: a decode server over fixed slots.
+"""Continuous batching: one decode server over a paged K/V pool.
 
 Serving completes the inference stack the way PG-Strom completes the
 reference's storage stack (SURVEY.md §3.5 — the consumer that turns a
 data path into a product).  Requests arrive at arbitrary times with
-arbitrary prompt lengths; the server packs them into a fixed-slot
-batch, admits new work the moment a slot frees, and every decode step
-advances EVERY active slot — no head-of-line blocking on the longest
-request.
+arbitrary prompt lengths; the server packs them into a fixed number of
+slots, admits new work the moment a slot frees and the pool has the
+request's blocks, and every decode step advances EVERY active slot — no
+head-of-line blocking on the longest request.
 
 TPU-first shape: the batch step is one jitted program with static
-shapes.  Per-slot sequence positions are data (a ``(B,)`` vector), not
-shapes: cache writes scatter to per-row positions, attention masks by
-``pos[b]``, RoPE takes per-row positions (transformer._rope's 2-D
-form).  One compiled step program serves every mix of request states.
-The paged step moves nothing of the K/V pool's size: its new rows go into
-the donated pool where they lie and the kernel reads the 5-D pool by layer
-index (ops/paged_attention.py ``write_rows`` / ``paged_attention``).
+shapes (``_paged_step``).  Per-slot sequence positions are data (a
+``(B,)`` vector), not shapes: cache writes go to per-row (block, offset)
+targets, attention masks by ``pos[b]``, RoPE takes per-row positions
+(transformer._rope's 2-D form).  One compiled step program serves every
+mix of request states.  The step moves nothing of the K/V pool's size:
+its new rows go into the donated pool where they lie and the kernel reads
+the 5-D pool by layer index (ops/paged_attention.py ``write_rows`` /
+``paged_attention``).
 
-Admission is one compiled program too (``_serve_prefill`` for the dense
-slots, ``_paged_prefill`` for the block pool): the cached prefix gathered
-into a dense cache, ``decode.block_step`` over the right-padded suffix,
-and the new KV rows written into the donated slot cache / pool blocks.
-It is keyed on shapes alone — (padded suffix length, dense cache length):
-multiples of ``block_len`` in the paged server, the power-of-two bucket
-(or the store's page multiple) in the dense one.  The true last row, the
-slot and the block ids are data, so prompts of 97 and 128 tokens share
-one program; ``timings["prefill_programs"]`` counts the shapes a server
-has used.  The call returns at dispatch: the logits stay on the device.
+Admission is one compiled program too (``_paged_prefill``): the cached
+prefix gathered into a dense cache, ``decode.block_step`` over the
+right-padded suffix, and the new KV rows written into the donated pool's
+blocks.  It is keyed on shapes alone — (padded suffix length, dense cache
+length), both multiples of ``block_len``.  The true last row, the slot
+and the block ids are data, so prompts of 97 and 128 tokens share one
+program; ``timings["prefill_programs"]`` counts the shapes a server has
+used.  The call returns at dispatch: the logits stay on the device.
 
 Per-request decoding params: ``max_new``, ``eos_id``, and sampling —
 ``temperature``/``top_p``/``seed`` are per-SLOT vectors (data, like the
@@ -154,7 +153,7 @@ def _gather_prefix(k_pool, v_pool, blks):
 
 def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
                   k_head, v_head, last, ssm=None):
-    """The admission prefill, traced inside both servers' programs:
+    """The admission prefill, traced inside ``_paged_prefill``:
     ``block_step`` of the right-padded suffix ``tokens`` (1, m) behind
     the cached prefix ``k_head``/``v_head`` ((L, 1, nkv, c, hd), or None
     when nothing is cached — block_step at pos 0 IS the dense prefill,
@@ -182,27 +181,10 @@ def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
     return logits, cache["k"], cache["v"], cache.get("ssm")
 
 
-@functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2, 3))
-def _serve_prefill(params: Dict, cfg: TransformerConfig, k_cache,
-                   v_cache, tokens, k_head, v_head, slot, last):
-    """One dense-slot admission: ``_prefill_rows``, then the request's
-    (L, 1, nkv, S, hd) KV placed at the head of row ``slot`` of the
-    donated slot caches.  ``slot`` and ``last`` are data: the program is
-    keyed on (tokens, head) shapes only.  Returns (logits, k_cache,
-    v_cache)."""
-    logits, k, v, _ = _prefill_rows(params, cfg, tokens, k_head, v_head,
-                                    last)
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0, 0))
-    return logits, k_cache, v_cache
-
-
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2, 3, 7))
 def _paged_prefill(params: Dict, cfg: TransformerConfig, k_pool, v_pool,
                    tokens, blks, last, state=None, slot=None):
-    """One block-pool admission: ``blks`` (n,) are the pool blocks of
+    """One admission: ``blks`` (n,) are the pool blocks of
     the whole padded prompt, ``tokens`` (1, m) its suffix past the
     cached blocks — so the first ``n - m // block_len`` of ``blks`` are
     gathered as the prefix (``_gather_prefix``), ``_prefill_rows`` runs
@@ -242,29 +224,45 @@ def _paged_prefill(params: Dict, cfg: TransformerConfig, k_pool, v_pool,
     return logits, k_pool, v_pool, state
 
 
-def _batched_step_body(params: Dict, cfg: TransformerConfig, tok, pos,
-                       write_and_attend, recur=None):
-    """Shared per-step transformer wiring of the batched servers.
+def paged_logits(params: Dict, cfg: TransformerConfig, tok,
+                 k_pool, v_pool, blk, off, table, pos, state=None,
+                 sidx=None):
+    """The decode step of every slot at its OWN position against the
+    shared block pool, before sampling: (logits (B, vocab) f32, k_pool,
+    v_pool, state) — ``state`` the recurrent layers' pool after the step,
+    None for a plain decoder.
 
-    ``write_and_attend(i, q, k, v) -> (B, nh, 1, hd)`` owns the cache
-    write + attention for its storage layout (contiguous per-slot rows
-    or a block-table pool); ``i`` counts the attention layers.
-    ``recur(j, h, L) -> (B, 1, d)`` is the mixer of the j-th recurrent
-    layer against its storage (the paged server's state pool)."""
+    blk/off (B,) int32: each slot's write target (block id in the pool,
+    row offset inside it); table (B, max_blocks) int32 + pos (B,) feed
+    the paged-attention kernel.  state/sidx: the recurrent layers' pool
+    (``models/ssm.init_state``) and each slot's row of it — a free slot's
+    is the sacrificial last row, as its ``blk`` is the trash block."""
+    from nvme_strom_tpu.ops.paged_attention import (paged_attention,
+                                                    write_rows)
     B = tok.shape[0]
+    if state is not None:
+        s_pools, tails = list(state["s"]), list(state["conv"])
     x = embed_tokens(params, cfg, tok[:, None])               # (B,1,d)
     positions = pos.astype(jnp.float32)[:, None]              # (B,1)
-    ai = mi = 0
+    ai = mi = 0             # attention layers / recurrent layers so far
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
         h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
         if cfg.is_mamba_layer(i):
-            a = recur(mi, h, L)
+            a, s_pools[mi], tails[mi] = _ssm.mamba_step(
+                h, params, L, cfg, s_pools[mi], tails[mi], sidx)
             mi += 1
         else:
             q, k, v = qkv_project(h, params, L, cfg, positions=positions)
             with jax.named_scope("strom.attn.paged"):
-                a = write_and_attend(ai, q, k, v)
+                # the new rows go into the (donated) pools where they lie
+                # and the kernel reads layer ai of them in place: nothing
+                # pool-sized moves
+                k_pool, v_pool = write_rows(
+                    k_pool, v_pool, k[:, :, 0], v[:, :, 0], blk, off,
+                    layer=ai)
+                a = paged_attention(q, k_pool, v_pool, table, pos,
+                                    layer=ai, scale=cfg.attn_scale)
             a = a.transpose(0, 2, 1, 3).reshape(B, 1, -1)
             a = a @ wmat(params, L + "wo", a.dtype)
             ai += 1
@@ -274,126 +272,80 @@ def _batched_step_body(params: Dict, cfg: TransformerConfig, tok, pos,
             f = _mlp_block(h, params, L, cfg)
         x = add_residual(x, f, cfg).astype(cfg.dtype)
     x = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
-    return lm_logits(params, cfg, x)
-
-
-def serve_logits(params: Dict, cfg: TransformerConfig, tok,
-                 k_cache, v_cache, pos, cache_attn=None):
-    """The decode step of every slot at its OWN position, before
-    sampling: (logits (B, vocab) f32, k_cache, v_cache).
-
-    ``cache_attn`` swaps the attention inner for the fused Pallas kernel
-    (ops/decode_attention supports the (B,) per-row pos form); None is
-    the dense XLA path — the reference the kernels' logits are compared
-    with (chip_smoke.py)."""
-    B = tok.shape[0]
-    rows = jnp.arange(B)
-    limit = pos[:, None]                                      # (B,1)
-    caches = {"k": k_cache, "v": v_cache}
-
-    def write_and_attend(i, q, k, v):
-        # per-row scatter: row b writes its kv at its own pos[b]
-        caches["k"] = caches["k"].at[i, rows, :, pos, :].set(
-            k[:, :, 0].astype(caches["k"].dtype))
-        caches["v"] = caches["v"].at[i, rows, :, pos, :].set(
-            v[:, :, 0].astype(caches["v"].dtype))
-        if cache_attn is not None:
-            return cache_attn(q, caches["k"][i], caches["v"][i], pos)
-        return _dec.cache_attention(q, caches["k"][i], caches["v"][i],
-                                    limit, cfg)
-
-    logits = _batched_step_body(params, cfg, tok, pos,
-                                write_and_attend)
-    return logits, caches["k"], caches["v"]
-
-
-@functools.partial(jax.jit, static_argnums=(1, 9),
-                   donate_argnums=(3, 4))
-def _serve_step(params: Dict, cfg: TransformerConfig, tok,
-                k_cache, v_cache, pos, temps, top_ps, seeds,
-                cache_attn=None):
-    """One decode step for every slot at its OWN position.
-
-    tok (B,) int32, pos (B,) int32 → (next_tok (B,), k_cache,
-    v_cache).  Free slots compute too, but their frozen-pos writes land
-    in rows the next admission overwrites and the host ignores their
-    outputs — one compiled program for every batch mix.
-    """
-    logits, k_cache, v_cache = serve_logits(
-        params, cfg, tok, k_cache, v_cache, pos, cache_attn)
-    nxt = _sample_slots(logits, temps, top_ps, seeds, pos)
-    return nxt, k_cache, v_cache
-
-
-def paged_logits(params: Dict, cfg: TransformerConfig, tok,
-                 k_pool, v_pool, blk, off, table, pos, state=None,
-                 sidx=None):
-    """The decode step against the shared block pool, before sampling:
-    (logits (B, vocab) f32, k_pool, v_pool) — and the state pool after
-    them when ``cfg`` has recurrent layers.
-
-    blk/off (B,) int32: each slot's write target (block id in the pool,
-    row offset inside it); table (B, max_blocks) int32 + pos (B,) feed
-    the paged-attention kernel.  state/sidx: the recurrent layers' pool
-    (``models/ssm.init_state``) and each slot's row of it — a free slot's
-    is the sacrificial last row, as its ``blk`` is the trash block."""
-    from nvme_strom_tpu.ops.paged_attention import (paged_attention,
-                                                    write_rows)
-    pools = {"k": k_pool, "v": v_pool}
-
-    def write_and_attend(i, q, k, v):
-        # the new rows go into the (donated) pools where they lie and the
-        # kernel reads layer i of them in place: nothing pool-sized moves
-        pools["k"], pools["v"] = write_rows(
-            pools["k"], pools["v"], k[:, :, 0], v[:, :, 0], blk, off,
-            layer=i)
-        return paged_attention(q, pools["k"], pools["v"], table, pos,
-                               layer=i, scale=cfg.attn_scale)
-
-    if state is None:
-        logits = _batched_step_body(params, cfg, tok, pos,
-                                    write_and_attend)
-        return logits, pools["k"], pools["v"]
-    s_pools, tails = list(state["s"]), list(state["conv"])
-
-    def recur(j, h, L):
-        out, s_pools[j], tails[j] = _ssm.mamba_step(
-            h, params, L, cfg, s_pools[j], tails[j], sidx)
-        return out
-
-    logits = _batched_step_body(params, cfg, tok, pos, write_and_attend,
-                                recur)
-    return (logits, pools["k"], pools["v"],
-            {"s": tuple(s_pools), "conv": tuple(tails)})
+    if state is not None:
+        state = {"s": tuple(s_pools), "conv": tuple(tails)}
+    return lm_logits(params, cfg, x), k_pool, v_pool, state
 
 
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(3, 4, 12))
 def _paged_step(params: Dict, cfg: TransformerConfig, tok,
                 k_pool, v_pool, blk, off, table, pos, temps, top_ps,
                 seeds, state=None, sidx=None):
-    """One decode step against the shared block pool: ``paged_logits``
-    then the per-slot sampler.  Returns (next_tok, k_pool, v_pool, state);
-    ``state`` (donated, every layer's array updated in place) is None
-    for a plain decoder, whose program this leaves as it was."""
-    logits, k_pool, v_pool, *rest = paged_logits(
+    """One decode step for every slot: ``paged_logits`` then the per-slot
+    sampler.  tok, pos (B,) int32 → (next_tok (B,), k_pool, v_pool,
+    state); ``state`` (donated, every layer's array updated in place) is
+    None for a plain decoder.  Free slots compute too, but their writes
+    land in the trash block (and the sacrificial state row) and the host
+    ignores their outputs — one compiled program for every batch mix."""
+    logits, k_pool, v_pool, state = paged_logits(
         params, cfg, tok, k_pool, v_pool, blk, off, table, pos, state, sidx)
     nxt = _sample_slots(logits, temps, top_ps, seeds, pos)
-    return nxt, k_pool, v_pool, (rest[0] if rest else None)
+    return nxt, k_pool, v_pool, state
 
 
 class DecodeServer:
-    """Fixed-slot continuous-batching decode server.
+    """Continuous batching over a SHARED block pool (paged attention).
 
     ``submit`` enqueues (optionally with per-request ``temperature``/
     ``top_p``/``seed`` — greedy by default); ``step`` admits waiting
     requests into free slots, advances every active slot one token,
     and returns requests that finished this step ({request_id: token
     list}).  ``run`` drains everything.
+
+    Capacity is ``total_blocks × block_len`` tokens across ALL slots —
+    sized for expected live tokens, so short requests stop paying for the
+    longest one's reservation.  Each request reserves its worst case
+    (``ceil((prompt+max_new)/block)``) at admission, so an admitted
+    request can never starve mid-decode; when the pool is exhausted,
+    requests simply wait in the queue.  Both sizes have defaults the
+    server works out: ``block_len`` is the store's page when a
+    ``kv_store`` is given (pages scatter 1:1 into blocks), else 128;
+    ``total_blocks`` is ``max_batch × ceil(max_len / block_len)`` — every
+    slot's worst case, so a caller who names no pool gets fixed slots:
+    admission never waits for a block.  Attention runs the
+    scalar-prefetch Pallas kernel (ops/paged_attention.py) — the block
+    indirection never materializes a gathered cache copy in HBM.
+
+    Automatic PREFIX CACHING (``prefix_cache=True``): full prompt
+    blocks register under chain hashes; a request whose prompt shares
+    the chain reuses those blocks read-only and prefills only its
+    suffix — the shared-system-prompt win.  refs==0 entries stay
+    resident as LRU-evictable and are reclaimed under pool pressure
+    before admission refuses.
     """
 
     def __init__(self, params: Dict, cfg: TransformerConfig,
-                 max_batch: int, max_len: int, cache_attn="auto",
-                 kv_store=None, shed_probe=None):
+                 max_batch: int, max_len: int,
+                 total_blocks: Optional[int] = None,
+                 block_len: Optional[int] = None,
+                 prefix_cache: bool = True, kv_store=None,
+                 shed_probe=None):
+        if block_len is None:
+            block_len = kv_store.page_tokens if kv_store is not None else 128
+        if block_len < 1 or (total_blocks is not None and total_blocks < 1):
+            raise ValueError("block_len and total_blocks must be >= 1")
+        if total_blocks is None:
+            total_blocks = max_batch * -(-max_len // block_len)
+        if kv_store is not None and kv_store.page_tokens != block_len:
+            # store pages scatter 1:1 into pool blocks; a mismatch
+            # would need a re-chunking copy on every restore
+            raise ValueError(
+                f"kv_store.page_tokens ({kv_store.page_tokens}) must "
+                f"equal block_len ({block_len})")
+        self.block_len = block_len
+        self.total_blocks = total_blocks
+        self.max_blocks = -(-max_len // block_len)
+        self.prefix_cache = prefix_cache
         #: elastic cold-start (docs/RESILIENCE.md "Elastic cold-start"):
         #: ``params`` may be a demand-faulting source (anything with a
         #: ``materialize()`` — parallel/weights.py FaultingCheckpoint)
@@ -421,7 +373,16 @@ class DecodeServer:
         self.B = max_batch
         self.max_len = max_len
         if cfg.mamba_layers:
-            self._check_recurrent(kv_store)
+            # pages without the state at their boundary are not a prefix,
+            # in the store as in the HBM prefix cache (_req_keys)
+            if kv_store is not None:
+                cfg.require_no_recurrent("a kv_store (PrefixStore)")
+            shardings = {getattr(w, "sharding", None)
+                         for w in (params or {}).values()
+                         if not isinstance(w, dict)}
+            if any(len(getattr(sh, "device_set", ())) > 1
+                   for sh in shardings):
+                cfg.require_no_recurrent("a mesh (sharded params)")
         #: load-shedding probe (docs/RESILIENCE.md "failure domains"):
         #: a callable returning True while new prefill admissions should
         #: DEFER (requests wait queued; in-flight decode continues;
@@ -449,20 +410,6 @@ class DecodeServer:
         #: each serve step batches EVERY admitting slot's due page
         #: reads into one decode-class plan_and_submit.
         self.kv_store = kv_store
-        # cache_attn: None = XLA dense; a callable (e.g.
-        # ops.decode_attention.make_decode_attn()) = that kernel;
-        # "auto" (default) = the fused Pallas kernel on TPU when
-        # max_len clears the measured ~1k-position crossover
-        # (config-6: XLA wins at S≈160, the kernel is ~1.7x at
-        # S≈1856), dense everywhere else — CPU/virtual-mesh behavior
-        # is unchanged.
-        if cache_attn == "auto":
-            cache_attn = None
-            if max_len >= 1024 and jax.default_backend() == "tpu":
-                from nvme_strom_tpu.ops.decode_attention import (
-                    make_decode_attn)
-                cache_attn = make_decode_attn()
-        self.cache_attn = cache_attn
         self.pos = jnp.zeros((max_batch,), jnp.int32)
         self.tok = jnp.zeros((max_batch,), jnp.int32)
         # per-slot decoding params (DATA, not shapes: any greedy/
@@ -528,992 +475,6 @@ class DecodeServer:
         #: recent decode TTFTs per tenant (the per-tenant SLO lane's
         #: p99 window, fed to SloGovernor.observe_tenant at retire)
         self._tenant_ttft: Dict[str, List[float]] = {}
-        self._alloc_storage()
-
-    def _check_recurrent(self, kv_store) -> None:
-        """What a config with recurrent layers may be served from: the
-        paged server overrides.  The dense slots gain no twin of its
-        state pool (ROADMAP C5)."""
-        self.cfg.require_no_recurrent("the dense DecodeServer")
-
-    def _alloc_storage(self) -> None:
-        cfg = self.cfg
-        L, nkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-        shape = (L, self.B, nkv, self.max_len, hd)
-        self.k_cache = jnp.zeros(shape, cfg.dtype)
-        self.v_cache = jnp.zeros(shape, cfg.dtype)
-
-    # -- intake -----------------------------------------------------------
-
-    def submit(self, rid, prompt_ids: List[int], max_new: int,
-               eos_id: Optional[int] = None,
-               temperature: float = 0.0, top_p: float = 1.0,
-               seed: int = 0, tenant=None) -> None:
-        if not prompt_ids:
-            raise ValueError("empty prompt")
-        if max_new < 1:
-            raise ValueError(f"max_new must be >= 1, got {max_new}")
-        if temperature < 0:
-            raise ValueError(f"temperature must be >= 0, got "
-                             f"{temperature}")
-        if not 0.0 < top_p <= 1.0:
-            raise ValueError(f"top_p must be in (0, 1], got {top_p}")
-        if len(prompt_ids) + max_new > self.max_len:
-            raise ValueError(
-                f"prompt {len(prompt_ids)} + max_new {max_new} exceeds "
-                f"server max_len {self.max_len}")
-        in_flight = ({r.rid for r in self.queue}
-                     | {r.rid for r in self.slots if r is not None})
-        if rid in in_flight:
-            # results key on rid — a duplicate would silently clobber
-            raise ValueError(f"request id {rid!r} already in flight")
-        req = _Request(rid, list(prompt_ids), max_new,
-                       eos_id, temperature=temperature,
-                       top_p=top_p,
-                       seed=seed & 0xFFFFFFFF,
-                       t_submit=time.monotonic())
-        if tenant is not None and tenants_enabled():
-            # resolve (and lazily register) the tenant ONCE at submit;
-            # with STROM_TENANTS=0 the tag is ignored and the request
-            # walks the exact pre-tenant path
-            from nvme_strom_tpu.io.tenants import get_registry
-            req.tenant = get_registry().get(tenant)
-        if self._tracer().enabled:
-            from nvme_strom_tpu.utils.trace import TraceContext
-            req.trace = TraceContext.new()
-            req.t_submit_ns = time.monotonic_ns()
-        self.queue.append(req)
-
-    # -- admission (plan / restore / finish) ------------------------------
-    #
-    # Admission is split in two so ONE serve step can gather every
-    # admitting slot's due NVMe page reads into a single decode-class
-    # plan_and_submit batch (the prefix store, docs/PERF.md §5): the
-    # PLAN phase makes the capacity decisions sequentially (block
-    # allocation, HBM prefix-cache refs — exactly the old per-slot
-    # order, so admission control is unchanged), the batched restore
-    # runs between, and the FINISH phase prefills/scatters.  With no
-    # store attached the two halves compose to the old _admit verbatim.
-
-    def _tracer(self):
-        """The span sink of this server, enabled or not: the KV-store
-        engine's tracer when a store is attached (one file for the
-        whole stack), else the global tracer.  Call sites that build a
-        span's arguments by hand check ``.enabled`` first."""
-        store = self.kv_store
-        tracer = (getattr(getattr(store, "engine", None), "tracer",
-                          None) if store is not None else None)
-        if tracer is None:
-            from nvme_strom_tpu.utils import trace
-            tracer = trace.global_tracer
-        return tracer
-
-    def _span(self, name: str, ctx=None, **args):
-        """One of this server's spans (``Tracer.span``: on the JAX
-        profiler's timeline inside a profiler session, in the tracer
-        when it is on).
-        Names are fixed — docs/OBSERVABILITY.md lists them; none is one
-        of the benchmark loop's bare phase names."""
-        return self._tracer().span(name, "strom.serve", ctx, **args)
-
-    def _prefill_span(self, padded: list, suffix: list, cache_len: int,
-                      rid: str):
-        """The span of one admission's prefill call, with its tokens
-        and its program counted: ``padded`` are handed to prefill,
-        ``suffix`` of them are prompt past the cached prefix (the rest
-        pads to the compiled shape), ``cache_len`` is the dense cache
-        they run against.  The caller adds the host seconds to
-        ``timings["prefill_s"]``."""
-        self.timings["prefill_tokens"] += len(padded)
-        self.timings["prompt_tokens"] += len(suffix)
-        self._prefill_shapes.add((len(padded), cache_len))
-        self.timings["prefill_programs"] = len(self._prefill_shapes)
-        return self._span("strom.serve.prefill", tokens=len(padded),
-                          useful=len(suffix),
-                          program=f"{len(padded)}x{cache_len}", rid=rid)
-
-    def _admit(self, slot: int, req: _Request) -> None:
-        """Single-request admission (compat path; step_many batches)."""
-        self._finish_traced(self._admit_plan(slot, req), {})
-
-    def _finish_traced(self, plan: dict, restored: dict) -> None:
-        """``_admit_finish`` under the request's trace scope: the
-        admission span (prefill + scatter) lands in the request's tree,
-        and everything the finish triggers — store puts, engine writes
-        — auto-parents to it via the contextvar.  A tenant-tagged
-        request additionally finishes under its TENANT scope, so the
-        host-cache lines the prefill touches and the store pages the
-        put writes are quota-charged to their owner (io/tenants.py)."""
-        req = plan["req"]
-        if req.tenant is not None:
-            with tenant_context(req.tenant):
-                self._finish_traced_inner(plan, restored)
-        else:
-            self._finish_traced_inner(plan, restored)
-
-    def _finish_traced_inner(self, plan: dict, restored: dict) -> None:
-        req = plan["req"]
-        wait = time.monotonic() - req.t_submit
-        self.timings["admits"] += 1
-        self.timings["queue_wait_s"] += wait
-        ctx = req.trace.child() if req.trace is not None else None
-        # rid last: the profiler's encoding cuts the arguments at a ","
-        with self._span("strom.serve.admit", ctx, slot=plan["slot"],
-                        prompt_tokens=len(req.prompt),
-                        cached_blocks=plan.get("c", 0),
-                        restored_pages=len(restored),
-                        queue_wait_ms=round(1000.0 * wait, 3),
-                        rid=str(req.rid)):
-            self._admit_finish(plan, restored)
-
-    def _admit_plan(self, slot: int, req: _Request) -> dict:
-        """Capacity decisions only — nothing is prefilled yet."""
-        return {"slot": slot, "req": req}
-
-    def _store_keys(self, req: _Request) -> list:
-        """The request's prefix-store chain keys, hashed once."""
-        if self.kv_store is None:
-            return []
-        if req.store_keys is None:
-            req.store_keys = self.kv_store.chain_keys(req.prompt)
-        return req.store_keys
-
-    def _store_skip(self, plan: dict) -> int:
-        """Chain pages a CHEAPER tier already covers (the paged server's
-        in-HBM block cache); the store only restores past them."""
-        return 0
-
-    def _store_fits(self, plan: dict, n_pages: int) -> bool:
-        """Whether a restored-prefix admission cache of ``n_pages``-page
-        granularity fits this server's storage."""
-        s = len(plan["req"].prompt)
-        P = self.kv_store.page_tokens
-        return -(-s // P) * P <= self.max_len
-
-    def _restore_prefixes(self, plans: list) -> Dict[int, dict]:
-        """Batch-restore every admitting slot's store-resident pages:
-        ONE plan_and_submit under the decode class (cross-request
-        locality for the coalescing planner and the ring scheduler).
-        Returns {slot: {chain_index: (k, v) numpy pages}}."""
-        store = self.kv_store
-        wants: Dict[int, tuple] = {}
-        misses = 0
-        for plan in plans:
-            req = plan["req"]
-            keys = self._store_keys(req)
-            if not keys:
-                continue
-            skip = self._store_skip(plan)
-            matched = store.match(keys)
-            misses += len(keys) - matched
-            if matched > skip and self._store_fits(plan, matched):
-                wants[plan["slot"]] = (skip, keys[skip:matched])
-        if misses and store.stats is not None:
-            store.stats.add(kv_prefix_misses=misses)
-        if not wants:
-            return {}
-        by_slot = {p["slot"]: p["req"] for p in plans}
-        # tenant scope mirrors the trace scope below: the FIRST
-        # participating tenant owns the batched restore (exact for the
-        # single-request step; a mixed batch is one shared read either
-        # way), so the decode-class batch and the host-cache lines it
-        # fills are quota-charged to an owner instead of nobody
-        ten = next((by_slot[s].tenant for s in wants
-                    if by_slot[s].tenant is not None), None)
-        # ONE batched restore serves several admitting requests: scope
-        # it under the FIRST participating request's tree (the single-
-        # request case — the acceptance walkthrough — is exact) and
-        # name every trace id so a multi-request step stays attributable
-        traced = [by_slot[s].trace for s in wants
-                  if by_slot[s].trace is not None]
-        ctx = traced[0].child() if traced else None
-        with self._span("strom.serve.kv_restore", ctx,
-                        slots=len(wants)) as span, tenant_context(ten):
-            if span:        # something records: build the rest
-                # traces: a string, not a list — the profiler's
-                # annotation takes scalars (docs/OBSERVABILITY.md)
-                span.set_metadata(
-                    pages=sum(len(k) for _s, k in wants.values()),
-                    traces=" ".join(f"{t.trace_id:x}" for t in traced))
-            return store.restore_many(wants)
-
-    def _contiguous_from(self, restored: dict, start: int) -> list:
-        """The restored pages usable as a prefix extension: chain
-        indices ``start, start+1, ...`` without a gap."""
-        use = []
-        i = start
-        while i in restored:
-            use.append(restored[i])
-            i += 1
-        return use
-
-    def _admit_finish(self, plan: dict, restored: dict) -> None:
-        """Prefill the request (suffix-only when pages restored) into
-        its slot: one ``_serve_prefill`` call.
-
-        Without a store hit the prompt right-pads to a power-of-two
-        bucket so admission compiles once per bucket, not once per
-        prompt length; the first-token logits read at the true last
-        position.  With a hit, the restored pages head a page-granular
-        cache and only the suffix is computed."""
-        import numpy as np
-        slot, req = plan["slot"], plan["req"]
-        s = len(req.prompt)
-        rid = str(req.rid)
-        store = self.kv_store
-        use = self._contiguous_from(restored, 0) if restored else []
-        k_head = v_head = None
-        if use:
-            P = store.page_tokens
-            cache_len = -(-s // P) * P
-            suffix = req.prompt[len(use) * P:]
-            k_head = np.concatenate([k for k, _ in use], axis=2)[:, None]
-            v_head = np.concatenate([v for _, v in use], axis=2)[:, None]
-        else:
-            cache_len = 16
-            while cache_len < s:
-                cache_len *= 2
-            cache_len = min(cache_len, self.max_len)
-            suffix = req.prompt
-        padded = suffix + [0] * (cache_len - s)
-        t0 = time.monotonic()
-        with self._prefill_span(padded, suffix, cache_len, rid):
-            logits, self.k_cache, self.v_cache = _serve_prefill(
-                self.params, self.cfg, self.k_cache, self.v_cache,
-                np.asarray([padded], np.int32), k_head, v_head, slot,
-                len(suffix) - 1)
-        self.timings["prefill_s"] += time.monotonic() - t0
-        if store is not None:
-            with self._span("strom.serve.scatter", blocks=1, rid=rid):
-                self._store_put(req, slot, len(use), store.page_tokens)
-        self._admit_first_token(slot, req, logits)
-
-    def _admit_first_token(self, slot: int, req: _Request, logits) -> None:
-        """The admitted slot's first token and decoding state — all
-        dispatches, nothing read back."""
-        s = len(req.prompt)
-        with self._span("strom.serve.first_token", rid=str(req.rid)):
-            first = self._first_token(logits, req, s)
-            self._pending_first.append((slot, first))
-            self.slots[slot] = req
-            self._set_slot_params(slot, req)
-            req.t_admit = time.monotonic()
-            # pos[slot] = s - nothing decoded past the prompt yet; tok
-            # is the token entering the cache on the next step
-            self.pos = self.pos.at[slot].set(s)
-            self.tok = self.tok.at[slot].set(first)
-
-    def _kv_rows(self, slot: int, lo: int, hi: int):
-        """The slot's KV at prompt positions lo..hi, on the device:
-        (k, v), each (L, nkv, hi - lo, hd)."""
-        return (self.k_cache[:, slot, :, lo:hi],
-                self.v_cache[:, slot, :, lo:hi])
-
-    def _store_put(self, req: _Request, slot: int, have: int,
-                   P: int) -> None:
-        """Persist this admission's newly computed full prompt pages
-        (chain indices ``have..``) — written once store-wide however
-        many sessions share them (put() dedupes by content key).  The
-        device→host pull is one slice per admission; admission already
-        tolerates host work, and the write itself is async."""
-        import numpy as np
-        keys = self._store_keys(req)
-        n_full = len(keys)
-        if n_full <= have:
-            return
-        # one pull for the whole new-page range, then page slices
-        k_all, v_all = (np.asarray(a) for a in
-                        self._kv_rows(slot, have * P, n_full * P))
-        pages = [(keys[i],
-                  k_all[:, :, (i - have) * P:(i - have + 1) * P],
-                  v_all[:, :, (i - have) * P:(i - have + 1) * P])
-                 for i in range(have, n_full)]
-        self.kv_store.put(pages)
-
-    def _first_token(self, logits, req: _Request, s: int):
-        """The prefill's next token under the request's own sampling
-        params (same sampler, 1-row view; position s-1 folds in so the
-        first draw differs from the next step's).
-
-        Returns the DEVICE scalar — admission must never read back
-        (the round-4 on-silicon row spent 20.6 of 27 s in admit because
-        every ``_admit`` blocked on this value crossing the link); the
-        host copy rides ``step_many``'s single batch readback."""
-        return _sample_slots(
-            logits, jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.top_p], jnp.float32),
-            jnp.asarray([req.seed], jnp.uint32),
-            jnp.asarray([s - 1], jnp.int32))[0]
-
-    def _set_slot_params(self, slot: int, req: _Request) -> None:
-        self.temp = self.temp.at[slot].set(req.temperature)
-        self.topp = self.topp.at[slot].set(req.top_p)
-        self.seed = self.seed.at[slot].set(jnp.uint32(req.seed))
-
-    def _drain_pending_first(self) -> None:
-        """Deliver deferred first tokens while ``step_many`` unwinds
-        from an exception.
-
-        Without this, an error between admission and the batch readback
-        (e.g. a device fault mid-dispatch) leaves ``_pending_first``
-        entries alive into the NEXT call, replaying each slot's first
-        token a full batch late — after tokens generated later — so the
-        output order and the TTFT/inflight accounting are both wrong.
-        Draining here appends the first tokens in generation order
-        before anything newer can land.  Retirements go to
-        ``_finished_carry`` (returned by the next step_many) because
-        our caller's ``finished`` dict is lost to the exception.  If
-        the readback itself fails (device wedged) the entries are
-        RESTORED: late replay on a dead device beats silently dropping
-        a token from a request's output."""
-        pending, self._pending_first = self._pending_first, []
-        if not pending:
-            return
-        try:
-            first_h = jax.device_get([v for _, v in pending])
-        except Exception:
-            self._pending_first = pending
-            return
-        t_now = time.monotonic()
-        for (slot, _), v in zip(pending, first_h):
-            if self.slots[slot] is None:
-                continue
-            self.slots[slot].t_first = t_now
-            self.slots[slot].out.append(int(v))
-            ret = self._retire_or_keep(slot)
-            if ret:
-                self._finished_carry[ret[0]] = ret[1]
-
-    def _retire_or_keep(self, slot: int) -> Optional[tuple]:
-        req = self.slots[slot]
-        done_len = len(req.out) >= req.max_new
-        done_eos = req.eos_id is not None and req.out[-1] == req.eos_id
-        if done_len or done_eos:
-            self.slots[slot] = None
-            self._record_metrics(req)
-            return req.rid, req.out
-        return None
-
-    #: default per-request metric retention — generous (entries are a
-    #: few floats) but BOUNDED: a long-lived server retiring millions
-    #: of requests must not grow ``request_metrics`` without limit.
-    #: ``STROM_SERVE_METRICS_MAX`` overrides per process.
-    _METRICS_KEEP = 4096
-
-    def _record_metrics(self, req: _Request) -> None:
-        """Retire-time serving metrics: TTFT (submit → first token
-        DELIVERED at a host readback) and admission wait (submit →
-        admitted into a slot) — the observable form of the SLO story
-        (docs/PERF.md §5)."""
-        ttft_ms = (1000.0 * (req.t_first - req.t_submit)
-                   if req.t_first is not None else 0.0)
-        wait_ms = 1000.0 * (req.t_admit - req.t_submit)
-        tracer = self._tracer()
-        if tracer.enabled and req.trace is not None:
-            end_ns = time.monotonic_ns()
-            # the request's ROOT span, submit → retirement: the tree
-            # every admit/restore/queue/engine span hangs under
-            tracer.add_span("strom.serve.request", req.t_submit_ns,
-                            end_ns,
-                            category="strom.serve", ctx=req.trace,
-                            rid=str(req.rid), ttft_ms=round(ttft_ms, 3),
-                            admit_wait_ms=round(wait_ms, 3),
-                            tokens=len(req.out))
-            # critical-path attribution (obs/attrib.py): fold this
-            # request's span tree into the per-class profiles —
-            # serving requests are the decode class
-            from nvme_strom_tpu.obs.attrib import get_collector
-            col = get_collector()
-            if col is not None:
-                col.request_retired(req.trace.trace_id, req.t_submit_ns,
-                                    end_ns, klass="decode",
-                                    extra={"rid": str(req.rid),
-                                           "ttft_ms": round(ttft_ms, 3)})
-        self.request_metrics[req.rid] = {
-            "ttft_ms": round(ttft_ms, 3),
-            "admit_wait_ms": round(wait_ms, 3)}
-        while len(self.request_metrics) > self._metrics_keep:
-            self.request_metrics.pop(next(iter(self.request_metrics)))
-        agg = self._metrics_agg
-        agg["n"] += 1
-        agg["ttft_sum"] += ttft_ms
-        agg["ttft_max"] = max(agg["ttft_max"], ttft_ms)
-        agg["wait_sum"] += wait_ms
-        agg["wait_max"] = max(agg["wait_max"], wait_ms)
-        if req.tenant is not None:
-            self._observe_tenant_ttft(req.tenant, ttft_ms)
-
-    #: TTFT samples kept per tenant for the p99 window, and the fill
-    #: level before the window is trusted to call a violation
-    _TENANT_TTFT_WIN = 64
-    _TENANT_TTFT_MIN = 8
-
-    def _observe_tenant_ttft(self, tenant, ttft_ms: float) -> None:
-        """Feed the per-tenant SLO lane: a sliding TTFT window per
-        tenant; once warm, its p99 goes to the store's SloGovernor,
-        which may notch the tenant's fair-share boost (never the
-        hedge budget — kv_offload.observe_tenant)."""
-        win = self._tenant_ttft.setdefault(tenant.id, [])
-        win.append(ttft_ms)
-        if len(win) > self._TENANT_TTFT_WIN:
-            del win[0]
-        stats = self._engine_stats()
-        if stats is not None:
-            stats.add_tenant_stat(tenant.id, requests_finished=1)
-        if (tenant.slo_p99_ms <= 0 or self.kv_store is None
-                or len(win) < self._TENANT_TTFT_MIN):
-            return
-        slo = getattr(self.kv_store, "slo", None)
-        if slo is None:
-            return
-        w = sorted(win)
-        p99 = w[min(len(w) - 1, int(0.99 * len(w)))]
-        slo.observe_tenant(getattr(self.kv_store, "engine", None),
-                           tenant, p99, stats=stats)
-
-    # -- serving ----------------------------------------------------------
-
-    @property
-    def idle(self) -> bool:
-        return not self.queue and all(s is None for s in self.slots)
-
-    def stats(self) -> Dict[str, int]:
-        """Point-in-time serving gauges (the STAT_INFO discipline for
-        the inference tier): slot occupancy, queue depth, tokens
-        generated by in-flight requests, the retired requests' TTFT /
-        admission-wait aggregates (per-request values live in
-        ``request_metrics``), and the prefill programs (shapes) used."""
-        agg = self._metrics_agg
-        n = agg["n"]
-        out = {
-            "slots_total": self.B,
-            "slots_busy": sum(r is not None for r in self.slots),
-            "queued": len(self.queue),
-            "inflight_tokens": sum(len(r.out) for r in self.slots
-                                   if r is not None),
-            "requests_finished": n,
-            "ttft_ms_avg": round(agg["ttft_sum"] / n, 3) if n else 0.0,
-            "ttft_ms_max": round(agg["ttft_max"], 3),
-            "admit_wait_ms_avg": round(agg["wait_sum"] / n, 3)
-            if n else 0.0,
-            "admit_wait_ms_max": round(agg["wait_max"], 3),
-            "admissions_shed": self.admissions_shed,
-            "prefill_programs": len(self._prefill_shapes),
-        }
-        if self.tenant_sheds:     # key appears only once tenancy acted
-            out["tenant_sheds"] = dict(self.tenant_sheds)
-        if self._draining:        # and these only once a drain began
-            out["draining"] = True
-            out["admissions_deferred"] = self.admissions_deferred
-        return out
-
-    def _can_admit(self, req: _Request) -> bool:
-        return True            # dense slots carry their own reservation
-
-    def _shed_now(self) -> bool:
-        """True while new prefill admissions should defer (the engine's
-        failure domains are degraded, or the explicit probe says so)."""
-        if self._shed_probe is not None:
-            return bool(self._shed_probe())
-        store = self.kv_store
-        sup = getattr(getattr(store, "engine", None), "supervisor",
-                      None) if store is not None else None
-        if sup is None:
-            return False
-        # the serving loop is a supervision heartbeat while it sheds:
-        # with admissions deferred there may be NO other I/O left to
-        # carry the half-open probe, and tick() re-probes from the
-        # last degraded span (time-gated inside)
-        sup.tick()
-        return bool(sup.degraded())
-
-    def _engine_stats(self):
-        """The shared StatCounters behind the KV store's engine (None
-        without a store — serving counters then live on the server)."""
-        store = self.kv_store
-        return (getattr(getattr(store, "engine", None), "stats", None)
-                if store is not None else None)
-
-    def _note_shed(self, n: int) -> None:
-        self.admissions_shed += n
-        stats = self._engine_stats()
-        if stats is not None:
-            stats.add(serve_admissions_shed=n)
-
-    # -- drain & handoff (io/handoff.py, docs/RESILIENCE.md) --------------
-
-    @property
-    def draining(self) -> bool:
-        return self._draining
-
-    def begin_drain(self) -> None:
-        """Close the admission gate for the remainder of this server's
-        life (drains are forward-only, like the phase machine driving
-        them): queued prefills DEFER — they stay queued for session
-        export, nothing is dropped — while in-flight decode keeps its
-        slots and runs to completion."""
-        self._draining = True
-
-    def _note_drain_defer(self, n: int) -> None:
-        self.admissions_deferred += n
-        stats = self._engine_stats()
-        if stats is not None:
-            stats.add(handoff_deferred=n)
-
-    def export_sessions(self, limit: int = 256,
-                        pop: bool = False) -> List[dict]:
-        """Export live session state for a handoff bundle: in-flight
-        slots first (their decode progress is the expensive part), then
-        the deferred queue, up to ``limit``.  Each entry carries the
-        prompt token chain, the tokens already DELIVERED (``emitted``),
-        the remaining ``max_new`` budget, the sampling params (seeded
-        sampling is position-keyed, so the replacement's continuation
-        is token-identical), and the session's NVMe prefix-store page
-        keys so its KV restores instead of re-prefilling.
-
-        ``pop`` removes exported sessions so the retiring server can
-        reach ``idle`` — their results are now the replacement's to
-        deliver."""
-        self.cfg.require_no_recurrent("export_sessions (the hand-off "
-                                      "bundle holds K/V page keys only)")
-        out: List[dict] = []
-        taken_slots: List[int] = []
-        taken_q: List[_Request] = []
-        for i, r in enumerate(self.slots):
-            if len(out) >= limit:
-                break
-            if r is None or r.max_new - len(r.out) < 1:
-                continue          # retiring this step anyway
-            out.append(self._export_one(r, emitted=list(r.out)))
-            taken_slots.append(i)
-        for r in self.queue:
-            if len(out) >= limit:
-                break
-            out.append(self._export_one(r, emitted=[]))
-            taken_q.append(r)
-        if pop:
-            for i in taken_slots:
-                self._release_slot(i)
-                self.slots[i] = None
-            self.queue = [r for r in self.queue
-                          if r not in taken_q]
-        return out
-
-    def _export_one(self, r: _Request, emitted: List[int]) -> dict:
-        doc = {
-            "rid": r.rid, "prompt": list(r.prompt),
-            "emitted": emitted,
-            "max_new": r.max_new - len(emitted),
-            "eos_id": r.eos_id, "temperature": r.temperature,
-            "top_p": r.top_p, "seed": int(r.seed),
-            "tenant": (r.tenant.id if r.tenant is not None else None),
-            "kv_keys": [],
-        }
-        store = self.kv_store
-        if store is not None:
-            try:
-                doc["kv_keys"] = [k.hex() for k in store.chain_keys(
-                    list(r.prompt) + emitted)]
-            except Exception:
-                doc["kv_keys"] = []
-        return doc
-
-    def _release_slot(self, slot: int) -> None:
-        """Capacity the slot held beyond the dense row itself — the
-        paged server overrides to free its blocks."""
-
-    # -- multi-tenant admission (docs/RESILIENCE.md) ----------------------
-
-    def _tenant_config(self):
-        if self._tenant_cfg is None:
-            # the registry's config, not a fresh env read: an explicit
-            # tenants.configure() (tests/bench) must govern here too
-            from nvme_strom_tpu.io.tenants import get_registry
-            self._tenant_cfg = get_registry().config
-        return self._tenant_cfg
-
-    def _bucket(self, tenant) -> TokenBucket:
-        """The tenant's admission token bucket, built on first sight
-        from its own rate/burst (spec) or the STROM_TENANT_* defaults."""
-        b = self._buckets.get(tenant.id)
-        if b is None:
-            cfg = self._tenant_config()
-            rate = tenant.rate if tenant.rate > 0 else cfg.default_rate
-            burst = (tenant.burst if tenant.burst > 0
-                     else cfg.default_burst)
-            b = TokenBucket(rate, burst)
-            self._buckets[tenant.id] = b
-        return b
-
-    def _admit_tenants(self) -> list:
-        """Tier-aware admission: under backlog pressure (more queued
-        than free slots) only the BEST SLO tier present may admit this
-        step — worse tiers are shed (they stay queued, re-checked next
-        step, exactly the degraded-defer semantics) and counted per
-        tenant.  Each admission also spends a token from its tenant's
-        bucket; an empty bucket sheds that request without blocking the
-        tenants behind it.  Within the admissible set the queue stays
-        strict FIFO, and a ``_can_admit`` refusal still STOPS the scan
-        — the paged server's no-starvation order is unchanged."""
-        free = sum(s is None for s in self.slots)
-        plans: list = []
-        if not free:
-            return plans
-        pressure = len(self.queue) > free
-        best = None
-        if pressure:
-            best = min(tier_rank(r.tenant.tier) for r in self.queue
-                       if r.tenant is not None)
-        shed: Dict[str, int] = {}
-        slots = iter([s for s in range(self.B)
-                      if self.slots[s] is None])
-        i = 0
-        while free and i < len(self.queue):
-            req = self.queue[i]
-            t = req.tenant
-            if t is not None:
-                if pressure and tier_rank(t.tier) > best:
-                    shed[t.id] = shed.get(t.id, 0) + 1
-                    i += 1
-                    continue
-                if not self._bucket(t).try_take():
-                    shed[t.id] = shed.get(t.id, 0) + 1
-                    i += 1
-                    continue
-            if not self._can_admit(req):
-                break
-            plans.append(self._admit_plan(next(slots),
-                                          self.queue.pop(i)))
-            free -= 1
-        if shed:
-            self._note_tenant_shed(shed)
-        return plans
-
-    def _note_tenant_shed(self, shed: Dict[str, int]) -> None:
-        """Account one step's tenant sheds: server + engine counters,
-        the per-tenant breakdown, and the storm trigger's window."""
-        n = sum(shed.values())
-        self.admissions_shed += n
-        stats = self._engine_stats()
-        if stats is not None:
-            stats.add(tenant_admissions_shed=n)
-        for tid, k in shed.items():
-            self.tenant_sheds[tid] = self.tenant_sheds.get(tid, 0) + k
-            self._storm_window[tid] = (self._storm_window.get(tid, 0)
-                                       + k)
-            if stats is not None:
-                stats.add_tenant_stat(tid, admissions_shed=k)
-        self._maybe_storm_dump(stats)
-
-    def _maybe_storm_dump(self, stats) -> None:
-        """Flight-record a misbehaving tenant: once a tenant's sheds
-        since the last dump cross ``STROM_TENANT_STORM_SHEDS``, capture
-        the op ring under ``reason=tenant_storm`` with the per-tenant
-        breakdown — the post-mortem wants WHO stormed and who paid,
-        not just that p99 moved.  Per-reason rate limiting inside
-        flightrec keeps a sustained storm from spamming dumps."""
-        thresh = self._tenant_config().storm_sheds
-        hot = [t for t, k in self._storm_window.items() if k >= thresh]
-        if not hot:
-            return
-        for tid in hot:
-            self._storm_window[tid] = 0
-        store = self.kv_store
-        flight = (getattr(getattr(store, "engine", None), "flight",
-                          None) if store is not None else None)
-        if flight is None:
-            return
-        path = flight.dump("tenant_storm",
-                           extra={"tenants": hot,
-                                  "sheds": dict(self.tenant_sheds),
-                                  "queued": len(self.queue)})
-        # count only PUBLISHED dumps: a sustained storm re-arms the
-        # window every few steps, but per-reason rate limiting inside
-        # flightrec swallows most of those triggers
-        if path is not None and stats is not None:
-            stats.add(tenant_storm_dumps=1)
-            for tid in hot:
-                stats.add_tenant_stat(tid, storm_dumps=1)
-
-    def _run_step(self):
-        """Storage-specific batched step → next-token device array."""
-        nxt, self.k_cache, self.v_cache = _serve_step(
-            self.params, self.cfg, self.tok, self.k_cache,
-            self.v_cache, self.pos, self.temp, self.topp, self.seed,
-            self.cache_attn)
-        return nxt
-
-    def _advanced(self, active_slots: List[int]) -> None:
-        """Post-step bookkeeping hook (host-side position mirrors)."""
-
-    def _ensure_params(self) -> None:
-        """Resolve a demand-faulting param source on first use: every
-        tensor not yet resident is faulted at ``decode`` class, ahead
-        of the bulk-restore/warmup streams.  Tensors the background
-        bulk thread already landed are returned from its claim table
-        without touching NVMe again.  No-op (one attribute test) on
-        the eager path."""
-        if self.params is None and self._param_source is not None:
-            self.params = self._param_source.materialize(klass="decode")
-
-    def step(self) -> Dict[object, List[int]]:
-        """Admit → one batched decode step → retire finished."""
-        return self.step_many(1)
-
-    def step_many(self, k_steps: int) -> Dict[object, List[int]]:
-        """Admit → up to ``k_steps`` batched decode steps → ONE host
-        readback → retire finished.
-
-        The lookahead exists for high-latency links: the round-3
-        on-silicon row served 43.6 tok/s against a 6,826 tok/s decode
-        row on the same chip (verdict weak #6) because ``step()`` paid
-        a blocking device→host readback per generated token.  Here the
-        k sub-steps dispatch back to back and the (k, B) token stack
-        crosses the link once.
-
-        The tradeoff is the classic one: a request that hits EOS at
-        sub-step j keeps decoding to the batch end — its surplus
-        tokens are computed, then discarded by the host replay below.
-        Surplus steps are SAFE: each slot's sub-steps are capped at
-        its max_new remainder, so positions never pass the
-        admission-time allocation (dense rows or paged blocks), and a
-        post-EOS write touches only the slot's own rows at positions
-        the next occupant overwrites-before-attending.  Admission
-        happens once per batch, so a freed slot idles at most
-        ``k_steps - 1`` sub-steps."""
-        with self._span("strom.serve.step", k=k_steps):
-            return self._step_many(k_steps)
-
-    def _step_many(self, k_steps: int) -> Dict[object, List[int]]:
-        self._ensure_params()
-        finished: Dict[object, List[int]] = {}
-        if self._finished_carry:
-            # retirements completed by _drain_pending_first while a
-            # previous call unwound — deliver them now, exactly once
-            finished.update(self._finished_carry)
-            self._finished_carry.clear()
-        t0 = time.monotonic()
-        # plan every admission first (capacity decisions in the same
-        # sequential order as per-slot admission), batch-restore ALL
-        # their store-resident prefix pages in ONE decode-class read
-        # batch, then finish each admission — dispatch-only: the first
-        # token stays on device (in _pending_first) and retirement is
-        # decided after the batch readback below, so admission
-        # pipelines with the decode dispatches instead of paying a
-        # link round trip per request
-        with self._span("strom.serve.plan") as plan_span:
-            plans = self._plan_admissions()
-            plan_span.set_metadata(admitted=len(plans))
-        # everything from here to the batch readback runs with
-        # _pending_first possibly non-empty; an exception must not
-        # leak those entries into the next call (first tokens would
-        # replay a full batch LATE, after newer tokens) — the except
-        # path drains them in generation order before re-raising
-        pending = None
-        try:
-            restored = (self._restore_prefixes(plans)
-                        if plans and self.kv_store is not None else {})
-            for plan in plans:
-                self._finish_traced(plan, restored.get(plan["slot"], {}))
-            self.timings["admit_s"] += time.monotonic() - t0
-            active_slots = [i for i, r in enumerate(self.slots)
-                            if r is not None]
-            if not active_slots:
-                return finished
-            # steps each slot may still take: positions must never pass
-            # the s + max_new rows/blocks _admit reserved.  A deferred
-            # first token counts against max_new; a first-token EOS
-            # decodes surplus sub-steps (safe — discarded at replay,
-            # writes stay in the slot's own reservation, same invariant
-            # as mid-batch EOS).
-            pending_slots = {s for s, _ in self._pending_first}
-            left = {b: (self.slots[b].max_new - len(self.slots[b].out)
-                        - (1 if b in pending_slots else 0))
-                    for b in active_slots}
-            k_eff = max(1, min(k_steps, max(left.values())))
-            toks: List = []
-            stepped: List[List[int]] = []
-            t0 = time.monotonic()
-            with self._span("strom.serve.dispatch", steps=k_eff):
-                for j in range(k_eff):
-                    stepping = [b for b in active_slots if left[b] > j]
-                    if not stepping:
-                        break
-                    mask = jnp.asarray([left.get(b, 0) > j
-                                        for b in range(self.B)])
-                    nxt = self._run_step()
-                    # the step ingested tok at pos for every stepping
-                    # slot; exhausted slots hold position (their next
-                    # step rewrites the same row — self-overwrite, never
-                    # another slot's)
-                    self.pos = jnp.where(mask, self.pos + 1, self.pos)
-                    self.tok = jnp.where(mask, nxt, self.tok)
-                    self._advanced(stepping)
-                    toks.append(nxt)
-                    stepped.append(stepping)
-            self.timings["dispatch_s"] += time.monotonic() - t0
-            t0 = time.monotonic()
-            pending, self._pending_first = self._pending_first, []
-            with self._span("strom.serve.readback", steps=len(toks),
-                            first=len(pending)):
-                first_h, tok_h = jax.device_get((   # the ONE readback
-                    [v for _, v in pending],
-                    jnp.stack(toks) if toks else None))
-        except BaseException:
-            if pending:
-                # the batch readback itself failed AFTER the swap
-                # emptied _pending_first: re-stash the entries so the
-                # drain below still owns them — otherwise the deferred
-                # first tokens would be silently dropped, breaking
-                # _drain_pending_first's restore-on-failure contract
-                self._pending_first = pending
-            self._drain_pending_first()
-            raise
-        self.timings["readback_s"] += time.monotonic() - t0
-        self.timings["steps"] += len(toks)
-        self.timings["readbacks"] += 1
-        # replay in generation order: deferred first tokens precede
-        # this batch's sub-step tokens for their slots
-        with self._span("strom.serve.replay") as replay_span:
-            t_now = time.monotonic()
-            for (slot, _), v in zip(pending, first_h):
-                self.slots[slot].t_first = t_now  # first token DELIVERED
-                self.slots[slot].out.append(int(v))
-                ret = self._retire_or_keep(slot)
-                if ret:
-                    finished[ret[0]] = ret[1]
-            for j, stepping in enumerate(stepped):
-                for slot in stepping:
-                    if self.slots[slot] is None:
-                        continue    # retired at an earlier sub-step:
-                                    # its surplus tokens are discarded
-                    self.slots[slot].out.append(int(tok_h[j][slot]))
-                    ret = self._retire_or_keep(slot)
-                    if ret:
-                        finished[ret[0]] = ret[1]
-            replay_span.set_metadata(finished=len(finished))
-        return finished
-
-    def _plan_admissions(self) -> list:
-        """This step's admission plans (capacity decisions only), or
-        none while the gate is closed."""
-        plans = []
-        # load shedding (docs/RESILIENCE.md "failure domains"): while
-        # the engine behind the KV store is degraded, new prefills
-        # DEFER — they stay queued (re-checked every step; nothing
-        # fails) and in-flight decode keeps its slots, so the sick
-        # device serves the work it already owes instead of taking more
-        if self.queue and self._draining:
-            # drain mode (io/handoff.py): the gate is closed for NEW
-            # prefills only — queued requests hold for export to the
-            # replacement's bundle while in-flight slots run out
-            self._note_drain_defer(min(sum(s is None
-                                           for s in self.slots),
-                                       len(self.queue)))
-        elif self.queue and self._shed_now():
-            self._note_shed(min(sum(s is None for s in self.slots),
-                                len(self.queue)))
-        elif any(r.tenant is not None for r in self.queue):
-            # at least one queued request carries a tenant: tier-aware
-            # admission (sheds by tier under pressure, token buckets);
-            # an all-untagged queue — STROM_TENANTS=0 always — never
-            # reaches this branch and runs the loop below verbatim
-            plans = self._admit_tenants()
-        else:
-            for slot in range(self.B):
-                if (self.slots[slot] is None and self.queue
-                        and self._can_admit(self.queue[0])):
-                    plans.append(self._admit_plan(slot,
-                                                  self.queue.pop(0)))
-        return plans
-
-    def run(self, lookahead: int = 1) -> Dict[object, List[int]]:
-        """Drain the queue: step until every request finishes.
-
-        ``lookahead``: decode sub-steps per host readback (see
-        :meth:`step_many`) — 1 reproduces the per-token readback;
-        8-16 amortizes a high-latency link.
-
-        Raises RuntimeError instead of spinning when the queue head can
-        NEVER be admitted (e.g. a paged request whose worst case
-        exceeds the whole pool) and nothing is in flight to free
-        capacity."""
-        if lookahead < 1:
-            raise ValueError(f"lookahead must be >= 1, got {lookahead}")
-        results: Dict[object, List[int]] = {}
-        while not self.idle:
-            if (self._draining
-                    and all(s is None for s in self.slots)):
-                # only drain-deferred queue entries remain; they belong
-                # to the handoff bundle now — spinning on the closed
-                # admission gate would never converge
-                break
-            if (self.queue and all(s is None for s in self.slots)
-                    and not self._can_admit(self.queue[0])):
-                raise RuntimeError(
-                    f"request {self.queue[0].rid!r} cannot ever be "
-                    f"admitted (needs more capacity than the server "
-                    f"has) and no in-flight work can free any")
-            results.update(self.step_many(lookahead))
-        return results
-
-
-class PagedDecodeServer(DecodeServer):
-    """Continuous batching over a SHARED block pool (paged attention).
-
-    Capacity is ``total_blocks × block_len`` tokens across ALL slots —
-    sized for expected live tokens, not slots × max_len, so short
-    requests stop paying for the longest one's reservation.  Each
-    request reserves its worst case (``ceil((prompt+max_new)/block)``)
-    at admission, so an admitted request can never starve mid-decode;
-    when the pool is exhausted, requests simply wait in the queue.
-    Attention runs the scalar-prefetch Pallas kernel
-    (ops/paged_attention.py) — the block indirection never materializes
-    a gathered cache copy in HBM.
-
-    Automatic PREFIX CACHING (``prefix_cache=True``): full prompt
-    blocks register under chain hashes; a request whose prompt shares
-    the chain reuses those blocks read-only and prefills only its
-    suffix — the shared-system-prompt win.  refs==0 entries stay
-    resident as LRU-evictable and are reclaimed under pool pressure
-    before admission refuses.
-    """
-
-    def __init__(self, params: Dict, cfg: TransformerConfig,
-                 max_batch: int, max_len: int, total_blocks: int,
-                 block_len: int = 128, prefix_cache: bool = True,
-                 kv_store=None, shed_probe=None):
-        if block_len < 1 or total_blocks < 1:
-            raise ValueError("block_len and total_blocks must be >= 1")
-        if kv_store is not None and kv_store.page_tokens != block_len:
-            # store pages scatter 1:1 into pool blocks; a mismatch
-            # would need a re-chunking copy on every restore
-            raise ValueError(
-                f"kv_store.page_tokens ({kv_store.page_tokens}) must "
-                f"equal block_len ({block_len})")
-        self.block_len = block_len
-        self.total_blocks = total_blocks
-        self.prefix_cache = prefix_cache
-        # cache_attn is the DENSE servers' knob; the paged step always
-        # runs the paged-attention kernel
-        super().__init__(params, cfg, max_batch, max_len,
-                         cache_attn=None, kv_store=kv_store,
-                         shed_probe=shed_probe)
-        self.max_blocks = -(-max_len // block_len)
-
-    def _check_recurrent(self, kv_store) -> None:
-        # pages without the state at their boundary are not a prefix, in
-        # the store as in the HBM prefix cache (_req_keys)
-        if kv_store is not None:
-            self.cfg.require_no_recurrent("a kv_store (PrefixStore)")
-        shardings = {getattr(w, "sharding", None)
-                     for w in (self.params or {}).values()
-                     if not isinstance(w, dict)}
-        if any(len(getattr(sh, "device_set", ())) > 1 for sh in shardings):
-            self.cfg.require_no_recurrent("a mesh (sharded params)")
-
-    def _alloc_storage(self) -> None:
-        cfg = self.cfg
         nkv, hd = cfg.n_kv_heads, cfg.head_dim
         # K/V for the layers that attend: all of them in a plain decoder
         L = len(cfg.attn_layers)
@@ -1643,6 +604,109 @@ class PagedDecodeServer(DecodeServer):
             out.append(self.free.pop())
         return out
 
+    # -- intake -----------------------------------------------------------
+
+    def submit(self, rid, prompt_ids: List[int], max_new: int,
+               eos_id: Optional[int] = None,
+               temperature: float = 0.0, top_p: float = 1.0,
+               seed: int = 0, tenant=None) -> None:
+        if not prompt_ids:
+            raise ValueError("empty prompt")
+        if max_new < 1:
+            raise ValueError(f"max_new must be >= 1, got {max_new}")
+        if temperature < 0:
+            raise ValueError(f"temperature must be >= 0, got "
+                             f"{temperature}")
+        if not 0.0 < top_p <= 1.0:
+            raise ValueError(f"top_p must be in (0, 1], got {top_p}")
+        if len(prompt_ids) + max_new > self.max_len:
+            raise ValueError(
+                f"prompt {len(prompt_ids)} + max_new {max_new} exceeds "
+                f"server max_len {self.max_len}")
+        in_flight = ({r.rid for r in self.queue}
+                     | {r.rid for r in self.slots if r is not None})
+        if rid in in_flight:
+            # results key on rid — a duplicate would silently clobber
+            raise ValueError(f"request id {rid!r} already in flight")
+        req = _Request(rid, list(prompt_ids), max_new,
+                       eos_id, temperature=temperature,
+                       top_p=top_p,
+                       seed=seed & 0xFFFFFFFF,
+                       t_submit=time.monotonic())
+        if tenant is not None and tenants_enabled():
+            # resolve (and lazily register) the tenant ONCE at submit;
+            # with STROM_TENANTS=0 the tag is ignored and the request
+            # walks the exact pre-tenant path
+            from nvme_strom_tpu.io.tenants import get_registry
+            req.tenant = get_registry().get(tenant)
+        if self._tracer().enabled:
+            from nvme_strom_tpu.utils.trace import TraceContext
+            req.trace = TraceContext.new()
+            req.t_submit_ns = time.monotonic_ns()
+        self.queue.append(req)
+
+    # -- admission (plan / restore / finish) ------------------------------
+    #
+    # Admission is split in two so ONE serve step can gather every
+    # admitting slot's due NVMe page reads into a single decode-class
+    # plan_and_submit batch (the prefix store, docs/PERF.md §5): the
+    # PLAN phase makes the capacity decisions sequentially (block
+    # allocation, HBM prefix-cache refs — exactly the old per-slot
+    # order, so admission control is unchanged), the batched restore
+    # runs between, and the FINISH phase prefills/scatters.  With no
+    # store attached the two halves compose to the old _admit verbatim.
+
+    def _tracer(self):
+        """The span sink of this server, enabled or not: the KV-store
+        engine's tracer when a store is attached (one file for the
+        whole stack), else the global tracer.  Call sites that build a
+        span's arguments by hand check ``.enabled`` first."""
+        store = self.kv_store
+        tracer = (getattr(getattr(store, "engine", None), "tracer",
+                          None) if store is not None else None)
+        if tracer is None:
+            from nvme_strom_tpu.utils import trace
+            tracer = trace.global_tracer
+        return tracer
+
+    def _span(self, name: str, ctx=None, **args):
+        """One of this server's spans (``Tracer.span``: on the JAX
+        profiler's timeline inside a profiler session, in the tracer
+        when it is on).
+        Names are fixed — docs/OBSERVABILITY.md lists them; none is one
+        of the benchmark loop's bare phase names."""
+        return self._tracer().span(name, "strom.serve", ctx, **args)
+
+    def _finish_traced(self, plan: dict, restored: dict) -> None:
+        """``_admit_finish`` under the request's trace scope: the
+        admission span (prefill + scatter) lands in the request's tree,
+        and everything the finish triggers — store puts, engine writes
+        — auto-parents to it via the contextvar.  A tenant-tagged
+        request additionally finishes under its TENANT scope, so the
+        host-cache lines the prefill touches and the store pages the
+        put writes are quota-charged to their owner (io/tenants.py)."""
+        req = plan["req"]
+        if req.tenant is not None:
+            with tenant_context(req.tenant):
+                self._finish_traced_inner(plan, restored)
+        else:
+            self._finish_traced_inner(plan, restored)
+
+    def _finish_traced_inner(self, plan: dict, restored: dict) -> None:
+        req = plan["req"]
+        wait = time.monotonic() - req.t_submit
+        self.timings["admits"] += 1
+        self.timings["queue_wait_s"] += wait
+        ctx = req.trace.child() if req.trace is not None else None
+        # rid last: the profiler's encoding cuts the arguments at a ","
+        with self._span("strom.serve.admit", ctx, slot=plan["slot"],
+                        prompt_tokens=len(req.prompt),
+                        cached_blocks=plan.get("c", 0),
+                        restored_pages=len(restored),
+                        queue_wait_ms=round(1000.0 * wait, 3),
+                        rid=str(req.rid)):
+            self._admit_finish(plan, restored)
+
     def _admit_plan(self, slot: int, req: _Request) -> dict:
         """Capacity phase: HBM prefix-cache refs + block allocation, in
         the exact order sequential admission made them (so a later
@@ -1658,15 +722,70 @@ class PagedDecodeServer(DecodeServer):
         return {"slot": slot, "req": req, "keys": keys, "c": c,
                 "blks": shared + new_blks}
 
-    def _store_skip(self, plan: dict) -> int:
-        # pages the in-HBM block cache already serves cost one gather —
-        # cheaper than any NVMe read, so the store starts past them
-        return plan["c"]
+    def _store_keys(self, req: _Request) -> list:
+        """The request's prefix-store chain keys, hashed once."""
+        if self.kv_store is None:
+            return []
+        if req.store_keys is None:
+            req.store_keys = self.kv_store.chain_keys(req.prompt)
+        return req.store_keys
 
-    def _store_fits(self, plan: dict, n_pages: int) -> bool:
-        return True    # restored pages land in already-reserved blocks
+    def _restore_prefixes(self, plans: list) -> Dict[int, dict]:
+        """Batch-restore every admitting slot's store-resident pages:
+        ONE plan_and_submit under the decode class (cross-request
+        locality for the coalescing planner and the ring scheduler).
+        Returns {slot: {chain_index: (k, v) numpy pages}}."""
+        store = self.kv_store
+        wants: Dict[int, tuple] = {}
+        misses = 0
+        for plan in plans:
+            req = plan["req"]
+            keys = self._store_keys(req)
+            if not keys:
+                continue
+            # pages the in-HBM block cache already serves cost one gather
+            # — cheaper than any NVMe read, so the store starts past them
+            skip = plan["c"]
+            matched = store.match(keys)
+            misses += len(keys) - matched
+            if matched > skip:     # they land in already-reserved blocks
+                wants[plan["slot"]] = (skip, keys[skip:matched])
+        if misses and store.stats is not None:
+            store.stats.add(kv_prefix_misses=misses)
+        if not wants:
+            return {}
+        by_slot = {p["slot"]: p["req"] for p in plans}
+        # tenant scope mirrors the trace scope below: the FIRST
+        # participating tenant owns the batched restore (exact for the
+        # single-request step; a mixed batch is one shared read either
+        # way), so the decode-class batch and the host-cache lines it
+        # fills are quota-charged to an owner instead of nobody
+        ten = next((by_slot[s].tenant for s in wants
+                    if by_slot[s].tenant is not None), None)
+        # ONE batched restore serves several admitting requests: scope
+        # it under the FIRST participating request's tree (the single-
+        # request case — the acceptance walkthrough — is exact) and
+        # name every trace id so a multi-request step stays attributable
+        traced = [by_slot[s].trace for s in wants
+                  if by_slot[s].trace is not None]
+        ctx = traced[0].child() if traced else None
+        with self._span("strom.serve.kv_restore", ctx,
+                        slots=len(wants)) as span, tenant_context(ten):
+            if span:        # something records: build the rest
+                # traces: a string, not a list — the profiler's
+                # annotation takes scalars (docs/OBSERVABILITY.md)
+                span.set_metadata(
+                    pages=sum(len(k) for _s, k in wants.values()),
+                    traces=" ".join(f"{t.trace_id:x}" for t in traced))
+            return store.restore_many(wants)
 
     def _admit_finish(self, plan: dict, restored: dict) -> None:
+        """Restored pages into the request's blocks, then the prefill
+        (suffix-only past the cached and restored blocks): one
+        ``_paged_prefill`` call.  The prompt right-pads to a block
+        multiple, so admission compiles once per (suffix, prompt) block
+        count, not once per prompt length; the first-token logits read at
+        the true last position."""
         import numpy as np
         slot, req = plan["slot"], plan["req"]
         keys, c, blks = plan["keys"], plan["c"], plan["blks"]
@@ -1682,7 +801,9 @@ class PagedDecodeServer(DecodeServer):
         # request's own new blocks and REGISTER in the HBM cache — the
         # next same-prefix admission hits DRAM, not NVMe
         rid = str(req.rid)
-        use = self._contiguous_from(restored, c) if restored else []
+        use = []                # chain indices c, c + 1, ... without a gap
+        while c + len(use) in restored:
+            use.append(restored[c + len(use)])
         c2 = len(use)
         if use:
             with self._span("strom.serve.scatter", blocks=c2,
@@ -1710,8 +831,16 @@ class PagedDecodeServer(DecodeServer):
         n_pb = -(-s // bk)
         suffix = req.prompt[ct * bk:]
         padded = suffix + [0] * (n_pb * bk - s)
+        # padded tokens are handed to prefill, len(suffix) of them prompt
+        # past the cached prefix; the program is keyed on the two shapes
+        self.timings["prefill_tokens"] += len(padded)
+        self.timings["prompt_tokens"] += len(suffix)
+        self._prefill_shapes.add((len(padded), n_pb * bk))
+        self.timings["prefill_programs"] = len(self._prefill_shapes)
         t0 = time.monotonic()
-        with self._prefill_span(padded, suffix, n_pb * bk, rid):
+        with self._span("strom.serve.prefill", tokens=len(padded),
+                        useful=len(suffix),
+                        program=f"{len(padded)}x{n_pb * bk}", rid=rid):
             # positional, so that the state pool is donated with the rest
             recur = () if self.state is None else (self.state,
                                                    np.int32(slot))
@@ -1730,70 +859,478 @@ class PagedDecodeServer(DecodeServer):
             for i in range(ct, len(keys)):
                 self._pc_register(keys[i], blks[i])
             if self.kv_store is not None:
-                self._store_put(req, slot, ct, bk)
-        self._admit_first_token(slot, req, logits)
+                self._store_put(req, slot, ct)
+        # the slot's first token and decoding state — all dispatches,
+        # nothing read back
+        with self._span("strom.serve.first_token", rid=rid):
+            first = self._first_token(logits, req, s)
+            self._pending_first.append((slot, first))
+            self.slots[slot] = req
+            self.temp = self.temp.at[slot].set(req.temperature)
+            self.topp = self.topp.at[slot].set(req.top_p)
+            self.seed = self.seed.at[slot].set(jnp.uint32(req.seed))
+            req.t_admit = time.monotonic()
+            # pos[slot] = s - nothing decoded past the prompt yet; tok
+            # is the token entering the cache on the next step
+            self.pos = self.pos.at[slot].set(s)
+            self.tok = self.tok.at[slot].set(first)
         self._pos_h[slot] = s
 
-    def _kv_rows(self, slot: int, lo: int, hi: int):
-        bk = self.block_len
-        k, v = _gather_prefix(
+    def _store_put(self, req: _Request, slot: int, have: int) -> None:
+        """Persist this admission's newly computed full prompt pages
+        (chain indices ``have..``) — written once store-wide however
+        many sessions share them (put() dedupes by content key).  The
+        device→host pull is one slice per admission; admission already
+        tolerates host work, and the write itself is async."""
+        import numpy as np
+        keys = self._store_keys(req)
+        n_full = len(keys)
+        if n_full <= have:
+            return
+        # one pull for the whole new-page range, then page slices
+        P = self.block_len
+        k_all, v_all = (np.asarray(a[:, 0]) for a in _gather_prefix(
             self.k_pool, self.v_pool,
-            jnp.asarray(self.blocks[slot][lo // bk:hi // bk], jnp.int32))
-        return k[:, 0], v[:, 0]
+            jnp.asarray(self.blocks[slot][have:n_full], jnp.int32)))
+        pages = [(keys[i],
+                  k_all[:, :, (i - have) * P:(i - have + 1) * P],
+                  v_all[:, :, (i - have) * P:(i - have + 1) * P])
+                 for i in range(have, n_full)]
+        self.kv_store.put(pages)
+
+    def _first_token(self, logits, req: _Request, s: int):
+        """The prefill's next token under the request's own sampling
+        params (same sampler, 1-row view; position s-1 folds in so the
+        first draw differs from the next step's).
+
+        Returns the DEVICE scalar — admission must never read back
+        (the round-4 on-silicon row spent 20.6 of 27 s in admit because
+        every admission blocked on this value crossing the link); the
+        host copy rides ``step_many``'s single batch readback."""
+        return _sample_slots(
+            logits, jnp.asarray([req.temperature], jnp.float32),
+            jnp.asarray([req.top_p], jnp.float32),
+            jnp.asarray([req.seed], jnp.uint32),
+            jnp.asarray([s - 1], jnp.int32))[0]
+
+    def _drain_pending_first(self) -> None:
+        """Deliver deferred first tokens while ``step_many`` unwinds
+        from an exception.
+
+        Without this, an error between admission and the batch readback
+        (e.g. a device fault mid-dispatch) leaves ``_pending_first``
+        entries alive into the NEXT call, replaying each slot's first
+        token a full batch late — after tokens generated later — so the
+        output order and the TTFT/inflight accounting are both wrong.
+        Draining here appends the first tokens in generation order
+        before anything newer can land.  Retirements go to
+        ``_finished_carry`` (returned by the next step_many) because
+        our caller's ``finished`` dict is lost to the exception.  If
+        the readback itself fails (device wedged) the entries are
+        RESTORED: late replay on a dead device beats silently dropping
+        a token from a request's output."""
+        pending, self._pending_first = self._pending_first, []
+        if not pending:
+            return
+        try:
+            first_h = jax.device_get([v for _, v in pending])
+        except Exception:
+            self._pending_first = pending
+            return
+        t_now = time.monotonic()
+        for (slot, _), v in zip(pending, first_h):
+            if self.slots[slot] is None:
+                continue
+            self.slots[slot].t_first = t_now
+            self.slots[slot].out.append(int(v))
+            ret = self._retire_or_keep(slot)
+            if ret:
+                self._finished_carry[ret[0]] = ret[1]
+
+    def _retire_or_keep(self, slot: int) -> Optional[tuple]:
+        req = self.slots[slot]
+        done_len = len(req.out) >= req.max_new
+        done_eos = req.eos_id is not None and req.out[-1] == req.eos_id
+        if done_len or done_eos:
+            self.slots[slot] = None
+            self._release_slot(slot)
+            self._record_metrics(req)
+            return req.rid, req.out
+        return None
+
+    #: default per-request metric retention — generous (entries are a
+    #: few floats) but BOUNDED: a long-lived server retiring millions
+    #: of requests must not grow ``request_metrics`` without limit.
+    #: ``STROM_SERVE_METRICS_MAX`` overrides per process.
+    _METRICS_KEEP = 4096
+
+    def _record_metrics(self, req: _Request) -> None:
+        """Retire-time serving metrics: TTFT (submit → first token
+        DELIVERED at a host readback) and admission wait (submit →
+        admitted into a slot) — the observable form of the SLO story
+        (docs/PERF.md §5)."""
+        ttft_ms = (1000.0 * (req.t_first - req.t_submit)
+                   if req.t_first is not None else 0.0)
+        wait_ms = 1000.0 * (req.t_admit - req.t_submit)
+        tracer = self._tracer()
+        if tracer.enabled and req.trace is not None:
+            end_ns = time.monotonic_ns()
+            # the request's ROOT span, submit → retirement: the tree
+            # every admit/restore/queue/engine span hangs under
+            tracer.add_span("strom.serve.request", req.t_submit_ns,
+                            end_ns,
+                            category="strom.serve", ctx=req.trace,
+                            rid=str(req.rid), ttft_ms=round(ttft_ms, 3),
+                            admit_wait_ms=round(wait_ms, 3),
+                            tokens=len(req.out))
+            # critical-path attribution (obs/attrib.py): fold this
+            # request's span tree into the per-class profiles —
+            # serving requests are the decode class
+            from nvme_strom_tpu.obs.attrib import get_collector
+            col = get_collector()
+            if col is not None:
+                col.request_retired(req.trace.trace_id, req.t_submit_ns,
+                                    end_ns, klass="decode",
+                                    extra={"rid": str(req.rid),
+                                           "ttft_ms": round(ttft_ms, 3)})
+        self.request_metrics[req.rid] = {
+            "ttft_ms": round(ttft_ms, 3),
+            "admit_wait_ms": round(wait_ms, 3)}
+        while len(self.request_metrics) > self._metrics_keep:
+            self.request_metrics.pop(next(iter(self.request_metrics)))
+        agg = self._metrics_agg
+        agg["n"] += 1
+        agg["ttft_sum"] += ttft_ms
+        agg["ttft_max"] = max(agg["ttft_max"], ttft_ms)
+        agg["wait_sum"] += wait_ms
+        agg["wait_max"] = max(agg["wait_max"], wait_ms)
+        if req.tenant is not None:
+            self._observe_tenant_ttft(req.tenant, ttft_ms)
+
+    #: TTFT samples kept per tenant for the p99 window, and the fill
+    #: level before the window is trusted to call a violation
+    _TENANT_TTFT_WIN = 64
+    _TENANT_TTFT_MIN = 8
+
+    def _observe_tenant_ttft(self, tenant, ttft_ms: float) -> None:
+        """Feed the per-tenant SLO lane: a sliding TTFT window per
+        tenant; once warm, its p99 goes to the store's SloGovernor,
+        which may notch the tenant's fair-share boost (never the
+        hedge budget — kv_offload.observe_tenant)."""
+        win = self._tenant_ttft.setdefault(tenant.id, [])
+        win.append(ttft_ms)
+        if len(win) > self._TENANT_TTFT_WIN:
+            del win[0]
+        stats = self._engine_stats()
+        if stats is not None:
+            stats.add_tenant_stat(tenant.id, requests_finished=1)
+        if (tenant.slo_p99_ms <= 0 or self.kv_store is None
+                or len(win) < self._TENANT_TTFT_MIN):
+            return
+        slo = getattr(self.kv_store, "slo", None)
+        if slo is None:
+            return
+        w = sorted(win)
+        p99 = w[min(len(w) - 1, int(0.99 * len(w)))]
+        slo.observe_tenant(getattr(self.kv_store, "engine", None),
+                           tenant, p99, stats=stats)
+
+    # -- serving ----------------------------------------------------------
+
+    @property
+    def idle(self) -> bool:
+        return not self.queue and all(s is None for s in self.slots)
+
+    def stats(self) -> Dict[str, int]:
+        """Point-in-time serving gauges (the STAT_INFO discipline for
+        the inference tier): slot occupancy, queue depth, tokens
+        generated by in-flight requests, the retired requests' TTFT /
+        admission-wait aggregates (per-request values live in
+        ``request_metrics``), and the prefill programs (shapes) used."""
+        agg = self._metrics_agg
+        n = agg["n"]
+        out = {
+            "slots_total": self.B,
+            "slots_busy": sum(r is not None for r in self.slots),
+            "queued": len(self.queue),
+            "inflight_tokens": sum(len(r.out) for r in self.slots
+                                   if r is not None),
+            "requests_finished": n,
+            "ttft_ms_avg": round(agg["ttft_sum"] / n, 3) if n else 0.0,
+            "ttft_ms_max": round(agg["ttft_max"], 3),
+            "admit_wait_ms_avg": round(agg["wait_sum"] / n, 3)
+            if n else 0.0,
+            "admit_wait_ms_max": round(agg["wait_max"], 3),
+            "admissions_shed": self.admissions_shed,
+            "prefill_programs": len(self._prefill_shapes),
+            "blocks_total": self.total_blocks,
+            "blocks_free": len(self.free),
+            "prefix_cached_blocks": len(self._pc),
+            "prefix_evictable": len(self._pc_lru),
+            "prefix_hits": self._pc_hits,
+            "prefix_shared_blocks": self._pc_shared_blocks,
+            # the two kinds of cache: layers that keep K/V pages, and the
+            # recurrent layers' fixed state (bytes on the device, rows)
+            "kv_layers": self.k_pool.shape[0],
+        }
+        state = jax.tree_util.tree_leaves(self.state)
+        out["state_bytes"] = sum(a.nbytes for a in state)
+        out["state_slots"] = self.B + 1 if state else 0
+        if self.tenant_sheds:     # key appears only once tenancy acted
+            out["tenant_sheds"] = dict(self.tenant_sheds)
+        if self._draining:        # and these only once a drain began
+            out["draining"] = True
+            out["admissions_deferred"] = self.admissions_deferred
+        return out
 
     def _can_admit(self, req: _Request) -> bool:
         # submit() bounds prompt+max_new by max_len, so need can never
         # exceed max_blocks — only pool availability gates admission.
         # Capacity counts cached-prefix reuse (matched blocks need no
         # allocation) and LRU-evictable refs==0 cache entries (the pool
-        # reclaims them before refusing).
+        # reclaims them before refusing); without a prefix cache there
+        # are neither.
         need = -(-(len(req.prompt) + req.max_new) // self.block_len)
-        if not self.prefix_cache:
-            return len(self.free) >= need
         matched = set(self._pc_match(self._req_keys(req)))
         evictable = sum(1 for k in self._pc_lru if k not in matched)
         return (len(self.free) + evictable
                 >= need - len(matched))
 
-    def stats(self) -> Dict[str, int]:
-        out = super().stats()
-        out["blocks_total"] = self.total_blocks
-        out["blocks_free"] = len(self.free)
-        out["prefix_cached_blocks"] = len(self._pc)
-        out["prefix_evictable"] = len(self._pc_lru)
-        out["prefix_hits"] = self._pc_hits
-        out["prefix_shared_blocks"] = self._pc_shared_blocks
-        # the two kinds of cache: layers that keep K/V pages, and the
-        # recurrent layers' fixed state (bytes on the device, rows)
-        out["kv_layers"] = self.k_pool.shape[0]
-        state = jax.tree_util.tree_leaves(self.state)
-        out["state_bytes"] = sum(a.nbytes for a in state)
-        out["state_slots"] = self.B + 1 if state else 0
+    def _shed_now(self) -> bool:
+        """True while new prefill admissions should defer (the engine's
+        failure domains are degraded, or the explicit probe says so)."""
+        if self._shed_probe is not None:
+            return bool(self._shed_probe())
+        store = self.kv_store
+        sup = getattr(getattr(store, "engine", None), "supervisor",
+                      None) if store is not None else None
+        if sup is None:
+            return False
+        # the serving loop is a supervision heartbeat while it sheds:
+        # with admissions deferred there may be NO other I/O left to
+        # carry the half-open probe, and tick() re-probes from the
+        # last degraded span (time-gated inside)
+        sup.tick()
+        return bool(sup.degraded())
+
+    def _engine_stats(self):
+        """The shared StatCounters behind the KV store's engine (None
+        without a store — serving counters then live on the server)."""
+        store = self.kv_store
+        return (getattr(getattr(store, "engine", None), "stats", None)
+                if store is not None else None)
+
+    def _note_shed(self, n: int) -> None:
+        self.admissions_shed += n
+        stats = self._engine_stats()
+        if stats is not None:
+            stats.add(serve_admissions_shed=n)
+
+    # -- drain & handoff (io/handoff.py, docs/RESILIENCE.md) --------------
+
+    @property
+    def draining(self) -> bool:
+        return self._draining
+
+    def begin_drain(self) -> None:
+        """Close the admission gate for the remainder of this server's
+        life (drains are forward-only, like the phase machine driving
+        them): queued prefills DEFER — they stay queued for session
+        export, nothing is dropped — while in-flight decode keeps its
+        slots and runs to completion."""
+        self._draining = True
+
+    def _note_drain_defer(self, n: int) -> None:
+        self.admissions_deferred += n
+        stats = self._engine_stats()
+        if stats is not None:
+            stats.add(handoff_deferred=n)
+
+    def export_sessions(self, limit: int = 256,
+                        pop: bool = False) -> List[dict]:
+        """Export live session state for a handoff bundle: in-flight
+        slots first (their decode progress is the expensive part), then
+        the deferred queue, up to ``limit``.  Each entry carries the
+        prompt token chain, the tokens already DELIVERED (``emitted``),
+        the remaining ``max_new`` budget, the sampling params (seeded
+        sampling is position-keyed, so the replacement's continuation
+        is token-identical), and the session's NVMe prefix-store page
+        keys so its KV restores instead of re-prefilling.
+
+        ``pop`` removes exported sessions so the retiring server can
+        reach ``idle`` — their results are now the replacement's to
+        deliver."""
+        self.cfg.require_no_recurrent("export_sessions (the hand-off "
+                                      "bundle holds K/V page keys only)")
+        out: List[dict] = []
+        taken_slots: List[int] = []
+        taken_q: List[_Request] = []
+        for i, r in enumerate(self.slots):
+            if len(out) >= limit:
+                break
+            if r is None or r.max_new - len(r.out) < 1:
+                continue          # retiring this step anyway
+            out.append(self._export_one(r, emitted=list(r.out)))
+            taken_slots.append(i)
+        for r in self.queue:
+            if len(out) >= limit:
+                break
+            out.append(self._export_one(r, emitted=[]))
+            taken_q.append(r)
+        if pop:
+            for i in taken_slots:
+                self._release_slot(i)
+                self.slots[i] = None
+            self.queue = [r for r in self.queue
+                          if r not in taken_q]
         return out
 
-    def _retire_or_keep(self, slot: int):
-        ret = super()._retire_or_keep(slot)
-        if ret is not None:
-            # cache-registered blocks drop a ref (staying resident as
-            # evictable when it hits 0 — the next same-prefix request
-            # reuses them); private blocks go straight back to the pool
-            for blk in self.blocks[slot]:
-                if not self._pc_release(blk):
-                    self.free.append(blk)
-            self.blocks[slot] = []
-            self._table_dev = None
-        return ret
+    def _export_one(self, r: _Request, emitted: List[int]) -> dict:
+        doc = {
+            "rid": r.rid, "prompt": list(r.prompt),
+            "emitted": emitted,
+            "max_new": r.max_new - len(emitted),
+            "eos_id": r.eos_id, "temperature": r.temperature,
+            "top_p": r.top_p, "seed": int(r.seed),
+            "tenant": (r.tenant.id if r.tenant is not None else None),
+            "kv_keys": [],
+        }
+        store = self.kv_store
+        if store is not None:
+            try:
+                doc["kv_keys"] = [k.hex() for k in store.chain_keys(
+                    list(r.prompt) + emitted)]
+            except Exception:
+                doc["kv_keys"] = []
+        return doc
 
     def _release_slot(self, slot: int) -> None:
-        # a drain-time session export vacates the slot without retiring
-        # it — its pool blocks return exactly as a retirement's would
+        """The slot's blocks back to the pool, at retirement and when a
+        drain-time session export vacates it: cache-registered blocks
+        drop a ref (staying resident as evictable when it hits 0 — the
+        next same-prefix request reuses them); private blocks go straight
+        back to the free list."""
         for blk in self.blocks[slot]:
             if not self._pc_release(blk):
                 self.free.append(blk)
         self.blocks[slot] = []
         self._table_dev = None
 
+    # -- multi-tenant admission (docs/RESILIENCE.md) ----------------------
+
+    def _tenant_config(self):
+        if self._tenant_cfg is None:
+            # the registry's config, not a fresh env read: an explicit
+            # tenants.configure() (tests/bench) must govern here too
+            from nvme_strom_tpu.io.tenants import get_registry
+            self._tenant_cfg = get_registry().config
+        return self._tenant_cfg
+
+    def _bucket(self, tenant) -> TokenBucket:
+        """The tenant's admission token bucket, built on first sight
+        from its own rate/burst (spec) or the STROM_TENANT_* defaults."""
+        b = self._buckets.get(tenant.id)
+        if b is None:
+            cfg = self._tenant_config()
+            rate = tenant.rate if tenant.rate > 0 else cfg.default_rate
+            burst = (tenant.burst if tenant.burst > 0
+                     else cfg.default_burst)
+            b = TokenBucket(rate, burst)
+            self._buckets[tenant.id] = b
+        return b
+
+    def _admit_tenants(self) -> list:
+        """Tier-aware admission: under backlog pressure (more queued
+        than free slots) only the BEST SLO tier present may admit this
+        step — worse tiers are shed (they stay queued, re-checked next
+        step, exactly the degraded-defer semantics) and counted per
+        tenant.  Each admission also spends a token from its tenant's
+        bucket; an empty bucket sheds that request without blocking the
+        tenants behind it.  Within the admissible set the queue stays
+        strict FIFO, and a ``_can_admit`` refusal still STOPS the scan
+        — the pool's no-starvation order is unchanged."""
+        free = sum(s is None for s in self.slots)
+        plans: list = []
+        if not free:
+            return plans
+        pressure = len(self.queue) > free
+        best = None
+        if pressure:
+            best = min(tier_rank(r.tenant.tier) for r in self.queue
+                       if r.tenant is not None)
+        shed: Dict[str, int] = {}
+        slots = iter([s for s in range(self.B)
+                      if self.slots[s] is None])
+        i = 0
+        while free and i < len(self.queue):
+            req = self.queue[i]
+            t = req.tenant
+            if t is not None:
+                if pressure and tier_rank(t.tier) > best:
+                    shed[t.id] = shed.get(t.id, 0) + 1
+                    i += 1
+                    continue
+                if not self._bucket(t).try_take():
+                    shed[t.id] = shed.get(t.id, 0) + 1
+                    i += 1
+                    continue
+            if not self._can_admit(req):
+                break
+            plans.append(self._admit_plan(next(slots),
+                                          self.queue.pop(i)))
+            free -= 1
+        if shed:
+            self._note_tenant_shed(shed)
+        return plans
+
+    def _note_tenant_shed(self, shed: Dict[str, int]) -> None:
+        """Account one step's tenant sheds: server + engine counters,
+        the per-tenant breakdown, and the storm trigger's window."""
+        n = sum(shed.values())
+        self.admissions_shed += n
+        stats = self._engine_stats()
+        if stats is not None:
+            stats.add(tenant_admissions_shed=n)
+        for tid, k in shed.items():
+            self.tenant_sheds[tid] = self.tenant_sheds.get(tid, 0) + k
+            self._storm_window[tid] = (self._storm_window.get(tid, 0)
+                                       + k)
+            if stats is not None:
+                stats.add_tenant_stat(tid, admissions_shed=k)
+        self._maybe_storm_dump(stats)
+
+    def _maybe_storm_dump(self, stats) -> None:
+        """Flight-record a misbehaving tenant: once a tenant's sheds
+        since the last dump cross ``STROM_TENANT_STORM_SHEDS``, capture
+        the op ring under ``reason=tenant_storm`` with the per-tenant
+        breakdown — the post-mortem wants WHO stormed and who paid,
+        not just that p99 moved.  Per-reason rate limiting inside
+        flightrec keeps a sustained storm from spamming dumps."""
+        thresh = self._tenant_config().storm_sheds
+        hot = [t for t, k in self._storm_window.items() if k >= thresh]
+        if not hot:
+            return
+        for tid in hot:
+            self._storm_window[tid] = 0
+        store = self.kv_store
+        flight = (getattr(getattr(store, "engine", None), "flight",
+                          None) if store is not None else None)
+        if flight is None:
+            return
+        path = flight.dump("tenant_storm",
+                           extra={"tenants": hot,
+                                  "sheds": dict(self.tenant_sheds),
+                                  "queued": len(self.queue)})
+        # count only PUBLISHED dumps: a sustained storm re-arms the
+        # window every few steps, but per-reason rate limiting inside
+        # flightrec swallows most of those triggers
+        if path is not None and stats is not None:
+            stats.add(tenant_storm_dumps=1)
+            for tid in hot:
+                stats.add_tenant_stat(tid, storm_dumps=1)
+
     def _run_step(self):
+        """One batched decode step → next-token device array."""
         # write targets from the HOST position mirror — no device sync
         # sits in front of the step launch
         blk = jnp.asarray(
@@ -1811,6 +1348,214 @@ class PagedDecodeServer(DecodeServer):
             self.seed, *recur)
         return nxt
 
-    def _advanced(self, active_slots: List[int]) -> None:
-        for slot in active_slots:
-            self._pos_h[slot] += 1
+    def _ensure_params(self) -> None:
+        """Resolve a demand-faulting param source on first use: every
+        tensor not yet resident is faulted at ``decode`` class, ahead
+        of the bulk-restore/warmup streams.  Tensors the background
+        bulk thread already landed are returned from its claim table
+        without touching NVMe again.  No-op (one attribute test) on
+        the eager path."""
+        if self.params is None and self._param_source is not None:
+            self.params = self._param_source.materialize(klass="decode")
+
+    def step(self) -> Dict[object, List[int]]:
+        """Admit → one batched decode step → retire finished."""
+        return self.step_many(1)
+
+    def step_many(self, k_steps: int) -> Dict[object, List[int]]:
+        """Admit → up to ``k_steps`` batched decode steps → ONE host
+        readback → retire finished.
+
+        The lookahead exists for high-latency links: the round-3
+        on-silicon row served 43.6 tok/s against a 6,826 tok/s decode
+        row on the same chip (verdict weak #6) because ``step()`` paid
+        a blocking device→host readback per generated token.  Here the
+        k sub-steps dispatch back to back and the (k, B) token stack
+        crosses the link once.
+
+        The tradeoff is the classic one: a request that hits EOS at
+        sub-step j keeps decoding to the batch end — its surplus
+        tokens are computed, then discarded by the host replay below.
+        Surplus steps are SAFE: each slot's sub-steps are capped at
+        its max_new remainder, so positions never pass the
+        admission-time allocation of blocks, and a
+        post-EOS write touches only the slot's own rows at positions
+        the next occupant overwrites-before-attending.  Admission
+        happens once per batch, so a freed slot idles at most
+        ``k_steps - 1`` sub-steps."""
+        with self._span("strom.serve.step", k=k_steps):
+            return self._step_many(k_steps)
+
+    def _step_many(self, k_steps: int) -> Dict[object, List[int]]:
+        self._ensure_params()
+        finished: Dict[object, List[int]] = {}
+        if self._finished_carry:
+            # retirements completed by _drain_pending_first while a
+            # previous call unwound — deliver them now, exactly once
+            finished.update(self._finished_carry)
+            self._finished_carry.clear()
+        t0 = time.monotonic()
+        # plan every admission first (capacity decisions in the same
+        # sequential order as per-slot admission), batch-restore ALL
+        # their store-resident prefix pages in ONE decode-class read
+        # batch, then finish each admission — dispatch-only: the first
+        # token stays on device (in _pending_first) and retirement is
+        # decided after the batch readback below, so admission
+        # pipelines with the decode dispatches instead of paying a
+        # link round trip per request
+        with self._span("strom.serve.plan") as plan_span:
+            plans = self._plan_admissions()
+            plan_span.set_metadata(admitted=len(plans))
+        # everything from here to the batch readback runs with
+        # _pending_first possibly non-empty; an exception must not
+        # leak those entries into the next call (first tokens would
+        # replay a full batch LATE, after newer tokens) — the except
+        # path drains them in generation order before re-raising
+        pending = None
+        try:
+            restored = (self._restore_prefixes(plans)
+                        if plans and self.kv_store is not None else {})
+            for plan in plans:
+                self._finish_traced(plan, restored.get(plan["slot"], {}))
+            self.timings["admit_s"] += time.monotonic() - t0
+            active_slots = [i for i, r in enumerate(self.slots)
+                            if r is not None]
+            if not active_slots:
+                return finished
+            # steps each slot may still take: positions must never pass
+            # the s + max_new blocks admission reserved.  A deferred
+            # first token counts against max_new; a first-token EOS
+            # decodes surplus sub-steps (safe — discarded at replay,
+            # writes stay in the slot's own reservation, same invariant
+            # as mid-batch EOS).
+            pending_slots = {s for s, _ in self._pending_first}
+            left = {b: (self.slots[b].max_new - len(self.slots[b].out)
+                        - (1 if b in pending_slots else 0))
+                    for b in active_slots}
+            k_eff = max(1, min(k_steps, max(left.values())))
+            toks: List = []
+            stepped: List[List[int]] = []
+            t0 = time.monotonic()
+            with self._span("strom.serve.dispatch", steps=k_eff):
+                for j in range(k_eff):
+                    stepping = [b for b in active_slots if left[b] > j]
+                    if not stepping:
+                        break
+                    mask = jnp.asarray([left.get(b, 0) > j
+                                        for b in range(self.B)])
+                    nxt = self._run_step()
+                    # the step ingested tok at pos for every stepping
+                    # slot; exhausted slots hold position (their next
+                    # step rewrites the same row — self-overwrite, never
+                    # another slot's)
+                    self.pos = jnp.where(mask, self.pos + 1, self.pos)
+                    self.tok = jnp.where(mask, nxt, self.tok)
+                    for b in stepping:          # host mirror of pos
+                        self._pos_h[b] += 1
+                    toks.append(nxt)
+                    stepped.append(stepping)
+            self.timings["dispatch_s"] += time.monotonic() - t0
+            t0 = time.monotonic()
+            pending, self._pending_first = self._pending_first, []
+            with self._span("strom.serve.readback", steps=len(toks),
+                            first=len(pending)):
+                first_h, tok_h = jax.device_get((   # the ONE readback
+                    [v for _, v in pending],
+                    jnp.stack(toks) if toks else None))
+        except BaseException:
+            if pending:
+                # the batch readback itself failed AFTER the swap
+                # emptied _pending_first: re-stash the entries so the
+                # drain below still owns them — otherwise the deferred
+                # first tokens would be silently dropped, breaking
+                # _drain_pending_first's restore-on-failure contract
+                self._pending_first = pending
+            self._drain_pending_first()
+            raise
+        self.timings["readback_s"] += time.monotonic() - t0
+        self.timings["steps"] += len(toks)
+        self.timings["readbacks"] += 1
+        # replay in generation order: deferred first tokens precede
+        # this batch's sub-step tokens for their slots
+        with self._span("strom.serve.replay") as replay_span:
+            t_now = time.monotonic()
+            for (slot, _), v in zip(pending, first_h):
+                self.slots[slot].t_first = t_now  # first token DELIVERED
+                self.slots[slot].out.append(int(v))
+                ret = self._retire_or_keep(slot)
+                if ret:
+                    finished[ret[0]] = ret[1]
+            for j, stepping in enumerate(stepped):
+                for slot in stepping:
+                    if self.slots[slot] is None:
+                        continue    # retired at an earlier sub-step:
+                                    # its surplus tokens are discarded
+                    self.slots[slot].out.append(int(tok_h[j][slot]))
+                    ret = self._retire_or_keep(slot)
+                    if ret:
+                        finished[ret[0]] = ret[1]
+            replay_span.set_metadata(finished=len(finished))
+        return finished
+
+    def _plan_admissions(self) -> list:
+        """This step's admission plans (capacity decisions only), or
+        none while the gate is closed."""
+        plans = []
+        # load shedding (docs/RESILIENCE.md "failure domains"): while
+        # the engine behind the KV store is degraded, new prefills
+        # DEFER — they stay queued (re-checked every step; nothing
+        # fails) and in-flight decode keeps its slots, so the sick
+        # device serves the work it already owes instead of taking more
+        if self.queue and self._draining:
+            # drain mode (io/handoff.py): the gate is closed for NEW
+            # prefills only — queued requests hold for export to the
+            # replacement's bundle while in-flight slots run out
+            self._note_drain_defer(min(sum(s is None
+                                           for s in self.slots),
+                                       len(self.queue)))
+        elif self.queue and self._shed_now():
+            self._note_shed(min(sum(s is None for s in self.slots),
+                                len(self.queue)))
+        elif any(r.tenant is not None for r in self.queue):
+            # at least one queued request carries a tenant: tier-aware
+            # admission (sheds by tier under pressure, token buckets);
+            # an all-untagged queue — STROM_TENANTS=0 always — never
+            # reaches this branch and runs the loop below verbatim
+            plans = self._admit_tenants()
+        else:
+            for slot in range(self.B):
+                if (self.slots[slot] is None and self.queue
+                        and self._can_admit(self.queue[0])):
+                    plans.append(self._admit_plan(slot,
+                                                  self.queue.pop(0)))
+        return plans
+
+    def run(self, lookahead: int = 1) -> Dict[object, List[int]]:
+        """Drain the queue: step until every request finishes.
+
+        ``lookahead``: decode sub-steps per host readback (see
+        :meth:`step_many`) — 1 reproduces the per-token readback;
+        8-16 amortizes a high-latency link.
+
+        Raises RuntimeError instead of spinning when the queue head can
+        NEVER be admitted (e.g. a request whose worst case
+        exceeds the whole pool) and nothing is in flight to free
+        capacity."""
+        if lookahead < 1:
+            raise ValueError(f"lookahead must be >= 1, got {lookahead}")
+        results: Dict[object, List[int]] = {}
+        while not self.idle:
+            if (self._draining
+                    and all(s is None for s in self.slots)):
+                # only drain-deferred queue entries remain; they belong
+                # to the handoff bundle now — spinning on the closed
+                # admission gate would never converge
+                break
+            if (self.queue and all(s is None for s in self.slots)
+                    and not self._can_admit(self.queue[0])):
+                raise RuntimeError(
+                    f"request {self.queue[0].rid!r} cannot ever be "
+                    f"admitted (needs more capacity than the server "
+                    f"has) and no in-flight work can free any")
+            results.update(self.step_many(lookahead))
+        return results

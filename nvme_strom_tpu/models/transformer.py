@@ -150,7 +150,7 @@ class TransformerConfig:
             raise NotImplementedError(
                 f"{what} does not carry the recurrent state of mamba "
                 f"layers (layer_kinds has {len(self.mamba_layers)}); serve "
-                f"this config from PagedDecodeServer on one device, "
+                f"this config from DecodeServer on one device, "
                 f"without a kv_store, a mesh or session hand-off")
 
 

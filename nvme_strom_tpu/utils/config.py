@@ -331,8 +331,7 @@ class KVServeConfig:
     store_mb: int = field(
         default_factory=lambda: _env_int("STROM_KV_STORE_MB", 64))
     #: tokens per content-addressed page; 0 (default) adopts the
-    #: server's own granularity (PagedDecodeServer.block_len, or the
-    #: dense server's page default)
+    #: server's own granularity (DecodeServer.block_len)
     page_tokens: int = field(
         default_factory=lambda: _env_int("STROM_KV_PAGE_TOKENS", 0))
     #: decode-path restore p99 target in ms; a violation makes the SLO

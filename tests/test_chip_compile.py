@@ -287,13 +287,11 @@ def _param_specs(cfg, sharding_of):
             for name, shape in chip_smoke.tensor_specs(cfg)}
 
 
-@pytest.mark.parametrize("server", ["paged", "pallas"])
-def test_decode_step_fits_one_chip(topo, monkeypatch, server):
-    """The servers' whole jitted decode step at chip_smoke.py's widths,
-    depth and cache sizes, handed the described device and
+def test_decode_step_fits_one_chip(topo, monkeypatch):
+    """The server's whole jitted decode step at chip_smoke.py's widths,
+    depth and pool size, handed the described device and
     ``jax.eval_shape`` shapes by the test."""
     from nvme_strom_tpu.models import serving
-    from nvme_strom_tpu.ops.decode_attention import make_decode_attn
     # the kernels pick interpret mode from the default backend, which is
     # the CPU here: steer them to the compiled form
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -303,22 +301,14 @@ def test_decode_step_fits_one_chip(topo, monkeypatch, server):
     params = _param_specs(cfg, lambda name: sh)
     vec = lambda dt: _spec((B,), dt, sh)                    # noqa: E731
     sampling = (vec(jnp.float32), vec(jnp.float32), vec(jnp.uint32))
-    if server == "paged":
-        pool = _spec((L, smoke.POOL_BLOCKS + 1, NKV, smoke.BLOCK_LEN, HD),
-                     jnp.bfloat16, sh)
-        table = _spec((B, max_len // smoke.BLOCK_LEN), jnp.int32, sh)
-        lowered = serving._paged_step.lower(
-            params, cfg, vec(jnp.int32), pool, pool, vec(jnp.int32),
-            vec(jnp.int32), table, vec(jnp.int32), *sampling)
-    else:
-        cache = _spec((L, B, NKV, max_len, HD), jnp.bfloat16, sh)
-        lowered = serving._serve_step.lower(
-            params, cfg, vec(jnp.int32), cache, cache, vec(jnp.int32),
-            *sampling, make_decode_attn())
-    compiled = lowered.compile()
+    pool = _spec((L, smoke.POOL_BLOCKS + 1, NKV, smoke.BLOCK_LEN, HD),
+                 jnp.bfloat16, sh)
+    table = _spec((B, max_len // smoke.BLOCK_LEN), jnp.int32, sh)
+    compiled = serving._paged_step.lower(
+        params, cfg, vec(jnp.int32), pool, pool, vec(jnp.int32),
+        vec(jnp.int32), table, vec(jnp.int32), *sampling).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    if server == "paged":
-        assert not pool_sized_ops(compiled.as_text(), pool.shape)
+    assert not pool_sized_ops(compiled.as_text(), pool.shape)
     m = compiled.memory_analysis()
     need = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
@@ -344,7 +334,7 @@ def test_step_moves_nothing_pool_sized(topo, monkeypatch, hd):
 @pytest.mark.parametrize("suffix_blocks,blocks", [(4, 4), (1, 4)],
                          ids=["no_hit", "prefix_hit"])
 def test_prefill_program_fits_one_chip(topo, suffix_blocks, blocks):
-    """The paged server's admission program at chip_smoke.py's widths,
+    """The server's admission program at chip_smoke.py's widths,
     depth and pool: it compiles, fits, and writes the donated pools in
     place (no second copy of a pool is ever live)."""
     from nvme_strom_tpu.models import serving
@@ -366,7 +356,7 @@ def test_prefill_program_fits_one_chip(topo, suffix_blocks, blocks):
 
 
 def test_hybrid_step_updates_both_caches_in_place(topo, monkeypatch):
-    """The paged server's decode step for a hybrid at granite-4.0-h-micro's
+    """The server's decode step for a hybrid at granite-4.0-h-micro's
     widths, one period of its layer pattern (9 mamba + 1 attention), 64
     slots: every recurrent layer's state pool, conv tail and the K/V pool
     are aliased input to output — nothing pool-sized is copied."""

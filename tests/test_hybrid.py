@@ -22,8 +22,7 @@ if ROOT not in sys.path:
 from benchmark import weights_hybrid as WH                      # noqa: E402
 from benchmark.reference import granite_hybrid as ref           # noqa: E402
 from nvme_strom_tpu.models import serving                       # noqa: E402
-from nvme_strom_tpu.models.serving import (DecodeServer,        # noqa: E402
-                                           PagedDecodeServer)
+from nvme_strom_tpu.models.serving import DecodeServer        # noqa: E402
 from nvme_strom_tpu.ops.ssm import ssm_scan, ssm_update         # noqa: E402
 from nvme_strom_tpu.tools.convert_llama import config_from_hf   # noqa: E402
 
@@ -54,8 +53,8 @@ def model():
 
 def _server(model, slots=4, **kw):
     cfg, params = model
-    return PagedDecodeServer(params, cfg, max_batch=slots, max_len=64,
-                             total_blocks=32, block_len=BLOCK, **kw)
+    return DecodeServer(params, cfg, max_batch=slots, max_len=64,
+                        total_blocks=32, block_len=BLOCK, **kw)
 
 
 def _prompt(n, salt=0):
@@ -366,8 +365,6 @@ def test_what_cannot_hold_the_state_refuses(model):
     class Store:
         page_tokens = BLOCK
 
-    with pytest.raises(NotImplementedError, match="dense DecodeServer"):
-        DecodeServer(params, cfg, max_batch=2, max_len=64)
     with pytest.raises(NotImplementedError, match="kv_store"):
         _server(model, kv_store=Store())
     srv = _server(model)
@@ -379,8 +376,8 @@ def test_what_cannot_hold_the_state_refuses(model):
     sharded["layers.0.w_gate"] = jax.device_put(
         params["layers.0.w_gate"], NamedSharding(mesh, P(None, "tp")))
     with pytest.raises(NotImplementedError, match="mesh"):
-        PagedDecodeServer(sharded, cfg, max_batch=2, max_len=64,
-                          total_blocks=8, block_len=BLOCK)
+        DecodeServer(sharded, cfg, max_batch=2, max_len=64,
+                     total_blocks=8, block_len=BLOCK)
     from nvme_strom_tpu.parallel.shardings import param_specs
     with pytest.raises(NotImplementedError, match="mesh"):
         param_specs(cfg)

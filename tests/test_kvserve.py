@@ -16,7 +16,7 @@ from nvme_strom_tpu.io.engine import StromEngine
 from nvme_strom_tpu.models import decode as dec
 from nvme_strom_tpu.models.kv_offload import (PrefixStore, SloGovernor,
                                               build_prefix_store)
-from nvme_strom_tpu.models.serving import DecodeServer, PagedDecodeServer
+from nvme_strom_tpu.models.serving import DecodeServer
 from nvme_strom_tpu.models.transformer import (TransformerConfig,
                                                init_params, tiny_config)
 from nvme_strom_tpu.utils.config import EngineConfig
@@ -74,8 +74,9 @@ def test_cross_session_dedupe_same_prefix_written_once(setup, engine,
     rng = np.random.default_rng(0)
     sys_prompt = rng.integers(0, cfg.vocab, 3 * PAGE).tolist()
     store = _store(cfg, engine, tmp_path)
+    # no HBM block cache: it would serve "b" before the store is asked
     srv = DecodeServer(params, cfg, max_batch=2, max_len=64,
-                       kv_store=store)
+                       kv_store=store, prefix_cache=False)
     srv.submit("a", sys_prompt + [7, 8], 5)
     out_a = srv.run()["a"]
     assert stats.kv_pages_written == 3          # the shared pages
@@ -86,10 +87,10 @@ def test_cross_session_dedupe_same_prefix_written_once(setup, engine,
     assert stats.kv_pages_written == 3          # written exactly once
     assert stats.kv_prefix_hits == 3
     assert stats.kv_pages_restored == 3
-    # THIRD session, a DIFFERENT server (paged) over the same store
-    srv2 = PagedDecodeServer(params, cfg, max_batch=2, max_len=64,
-                             total_blocks=16, block_len=PAGE,
-                             kv_store=store)
+    # THIRD session, a DIFFERENT server (a named pool) over the same store
+    srv2 = DecodeServer(params, cfg, max_batch=2, max_len=64,
+                        total_blocks=16, block_len=PAGE,
+                        kv_store=store)
     srv2.submit("c", sys_prompt + [11, 12], 5)
     out_c = srv2.run()["c"]
     assert stats.kv_pages_written == 3          # still once, fleet-wide
@@ -114,9 +115,9 @@ def test_dedupe_counts_on_explicit_double_put(setup, engine, tmp_path):
     store.close()
 
 
-@pytest.mark.parametrize("paged", [False, True])
+@pytest.mark.parametrize("pool", ["slots", "shared"])
 def test_token_equivalence_store_on_vs_off(setup, engine, tmp_path,
-                                           paged):
+                                           pool):
     """Greedy outputs with the prefix store attached are token-identical
     to the store-less server — restored pages are bit-for-bit the KV
     the prefill would have computed."""
@@ -129,12 +130,11 @@ def test_token_equivalence_store_on_vs_off(setup, engine, tmp_path,
             for i in range(4)]
 
     def make(store):
-        if paged:
-            return PagedDecodeServer(params, cfg, max_batch=2,
-                                     max_len=64, total_blocks=16,
-                                     block_len=PAGE, kv_store=store)
+        # "slots": the pool the server works out; blocks of the store's
+        # page with a store, so PAGE for the store-less twin as well
+        kw = {"total_blocks": 16} if pool == "shared" else {}
         return DecodeServer(params, cfg, max_batch=2, max_len=64,
-                            kv_store=store)
+                            block_len=PAGE, kv_store=store, **kw)
 
     srv_off = make(None)
     for rid, p, m in reqs:
@@ -150,8 +150,8 @@ def test_token_equivalence_store_on_vs_off(setup, engine, tmp_path,
     out_on = srv_on.run()
     assert out_on == out_off
     # a fresh server over the now-warm store: its cheaper tiers are
-    # cold, so admissions RESTORE from NVMe (the paged server's first
-    # run may have served later batches from its own in-HBM blocks)
+    # cold, so admissions RESTORE from NVMe (the first server may have
+    # served later batches from its own in-HBM blocks)
     srv_on2 = make(store)
     for rid, p, m in reqs:
         srv_on2.submit(rid, p, m)
@@ -171,9 +171,9 @@ def test_paged_store_with_hbm_prefix_cache_disabled(setup, engine,
     store = _store(cfg, engine, tmp_path)
 
     def make():
-        return PagedDecodeServer(params, cfg, max_batch=1, max_len=64,
-                                 total_blocks=12, block_len=PAGE,
-                                 prefix_cache=False, kv_store=store)
+        return DecodeServer(params, cfg, max_batch=1, max_len=64,
+                            total_blocks=12, block_len=PAGE,
+                            prefix_cache=False, kv_store=store)
 
     srv = make()
     srv.submit("a", sys_prompt + [1], 4)
@@ -290,8 +290,9 @@ def test_restore_heals_through_recompute_on_corruption(setup, engine,
     rng = np.random.default_rng(21)
     sys_prompt = rng.integers(0, cfg.vocab, 2 * PAGE).tolist()
     store = _store(cfg, engine, tmp_path)
+    # no HBM block cache: "b" has to ask the store for the damaged page
     srv = DecodeServer(params, cfg, max_batch=1, max_len=64,
-                       kv_store=store)
+                       kv_store=store, prefix_cache=False)
     srv.submit("a", sys_prompt + [3], 4)
     srv.run()
     store.flush()
@@ -661,9 +662,9 @@ def test_close_gates_restore_many(setup, engine, tmp_path):
     assert store.restore_many({0: (0, [key])}) == {}
 
 
-@pytest.mark.parametrize("paged", [False, True])
+@pytest.mark.parametrize("pool", ["slots", "shared"])
 def test_store_restored_admission_runs_the_prefill_program(
-        setup, engine, tmp_path, paged, monkeypatch):
+        setup, engine, tmp_path, pool, monkeypatch):
     """An admission whose prefix comes back from the store prefills its
     suffix through the same compiled program as any other (a (suffix,
     cache) shape with suffix < cache), serves ``generate()``'s tokens,
@@ -675,12 +676,11 @@ def test_store_restored_admission_runs_the_prefill_program(
     store = _store(cfg, engine, tmp_path)
 
     def make():
-        if paged:
-            return PagedDecodeServer(params, cfg, max_batch=1, max_len=64,
-                                     total_blocks=16, block_len=PAGE,
-                                     kv_store=store)
+        # "slots": block_len and the pool worked out from the store's page
+        kw = ({"total_blocks": 16, "block_len": PAGE} if pool == "shared"
+              else {})
         return DecodeServer(params, cfg, max_batch=1, max_len=64,
-                            kv_store=store)
+                            kv_store=store, **kw)
 
     pulls = []
     to_numpy = np.asarray

@@ -708,8 +708,9 @@ def test_serving_request_trace_tree_with_store(tmp_path):
     store = PrefixStore(cfg, eng, str(tmp_path / "p.kvstore"),
                         page_tokens=PAGE,
                         capacity_bytes=64 * page_bytes)
+    # no HBM block cache: it would serve "b" before the store is asked
     srv = DecodeServer(params, cfg, max_batch=2, max_len=64,
-                       kv_store=store)
+                       kv_store=store, prefix_cache=False)
     rng = np.random.default_rng(0)
     sys_prompt = rng.integers(0, cfg.vocab, 3 * PAGE).tolist()
     srv.submit("a", sys_prompt + [7, 8], 4)

@@ -3,7 +3,7 @@ and counters", docs/OBSERVABILITY.md "With the JAX profiler").
 
 ONE profiler session for the whole file (a process holds one at a time; the
 tier-1 command's ``--dist loadfile`` keeps a file on one worker): the module
-fixture runs a tiny ``PagedDecodeServer`` with two admissions, a dense
+fixture runs a tiny ``DecodeServer`` with two admissions, a second
 ``DecodeServer`` over a prefix store (the ``kv_restore`` span) and a
 ``load_sharded`` of a two-tensor safetensors file under
 ``jax.profiler.start_trace``, reads the xplane back with
@@ -44,9 +44,9 @@ def _engine(tracer):
 
 def _serve_paged(params, cfg):
     """No store, so the server's spans go to the global tracer."""
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
-    srv = PagedDecodeServer(params, cfg, max_batch=2, max_len=64,
-                            total_blocks=16, block_len=BLOCK)
+    from nvme_strom_tpu.models.serving import DecodeServer
+    srv = DecodeServer(params, cfg, max_batch=2, max_len=64,
+                       total_blocks=16, block_len=BLOCK)
     rng = np.random.default_rng(0)
     for i, n in enumerate(PROMPT_LENS):
         srv.submit(f"r{i}", rng.integers(0, cfg.vocab, n).tolist(), 6)
@@ -69,7 +69,9 @@ def _serve_with_store(params, cfg, tracer, tmp):
     page_bytes = 2 * cfg.n_layers * cfg.n_kv_heads * page * cfg.head_dim * 4
     store = PrefixStore(cfg, eng, str(tmp / "p.kvstore"), page_tokens=page,
                         capacity_bytes=64 * page_bytes)
-    srv = DecodeServer(params, cfg, max_batch=2, max_len=64, kv_store=store)
+    # no HBM block cache: it would serve "b" before the store is asked
+    srv = DecodeServer(params, cfg, max_batch=2, max_len=64, kv_store=store,
+                       prefix_cache=False)
     shared = np.random.default_rng(1).integers(0, cfg.vocab,
                                                3 * page).tolist()
     srv.submit("a", shared + [7, 8], 2)

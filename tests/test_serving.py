@@ -31,6 +31,15 @@ def _solo(params, cfg, prompt_ids, max_new, eos_id=None):
     return out
 
 
+def _server(pool, params, cfg, **kw):
+    """Two slots of 64 positions over the pool the server works out
+    ("slots": every slot's worst case, in one block of the default 128) or
+    over an explicit, smaller one ("shared": 16 blocks of 8)."""
+    if pool == "shared":
+        kw = dict(total_blocks=16, block_len=8, **kw)
+    return DecodeServer(params, cfg, max_batch=2, max_len=64, **kw)
+
+
 def test_mixed_lengths_match_solo(setup):
     """Three requests with different prompt lengths and budgets, all
     admitted together, each matches its solo run."""
@@ -139,15 +148,14 @@ def test_validation(setup):
 def test_paged_server_matches_solo(setup):
     """Block-pool serving (paged-attention kernel) is token-identical
     to solo generate, with a pool FAR smaller than slots×max_len."""
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
     cfg, params = setup
     rng = np.random.default_rng(7)
     reqs = {f"b{i}": (rng.integers(0, cfg.vocab, n).tolist(), m)
             for i, (n, m) in enumerate([(5, 9), (11, 6), (3, 12)])}
     # worst cases: 14, 17, 15 tokens → 4+5+4 = 13 blocks of 4;
     # dense reservation would be 3 slots × 64 rows = 48 blocks
-    srv = PagedDecodeServer(params, cfg, max_batch=3, max_len=64,
-                            total_blocks=13, block_len=4)
+    srv = DecodeServer(params, cfg, max_batch=3, max_len=64,
+                       total_blocks=13, block_len=4)
     for rid, (p, m) in reqs.items():
         srv.submit(rid, p, m)
     got = srv.run()
@@ -163,15 +171,14 @@ def test_paged_server_matches_solo(setup):
 def test_paged_server_queues_on_pool_exhaustion(setup):
     """Admission control: requests wait for blocks, recycled blocks
     admit them, everything still matches solo."""
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
     cfg, params = setup
     rng = np.random.default_rng(8)
     reqs = {f"q{i}": (rng.integers(0, cfg.vocab, 6).tolist(), 6)
             for i in range(4)}
     # each request needs ceil(12/4)=3 blocks; pool of 4 → strictly one
     # in flight even though 2 slots exist
-    srv = PagedDecodeServer(params, cfg, max_batch=2, max_len=32,
-                            total_blocks=4, block_len=4)
+    srv = DecodeServer(params, cfg, max_batch=2, max_len=32,
+                       total_blocks=4, block_len=4)
     for rid, (p, m) in reqs.items():
         srv.submit(rid, p, m)
     steps = 0
@@ -187,40 +194,20 @@ def test_paged_server_queues_on_pool_exhaustion(setup):
 
 
 def test_paged_server_rejects_oversized(setup):
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
     cfg, params = setup
-    srv = PagedDecodeServer(params, cfg, max_batch=1, max_len=16,
-                            total_blocks=8, block_len=4)
+    srv = DecodeServer(params, cfg, max_batch=1, max_len=16,
+                       total_blocks=8, block_len=4)
     srv.submit("big", [1] * 8, 8)     # needs 4 blocks == max_blocks: ok
     with pytest.raises(ValueError, match="exceeds"):
         srv.submit("huge", [1] * 10, 7)   # 17 > max_len
     with pytest.raises(ValueError, match=">= 1"):
-        PagedDecodeServer(params, cfg, 1, 16, total_blocks=0)
+        DecodeServer(params, cfg, 1, 16, total_blocks=0)
     srv.run()
 
 
-def test_serving_with_pallas_kernel_matches_dense(setup):
-    """cache_attn=make_decode_attn() (per-row-pos Pallas kernel, run in
-    the interpreter on CPU) produces the same tokens as the dense step."""
-    from nvme_strom_tpu.ops.decode_attention import make_decode_attn
-    cfg, params = setup
-    rng = np.random.default_rng(5)
-    reqs = {f"p{i}": (rng.integers(0, cfg.vocab, 4 + 3 * i).tolist(), 5)
-            for i in range(3)}
-    outs = {}
-    for attn in (None, make_decode_attn(block_k=16)):
-        srv = DecodeServer(params, cfg, max_batch=3, max_len=32,
-                           cache_attn=attn)
-        for rid, (p, m) in reqs.items():
-            srv.submit(rid, p, m)
-        outs[attn is None] = srv.run()
-    assert outs[True] == outs[False]
-
-
 def test_moe_model_serves():
-    """Expert-routed models run through both servers (the dense-or-MoE
-    dispatch is shared with decode), matching solo generate."""
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
+    """Expert-routed models run through the server over both pools (the
+    dense-or-MoE dispatch is shared with decode), matching solo generate."""
     from nvme_strom_tpu.models.transformer import (
         TransformerConfig, init_params, tiny_moe_config)
     mcfg = TransformerConfig(**{**tiny_moe_config().__dict__,
@@ -230,19 +217,18 @@ def test_moe_model_serves():
     p = rng.integers(0, mcfg.vocab, 6).tolist()
     want = _solo(mparams, mcfg, p, 6)
     for make in (lambda: DecodeServer(mparams, mcfg, 2, 32),
-                 lambda: PagedDecodeServer(mparams, mcfg, 2, 32,
-                                           total_blocks=8,
-                                           block_len=4)):
+                 lambda: DecodeServer(mparams, mcfg, 2, 32,
+                                      total_blocks=8,
+                                      block_len=4)):
         srv = make()
         srv.submit("m", p, 6)
         assert srv.run()["m"] == want
 
 
 def test_server_stats_gauges(setup):
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
     cfg, params = setup
-    srv = PagedDecodeServer(params, cfg, max_batch=2, max_len=32,
-                            total_blocks=6, block_len=4)
+    srv = DecodeServer(params, cfg, max_batch=2, max_len=32,
+                       total_blocks=6, block_len=4)
     srv.submit("a", [1, 2, 3], 5)      # needs 2 blocks
     srv.submit("b", [4, 5], 5)         # needs 2 blocks
     s0 = srv.stats()
@@ -327,20 +313,18 @@ def test_sampled_requests_reproducible_and_mixed_with_greedy(setup):
 
 
 def test_paged_server_sampling(setup):
-    """The block-pool server shares the sampler: same (seed, prompt)
-    gives the dense server's sampled tokens (identical logits path)."""
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
+    """Sampling does not depend on the pool: same (seed, prompt) gives the
+    same sampled tokens from the derived pool (every slot's worst case) and
+    from a smaller shared one of other blocks."""
     cfg, params = setup
     prompt = [3, 4, 5, 6]
 
-    def run(cls, **kw):
-        srv = cls(params, cfg, max_batch=2, max_len=64, **kw)
+    def run(**kw):
+        srv = DecodeServer(params, cfg, max_batch=2, max_len=64, **kw)
         srv.submit("r", prompt, max_new=8, temperature=0.7, seed=99)
         return srv.run()["r"]
 
-    dense = run(DecodeServer)
-    paged = run(PagedDecodeServer, total_blocks=8, block_len=16)
-    assert dense == paged
+    assert run() == run(total_blocks=8, block_len=16)
 
 
 def test_submit_sampling_validation(setup):
@@ -354,21 +338,20 @@ def test_submit_sampling_validation(setup):
         srv.submit("c", [1], 2, top_p=1.5)
 
 
-# -- automatic prefix caching (PagedDecodeServer) ---------------------------
+# -- automatic prefix caching (DecodeServer) ---------------------------
 
 
 def test_prefix_cache_reuses_blocks_and_stays_exact(setup):
     """Two sequential requests sharing a long prompt prefix: the second
     admission reuses the cached blocks (stats prove it) and both
     outputs stay token-identical to solo generate."""
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
     cfg, params = setup
     rng = np.random.default_rng(21)
     sys_prompt = rng.integers(0, cfg.vocab, 12).tolist()  # 3 full blocks
     a = sys_prompt + [7, 8]
     b = sys_prompt + [9]
-    srv = PagedDecodeServer(params, cfg, max_batch=1, max_len=64,
-                            total_blocks=16, block_len=4)
+    srv = DecodeServer(params, cfg, max_batch=1, max_len=64,
+                       total_blocks=16, block_len=4)
     srv.submit("a", a, 6)
     out_a = srv.run()["a"]
     st = srv.stats()
@@ -387,12 +370,11 @@ def test_prefix_cache_block_aligned_prompt(setup):
     """A prompt that is an exact multiple of block_len: the last full
     block is deliberately NOT shared (suffix >= 1 token must prefill
     live; decode's first write must never hit a shared block)."""
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
     cfg, params = setup
     rng = np.random.default_rng(22)
     prompt = rng.integers(0, cfg.vocab, 12).tolist()   # exactly 3 blocks
-    srv = PagedDecodeServer(params, cfg, max_batch=1, max_len=64,
-                            total_blocks=12, block_len=4)
+    srv = DecodeServer(params, cfg, max_batch=1, max_len=64,
+                       total_blocks=12, block_len=4)
     srv.submit("a", prompt, 5)
     out_a = srv.run()["a"]
     assert srv.stats()["prefix_cached_blocks"] == 2    # (s-1)//bk cap
@@ -405,11 +387,10 @@ def test_prefix_cache_block_aligned_prompt(setup):
 def test_prefix_cache_eviction_under_pressure(setup):
     """Pool pressure reclaims refs==0 cached blocks (LRU) before
     refusing admission; distinct prompts still serve correctly."""
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
     cfg, params = setup
     rng = np.random.default_rng(23)
-    srv = PagedDecodeServer(params, cfg, max_batch=1, max_len=64,
-                            total_blocks=6, block_len=4)
+    srv = DecodeServer(params, cfg, max_batch=1, max_len=64,
+                       total_blocks=6, block_len=4)
     outs, refs = {}, {}
     for i in range(3):        # each needs ceil((9+6)/4)=4 of 6 blocks
         p = rng.integers(0, cfg.vocab, 9).tolist()
@@ -425,12 +406,11 @@ def test_prefix_cache_eviction_under_pressure(setup):
 def test_prefix_cache_off_switch(setup):
     """prefix_cache=False restores the round-2 behavior: no registry,
     every block returns to the free list at retirement."""
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
     cfg, params = setup
     prompt = [1, 2, 3, 4, 5, 6, 7, 8, 9]
-    srv = PagedDecodeServer(params, cfg, max_batch=1, max_len=64,
-                            total_blocks=8, block_len=4,
-                            prefix_cache=False)
+    srv = DecodeServer(params, cfg, max_batch=1, max_len=64,
+                       total_blocks=8, block_len=4,
+                       prefix_cache=False)
     srv.submit("a", prompt, 5)
     out = srv.run()["a"]
     assert out == _solo(params, cfg, prompt, 5)
@@ -444,7 +424,6 @@ def test_serving_randomized_soak(setup):
     sharing a system prompt, under a deliberately tight pool — every
     greedy request must match solo generate exactly, every run must be
     reproducible, and the pool must account every block at drain."""
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
     cfg, params = setup
     rng = np.random.default_rng(77)
     system = rng.integers(0, cfg.vocab, 9).tolist()
@@ -459,8 +438,8 @@ def test_serving_randomized_soak(setup):
         reqs.append((f"q{i}", prompt, max_new, temp, int(i * 131)))
 
     def run_all():
-        srv = PagedDecodeServer(params, cfg, max_batch=3, max_len=64,
-                                total_blocks=14, block_len=4)
+        srv = DecodeServer(params, cfg, max_batch=3, max_len=64,
+                           total_blocks=14, block_len=4)
         for rid, prompt, max_new, temp, seed in reqs:
             srv.submit(rid, prompt, max_new, temperature=temp,
                        top_p=0.9 if temp else 1.0, seed=seed)
@@ -483,15 +462,14 @@ def test_serving_randomized_soak(setup):
     assert sorted(srv.free + cached) == list(range(14))  # no leaks
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_lookahead_token_identical(setup, paged):
+@pytest.mark.parametrize("pool", ["slots", "shared"])
+def test_lookahead_token_identical(setup, pool):
     """step_many(k) (k decode sub-steps per host readback — the
     high-latency-link amortization, round-3 verdict #6) must return
     exactly what per-token stepping returns: same requests, same
     tokens, same EOS truncation — surplus sub-step tokens after a
     mid-batch EOS are discarded, never surfaced.  More requests than
     slots forces slot recycling through the lookahead path too."""
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
     cfg, params = setup
     rng = np.random.default_rng(3)
     # eos_id chosen so some requests stop early and some run out
@@ -500,16 +478,9 @@ def test_lookahead_token_identical(setup, paged):
             for i, (n, m) in enumerate(
                 [(5, 12), (9, 3), (3, 15), (7, 1), (4, 9)])}
 
-    def make():
-        if paged:
-            return PagedDecodeServer(params, cfg, max_batch=2,
-                                     max_len=64, total_blocks=16,
-                                     block_len=8)
-        return DecodeServer(params, cfg, max_batch=2, max_len=64)
-
     results = {}
     for k in (1, 4, 16):
-        srv = make()
+        srv = _server(pool, params, cfg)
         for rid, (p, m) in reqs.items():
             srv.submit(rid, p, m, eos_id=7)
         results[k] = srv.run(lookahead=k)
@@ -592,30 +563,16 @@ def test_pending_first_restored_on_readback_failure(setup, monkeypatch):
 
 # -- admission's prefill as one compiled program ---------------------------
 
-def _server(kind, params, cfg, **kw):
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
-    if kind == "paged":
-        return PagedDecodeServer(params, cfg, max_batch=2, max_len=64,
-                                 total_blocks=16, block_len=8, **kw)
-    return DecodeServer(params, cfg, max_batch=2, max_len=64, **kw)
-
-
-def _prefill_program(kind):
-    from nvme_strom_tpu.models import serving
-    return (serving._paged_prefill if kind == "paged"
-            else serving._serve_prefill)
-
-
-@pytest.mark.parametrize("kind,prompt_lens,shared,programs", [
-    # no hit: the bucket of 16 (dense) / two blocks of 8 (paged)
-    ("dense", (9, 16), 0, {(16, 16)}),
-    ("paged", (9, 16), 0, {(16, 16)}),
+@pytest.mark.parametrize("pool,prompt_lens,shared,programs", [
+    # no hit: one block of 128 (slots) / two blocks of 8 (shared)
+    ("slots", (9, 16), 0, {(128, 128)}),
+    ("shared", (9, 16), 0, {(16, 16)}),
     # HBM prefix-cache hit: the second prompt shares two full blocks and
     # prefills its last block only, against the same 24-row cache
-    ("paged", (20, 19), 16, {(24, 24), (8, 24)}),
+    ("shared", (20, 19), 16, {(24, 24), (8, 24)}),
 ])
 def test_served_tokens_match_generate_through_the_prefill_program(
-        setup, kind, prompt_lens, shared, programs):
+        setup, pool, prompt_lens, shared, programs):
     """Greedy tokens out of the compiled admission are ``generate()``'s,
     and the program is keyed on (padded suffix, cache) lengths alone:
     prompts of different lengths inside one bucket build ONE program —
@@ -625,9 +582,10 @@ def test_served_tokens_match_generate_through_the_prefill_program(
     head = rng.integers(0, cfg.vocab, shared).tolist()
     prompts = [head + rng.integers(0, cfg.vocab, n - shared).tolist()
                for n in prompt_lens]
-    fn = _prefill_program(kind)
+    from nvme_strom_tpu.models import serving
+    fn = serving._paged_prefill
     fn.clear_cache()
-    srv = _server(kind, params, cfg)
+    srv = _server(pool, params, cfg)
     for i, p in enumerate(prompts):
         srv.submit(i, p, 5)
         assert srv.run()[i] == _solo(params, cfg, p, 5)
@@ -637,20 +595,20 @@ def test_served_tokens_match_generate_through_the_prefill_program(
     if shared:
         assert srv.stats()["prefix_hits"] == 1
     # a second server of the same shapes compiles nothing new
-    srv = _server(kind, params, cfg)
+    srv = _server(pool, params, cfg)
     srv.submit("again", prompts[0], 2)
     srv.run()
     assert fn._cache_size() == len(programs)
     assert srv.timings["prefill_programs"] == 1
 
 
-@pytest.mark.parametrize("kind", ["dense", "paged"])
-def test_admission_reads_nothing_back(setup, kind, monkeypatch):
+@pytest.mark.parametrize("pool", ["slots", "shared"])
+def test_admission_reads_nothing_back(setup, pool, monkeypatch):
     """A store-less admission is dispatches only: no ``device_get`` and
     no host conversion of any device array (the logits stay on the
     device; the first token rides ``step_many``'s one readback)."""
     cfg, params = setup
-    srv = _server(kind, params, cfg)
+    srv = _server(pool, params, cfg)
     srv.submit("warm", [1, 2, 3], 2)        # compile outside the guard
     srv.run()
     pulled = []
@@ -676,6 +634,136 @@ def test_admission_reads_nothing_back(setup, kind, monkeypatch):
             srv._finish_traced(plan, {})
     assert not pulled and len(srv._pending_first) == 1
     assert srv.run()["r"] == _solo(params, cfg, [5, 6, 7, 8, 9], 4)
+
+
+# -- one server: the pool it works out (PR 28) -------------------------------
+
+def _Page(page_tokens):
+    """A prefix store as far as the constructor looks."""
+    import types
+    return types.SimpleNamespace(page_tokens=page_tokens)
+
+
+@pytest.mark.parametrize("max_len,kw,page,block_len,total_blocks", [
+    (64, {}, None, 128, 3 * 1),                 # no store: blocks of 128
+    (100, {"block_len": 16}, None, 16, 3 * 7),  # ceil(100 / 16) a slot
+    (64, {}, 8, 8, 3 * 8),                      # the store's page is the block
+    (64, {"total_blocks": 5}, 8, 8, 5),         # a named pool stays as named
+    (64, {"block_len": 8}, 8, 8, 3 * 8),
+], ids=["default", "block_len", "store_page", "named_pool", "page_agrees"])
+def test_pool_sizes_are_worked_out(setup, max_len, kw, page, block_len,
+                                   total_blocks):
+    """``block_len`` and ``total_blocks`` left out are worked out, not
+    options: the store's page (else 128), and every slot's worst case — the
+    capacity fixed slots had."""
+    cfg, params = setup
+    store = None if page is None else _Page(page)
+    srv = DecodeServer(params, cfg, max_batch=3, max_len=max_len,
+                       kv_store=store, **kw)
+    assert (srv.block_len, srv.total_blocks) == (block_len, total_blocks)
+    assert srv.max_blocks == -(-max_len // block_len)
+    assert srv.k_pool.shape[1:4:2] == (total_blocks + 1, block_len)
+    st = srv.stats()
+    assert (st["blocks_total"], st["blocks_free"]) == (total_blocks,) * 2
+
+
+def test_pool_sizes_that_cannot_hold_refuse(setup):
+    cfg, params = setup
+    with pytest.raises(ValueError, match="must equal block_len"):
+        DecodeServer(params, cfg, 2, 64, block_len=16, kv_store=_Page(8))
+    with pytest.raises(ValueError, match=">= 1"):
+        DecodeServer(params, cfg, 2, 64, total_blocks=0)
+    with pytest.raises(ValueError, match=">= 1"):
+        DecodeServer(params, cfg, 2, 64, block_len=0)
+
+
+@pytest.mark.parametrize("shared_head", [0, 16], ids=["distinct", "shared"])
+def test_derived_pool_never_defers_for_blocks(setup, shared_head):
+    """A server that was given no pool admits ``max_batch`` worst-case
+    requests (prompt + budget = max_len) at once, and every later step
+    admits as many queued requests as it has free slots: admission never
+    waits for a block, with prompts that share cached blocks or not."""
+    cfg, params = setup
+    rng = np.random.default_rng(28)
+    head = rng.integers(0, cfg.vocab, shared_head).tolist()
+    reqs = {i: head + rng.integers(0, cfg.vocab, 30 - shared_head).tolist()
+            for i in range(7)}
+    srv = DecodeServer(params, cfg, max_batch=3, max_len=40, block_len=8)
+    assert srv.total_blocks == 3 * 5
+    for i, p in reqs.items():
+        srv.submit(i, p, 10 if i % 2 else 3)     # 30 + 10 = max_len
+    got, steps = {}, 0
+    while not srv.idle:
+        due = min(len(srv.queue), sum(r is None for r in srv.slots))
+        before = srv.timings["admits"]
+        got.update(srv.step_many(2))
+        assert srv.timings["admits"] - before == due
+        steps += 1
+        assert steps < 100
+    assert srv.timings["admits"] == len(reqs)
+    for i, p in reqs.items():
+        assert got[i] == _solo(params, cfg, p, 10 if i % 2 else 3), i
+    cached = [e["blk"] for e in srv._pc.values()]
+    assert sorted(srv.free + cached) == list(range(15))     # none leaked
+
+
+def test_build_server_without_a_pool_serves_generates_tokens(setup):
+    """``examples/serve.build_server(paged=0)``: the one class over the
+    pool it works out, serving ``generate()``'s greedy tokens."""
+    from examples.serve import build_server
+    cfg, params = setup
+    srv = build_server(params, cfg, slots=2, max_len=48, paged=0,
+                       block_len=16)
+    assert type(srv) is DecodeServer
+    assert (srv.block_len, srv.total_blocks) == (16, 2 * 3)
+    named = build_server(params, cfg, slots=2, max_len=48, paged=4,
+                         block_len=16)
+    assert (named.block_len, named.total_blocks) == (16, 4)
+    rng = np.random.default_rng(5)
+    reqs = {f"p{i}": (rng.integers(0, cfg.vocab, 4 + 3 * i).tolist(), 5)
+            for i in range(3)}
+    for rid, (p, m) in reqs.items():
+        srv.submit(rid, p, m)
+    got = srv.run(lookahead=2)
+    for rid, (p, m) in reqs.items():
+        assert got[rid] == _solo(params, cfg, p, m), rid
+
+
+def test_serve_example_runs_without_paged(tmp_path, capsys):
+    """``examples/serve.py`` with no ``--paged`` end to end, from a
+    converted checkpoint directory: the tokens are ``generate()``'s on the
+    weights as loaded, and an explicit pool serves the same."""
+    import json
+
+    from examples import serve
+    from nvme_strom_tpu.formats.safetensors import write_safetensors
+    from nvme_strom_tpu.tools.convert_llama import strom_config_dict
+    cfg = tiny_config()
+    params = init_params(jax.random.key(4), cfg)
+    write_safetensors(str(tmp_path / "model.safetensors"),
+                      {k: np.asarray(v) for k, v in params.items()})
+    with open(tmp_path / "strom_config.json", "w") as f:
+        json.dump(strom_config_dict(cfg), f)
+    argv = ["--weights", str(tmp_path), "--slots", "2", "--max-len", "32",
+            "--request", "5,6,7:8", "--request", "9,1:5",
+            "--request", "3:4"]
+
+    def served(extra):
+        assert serve.main(argv + extra) == 0
+        out = capsys.readouterr().out
+        assert "served 3 requests / 17 tokens" in out
+        return {ln.split(":")[0]: [int(t) for t in
+                                   ln.split(":")[1].split(",")]
+                for ln in out.splitlines() if ln[:1] == "r"}
+
+    got = served([])
+    cfg = serve.read_config(str(tmp_path))
+    for rid, p, m in (("r0", [5, 6, 7], 8), ("r1", [9, 1], 5),
+                      ("r2", [3], 4)):
+        assert got[rid] == _solo(params, cfg, p, m), rid
+    assert served(["--paged", "3", "--block-len", "16"]) == got
+    with pytest.raises(SystemExit):
+        serve.main(argv + ["--pallas"])         # the flag is gone
 
 
 # -- the decode step writes the pool in place (PR 27) ------------------------
@@ -735,15 +823,14 @@ def test_paged_server_serves_what_it_served_before_the_in_place_step(kind):
     admissions hit the prefix cache (a hybrid has no prefix reuse), free
     slots write the trash block meanwhile — token for token what the
     scatter-and-slice step served."""
-    from nvme_strom_tpu.models.serving import PagedDecodeServer
     params, cfg, bk, blocks = _pr26_model(kind)
     rng = np.random.default_rng(27)
     shared = rng.integers(0, cfg.vocab, 3 * bk + 1).tolist()
     reqs = [(f"r{i}", shared + rng.integers(0, cfg.vocab, n).tolist(), m)
             for i, (n, m) in enumerate([(2, 9), (5, 6), (1, 11), (7, 5),
                                         (3, 8)])]
-    srv = PagedDecodeServer(params, cfg, max_batch=2, max_len=64,
-                            total_blocks=blocks, block_len=bk)
+    srv = DecodeServer(params, cfg, max_batch=2, max_len=64,
+                       total_blocks=blocks, block_len=bk)
     for rid, prompt, budget in reqs:
         srv.submit(rid, prompt, budget)
     assert srv.run(lookahead=2) == SERVED_AT_PR26[kind]

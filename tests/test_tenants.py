@@ -428,6 +428,7 @@ def test_tenant_storm_flight_dump(setup, tmp_path):
     flight = FlightRecorder(FlightConfig(dir=str(tmp_path)),
                             stats=stats)
     srv = _server(setup, kv_store=types.SimpleNamespace(
+        page_tokens=16,
         engine=types.SimpleNamespace(flight=flight, stats=stats)))
     srv._note_tenant_shed({"noisy": 3})
     assert stats.snapshot()["tenant_storm_dumps"] == 0   # under threshold
