@@ -236,10 +236,14 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     row offset inside it); table (B, max_blocks) int32 + pos (B,) feed
     the paged-attention kernel.  state/sidx: the recurrent layers' pool
     (``models/ssm.init_state``) and each slot's row of it — a free slot's
-    is the sacrificial last row, as its ``blk`` is the trash block."""
+    is the sacrificial last row, as its ``blk`` is the trash block (the
+    pool's last)."""
     from nvme_strom_tpu.ops.paged_attention import (paged_attention,
                                                     write_rows)
     B = tok.shape[0]
+    # a free slot keeps its last pos over a table row of zeros; nobody
+    # reads its output, so to the kernel its history is one row
+    attn_pos = jnp.where(blk == k_pool.shape[1] - 1, 0, pos)
     if state is not None:
         s_pools, tails = list(state["s"]), list(state["conv"])
     x = embed_tokens(params, cfg, tok[:, None])               # (B,1,d)
@@ -261,7 +265,7 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
                 k_pool, v_pool = write_rows(
                     k_pool, v_pool, k[:, :, 0], v[:, :, 0], blk, off,
                     layer=ai)
-                a = paged_attention(q, k_pool, v_pool, table, pos,
+                a = paged_attention(q, k_pool, v_pool, table, attn_pos,
                                     layer=ai, scale=cfg.attn_scale)
             a = a.transpose(0, 2, 1, 3).reshape(B, 1, -1)
             a = a @ wmat(params, L + "wo", a.dtype)
@@ -444,13 +448,18 @@ class DecodeServer:
         #: for a plain decoder) and ``prefill_programs``
         #: (distinct (suffix, cache) shapes this server has prefilled
         #: with, each one compiled or fetched program: a handful on a
-        #: healthy server, a climbing count is a shape leak)
+        #: healthy server, a climbing count is a shape leak).  Per decode
+        #: step, from the host's position mirror: ``attn_blocks_live``
+        #: (Σ over active slots of ``pos // block_len + 1``, the table
+        #: entries paged attention has to read) and ``attn_blocks_table``
+        #: (``B × max_blocks``, the entries it would walk unbounded)
         self.timings: Dict[str, float] = {
             "admit_s": 0.0, "dispatch_s": 0.0, "readback_s": 0.0,
             "steps": 0, "readbacks": 0,
             "admits": 0, "queue_wait_s": 0.0, "prefill_s": 0.0,
             "prefill_tokens": 0, "prompt_tokens": 0,
-            "prefill_programs": 0, "scan_tokens": 0}
+            "prefill_programs": 0, "scan_tokens": 0,
+            "attn_blocks_live": 0, "attn_blocks_table": 0}
         self._prefill_shapes: set = set()
         #: per-request serving metrics of RETIRED requests ({rid:
         #: {"ttft_ms", "admit_wait_ms"}}, newest last, bounded) plus
@@ -1072,6 +1081,10 @@ class DecodeServer:
             # the two kinds of cache: layers that keep K/V pages, and the
             # recurrent layers' fixed state (bytes on the device, rows)
             "kv_layers": self.k_pool.shape[0],
+            # table entries paged attention had to read, and those an
+            # unbounded walk would have, summed over the decode steps
+            "attn_blocks_live": self.timings["attn_blocks_live"],
+            "attn_blocks_table": self.timings["attn_blocks_table"],
         }
         state = jax.tree_util.tree_leaves(self.state)
         out["state_bytes"] = sum(a.nbytes for a in state)
@@ -1338,6 +1351,10 @@ class DecodeServer:
               if self.blocks[b] else self._trash)
              for b in range(self.B)], jnp.int32)
         off = self.pos % self.block_len
+        self.timings["attn_blocks_live"] += sum(
+            self._pos_h[b] // self.block_len + 1
+            for b in range(self.B) if self.slots[b] is not None)
+        self.timings["attn_blocks_table"] += self.B * self.max_blocks
         recur = () if self.state is None else (
             self.state,
             jnp.asarray([b if self.slots[b] is not None else self.B

@@ -7,17 +7,30 @@ its history.  Capacity is sized for the TOTAL live tokens, not
 slots × max_len — heterogeneous requests stop paying for the longest
 one's reservation.
 
-Kernel shape: the block table and per-slot positions ride scalar
-prefetch (``pltpu.PrefetchScalarGridSpec``), so each grid step's K/V
-BlockSpec ``index_map`` dereferences ``table[b, j]`` and the DMA fetches
-exactly that pool block — the indirection costs nothing extra over the
-contiguous-cache kernel (ops/decode_attention.py), and no gathered copy
-of the cache ever materializes in HBM.  Everything else is the same
-fused position-masked online softmax at kv-head width.
+Kernel shape (``strom_paged_attn``): the block table and per-slot
+positions ride scalar prefetch (``pltpu.PrefetchScalarGridSpec``), so each
+grid step's K/V BlockSpec ``index_map`` dereferences ``table[b, j]`` and
+the DMA fetches exactly that pool block — the indirection costs nothing
+extra over the contiguous-cache kernel (ops/decode_attention.py), and no
+gathered copy of the cache ever materializes in HBM.  Everything else is
+the same fused position-masked online softmax, in float32.
 
-Padding-table entries may point anywhere (block 0 convention): their
-columns sit past ``pos`` and are masked; their V rows are zeroed before
-use so garbage cannot ride a 0·NaN.
+The grid is ``(slots, blocks)`` and walks only what is live.  One grid
+step covers EVERY KV head of one pool block — for a fixed layer and block
+the heads lie next to each other in the pool, so K and V come in one DMA
+each (256 KiB at 8 heads of 128 under block 128) and scores and ``p·v``
+are one ``dot_general`` batched over the heads; a grid step costs its
+fixed ~0.3 us whatever it carries, so the heads share it.  The block axis
+is as long as the batch's LONGEST slot (``max(pos) // block + 1``, a
+dynamic grid bound computed on the device: data, not a compiled shape),
+and a shorter slot's steps past its own last block do nothing: the index
+map holds the block index where it is (an unchanged index fetches
+nothing) and the body runs under ``pl.when``.  Table entries past a
+slot's last live block are never dereferenced.
+
+Within a slot's last block the rows past ``pos`` (and whatever block a
+caller's table names there) may hold garbage: their columns are masked,
+and their V rows are zeroed before use so garbage cannot ride a 0·NaN.
 
 The pool of EVERY layer is one array ``(layers, blocks, kv_heads, block,
 d)`` and both kernels here take it whole, with a static layer index in
@@ -73,10 +86,12 @@ def _kernel_view(pool, lanes: bool):
 
 
 def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale, block_k, n_blocks, tok):
+                  m_ref, l_ref, acc_ref, *, scale, block_k, tok):
+    """One (slot, table entry) step: every KV head of the entry's pool
+    block at once.  Entries past the slot's last live block do nothing."""
     bi = pl.program_id(0)
-    ji = pl.program_id(2)
-    g = q_ref.shape[2]
+    ji = pl.program_id(1)
+    pos = pos_ref[bi]
 
     @pl.when(ji == 0)
     def _init():
@@ -84,36 +99,38 @@ def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (g, d)
-    # (bk, d), tokens on axis ``tok`` = 0; (d, bk) and 1 on a swapped pool
-    k = k_ref[0, 0, 0].astype(jnp.float32)
-    v = v_ref[0, 0, 0].astype(jnp.float32)
-    pos = pos_ref[bi]
-    # rows past pos carry zero weight, but padded/foreign blocks may
-    # hold garbage and 0·NaN = NaN — zero those V rows outright
-    rows_ok = (ji * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, v.shape, tok)) <= pos
-    v = jnp.where(rows_ok, v, 0.0)
-    s = jax.lax.dot_general(q, k, (((1,), (1 - tok,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    cols = ji * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (g, block_k), 1)
-    s = jnp.where(cols <= pos, s, _NEG_INF)
+    @pl.when(ji * block_k <= pos)
+    def _update():
+        q = q_ref[0].astype(jnp.float32) * scale         # (nkv, g, d)
+        # (nkv, bk, d), tokens on axis 1 + ``tok`` = 1; (nkv, d, bk) and 2
+        # on a swapped pool
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
+        # rows past pos carry zero weight, but the block's tail (and a
+        # foreign block) may hold garbage and 0·NaN = NaN — zero those V
+        # rows outright
+        rows_ok = (ji * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, v.shape, 1 + tok)) <= pos
+        v = jnp.where(rows_ok, v, 0.0)
+        s = jax.lax.dot_general(q, k, (((2,), (2 - tok,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32)
+        cols = ji * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 2)
+        s = jnp.where(cols <= pos, s, _NEG_INF)          # (nkv, g, bk)
 
-    m = m_ref[:, 0]
-    l = l_ref[:, 0]
-    m_new = jnp.maximum(m, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    alpha = jnp.exp(m - m_new)
-    m_ref[:, 0] = m_new
-    l_ref[:, 0] = l * alpha + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (tok,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        m = m_ref[...]                                   # (nkv, g, 1)
+        m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p, v, (((2,), (1 + tok,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(ji == n_blocks - 1)
+    @pl.when(ji == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
@@ -125,9 +142,9 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
     pool of EVERY layer, read where it lies; ``layer`` (static) picks the
     one this call attends to.
     table (b, max_blocks) int32: slot b's sequence lives in pool blocks
-    ``table[b, 0] .. table[b, ·]`` (padding entries arbitrary — they
-    are masked).  pos (b,) int32: index of slot b's newest entry in its
-    OWN coordinate space (block j covers positions
+    ``table[b, 0] .. table[b, pos[b] // block_k]``; the entries past that
+    are never dereferenced.  pos (b,) int32: index of slot b's newest
+    entry in its OWN coordinate space (block j covers positions
     [j·block_k, (j+1)·block_k)).
 
     Returns (b, n_heads, 1, d).  ``interpret`` defaults to True off-TPU.
@@ -152,36 +169,42 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
     if scale is None:
         scale = 1.0 / np.sqrt(d)
     lanes = _tokens_on_lanes(k_pool.shape)
+    table = jnp.asarray(table, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    # the walk is as long as the batch's longest slot, not the table: a
+    # grid bound that is data, so one compiled program for every length
+    n_walk = jnp.clip(jnp.max(pos) // block_k + 1, 1, max_blocks)
+
+    def kv_block(bi, ji, tbl, ps):
+        # past the slot's last live block the index stays where it is: an
+        # unchanged block is not fetched again
+        return (layer, tbl[bi, jnp.minimum(ji, ps[bi] // block_k)], 0, 0, 0)
+
     kv_spec = pl.BlockSpec(
-        (1, 1, 1, d, block_k) if lanes else (1, 1, 1, block_k, d),
-        lambda bi, hi, ji, tbl, ps: (layer, tbl[bi, ji], hi, 0, 0))
-    qg = q.reshape(b, nkv, g, d)
+        (1, 1, nkv, d, block_k) if lanes else (1, 1, nkv, block_k, d),
+        kv_block)
+    qo_spec = pl.BlockSpec((1, nkv, g, d),
+                           lambda bi, ji, tbl, ps: (bi, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, nkv, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d),
-                         lambda bi, hi, ji, tbl, ps: (bi, hi, 0, 0)),
-            kv_spec, kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bi, hi, ji, tbl, ps:
-                               (bi, hi, 0, 0)),
+        grid=(b, n_walk),
+        in_specs=[qo_spec, kv_spec, kv_spec],
+        out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((nkv, g, 1), jnp.float32),
+            pltpu.VMEM((nkv, g, 1), jnp.float32),
+            pltpu.VMEM((nkv, g, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=float(scale),
-                          block_k=block_k, n_blocks=max_blocks,
-                          tok=int(lanes)),
+                          block_k=block_k, tok=int(lanes)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, d), q.dtype),
+        name="strom_paged_attn",
         interpret=_interpret(interpret),
-    )(jnp.asarray(table, jnp.int32), jnp.asarray(pos, jnp.int32),
-      qg, _kernel_view(k_pool, lanes), _kernel_view(v_pool, lanes))
+    )(table, pos, q.reshape(b, nkv, g, d),
+      _kernel_view(k_pool, lanes), _kernel_view(v_pool, lanes))
     return out.reshape(b, nh, 1, d)
 
 

@@ -116,16 +116,24 @@ def _small_step(hd, sharding=None):
     return compiled, pool.shape
 
 
-def _paged(topo):
+def _paged(topo, slots=16, layers=24, blocks=256, max_len=4096, nh=NH,
+           hd=HD, **kw):
+    """Paged attention at the shapes of the benchmark's ``m7b`` cells: 16
+    slots, every layer's pool in one array and the last layer read, a
+    table 32 entries wide.  All 8 KV heads of a block come in one grid
+    step (256 KiB of K, as much of V, double-buffered) and the block axis
+    of the grid is data."""
     from nvme_strom_tpu.ops.paged_attention import paged_attention
-    sh, b, blocks, bk = _one(topo), 8, 96, 128
-    return _compile(
-        functools.partial(paged_attention, interpret=False),
-        _spec((b, NH, 1, HD), jnp.bfloat16, sh),
-        _spec((1, blocks + 1, NKV, bk, HD), jnp.bfloat16, sh),
-        _spec((1, blocks + 1, NKV, bk, HD), jnp.bfloat16, sh),
-        _spec((b, 4096 // bk), jnp.int32, sh),
-        _spec((b,), jnp.int32, sh))
+    sh, bk = _one(topo), 128
+    pool = _spec((layers, blocks + 1, NKV, bk, hd), jnp.bfloat16, sh)
+    compiled = _compile(
+        functools.partial(paged_attention, layer=layers - 1,
+                          interpret=False, **kw),
+        _spec((slots, nh, 1, hd), jnp.bfloat16, sh), pool, pool,
+        _spec((slots, max_len // bk), jnp.int32, sh),
+        _spec((slots,), jnp.int32, sh))
+    assert not pool_sized_ops(compiled.as_text(), pool.shape)
+    return compiled
 
 
 def _decode(topo):
@@ -193,17 +201,12 @@ SSM_B, SSM_H, SSM_P, SSM_N = 64, 64, 64, 128
 
 
 def _paged_hd64(topo):
-    """Paged attention at head_dim 64 (half a lane row), 4 queries a KV
-    head, with a scale that is passed in."""
-    from nvme_strom_tpu.ops.paged_attention import paged_attention
-    sh, blocks, bk = _one(topo), 640, 128
-    return _compile(
-        functools.partial(paged_attention, scale=1 / 64, interpret=False),
-        _spec((SSM_B, 32, 1, 64), jnp.bfloat16, sh),
-        _spec((1, blocks + 1, 8, bk, 64), jnp.bfloat16, sh),
-        _spec((1, blocks + 1, 8, bk, 64), jnp.bfloat16, sh),
-        _spec((SSM_B, 1280 // bk), jnp.int32, sh),
-        _spec((SSM_B,), jnp.int32, sh))
+    """...and of ``g4hm.flood``: head_dim 64 (half a lane row: the pool is
+    read through its swapped view), 4 queries a KV head, 64 slots over the
+    4 attention layers' pool, a table 10 entries wide, with a scale that
+    is passed in."""
+    return _paged(topo, slots=SSM_B, layers=4, blocks=640, max_len=1280,
+                  nh=32, hd=64, scale=1 / 64)
 
 
 def _kv_write(topo, hd=HD, slots=16, blocks=256, layers=2):

@@ -1,7 +1,11 @@
 """Paged-attention kernel (ops/paged_attention.py) vs dense
 block-gather reference, ragged slot lengths, and the row writer against
 the scatter it replaced; interpret mode on CPU.  The pools are 5-D, every
-layer's in one array: a 4-D pool of the reference is a pool of one layer."""
+layer's in one array: a 4-D pool of the reference is a pool of one layer.
+The kernel's walk ends at a slot's last live block (grid ``(slots, blocks
+of the longest slot)``, every KV head of a block in one grid step): pools
+poisoned outside what a slot owns, free slots and the grid itself are
+pinned below, in both layouts of the pool."""
 
 import functools
 
@@ -115,6 +119,150 @@ def test_reads_only_its_layer(layer, block_k, d):
         jnp.asarray(table), jnp.asarray(pos), layer=layer))
     want = _reference(q, kp[layer], vp[layer], table, pos)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def _slots(rng, block_k, d, pos, max_blocks, nkv=2, g=2, spare=2):
+    """(q, k, v, table) for slots at ``pos``: each owns the table entries up
+    to its last live block, drawn from a shuffled pool; everything else in
+    the pool — blocks nobody owns, rows past a slot's ``pos`` in its last
+    block, the table's padding entries (block 0, which nobody owns
+    either) — is NaN in K and Inf in V."""
+    b = len(pos)
+    n_pool = 1 + b * max_blocks + spare
+    kp = np.full((n_pool, nkv, block_k, d), np.nan, np.float32)
+    vp = np.full((n_pool, nkv, block_k, d), np.inf, np.float32)
+    ids = 1 + rng.permutation(n_pool - 1)
+    table = np.zeros((b, max_blocks), np.int32)
+    for i, p in enumerate(pos):
+        n = p // block_k + 1
+        table[i, :n] = ids[i * max_blocks:i * max_blocks + n]
+        for j, blk in enumerate(table[i, :n]):
+            rows = min(block_k, p + 1 - j * block_k)
+            kp[blk, :, :rows] = rng.standard_normal((nkv, rows, d))
+            vp[blk, :, :rows] = rng.standard_normal((nkv, rows, d))
+    q = rng.standard_normal((b, nkv * g, 1, d)).astype(np.float32)
+    return q, kp, vp, table
+
+
+def _dense(q, kp, vp, table, pos, block_k):
+    """``_reference`` over each slot's live rows alone (no poison enters)."""
+    out = np.empty_like(q)
+    for i, p in enumerate(pos):
+        n = p // block_k + 1
+        kd = np.nan_to_num(kp[table[i, :n]], nan=0.0)
+        vd = np.nan_to_num(vp[table[i, :n]], posinf=0.0)
+        out[i] = _reference(q[i:i + 1], kd, vd,
+                            np.arange(n, dtype=np.int32)[None],
+                            np.array([p]))[0]
+    return out
+
+
+MAX_BLOCKS = 4
+# where a slot's newest entry lies: the first row, the last row of the
+# first block, the first row of the second, mid-block, the table's end
+POSITIONS = {"0": lambda bk: 0, "block-1": lambda bk: bk - 1,
+             "block": lambda bk: bk, "mid": lambda bk: 2 * bk + bk // 2,
+             "full": lambda bk: MAX_BLOCKS * bk - 1}
+
+
+@pytest.mark.parametrize("block_k,d", GEOMETRIES)
+@pytest.mark.parametrize("where", sorted(POSITIONS))
+def test_poison_outside_the_live_rows_never_enters(where, block_k, d):
+    """Blocks a slot does not own, rows past ``pos`` and the table's padding
+    are NaN / Inf: the output is finite and the dense reference's, for a
+    slot at ``where`` beside a neighbour of another length."""
+    rng = np.random.default_rng([block_k, d, len(where)])
+    pos = [POSITIONS[where](block_k), block_k + 1]
+    q, kp, vp, table = _slots(rng, block_k, d, pos, MAX_BLOCKS)
+    got = np.asarray(paged_attention(
+        jnp.asarray(q), jnp.asarray(kp)[None], jnp.asarray(vp)[None],
+        jnp.asarray(table), jnp.asarray(pos, jnp.int32)))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, _dense(q, kp, vp, table, pos, block_k),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("block_k,d", GEOMETRIES)
+@pytest.mark.parametrize("stale", ["as_the_step_hands_it", "stale_pos"])
+def test_free_slot_is_finite_and_leaves_the_live_slots_alone(stale, block_k,
+                                                             d):
+    """A free slot between two live ones: a table row of zeros and, as the
+    step hands it over, ``pos`` 0 — or, straight from the server's vector,
+    the ``pos`` its last request ended at.  Its output is finite either way
+    (block 0 holds finite rows here: in the server it is some request's or
+    zeros) and the live slots' outputs are bit for bit what they are
+    without it."""
+    rng = np.random.default_rng([block_k, d])
+    pos = [block_k + 3, 0, 3 * block_k - 1]
+    q, kp, vp, table = _slots(rng, block_k, d, pos, MAX_BLOCKS)
+    kp[0] = rng.standard_normal(kp[0].shape)
+    vp[0] = rng.standard_normal(vp[0].shape)
+    table[1] = 0
+    if stale == "stale_pos":
+        pos[1] = MAX_BLOCKS * block_k - 2
+    pools = jnp.asarray(kp)[None], jnp.asarray(vp)[None]
+    got = np.asarray(paged_attention(
+        jnp.asarray(q), *pools, jnp.asarray(table),
+        jnp.asarray(pos, jnp.int32)))
+    assert np.isfinite(got).all()
+    live = [0, 2]
+    alone = np.asarray(paged_attention(
+        jnp.asarray(q[live]), *pools, jnp.asarray(table[live]),
+        jnp.asarray(np.array(pos)[live], jnp.int32)))
+    np.testing.assert_array_equal(got[live], alone)
+    np.testing.assert_allclose(
+        alone, _dense(q[live], kp, vp, table[live], np.array(pos)[live],
+                      block_k), atol=2e-5, rtol=2e-5)
+
+
+def _pallas_eqns(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_eqns(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("block_k,d", GEOMETRIES)
+def test_grid_has_no_kv_head_axis_and_ends_with_the_longest_slot(block_k,
+                                                                 d):
+    """The grid read off the traced ``pallas_call``: (slots, blocks) — no
+    axis over the KV heads, one K/V block holds them all — and the block
+    axis is data, the longest slot's live blocks, never more than the
+    table's width."""
+    from nvme_strom_tpu.ops.paged_attention import _tokens_on_lanes
+    b, nkv, g, n_pool = 3, 2, 2, 9
+    pool = jnp.zeros((2, n_pool, nkv, block_k, d), jnp.float32)
+    q = jnp.zeros((b, nkv * g, 1, d), jnp.float32)
+    table = jnp.zeros((b, MAX_BLOCKS), jnp.int32)
+
+    def walk(pos):
+        jaxpr = jax.make_jaxpr(functools.partial(paged_attention, layer=1))(
+            q, pool, pool, table, pos)
+        call, = _pallas_eqns(jaxpr.jaxpr, [])
+        mapping = call.params["grid_mapping"]
+        assert call.params["name"] == "strom_paged_attn"
+        assert len(mapping.grid) == 2 and mapping.grid[0] == b
+        assert mapping.num_dynamic_grid_bounds == 1
+        kv = tuple(mapping.block_mappings[1].block_shape)
+        heads_and_block = ((nkv, d, block_k)
+                           if _tokens_on_lanes(pool.shape)
+                           else (nkv, block_k, d))
+        assert tuple(getattr(n, "block_size", n)
+                     for n in kv[-3:]) == heads_and_block
+        # the bound itself: the call's first operand, computed from pos
+        bound = jax.jit(lambda p: jax.core.eval_jaxpr(
+            jaxpr.jaxpr.replace(outvars=[call.invars[0]]), jaxpr.consts,
+            q, pool, pool, table, p)[0])(pos)
+        return int(bound)
+
+    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    assert walk(i32([0, 0, 0])) == 1
+    assert walk(i32([block_k - 1, 0, 3])) == 1
+    assert walk(i32([block_k, 0, 3])) == 2
+    assert walk(i32([1, MAX_BLOCKS * block_k - 1, 0])) == MAX_BLOCKS
+    assert walk(i32([1, 9 * MAX_BLOCKS * block_k, 0])) == MAX_BLOCKS
 
 
 def _pools(rng, shape, dtype):

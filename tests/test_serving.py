@@ -242,11 +242,15 @@ def test_server_stats_gauges(setup):
              "admissions_shed": 0, "prefill_programs": 0,
              # the two kinds of cache: every layer keeps K/V here, and no
              # layer carries a recurrent state (tests/test_hybrid.py)
-             "kv_layers": cfg.n_layers, "state_bytes": 0, "state_slots": 0}
+             "kv_layers": cfg.n_layers, "state_bytes": 0, "state_slots": 0,
+             # no decode step yet: paged attention has walked nothing
+             "attn_blocks_live": 0, "attn_blocks_table": 0}
     assert s0 == want0
     srv.step()
     s1 = srv.stats()
     assert s1["slots_busy"] == 2 and s1["queued"] == 0
+    # one step, both slots inside their first block of a table 8 wide
+    assert (s1["attn_blocks_live"], s1["attn_blocks_table"]) == (2, 16)
     assert s1["prefill_programs"] == 1      # both prompts: one block
     assert s1["blocks_free"] == 2 and s1["inflight_tokens"] >= 2
     srv.run()
@@ -870,3 +874,41 @@ def test_decode_step_traces_no_pool_sized_op_outside_the_kernels(kind):
         state, i32)
     assert str(jaxpr).count("pallas_call") >= 2 * L
     assert not _pool_sized_eqns(jaxpr.jaxpr, {pool.size, pool.size // L}, [])
+
+
+# -- paged attention walks only what is live --------------------------------
+
+@pytest.mark.parametrize("kind", ["plain_f32", "hybrid"])
+def test_mixed_batch_serves_generates_tokens_and_counts_its_live_blocks(
+        kind):
+    """Short and long prompts on two slots of a table 8 blocks wide, one
+    step a call: slot 0 finishes twice and is admitted again while slot 1's
+    long request runs on, and at the end slot 1 lies free (a stale ``pos``
+    over a table row of zeros) beside the last request.  Tokens are
+    ``generate()``'s, and the two counters are what the prompts' lengths
+    say: a request of prompt ``s`` and budget ``m`` takes ``m - 1`` decode
+    steps at positions ``s .. s + m - 2`` (its first token is the
+    prefill's), each reading ``pos // block + 1`` table entries, where an
+    unbounded walk reads slots x table width at every step."""
+    params, cfg, _, _ = _pr26_model(kind)
+    bk = 8
+    rng = np.random.default_rng(29)
+    reqs = {f"r{i}": (rng.integers(0, cfg.vocab, s).tolist(), m)
+            for i, (s, m) in enumerate([(3, 4), (30, 9), (17, 6), (5, 3)])}
+    srv = DecodeServer(params, cfg, max_batch=2, max_len=64,
+                       total_blocks=12, block_len=bk)
+    for rid, (prompt, budget) in reqs.items():
+        srv.submit(rid, prompt, budget)
+    got = srv.run()
+    for rid, (prompt, budget) in reqs.items():
+        assert got[rid] == _solo(params, cfg, prompt, budget), rid
+    # r0 holds slot 0 for calls 1-3, r2 for 4-8, r3 for 9-10; r1 slot 1 for
+    # calls 1-8: ten steps, the last two with slot 1 free
+    assert srv.timings["steps"] == 10 and srv.timings["admits"] == 4
+    live = sum(pos // bk + 1 for prompt, budget in reqs.values()
+               for pos in range(len(prompt), len(prompt) + budget - 1))
+    assert live == 3 * 1 + (2 * 4 + 6 * 5) + 5 * 3 + 2 * 1 == 58
+    stats = srv.stats()
+    # 58 of the 160 entries ten unbounded steps would have walked
+    assert (stats["attn_blocks_live"], stats["attn_blocks_table"]) \
+        == (live, 10 * 2 * (64 // bk))
