@@ -43,7 +43,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Dict:
         "v": jnp.zeros(shape, cfg.dtype),
         "pos": jnp.zeros((), jnp.int32),
     }
-    if cfg.mamba_layers:
+    if cfg.recurrent_layers:
         # K/V for the attention layers only; the recurrent layers carry a
         # fixed-size state instead (models/ssm.py)
         from nvme_strom_tpu.models.ssm import init_state
@@ -61,11 +61,30 @@ def cache_shardings(mesh, tp_axis: str = "tp", dp_axis: str = "dp"):
             "pos": NamedSharding(mesh, prune_spec(P(), mesh))}
 
 
-def mlp_block(h, p, L, cfg):
-    """Dense-or-MoE MLP dispatch for one layer — shared by the dense
-    decode path here and the paged decode path (models/kv_offload.py),
-    so layer-kind routing can never diverge between the two."""
-    if cfg.is_moe_layer(int(L.split(".")[1])):
+def mlp_block(h, p, L, cfg, valid=None, calls=None):
+    """The MLP of one layer, as the config's per-layer description says —
+    shared by every decode and serving layer loop, so layer-kind routing
+    can never diverge between them.
+
+    An expert layer (``mlp_kind`` "experts") computes every selected pair
+    (``moe.expert_mlp``); ``valid`` (b, m) bool marks the rows that are
+    real (None: all) and ``calls``, a list, collects the layer's (counts,
+    rows computed) for ``moe.add_load``.  The capacity-dropping GShard layer
+    runs only for a config that places it by ``moe_every``; a config that
+    describes a model's own router gets an error, never a dropped token."""
+    kind = cfg.mlp_kind(int(L.split(".")[1]))
+    if kind == "experts":
+        out, counts, rows = _moe.expert_mlp(h, p, L, cfg, valid)
+        if calls is not None:
+            calls.append((counts, rows))
+        return out
+    if kind == "gshard":
+        if cfg.router_kind != "softmax" or cfg.router_bias or cfg.d_expert:
+            raise NotImplementedError(
+                "the capacity-dropping GShard layer (moe_every) routes by "
+                "softmax at d_ff and drops pairs over its capacity; a "
+                "config with a sigmoid router, a selection bias or an "
+                "expert width names its expert layers in mlp_kinds")
         out, _ = _moe.moe_mlp(h, p, L, cfg)
         return out
     return mlp(h, p, L)
@@ -87,8 +106,9 @@ def prefill(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     them.
     """
     b, s = tokens.shape
-    if cfg.mamba_layers:
+    if cfg.recurrent_layers or cfg.expert_layers:
         # block_step from an empty cache IS the prefill, state included
+        # (and it is what tells an expert layer which rows are padding)
         return block_step(params, tokens, cfg, cache,
                           last=s - 1 if last is None else last,
                           n_valid=None if last is None else last + 1)
@@ -199,7 +219,9 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     ``n_valid``: rows from it on are right padding.  Attention needs no
     telling (pad rows sit past every valid row's mask and are overwritten
     before a mask reaches them); a recurrent layer does — its state and
-    conv tail (``cache["ssm"]``) stop at row ``n_valid - 1``.
+    conv tail (``cache["ssm"]``) stop at row ``n_valid - 1`` — and an
+    expert layer routes only the rows before it.  ``cache["moe"]``, where
+    a caller put ``moe.load_counters``, comes back with this call added.
     """
     b, m = tokens.shape
     pos = cache["pos"]
@@ -209,15 +231,23 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     limit = jnp.broadcast_to(pos + jnp.arange(m), (b, m))
     ssm = cache.get("ssm")
     states, tails = (list(ssm["s"]), list(ssm["conv"])) if ssm else ([], [])
-    ai = mi = 0           # this layer's place among its kind's caches
+    valid = (jnp.broadcast_to(jnp.arange(m) < n_valid, (b, m))
+             if n_valid is not None and cfg.expert_layers else None)
+    calls = []            # the expert layers' (counts, rows computed)
+    ai = mi = ti = 0      # this layer's place among its kind's caches
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
         h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
         if cfg.is_mamba_layer(i):
             from nvme_strom_tpu.models.ssm import mamba_block
-            a, states[mi], tails[mi] = mamba_block(
-                h, params, L, cfg, states[mi], tails[mi], n_valid)
+            a, states[mi], tails[ti] = mamba_block(
+                h, params, L, cfg, states[mi], tails[ti], n_valid)
             mi += 1
+            ti += 1
+        elif cfg.mixer(i) == "conv":
+            from nvme_strom_tpu.models.ssm import conv_block
+            a, tails[ti] = conv_block(h, params, L, cfg, tails[ti], n_valid)
+            ti += 1
         else:
             q, k, v = qkv_project(h, params, L, cfg, positions=positions)
             cache["k"] = lax.dynamic_update_slice(
@@ -233,11 +263,13 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
         x = add_residual(x, a, cfg)
         h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
         with jax.named_scope("strom.mlp"):
-            f = _mlp_block(h, params, L, cfg)
+            f = _mlp_block(h, params, L, cfg, valid, calls)
         x = add_residual(x, f, cfg).astype(cfg.dtype)
     cache["pos"] = pos + m
     if ssm:
         cache["ssm"] = {"s": tuple(states), "conv": tuple(tails)}
+    if "moe" in cache and calls:
+        cache["moe"] = _moe.add_load(cache["moe"], calls)
     if last is not None:
         x = x[:, last]
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)

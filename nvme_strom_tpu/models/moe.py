@@ -1,4 +1,8 @@
-"""Mixture-of-experts MLP with expert parallelism over an ``ep`` mesh axis.
+"""Mixture-of-experts MLPs: the exact expert layer the serving path runs
+(``expert_mlp``, below the GShard code) and the capacity-dropping GShard
+layer with expert parallelism over an ``ep`` mesh axis (``moe_mlp``: the
+training path's, reached only from a config that places it by
+``moe_every``).
 
 The reference has no model-parallel concepts (SURVEY.md §2 "Parallelism
 strategies: NOT PRESENT") — expert parallelism is here because it is a
@@ -140,17 +144,114 @@ def moe_mlp(x: jax.Array, p: dict, prefix: str, cfg) -> tuple:
     return out.reshape(b, s, d), aux
 
 
+# ------------------------------------------------------ the exact layer
+#
+#   s    = score(h W_g) in float32            sigmoid, or softmax over E
+#   sel  = top-k of (s + b)                   b: "router_bias", chooses only
+#   w    = s[sel] / (sum s[sel] + 1e-6)       (router_norm_topk) x router_scale
+#   f    = sum_{e in sel} w_e W2_e (silu(W1_e h) * W3_e h)
+#
+# Every selected (row, expert) pair is computed: no capacity, nothing
+# dropped, and no row's result depends on any other row of the call.
+
+def route(x, p: dict, prefix: str, cfg):
+    """x (T, d) -> (sel (T, k) int32, w (T, k) float32)."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        p[prefix + "router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    s = (jax.nn.sigmoid(logits) if cfg.router_kind == "sigmoid"
+         else jax.nn.softmax(logits, axis=-1))
+    choose = s
+    if cfg.router_bias:
+        choose = s + p[prefix + "router_bias"].astype(jnp.float32)
+    _, sel = jax.lax.top_k(choose, cfg.expert_top_k)
+    w = jnp.take_along_axis(s, sel, axis=-1)
+    if cfg.router_norm_topk:
+        w = w / (w.sum(-1, keepdims=True) + 1e-6)
+    if cfg.router_scale != 1.0:
+        w = w * jnp.float32(cfg.router_scale)
+    return sel.astype(jnp.int32), w
+
+
+def expert_mlp(x: jax.Array, p: dict, prefix: str, cfg, valid=None) -> tuple:
+    """The exact expert layer.  x (b, s, d) -> (out (b, s, d), counts (E,)
+    int32 — pairs routed to each expert —, rows the grouped product ran,
+    tile padding included ()).
+
+    ``valid`` (b, s) bool or None: rows that are not valid (right padding
+    of a prefill, a free serving slot) are routed nowhere — they cost no
+    expert a row, count in no histogram, and come out as zeros."""
+    from nvme_strom_tpu.ops import moe as _ops
+    b, s, d = x.shape
+    T, E, k = b * s, cfg.n_experts, cfg.expert_top_k
+    xt = x.reshape(T, d)
+    tm = _ops.tile_rows(T * k, E)
+    with jax.named_scope("strom.moe.route"):
+        sel, w = route(xt, p, prefix, cfg)
+        if valid is not None:
+            sel = jnp.where(valid.reshape(T, 1), sel, E)
+        dest, tile_expert, n_tiles, counts = _ops.group_rows(
+            sel.reshape(T * k), E, tm)
+        rows = _ops.padded_rows(T * k, E, tm)
+        # the layout's row r holds pair src[r] (row src[r] // k of x), or
+        # zeros; one spare row takes the pairs that go nowhere
+        src = jnp.full((rows + 1,), T * k, jnp.int32).at[dest].set(
+            jnp.arange(T * k, dtype=jnp.int32))[:rows]
+        xs = jnp.where((src < T * k)[:, None],
+                       xt[jnp.minimum(src, T * k - 1) // k], 0)
+    with jax.named_scope("strom.moe.experts"):
+        h = _ops.gmm(xs, (_tr.wmat(p, prefix + "moe_w_gate", x.dtype),
+                          _tr.wmat(p, prefix + "moe_w_up", x.dtype)),
+                     tile_expert, n_tiles, tm=tm)
+        y = _ops.gmm(h, (_tr.wmat(p, prefix + "moe_w_down", x.dtype),),
+                     tile_expert, n_tiles, tm=tm)
+    with jax.named_scope("strom.moe.route"):
+        dest = dest.reshape(T, k)
+        live = dest < rows
+        picked = y[jnp.minimum(dest, rows - 1)].astype(jnp.float32)
+        out = jnp.sum(jnp.where(live[..., None], w[..., None] * picked, 0.0),
+                      axis=1)
+    return (out.astype(x.dtype).reshape(b, s, d), counts, n_tiles * tm)
+
+
+#: columns of ``load_counters``' "sums": per expert layer, summed over calls
+SUMS = ("experts_touched", "rows_computed", "load_max")
+
+
+def load_counters(cfg) -> dict:
+    """Zeroed device counters of the exact expert layers: ``load``
+    (expert layers, E) int32, pairs routed to each expert, and ``sums``
+    (expert layers, 3) int32: experts touched, rows computed, and the
+    busiest expert's load, each summed over the calls."""
+    n = len(cfg.expert_layers)
+    return {"load": jnp.zeros((n, cfg.n_experts), jnp.int32),
+            "sums": jnp.zeros((n, len(SUMS)), jnp.int32)}
+
+
+def add_load(counters: dict, calls: list) -> dict:
+    """``counters`` plus one call of every expert layer: ``calls`` is
+    [(counts (E,), rows computed ())] in layer order."""
+    load = jnp.stack([c for c, _ in calls])
+    sums = jnp.stack([jnp.stack([jnp.sum(c > 0), r, jnp.max(c)])
+                      for c, r in calls])
+    return {"load": counters["load"] + load,
+            "sums": counters["sums"] + sums.astype(jnp.int32)}
+
+
 def init_moe_params(keys, cfg, prefix: str, dense) -> dict:
     """MoE weights for one layer.  ``keys`` is an iterator of PRNG keys;
     ``dense`` is the caller's initializer (transformer.dense_init — passed
     in rather than imported to keep moe.py import-cycle-free)."""
-    E, dm, ff = cfg.n_experts, cfg.d_model, cfg.d_ff
-    return {
+    E, dm, ff = cfg.n_experts, cfg.d_model, cfg.expert_width
+    out = {
         prefix + "router": dense(next(keys), dm, (dm, E)),
         prefix + "moe_w_gate": dense(next(keys), dm, (E, dm, ff)),
         prefix + "moe_w_up": dense(next(keys), dm, (E, dm, ff)),
         prefix + "moe_w_down": dense(next(keys), ff, (E, ff, dm)),
     }
+    if cfg.router_bias:
+        out[prefix + "router_bias"] = jnp.zeros((E,), jnp.float32)
+    return out
 
 
 def moe_param_specs(cfg, layer_prefix: str) -> dict:

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 from nvme_strom_tpu.io.tenants import (
     TokenBucket, tenant_context, tenants_enabled, tier_rank)
 from nvme_strom_tpu.models import decode as _dec
+from nvme_strom_tpu.models import moe as _moe
 from nvme_strom_tpu.models import ssm as _ssm
 from nvme_strom_tpu.models.decode import _mlp_block
 from nvme_strom_tpu.models.transformer import (
@@ -152,7 +153,7 @@ def _gather_prefix(k_pool, v_pool, blks):
 
 
 def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
-                  k_head, v_head, last, ssm=None):
+                  k_head, v_head, last, ssm=None, moe=None):
     """The admission prefill, traced inside ``_paged_prefill``:
     ``block_step`` of the right-padded suffix ``tokens`` (1, m) behind
     the cached prefix ``k_head``/``v_head`` ((L, 1, nkv, c, hd), or None
@@ -160,9 +161,10 @@ def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
     so every admission shares one math).
 
     Returns (logits (1, vocab) f32 at suffix row ``last``, k, v dense
-    (L, 1, nkv, c + m, hd), and for a config with recurrent layers their
-    state after row ``last`` — else None).  The pad rows sit past
-    ``last``: causality keeps them out of the logits, and their cache
+    (L, 1, nkv, c + m, hd), for a config with recurrent layers their
+    state after row ``last`` — else None —, and the expert layers' load
+    counters ``moe`` with this prompt's valid rows added).  The pad rows sit
+    past ``last``: causality keeps them out of the logits, and their cache
     entries are dead — decode overwrites a position before its mask
     exposes it.  A recurrence has no mask to hide behind: ``n_valid``
     tells it where the prompt ends."""
@@ -170,6 +172,8 @@ def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
     cache = _dec.init_cache(cfg, 1, m)
     if ssm is not None:
         cache["ssm"] = ssm
+    if moe is not None:
+        cache["moe"] = moe
     if k_head is not None:
         cache["k"] = jnp.concatenate(
             [k_head.astype(cfg.dtype), cache["k"]], axis=3)
@@ -178,7 +182,8 @@ def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
         cache["pos"] = jnp.asarray(k_head.shape[3], jnp.int32)
     logits, cache = _dec.block_step(params, tokens, cfg, cache, last=last,
                                     n_valid=last + 1)
-    return logits, cache["k"], cache["v"], cache.get("ssm")
+    return (logits, cache["k"], cache["v"], cache.get("ssm"),
+            cache.get("moe"))
 
 
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2, 3, 7))
@@ -192,27 +197,32 @@ def _paged_prefill(params: Dict, cfg: TransformerConfig, k_pool, v_pool,
     pools.  Block ids and ``last`` are data: the program is keyed on
     (m, n) only.
 
-    With recurrent layers in ``cfg``, ``state`` is the server's state
-    pool (``models/ssm.init_state``, donated) and ``slot`` the admitted
-    slot's row: the prompt starts from an empty state (there is no prefix
-    to resume: a page without the state at its boundary is not one) and
-    the state after row ``last`` overwrites the row — which is why
-    releasing a slot clears nothing.  Returns (logits, k_pool, v_pool,
-    state); ``state`` stays None for a plain decoder, whose program this
-    leaves as it was."""
+    With recurrent or expert layers in ``cfg``, ``state`` is what the
+    server's programs carry beside the K/V pools (``init_carried``,
+    donated) and ``slot`` the admitted slot's row of its pools: the prompt
+    starts from an empty state (there is no prefix to resume: a page
+    without the state at its boundary is not one) and the state after row
+    ``last`` overwrites the row — which is why releasing a slot clears
+    nothing; the expert layers' ``"prefill"`` counters take the prompt's
+    valid rows.  Returns (logits, k_pool, v_pool, state); ``state`` stays
+    None for a plain decoder, whose program this leaves as it was."""
     bk = k_pool.shape[3]
     ct = blks.shape[0] - tokens.shape[1] // bk
     k_head = v_head = None
     if ct:
         k_head, v_head = _gather_prefix(k_pool, v_pool, blks[:ct])
-    logits, k, v, ssm = _prefill_rows(
+    moe = state.get("moe") if state else None
+    logits, k, v, ssm, load = _prefill_rows(
         params, cfg, tokens, k_head, v_head, last,
-        None if state is None else _ssm.init_state(cfg, 1))
+        _ssm.init_state(cfg, 1) if cfg.recurrent_layers else None,
+        moe and moe["prefill"])
     if state is not None:
-        state = {key: tuple(
+        state = dict(state, **{key: tuple(
             jax.lax.dynamic_update_slice_in_dim(
                 pool, new.astype(pool.dtype), slot, axis=0)
-            for pool, new in zip(state[key], ssm[key])) for key in state}
+            for pool, new in zip(state[key], ssm[key])) for key in ssm or ()})
+        if moe:
+            state["moe"] = dict(moe, prefill=load)
 
     def new_rows(dense):                   # → (L, n - ct, nkv, bk, hd)
         L, _, nkv, _, hd = dense.shape
@@ -237,25 +247,34 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     the paged-attention kernel.  state/sidx: the recurrent layers' pool
     (``models/ssm.init_state``) and each slot's row of it — a free slot's
     is the sacrificial last row, as its ``blk`` is the trash block (the
-    pool's last)."""
+    pool's last).  An expert layer routes the live slots only (a free
+    slot is told by its trash block) and ``state["moe"]["decode"]`` takes
+    their load."""
     from nvme_strom_tpu.ops.paged_attention import (paged_attention,
                                                     write_rows)
     B = tok.shape[0]
+    free = blk == k_pool.shape[1] - 1
     # a free slot keeps its last pos over a table row of zeros; nobody
     # reads its output, so to the kernel its history is one row
-    attn_pos = jnp.where(blk == k_pool.shape[1] - 1, 0, pos)
-    if state is not None:
-        s_pools, tails = list(state["s"]), list(state["conv"])
+    attn_pos = jnp.where(free, 0, pos)
+    s_pools, tails = ((list(state["s"]), list(state["conv"]))
+                      if cfg.recurrent_layers else ([], []))
+    live = ~free[:, None] if cfg.expert_layers else None
+    calls = []              # the expert layers' (counts, rows computed)
     x = embed_tokens(params, cfg, tok[:, None])               # (B,1,d)
     positions = pos.astype(jnp.float32)[:, None]              # (B,1)
-    ai = mi = 0             # attention layers / recurrent layers so far
+    ai = mi = ti = 0        # attention / mamba / tail-keeping layers so far
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
         h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
         if cfg.is_mamba_layer(i):
-            a, s_pools[mi], tails[mi] = _ssm.mamba_step(
-                h, params, L, cfg, s_pools[mi], tails[mi], sidx)
+            a, s_pools[mi], tails[ti] = _ssm.mamba_step(
+                h, params, L, cfg, s_pools[mi], tails[ti], sidx)
             mi += 1
+            ti += 1
+        elif cfg.mixer(i) == "conv":
+            a, tails[ti] = _ssm.conv_step(h, params, L, cfg, tails[ti], sidx)
+            ti += 1
         else:
             q, k, v = qkv_project(h, params, L, cfg, positions=positions)
             with jax.named_scope("strom.attn.paged"):
@@ -273,12 +292,31 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
         x = add_residual(x, a, cfg)
         h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
         with jax.named_scope("strom.mlp"):
-            f = _mlp_block(h, params, L, cfg)
+            f = _mlp_block(h, params, L, cfg, live, calls)
         x = add_residual(x, f, cfg).astype(cfg.dtype)
     x = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
     if state is not None:
-        state = {"s": tuple(s_pools), "conv": tuple(tails)}
+        state = dict(state, s=tuple(s_pools), conv=tuple(tails))
+        if calls:
+            state["moe"] = dict(state["moe"], decode=_moe.add_load(
+                state["moe"]["decode"], calls))
     return lm_logits(params, cfg, x), k_pool, v_pool, state
+
+
+def init_carried(cfg: TransformerConfig, rows: int):
+    """What the server's two programs carry on the device beside the K/V
+    pools, donated and updated in place: the recurrent layers' pools of
+    ``rows`` rows (``models/ssm.init_state``: ``"s"``, ``"conv"``) and, for
+    a config with expert layers, their load counters ``"moe"``
+    (``models/moe.load_counters``), ``"decode"`` and ``"prefill"`` apart.
+    None for a plain decoder."""
+    if not (cfg.recurrent_layers or cfg.expert_layers):
+        return None
+    state = _ssm.init_state(cfg, rows)
+    if cfg.expert_layers:
+        state["moe"] = {"decode": _moe.load_counters(cfg),
+                        "prefill": _moe.load_counters(cfg)}
+    return state
 
 
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(3, 4, 12))
@@ -376,7 +414,7 @@ class DecodeServer:
         self.cfg = cfg
         self.B = max_batch
         self.max_len = max_len
-        if cfg.mamba_layers:
+        if cfg.recurrent_layers:
             # pages without the state at their boundary are not a prefix,
             # in the store as in the HBM prefix cache (_req_keys)
             if kv_store is not None:
@@ -452,14 +490,27 @@ class DecodeServer:
         #: step, from the host's position mirror: ``attn_blocks_live``
         #: (Σ over active slots of ``pos // block_len + 1``, the table
         #: entries paged attention has to read) and ``attn_blocks_table``
-        #: (``B × max_blocks``, the entries it would walk unbounded)
+        #: (``B × max_blocks``, the entries it would walk unbounded).  Per
+        #: call of an exact expert layer (one per layer per decode step;
+        #: the admissions' under ``*_prefill``), read off the device's
+        #: counters at each readback: ``moe_calls``, ``moe_pairs`` (valid
+        #: rows × k routed), ``moe_rows_computed`` (rows the grouped
+        #: product ran, tile padding included), ``moe_experts_touched``
+        #: (experts with at least one row) and ``moe_load_max`` (the
+        #: busiest expert's rows) — sums over the calls
         self.timings: Dict[str, float] = {
             "admit_s": 0.0, "dispatch_s": 0.0, "readback_s": 0.0,
             "steps": 0, "readbacks": 0,
             "admits": 0, "queue_wait_s": 0.0, "prefill_s": 0.0,
             "prefill_tokens": 0, "prompt_tokens": 0,
             "prefill_programs": 0, "scan_tokens": 0,
-            "attn_blocks_live": 0, "attn_blocks_table": 0}
+            "attn_blocks_live": 0, "attn_blocks_table": 0,
+            **{key + sfx: 0 for sfx in ("", "_prefill") for key in (
+                "moe_calls", "moe_pairs", "moe_rows_computed",
+                "moe_experts_touched", "moe_load_max")}}
+        #: cumulative (expert layers, E) load histogram of the decode steps
+        #: (numpy; None without expert layers)
+        self.moe_load = None
         self._prefill_shapes: set = set()
         #: per-request serving metrics of RETIRED requests ({rid:
         #: {"ttft_ms", "admit_wait_ms"}}, newest last, bounded) plus
@@ -494,12 +545,18 @@ class DecodeServer:
         self.k_pool = jnp.zeros(shape, cfg.dtype)
         self.v_pool = jnp.zeros(shape, cfg.dtype)
         self._trash = self.total_blocks
-        # the second kind of cache: per recurrent layer a fixed-size
-        # state and conv tail for every slot, +1 sacrificial row that
-        # free slots step into (row B, as their K/V goes to the trash
-        # block).  None for a plain decoder.
-        self.state = (_ssm.init_state(cfg, self.B + 1)
-                      if cfg.mamba_layers else None)
+        # the second kind of cache: per recurrent layer what its mixer
+        # declares per sequence (a Mamba-2 state and conv tail, a short
+        # conv's tail) for every slot, +1 sacrificial row that free slots
+        # step into (row B, as their K/V goes to the trash block) — and
+        # with it the expert layers' load counters, carried by the same
+        # two programs.  None for a plain decoder.
+        self.state = init_carried(cfg, self.B + 1)
+        #: host copy of the device's load counters at the last readback
+        #: ({"decode" | "prefill": {"load", "sums"}} as uint32: a counter
+        #: may wrap, a difference of two readings does not)
+        self._moe_seen = None
+        self._moe_admits = 0        # admissions at the last readback
         self.free: List[int] = list(range(self.total_blocks))
         self.blocks: List[List[int]] = [[] for _ in range(self.B)]
         self._pos_h: List[int] = [0] * self.B   # host mirror of pos
@@ -552,7 +609,7 @@ class DecodeServer:
         """The request's chain keys, hashed ONCE — _can_admit runs per
         step while a request queues, and per-wait rehashing of a long
         prompt is O(prompt) host work on the decode path."""
-        if not self.prefix_cache or self.state is not None:
+        if not self.prefix_cache or self.cfg.recurrent_layers:
             # recurrent layers: a cached page is worthless without the
             # state at its boundary, which nobody keeps — no keys, so no
             # match (_pc_match) and nothing registered
@@ -859,7 +916,7 @@ class DecodeServer:
                 np.asarray(blks[:n_pb], np.int32), len(suffix) - 1,
                 *recur)
         self.timings["prefill_s"] += time.monotonic() - t0
-        if self.state is not None:
+        if self.cfg.recurrent_layers:
             self.timings["scan_tokens"] += len(suffix)
         with self._span("strom.serve.scatter", blocks=n_pb - ct,
                         rid=rid):
@@ -1086,9 +1143,16 @@ class DecodeServer:
             "attn_blocks_live": self.timings["attn_blocks_live"],
             "attn_blocks_table": self.timings["attn_blocks_table"],
         }
-        state = jax.tree_util.tree_leaves(self.state)
+        state = jax.tree_util.tree_leaves(
+            [self.state[k] for k in ("s", "conv")] if self.state else None)
         out["state_bytes"] = sum(a.nbytes for a in state)
         out["state_slots"] = self.B + 1 if state else 0
+        # layers whose MLP is the exact expert layer, and what they routed
+        # (decode steps; the prefill's own under *_prefill in timings)
+        out["moe_layers"] = len(self.cfg.expert_layers)
+        for key in ("moe_pairs", "moe_rows_computed", "moe_experts_touched",
+                    "moe_load_max", "moe_calls"):
+            out[key] = self.timings[key]
         if self.tenant_sheds:     # key appears only once tenancy acted
             out["tenant_sheds"] = dict(self.tenant_sheds)
         if self._draining:        # and these only once a drain began
@@ -1365,6 +1429,32 @@ class DecodeServer:
             self.seed, *recur)
         return nxt
 
+    def _note_moe(self, moe_h: dict, steps: int) -> None:
+        """The expert layers' device counters, as read back with a batch's
+        tokens, into ``timings`` and ``moe_load``: what was added since the
+        last reading."""
+        import numpy as np
+        now = jax.tree_util.tree_map(lambda a: np.asarray(a).view(np.uint32),
+                                     moe_h)
+        seen = self._moe_seen or jax.tree_util.tree_map(np.zeros_like, now)
+        self._moe_seen = now
+        n_layers = len(self.cfg.expert_layers)
+        admits = self.timings["admits"]
+        for phase, sfx, calls in (
+                ("decode", "", steps * n_layers),
+                ("prefill", "_prefill",
+                 (admits - self._moe_admits) * n_layers)):
+            load = (now[phase]["load"] - seen[phase]["load"]).astype(np.int64)
+            sums = (now[phase]["sums"] - seen[phase]["sums"]).astype(np.int64)
+            self.timings["moe_calls" + sfx] += calls
+            self.timings["moe_pairs" + sfx] += int(load.sum())
+            for j, name in enumerate(_moe.SUMS):
+                self.timings[f"moe_{name}{sfx}"] += int(sums[:, j].sum())
+            if phase == "decode":
+                self.moe_load = load if self.moe_load is None \
+                    else self.moe_load + load
+        self._moe_admits = admits
+
     def _ensure_params(self) -> None:
         """Resolve a demand-faulting param source on first use: every
         tensor not yet resident is faulted at ``decode`` class, ahead
@@ -1476,9 +1566,10 @@ class DecodeServer:
             pending, self._pending_first = self._pending_first, []
             with self._span("strom.serve.readback", steps=len(toks),
                             first=len(pending)):
-                first_h, tok_h = jax.device_get((   # the ONE readback
+                first_h, tok_h, moe_h = jax.device_get((  # the ONE readback
                     [v for _, v in pending],
-                    jnp.stack(toks) if toks else None))
+                    jnp.stack(toks) if toks else None,
+                    self.state.get("moe") if self.state else None))
         except BaseException:
             if pending:
                 # the batch readback itself failed AFTER the swap
@@ -1492,6 +1583,8 @@ class DecodeServer:
         self.timings["readback_s"] += time.monotonic() - t0
         self.timings["steps"] += len(toks)
         self.timings["readbacks"] += 1
+        if moe_h:
+            self._note_moe(moe_h, len(toks))
         # replay in generation order: deferred first tokens precede
         # this batch's sub-step tokens for their slots
         with self._span("strom.serve.replay") as replay_span:

@@ -1,4 +1,7 @@
-"""The Mamba-2 mixer: the recurrent layer of a hybrid decoder.
+"""The recurrent mixers of a hybrid decoder — Mamba-2 and the gated short
+convolution — and the state a sequence carries through them.
+
+Mamba-2:
 
     [z, u, dt] = split(h W_in)             widths inner | inner + 2N | H
     u_t  = silu(Σ_j w_conv[j] · u_{t-K+1+j} + b_conv)      depthwise, causal
@@ -8,8 +11,20 @@
     y_t  = S_t C_t + D ⊙ x_t
     out  = RMSNorm(y ⊙ silu(z); g_norm over all of inner) W_out
 
-What a sequence carries from one call to the next is ``S`` and the last
-K−1 rows of ``u`` before the conv.  ``mamba_block`` runs a block of rows
+The gated short convolution (``layer_kinds`` "conv"; the LFM2 family's
+operator, ``conv_block`` / ``conv_step`` at the end of this file):
+
+    [B, C, u] = split(h W_in)              three times d_model, in that order
+    v_t  = B_t ⊙ u_t
+    c_t  = Σ_j w[j] ⊙ v_{t-K+1+j}          depthwise, causal, K = conv_taps,
+                                           no bias, no activation
+    out  = (C ⊙ c) W_out
+
+What a sequence carries from one call to the next is, for Mamba-2, ``S`` and
+the last K−1 rows of ``u`` before the conv; for the short conv the last K−1
+rows of ``v``.  ``init_state`` holds both kinds: ``"s"`` one array per
+Mamba-2 layer, ``"conv"`` one tail per recurrent layer of either kind, in
+layer order.  ``mamba_block`` runs a block of rows
 (prefill, a full forward) through ``ops.ssm.ssm_scan``; ``mamba_step`` runs
 one token of every serving slot through ``ops.ssm.ssm_update`` against the
 server's state pool.  Parameter leaves of layer ``L``: ``ssm_in`` (d, 2·inner
@@ -53,16 +68,21 @@ def init_mamba_params(keys, cfg: TransformerConfig, L: str, dense) -> Dict:
 
 
 def init_state(cfg: TransformerConfig, rows: int) -> Dict:
-    """Zeroed recurrent state for ``rows`` sequences: per mamba layer one
-    ``S`` (rows, H, P, N) float32 and one conv tail (rows, K−1, inner + 2N).
-    A tuple of per-layer arrays, never one stacked array: each is donated to
-    the step and updated in place, and indexing a stacked one by layer would
-    copy the lot (the KV pool's copies in PERF.md §5)."""
-    n = len(cfg.mamba_layers)
+    """Zeroed recurrent state for ``rows`` sequences, whatever each mixer
+    declares per sequence: per mamba layer one ``S`` (rows, H, P, N) float32
+    under ``"s"``, and per recurrent layer of either kind one conv tail under
+    ``"conv"`` — (rows, K−1, inner + 2N) for Mamba-2, (rows, conv_taps − 1,
+    d_model) for the short conv — in layer order.  Tuples of per-layer
+    arrays, never one stacked array: each is donated to the step and updated
+    in place, and indexing a stacked one by layer would copy the lot (the KV
+    pool's copies in PERF.md §5)."""
     s = (rows, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
-    tail = (rows, cfg.ssm_conv - 1, cfg.ssm_conv_dim)
-    return {"s": tuple(jnp.zeros(s, jnp.float32) for _ in range(n)),
-            "conv": tuple(jnp.zeros(tail, cfg.dtype) for _ in range(n))}
+    tails = [(rows, cfg.ssm_conv - 1, cfg.ssm_conv_dim)
+             if cfg.is_mamba_layer(i) else (rows, cfg.conv_taps - 1,
+                                            cfg.d_model)
+             for i in cfg.recurrent_layers]
+    return {"s": tuple(jnp.zeros(s, jnp.float32) for _ in cfg.mamba_layers),
+            "conv": tuple(jnp.zeros(t, cfg.dtype) for t in tails)}
 
 
 def _project_in(h, p, L, cfg):
@@ -149,3 +169,62 @@ def mamba_step(h, p: Dict, L: str, cfg: TransformerConfig, s_pool,
     with jax.named_scope("strom.ssm.update"):
         y, s_pool = ssm_update(s_pool, sidx, x, _delta(dt, p, L), a, bv, cv)
     return _project_out(y, x, z, p, L, cfg)[:, None], s_pool, tail_pool
+
+
+# ------------------------------------------------- the gated short conv
+
+def init_conv_params(keys, cfg: TransformerConfig, L: str, dense) -> Dict:
+    d = cfg.d_model
+    return {L + "conv_in": dense(next(keys), d, (d, 3 * d)),
+            L + "conv_w": dense(next(keys), cfg.conv_taps,
+                                (cfg.conv_taps, d)),
+            L + "conv_out": dense(next(keys), d, (d, d))}
+
+
+def _gates(h, p, L):
+    """h (..., d) -> (C, v = B ⊙ u), each (..., d)."""
+    bcu = h @ wmat(p, L + "conv_in", h.dtype)
+    b, c, u = jnp.split(bcu, 3, axis=-1)
+    return c, b * u
+
+
+def conv_block(h, p: Dict, L: str, cfg: TransformerConfig, tail=None,
+               n_valid=None):
+    """A block of rows through the short-conv mixer.  h (b, m, d)
+    post-norm; tail (b, K−1, d): the rows of ``v`` the sequences carried in
+    (None: zeros); n_valid as in ``mamba_block``.  Returns (out (b, m, d),
+    tail)."""
+    b, m, d = h.shape
+    k1 = cfg.conv_taps - 1
+    with jax.named_scope("strom.conv"):
+        c, v = _gates(h, p, L)
+        if tail is None:
+            tail = jnp.zeros((b, k1, d), v.dtype)
+        window = jnp.concatenate([tail.astype(v.dtype), v], axis=1)
+        w = p[L + "conv_w"].astype(jnp.float32)
+        conv = sum(w[j] * window[:, j:j + m].astype(jnp.float32)
+                   for j in range(cfg.conv_taps))
+        if n_valid is None:
+            new_tail = window[:, m:]
+        else:
+            # window row n_valid + j is v's row n_valid - (K-1) + j
+            new_tail = jax.lax.dynamic_slice_in_dim(window, n_valid, k1,
+                                                    axis=1)
+        y = (c * conv.astype(c.dtype)) @ wmat(p, L + "conv_out", c.dtype)
+    return y, new_tail
+
+
+def conv_step(h, p: Dict, L: str, cfg: TransformerConfig, tail_pool, sidx):
+    """One token of every slot through the short-conv mixer, against the
+    server's tail pool (rows, K−1, d), updated in place when donated; sidx
+    (B,) each slot's row.  Returns (out (B, 1, d), tail_pool)."""
+    with jax.named_scope("strom.conv"):
+        c, v = _gates(h[:, 0], p, L)
+        window = jnp.concatenate(
+            [tail_pool[sidx].astype(v.dtype), v[:, None]], axis=1)  # (B,K,d)
+        tail_pool = tail_pool.at[sidx].set(
+            window[:, 1:].astype(tail_pool.dtype))
+        conv = jnp.einsum("bkc,kc->bc", window.astype(jnp.float32),
+                          p[L + "conv_w"].astype(jnp.float32))
+        y = (c * conv.astype(c.dtype)) @ wmat(p, L + "conv_out", c.dtype)
+    return y[:, None], tail_pool

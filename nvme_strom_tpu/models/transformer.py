@@ -65,13 +65,29 @@ class TransformerConfig:
     # materialize (chunked_xent) — essential at Llama-vocab sizes.
     # 0/1 = the plain full-logits path.
     xent_chunks: int = 0
-    # Hybrid decoders (models/ssm.py): the mixer of every layer,
-    # "attention" or "mamba", read off the model's own config
-    # (tools/convert_llama.config_from_hf).  Empty == attention in every
-    # layer, which is every program this config built before the field
-    # existed.  The ssm_* sizes are Mamba-2's heads, head width, state
-    # width, causal-conv taps and scan chunk (one B/C group).
+    # The per-layer description, read off the model's own config
+    # (tools/convert_llama.config_from_hf): the mixer of every layer
+    # ("attention", "mamba" or "conv": models/ssm.py) and its MLP
+    # ("dense" at d_ff, or "experts" at d_expert: the exact expert layer
+    # of models/moe.py).  Empty layer_kinds == attention in every layer;
+    # empty mlp_kinds == dense everywhere, or, with n_experts > 0, the
+    # capacity-dropping GShard layer wherever ``moe_every`` puts one (the
+    # training path's MoE, which a config has to name this way).  The
+    # ssm_* sizes are Mamba-2's heads, head width, state width,
+    # causal-conv taps and scan chunk (one B/C group); conv_taps the short
+    # conv mixer's.
     layer_kinds: tuple = ()
+    mlp_kinds: tuple = ()
+    d_expert: int = 0               # an expert's width (0: d_ff)
+    # the exact layer's router: scores "sigmoid" or "softmax" of the
+    # logits; a per-expert bias ("router_bias" leaf) added for the top-k
+    # SELECTION only; the chosen scores renormalised to sum 1; a scale
+    router_kind: str = "softmax"
+    router_bias: bool = False
+    router_norm_topk: bool = True
+    router_scale: float = 1.0
+    conv_taps: int = 3
+    qk_norm: bool = False           # per-head RMS norm of q and k pre-rotary
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
@@ -95,15 +111,31 @@ class TransformerConfig:
         kinds = tuple(self.layer_kinds)
         object.__setattr__(self, "layer_kinds", kinds)
         if kinds:
-            if len(kinds) != self.n_layers or set(kinds) - {"attention",
-                                                            "mamba"}:
+            if len(kinds) != self.n_layers or set(kinds) - {
+                    "attention", "mamba", "conv"}:
                 raise ValueError(
-                    f"layer_kinds must name 'attention' or 'mamba' for each "
-                    f"of the {self.n_layers} layers, got {kinds}")
+                    f"layer_kinds must name 'attention', 'mamba' or 'conv' "
+                    f"for each of the {self.n_layers} layers, got {kinds}")
             if "mamba" in kinds and not (self.ssm_heads and self.ssm_head_dim
                                          and self.ssm_state):
                 raise ValueError("mamba layers need ssm_heads, ssm_head_dim "
                                  "and ssm_state")
+        mlps = tuple(self.mlp_kinds)
+        object.__setattr__(self, "mlp_kinds", mlps)
+        if mlps:
+            if len(mlps) != self.n_layers or set(mlps) - {"dense",
+                                                          "experts"}:
+                raise ValueError(
+                    f"mlp_kinds must name 'dense' or 'experts' for each of "
+                    f"the {self.n_layers} layers, got {mlps}")
+            if "experts" in mlps and not (
+                    0 < self.expert_top_k <= self.n_experts):
+                raise ValueError(
+                    f"expert layers need 0 < expert_top_k <= n_experts, got "
+                    f"{self.expert_top_k} of {self.n_experts}")
+        if self.router_kind not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_kind {self.router_kind!r}: expected "
+                             "'softmax' or 'sigmoid'")
 
     @property
     def rope_scaling_dict(self):
@@ -114,18 +146,49 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    def mixer(self, i: int) -> str:
+        """Layer ``i``'s mixer: "attention", "mamba" or "conv"."""
+        return self.layer_kinds[i] if self.layer_kinds else "attention"
+
+    def mlp_kind(self, i: int) -> str:
+        """Layer ``i``'s MLP: "dense", "experts" (the exact expert layer)
+        or "gshard" (capacity-dropping, placed by ``moe_every``)."""
+        if self.mlp_kinds:
+            return self.mlp_kinds[i]
+        if self.n_experts > 0 and i % self.moe_every == self.moe_every - 1:
+            return "gshard"
+        return "dense"
+
     def is_moe_layer(self, i: int) -> bool:
-        return (self.n_experts > 0
-                and i % self.moe_every == self.moe_every - 1)
+        """Whether layer ``i`` holds expert weights, of either kind."""
+        return self.mlp_kind(i) != "dense"
 
     def is_mamba_layer(self, i: int) -> bool:
-        return bool(self.layer_kinds) and self.layer_kinds[i] == "mamba"
+        return self.mixer(i) == "mamba"
 
     @property
     def mamba_layers(self) -> tuple:
-        """Indices of the recurrent layers (empty for a plain decoder)."""
+        """Indices of the Mamba-2 layers (empty for a plain decoder)."""
         return tuple(i for i, k in enumerate(self.layer_kinds)
                      if k == "mamba")
+
+    @property
+    def recurrent_layers(self) -> tuple:
+        """Indices of the layers that carry something per sequence other
+        than K/V — a Mamba-2 state and conv tail, a short conv's tail — in
+        layer order: what ``models/ssm.init_state`` holds a row of."""
+        return tuple(i for i, k in enumerate(self.layer_kinds)
+                     if k in ("mamba", "conv"))
+
+    @property
+    def expert_layers(self) -> tuple:
+        """Indices of the layers whose MLP is the exact expert layer."""
+        return tuple(i for i in range(self.n_layers)
+                     if self.mlp_kind(i) == "experts")
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_expert or self.d_ff
 
     @property
     def attn_layers(self) -> tuple:
@@ -133,7 +196,7 @@ class TransformerConfig:
         decoder.  A cache holds ``len(attn_layers)`` layers of K/V; layer
         ``i``'s are at ``attn_layers.index(i)``."""
         return tuple(i for i in range(self.n_layers)
-                     if not self.is_mamba_layer(i))
+                     if self.mixer(i) == "attention")
 
     @property
     def ssm_inner(self) -> int:
@@ -146,10 +209,11 @@ class TransformerConfig:
 
     def require_no_recurrent(self, what: str) -> None:
         """The one message of everything that holds K/V pages only."""
-        if self.mamba_layers:
+        if self.recurrent_layers:
             raise NotImplementedError(
-                f"{what} does not carry the recurrent state of mamba "
-                f"layers (layer_kinds has {len(self.mamba_layers)}); serve "
+                f"{what} does not carry the recurrent state of mamba or "
+                f"conv layers (layer_kinds has "
+                f"{len(self.recurrent_layers)}); serve "
                 f"this config from DecodeServer on one device, "
                 f"without a kv_store, a mesh or session hand-off")
 
@@ -198,7 +262,13 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
         if cfg.is_mamba_layer(i):
             from nvme_strom_tpu.models.ssm import init_mamba_params
             p.update(init_mamba_params(keys, cfg, L, dense))
+        elif cfg.mixer(i) == "conv":
+            from nvme_strom_tpu.models.ssm import init_conv_params
+            p.update(init_conv_params(keys, cfg, L, dense))
         else:
+            if cfg.qk_norm:
+                p[L + "q_norm"] = jnp.ones((hd,), jnp.float32)
+                p[L + "k_norm"] = jnp.ones((hd,), jnp.float32)
             p[L + "wq"] = dense(next(keys), cfg.d_model,
                                 (cfg.d_model, nh * hd))
             p[L + "wk"] = dense(next(keys), cfg.d_model,
@@ -529,6 +599,15 @@ dense_causal_attention_grouped.defvjp(_grouped_attn_fwd,
                                       _grouped_attn_bwd)
 
 
+def _qk_norm(q, k, p, prefix, cfg: TransformerConfig):
+    """Per-head RMS norm of q and k (..., heads, head_dim) before rotary,
+    for the families that have one (``cfg.qk_norm``)."""
+    if not cfg.qk_norm:
+        return q, k
+    return (rms_norm(q, p[prefix + "q_norm"], cfg.norm_eps),
+            rms_norm(k, p[prefix + "k_norm"], cfg.norm_eps))
+
+
 def qkv_project(x, p, prefix, cfg: TransformerConfig, positions=None):
     """Shared QKV projection + RoPE.  Returns q (b, nh, s, hd) and k/v at
     kv-head width (b, n_kv_heads, s, hd) — pre-GQA-expansion, which is the
@@ -538,6 +617,7 @@ def qkv_project(x, p, prefix, cfg: TransformerConfig, positions=None):
     q = (x @ wmat(p, prefix + "wq", x.dtype)).reshape(b, s, nh, hd)
     k = (x @ wmat(p, prefix + "wk", x.dtype)).reshape(b, s, nkv, hd)
     v = (x @ wmat(p, prefix + "wv", x.dtype)).reshape(b, s, nkv, hd)
+    q, k = _qk_norm(q, k, p, prefix, cfg)
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # b h s d
     if cfg.rope:
         q, k = _rope(q, k, cfg.rope_theta, positions=positions,
@@ -555,6 +635,7 @@ def qkv_project_bshd(x, p, prefix, cfg: TransformerConfig,
     q = (x @ wmat(p, prefix + "wq", x.dtype)).reshape(b, s, nh, hd)
     k = (x @ wmat(p, prefix + "wk", x.dtype)).reshape(b, s, nkv, hd)
     v = (x @ wmat(p, prefix + "wv", x.dtype)).reshape(b, s, nkv, hd)
+    q, k = _qk_norm(q, k, p, prefix, cfg)
     cos, sin = _rope_cos_sin(hd // 2, cfg.rope_theta, positions,
                              cfg.rope_scaling_dict, s)
     # (s, half) → (s, 1, half) broadcasts over (b, s, H, half);
@@ -637,14 +718,21 @@ def forward_hidden(params: Dict, tokens: jax.Array,
         if cfg.is_mamba_layer(i):
             from nvme_strom_tpu.models.ssm import mamba_block
             h = mamba_block(h, p, L, cfg)[0]
+        elif cfg.mixer(i) == "conv":
+            from nvme_strom_tpu.models.ssm import conv_block
+            h = conv_block(h, p, L, cfg)[0]
         else:
             h = attention(h, p, L, cfg, attn_fn)
         x = add_residual(x, h, cfg)
         h = rms_norm(x, p[L + "mlp_norm"], cfg.norm_eps)
-        if cfg.is_moe_layer(i):
+        a = jnp.zeros((), jnp.float32)
+        kind = cfg.mlp_kind(i)
+        if kind == "gshard":
             h, a = _moe.moe_mlp(h, p, L, cfg)
+        elif kind == "experts":
+            h = _moe.expert_mlp(h, p, L, cfg)[0]
         else:
-            h, a = mlp(h, p, L), jnp.zeros((), jnp.float32)
+            h = mlp(h, p, L)
         return add_residual(x, h, cfg), a
 
     def one_layer(x, i):

@@ -19,6 +19,13 @@ from nvme_strom_tpu.models.transformer import TransformerConfig
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, P]:
     cfg.require_no_recurrent("a mesh (parallel/shardings.param_specs)")
+    if cfg.expert_layers:
+        # the exact expert layer holds every expert on one device: under
+        # an ``ep`` axis its rows would need an exchange it does not have
+        raise NotImplementedError(
+            "a mesh (parallel/shardings.param_specs) does not shard the "
+            "exact expert layer (mlp_kinds 'experts'): serve this config "
+            "on one device")
     specs = {
         "tok_embed": P(None, "tp"),     # d_model sharded
         "final_norm": P(),

@@ -62,11 +62,34 @@ _LAYER_RULES: Tuple[Tuple[str, str, bool], ...] = (
     ("mamba.norm.weight", "ssm_norm", False),
     ("shared_mlp.input_linear.weight", "w_gate_up", True),
     ("shared_mlp.output_linear.weight", "w_down", True),
+    # lfm2 / lfm2_moe: the gated short conv (models/ssm.py conv_block; its
+    # conv.weight is (d, 1, K) like Mamba's), q/k norms, w1/w3/w2 for
+    # gate/up/down, the family's own norm names.  The MoE block's names
+    # (feed_forward.gate, .expert_bias, .experts.E.w1/w3/w2) are ASSUMED from
+    # the dense half of the family: the installed transformers has lfm2 only.
+    ("conv.in_proj.weight", "conv_in", True),
+    ("conv.out_proj.weight", "conv_out", True),
+    ("conv.conv.weight", "conv_w", True),
+    ("self_attn.out_proj.weight", "wo", True),
+    ("self_attn.q_layernorm.weight", "q_norm", False),
+    ("self_attn.k_layernorm.weight", "k_norm", False),
+    ("feed_forward.w1.weight", "w_gate", True),
+    ("feed_forward.w3.weight", "w_up", True),
+    ("feed_forward.w2.weight", "w_down", True),
+    ("operator_norm.weight", "attn_norm", False),
+    ("ffn_norm.weight", "mlp_norm", False),
+    ("feed_forward.gate.weight", "router", True),
+    ("feed_forward.expert_bias", "router_bias", False),
 )
+
+#: one expert's matrices: convert() stacks them into moe_w_* (E, in, out)
+_EXPERT_RE = re.compile(r"^feed_forward\.experts\.(\d+)\.(w[123])\.weight$")
+_EXPERT_LEAF = {"w1": "moe_w_gate", "w3": "moe_w_up", "w2": "moe_w_down"}
 
 _TOP_RULES: Dict[str, Tuple[str, bool]] = {
     "model.embed_tokens.weight": ("tok_embed", False),
     "model.norm.weight": ("final_norm", False),
+    "model.embedding_norm.weight": ("final_norm", False),       # lfm2
     "lm_head.weight": ("lm_head", True),
 }
 
@@ -89,6 +112,10 @@ def map_name(hf_name: str) -> Optional[Tuple[str, bool]]:
         for hf_suffix, ours, tr in _LAYER_RULES:
             if rest == hf_suffix:
                 return f"layers.{idx}.{ours}", tr
+        e = _EXPERT_RE.match(rest)
+        if e:       # "…moe_w_gate.7": expert 7's slice, stacked by convert()
+            return f"layers.{idx}.{_EXPERT_LEAF[e.group(2)]}.{e.group(1)}", \
+                True
     return None
 
 
@@ -135,9 +162,61 @@ def _hybrid_fields(hf_cfg: dict) -> dict:
         rope=False, tie_embed=bool(hf_cfg.get("tie_word_embeddings", False)))
 
 
+def _lfm2_fields(hf_cfg: dict) -> dict:
+    """The TransformerConfig fields of an ``lfm2`` / ``lfm2_moe`` config
+    (gated short convs beside q/k-normed GQA; in ``lfm2_moe`` sigmoid-routed
+    experts after ``num_dense_layers`` dense MLPs), beyond the dense
+    family's.  Raises on what the model does not implement."""
+    def refuse(what):
+        raise ValueError(f"unsupported {hf_cfg['model_type']} config: {what}")
+    if hf_cfg.get("conv_bias"):
+        refuse("conv_bias=True (the short conv and its projections have "
+               "no bias)")
+    rope = hf_cfg.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default":
+        refuse(f"rope_type {rope.get('rope_type')!r} (only 'default')")
+    kinds = tuple("attention" if k == "full_attention" else k
+                  for k in hf_cfg["layer_types"])
+    if set(kinds) - {"attention", "conv"}:
+        refuse(f"layer_types {sorted(set(hf_cfg['layer_types']))}")
+    out = dict(layer_kinds=kinds, conv_taps=int(hf_cfg.get("conv_L_cache", 3)),
+               qk_norm=True,
+               tie_embed=bool(hf_cfg.get("tie_word_embeddings",
+                                         hf_cfg.get("tie_embedding", True))))
+    if "rope_theta" in rope:
+        out["rope_theta"] = float(rope["rope_theta"])
+    if hf_cfg["model_type"] == "lfm2":
+        ff = hf_cfg.get("block_ff_dim", hf_cfg["intermediate_size"])
+        if hf_cfg.get("block_auto_adjust_ff_dim", True):
+            # Lfm2MLP's own rule: 2/3 of the stated width, times the
+            # multiplier, rounded up to block_multiple_of
+            ff = int(2 * ff / 3)
+            mult = hf_cfg.get("block_ffn_dim_multiplier", 1.0)
+            if mult is not None:
+                mo = hf_cfg.get("block_multiple_of", 256)
+                ff = mo * ((int(mult * ff) + mo - 1) // mo)
+        out["d_ff"] = ff
+        return out
+    n_exp, k = hf_cfg["num_experts"], hf_cfg["num_experts_per_tok"]
+    if not 0 < k <= n_exp:
+        refuse(f"num_experts_per_tok={k} of num_experts={n_exp}")
+    dense = hf_cfg.get("num_dense_layers", 0)
+    n = len(kinds)
+    out.update(
+        mlp_kinds=tuple("dense" if i < dense else "experts"
+                        for i in range(n)),
+        n_experts=n_exp, expert_top_k=k,
+        d_expert=hf_cfg["moe_intermediate_size"], router_kind="sigmoid",
+        router_bias=bool(hf_cfg.get("use_expert_bias", False)),
+        router_norm_topk=bool(hf_cfg.get("norm_topk_prob", True)),
+        router_scale=float(hf_cfg.get("routed_scaling_factor", 1.0)))
+    return out
+
+
 def config_from_hf(hf_cfg: dict):
-    """HF ``config.json`` → TransformerConfig: the dense Llama family, and
-    ``model_type`` granitemoehybrid (``_hybrid_fields``).
+    """HF ``config.json`` → TransformerConfig: the dense Llama family,
+    ``model_type`` granitemoehybrid (``_hybrid_fields``) and ``lfm2`` /
+    ``lfm2_moe`` (``_lfm2_fields``).
 
     Raises on architecture knobs the model does not implement — silently
     ignoring them (e.g. a non-SiLU activation) would convert into a model
@@ -151,8 +230,10 @@ def config_from_hf(hf_cfg: dict):
         if hf_cfg.get(knob):
             raise ValueError(f"unsupported {knob}=True (model has no "
                              "bias terms)")
-    hybrid = (_hybrid_fields(hf_cfg)
-              if hf_cfg.get("model_type") == "granitemoehybrid" else {})
+    model_type = hf_cfg.get("model_type")
+    family = (_hybrid_fields(hf_cfg) if model_type == "granitemoehybrid"
+              else _lfm2_fields(hf_cfg) if model_type in ("lfm2", "lfm2_moe")
+              else {})
     derived_hd = hf_cfg["hidden_size"] // hf_cfg["num_attention_heads"]
     if hf_cfg["hidden_size"] % hf_cfg["num_attention_heads"]:
         raise ValueError("hidden_size not divisible by num_attention_heads")
@@ -173,7 +254,7 @@ def config_from_hf(hf_cfg: dict):
                    if k in ("rope_type", "type", "factor",
                             "low_freq_factor", "high_freq_factor",
                             "original_max_position_embeddings")}
-    return TransformerConfig(
+    fields = dict(
         vocab=hf_cfg["vocab_size"],
         d_model=hf_cfg["hidden_size"],
         n_layers=hf_cfg["num_hidden_layers"],
@@ -184,9 +265,10 @@ def config_from_hf(hf_cfg: dict):
         max_seq=hf_cfg.get("max_position_embeddings", 2048),
         rope_theta=float(hf_cfg.get("rope_theta", 10000.0)),
         rope_scaling=scaling,
-        norm_eps=float(hf_cfg.get("rms_norm_eps", 1e-5)),
-        **hybrid,
-    )
+        norm_eps=float(hf_cfg.get("rms_norm_eps",
+                                  hf_cfg.get("norm_eps", 1e-5))))
+    fields.update(family)         # a family may state a key its own way
+    return TransformerConfig(**fields)
 
 
 def strom_config_dict(cfg) -> dict:
@@ -198,11 +280,17 @@ def strom_config_dict(cfg) -> dict:
         "max_seq", "rope_theta", "norm_eps")}
     if cfg.rope_scaling:
         out["rope_scaling"] = dict(cfg.rope_scaling)
-    if cfg.layer_kinds:     # a hybrid: what _hybrid_fields read off the HF file
+    if cfg.layer_kinds:     # a hybrid: what the family's fields read off HF
         out.update({k: getattr(cfg, k) for k in (
             "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_conv", "ssm_chunk",
             "embed_mult", "residual_mult", "logits_div", "attn_scale", "rope",
-            "tie_embed")}, layer_kinds=list(cfg.layer_kinds))
+            "tie_embed", "conv_taps", "qk_norm")},
+            layer_kinds=list(cfg.layer_kinds))
+    if cfg.mlp_kinds:       # the per-layer MLPs and the exact layer's router
+        out.update({k: getattr(cfg, k) for k in (
+            "n_experts", "expert_top_k", "d_expert", "router_kind",
+            "router_bias", "router_norm_topk", "router_scale")},
+            mlp_kinds=list(cfg.mlp_kinds))
     return out
 
 
@@ -270,6 +358,7 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
             flush()
 
     skipped = []
+    experts: Dict[str, Dict[int, np.ndarray]] = {}
     for hf_name, arr in _iter_hf_tensors(hf_dir):
         mapped = map_name(hf_name)
         if mapped is None:
@@ -288,6 +377,15 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
         out = np.ascontiguousarray(arr.T) if transpose else arr
         if ours == "tok_embed":
             embed = arr
+        e = re.fullmatch(r"(.*\.moe_w_(?:gate|up|down))\.(\d+)", ours)
+        if e:                               # one expert's slice: held until
+            experts.setdefault(e.group(1), {})[int(e.group(2))] = out
+            if len(experts[e.group(1)]) == cfg.n_experts:   # the layer's set
+                stack = experts.pop(e.group(1))
+                seen.add(e.group(1))
+                emit(e.group(1), np.stack([stack[j]
+                                           for j in range(cfg.n_experts)]))
+            continue
         if ours.endswith("w_gate_up"):      # (d, 2 ff) → gate | up
             ff = out.shape[1] // 2
             for leaf, half in (("w_gate", out[:, :ff]), ("w_up", out[:, ff:])):
@@ -298,6 +396,9 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
         seen.add(ours)
         emit(ours, out)
 
+    if experts:
+        raise ValueError(f"expert matrices missing: {sorted(experts)} hold "
+                         f"fewer than {cfg.n_experts} experts")
     if cfg.tie_embed:
         seen.add("lm_head")     # the head IS tok_embed: nothing to write
     if "lm_head" not in seen:

@@ -268,10 +268,42 @@ def _ssm_scan_128(topo):
     return _ssm_scan(topo, rows=128)       # a chunk shorter than 256
 
 
+def _moe_gmm(topo, rows=128, k=4, gated=True):
+    """The grouped product of an expert layer at LFM2-24B-A2B's widths (64
+    experts of 2048 x 1536): gate and up fused, or down."""
+    from nvme_strom_tpu.ops import moe as ops
+    sh = _one(topo)
+    E, d, fe = 64, 2048, 1536
+    tm = ops.tile_rows(rows * k, E)
+    padded = ops.padded_rows(rows * k, E, tm)
+    kdim, n = (d, fe) if gated else (fe, d)
+    w = _spec((E, kdim, n), jnp.bfloat16, sh)
+    return _compile(
+        lambda x, te, nt, *ws: ops.gmm(x, ws, te, nt, tm=tm,
+                                       interpret=False),
+        _spec((padded, kdim), jnp.bfloat16, sh),
+        _spec((padded // tm,), jnp.int32, sh), _spec((), jnp.int32, sh),
+        *([w, w] if gated else [w]))
+
+
+def _moe_gmm_down(topo):
+    return _moe_gmm(topo, gated=False)
+
+
+def _moe_gmm_1024(topo):
+    return _moe_gmm(topo, rows=1024)
+
+
+def _moe_gmm_down_1024(topo):
+    return _moe_gmm(topo, rows=1024, gated=False)
+
+
 @pytest.mark.parametrize("build", [_paged, _decode, _flash_fwd,
                                    _flash_bwd, _bridge, _ici, _paged_hd64,
                                    _ssm_update, _ssm_scan, _ssm_scan_128,
-                                   _kv_write, _kv_write_hd64],
+                                   _kv_write, _kv_write_hd64, _moe_gmm,
+                                   _moe_gmm_down, _moe_gmm_1024,
+                                   _moe_gmm_down_1024],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_kernel_compiles_for_v5e(topo, build):
     assert build(topo) is not None
@@ -400,6 +432,52 @@ def test_hybrid_step_updates_both_caches_in_place(topo, monkeypatch):
     # and no operation of the step copies a state array (65 x 2 MiB)
     assert not [line for line in text.splitlines()
                 if " copy(" in line and "= f32[65,64,64,128]" in line]
+
+
+def test_lfm2_step_updates_every_pool_in_place(topo, monkeypatch):
+    """The server's decode step at LFM2-24B-A2B's widths, its first period
+    and one more conv layer (conv, conv, attention, conv, conv: 2 dense
+    MLPs, 3 expert layers of 64 experts), 128 slots: every conv layer's tail
+    pool, the K/V pool and the load counters are aliased input to output,
+    and each expert layer is two calls of the grouped product."""
+    import json
+    from nvme_strom_tpu.models import serving
+    from nvme_strom_tpu.models.transformer import init_params
+    from nvme_strom_tpu.tools.convert_llama import config_from_hf
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-24b-a2b.json")) as f:
+        hf = json.load(f)
+    hf = dict(hf, num_hidden_layers=5, layer_types=hf["layer_types"][:5])
+    cfg = config_from_hf(hf)
+    sh = _one(topo)
+    B, blocks, bk = 128, 1280, 128
+    params = {k: _spec(v.shape, jnp.bfloat16, sh) for k, v in jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)).items()}
+    pool = _spec((1, blocks + 1, cfg.n_kv_heads, bk, cfg.head_dim),
+                 jnp.bfloat16, sh)
+    state = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype, sh),
+        jax.eval_shape(lambda: serving.init_carried(cfg, B + 1)))
+    assert len(state["conv"]) == 4 and not state["s"]
+    vec = lambda dt: _spec((B,), dt, sh)                    # noqa: E731
+    compiled = serving._paged_step.lower(
+        params, cfg, vec(jnp.int32), pool, pool, vec(jnp.int32),
+        vec(jnp.int32), _spec((B, 1280 // bk), jnp.int32, sh),
+        vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
+        vec(jnp.uint32), state, vec(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 + 3 * 2  # write, attend; gmm
+    assert not pool_sized_ops(text, pool.shape)
+    donated = (2 * np.prod(pool.shape) * 2
+               + sum(np.prod(a.shape) * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(state)))
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= donated, m
+    # the router's scores are the one f32[slots, experts] array of the step:
+    # benchmark/layer_metrics/moe_route_share.py finds routing by it
+    assert "f32[128,64]" in text
 
 
 def test_sharded_forward_compiles_for_four_chips(topo):

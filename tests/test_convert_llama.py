@@ -304,3 +304,166 @@ def test_hybrid_logits_match_hf(hf_hybrid, tmp_path):
                              jnp.asarray(toks), cfg))
     np.testing.assert_allclose(got, want, rtol=0,
                                atol=2e-5 * np.abs(want).max())
+
+
+# -- lfm2 / lfm2_moe: gated short convs beside q/k-normed attention ---------
+
+LFM2_MOE = dict(
+    model_type="lfm2_moe", vocab_size=256, hidden_size=64,
+    intermediate_size=128, moe_intermediate_size=32, num_hidden_layers=4,
+    num_attention_heads=4, num_key_value_heads=2, max_position_embeddings=128,
+    norm_eps=1e-5, conv_L_cache=3, conv_bias=False, num_dense_layers=1,
+    num_experts=8, num_experts_per_tok=2, norm_topk_prob=True,
+    use_expert_bias=True, routed_scaling_factor=1,
+    rope_parameters={"rope_theta": 1000000, "rope_type": "default"},
+    layer_types=["conv", "conv", "full_attention", "conv"])
+
+
+@pytest.fixture(scope="module")
+def hf_lfm2(tmp_path_factory):
+    if not hasattr(transformers, "Lfm2ForCausalLM"):
+        pytest.skip("this transformers has no Lfm2")
+    d = tmp_path_factory.mktemp("hf_lfm2")
+    cfg = transformers.Lfm2Config(
+        vocab_size=256, hidden_size=64, intermediate_size=192,
+        num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=128, norm_eps=1e-5, rope_theta=1000000.0,
+        conv_bias=False, conv_L_cache=3, block_multiple_of=32,
+        block_ffn_dim_multiplier=1.0, block_auto_adjust_ff_dim=True,
+        layer_types=["conv", "conv", "full_attention", "conv"],
+        tie_word_embeddings=True)
+    torch.manual_seed(0)
+    model = transformers.Lfm2ForCausalLM(cfg).eval()
+    with torch.no_grad():       # the norms off their init of ones
+        for name, p in model.named_parameters():
+            if name.endswith(("norm.weight", "layernorm.weight")):
+                p.add_(0.1 * torch.randn_like(p))
+    model.save_pretrained(d, safe_serialization=True)
+    return str(d), model
+
+
+def test_map_name_covers_lfm2_tensors():
+    m = convert_llama.map_name
+    assert m("model.layers.0.conv.in_proj.weight") == ("layers.0.conv_in",
+                                                       True)
+    assert m("model.layers.0.conv.conv.weight") == ("layers.0.conv_w", True)
+    assert m("model.layers.2.self_attn.out_proj.weight") == ("layers.2.wo",
+                                                             True)
+    assert m("model.layers.2.self_attn.q_layernorm.weight") == (
+        "layers.2.q_norm", False)
+    assert m("model.layers.1.feed_forward.w3.weight") == ("layers.1.w_up",
+                                                          True)
+    assert m("model.layers.1.operator_norm.weight") == ("layers.1.attn_norm",
+                                                        False)
+    assert m("model.embedding_norm.weight") == ("final_norm", False)
+    # the MoE block's names (assumed: benchmark/configs/lfm2-24b-a2b.json)
+    assert m("model.layers.3.feed_forward.gate.weight") == ("layers.3.router",
+                                                            True)
+    assert m("model.layers.3.feed_forward.expert_bias") == (
+        "layers.3.router_bias", False)
+    assert m("model.layers.3.feed_forward.experts.5.w2.weight") == (
+        "layers.3.moe_w_down.5", True)
+
+
+def test_lfm2_logits_match_hf(hf_lfm2, tmp_path):
+    """Converted lfm2 weights through ``forward`` against transformers' own
+    ``Lfm2ForCausalLM``: the conv operator (B | C | x split, the depthwise
+    taps' layout, no activation), per-head q/k norms before rotary, w1/w3/w2,
+    the MLP's adjusted width, the family's norm names and the tied head."""
+    import jax.numpy as jnp
+    from nvme_strom_tpu.models.transformer import forward
+    hf_dir, model = hf_lfm2
+    out = str(tmp_path / "converted")
+    summary = convert_llama.convert(hf_dir, out)
+    assert summary["skipped"] == []
+    cfg, params = _load_converted(out)
+    assert cfg.layer_kinds == ("conv", "conv", "attention", "conv")
+    assert cfg.qk_norm and cfg.tie_embed and "lm_head" not in params
+    assert cfg.d_ff == 128 == params["layers.0.w_gate"].shape[1]  # 2/3 of 192
+    assert params["layers.0.conv_w"].shape == (3, 64)
+    toks = np.random.default_rng(0).integers(0, 256, (2, 21))
+    with torch.no_grad():
+        want = model(torch.tensor(toks)).logits.numpy()
+    got = np.asarray(forward({k: jnp.asarray(v, jnp.float32)
+                              for k, v in params.items()},
+                             jnp.asarray(toks), cfg))
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=2e-5 * np.abs(want).max())
+
+
+def test_lfm2_moe_config_round_trips_through_strom_config():
+    from nvme_strom_tpu.models.transformer import TransformerConfig
+    cfg = convert_llama.config_from_hf(LFM2_MOE)
+    assert cfg.mlp_kinds == ("dense", "experts", "experts", "experts")
+    assert (cfg.n_experts, cfg.expert_top_k, cfg.d_expert) == (8, 2, 32)
+    assert cfg.router_kind == "sigmoid" and cfg.router_bias
+    assert cfg.router_norm_topk and cfg.router_scale == 1.0
+    assert cfg.rope_theta == 1e6 and cfg.conv_taps == 3 and cfg.qk_norm
+    d = convert_llama.strom_config_dict(cfg)
+    assert TransformerConfig(**json.loads(json.dumps(d))) == cfg
+    # a dense config's file is what it was: none of the new keys
+    plain = convert_llama.strom_config_dict(convert_llama.config_from_hf(
+        dict(vocab_size=64, hidden_size=32, num_hidden_layers=2,
+             num_attention_heads=4, intermediate_size=64)))
+    assert not {"mlp_kinds", "layer_kinds", "router_kind"} & set(plain)
+
+
+@pytest.mark.parametrize("key,value,msg", [
+    ("conv_bias", True, "conv_bias"),
+    ("rope_parameters", {"rope_theta": 1e6, "rope_type": "yarn"}, "rope_type"),
+    ("num_experts_per_tok", 9, "num_experts_per_tok"),
+    ("layer_types", ["conv", "mamba", "full_attention", "conv"],
+     "layer_types"),
+    ("hidden_act", "gelu", "hidden_act"),
+])
+def test_lfm2_moe_config_raises_on_what_is_not_implemented(key, value, msg):
+    with pytest.raises(ValueError, match=msg):
+        convert_llama.config_from_hf(dict(LFM2_MOE, **{key: value}))
+
+
+def test_convert_stacks_the_experts_of_a_layer(tmp_path):
+    """An lfm2_moe checkpoint under the assumed names: every expert's three
+    matrices land stacked (experts, in, out), and a missing expert is an
+    error, not a short stack."""
+    from nvme_strom_tpu.formats.safetensors import write_safetensors
+    hf = dict(LFM2_MOE, num_hidden_layers=1, layer_types=["conv"],
+              num_dense_layers=0, num_experts=2, num_experts_per_tok=1)
+    rng = np.random.default_rng(1)
+    f32 = lambda *s: rng.standard_normal(s).astype(np.float32)   # noqa: E731
+    L = "model.layers.0."
+    tensors = {
+        "model.embed_tokens.weight": f32(256, 64),
+        "model.embedding_norm.weight": f32(64),
+        L + "operator_norm.weight": f32(64), L + "ffn_norm.weight": f32(64),
+        L + "conv.in_proj.weight": f32(192, 64),
+        L + "conv.conv.weight": f32(64, 1, 3),
+        L + "conv.out_proj.weight": f32(64, 64),
+        L + "feed_forward.gate.weight": f32(2, 64),
+        L + "feed_forward.expert_bias": f32(2)}
+    for e in range(2):
+        E = f"{L}feed_forward.experts.{e}."
+        tensors.update({E + "w1.weight": f32(32, 64),
+                        E + "w3.weight": f32(32, 64),
+                        E + "w2.weight": f32(64, 32)})
+    src = tmp_path / "hf"
+    src.mkdir()
+
+    def write(ts):
+        write_safetensors(str(src / "model.safetensors"), ts)
+        (src / "config.json").write_text(json.dumps(hf))
+    write(tensors)
+    out = str(tmp_path / "out")
+    assert convert_llama.convert(str(src), out)["skipped"] == []
+    cfg, params = _load_converted(out)
+    assert cfg.mlp_kinds == ("experts",)
+    assert params["layers.0.moe_w_gate"].shape == (2, 64, 32)
+    assert params["layers.0.moe_w_down"].shape == (2, 32, 64)
+    np.testing.assert_array_equal(
+        params["layers.0.moe_w_up"][1],
+        tensors[L + "feed_forward.experts.1.w3.weight"].T)
+    np.testing.assert_array_equal(params["layers.0.router"],
+                                  tensors[L + "feed_forward.gate.weight"].T)
+    del tensors[L + "feed_forward.experts.1.w2.weight"]
+    write(tensors)
+    with pytest.raises(ValueError, match="expert matrices missing"):
+        convert_llama.convert(str(src), str(tmp_path / "out2"))

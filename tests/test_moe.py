@@ -148,3 +148,37 @@ def test_moe_sharded_matches_single_device(axes):
     st = jax.device_put(tokens, NamedSharding(mesh, tok_spec))
     got = jax.jit(lambda p, t: loss_fn(p, t, cfg))(sp, st)
     np.testing.assert_allclose(float(got), float(ref), rtol=2e-2)
+
+
+@pytest.mark.parametrize("factor,drops", [(1.25, True), (64.0, False)],
+                         ids=["capacity_1.25_drops", "ample_capacity_agrees"])
+def test_capacity_layer_against_the_exact_layer(factor, drops):
+    """The nature of the capacity-dropping path, pinned for the day it goes
+    (ROADMAP C11): under a skewed router ``moe_mlp`` at capacity factor 1.25
+    drops pairs and differs from the exact layer (``expert_mlp``: every
+    selected pair computed); at a capacity that holds every pair the two
+    are the same function of the same weights."""
+    import dataclasses
+
+    from nvme_strom_tpu.models.moe import expert_mlp
+    cfg = dataclasses.replace(
+        tiny_moe_config(), dtype=jnp.float32, n_experts=8, expert_top_k=2,
+        moe_every=1, capacity_factor=factor, moe_group_size=64)
+    params = init_params(jax.random.key(4), cfg)
+    L = "layers.0."
+    # a skewed router: experts 0 and 1 draw most first and second choices
+    skew = jnp.zeros((cfg.d_model, 8)).at[:, 0].set(0.6).at[:, 1].set(0.4)
+    params[L + "router"] = 0.3 * params[L + "router"] + skew
+    x = jnp.abs(jax.random.normal(jax.random.key(5), (1, 64, cfg.d_model)))
+    dropped, _ = moe_mlp(x, params, L, cfg)
+    exact_cfg = dataclasses.replace(cfg, mlp_kinds=("experts",) * 2)
+    exact, counts, _ = expert_mlp(x, params, L, exact_cfg)
+    assert int(counts.sum()) == 64 * 2              # the exact layer: all
+    cap = expert_capacity(64, 8, 2, factor)
+    over = int(np.maximum(np.asarray(counts) - cap, 0).sum())
+    diff = np.abs(np.asarray(dropped) - np.asarray(exact)).max()
+    scale = np.abs(np.asarray(exact)).max()
+    if drops:
+        assert over > 0 and diff > 1e-2 * scale
+    else:
+        assert over == 0 and diff < 1e-5 * scale

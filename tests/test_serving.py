@@ -244,7 +244,12 @@ def test_server_stats_gauges(setup):
              # layer carries a recurrent state (tests/test_hybrid.py)
              "kv_layers": cfg.n_layers, "state_bytes": 0, "state_slots": 0,
              # no decode step yet: paged attention has walked nothing
-             "attn_blocks_live": 0, "attn_blocks_table": 0}
+             "attn_blocks_live": 0, "attn_blocks_table": 0,
+             # no layer's MLP is the exact expert layer here, so nothing is
+             # routed (tests/test_lfm2.py has a model that does)
+             "moe_layers": 0, "moe_calls": 0, "moe_pairs": 0,
+             "moe_rows_computed": 0, "moe_experts_touched": 0,
+             "moe_load_max": 0}
     assert s0 == want0
     srv.step()
     s1 = srv.stats()
