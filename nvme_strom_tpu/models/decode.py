@@ -25,7 +25,7 @@ from jax import lax
 from nvme_strom_tpu.models.transformer import (
     wmat,
     TransformerConfig, add_residual, attention, embed_tokens,
-    expand_gqa, lm_logits, mlp, qkv_project, rms_norm)
+    expand_gqa, lm_logits, mlp, qkv_project, rms_norm, valid_rows)
 from nvme_strom_tpu.models import moe as _moe
 
 
@@ -214,9 +214,12 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     ``last``: project lm_head at only this row → logits (b, vocab) —
     admission-style callers that need one next-token distribution skip
     m-1 useless vocab projections (a 128k-vocab lm_head over thousands
-    of pad rows is real FLOPs).
+    of pad rows is real FLOPs).  One row for every sequence, (), or each
+    sequence's own, (b,): a group of prompts of unequal lengths.
 
-    ``n_valid``: rows from it on are right padding.  Attention needs no
+    ``n_valid``, () or (b,) likewise: rows from it on are right padding (a
+    sequence with 0 is all padding: it is routed nowhere and its state
+    comes out as it went in).  Attention needs no
     telling (pad rows sit past every valid row's mask and are overwritten
     before a mask reaches them); a recurrent layer does — its state and
     conv tail (``cache["ssm"]``) stop at row ``n_valid - 1`` — and an
@@ -231,7 +234,7 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     limit = jnp.broadcast_to(pos + jnp.arange(m), (b, m))
     ssm = cache.get("ssm")
     states, tails = (list(ssm["s"]), list(ssm["conv"])) if ssm else ([], [])
-    valid = (jnp.broadcast_to(jnp.arange(m) < n_valid, (b, m))
+    valid = (valid_rows(n_valid, b, m)
              if n_valid is not None and cfg.expert_layers else None)
     calls = []            # the expert layers' (counts, rows computed)
     ai = mi = ti = 0      # this layer's place among its kind's caches
@@ -271,7 +274,8 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     if "moe" in cache and calls:
         cache["moe"] = _moe.add_load(cache["moe"], calls)
     if last is not None:
-        x = x[:, last]
+        rows = jnp.broadcast_to(jnp.asarray(last, jnp.int32), (b,))
+        x = jnp.take_along_axis(x, rows[:, None, None], axis=1)[:, 0]
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return lm_logits(params, cfg, x), cache
 

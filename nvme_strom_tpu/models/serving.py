@@ -18,14 +18,22 @@ its new rows go into the donated pool where they lie and the kernel reads
 the 5-D pool by layer index (ops/paged_attention.py ``write_rows`` /
 ``paged_attention``).
 
-Admission is one compiled program too (``_paged_prefill``): the cached
-prefix gathered into a dense cache, ``decode.block_step`` over the
-right-padded suffix, and the new KV rows written into the donated pool's
-blocks.  It is keyed on shapes alone — (padded suffix length, dense cache
-length), both multiples of ``block_len``.  The true last row, the slot
-and the block ids are data, so prompts of 97 and 128 tokens share one
-program; ``timings["prefill_programs"]`` counts the shapes a server has
-used.  The call returns at dispatch: the logits stay on the device.
+Admission is one compiled program too (``_paged_prefill``), and an
+admission is a GROUP: the prompts one ``step_many`` call admits that may
+share a program (``models/admission.form_groups``: while the group's rows
+stay under the rows at which a prefill's arithmetic outlasts its weight
+read) go through one call, each weight read once for all of them; a single
+request is a group of one.  The program: the cached prefix gathered into a
+dense cache, ``decode.block_step`` over the right-padded suffixes, and each
+row's new KV rows written into its own blocks of the donated pool.  It is
+keyed on shapes alone — (width, padded suffix length, dense cache length),
+the lengths multiples of ``block_len``, the width what the length's rows
+allow (``admission.width_for``: one program a length).  Each row's true
+last position, slot and block ids are data, so prompts of 97 and 128
+tokens share one program, and a row that holds no prompt writes to the
+trash block and the sacrificial state row;
+``timings["prefill_programs"]`` counts the shapes a server has used.
+The call returns at dispatch: the logits stay on the device.
 
 Per-request decoding params: ``max_new``, ``eos_id``, and sampling —
 ``temperature``/``top_p``/``seed`` are per-SLOT vectors (data, like the
@@ -49,6 +57,7 @@ import jax.numpy as jnp
 
 from nvme_strom_tpu.io.tenants import (
     TokenBucket, tenant_context, tenants_enabled, tier_rank)
+from nvme_strom_tpu.models import admission as _adm
 from nvme_strom_tpu.models import decode as _dec
 from nvme_strom_tpu.models import moe as _moe
 from nvme_strom_tpu.models import ssm as _ssm
@@ -95,11 +104,11 @@ def _sample_slots(logits, temps, top_ps, seeds, pos):
     compiled program serves any mix of greedy and sampled requests
     (the per-slot-position trick applied to decoding params).
 
-    Jitted at this level because ``_first_token`` calls it EAGERLY once
-    per admission: un-jitted, the ``lax.cond`` dispatch re-traced its
-    branches every call (~175 ms per admission on a CPU run —
-    it dominated the whole admission phase); inside the jitted step
-    programs the wrapper is inlined and changes nothing.
+    Jitted at this level because an eager call re-traces the
+    ``lax.cond``'s branches every time (~175 ms per admission on a CPU run
+    when admission still called it so); inside the jitted programs
+    (``_paged_step``, ``_admit_slots``) the wrapper is inlined and changes
+    nothing.
 
     logits (B, V) f32; temps/top_ps (B,) f32; seeds (B,) uint32 (per
     request, from submit); pos (B,) int32 — the step index folds into
@@ -139,37 +148,43 @@ def _scatter_blocks(k_pool, v_pool, blks, k_rows, v_rows):
 
 @jax.jit
 def _gather_prefix(k_pool, v_pool, blks):
-    """Pool blocks ``blks`` (c,) → a dense (L, 1, nkv, c * bk, hd) cache
-    pair: the cached prefix at the head of an admission's prefill (one
-    gather per admission — prefix caching trades this HBM read for the
-    prefix's quadratic prefill compute), and the pages ``_store_put``
-    pulls."""
+    """Pool blocks ``blks`` (b, c) → a dense (L, b, nkv, c * bk, hd) cache
+    pair: the cached prefixes at the head of a group's prefill (one gather
+    per program — prefix caching trades this HBM read for the prefix's
+    quadratic prefill compute), and the pages ``_store_put`` pulls."""
     def to_dense(pool):
-        rows = pool[:, blks]                   # (L, c, nkv, bk, hd)
-        L, c, nkv, bk, hd = rows.shape
-        return rows.transpose(0, 2, 1, 3, 4).reshape(L, 1, nkv, c * bk,
-                                                     hd)
+        rows = pool[:, blks]                   # (L, b, c, nkv, bk, hd)
+        L, b, c, nkv, bk, hd = rows.shape
+        return rows.transpose(0, 1, 3, 2, 4, 5).reshape(L, b, nkv, c * bk,
+                                                        hd)
     return to_dense(k_pool), to_dense(v_pool)
+
+
+def _rids(group: list) -> str:
+    """The request ids of a group's plans as ONE span argument (the
+    profiler's annotation takes scalars, and cuts at a ",")."""
+    return " ".join(str(plan["req"].rid) for plan in group)
 
 
 def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
                   k_head, v_head, last, ssm=None, moe=None):
     """The admission prefill, traced inside ``_paged_prefill``:
-    ``block_step`` of the right-padded suffix ``tokens`` (1, m) behind
-    the cached prefix ``k_head``/``v_head`` ((L, 1, nkv, c, hd), or None
+    ``block_step`` of the right-padded suffixes ``tokens`` (b, m) behind
+    the cached prefixes ``k_head``/``v_head`` ((L, b, nkv, c, hd), or None
     when nothing is cached — block_step at pos 0 IS the dense prefill,
     so every admission shares one math).
 
-    Returns (logits (1, vocab) f32 at suffix row ``last``, k, v dense
-    (L, 1, nkv, c + m, hd), for a config with recurrent layers their
-    state after row ``last`` — else None —, and the expert layers' load
-    counters ``moe`` with this prompt's valid rows added).  The pad rows sit
-    past ``last``: causality keeps them out of the logits, and their cache
-    entries are dead — decode overwrites a position before its mask
-    exposes it.  A recurrence has no mask to hide behind: ``n_valid``
-    tells it where the prompt ends."""
-    m = tokens.shape[1]
-    cache = _dec.init_cache(cfg, 1, m)
+    Returns (logits (b, vocab) f32 at each row's own suffix row ``last``
+    (b,), k, v dense (L, b, nkv, c + m, hd), for a config with recurrent
+    layers their state after each row's ``last`` — else None —, and the
+    expert layers' load counters ``moe`` with the group's valid rows added).
+    The pad rows sit past ``last``: causality keeps them out of the logits,
+    and their cache entries are dead — decode overwrites a position before
+    its mask exposes it.  A recurrence has no mask to hide behind:
+    ``n_valid`` tells it where each prompt ends (``last`` -1: a row that
+    holds no prompt, routed nowhere, its state left empty)."""
+    b, m = tokens.shape
+    cache = _dec.init_cache(cfg, b, m)
     if ssm is not None:
         cache["ssm"] = ssm
     if moe is not None:
@@ -189,49 +204,74 @@ def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2, 3, 7))
 def _paged_prefill(params: Dict, cfg: TransformerConfig, k_pool, v_pool,
                    tokens, blks, last, state=None, slot=None):
-    """One admission: ``blks`` (n,) are the pool blocks of
-    the whole padded prompt, ``tokens`` (1, m) its suffix past the
-    cached blocks — so the first ``n - m // block_len`` of ``blks`` are
-    gathered as the prefix (``_gather_prefix``), ``_prefill_rows`` runs
-    the suffix, and its rows land in the remaining blocks of the donated
-    pools.  Block ids and ``last`` are data: the program is keyed on
-    (m, n) only.
+    """One admission, a group of b prompts: ``blks`` (b, n) are each row's
+    pool blocks over the group's longest padded prompt, ``tokens`` (b, m)
+    the suffixes past the cached blocks — so the first ``n - m //
+    block_len`` of every row's ``blks`` are gathered as its prefix
+    (``_gather_prefix``), ``_prefill_rows`` runs the suffixes, and each
+    row's rows land in its remaining blocks of the donated pools.  A shorter
+    prompt has no block for the rows past its own padded length: its table
+    names the trash block there (the pool's last), as a free slot's write
+    does in the step; a row that holds no prompt names nothing else.  Block
+    ids and ``last`` (b,) are data: the program is keyed on (b, m, n) only.
 
     With recurrent or expert layers in ``cfg``, ``state`` is what the
     server's programs carry beside the K/V pools (``init_carried``,
-    donated) and ``slot`` the admitted slot's row of its pools: the prompt
-    starts from an empty state (there is no prefix to resume: a page
-    without the state at its boundary is not one) and the state after row
-    ``last`` overwrites the row — which is why releasing a slot clears
-    nothing; the expert layers' ``"prefill"`` counters take the prompt's
-    valid rows.  Returns (logits, k_pool, v_pool, state); ``state`` stays
-    None for a plain decoder, whose program this leaves as it was."""
+    donated) and ``slot`` (b,) the admitted slots' rows of its pools: every
+    prompt starts from an empty state (there is no prefix to resume: a page
+    without the state at its boundary is not one) and the state after its
+    row ``last`` overwrites its slot's row — which is why releasing a slot
+    clears nothing; a row that holds no prompt names the sacrificial last
+    row.  The expert layers' ``"prefill"`` counters take the group's valid
+    rows.  Returns (logits (b, vocab), k_pool, v_pool, state); ``state``
+    stays None for a plain decoder."""
     bk = k_pool.shape[3]
-    ct = blks.shape[0] - tokens.shape[1] // bk
+    b, m = tokens.shape
+    ct = blks.shape[1] - m // bk
     k_head = v_head = None
     if ct:
-        k_head, v_head = _gather_prefix(k_pool, v_pool, blks[:ct])
+        k_head, v_head = _gather_prefix(k_pool, v_pool, blks[:, :ct])
     moe = state.get("moe") if state else None
     logits, k, v, ssm, load = _prefill_rows(
         params, cfg, tokens, k_head, v_head, last,
-        _ssm.init_state(cfg, 1) if cfg.recurrent_layers else None,
+        _ssm.init_state(cfg, b) if cfg.recurrent_layers else None,
         moe and moe["prefill"])
     if state is not None:
         state = dict(state, **{key: tuple(
-            jax.lax.dynamic_update_slice_in_dim(
-                pool, new.astype(pool.dtype), slot, axis=0)
+            pool.at[slot].set(new.astype(pool.dtype))
             for pool, new in zip(state[key], ssm[key])) for key in ssm or ()})
         if moe:
             state["moe"] = dict(moe, prefill=load)
 
-    def new_rows(dense):                   # → (L, n - ct, nkv, bk, hd)
+    def new_rows(dense):                   # → (L, b * (n - ct), nkv, bk, hd)
         L, _, nkv, _, hd = dense.shape
-        return (dense[:, 0, :, ct * bk:].reshape(L, nkv, -1, bk, hd)
-                .transpose(0, 2, 1, 3, 4))
+        return (dense[:, :, :, ct * bk:].reshape(L, b, nkv, -1, bk, hd)
+                .transpose(0, 1, 3, 2, 4, 5).reshape(L, -1, nkv, bk, hd))
 
-    k_pool, v_pool = _scatter_blocks(k_pool, v_pool, blks[ct:],
+    k_pool, v_pool = _scatter_blocks(k_pool, v_pool, blks[:, ct:].reshape(-1),
                                      new_rows(k), new_rows(v))
     return logits, k_pool, v_pool, state
+
+
+@jax.jit
+def _admit_slots(logits, temp, topp, seed, pos, tok, slots, temps, top_ps,
+                 seeds, lens):
+    """The tail of a group's admission, one program: the b first tokens
+    sampled from ``logits`` (b, vocab) under each request's own params
+    (position ``lens - 1`` folds in, so the first draw differs from the
+    next step's), and the server's per-slot arrays with the group's rows
+    set at ``slots`` (b,) — ``pos`` the prompt's length (nothing decoded
+    past it yet), ``tok`` the token entering the cache on the next step.  A
+    row that holds no prompt names a slot past the last: dropped.  Returns
+    (the b first tokens, each a device scalar, temp, topp, seed, pos, tok)."""
+    first = _sample_slots(logits, temps, top_ps, seeds, lens - 1)
+
+    def put(rows, values):
+        return rows.at[slots].set(values.astype(rows.dtype), mode="drop")
+
+    return (tuple(first[i] for i in range(first.shape[0])),
+            put(temp, temps), put(topp, top_ps), put(seed, seeds),
+            put(pos, lens), put(tok, first))
 
 
 def paged_logits(params: Dict, cfg: TransformerConfig, tok,
@@ -483,16 +523,23 @@ class DecodeServer:
         #: ``prompt_tokens`` (prompt tokens those prefills had to
         #: compute: past the cached prefix), ``scan_tokens`` (valid
         #: tokens through the recurrent layers' scan, pads excluded; 0
-        #: for a plain decoder) and ``prefill_programs``
-        #: (distinct (suffix, cache) shapes this server has prefilled
-        #: with, each one compiled or fetched program: a handful on a
-        #: healthy server, a climbing count is a shape leak).  Per decode
+        #: for a plain decoder), ``prefill_calls`` (prefill programs
+        #: dispatched, one a group: ``admits`` over it is the mean group),
+        #: ``prefill_rows_dead`` (rows of those programs that held no
+        #: prompt: a group of 3 in a program of 4; ``prefill_tokens``
+        #: counts width × length as handed to the program, so the padding
+        #: of a shorter prompt and the dead rows both show in it) and
+        #: ``prefill_programs`` (distinct (width, suffix, cache) shapes
+        #: this server has prefilled with, each one compiled or fetched
+        #: program, one a (suffix, cache) bucket: a handful on a healthy
+        #: server, a climbing count is a shape leak).  Per decode
         #: step, from the host's position mirror: ``attn_blocks_live``
         #: (Σ over active slots of ``pos // block_len + 1``, the table
         #: entries paged attention has to read) and ``attn_blocks_table``
         #: (``B × max_blocks``, the entries it would walk unbounded).  Per
         #: call of an exact expert layer (one per layer per decode step;
-        #: the admissions' under ``*_prefill``), read off the device's
+        #: one per layer per prefill program under ``*_prefill``), read
+        #: off the device's
         #: counters at each readback: ``moe_calls``, ``moe_pairs`` (valid
         #: rows × k routed), ``moe_rows_computed`` (rows the grouped
         #: product ran, tile padding included), ``moe_experts_touched``
@@ -503,6 +550,7 @@ class DecodeServer:
             "steps": 0, "readbacks": 0,
             "admits": 0, "queue_wait_s": 0.0, "prefill_s": 0.0,
             "prefill_tokens": 0, "prompt_tokens": 0,
+            "prefill_calls": 0, "prefill_rows_dead": 0,
             "prefill_programs": 0, "scan_tokens": 0,
             "attn_blocks_live": 0, "attn_blocks_table": 0,
             **{key + sfx: 0 for sfx in ("", "_prefill") for key in (
@@ -556,7 +604,12 @@ class DecodeServer:
         #: ({"decode" | "prefill": {"load", "sums"}} as uint32: a counter
         #: may wrap, a difference of two readings does not)
         self._moe_seen = None
-        self._moe_admits = 0        # admissions at the last readback
+        self._moe_prefills = 0      # prefill programs at the last readback
+        #: rows a prefill program may hold before its arithmetic outlasts
+        #: its weight read on this server's device: what groups are formed
+        #: within (models/admission.py)
+        self._group_rows = _adm.breakeven_rows(
+            cfg, next(iter(self.k_pool.devices())).device_kind)
         self.free: List[int] = list(range(self.total_blocks))
         self.blocks: List[List[int]] = [[] for _ in range(self.B)]
         self._pos_h: List[int] = [0] * self.B   # host mirror of pos
@@ -743,35 +796,84 @@ class DecodeServer:
         of the benchmark loop's bare phase names."""
         return self._tracer().span(name, "strom.serve", ctx, **args)
 
-    def _finish_traced(self, plan: dict, restored: dict) -> None:
-        """``_admit_finish`` under the request's trace scope: the
-        admission span (prefill + scatter) lands in the request's tree,
-        and everything the finish triggers — store puts, engine writes
-        — auto-parents to it via the contextvar.  A tenant-tagged
-        request additionally finishes under its TENANT scope, so the
-        host-cache lines the prefill touches and the store pages the
-        put writes are quota-charged to their owner (io/tenants.py)."""
-        req = plan["req"]
-        if req.tenant is not None:
-            with tenant_context(req.tenant):
+    def _form_groups(self, plans: list, restored: Dict[int, dict]) -> list:
+        """This step's admissions as groups, each a list of plans that goes
+        through one prefill program (``models/admission.form_groups`` over
+        the padded suffix lengths, within the break-even rows of this
+        server's config on its device).  Only requests that differ in
+        nothing a program or a scope is keyed on share one: a request with
+        store pages to scatter or a tenant scope of its own is a group of
+        one, and a group's rows share their count of cached prefix
+        blocks."""
+        bk = self.block_len
+        alone, by_cached = [], {}
+        for plan in plans:
+            if plan["req"].tenant is not None or restored.get(plan["slot"]):
+                alone.append([plan])
+            else:
+                by_cached.setdefault(plan["c"], []).append(plan)
+        groups = []
+        for c, peers in by_cached.items():
+            lengths = [(-(-len(p["req"].prompt) // bk) - c) * bk
+                       for p in peers]
+            groups += [[peers[i] for i in idx] for idx in _adm.form_groups(
+                lengths, self._group_rows)]
+        return alone + groups
+
+    def _finish_traced(self, plan: list, restored: dict) -> None:
+        """The one door an admission goes through: ``plan`` is a GROUP, the
+        list of the plans (``_admit_plan``) that share a prefill program — a
+        single request is a group of one — and ``restored`` the store pages
+        of its only member where it has any.  The admission span (prefill +
+        scatter + first token) lands in the request's tree, and everything
+        the finish triggers — store puts, engine writes — auto-parents to
+        it via the contextvar.  A tenant-tagged request (always a group of
+        one) additionally finishes under its TENANT scope, so the
+        host-cache lines the prefill touches and the store pages the put
+        writes are quota-charged to their owner (io/tenants.py)."""
+        tenant = plan[0]["req"].tenant
+        if tenant is not None:
+            with tenant_context(tenant):
                 self._finish_traced_inner(plan, restored)
         else:
             self._finish_traced_inner(plan, restored)
 
-    def _finish_traced_inner(self, plan: dict, restored: dict) -> None:
-        req = plan["req"]
-        wait = time.monotonic() - req.t_submit
-        self.timings["admits"] += 1
-        self.timings["queue_wait_s"] += wait
-        ctx = req.trace.child() if req.trace is not None else None
+    def _finish_traced_inner(self, group: list, restored: dict) -> None:
+        now = time.monotonic()
+        waits = [now - p["req"].t_submit for p in group]
+        self.timings["admits"] += len(group)
+        self.timings["queue_wait_s"] += sum(waits)
+        # ONE span serves the group's requests: scope it, and with it the
+        # prefill and the first token, under the FIRST traced request's
+        # tree (a group of one is exact) and name every trace id, as the
+        # batched store restore does; the other requests' trees get the
+        # admission as a copy that names the tree the rest is in
+        traced = [p["req"] for p in group if p["req"].trace is not None]
+        ctx = traced[0].trace.child() if traced else None
+        t0_ns = time.monotonic_ns() if len(traced) > 1 else 0
         # rid last: the profiler's encoding cuts the arguments at a ","
-        with self._span("strom.serve.admit", ctx, slot=plan["slot"],
-                        prompt_tokens=len(req.prompt),
-                        cached_blocks=plan.get("c", 0),
+        with self._span("strom.serve.admit", ctx, slot=group[0]["slot"],
+                        rows=len(group),
+                        prompt_tokens=sum(len(p["req"].prompt)
+                                          for p in group),
+                        cached_blocks=group[0].get("c", 0),
                         restored_pages=len(restored),
-                        queue_wait_ms=round(1000.0 * wait, 3),
-                        rid=str(req.rid)):
-            self._admit_finish(plan, restored)
+                        queue_wait_ms=round(1000.0 * max(waits), 3),
+                        rid=_rids(group)) as span:
+            if span and len(traced) > 1:
+                span.set_metadata(traces=" ".join(
+                    f"{r.trace.trace_id:x}" for r in traced))
+            logits = self._admit_prefill(group, restored)
+            for plan in group:
+                self._admit_finish(plan, restored)
+            self._admit_first(group, logits)
+        for req in traced[1:]:
+            self._tracer().add_span(
+                "strom.serve.admit", t0_ns, time.monotonic_ns(),
+                category="strom.serve", ctx=req.trace.child(),
+                rows=len(group), group=f"{traced[0].trace.trace_id:x}",
+                queue_wait_ms=round(1000.0 * (now - req.t_submit), 3),
+                rid=str(req.rid))
 
     def _admit_plan(self, slot: int, req: _Request) -> dict:
         """Capacity phase: HBM prefix-cache refs + block allocation, in
@@ -845,35 +947,34 @@ class DecodeServer:
                     traces=" ".join(f"{t.trace_id:x}" for t in traced))
             return store.restore_many(wants)
 
-    def _admit_finish(self, plan: dict, restored: dict) -> None:
-        """Restored pages into the request's blocks, then the prefill
-        (suffix-only past the cached and restored blocks): one
-        ``_paged_prefill`` call.  The prompt right-pads to a block
-        multiple, so admission compiles once per (suffix, prompt) block
-        count, not once per prompt length; the first-token logits read at
-        the true last position."""
+    def _admit_prefill(self, group: list, restored: dict):
+        """Restored pages into the request's blocks, then the group's
+        prefill (suffixes only, past the cached and restored blocks): one
+        ``_paged_prefill`` call of (width, the longest padded suffix).
+        Prompts right-pad to a block multiple and the group, with dead
+        rows, to the one width its length has (``admission.width_for``),
+        so admission compiles once per (suffix, prompt) bucket, not once
+        per prompt length, per width or per mix: a burst of equal lengths
+        (a warm-up, a cold fill) builds the program that every narrower or
+        mixed group of that length runs later.  Each row's logits are read
+        at its own last position.  Returns the logits (width, vocab), on
+        the device."""
         import numpy as np
-        slot, req = plan["slot"], plan["req"]
-        keys, c, blks = plan["keys"], plan["c"], plan["blks"]
-        s = len(req.prompt)
         bk = self.block_len
-        self.blocks[slot] = blks
-        self._table_dev = None
-        if c:
-            self._pc_hits += 1
-            self._pc_shared_blocks += c
+        c = group[0]["c"]
         # NVMe-restored pages (chain indices past the HBM match, from
         # the step's batched decode-class read) scatter into this
         # request's own new blocks and REGISTER in the HBM cache — the
         # next same-prefix admission hits DRAM, not NVMe
-        rid = str(req.rid)
         use = []                # chain indices c, c + 1, ... without a gap
         while c + len(use) in restored:
             use.append(restored[c + len(use)])
         c2 = len(use)
         if use:
+            plan, = group       # _form_groups leaves such a request alone
+            blks, keys = plan["blks"], plan["keys"]
             with self._span("strom.serve.scatter", blocks=c2,
-                            rid=rid):
+                            rid=str(plan["req"].rid)):
                 rows_k = jnp.asarray(np.stack([k for k, _ in use],
                                               axis=1))
                 rows_v = jnp.asarray(np.stack([v for _, v in use],
@@ -889,58 +990,107 @@ class DecodeServer:
                     for j in range(c2):
                         self._pc_register(keys[c + j], blks[c + j])
         ct = c + c2
+        for plan in group:
+            plan["ct"] = ct
 
-        # prefill: ONE program gathers the cached prefix (HBM-shared +
-        # just-restored blocks), runs block_step over the suffix and
-        # writes its rows into the new blocks; pad rows sit past pos and
-        # are overwritten before the mask reaches them
-        n_pb = -(-s // bk)
-        suffix = req.prompt[ct * bk:]
-        padded = suffix + [0] * (n_pb * bk - s)
-        # padded tokens are handed to prefill, len(suffix) of them prompt
-        # past the cached prefix; the program is keyed on the two shapes
-        self.timings["prefill_tokens"] += len(padded)
-        self.timings["prompt_tokens"] += len(suffix)
-        self._prefill_shapes.add((len(padded), n_pb * bk))
+        # prefill: ONE program gathers the cached prefixes (HBM-shared +
+        # just-restored blocks), runs block_step over the suffixes and
+        # writes each row's rows into its new blocks; pad rows sit past pos
+        # and are overwritten before the mask reaches them, and what a
+        # shorter prompt has no block for goes to the trash block
+        n = max(-(-len(p["req"].prompt) // bk) for p in group)
+        m = (n - ct) * bk
+        b = _adm.width_for(m, self._group_rows)
+        if len(group) > b:
+            raise ValueError(f"{len(group)} prompts in a program of {b}")
+        tokens = np.zeros((b, m), np.int32)
+        blks = np.full((b, n), self._trash, np.int32)
+        last = np.full((b,), -1, np.int32)      # a dead row: nothing valid
+        slots = np.full((b,), self.B, np.int32)     # ... the sacrificial row
+        for i, plan in enumerate(group):
+            suffix = plan["req"].prompt[ct * bk:]
+            n_pb = -(-len(plan["req"].prompt) // bk)
+            tokens[i, :len(suffix)] = suffix
+            blks[i, :n_pb] = plan["blks"][:n_pb]
+            last[i] = len(suffix) - 1
+            slots[i] = plan["slot"]
+        useful = int(last.sum()) + b
+        # b x m tokens are handed to the program, ``useful`` of them prompt
+        # past the cached prefix; the rest is padding, dead rows included
+        self.timings["prefill_calls"] += 1
+        self.timings["prefill_rows_dead"] += b - len(group)
+        self.timings["prefill_tokens"] += b * m
+        self.timings["prompt_tokens"] += useful
+        if self.cfg.recurrent_layers:
+            self.timings["scan_tokens"] += useful
+        self._prefill_shapes.add((b, m, n * bk))
         self.timings["prefill_programs"] = len(self._prefill_shapes)
         t0 = time.monotonic()
-        with self._span("strom.serve.prefill", tokens=len(padded),
-                        useful=len(suffix),
-                        program=f"{len(padded)}x{n_pb * bk}", rid=rid):
+        with self._span("strom.serve.prefill", tokens=b * m, useful=useful,
+                        rows=len(group), program=f"{b}x{m}x{n * bk}",
+                        rid=_rids(group)):
             # positional, so that the state pool is donated with the rest
-            recur = () if self.state is None else (self.state,
-                                                   np.int32(slot))
+            recur = () if self.state is None else (self.state, slots)
             logits, self.k_pool, self.v_pool, self.state = _paged_prefill(
-                self.params, self.cfg, self.k_pool, self.v_pool,
-                np.asarray([padded], np.int32),
-                np.asarray(blks[:n_pb], np.int32), len(suffix) - 1,
-                *recur)
+                self.params, self.cfg, self.k_pool, self.v_pool, tokens,
+                blks, last, *recur)
         self.timings["prefill_s"] += time.monotonic() - t0
-        if self.cfg.recurrent_layers:
-            self.timings["scan_tokens"] += len(suffix)
-        with self._span("strom.serve.scatter", blocks=n_pb - ct,
-                        rid=rid):
+        return logits
+
+    def _admit_finish(self, plan: dict, restored: dict) -> None:
+        """One request of a group after the group's prefill: its blocks
+        into the table, its newly computed full blocks into the prefix
+        cache and the store, the request into its slot.  (``restored`` is
+        the door's second argument, passed on unread: what wraps this
+        method on an instance — the benchmark's controls — wraps the
+        pair.)"""
+        slot, req, blks = plan["slot"], plan["req"], plan["blks"]
+        keys, ct = plan["keys"], plan["ct"]
+        self.blocks[slot] = blks
+        self._table_dev = None
+        if plan["c"]:
+            self._pc_hits += 1
+            self._pc_shared_blocks += plan["c"]
+        with self._span("strom.serve.scatter",
+                        blocks=-(-len(req.prompt) // self.block_len) - ct,
+                        rid=str(req.rid)):
             # newly computed FULL blocks join the cache for future
             # requests
             for i in range(ct, len(keys)):
                 self._pc_register(keys[i], blks[i])
             if self.kv_store is not None:
                 self._store_put(req, slot, ct)
-        # the slot's first token and decoding state — all dispatches,
-        # nothing read back
-        with self._span("strom.serve.first_token", rid=rid):
-            first = self._first_token(logits, req, s)
-            self._pending_first.append((slot, first))
-            self.slots[slot] = req
-            self.temp = self.temp.at[slot].set(req.temperature)
-            self.topp = self.topp.at[slot].set(req.top_p)
-            self.seed = self.seed.at[slot].set(jnp.uint32(req.seed))
-            req.t_admit = time.monotonic()
-            # pos[slot] = s - nothing decoded past the prompt yet; tok
-            # is the token entering the cache on the next step
-            self.pos = self.pos.at[slot].set(s)
-            self.tok = self.tok.at[slot].set(first)
-        self._pos_h[slot] = s
+        self.slots[slot] = req
+        self._pos_h[slot] = len(req.prompt)
+
+    def _admit_first(self, group: list, logits) -> None:
+        """The group's first tokens and decoding state, one program
+        (``_admit_slots``) — a dispatch, nothing read back: admission must
+        never block on a value crossing the link (the round-4 on-silicon
+        row spent 20.6 of 27 s in admit that way); the host copies ride
+        ``step_many``'s single batch readback."""
+        import numpy as np
+        b = logits.shape[0]
+        slots = np.full((b,), self.B, np.int32)     # a dead row: dropped
+        temps = np.zeros((b,), np.float32)
+        top_ps = np.ones((b,), np.float32)
+        seeds = np.zeros((b,), np.uint32)
+        lens = np.ones((b,), np.int32)
+        for i, plan in enumerate(group):
+            req = plan["req"]
+            slots[i], lens[i] = plan["slot"], len(req.prompt)
+            temps[i], top_ps[i], seeds[i] = (req.temperature, req.top_p,
+                                             req.seed)
+        with self._span("strom.serve.first_token", rows=len(group),
+                        rid=_rids(group)):
+            (first, self.temp, self.topp, self.seed, self.pos,
+             self.tok) = _admit_slots(
+                logits, self.temp, self.topp, self.seed, self.pos, self.tok,
+                slots, temps, top_ps, seeds, lens)
+            t_admit = time.monotonic()
+            for plan, tok in zip(group, first):
+                self._pending_first.append((plan["slot"], tok))
+                plan["req"].t_admit = t_admit
 
     def _store_put(self, req: _Request, slot: int, have: int) -> None:
         """Persist this admission's newly computed full prompt pages
@@ -957,27 +1107,12 @@ class DecodeServer:
         P = self.block_len
         k_all, v_all = (np.asarray(a[:, 0]) for a in _gather_prefix(
             self.k_pool, self.v_pool,
-            jnp.asarray(self.blocks[slot][have:n_full], jnp.int32)))
+            jnp.asarray([self.blocks[slot][have:n_full]], jnp.int32)))
         pages = [(keys[i],
                   k_all[:, :, (i - have) * P:(i - have + 1) * P],
                   v_all[:, :, (i - have) * P:(i - have + 1) * P])
                  for i in range(have, n_full)]
         self.kv_store.put(pages)
-
-    def _first_token(self, logits, req: _Request, s: int):
-        """The prefill's next token under the request's own sampling
-        params (same sampler, 1-row view; position s-1 folds in so the
-        first draw differs from the next step's).
-
-        Returns the DEVICE scalar — admission must never read back
-        (the round-4 on-silicon row spent 20.6 of 27 s in admit because
-        every admission blocked on this value crossing the link); the
-        host copy rides ``step_many``'s single batch readback."""
-        return _sample_slots(
-            logits, jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.top_p], jnp.float32),
-            jnp.asarray([req.seed], jnp.uint32),
-            jnp.asarray([s - 1], jnp.int32))[0]
 
     def _drain_pending_first(self) -> None:
         """Deliver deferred first tokens while ``step_many`` unwinds
@@ -1439,11 +1574,11 @@ class DecodeServer:
         seen = self._moe_seen or jax.tree_util.tree_map(np.zeros_like, now)
         self._moe_seen = now
         n_layers = len(self.cfg.expert_layers)
-        admits = self.timings["admits"]
+        prefills = self.timings["prefill_calls"]
         for phase, sfx, calls in (
                 ("decode", "", steps * n_layers),
                 ("prefill", "_prefill",
-                 (admits - self._moe_admits) * n_layers)):
+                 (prefills - self._moe_prefills) * n_layers)):
             load = (now[phase]["load"] - seen[phase]["load"]).astype(np.int64)
             sums = (now[phase]["sums"] - seen[phase]["sums"]).astype(np.int64)
             self.timings["moe_calls" + sfx] += calls
@@ -1453,7 +1588,7 @@ class DecodeServer:
             if phase == "decode":
                 self.moe_load = load if self.moe_load is None \
                     else self.moe_load + load
-        self._moe_admits = admits
+        self._moe_prefills = prefills
 
     def _ensure_params(self) -> None:
         """Resolve a demand-faulting param source on first use: every
@@ -1522,8 +1657,9 @@ class DecodeServer:
         try:
             restored = (self._restore_prefixes(plans)
                         if plans and self.kv_store is not None else {})
-            for plan in plans:
-                self._finish_traced(plan, restored.get(plan["slot"], {}))
+            for group in self._form_groups(plans, restored):
+                self._finish_traced(group,
+                                    restored.get(group[0]["slot"], {}))
             self.timings["admit_s"] += time.monotonic() - t0
             active_slots = [i for i, r in enumerate(self.slots)
                             if r is not None]

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from nvme_strom_tpu.models.transformer import (TransformerConfig, rms_norm,
-                                               wmat)
+                                               valid_rows, wmat)
 from nvme_strom_tpu.ops.ssm import ssm_scan, ssm_update
 
 
@@ -85,6 +85,18 @@ def init_state(cfg: TransformerConfig, rows: int) -> Dict:
             "conv": tuple(jnp.zeros(t, cfg.dtype) for t in tails)}
 
 
+def _tail_at(window, n_valid, k1: int):
+    """What each sequence carries out of a right-padded block: rows
+    ``n_valid[i] .. n_valid[i] + K - 2`` of its ``window`` (b, K - 1 + m, C)
+    — window row n_valid + j is the block's row n_valid - (K - 1) + j, so
+    these are its last K - 1 valid rows (the tail it came in with where the
+    block holds fewer).  ``n_valid`` () or (b,)."""
+    starts = jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32),
+                              window.shape[:1])
+    return jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
+        w, n, k1, axis=0))(window, starts)
+
+
 def _project_in(h, p, L, cfg):
     inner = cfg.ssm_inner
     zu = h @ wmat(p, L + "ssm_in", h.dtype)
@@ -120,8 +132,10 @@ def mamba_block(h, p: Dict, L: str, cfg: TransformerConfig, s0=None,
 
     h (b, m, d) post-norm; s0 (b, H, P, N) float32 and tail (b, K−1, inner
     + 2N): what the sequences carried in (None: nothing yet, zeros);
-    n_valid: rows past it are right padding — they leave state and tail as
-    the last valid row left them.  Returns (out (b, m, d), S, tail)."""
+    n_valid, () or one count a sequence (b,): rows past it are right padding
+    — they leave state and tail as the last valid row left them (a sequence
+    with none keeps what it came in with).  Returns (out (b, m, d), S,
+    tail)."""
     b, m, _ = h.shape
     k1 = cfg.ssm_conv - 1
     z, u, dt = _project_in(h, p, L, cfg)
@@ -140,9 +154,8 @@ def mamba_block(h, p: Dict, L: str, cfg: TransformerConfig, s0=None,
     if n_valid is None:
         new_tail = window[:, m:]
     else:
-        valid = jnp.broadcast_to(jnp.arange(m) < n_valid, (b, m))
-        # window row n_valid + j is u's row n_valid - (K-1) + j
-        new_tail = jax.lax.dynamic_slice_in_dim(window, n_valid, k1, axis=1)
+        valid = valid_rows(n_valid, b, m)
+        new_tail = _tail_at(window, n_valid, k1)
     a = -jnp.exp(p[L + "ssm_A_log"].astype(jnp.float32))
     with jax.named_scope("strom.ssm.scan"):
         y, s = ssm_scan(x, _delta(dt, p, L), a, bm, cm, s0, valid,
@@ -207,9 +220,7 @@ def conv_block(h, p: Dict, L: str, cfg: TransformerConfig, tail=None,
         if n_valid is None:
             new_tail = window[:, m:]
         else:
-            # window row n_valid + j is v's row n_valid - (K-1) + j
-            new_tail = jax.lax.dynamic_slice_in_dim(window, n_valid, k1,
-                                                    axis=1)
+            new_tail = _tail_at(window, n_valid, k1)
         y = (c * conv.astype(c.dtype)) @ wmat(p, L + "conv_out", c.dtype)
     return y, new_tail
 

@@ -414,6 +414,14 @@ def embed_tokens(params: Dict, cfg: "TransformerConfig", tokens):
     return x
 
 
+def valid_rows(n_valid, b: int, m: int):
+    """(b, m) bool: row t of sequence i is real while t < n_valid[i], the
+    rest right padding.  ``n_valid`` is one count for every sequence, (),
+    or one each, (b,)."""
+    ends = jnp.reshape(jnp.asarray(n_valid, jnp.int32), (-1, 1))
+    return jnp.broadcast_to(jnp.arange(m) < ends, (b, m))
+
+
 def add_residual(x, f, cfg: "TransformerConfig"):
     """x + residual_mult · f."""
     if cfg.residual_mult != 1.0:

@@ -366,12 +366,14 @@ def test_step_moves_nothing_pool_sized(topo, monkeypatch, hd):
     assert m.alias_size_in_bytes >= 2 * np.prod(pool_shape) * 2, m
 
 
-@pytest.mark.parametrize("suffix_blocks,blocks", [(4, 4), (1, 4)],
-                         ids=["no_hit", "prefix_hit"])
-def test_prefill_program_fits_one_chip(topo, suffix_blocks, blocks):
+@pytest.mark.parametrize("width,suffix_blocks,blocks", [
+    (1, 4, 4), (1, 1, 4), (2, 1, 1)],
+    ids=["no_hit", "prefix_hit", "group_of_two"])
+def test_prefill_program_fits_one_chip(topo, width, suffix_blocks, blocks):
     """The server's admission program at chip_smoke.py's widths,
-    depth and pool: it compiles, fits, and writes the donated pools in
-    place (no second copy of a pool is ever live)."""
+    depth and pool, for one prompt and for a group: it compiles, fits, and
+    writes the donated pools in place (no second copy of a pool is ever
+    live)."""
     from nvme_strom_tpu.models import serving
     smoke, cfg = _smoke_cfg()
     sh = _one(topo)
@@ -380,8 +382,10 @@ def test_prefill_program_fits_one_chip(topo, suffix_blocks, blocks):
     pool = _spec((cfg.n_layers, smoke.POOL_BLOCKS + 1, NKV, bk, HD),
                  jnp.bfloat16, sh)
     compiled = serving._paged_prefill.lower(
-        params, cfg, pool, pool, _spec((1, suffix_blocks * bk), jnp.int32, sh),
-        _spec((blocks,), jnp.int32, sh), _spec((), jnp.int32, sh)).compile()
+        params, cfg, pool, pool,
+        _spec((width, suffix_blocks * bk), jnp.int32, sh),
+        _spec((width, blocks), jnp.int32, sh),
+        _spec((width,), jnp.int32, sh)).compile()
     m = compiled.memory_analysis()
     pools = 2 * np.prod(pool.shape) * 2
     assert m.alias_size_in_bytes >= pools, m
@@ -434,17 +438,16 @@ def test_hybrid_step_updates_both_caches_in_place(topo, monkeypatch):
                 if " copy(" in line and "= f32[65,64,64,128]" in line]
 
 
-def test_lfm2_step_updates_every_pool_in_place(topo, monkeypatch):
-    """The server's decode step at LFM2-24B-A2B's widths, its first period
-    and one more conv layer (conv, conv, attention, conv, conv: 2 dense
-    MLPs, 3 expert layers of 64 experts), 128 slots: every conv layer's tail
-    pool, the K/V pool and the load counters are aliased input to output,
-    and each expert layer is two calls of the grouped product."""
+def _lfm2_five_layers(topo):
+    """LFM2-24B-A2B's widths, its first period and one more conv layer
+    (conv, conv, attention, conv, conv: 2 dense MLPs, 3 expert layers of 64
+    experts) as the cell serves it — 128 slots, 1,280 blocks of 128 —, as
+    shapes on one described chip: (cfg, sharding, params, one K/V pool,
+    the carried state, the bytes of what a serving program is donated)."""
     import json
     from nvme_strom_tpu.models import serving
     from nvme_strom_tpu.models.transformer import init_params
     from nvme_strom_tpu.tools.convert_llama import config_from_hf
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
                            "lfm2-24b-a2b.json")) as f:
@@ -452,14 +455,58 @@ def test_lfm2_step_updates_every_pool_in_place(topo, monkeypatch):
     hf = dict(hf, num_hidden_layers=5, layer_types=hf["layer_types"][:5])
     cfg = config_from_hf(hf)
     sh = _one(topo)
-    B, blocks, bk = 128, 1280, 128
     params = {k: _spec(v.shape, jnp.bfloat16, sh) for k, v in jax.eval_shape(
         lambda: init_params(jax.random.key(0), cfg)).items()}
-    pool = _spec((1, blocks + 1, cfg.n_kv_heads, bk, cfg.head_dim),
+    pool = _spec((1, 1280 + 1, cfg.n_kv_heads, 128, cfg.head_dim),
                  jnp.bfloat16, sh)
     state = jax.tree_util.tree_map(
         lambda a: _spec(a.shape, a.dtype, sh),
-        jax.eval_shape(lambda: serving.init_carried(cfg, B + 1)))
+        jax.eval_shape(lambda: serving.init_carried(cfg, 128 + 1)))
+    donated = (2 * np.prod(pool.shape) * 2
+               + sum(np.prod(a.shape) * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(state)))
+    return cfg, sh, params, pool, state, donated
+
+
+@pytest.mark.parametrize("width,rows", [(2, 1024), (4, 512), (4, 128)])
+def test_lfm2_group_prefill_updates_every_pool_in_place(topo, monkeypatch,
+                                                        width, rows):
+    """The admission program of a GROUP at LFM2-24B-A2B's widths (the same
+    five layers as the step below) at the programs the grouping rule gives
+    its lengths there — two prompts of 1,024 rows, four of 512, four of
+    128: it compiles for a v5e, the K/V pool, every conv layer's
+    tail pool and the load counters are aliased input to output, each expert
+    layer is two calls of the grouped product over ALL the group's rows,
+    and what it needs beside the 12-layer model's 12.11 GiB fits the chip."""
+    from nvme_strom_tpu.models import serving
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, sh, params, pool, state, donated = _lfm2_five_layers(topo)
+    bk = 128
+    vec = _spec((width,), jnp.int32, sh)
+    compiled = serving._paged_prefill.lower(
+        params, cfg, pool, pool, _spec((width, rows), jnp.int32, sh),
+        _spec((width, rows // bk), jnp.int32, sh), vec, state, vec).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3 * 2       # gmm only
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= donated, m
+    weights = sum(np.prod(a.shape) * 2 for a in params.values())
+    scratch = (m.argument_size_in_bytes + m.output_size_in_bytes
+               + m.temp_size_in_bytes - m.alias_size_in_bytes - weights)
+    # beside the cell's 13.13 GiB (weights, pools, the step's own scratch)
+    assert scratch < (15.75 - 13.13) * 2 ** 30, (scratch / 2 ** 30, m)
+
+
+def test_lfm2_step_updates_every_pool_in_place(topo, monkeypatch):
+    """The server's decode step at LFM2-24B-A2B's widths, its first period
+    and one more conv layer (conv, conv, attention, conv, conv: 2 dense
+    MLPs, 3 expert layers of 64 experts), 128 slots: every conv layer's tail
+    pool, the K/V pool and the load counters are aliased input to output,
+    and each expert layer is two calls of the grouped product."""
+    from nvme_strom_tpu.models import serving
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, sh, params, pool, state, donated = _lfm2_five_layers(topo)
+    B, bk = 128, 128
     assert len(state["conv"]) == 4 and not state["s"]
     vec = lambda dt: _spec((B,), dt, sh)                    # noqa: E731
     compiled = serving._paged_step.lower(
@@ -470,9 +517,6 @@ def test_lfm2_step_updates_every_pool_in_place(topo, monkeypatch):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2 + 3 * 2  # write, attend; gmm
     assert not pool_sized_ops(text, pool.shape)
-    donated = (2 * np.prod(pool.shape) * 2
-               + sum(np.prod(a.shape) * a.dtype.itemsize
-                     for a in jax.tree_util.tree_leaves(state)))
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= donated, m
     # the router's scores are the one f32[slots, experts] array of the step:

@@ -21,7 +21,7 @@ if ROOT not in sys.path:
 
 from benchmark import weights_hybrid as WH                      # noqa: E402
 from benchmark.reference import granite_hybrid as ref           # noqa: E402
-from nvme_strom_tpu.models import serving                       # noqa: E402
+from nvme_strom_tpu.models import admission, serving            # noqa: E402
 from nvme_strom_tpu.models.serving import DecodeServer        # noqa: E402
 from nvme_strom_tpu.ops.ssm import ssm_scan, ssm_update         # noqa: E402
 from nvme_strom_tpu.tools.convert_llama import config_from_hf   # noqa: E402
@@ -73,18 +73,20 @@ def _reference(prompt, tokens, hf=HF):
 @pytest.fixture
 def spy(monkeypatch):
     """Record the logits every token of every request was sampled from: the
-    prefill's (``_first_token``), then each decode step's — ``paged_logits``
+    prefill's (``_admit_first``, a group's rows), then each decode step's — ``paged_logits``
     compiled as the step compiles it, minus the donation.  Returns
     ``run(srv, lookahead) -> {rid: (tokens, logits (n, vocab))}``."""
     rows = {}
     step_logits = jax.jit(serving.paged_logits, static_argnums=(1,))
 
     def run(srv, lookahead=1):
-        first = srv._first_token
+        first = srv._admit_first
 
-        def first_spy(logits, req, s):
-            rows.setdefault(req.rid, []).append(np.asarray(logits[0]))
-            return first(logits, req, s)
+        def first_spy(group, logits):
+            for i, plan in enumerate(group):
+                rows.setdefault(plan["req"].rid, []).append(
+                    np.asarray(logits[i]))
+            return first(group, logits)
 
         def step_spy(params, cfg, tok, k_pool, v_pool, blk, off, table,
                      pos, temps, top_ps, seeds, *recur):
@@ -97,8 +99,8 @@ def spy(monkeypatch):
             nxt = serving._sample_slots(logits, temps, top_ps, seeds, pos)
             return nxt, k_pool, v_pool, (state[0] if state else None)
 
-        if srv._first_token.__name__ != "first_spy":   # once per server
-            srv._first_token = first_spy
+        if srv._admit_first.__name__ != "first_spy":   # once per server
+            srv._admit_first = first_spy
         monkeypatch.setattr(serving, "_paged_step", step_spy)
         out = srv.run(lookahead=lookahead)
         return {rid: (toks, np.stack(rows[rid][:len(toks)]))
@@ -128,7 +130,14 @@ def test_server_logits_match_the_reference(model, spy, n_prompt, lookahead):
     _close(logits, want)
     assert tokens == np.argmax(want, -1).tolist()
     assert srv.timings["scan_tokens"] == n_prompt
-    assert srv.timings["prefill_tokens"] == -(-n_prompt // BLOCK) * BLOCK
+    # ... as handed to the length's one program: padded to blocks, times
+    # the program's width (two prompts of 8 rows are under this model's
+    # break-even on the CPU, so a lone one has a dead row beside it)
+    padded = -(-n_prompt // BLOCK) * BLOCK
+    width = admission.width_for(padded, srv._group_rows)
+    assert width == (2 if padded == 8 else 1)
+    assert srv.timings["prefill_tokens"] == width * padded
+    assert srv.timings["prefill_rows_dead"] == width - 1
 
 
 # -- (2) the scan kernel ----------------------------------------------------

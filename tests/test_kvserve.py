@@ -697,8 +697,8 @@ def test_store_restored_admission_runs_the_prefill_program(
         del pulls[:]
         with monkeypatch.context() as m:
             m.setattr(np, "asarray", asarray)
-            for plan in plans:
-                srv._finish_traced(plan, restored.get(plan["slot"], {}))
+            for group in srv._form_groups(plans, restored):
+                srv._finish_traced(group, restored.get(group[0]["slot"], {}))
         return srv.run()[rid]
 
     a = sys_prompt + [7, 8, 9, 10, 11]        # 4 full pages + 1 token
@@ -713,6 +713,7 @@ def test_store_restored_admission_runs_the_prefill_program(
     b = sys_prompt + [1, 2, 3, 4, 5]
     assert admit(srv, "b", b) == _solo(params, cfg, b, 5)
     assert engine.stats.kv_pages_restored == 3
-    assert srv._prefill_shapes == {(2 * PAGE, 5 * PAGE)}
+    assert {shape[1:] for shape in srv._prefill_shapes} == {
+        (2 * PAGE, 5 * PAGE)}
     assert pulls == [(L, nkv, PAGE, hd)] * 2
     store.close()

@@ -76,17 +76,19 @@ def _reference(prompt, tokens, hf=HF):
 @pytest.fixture
 def spy(monkeypatch):
     """Record the logits every token of every request was sampled from: the
-    prefill's (``_first_token``), then each decode step's (``paged_logits``
+    prefill's (``_admit_first``, a group's rows), then each decode step's (``paged_logits``
     compiled as the step compiles it, minus the donation)."""
     rows = {}
     step_logits = jax.jit(serving.paged_logits, static_argnums=(1,))
 
     def run(srv, lookahead=1):
-        first = srv._first_token
+        first = srv._admit_first
 
-        def first_spy(logits, req, s):
-            rows.setdefault(req.rid, []).append(np.asarray(logits[0]))
-            return first(logits, req, s)
+        def first_spy(group, logits):
+            for i, plan in enumerate(group):
+                rows.setdefault(plan["req"].rid, []).append(
+                    np.asarray(logits[i]))
+            return first(group, logits)
 
         def step_spy(params, cfg, tok, k_pool, v_pool, blk, off, table,
                      pos, temps, top_ps, seeds, *recur):
@@ -99,8 +101,8 @@ def spy(monkeypatch):
             nxt = serving._sample_slots(logits, temps, top_ps, seeds, pos)
             return nxt, k_pool, v_pool, state
 
-        if srv._first_token.__name__ != "first_spy":
-            srv._first_token = first_spy
+        if srv._admit_first.__name__ != "first_spy":
+            srv._admit_first = first_spy
         monkeypatch.setattr(serving, "_paged_step", step_spy)
         out = srv.run(lookahead=lookahead)
         return {rid: (toks, np.stack(rows[rid][:len(toks)]))
@@ -153,7 +155,10 @@ def test_unequal_prompts_side_by_side_match_the_reference(model, spy):
     n_exp, k = len(srv.cfg.expert_layers), HF["num_experts_per_tok"]
     # no pair is dropped, and no pad row or free slot is routed
     assert t["moe_pairs_prefill"] == rows * k * n_exp
-    assert t["moe_calls_prefill"] == 3 * n_exp
+    # 32 rows alone, 24 and 8 in one call of the program of (2, 24): a call
+    # of an expert layer is a program's, whatever it holds
+    assert (t["admits"], t["prefill_calls"]) == (3, 2)
+    assert t["moe_calls_prefill"] == 2 * n_exp
     assert t["moe_pairs"] % (k * n_exp) == 0 and t["moe_pairs"] > 0
     assert t["moe_pairs"] <= 3 * k * t["moe_calls"]
     assert t["moe_rows_computed"] >= t["moe_pairs"]

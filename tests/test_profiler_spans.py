@@ -210,14 +210,31 @@ def test_chrome_spans_carry_one_trace_id_per_request(traced):
     assert len(roots) == 4              # r0, r1 (paged); a, b (store)
     ids = {e["args"]["trace"] for e in roots}
     assert len(ids) == 4
+    by_int = {int(tid, 16): tid for tid in ids}
+    shared = []
     for tid in ids:
-        mine = [e["name"] for e in evs
-                if e.get("args", {}).get("trace") == tid]
-        for name in ("strom.serve.admit", "strom.serve.prefill",
-                     "strom.serve.scatter", "strom.serve.first_token"):
-            assert mine.count(name) >= 1, (tid, name)
-        assert mine.count("strom.serve.admit") == 1
+        (admit,) = [e for e in evs if e["name"] == "strom.serve.admit"
+                    and e["args"].get("trace") == tid]
+        # a request that shared its prefill program with another has the
+        # admission in its own tree and finds the program's spans in the
+        # tree that admission names
+        lead = tid
+        if "group" in admit["args"]:
+            lead = by_int[int(admit["args"]["group"], 16)]
+            shared.append((tid, lead))
+        names = [e["name"] for e in evs
+                 if e.get("args", {}).get("trace") == lead]
+        for name in ("strom.serve.prefill", "strom.serve.scatter",
+                     "strom.serve.first_token"):
+            assert names.count(name) >= 1, (tid, name)
         assert connected_tree(evs, tid)
+    # r0 and r1 (11 and 16 tokens: one bucket) went through ONE program
+    ((member, lead),) = shared
+    (group,) = [e for e in evs if e["name"] == "strom.serve.admit"
+                and e["args"].get("trace") == lead]
+    assert group["args"]["rows"] == 2 and group["args"]["rid"] == "r0 r1"
+    assert {int(t, 16) for t in group["args"]["traces"].split()} == {
+        int(member, 16), int(lead, 16)}
     # the batched store restore names its requests in one string, and the
     # admission keeps the wait it always carried
     (kv,) = [e for e in evs if e["name"] == "strom.serve.kv_restore"]

@@ -256,7 +256,11 @@ def test_server_stats_gauges(setup):
     assert s1["slots_busy"] == 2 and s1["queued"] == 0
     # one step, both slots inside their first block of a table 8 wide
     assert (s1["attn_blocks_live"], s1["attn_blocks_table"]) == (2, 16)
-    assert s1["prefill_programs"] == 1      # both prompts: one block
+    # both prompts, one block each, went through ONE call of the bucket's
+    # one program (four wide at four rows a prompt: two rows dead)
+    assert s1["prefill_programs"] == 1
+    assert (srv.timings["admits"], srv.timings["prefill_calls"],
+            srv.timings["prefill_rows_dead"]) == (2, 1, 2)
     assert s1["blocks_free"] == 2 and s1["inflight_tokens"] >= 2
     srv.run()
     s2 = srv.stats()
@@ -573,19 +577,22 @@ def test_pending_first_restored_on_readback_failure(setup, monkeypatch):
 # -- admission's prefill as one compiled program ---------------------------
 
 @pytest.mark.parametrize("pool,prompt_lens,shared,programs", [
-    # no hit: one block of 128 (slots) / two blocks of 8 (shared)
-    ("slots", (9, 16), 0, {(128, 128)}),
-    ("shared", (9, 16), 0, {(16, 16)}),
+    # no hit: one block of 128 (slots) / two blocks of 8 (shared); 128 rows
+    # are past this model's break-even on the CPU (35), so width 1; the
+    # program of 16 rows holds two prompts
+    ("slots", (9, 16), 0, {(1, 128, 128)}),
+    ("shared", (9, 16), 0, {(2, 16, 16)}),
     # HBM prefix-cache hit: the second prompt shares two full blocks and
     # prefills its last block only, against the same 24-row cache
-    ("shared", (20, 19), 16, {(24, 24), (8, 24)}),
+    ("shared", (20, 19), 16, {(1, 24, 24), (4, 8, 24)}),
 ])
 def test_served_tokens_match_generate_through_the_prefill_program(
         setup, pool, prompt_lens, shared, programs):
     """Greedy tokens out of the compiled admission are ``generate()``'s,
-    and the program is keyed on (padded suffix, cache) lengths alone:
-    prompts of different lengths inside one bucket build ONE program —
-    the true last row, the slot and the block ids do not retrace."""
+    and the program is keyed on (width, padded suffix, cache) alone, the
+    width following from the suffix: prompts of different lengths inside
+    one bucket build ONE program — the true last row, the slot and the
+    block ids do not retrace."""
     cfg, params = setup
     rng = np.random.default_rng(31)
     head = rng.integers(0, cfg.vocab, shared).tolist()
@@ -640,7 +647,7 @@ def test_admission_reads_nothing_back(setup, pool, monkeypatch):
         m.setattr(np, "asarray", asarray)
         m.setattr(arr, "_value", property(pull))  # int(), .tolist(), ...
         for plan in srv._plan_admissions():
-            srv._finish_traced(plan, {})
+            srv._finish_traced([plan], {})
     assert not pulled and len(srv._pending_first) == 1
     assert srv.run()["r"] == _solo(params, cfg, [5, 6, 7, 8, 9], 4)
 
