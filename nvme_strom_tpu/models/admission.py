@@ -80,6 +80,12 @@ def prefill_counts(cfg) -> tuple:
                        * (cfg.ssm_chunk + 2 * cfg.ssm_state))
         elif kind == "conv":
             mix = 4 * d * d
+        elif cfg.latent:
+            mix = (d * cfg.q_lora_rank + cfg.q_lora_rank * cfg.n_heads
+                   * (cfg.qk_nope_dim + cfg.qk_rope_dim)
+                   + d * cfg.latent_width + cfg.kv_lora_rank * cfg.n_heads
+                   * (cfg.qk_nope_dim + cfg.v_head_dim)
+                   + cfg.n_heads * cfg.v_head_dim * d)
         else:
             mix = 2 * d * hd * (cfg.n_heads + cfg.n_kv_heads)
         read += mix
@@ -90,9 +96,13 @@ def prefill_counts(cfg) -> tuple:
         else:
             width = (cfg.expert_width if cfg.mlp_kind(i) == "experts"
                      else cfg.d_ff)
-            router = d * cfg.n_experts
-            read += cfg.n_experts * 3 * d * width + router
-            rowops += cfg.expert_top_k * 3 * d * width + router
+            # every row runs the router and a shared expert; of its k
+            # routed pairs the share that falls on the experts held here
+            rest = d * cfg.n_experts + 3 * d * cfg.d_shared
+            held = cfg.experts_local
+            read += held * 3 * d * width + rest
+            rowops += (cfg.expert_top_k * held * 3 * d * width
+                       // cfg.n_experts + rest)
     return read + d * cfg.vocab, rowops
 
 

@@ -26,6 +26,7 @@ from nvme_strom_tpu.models.transformer import (
     wmat,
     TransformerConfig, add_residual, attention, embed_tokens,
     expand_gqa, lm_logits, mlp, qkv_project, rms_norm, valid_rows)
+from nvme_strom_tpu.models import mla as _mla
 from nvme_strom_tpu.models import moe as _moe
 
 
@@ -38,9 +39,13 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Dict:
     prompt_len + max_new_tokens, exactly enough)."""
     shape = (len(cfg.attn_layers), batch, cfg.n_kv_heads, max_len,
              cfg.head_dim)
+    if cfg.latent:
+        # one latent row a token a layer (models/mla.py) where the others
+        # keep K and V: "k" holds it as one head of latent_width, "v" nothing
+        shape = shape[:2] + (1, max_len, cfg.latent_width)
     cache = {
         "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
+        "v": None if cfg.latent else jnp.zeros(shape, cfg.dtype),
         "pos": jnp.zeros((), jnp.int32),
     }
     if cfg.recurrent_layers:
@@ -106,7 +111,7 @@ def prefill(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     them.
     """
     b, s = tokens.shape
-    if cfg.recurrent_layers or cfg.expert_layers:
+    if cfg.recurrent_layers or cfg.expert_layers or cfg.latent:
         # block_step from an empty cache IS the prefill, state included
         # (and it is what tells an expert layer which rows are padding)
         return block_step(params, tokens, cfg, cache,
@@ -150,6 +155,7 @@ def decode_step(params: Dict, token: jax.Array, cfg: TransformerConfig,
         logits, cache = block_step(params, token[:, None], cfg, cache)
         return logits[:, 0], cache
     cfg.require_no_recurrent("decode_step with a cache_attn kernel")
+    cfg.require_kv_pages("decode_step with a cache_attn kernel")
     b = token.shape[0]
     pos = cache["pos"]
     x = embed_tokens(params, cfg, token[:, None])              # (b, 1, d)
@@ -251,6 +257,18 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
             from nvme_strom_tpu.models.ssm import conv_block
             a, tails[ti] = conv_block(h, params, L, cfg, tails[ti], n_valid)
             ti += 1
+        elif cfg.latent:
+            # the expanded form over the cached latent rows, the block's
+            # own among them (models/mla.py)
+            with jax.named_scope("strom.attn.mla"):
+                q, rows = _mla.project(h, params, L, cfg, positions)
+                cache["k"] = lax.dynamic_update_slice(
+                    cache["k"], rows[None, :, None].astype(cfg.dtype),
+                    (ai, 0, 0, pos, 0))
+                a = _mla.attend(q, cache["k"][ai, :, 0], pos, params, L,
+                                cfg)
+            a = a @ wmat(params, L + "wo", a.dtype)
+            ai += 1
         else:
             q, k, v = qkv_project(h, params, L, cfg, positions=positions)
             cache["k"] = lax.dynamic_update_slice(

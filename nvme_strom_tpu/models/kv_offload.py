@@ -229,6 +229,7 @@ class PagedKVCache:
 
     def __init__(self, cfg: TransformerConfig, ocfg: OffloadConfig,
                  engine: StromEngine, batch: int, device=None):
+        cfg.require_kv_pages("PagedKVCache (models/kv_offload.py)")
         self.cfg = cfg
         self.ocfg = ocfg
         self.engine = engine
@@ -977,6 +978,7 @@ class PrefixStore:
                  p99_target_ms: float = 0.0):
         import hashlib
         import threading
+        cfg.require_kv_pages("PrefixStore (models/kv_offload.py)")
         if page_tokens < 1:
             raise ValueError(f"page_tokens must be >= 1, "
                              f"got {page_tokens}")

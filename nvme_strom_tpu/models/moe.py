@@ -173,23 +173,22 @@ def route(x, p: dict, prefix: str, cfg):
     return sel.astype(jnp.int32), w
 
 
-def expert_mlp(x: jax.Array, p: dict, prefix: str, cfg, valid=None) -> tuple:
-    """The exact expert layer.  x (b, s, d) -> (out (b, s, d), counts (E,)
-    int32 — pairs routed to each expert —, rows the grouped product ran,
-    tile padding included ()).
+#: (row, expert) pairs one grouped layout may be made for: its static rows
+#: are the pairs' worst case however few fall on the experts held here, and
+#: at 8,192 rows x 8 they would be 0.96 GB a buffer at d 7168.  A longer
+#: call walks its rows in chunks of this many pairs (LFM2's widest prefill,
+#: 2 x 1,024 rows x 4, is one chunk).
+MAX_PAIRS = 16384
 
-    ``valid`` (b, s) bool or None: rows that are not valid (right padding
-    of a prefill, a free serving slot) are routed nowhere — they cost no
-    expert a row, count in no histogram, and come out as zeros."""
+
+def _held_pairs(xt, sel, w, p: dict, prefix: str, cfg, tm: int) -> tuple:
+    """The routed part of ``expert_mlp`` for rows xt (T, d): sel (T, k) the
+    pair's expert among those held here, ``experts_local`` for a pair that
+    is computed nowhere.  Returns (out (T, d) float32, counts, rows the
+    grouped product ran)."""
     from nvme_strom_tpu.ops import moe as _ops
-    b, s, d = x.shape
-    T, E, k = b * s, cfg.n_experts, cfg.expert_top_k
-    xt = x.reshape(T, d)
-    tm = _ops.tile_rows(T * k, E)
+    T, k, E = xt.shape[0], sel.shape[1], cfg.experts_local
     with jax.named_scope("strom.moe.route"):
-        sel, w = route(xt, p, prefix, cfg)
-        if valid is not None:
-            sel = jnp.where(valid.reshape(T, 1), sel, E)
         dest, tile_expert, n_tiles, counts = _ops.group_rows(
             sel.reshape(T * k), E, tm)
         rows = _ops.padded_rows(T * k, E, tm)
@@ -200,10 +199,10 @@ def expert_mlp(x: jax.Array, p: dict, prefix: str, cfg, valid=None) -> tuple:
         xs = jnp.where((src < T * k)[:, None],
                        xt[jnp.minimum(src, T * k - 1) // k], 0)
     with jax.named_scope("strom.moe.experts"):
-        h = _ops.gmm(xs, (_tr.wmat(p, prefix + "moe_w_gate", x.dtype),
-                          _tr.wmat(p, prefix + "moe_w_up", x.dtype)),
+        h = _ops.gmm(xs, (_tr.wmat(p, prefix + "moe_w_gate", xt.dtype),
+                          _tr.wmat(p, prefix + "moe_w_up", xt.dtype)),
                      tile_expert, n_tiles, tm=tm)
-        y = _ops.gmm(h, (_tr.wmat(p, prefix + "moe_w_down", x.dtype),),
+        y = _ops.gmm(h, (_tr.wmat(p, prefix + "moe_w_down", xt.dtype),),
                      tile_expert, n_tiles, tm=tm)
     with jax.named_scope("strom.moe.route"):
         dest = dest.reshape(T, k)
@@ -211,7 +210,59 @@ def expert_mlp(x: jax.Array, p: dict, prefix: str, cfg, valid=None) -> tuple:
         picked = y[jnp.minimum(dest, rows - 1)].astype(jnp.float32)
         out = jnp.sum(jnp.where(live[..., None], w[..., None] * picked, 0.0),
                       axis=1)
-    return (out.astype(x.dtype).reshape(b, s, d), counts, n_tiles * tm)
+    return out, counts, n_tiles * tm
+
+
+def expert_mlp(x: jax.Array, p: dict, prefix: str, cfg, valid=None) -> tuple:
+    """The exact expert layer.  x (b, s, d) -> (out (b, s, d), counts
+    (experts held,) int32 — pairs that fell on each expert held here —,
+    rows the grouped product ran, tile padding included ()).
+
+    ``valid`` (b, s) bool or None: rows that are not valid (right padding
+    of a prefill, a free serving slot) are routed nowhere — they cost no
+    expert a row, count in no histogram, and come out as zeros.
+
+    The router scores all ``n_experts``; a device that holds a share of
+    them (``cfg.experts_held`` from ``cfg.expert_offset``) computes the
+    pairs that fall on its own and sends the others nowhere, as it does pad
+    rows: their part of the sum is another device's.  The weights are
+    normalised over all the selected experts, held or not.  A shared expert
+    (``cfg.d_shared``) takes every row beside the routed ones."""
+    from nvme_strom_tpu.ops import moe as _ops
+    b, s, d = x.shape
+    T, k, held = b * s, cfg.expert_top_k, cfg.experts_local
+    xt = x.reshape(T, d)
+    tm = _ops.tile_rows(min(T * k, MAX_PAIRS), cfg.n_experts)
+    with jax.named_scope("strom.moe.route"):
+        sel, w = route(xt, p, prefix, cfg)
+        if held != cfg.n_experts:
+            sel = sel - cfg.expert_offset
+            sel = jnp.where((sel >= 0) & (sel < held), sel, held)
+        if valid is not None:
+            sel = jnp.where(valid.reshape(T, 1), sel, held)
+    if T * k <= MAX_PAIRS:
+        out, counts, rows = _held_pairs(xt, sel, w, p, prefix, cfg, tm)
+    else:
+        # a long prefill: chunks of MAX_PAIRS pairs, one layout at a time
+        # (each chunk reads the experts it touches again: a few hundred
+        # rows an expert amortise that)
+        tc = MAX_PAIRS // k
+        pad = -T % tc
+
+        def chunks(a, fill):
+            a = jnp.pad(a, ((0, pad), (0, 0)), constant_values=fill)
+            return a.reshape(-1, tc, a.shape[-1])
+
+        out, counts, rows = jax.lax.map(
+            lambda c: _held_pairs(*c, p, prefix, cfg, tm),
+            (chunks(xt, 0), chunks(sel, held), chunks(w, 0)))
+        out = out.reshape(-1, d)[:T]
+        counts, rows = counts.sum(axis=0), rows.sum()
+    out = out.astype(x.dtype).reshape(b, s, d)
+    if cfg.d_shared:
+        with jax.named_scope("strom.moe.shared"):
+            out = out + _tr.mlp(x, p, prefix + "shared_")
+    return out, counts, rows
 
 
 #: columns of ``load_counters``' "sums": per expert layer, summed over calls
@@ -220,11 +271,12 @@ SUMS = ("experts_touched", "rows_computed", "load_max")
 
 def load_counters(cfg) -> dict:
     """Zeroed device counters of the exact expert layers: ``load``
-    (expert layers, E) int32, pairs routed to each expert, and ``sums``
+    (expert layers, experts held) int32, pairs that fell on each expert
+    held here, and ``sums``
     (expert layers, 3) int32: experts touched, rows computed, and the
     busiest expert's load, each summed over the calls."""
     n = len(cfg.expert_layers)
-    return {"load": jnp.zeros((n, cfg.n_experts), jnp.int32),
+    return {"load": jnp.zeros((n, cfg.experts_local), jnp.int32),
             "sums": jnp.zeros((n, len(SUMS)), jnp.int32)}
 
 
@@ -243,14 +295,21 @@ def init_moe_params(keys, cfg, prefix: str, dense) -> dict:
     ``dense`` is the caller's initializer (transformer.dense_init — passed
     in rather than imported to keep moe.py import-cycle-free)."""
     E, dm, ff = cfg.n_experts, cfg.d_model, cfg.expert_width
+    held = cfg.experts_local        # the router is whole, the experts a share
     out = {
         prefix + "router": dense(next(keys), dm, (dm, E)),
-        prefix + "moe_w_gate": dense(next(keys), dm, (E, dm, ff)),
-        prefix + "moe_w_up": dense(next(keys), dm, (E, dm, ff)),
-        prefix + "moe_w_down": dense(next(keys), ff, (E, ff, dm)),
+        prefix + "moe_w_gate": dense(next(keys), dm, (held, dm, ff)),
+        prefix + "moe_w_up": dense(next(keys), dm, (held, dm, ff)),
+        prefix + "moe_w_down": dense(next(keys), ff, (held, ff, dm)),
     }
     if cfg.router_bias:
         out[prefix + "router_bias"] = jnp.zeros((E,), jnp.float32)
+    if cfg.d_shared:
+        ds = cfg.d_shared
+        out.update({
+            prefix + "shared_w_gate": dense(next(keys), dm, (dm, ds)),
+            prefix + "shared_w_up": dense(next(keys), dm, (dm, ds)),
+            prefix + "shared_w_down": dense(next(keys), ds, (ds, dm))})
     return out
 
 

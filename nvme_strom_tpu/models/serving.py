@@ -59,6 +59,7 @@ from nvme_strom_tpu.io.tenants import (
     TokenBucket, tenant_context, tenants_enabled, tier_rank)
 from nvme_strom_tpu.models import admission as _adm
 from nvme_strom_tpu.models import decode as _dec
+from nvme_strom_tpu.models import mla as _mla
 from nvme_strom_tpu.models import moe as _moe
 from nvme_strom_tpu.models import ssm as _ssm
 from nvme_strom_tpu.models.decode import _mlp_block
@@ -140,9 +141,12 @@ def _sample_slots(logits, temps, top_ps, seeds, pos):
 def _scatter_blocks(k_pool, v_pool, blks, k_rows, v_rows):
     """KV rows (L, n, nkv, bk, hd) into pool blocks ``blks`` (n,): the
     tail of ``_paged_prefill``, and on its own (one donated program, no
-    per-block pool copies) for pages restored from the store."""
+    per-block pool copies) for pages restored from the store.  A latent
+    pool is the one array ``k_pool`` (L, blocks, width, bk) beside no
+    ``v_pool``; its rows come as blocks of it, (L, n, width, bk)."""
     k_pool = k_pool.at[:, blks].set(k_rows.astype(k_pool.dtype))
-    v_pool = v_pool.at[:, blks].set(v_rows.astype(v_pool.dtype))
+    if v_pool is not None:
+        v_pool = v_pool.at[:, blks].set(v_rows.astype(v_pool.dtype))
     return k_pool, v_pool
 
 
@@ -151,7 +155,14 @@ def _gather_prefix(k_pool, v_pool, blks):
     """Pool blocks ``blks`` (b, c) → a dense (L, b, nkv, c * bk, hd) cache
     pair: the cached prefixes at the head of a group's prefill (one gather
     per program — prefix caching trades this HBM read for the prefix's
-    quadratic prefill compute), and the pages ``_store_put`` pulls."""
+    quadratic prefill compute), and the pages ``_store_put`` pulls.  A
+    latent pool (no ``v_pool``) gives (L, b, 1, c * bk, width) and None."""
+    if v_pool is None:
+        rows = k_pool[:, blks]                 # (L, b, c, width, bk)
+        L, b, c, width, bk = rows.shape
+        return rows.transpose(0, 1, 2, 4, 3).reshape(L, b, 1, c * bk,
+                                                     width), None
+
     def to_dense(pool):
         rows = pool[:, blks]                   # (L, b, c, nkv, bk, hd)
         L, b, c, nkv, bk, hd = rows.shape
@@ -192,8 +203,9 @@ def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
     if k_head is not None:
         cache["k"] = jnp.concatenate(
             [k_head.astype(cfg.dtype), cache["k"]], axis=3)
-        cache["v"] = jnp.concatenate(
-            [v_head.astype(cfg.dtype), cache["v"]], axis=3)
+        if v_head is not None:
+            cache["v"] = jnp.concatenate(
+                [v_head.astype(cfg.dtype), cache["v"]], axis=3)
         cache["pos"] = jnp.asarray(k_head.shape[3], jnp.int32)
     logits, cache = _dec.block_step(params, tokens, cfg, cache, last=last,
                                     n_valid=last + 1)
@@ -244,7 +256,12 @@ def _paged_prefill(params: Dict, cfg: TransformerConfig, k_pool, v_pool,
             state["moe"] = dict(moe, prefill=load)
 
     def new_rows(dense):                   # → (L, b * (n - ct), nkv, bk, hd)
+        if dense is None:
+            return None
         L, _, nkv, _, hd = dense.shape
+        if cfg.latent:                     # → (L, b * (n - ct), width, bk)
+            return (dense[:, :, 0, ct * bk:].reshape(L, -1, bk, hd)
+                    .transpose(0, 1, 3, 2))
         return (dense[:, :, :, ct * bk:].reshape(L, b, nkv, -1, bk, hd)
                 .transpose(0, 1, 3, 2, 4, 5).reshape(L, -1, nkv, bk, hd))
 
@@ -290,6 +307,8 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     pool's last).  An expert layer routes the live slots only (a free
     slot is told by its trash block) and ``state["moe"]["decode"]`` takes
     their load."""
+    from nvme_strom_tpu.ops.mla_attention import (latent_write,
+                                                  mla_attention)
     from nvme_strom_tpu.ops.paged_attention import (paged_attention,
                                                     write_rows)
     B = tok.shape[0]
@@ -315,6 +334,19 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
         elif cfg.mixer(i) == "conv":
             a, tails[ti] = _ssm.conv_step(h, params, L, cfg, tails[ti], sidx)
             ti += 1
+        elif cfg.latent:
+            with jax.named_scope("strom.attn.mla"):
+                # the absorbed form: the step's latent row goes into the
+                # (donated) pool where it lies, and every head attends
+                # over the pool's rows themselves (models/mla.py)
+                q, rows = _mla.project(h, params, L, cfg, positions)
+                k_pool = latent_write(k_pool, rows[:, 0], blk, off, layer=ai)
+                a = mla_attention(
+                    _mla.absorb_q(q[:, 0], params, L, cfg), k_pool, table,
+                    attn_pos, layer=ai, dc=cfg.kv_lora_rank)
+                a = _mla.unabsorb(a, params, L, cfg)[:, None]
+            a = a @ wmat(params, L + "wo", a.dtype)
+            ai += 1
         else:
             q, k, v = qkv_project(h, params, L, cfg, positions=positions)
             with jax.named_scope("strom.attn.paged"):
@@ -454,6 +486,8 @@ class DecodeServer:
         self.cfg = cfg
         self.B = max_batch
         self.max_len = max_len
+        if kv_store is not None:
+            cfg.require_kv_pages("a kv_store (PrefixStore)")
         if cfg.recurrent_layers:
             # pages without the state at their boundary are not a prefix,
             # in the store as in the HBM prefix cache (_req_keys)
@@ -540,8 +574,11 @@ class DecodeServer:
         #: call of an exact expert layer (one per layer per decode step;
         #: one per layer per prefill program under ``*_prefill``), read
         #: off the device's
-        #: counters at each readback: ``moe_calls``, ``moe_pairs`` (valid
-        #: rows × k routed), ``moe_rows_computed`` (rows the grouped
+        #: counters at each readback: ``moe_calls``, ``moe_pairs`` (the
+        #: pairs computed here: those of ``moe_pairs_routed``, valid rows ×
+        #: k from the host's own count, that fell on an expert this device
+        #: holds — all of them unless ``cfg.experts_held`` says a share),
+        #: ``moe_rows_computed`` (rows the grouped
         #: product ran, tile padding included), ``moe_experts_touched``
         #: (experts with at least one row) and ``moe_load_max`` (the
         #: busiest expert's rows) — sums over the calls
@@ -554,8 +591,9 @@ class DecodeServer:
             "prefill_programs": 0, "scan_tokens": 0,
             "attn_blocks_live": 0, "attn_blocks_table": 0,
             **{key + sfx: 0 for sfx in ("", "_prefill") for key in (
-                "moe_calls", "moe_pairs", "moe_rows_computed",
-                "moe_experts_touched", "moe_load_max")}}
+                "moe_calls", "moe_pairs", "moe_pairs_routed",
+                "moe_rows_computed", "moe_experts_touched",
+                "moe_load_max")}}
         #: cumulative (expert layers, E) load histogram of the decode steps
         #: (numpy; None without expert layers)
         self.moe_load = None
@@ -590,8 +628,12 @@ class DecodeServer:
         # (masked) step and its frozen-pos write must never land in a
         # block some live request owns
         shape = (L, self.total_blocks + 1, nkv, self.block_len, hd)
+        if cfg.latent:
+            # ONE array of latent rows, a block's tokens along the lanes
+            # (ops/mla_attention.py), and no second pool
+            shape = shape[:2] + (cfg.latent_width, self.block_len)
         self.k_pool = jnp.zeros(shape, cfg.dtype)
-        self.v_pool = jnp.zeros(shape, cfg.dtype)
+        self.v_pool = None if cfg.latent else jnp.zeros(shape, cfg.dtype)
         self._trash = self.total_blocks
         # the second kind of cache: per recurrent layer what its mixer
         # declares per sequence (a Mamba-2 state and conv tail, a short
@@ -1023,6 +1065,8 @@ class DecodeServer:
         self.timings["prompt_tokens"] += useful
         if self.cfg.recurrent_layers:
             self.timings["scan_tokens"] += useful
+        self.timings["moe_pairs_routed_prefill"] += (
+            useful * self.cfg.expert_top_k * len(self.cfg.expert_layers))
         self._prefill_shapes.add((b, m, n * bk))
         self.timings["prefill_programs"] = len(self._prefill_shapes)
         t0 = time.monotonic()
@@ -1285,9 +1329,19 @@ class DecodeServer:
         # layers whose MLP is the exact expert layer, and what they routed
         # (decode steps; the prefill's own under *_prefill in timings)
         out["moe_layers"] = len(self.cfg.expert_layers)
-        for key in ("moe_pairs", "moe_rows_computed", "moe_experts_touched",
-                    "moe_load_max", "moe_calls"):
+        for key in ("moe_pairs", "moe_pairs_routed", "moe_rows_computed",
+                    "moe_experts_touched", "moe_load_max", "moe_calls"):
             out[key] = self.timings[key]
+        # what a deployment's share looks like from here: the routed
+        # experts whose weights this device holds, and the bytes one token
+        # costs the pool (K and V of every attention layer, or one latent
+        # row a layer)
+        out["experts_held"] = (self.cfg.experts_local
+                               if self.cfg.expert_layers else 0)
+        pool = self.k_pool
+        out["latent_bytes_per_token"] = (
+            pool.shape[0] * pool.shape[2] * pool.dtype.itemsize
+            if self.cfg.latent else 0)
         if self.tenant_sheds:     # key appears only once tenancy acted
             out["tenant_sheds"] = dict(self.tenant_sheds)
         if self._draining:        # and these only once a drain began
@@ -1374,6 +1428,7 @@ class DecodeServer:
         deliver."""
         self.cfg.require_no_recurrent("export_sessions (the hand-off "
                                       "bundle holds K/V page keys only)")
+        self.cfg.require_kv_pages("export_sessions (the hand-off bundle)")
         out: List[dict] = []
         taken_slots: List[int] = []
         taken_q: List[_Request] = []
@@ -1554,6 +1609,11 @@ class DecodeServer:
             self._pos_h[b] // self.block_len + 1
             for b in range(self.B) if self.slots[b] is not None)
         self.timings["attn_blocks_table"] += self.B * self.max_blocks
+        # an expert layer routes every slot that holds blocks (a free one
+        # is told by its trash block)
+        self.timings["moe_pairs_routed"] += (
+            sum(1 for blks in self.blocks if blks)
+            * self.cfg.expert_top_k * len(self.cfg.expert_layers))
         recur = () if self.state is None else (
             self.state,
             jnp.asarray([b if self.slots[b] is not None else self.B
@@ -1743,9 +1803,22 @@ class DecodeServer:
             replay_span.set_metadata(finished=len(finished))
         return finished
 
+    #: padded prompt rows one call admits ALONE IN THEIR PROGRAMS while some
+    #: slot is decoding: a call's prefills run before its decode steps, so
+    #: without a bound a burst of long prompts holds every decoding slot for
+    #: seconds (21 admissions of 1k-8k rows: 3.3 s in one call, PERF.md §6,
+    #: PR 32).  Two 8,192-row prompts' worth.  Prompts that share a program
+    #: (models/admission.py) are not counted: admitted together is what
+    #: makes them cheap; one that runs alone loses nothing by waiting a
+    #: call.  Always at least one prompt a call, and an empty server fills
+    #: at once: there is nobody to hold up
+    ADMIT_ROWS = 16384
+
     def _plan_admissions(self) -> list:
         """This step's admission plans (capacity decisions only), or
-        none while the gate is closed."""
+        none while the gate is closed.  Free slots fill in queue order;
+        beside decoding slots, until the prompts that go alone in their
+        programs pass ``ADMIT_ROWS`` padded rows."""
         plans = []
         # load shedding (docs/RESILIENCE.md "failure domains"): while
         # the engine behind the KV store is degraded, new prefills
@@ -1769,9 +1842,17 @@ class DecodeServer:
             # reaches this branch and runs the loop below verbatim
             plans = self._admit_tenants()
         else:
+            rows = 0
+            decoding = any(r is not None for r in self.slots)
             for slot in range(self.B):
                 if (self.slots[slot] is None and self.queue
                         and self._can_admit(self.queue[0])):
+                    m = -(-len(self.queue[0].prompt)
+                          // self.block_len) * self.block_len
+                    if _adm.width_for(m, self._group_rows) == 1:
+                        rows += m
+                        if decoding and plans and rows > self.ADMIT_ROWS:
+                            break   # the rest wait one call, queued
                     plans.append(self._admit_plan(slot,
                                                   self.queue.pop(0)))
         return plans

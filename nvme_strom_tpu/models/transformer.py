@@ -102,6 +102,25 @@ class TransformerConfig:
     attn_scale: object = None
     rope: bool = True               # False: no positional encoding
     tie_embed: bool = False         # logits through tok_embedᵀ, no lm_head
+    # Latent attention (MLA, models/mla.py; kv_lora_rank 0 == the K/V kind):
+    # q through a rank-``q_lora_rank`` bottleneck to n_heads x (qk_nope_dim |
+    # qk_rope_dim); ONE latent row of kv_lora_rank + qk_rope_dim values a
+    # token, which is what a cache holds, expanded to n_heads x (qk_nope_dim
+    # | v_head_dim) keys and values.  Such a config states ``attn_scale``
+    # and a "yarn" ``rope_scaling`` itself (config_from_hf).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # The exact expert layer's share of a deployment: the router scores
+    # ``n_experts``, this device holds ``experts_held`` of them (0: all)
+    # from ``expert_offset`` on and computes the pairs that fall on those;
+    # ``d_shared`` is the width of a shared expert every row goes through
+    # beside the routed ones (0: none).
+    experts_held: int = 0
+    expert_offset: int = 0
+    d_shared: int = 0
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -136,6 +155,19 @@ class TransformerConfig:
         if self.router_kind not in ("softmax", "sigmoid"):
             raise ValueError(f"router_kind {self.router_kind!r}: expected "
                              "'softmax' or 'sigmoid'")
+        if self.experts_held and not (
+                0 <= self.expert_offset
+                and self.expert_offset + self.experts_held <= self.n_experts):
+            raise ValueError(
+                f"{self.experts_held} experts from {self.expert_offset} on "
+                f"held of {self.n_experts} routed")
+        if self.latent and (kinds or not (
+                self.q_lora_rank and self.qk_nope_dim and self.qk_rope_dim
+                and self.v_head_dim and self.attn_scale)):
+            raise ValueError(
+                "latent attention needs q_lora_rank, qk_nope_dim, "
+                "qk_rope_dim, v_head_dim and attn_scale, in every layer "
+                "(no layer_kinds)")
 
     @property
     def rope_scaling_dict(self):
@@ -199,6 +231,33 @@ class TransformerConfig:
                      if self.mixer(i) == "attention")
 
     @property
+    def latent(self) -> bool:
+        """Whether attention is the latent kind (MLA): a cache then holds
+        one row of ``latent_width`` values a token a layer, not K and V."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def experts_local(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.experts_held or self.n_experts
+
+    def require_kv_pages(self, what: str) -> None:
+        """The one message of everything that reads a cache as K and V
+        pages at KV-head width."""
+        if self.latent:
+            raise NotImplementedError(
+                f"{what} holds K and V pages; a latent-attention config "
+                f"(kv_lora_rank {self.kv_lora_rank}) caches one "
+                f"{self.latent_width}-wide latent row a token, which has no "
+                f"store format yet: serve this config from DecodeServer on "
+                f"one device, without a kv_store, a mesh or session "
+                f"hand-off")
+
+    @property
     def ssm_inner(self) -> int:
         return self.ssm_heads * self.ssm_head_dim
 
@@ -245,7 +304,8 @@ def dense_init(key, fan_in, shape):
 def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
     """Parameters as a flat {name: array} dict — the same namespace the
     safetensors lazy loader uses, so checkpoints round-trip by name."""
-    keys = iter(jax.random.split(rng, 4 + 13 * cfg.n_layers))
+    keys = iter(jax.random.split(
+        rng, 4 + (16 if cfg.latent or cfg.d_shared else 13) * cfg.n_layers))
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     dense = dense_init
 
@@ -265,6 +325,9 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
         elif cfg.mixer(i) == "conv":
             from nvme_strom_tpu.models.ssm import init_conv_params
             p.update(init_conv_params(keys, cfg, L, dense))
+        elif cfg.latent:
+            from nvme_strom_tpu.models.mla import init_mla_params
+            p.update(init_mla_params(keys, cfg, L, dense))
         else:
             if cfg.qk_norm:
                 p[L + "q_norm"] = jnp.ones((hd,), jnp.float32)
@@ -321,17 +384,58 @@ def _llama3_scale_freqs(freqs, scaling: dict):
                                + smooth * freqs))
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature (HF ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
+def yarn_freqs(half: int, theta: float, scaling: dict) -> np.ndarray:
+    """YaRN's rotary frequencies (HF DeepSeek-V3 ``YarnRotaryEmbedding``):
+    theta^(-j/half) kept where a pair turns more than ``beta_fast`` times
+    over the original context, divided by ``factor`` where it turns fewer
+    than ``beta_slow`` times, blended linearly between.  float32 (half,)."""
+    dim, factor = 2 * half, float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(rotations):
+        return (dim * np.log(orig / (rotations * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = max(np.floor(correction_dim(float(scaling.get("beta_fast", 32)))),
+              0)
+    high = min(np.ceil(correction_dim(float(scaling.get("beta_slow", 1)))),
+               dim - 1)
+    if low == high:
+        high += 0.001                       # HF: prevent singularity
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0, 1)
+    extra = float(theta) ** (-np.arange(half, dtype=np.float32) / half)
+    return (extra / factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
 def _rope_cos_sin(half: int, theta, positions, scaling, seq: int):
     """cos/sin tables for RoPE: (..., seq, half) in f32."""
     if positions is None:
         positions = jnp.arange(seq, dtype=jnp.float32)
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    mult = 1.0
     if scaling is not None:
         rt = scaling.get("rope_type", scaling.get("type"))
-        if rt != "llama3":
+        if rt == "yarn":
+            freqs = jnp.asarray(yarn_freqs(half, theta, scaling))
+            # HF ``_compute_yarn_parameters``: the ratio where both are
+            # stated, else the plain temperature
+            ms, ma = scaling.get("mscale"), scaling.get("mscale_all_dim")
+            mult = (yarn_mscale(scaling["factor"], ms)
+                    / yarn_mscale(scaling["factor"], ma) if ms and ma
+                    else yarn_mscale(scaling["factor"]))
+        elif rt == "llama3":
+            freqs = _llama3_scale_freqs(freqs, scaling)
+        else:
             raise NotImplementedError(f"rope_scaling type {rt!r}")
-        freqs = _llama3_scale_freqs(freqs, scaling)
     ang = positions.astype(jnp.float32)[..., None] * freqs
+    if mult != 1.0:
+        return jnp.cos(ang) * mult, jnp.sin(ang) * mult
     return jnp.cos(ang), jnp.sin(ang)
 
 
@@ -668,6 +772,12 @@ def attention(x, p, prefix, cfg: TransformerConfig, attn_fn=None,
     ``return_kv=True`` additionally returns the post-RoPE kv-width k/v for
     cache prefill."""
     b, s, _ = x.shape
+    if cfg.latent:
+        if attn_fn is not None or return_kv:
+            raise NotImplementedError(
+                "latent attention has its own inner block (models/mla.py)")
+        from nvme_strom_tpu.models import mla
+        return mla.self_attention(x, p, prefix, cfg, positions)
     # no rotary, or a config's own score scale: only qkv_project and
     # dense_causal_attention know them
     llama_like = cfg.rope and cfg.attn_scale is None

@@ -19,8 +19,10 @@ then not fetched again — every (expert, column tile) block of the weights
 moves at most once a call, and an expert with no rows is never named.  Row
 tiles past the last used one do nothing: their index maps hold the last
 used indices (an unchanged index fetches nothing) and the body runs under
-``pl.when``.  The whole contraction fits one block (2048 or 1536 deep), so
-there is no accumulator.
+``pl.when``.  The whole contraction is one block, so there is no
+accumulator; where it is deep (7168 against LFM2's 2048 or 1536) the column
+tile narrows instead, until the weight blocks in flight fit their share of
+VMEM (``column_tile``).
 
 Two forms, one kernel: ``gmm(x, (w_gate, w_up), ...)`` gives
 ``silu(x w_gate) * (x w_up)`` (both products in float32, one pass over x),
@@ -40,6 +42,9 @@ from jax.experimental.pallas import tpu as pltpu
 #: widest column tile: a (2048, 512) bf16 block of weights is 2 MiB, the
 #: gated form holds two and Pallas double-buffers them: 8 MiB of VMEM
 _TILE_N = 512
+#: what the weight blocks in flight may take of the kernel's 32 MiB (the row
+#: tile, the result and the float32 products share the rest)
+_WEIGHT_VMEM = 16 * 2 ** 20
 
 
 def _interpret(interpret):
@@ -91,6 +96,18 @@ def group_rows(expert, n_experts: int, tm: int):
         counts
 
 
+def column_tile(kdim: int, n: int, n_weights: int, itemsize: int) -> int:
+    """Columns of a weight block: the widest power-of-two share of
+    ``_TILE_N`` that divides ``n`` and keeps ``n_weights`` double-buffered
+    (kdim, tile) blocks within ``_WEIGHT_VMEM`` (512 at LFM2's depths, 256
+    for a gated product 7168 deep)."""
+    tn = min(n, _TILE_N)
+    while n % tn or (tn > 128 and 2 * n_weights * kdim * tn * itemsize
+                     > _WEIGHT_VMEM):
+        tn //= 2
+    return tn
+
+
 def _gmm_kernel(te_ref, nt_ref, x_ref, *refs, gated: bool):
     w_refs, o_ref = refs[:-1], refs[-1]
 
@@ -119,9 +136,7 @@ def gmm(x, weights: tuple, tile_expert, n_tiles, *, tm: int,
                                       for w in weights):
         raise ValueError(f"gmm: x {x.shape} (tile {tm}) against "
                          f"{[w.shape for w in weights]}")
-    tn = min(n, _TILE_N)
-    while n % tn:
-        tn //= 2
+    tn = column_tile(kdim, n, len(weights), x.dtype.itemsize)
     weights = tuple(w.astype(x.dtype) for w in weights)
 
     def used(i, nt):                 # a tile past the end holds the last one

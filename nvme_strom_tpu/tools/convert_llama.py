@@ -80,11 +80,29 @@ _LAYER_RULES: Tuple[Tuple[str, str, bool], ...] = (
     ("ffn_norm.weight", "mlp_norm", False),
     ("feed_forward.gate.weight", "router", True),
     ("feed_forward.expert_bias", "router_bias", False),
+    # deepseek_v3 / kimi_k2: latent attention (models/mla.py), the router
+    # with its selection bias, the shared expert(s) as one gated MLP.  The
+    # names are transformers' deepseek_v3's; kimi_k2 ships its own modelling
+    # file, ASSUMED to keep them.
+    ("self_attn.q_a_proj.weight", "wq_a", True),
+    ("self_attn.q_a_layernorm.weight", "q_a_norm", False),
+    ("self_attn.q_b_proj.weight", "wq_b", True),
+    ("self_attn.kv_a_proj_with_mqa.weight", "wkv_a", True),
+    ("self_attn.kv_a_layernorm.weight", "kv_a_norm", False),
+    ("self_attn.kv_b_proj.weight", "wkv_b", True),
+    ("mlp.gate.weight", "router", True),
+    ("mlp.gate.e_score_correction_bias", "router_bias", False),
+    ("mlp.shared_experts.gate_proj.weight", "shared_w_gate", True),
+    ("mlp.shared_experts.up_proj.weight", "shared_w_up", True),
+    ("mlp.shared_experts.down_proj.weight", "shared_w_down", True),
 )
 
 #: one expert's matrices: convert() stacks them into moe_w_* (E, in, out)
-_EXPERT_RE = re.compile(r"^feed_forward\.experts\.(\d+)\.(w[123])\.weight$")
-_EXPERT_LEAF = {"w1": "moe_w_gate", "w3": "moe_w_up", "w2": "moe_w_down"}
+_EXPERT_RE = re.compile(r"^(?:feed_forward|mlp)\.experts\.(\d+)\."
+                        r"(w[123]|gate_proj|up_proj|down_proj)\.weight$")
+_EXPERT_LEAF = {"w1": "moe_w_gate", "w3": "moe_w_up", "w2": "moe_w_down",
+                "gate_proj": "moe_w_gate", "up_proj": "moe_w_up",
+                "down_proj": "moe_w_down"}
 
 _TOP_RULES: Dict[str, Tuple[str, bool]] = {
     "model.embed_tokens.weight": ("tok_embed", False),
@@ -213,10 +231,84 @@ def _lfm2_fields(hf_cfg: dict) -> dict:
     return out
 
 
+def _mla_fields(hf_cfg: dict) -> dict:
+    """The TransformerConfig fields of a ``deepseek_v3`` / ``kimi_k2``
+    config (latent attention in every layer, ``first_k_dense_replace`` dense
+    MLPs then sigmoid-routed experts beside shared ones, YaRN), beyond the
+    dense family's.  A file that states one device's share of a deployment
+    carries ``expert_share`` ({"routed": the router's published width,
+    "offset": the first expert held}) beside ``n_routed_experts``, which
+    then counts the experts held.  Raises on what the model does not
+    implement."""
+    import math
+
+    def refuse(what):
+        raise ValueError(f"unsupported {hf_cfg['model_type']} config: {what}")
+    for knob in ("n_group", "topk_group"):
+        if hf_cfg.get(knob, 1) > 1:
+            refuse(f"{knob}={hf_cfg[knob]}: the router picks its top-k among "
+                   "all the experts; choosing groups of experts first is not "
+                   "implemented")
+    if hf_cfg.get("scoring_func", "sigmoid") != "sigmoid":
+        refuse(f"scoring_func {hf_cfg['scoring_func']!r} (only 'sigmoid')")
+    if hf_cfg.get("topk_method", "noaux_tc") != "noaux_tc":
+        refuse(f"topk_method {hf_cfg['topk_method']!r} (only 'noaux_tc': "
+               "top-k of score + e_score_correction_bias)")
+    if hf_cfg.get("moe_layer_freq", 1) != 1:
+        refuse(f"moe_layer_freq={hf_cfg['moe_layer_freq']} (every layer "
+               "past the dense ones holds experts)")
+    if not hf_cfg.get("rope_interleave", True):
+        refuse("rope_interleave=False (the converter folds HF's "
+               "de-interleave of the rotary features into the weights)")
+    if hf_cfg.get("num_nextn_predict_layers", 0):
+        refuse("num_nextn_predict_layers > 0 (no multi-token prediction "
+               "head)")
+    if not hf_cfg.get("q_lora_rank"):
+        refuse("q_lora_rank is not set (queries go through the low-rank "
+               "projection)")
+    dn, dr = hf_cfg["qk_nope_head_dim"], hf_cfg["qk_rope_head_dim"]
+    scale = float(dn + dr) ** -0.5
+    scaling = hf_cfg.get("rope_scaling")
+    if scaling is not None:
+        if scaling.get("rope_type", scaling.get("type")) != "yarn":
+            refuse(f"rope_scaling {scaling} (only 'yarn')")
+        scaling = {k: v for k, v in scaling.items() if k in (
+            "factor", "beta_fast", "beta_slow", "mscale", "mscale_all_dim",
+            "original_max_position_embeddings")}
+        scaling["rope_type"] = "yarn"
+        if scaling.get("mscale_all_dim"):
+            # HF DeepseekV3Attention: the softmax scale takes YaRN's
+            # temperature twice
+            scale *= (0.1 * scaling["mscale_all_dim"]
+                      * math.log(scaling["factor"]) + 1.0) ** 2
+    share = hf_cfg.get("expert_share") or {}
+    held = hf_cfg["n_routed_experts"]
+    routed = share.get("routed", held)
+    n = hf_cfg["num_hidden_layers"]
+    dense = hf_cfg.get("first_k_dense_replace", 0)
+    return dict(
+        q_lora_rank=hf_cfg["q_lora_rank"], kv_lora_rank=hf_cfg["kv_lora_rank"],
+        qk_nope_dim=dn, qk_rope_dim=dr, v_head_dim=hf_cfg["v_head_dim"],
+        attn_scale=scale, rope_scaling=scaling,
+        mlp_kinds=tuple("dense" if i < dense else "experts"
+                        for i in range(n)),
+        n_experts=routed, expert_top_k=hf_cfg["num_experts_per_tok"],
+        experts_held=held if held != routed else 0,
+        expert_offset=share.get("offset", 0),
+        d_expert=hf_cfg["moe_intermediate_size"],
+        d_shared=(hf_cfg["moe_intermediate_size"]
+                  * hf_cfg.get("n_shared_experts", 0)),
+        router_kind="sigmoid", router_bias=True,
+        router_norm_topk=bool(hf_cfg.get("norm_topk_prob", True)),
+        router_scale=float(hf_cfg.get("routed_scaling_factor", 1.0)),
+        tie_embed=bool(hf_cfg.get("tie_word_embeddings", False)))
+
+
 def config_from_hf(hf_cfg: dict):
     """HF ``config.json`` → TransformerConfig: the dense Llama family,
-    ``model_type`` granitemoehybrid (``_hybrid_fields``) and ``lfm2`` /
-    ``lfm2_moe`` (``_lfm2_fields``).
+    ``model_type`` granitemoehybrid (``_hybrid_fields``), ``lfm2`` /
+    ``lfm2_moe`` (``_lfm2_fields``) and ``deepseek_v3`` / ``kimi_k2``
+    (``_mla_fields``).
 
     Raises on architecture knobs the model does not implement — silently
     ignoring them (e.g. a non-SiLU activation) would convert into a model
@@ -233,18 +325,23 @@ def config_from_hf(hf_cfg: dict):
     model_type = hf_cfg.get("model_type")
     family = (_hybrid_fields(hf_cfg) if model_type == "granitemoehybrid"
               else _lfm2_fields(hf_cfg) if model_type in ("lfm2", "lfm2_moe")
-              else {})
+              else _mla_fields(hf_cfg)
+              if model_type in ("deepseek_v3", "kimi_k2") else {})
     derived_hd = hf_cfg["hidden_size"] // hf_cfg["num_attention_heads"]
     if hf_cfg["hidden_size"] % hf_cfg["num_attention_heads"]:
         raise ValueError("hidden_size not divisible by num_attention_heads")
-    if hf_cfg.get("head_dim", derived_hd) != derived_hd:
+    # (latent attention states its own head widths: where HF writes a
+    # head_dim there, it is qk_rope_head_dim)
+    if "kv_lora_rank" not in family and hf_cfg.get(
+            "head_dim", derived_hd) != derived_hd:
         # recent HF configs may carry an explicit head_dim decoupled from
         # hidden_size/n_heads; TransformerConfig derives it, so a
         # mismatch would only explode later inside qkv_project
         raise ValueError(
             f"unsupported explicit head_dim={hf_cfg['head_dim']} "
             f"(model derives {derived_hd} = hidden_size/num_heads)")
-    scaling = hf_cfg.get("rope_scaling")
+    scaling = None if "rope_scaling" in family else hf_cfg.get(
+        "rope_scaling")
     if scaling is not None:
         rt = scaling.get("rope_type", scaling.get("type"))
         if rt != "llama3":
@@ -271,6 +368,25 @@ def config_from_hf(hf_cfg: dict):
     return TransformerConfig(**fields)
 
 
+def _deinterleave_rope(w: np.ndarray, cfg, per_head: bool) -> np.ndarray:
+    """HF's DeepSeek-V3 attention de-interleaves the rotary features of q
+    and k ((rope/2, 2) -> (2, rope/2)) before its half-split rotation
+    (``rope_interleave``); ``models/mla.py`` rotates half-split as the rest
+    of this model does, so the same permutation goes into the output columns
+    that produce those features, once, here: the last ``qk_rope_dim``
+    columns of every head of W_qb (in, heads x (nope + rope)), of W_kva (in,
+    kv_lora_rank + rope)."""
+    dr = cfg.qk_rope_dim
+    perm = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+    out = w.copy()
+    if per_head:
+        h = out.reshape(w.shape[0], cfg.n_heads, cfg.qk_nope_dim + dr)
+        h[..., cfg.qk_nope_dim:] = h[..., cfg.qk_nope_dim:][..., perm]
+    else:
+        out[:, cfg.kv_lora_rank:] = w[:, cfg.kv_lora_rank:][:, perm]
+    return out
+
+
 def strom_config_dict(cfg) -> dict:
     """``strom_config.json`` of a converted checkpoint: the
     TransformerConfig keys the serving/training entry points rebuild the
@@ -289,8 +405,13 @@ def strom_config_dict(cfg) -> dict:
     if cfg.mlp_kinds:       # the per-layer MLPs and the exact layer's router
         out.update({k: getattr(cfg, k) for k in (
             "n_experts", "expert_top_k", "d_expert", "router_kind",
-            "router_bias", "router_norm_topk", "router_scale")},
+            "router_bias", "router_norm_topk", "router_scale",
+            "experts_held", "expert_offset", "d_shared")},
             mlp_kinds=list(cfg.mlp_kinds))
+    if cfg.latent:
+        out.update({k: getattr(cfg, k) for k in (
+            "q_lora_rank", "kv_lora_rank", "qk_nope_dim", "qk_rope_dim",
+            "v_head_dim", "attn_scale", "tie_embed")})
     return out
 
 
@@ -377,14 +498,16 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
         out = np.ascontiguousarray(arr.T) if transpose else arr
         if ours == "tok_embed":
             embed = arr
+        if cfg.latent and ours.endswith((".wq_b", ".wkv_a")):
+            out = _deinterleave_rope(out, cfg, ours.endswith(".wq_b"))
         e = re.fullmatch(r"(.*\.moe_w_(?:gate|up|down))\.(\d+)", ours)
         if e:                               # one expert's slice: held until
             experts.setdefault(e.group(1), {})[int(e.group(2))] = out
-            if len(experts[e.group(1)]) == cfg.n_experts:   # the layer's set
+            if len(experts[e.group(1)]) == cfg.experts_local:   # the layer's
                 stack = experts.pop(e.group(1))
                 seen.add(e.group(1))
-                emit(e.group(1), np.stack([stack[j]
-                                           for j in range(cfg.n_experts)]))
+                emit(e.group(1), np.stack([stack[j] for j in range(
+                    cfg.experts_local)]))
             continue
         if ours.endswith("w_gate_up"):      # (d, 2 ff) → gate | up
             ff = out.shape[1] // 2
@@ -398,7 +521,7 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
 
     if experts:
         raise ValueError(f"expert matrices missing: {sorted(experts)} hold "
-                         f"fewer than {cfg.n_experts} experts")
+                         f"fewer than {cfg.experts_local} experts")
     if cfg.tie_embed:
         seen.add("lm_head")     # the head IS tok_embed: nothing to write
     if "lm_head" not in seen:

@@ -298,12 +298,70 @@ def _moe_gmm_down_1024(topo):
     return _moe_gmm(topo, rows=1024, gated=False)
 
 
+def _moe_gmm_7168(topo, gated=True):
+    """The grouped product at Kimi-K2's widths (12 experts held of 7168 x
+    2048): a gated contraction 7168 deep, whose column tile narrows to 256
+    so that the weight blocks fit VMEM, and the down product back."""
+    from nvme_strom_tpu.ops import moe as ops
+    sh = _one(topo)
+    E, d, fe, pairs = 12, 7168, 2048, 16384
+    tm = ops.tile_rows(pairs, 384)
+    padded = ops.padded_rows(pairs, E, tm)
+    kdim, n = (d, fe) if gated else (fe, d)
+    w = _spec((E, kdim, n), jnp.bfloat16, sh)
+    return _compile(
+        lambda x, te, nt, *ws: ops.gmm(x, ws, te, nt, tm=tm,
+                                       interpret=False),
+        _spec((padded, kdim), jnp.bfloat16, sh),
+        _spec((padded // tm,), jnp.int32, sh), _spec((), jnp.int32, sh),
+        *([w, w] if gated else [w]))
+
+
+def _moe_gmm_down_7168(topo):
+    return _moe_gmm_7168(topo, gated=False)
+
+
+K2C_POOL = (5, 4224 + 1, 576, 128)      # the cell's latent pool
+
+
+def _mla_attn(topo):
+    """The absorbed-form decode kernel at the cell ``k2c.flood8k``'s shapes:
+    64 slots of 64 heads against 576-wide latent rows, a table 66 entries
+    wide, the last layer of the five-layer pool read in place."""
+    from nvme_strom_tpu.ops.mla_attention import mla_attention
+    sh = _one(topo)
+    pool = _spec(K2C_POOL, jnp.bfloat16, sh)
+    compiled = _compile(
+        functools.partial(mla_attention, layer=4, dc=512, interpret=False),
+        _spec((64, 64, 576), jnp.bfloat16, sh), pool,
+        _spec((64, 66), jnp.int32, sh), _spec((64,), jnp.int32, sh))
+    assert not pool_sized_ops(compiled.as_text(), pool.shape)
+    return compiled
+
+
+def _latent_write(topo):
+    from nvme_strom_tpu.ops.mla_attention import latent_write
+    sh = _one(topo)
+    pool = _spec(K2C_POOL, jnp.bfloat16, sh)
+    vec = _spec((64,), jnp.int32, sh)
+    compiled = _compile(
+        functools.partial(latent_write, layer=4, interpret=False),
+        pool, _spec((64, 576), jnp.bfloat16, sh), vec, vec,
+        donate_argnums=(0,))
+    assert not pool_sized_ops(compiled.as_text(), pool.shape)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= np.prod(pool.shape) * 2
+    return compiled
+
+
 @pytest.mark.parametrize("build", [_paged, _decode, _flash_fwd,
                                    _flash_bwd, _bridge, _ici, _paged_hd64,
                                    _ssm_update, _ssm_scan, _ssm_scan_128,
                                    _kv_write, _kv_write_hd64, _moe_gmm,
                                    _moe_gmm_down, _moe_gmm_1024,
-                                   _moe_gmm_down_1024],
+                                   _moe_gmm_down_1024, _moe_gmm_7168,
+                                   _moe_gmm_down_7168, _mla_attn,
+                                   _latent_write],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_kernel_compiles_for_v5e(topo, build):
     assert build(topo) is not None
@@ -522,6 +580,84 @@ def test_lfm2_step_updates_every_pool_in_place(topo, monkeypatch):
     # the router's scores are the one f32[slots, experts] array of the step:
     # benchmark/layer_metrics/moe_route_share.py finds routing by it
     assert "f32[128,64]" in text
+
+
+def _k2c_two_layers(topo):
+    """Kimi-K2.7-Code's widths as the cell serves them, cut to its dense
+    layer and one expert layer (12 experts held of 384) for the compiler's
+    sake, as shapes on one described chip: (cfg, sharding, params, the
+    latent pool of those two layers, the carried state)."""
+    import json
+    from nvme_strom_tpu.models import serving
+    from nvme_strom_tpu.models.transformer import init_params
+    from nvme_strom_tpu.tools.convert_llama import config_from_hf
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-k2.7-code.json")) as f:
+        hf = json.load(f)
+    cfg = config_from_hf(dict(hf, num_hidden_layers=2))
+    sh = _one(topo)
+    params = {k: _spec(v.shape, jnp.bfloat16, sh) for k, v in jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)).items()}
+    pool = _spec((2,) + K2C_POOL[1:], jnp.bfloat16, sh)
+    state = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype, sh),
+        jax.eval_shape(lambda: serving.init_carried(cfg, 64 + 1)))
+    return cfg, sh, params, pool, state
+
+
+def test_k2c_step_updates_the_latent_pool_in_place(topo, monkeypatch):
+    """The server's decode step of a latent configuration at the cell's
+    widths and 64 slots: the ONE latent pool is aliased input to output
+    beside no second pool, nothing of its size is copied or transposed, a
+    layer is the row writer and the absorbed-form kernel, the expert layer
+    two calls of the grouped product over the 12 experts held."""
+    from nvme_strom_tpu.models import serving
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, sh, params, pool, state = _k2c_two_layers(topo)
+    B = 64
+    vec = lambda dt: _spec((B,), dt, sh)                    # noqa: E731
+    compiled = serving._paged_step.lower(
+        params, cfg, vec(jnp.int32), pool, None, vec(jnp.int32),
+        vec(jnp.int32), _spec((B, 66), jnp.int32, sh), vec(jnp.int32),
+        vec(jnp.float32), vec(jnp.float32), vec(jnp.uint32), state,
+        vec(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 * 2 + 2   # write, attend; gmm
+    assert "strom_mla_attn" in text and "strom_latent_write" in text
+    assert not pool_sized_ops(text, pool.shape)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= np.prod(pool.shape) * 2
+
+
+def test_k2c_long_prefill_holds_no_score_tensor_over_a_gib(topo,
+                                                            monkeypatch):
+    """The admission program of one 8,192-row prompt at the cell's widths
+    (the same two layers): it compiles for a v5e, the latent pool is
+    aliased through, a layer's attention is the blocked kernel
+    ``strom_mla_prefill`` so that no array of the program is larger than
+    1 GiB — the (64, 8192, 8192) float32 score tensor would be 17 GB — and
+    its temporaries fit beside the five-layer model's 9.41 GiB of weights
+    and pool."""
+    from nvme_strom_tpu.models import serving
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, sh, params, pool, state = _k2c_two_layers(topo)
+    rows, bk = 8192, 128
+    vec = _spec((1,), jnp.int32, sh)
+    compiled = serving._paged_prefill.lower(
+        params, cfg, pool, None, _spec((1, rows), jnp.int32, sh),
+        _spec((1, rows // bk), jnp.int32, sh), vec, state, vec).compile()
+    text = compiled.as_text()
+    assert text.count("strom_mla_prefill") >= 2
+    size = {"f32": 4, "bf16": 2, "s32": 4}
+    largest = max(size[t] * int(np.prod([int(n) for n in dims.split(",")]))
+                  for t, dims in re.findall(r"\b(f32|bf16|s32)\[([\d,]+)\]",
+                                            text)
+                  if dims != ",".join(map(str, pool.shape)))
+    assert largest <= 2 ** 30, largest / 2 ** 30
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= np.prod(pool.shape) * 2
+    assert m.temp_size_in_bytes < (15.75 - 9.41 - 1.0) * 2 ** 30, m
 
 
 def test_sharded_forward_compiles_for_four_chips(topo):

@@ -467,3 +467,63 @@ def test_convert_stacks_the_experts_of_a_layer(tmp_path):
     write(tensors)
     with pytest.raises(ValueError, match="expert matrices missing"):
         convert_llama.convert(str(src), str(tmp_path / "out2"))
+
+
+# -- deepseek_v3 / kimi_k2: latent attention, routed + shared experts -------
+
+@pytest.fixture(scope="module")
+def hf_deepseek(tmp_path_factory):
+    if not hasattr(transformers, "DeepseekV3ForCausalLM"):
+        pytest.skip("this transformers has no DeepseekV3")
+    d = tmp_path_factory.mktemp("hf_deepseek")
+    cfg = transformers.DeepseekV3Config(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        moe_intermediate_size=32, num_hidden_layers=3,
+        num_attention_heads=4, num_key_value_heads=4, n_shared_experts=1,
+        n_routed_experts=8, routed_scaling_factor=2.827, kv_lora_rank=16,
+        q_lora_rank=24, qk_rope_head_dim=8, v_head_dim=8,
+        qk_nope_head_dim=8, n_group=1, topk_group=1, num_experts_per_tok=3,
+        first_k_dense_replace=1, norm_topk_prob=True,
+        max_position_embeddings=64, rms_norm_eps=1e-5, rope_theta=50000.0,
+        rope_scaling={"type": "yarn", "rope_type": "yarn", "factor": 4.0,
+                      "beta_fast": 32, "beta_slow": 1, "mscale": 1.0,
+                      "mscale_all_dim": 1.0,
+                      "original_max_position_embeddings": 16},
+        attention_bias=False, tie_word_embeddings=False)
+    torch.manual_seed(0)
+    model = transformers.DeepseekV3ForCausalLM(cfg).eval()
+    with torch.no_grad():       # norms and the selection bias off their init
+        for name, p in list(model.named_parameters()) + list(
+                model.named_buffers()):
+            if name.endswith(("norm.weight", "layernorm.weight")):
+                p.add_(0.1 * torch.randn_like(p))
+            elif name.endswith("e_score_correction_bias"):
+                p.add_(0.2 * torch.randn_like(p))
+    model.save_pretrained(d, safe_serialization=True)
+    return str(d), model
+
+
+def test_deepseek_logits_match_hf(hf_deepseek, tmp_path):
+    """Converted deepseek_v3 weights (kimi_k2's architecture) through
+    ``forward`` — latent attention in its expanded form through the blocked
+    kernel in interpret mode — against transformers' own forward: the tensor
+    names, the rotary features de-interleaved into the weights, YaRN's
+    frequencies (factor 4 over 16 positions, so the blend is in play at 21)
+    and the m^2 in the softmax scale, top-3 of 8 sigmoid scores by score +
+    bias, the weights' normalisation and 2.827, the shared expert, the dense
+    first layer and the untied head all line up."""
+    import jax.numpy as jnp
+    from nvme_strom_tpu.models.transformer import forward
+    hf_dir, model = hf_deepseek
+    out = str(tmp_path / "converted")
+    summary = convert_llama.convert(hf_dir, out)
+    assert summary["skipped"] == []
+    cfg, params = _load_converted(out)
+    assert cfg.latent and cfg.mlp_kinds == ("dense", "experts", "experts")
+    assert cfg.d_shared == 32 and cfg.experts_held == 0
+    assert params["layers.1.moe_w_gate"].shape == (8, 64, 32)
+    toks = np.random.default_rng(0).integers(0, 256, (2, 21))
+    with torch.no_grad():
+        ref = model(torch.from_numpy(toks)).logits.float().numpy()
+    ours = np.asarray(forward(params, jnp.asarray(toks, jnp.int32), cfg))
+    np.testing.assert_allclose(ours, ref, atol=2e-4, rtol=2e-4)

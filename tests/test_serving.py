@@ -225,6 +225,46 @@ def test_moe_model_serves():
         assert srv.run()["m"] == want
 
 
+def test_a_call_admits_at_most_admit_rows(setup):
+    """Beside decoding slots ``ADMIT_ROWS`` bounds the padded prompt rows one
+    call admits alone in their programs (a long burst must not hold them up;
+    prompts that share a program are not counted): the queue fills the
+    free slots over successive calls, always at least one prompt a call; an
+    EMPTY server fills at once; and every request's tokens are those of an
+    unbounded server."""
+    cfg, params = setup
+    prompts = {f"r{i}": [1 + i, 2, 3, 4, 5] for i in range(4)}   # 8 rows each
+
+    def run(cap, first_alone, group_rows=0):
+        srv = DecodeServer(params, cfg, max_batch=5, max_len=32,
+                           total_blocks=20, block_len=4)
+        if cap:
+            srv.ADMIT_ROWS = cap
+        srv._group_rows = group_rows         # 0: every prompt goes alone
+        out = {}
+        if first_alone:
+            srv.submit("first", [9, 8, 7], 12)
+            out.update(srv.step())           # one slot now decodes
+        for rid, p in prompts.items():
+            srv.submit(rid, p, 6)
+        out.update(srv.step())
+        busy = srv.stats()["slots_busy"]
+        out.update(srv.run())
+        return busy, out
+
+    busy, out = run(12, True)       # one prompt of 8 rows fits, two do not
+    assert busy == 1 + 1
+    busy2, out2 = run(16, True)
+    assert busy2 == 1 + 2
+    busy_all, want = run(None, True)
+    assert busy_all == 1 + 4 and out == out2 == want
+    assert run(12, False)[0] == 4   # nobody decoding: nobody to hold up
+    # prompts that share a program (two of 8 rows within 16) are not
+    # counted: together is what makes them cheap
+    busy_g, out_g = run(12, True, group_rows=16)
+    assert busy_g == 1 + 4 and out_g == want
+
+
 def test_server_stats_gauges(setup):
     cfg, params = setup
     srv = DecodeServer(params, cfg, max_batch=2, max_len=32,
@@ -248,8 +288,11 @@ def test_server_stats_gauges(setup):
              # no layer's MLP is the exact expert layer here, so nothing is
              # routed (tests/test_lfm2.py has a model that does)
              "moe_layers": 0, "moe_calls": 0, "moe_pairs": 0,
-             "moe_rows_computed": 0, "moe_experts_touched": 0,
-             "moe_load_max": 0}
+             "moe_pairs_routed": 0, "moe_rows_computed": 0,
+             "moe_experts_touched": 0, "moe_load_max": 0,
+             # nor a share of a deployment's experts, nor a latent pool
+             # (tests/test_mla.py has both)
+             "experts_held": 0, "latent_bytes_per_token": 0}
     assert s0 == want0
     srv.step()
     s1 = srv.stats()
