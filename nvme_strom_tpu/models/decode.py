@@ -74,14 +74,14 @@ def mlp_block(h, p, L, cfg, valid=None, calls=None):
     An expert layer (``mlp_kind`` "experts") computes every selected pair
     (``moe.expert_mlp``); ``valid`` (b, m) bool marks the rows that are
     real (None: all) and ``calls``, a list, collects the layer's (counts,
-    rows computed) for ``moe.add_load``.  The capacity-dropping GShard layer
+    work) for ``moe.add_load``.  The capacity-dropping GShard layer
     runs only for a config that places it by ``moe_every``; a config that
     describes a model's own router gets an error, never a dropped token."""
     kind = cfg.mlp_kind(int(L.split(".")[1]))
     if kind == "experts":
-        out, counts, rows = _moe.expert_mlp(h, p, L, cfg, valid)
+        out, counts, work = _moe.expert_mlp(h, p, L, cfg, valid)
         if calls is not None:
-            calls.append((counts, rows))
+            calls.append((counts, work))
         return out
     if kind == "gshard":
         if cfg.router_kind != "softmax" or cfg.router_bias or cfg.d_expert:
@@ -242,7 +242,7 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     states, tails = (list(ssm["s"]), list(ssm["conv"])) if ssm else ([], [])
     valid = (valid_rows(n_valid, b, m)
              if n_valid is not None and cfg.expert_layers else None)
-    calls = []            # the expert layers' (counts, rows computed)
+    calls = []            # the expert layers' (counts, work)
     ai = mi = ti = 0      # this layer's place among its kind's caches
     for i in range(cfg.n_layers):
         L = f"layers.{i}."

@@ -173,50 +173,143 @@ def route(x, p: dict, prefix: str, cfg):
     return sel.astype(jnp.int32), w
 
 
-#: (row, expert) pairs one grouped layout may be made for: its static rows
-#: are the pairs' worst case however few fall on the experts held here, and
-#: at 8,192 rows x 8 they would be 0.96 GB a buffer at d 7168.  A longer
-#: call walks its rows in chunks of this many pairs (LFM2's widest prefill,
-#: 2 x 1,024 rows x 4, is one chunk).
-MAX_PAIRS = 16384
+#: A device that holds a share of the router's experts sizes its grouped
+#: layout for that share of a call's (row, expert) pairs times this headroom,
+#: not for all of them; what exceeds it is computed in further rounds.
+HEADROOM = 2
+#: ... and for no fewer pairs than this: under it a layout is the held
+#: experts' own tiles and little else, so a small call (a decode step) keeps
+#: the layout of all its pairs and runs no compaction.
+MIN_PAIRS = 512
 
 
-def _held_pairs(xt, sel, w, p: dict, prefix: str, cfg, tm: int) -> tuple:
-    """The routed part of ``expert_mlp`` for rows xt (T, d): sel (T, k) the
-    pair's expert among those held here, ``experts_local`` for a pair that
-    is computed nowhere.  Returns (out (T, d) float32, counts, rows the
-    grouped product ran)."""
+def pair_bound(pairs: int, cfg) -> int:
+    """(row, expert) pairs one grouped layout is made for, of a call's
+    ``pairs``: all of them where every expert is held here, else the held
+    share with ``HEADROOM`` (8,192 rows x 8 on 12 of 384 experts: 4,096
+    pairs for ~2,048 expected, a layout of 5,632 rows)."""
+    share = -(-HEADROOM * pairs * cfg.experts_local // cfg.n_experts)
+    # (a row's pairs are one round's: never fewer than a row's)
+    return min(pairs, max(MIN_PAIRS, share, cfg.expert_top_k))
+
+
+def _layout(xt, pair, expert, k: int, p: dict, prefix: str, cfg, tm: int):
+    """One grouped layout and its two products.  pair (n,) int32: which of
+    the call's pairs (pair i reads row i // k of xt (T, d)), T * k for none;
+    expert (n,): its expert among those held here, ``experts_local`` for a
+    pair that is computed nowhere.  Returns (y (rows, d), dest (n,) — the
+    pair's row of y, ``rows`` for none —, counts, tiles used)."""
     from nvme_strom_tpu.ops import moe as _ops
-    T, k, E = xt.shape[0], sel.shape[1], cfg.experts_local
+    n_pairs, E = xt.shape[0] * k, cfg.experts_local
     with jax.named_scope("strom.moe.route"):
-        dest, tile_expert, n_tiles, counts = _ops.group_rows(
-            sel.reshape(T * k), E, tm)
-        rows = _ops.padded_rows(T * k, E, tm)
+        dest, tile_expert, n_tiles, counts = _ops.group_rows(expert, E, tm)
+        rows = _ops.padded_rows(pair.shape[0], E, tm)
         # the layout's row r holds pair src[r] (row src[r] // k of x), or
         # zeros; one spare row takes the pairs that go nowhere
-        src = jnp.full((rows + 1,), T * k, jnp.int32).at[dest].set(
-            jnp.arange(T * k, dtype=jnp.int32))[:rows]
-        xs = jnp.where((src < T * k)[:, None],
-                       xt[jnp.minimum(src, T * k - 1) // k], 0)
+        src = jnp.full((rows + 1,), n_pairs, jnp.int32).at[dest].set(
+            pair)[:rows]
+        xs = jnp.where((src < n_pairs)[:, None],
+                       xt[jnp.minimum(src, n_pairs - 1) // k], 0)
     with jax.named_scope("strom.moe.experts"):
         h = _ops.gmm(xs, (_tr.wmat(p, prefix + "moe_w_gate", xt.dtype),
                           _tr.wmat(p, prefix + "moe_w_up", xt.dtype)),
                      tile_expert, n_tiles, tm=tm)
         y = _ops.gmm(h, (_tr.wmat(p, prefix + "moe_w_down", xt.dtype),),
                      tile_expert, n_tiles, tm=tm)
+    return y, dest, counts, n_tiles
+
+
+def _all_pairs(xt, sel, w, p: dict, prefix: str, cfg, tm: int) -> tuple:
+    """The routed part of ``expert_mlp`` in one layout of all the call's
+    pairs.  Returns (out (T, d) float32, counts, rows the grouped product
+    ran, rounds: one)."""
+    T, k = sel.shape
+    y, dest, counts, n_tiles = _layout(
+        xt, jnp.arange(T * k, dtype=jnp.int32), sel.reshape(T * k), k, p,
+        prefix, cfg, tm)
     with jax.named_scope("strom.moe.route"):
         dest = dest.reshape(T, k)
-        live = dest < rows
-        picked = y[jnp.minimum(dest, rows - 1)].astype(jnp.float32)
+        live = dest < y.shape[0]
+        picked = y[jnp.minimum(dest, y.shape[0] - 1)].astype(jnp.float32)
         out = jnp.sum(jnp.where(live[..., None], w[..., None] * picked, 0.0),
                       axis=1)
-    return out, counts, n_tiles * tm
+    return out, counts, n_tiles * tm, 1
+
+
+def _local_pairs(xt, sel, w, p: dict, prefix: str, cfg, tm: int,
+                 bound: int) -> tuple:
+    """The routed part of ``expert_mlp`` where few of the call's pairs fall
+    on the experts held here: the local pairs are numbered by a running
+    count and computed ``bound`` at a time, every round in one layout of
+    ``bound`` pairs.  A round more is all a hot share costs: no pair is
+    dropped.  Only int32 arrays (and the pairs' float32 weights) are as
+    long as the call's pairs; nothing is scattered but int32.  Returns
+    (out (T, d) in xt's dtype — the float32 sums, rounded —, counts, rows
+    the grouped product ran, rounds run: at least one)."""
+    T, k = sel.shape
+    n_pairs, held = T * k, cfg.experts_local
+    with jax.named_scope("strom.moe.route"):
+        expert, weight = sel.reshape(n_pairs), w.reshape(n_pairs)
+        local = expert < held
+        seen = jnp.cumsum(local, dtype=jnp.int32)
+        # per row: the local pairs before it, and its own
+        first = (seen - local).reshape(T, k)[:, 0]
+        mine = jnp.sum(local.reshape(T, k), axis=1)
+        # order[j]: the j-th local pair, n_pairs past the last; long enough
+        # for a window to start at any of them
+        slots = n_pairs + bound + 1
+        order = jnp.full((slots,), n_pairs, jnp.int32).at[
+            jnp.where(local, seen - 1, slots)].set(
+            jnp.arange(n_pairs, dtype=jnp.int32), mode="drop")
+        ahead = jnp.arange(bound, dtype=jnp.int32)
+
+    def one_round(carry):
+        out, counts, tiles, start, rounds = carry
+        with jax.named_scope("strom.moe.route"):
+            window = jax.lax.dynamic_slice(order, (start,), (bound + 1,))
+            pair, beyond = window[:bound], window[bound]
+            # a row's pairs are ONE round's (its sum is made once, in
+            # float32): those of a row that goes on past the window wait
+            there = (pair < n_pairs) & (pair // k != beyond // k)
+            at = jnp.minimum(pair, n_pairs - 1)
+        y, dest, c, n_tiles = _layout(
+            xt, jnp.where(there, pair, n_pairs),
+            jnp.where(there, expert[at], held), k, p, prefix, cfg, tm)
+        with jax.named_scope("strom.moe.route"):
+            picked = y[jnp.minimum(dest, y.shape[0] - 1)]
+            row = jnp.where(there, pair // k, T)
+            scale = jnp.where(there, weight[at], 0.0)
+            # a row's pairs are neighbours in the order: each row's first
+            # one sums the row's, at most k of them, in float32
+            total = jnp.zeros(picked.shape, jnp.float32)
+            for j in range(k):
+                same = there & (jnp.roll(row, -j) == row) & (ahead < bound - j)
+                total = total + jnp.where(
+                    same[:, None], jnp.roll(scale, -j)[:, None]
+                    * jnp.roll(picked, -j, axis=0).astype(jnp.float32), 0.0)
+            taken = jnp.sum(there, dtype=jnp.int32)
+            here = (mine > 0) & (first >= start) & (first < start + taken)
+            out = jnp.where(
+                here[:, None],
+                total.astype(out.dtype)[jnp.clip(first - start, 0, bound - 1)],
+                out)
+        return out, counts + c, tiles + n_tiles, start + taken, rounds + 1
+
+    # the first round is every call's and stands in the open, where XLA
+    # schedules it with its neighbours; the loop is the overflow's alone
+    out, counts, tiles, _, rounds = jax.lax.while_loop(
+        lambda carry: carry[3] < seen[-1], one_round,
+        one_round((jnp.zeros(xt.shape, xt.dtype),
+                   jnp.zeros((held,), jnp.int32), jnp.int32(0), jnp.int32(0),
+                   jnp.int32(0))))
+    return out, counts, tiles * tm, rounds
 
 
 def expert_mlp(x: jax.Array, p: dict, prefix: str, cfg, valid=None) -> tuple:
     """The exact expert layer.  x (b, s, d) -> (out (b, s, d), counts
     (experts held,) int32 — pairs that fell on each expert held here —,
-    rows the grouped product ran, tile padding included ()).
+    work (2,) int32: rows the grouped product ran, tile padding included,
+    and the rounds it took).
 
     ``valid`` (b, s) bool or None: rows that are not valid (right padding
     of a prefill, a free serving slot) are routed nowhere — they cost no
@@ -225,14 +318,17 @@ def expert_mlp(x: jax.Array, p: dict, prefix: str, cfg, valid=None) -> tuple:
     The router scores all ``n_experts``; a device that holds a share of
     them (``cfg.experts_held`` from ``cfg.expert_offset``) computes the
     pairs that fall on its own and sends the others nowhere, as it does pad
-    rows: their part of the sum is another device's.  The weights are
-    normalised over all the selected experts, held or not.  A shared expert
-    (``cfg.d_shared``) takes every row beside the routed ones."""
+    rows: their part of the sum is another device's.  Its layout is sized
+    by that share (``pair_bound``), and a call whose local pairs exceed it
+    takes further rounds.  The weights are normalised over all the selected
+    experts, held or not.  A shared expert (``cfg.d_shared``) takes every
+    row beside the routed ones."""
     from nvme_strom_tpu.ops import moe as _ops
     b, s, d = x.shape
     T, k, held = b * s, cfg.expert_top_k, cfg.experts_local
     xt = x.reshape(T, d)
-    tm = _ops.tile_rows(min(T * k, MAX_PAIRS), cfg.n_experts)
+    tm = _ops.tile_rows(T * k, cfg.n_experts)
+    bound = pair_bound(T * k, cfg)
     with jax.named_scope("strom.moe.route"):
         sel, w = route(xt, p, prefix, cfg)
         if held != cfg.n_experts:
@@ -240,41 +336,30 @@ def expert_mlp(x: jax.Array, p: dict, prefix: str, cfg, valid=None) -> tuple:
             sel = jnp.where((sel >= 0) & (sel < held), sel, held)
         if valid is not None:
             sel = jnp.where(valid.reshape(T, 1), sel, held)
-    if T * k <= MAX_PAIRS:
-        out, counts, rows = _held_pairs(xt, sel, w, p, prefix, cfg, tm)
+    if bound == T * k:
+        out, counts, rows, rounds = _all_pairs(xt, sel, w, p, prefix, cfg, tm)
     else:
-        # a long prefill: chunks of MAX_PAIRS pairs, one layout at a time
-        # (each chunk reads the experts it touches again: a few hundred
-        # rows an expert amortise that)
-        tc = MAX_PAIRS // k
-        pad = -T % tc
-
-        def chunks(a, fill):
-            a = jnp.pad(a, ((0, pad), (0, 0)), constant_values=fill)
-            return a.reshape(-1, tc, a.shape[-1])
-
-        out, counts, rows = jax.lax.map(
-            lambda c: _held_pairs(*c, p, prefix, cfg, tm),
-            (chunks(xt, 0), chunks(sel, held), chunks(w, 0)))
-        out = out.reshape(-1, d)[:T]
-        counts, rows = counts.sum(axis=0), rows.sum()
+        out, counts, rows, rounds = _local_pairs(xt, sel, w, p, prefix, cfg,
+                                                 tm, bound)
     out = out.astype(x.dtype).reshape(b, s, d)
     if cfg.d_shared:
         with jax.named_scope("strom.moe.shared"):
             out = out + _tr.mlp(x, p, prefix + "shared_")
-    return out, counts, rows
+    return out, counts, jnp.stack([rows, rounds]).astype(jnp.int32)
 
 
 #: columns of ``load_counters``' "sums": per expert layer, summed over calls
-SUMS = ("experts_touched", "rows_computed", "load_max")
+SUMS = ("experts_touched", "rows_computed", "load_max", "rounds")
 
 
 def load_counters(cfg) -> dict:
     """Zeroed device counters of the exact expert layers: ``load``
     (expert layers, experts held) int32, pairs that fell on each expert
     held here, and ``sums``
-    (expert layers, 3) int32: experts touched, rows computed, and the
-    busiest expert's load, each summed over the calls."""
+    (expert layers, 4) int32: experts touched, rows computed, the busiest
+    expert's load and the rounds run (``expert_mlp``: one a call unless a
+    call's local pairs overflowed its layout), each summed over the
+    calls."""
     n = len(cfg.expert_layers)
     return {"load": jnp.zeros((n, cfg.experts_local), jnp.int32),
             "sums": jnp.zeros((n, len(SUMS)), jnp.int32)}
@@ -282,10 +367,11 @@ def load_counters(cfg) -> dict:
 
 def add_load(counters: dict, calls: list) -> dict:
     """``counters`` plus one call of every expert layer: ``calls`` is
-    [(counts (E,), rows computed ())] in layer order."""
+    [(counts (E,), work (2,): rows computed, rounds)] in layer order."""
     load = jnp.stack([c for c, _ in calls])
-    sums = jnp.stack([jnp.stack([jnp.sum(c > 0), r, jnp.max(c)])
-                      for c, r in calls])
+    sums = jnp.stack([jnp.stack([jnp.sum(c > 0), work[0], jnp.max(c),
+                                 work[1]])
+                      for c, work in calls])
     return {"load": counters["load"] + load,
             "sums": counters["sums"] + sums.astype(jnp.int32)}
 

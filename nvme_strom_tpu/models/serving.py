@@ -319,7 +319,7 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     s_pools, tails = ((list(state["s"]), list(state["conv"]))
                       if cfg.recurrent_layers else ([], []))
     live = ~free[:, None] if cfg.expert_layers else None
-    calls = []              # the expert layers' (counts, rows computed)
+    calls = []              # the expert layers' (counts, work)
     x = embed_tokens(params, cfg, tok[:, None])               # (B,1,d)
     positions = pos.astype(jnp.float32)[:, None]              # (B,1)
     ai = mi = ti = 0        # attention / mamba / tail-keeping layers so far
@@ -580,8 +580,10 @@ class DecodeServer:
         #: holds — all of them unless ``cfg.experts_held`` says a share),
         #: ``moe_rows_computed`` (rows the grouped
         #: product ran, tile padding included), ``moe_experts_touched``
-        #: (experts with at least one row) and ``moe_load_max`` (the
-        #: busiest expert's rows) — sums over the calls
+        #: (experts with at least one row), ``moe_load_max`` (the
+        #: busiest expert's rows) and ``moe_rounds`` (layouts the calls
+        #: took: ``moe_calls`` unless a call's local pairs overflowed its
+        #: bounded layout, ``models/moe.pair_bound``) — sums over the calls
         self.timings: Dict[str, float] = {
             "admit_s": 0.0, "dispatch_s": 0.0, "readback_s": 0.0,
             "steps": 0, "readbacks": 0,
@@ -593,7 +595,7 @@ class DecodeServer:
             **{key + sfx: 0 for sfx in ("", "_prefill") for key in (
                 "moe_calls", "moe_pairs", "moe_pairs_routed",
                 "moe_rows_computed", "moe_experts_touched",
-                "moe_load_max")}}
+                "moe_load_max", "moe_rounds")}}
         #: cumulative (expert layers, E) load histogram of the decode steps
         #: (numpy; None without expert layers)
         self.moe_load = None
@@ -1330,7 +1332,8 @@ class DecodeServer:
         # (decode steps; the prefill's own under *_prefill in timings)
         out["moe_layers"] = len(self.cfg.expert_layers)
         for key in ("moe_pairs", "moe_pairs_routed", "moe_rows_computed",
-                    "moe_experts_touched", "moe_load_max", "moe_calls"):
+                    "moe_experts_touched", "moe_load_max", "moe_calls",
+                    "moe_rounds", "moe_calls_prefill", "moe_rounds_prefill"):
             out[key] = self.timings[key]
         # what a deployment's share looks like from here: the routed
         # experts whose weights this device holds, and the bytes one token

@@ -300,13 +300,16 @@ def _moe_gmm_down_1024(topo):
 
 def _moe_gmm_7168(topo, gated=True):
     """The grouped product at Kimi-K2's widths (12 experts held of 7168 x
-    2048): a gated contraction 7168 deep, whose column tile narrows to 256
-    so that the weight blocks fit VMEM, and the down product back."""
+    2048) over the bounded layout of an 8,192-row prompt (4,096 of its
+    65,536 pairs, ``models/moe.pair_bound``: 5,632 rows in tiles of 128):
+    a gated contraction 7168 deep, whose column tile narrows to 256 so that
+    the weight blocks fit VMEM, and the down product back."""
     from nvme_strom_tpu.ops import moe as ops
     sh = _one(topo)
-    E, d, fe, pairs = 12, 7168, 2048, 16384
-    tm = ops.tile_rows(pairs, 384)
-    padded = ops.padded_rows(pairs, E, tm)
+    E, d, fe = 12, 7168, 2048
+    tm = ops.tile_rows(8192 * 8, 384)
+    padded = ops.padded_rows(4096, E, tm)
+    assert (tm, padded) == (128, 5632)
     kdim, n = (d, fe) if gated else (fe, d)
     w = _spec((E, kdim, n), jnp.bfloat16, sh)
     return _compile(
@@ -649,6 +652,10 @@ def test_k2c_long_prefill_holds_no_score_tensor_over_a_gib(topo,
         _spec((1, rows // bk), jnp.int32, sh), vec, state, vec).compile()
     text = compiled.as_text()
     assert text.count("strom_mla_prefill") >= 2
+    # the expert layer's one layout is the held share's (4,096 of 65,536
+    # pairs in tiles of 128), not a 16,384-pair chunk's 17,152 rows
+    assert "bf16[5632,7168]" in text and "bf16[5632,2048]" in text
+    assert "[17152," not in text
     size = {"f32": 4, "bf16": 2, "s32": 4}
     largest = max(size[t] * int(np.prod([int(n) for n in dims.split(",")]))
                   for t, dims in re.findall(r"\b(f32|bf16|s32)\[([\d,]+)\]",

@@ -216,13 +216,13 @@ def test_extreme_loads_are_computed_exactly(model, case):
     else:
         bias[0] = -10.0
     p = dict(params, **{L + "router_bias": jnp.asarray(bias)})
-    out, counts, rows = moe.expert_mlp(x, p, L, cfg)
+    out, counts, work = moe.expert_mlp(x, p, L, cfg)
     counts = np.asarray(counts)
     if case == "one_expert_holds_every_row":
         assert counts[3] == counts[5] == 40 and counts.sum() == 80
     else:
         assert counts[0] == 0 and counts.sum() == 80
-    assert int(rows) >= 80
+    assert int(work[0]) >= 80 and int(work[1]) == 1     # rows, rounds
     w = {k[len(L):]: v for k, v in p.items() if k.startswith(L)}
     stacked = (w["moe_w_gate"], w["moe_w_up"], w["moe_w_down"])
     with jax.default_matmul_precision("highest"):

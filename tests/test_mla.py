@@ -387,20 +387,156 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_whole():
                                np.asarray(shared[1, 6:]), atol=1e-6)
 
 
-def test_a_long_call_walks_its_rows_in_chunks(monkeypatch):
-    """Past ``MAX_PAIRS`` (row, expert) pairs the layer lays out one chunk
-    of rows at a time; results and counts are those of one layout."""
+def _share_layer():
+    """The expert layer of a device that holds experts 8..11 of 16."""
     cfg, p = _expert_layer(dict(experts_held=4, expert_offset=8))
-    p = dict(p, **{k: p[k][:4] for k in ("moe_w_gate", "moe_w_up",
-                                         "moe_w_down")})
-    x = jax.random.normal(jax.random.key(2), (1, 50, 32), jnp.float32)
-    valid = jnp.ones((1, 50), bool).at[0, 45:].set(False)
-    want, counts, _ = moe.expert_mlp(x, p, "", cfg, valid)
-    monkeypatch.setattr(moe, "MAX_PAIRS", 64)          # 16 rows a chunk
-    got, c2, rows = moe.expert_mlp(x, p, "", cfg, valid)
+    return cfg, dict(p, **{k: p[k][:4] for k in ("moe_w_gate", "moe_w_up",
+                                                 "moe_w_down")})
+
+
+#: name: (the router's bias sends every row to the four held experts,
+#: rows of right padding, HEADROOM, MIN_PAIRS, the layout's pairs, rounds)
+#: over 50 rows x 4 = 200 pairs
+BOUNDED = {
+    "under_the_bound":          (False, 5, 2, 8, 100, 1),
+    "no_pad_rows":              (False, 0, 2, 8, 100, 1),
+    "exactly_at_the_bound":     (True, 5, 0, 180, 180, 1),
+    "one_pair_over":            (True, 5, 0, 179, 179, 2),
+    "a_hot_share_two_rounds":   (True, 5, 2, 8, 100, 2),
+    "a_hot_share_three_rounds": (True, 5, 1, 60, 60, 3),
+    # a row's pairs are never split: 6 pairs a layout take one row of 4
+    "a_row_a_round":            (True, 5, 0, 6, 6, 45),
+    "never_under_a_rows_pairs": (True, 5, 0, 1, 4, 45),
+}
+
+
+@pytest.mark.parametrize("case", list(BOUNDED))
+def test_a_bounded_layout_computes_every_local_pair(case, monkeypatch):
+    """The layout of a device that holds a share is made for ``pair_bound``
+    pairs; local pairs past it take further rounds through the same layout.
+    Output and load histogram are those of ONE layout of all the call's
+    pairs (the bound at its default floor), whatever the rounds: nothing is
+    dropped, and ``work`` says how many ran."""
+    hot, pad, headroom, floor, bound, rounds = BOUNDED[case]
+    cfg, p = _share_layer()
+    if hot:
+        p["router_bias"] = jnp.zeros((16,)).at[8:12].set(10.0)
+    x = jax.random.normal(jax.random.key(2), (2, 25, 32), jnp.float32)
+    valid = jnp.ones((2, 25), bool).at[0, 25 - pad:].set(False)
+    assert moe.pair_bound(200, cfg) == 200              # one layout of all
+    want, counts, work = moe.expert_mlp(x, p, "", cfg, valid)
+    assert work[1] == 1
+    local = int(counts.sum())
+    assert local == (4 * (50 - pad) if hot else local) and 0 < local < 200
+    monkeypatch.setattr(moe, "HEADROOM", headroom)
+    monkeypatch.setattr(moe, "MIN_PAIRS", floor)
+    assert moe.pair_bound(200, cfg) == bound
+    got, c2, work = moe.expert_mlp(x, p, "", cfg, valid)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     np.testing.assert_array_equal(c2, counts)
-    assert int(rows) % 16 == 0
+    assert int(work[1]) == rounds >= -(-local // bound)
+    assert int(work[0]) % 16 == 0 and int(work[0]) >= local
+    assert not np.asarray(got)[0, 25 - pad:].any() or cfg.d_shared
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the loops inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _arrays(jaxpr):
+    return (v.aval for eqn in _eqns(jaxpr) for v in eqn.outvars)
+
+
+def _primitives(jaxpr):
+    return {eqn.primitive.name for eqn in _eqns(jaxpr)}
+
+
+def test_a_device_that_holds_every_expert_traces_no_compaction(monkeypatch):
+    """Where every expert is held (``experts_held`` 0) the layer is one
+    layout of all its pairs with no loop of rounds, however low the floor;
+    a share under the floor likewise (a decode step).  A share over it
+    carries nothing as long as the call's pairs but int32 and the pairs'
+    float32 weights: every array d or d_expert wide has at most the call's
+    rows or the bounded layout's."""
+    monkeypatch.setattr(moe, "MIN_PAIRS", 8)
+    x = jax.random.normal(jax.random.key(2), (1, 50, 32), jnp.float32)
+
+    def traced(cfg, p):
+        return jax.make_jaxpr(
+            lambda x: moe.expert_mlp(x, p, "", cfg))(x).jaxpr
+
+    def wide(jaxpr):       # rows of the longest float array d or d_expert wide
+        return max(a.shape[0] for a in _arrays(jaxpr) if a.ndim == 2
+                   and a.shape[1] in (32, 16)
+                   and jnp.issubdtype(a.dtype, jnp.floating))
+
+    whole = traced(*_expert_layer({}))
+    assert "while" not in _primitives(whole)
+    assert wide(whole) == ops_moe.padded_rows(200, 16, 16) == 448
+
+    share = traced(*_share_layer())
+    assert "while" in _primitives(share)
+    assert "scatter-add" not in _primitives(share)
+    bound = moe.pair_bound(200, _share_layer()[0])
+    assert bound == 100
+    assert wide(share) == ops_moe.padded_rows(bound, 4, 16) == 160
+
+    monkeypatch.setattr(moe, "MIN_PAIRS", 512)          # the default floor
+    small = traced(*_share_layer())
+    assert "while" not in _primitives(small)
+    assert wide(small) == ops_moe.padded_rows(200, 4, 16) == 256
+
+
+@pytest.mark.parametrize("overflow", [False, True],
+                         ids=["nothing_overflows", "a_hot_share_overflows"])
+def test_the_server_counts_the_rounds_its_expert_layers_ran(model, overflow,
+                                                            monkeypatch):
+    """``moe_rounds`` / ``moe_rounds_prefill`` in ``timings`` and
+    ``stats()``: the calls' own number when no call's local pairs pass its
+    layout; more where a router sends every row to the experts held here
+    under a bound of one row's pairs — and the tokens served are the same."""
+    cfg, params = model
+    if overflow:
+        held = slice(cfg.expert_offset, cfg.expert_offset + cfg.experts_held)
+        params = dict(params, **{
+            key: bias.at[held].add(10.0) for key, bias in params.items()
+            if key.endswith("router_bias")})
+    prompts = {"a": _prompt(10), "b": _prompt(21), "c": _prompt(5)}
+
+    def serve(cfg):
+        srv = _server((cfg, params), slots=2)
+        for rid, prompt in prompts.items():
+            srv.submit(rid, prompt, 5)
+        return srv.run(lookahead=2), srv
+
+    want, srv = serve(cfg)
+    if overflow:
+        monkeypatch.setattr(moe, "HEADROOM", 0)
+        monkeypatch.setattr(moe, "MIN_PAIRS", 1)
+        # a config of its own (the field is the training path's), so that
+        # the server's programs are compiled under this bound
+        got, srv = serve(dataclasses.replace(cfg, xent_chunks=2))
+        assert got == want
+    t, st = srv.timings, srv.stats()
+    assert t["moe_calls"] == 2 * t["steps"] > 0
+    assert t["moe_calls_prefill"] == 2 * t["prefill_calls"] > 0
+    for key in ("moe_rounds", "moe_rounds_prefill", "moe_calls_prefill"):
+        assert st[key] == t[key]
+    if overflow:
+        # a round a live row: every pair of a row is local, k of them fill
+        # the layout; a call with no live row still runs its one
+        k = cfg.expert_top_k
+        assert t["moe_calls"] < t["moe_pairs"] // k <= t["moe_rounds"] \
+            <= t["moe_pairs"] // k + t["moe_calls"]
+        assert t["moe_rounds_prefill"] == t["moe_pairs_prefill"] // k \
+            == 2 * sum(len(p) for p in prompts.values())
+    else:
+        assert t["moe_rounds"] == t["moe_calls"]
+        assert t["moe_rounds_prefill"] == t["moe_calls_prefill"]
 
 
 # -- (7) the config --------------------------------------------------------------

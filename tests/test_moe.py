@@ -182,3 +182,52 @@ def test_capacity_layer_against_the_exact_layer(factor, drops):
         assert over > 0 and diff > 1e-2 * scale
     else:
         assert over == 0 and diff < 1e-5 * scale
+
+
+def _routed(n_experts, held, top_k=8):
+    import dataclasses
+    return dataclasses.replace(
+        tiny_moe_config(), n_experts=n_experts, expert_top_k=top_k,
+        experts_held=held, mlp_kinds=("experts",) * 2)
+
+
+@pytest.mark.parametrize("rows,top_k,n_experts,held,bound,layout", [
+    # Kimi-K2's share of a deployment, 12 of 384 experts, top-8: twice the
+    # expected local pairs at the four prompt lengths of k2c.flood8k ...
+    (8192, 8, 384, 12, 4096, 5632),
+    (4096, 8, 384, 12, 2048, 3584),
+    (2048, 8, 384, 12, 1024, 1792),
+    (1024, 8, 384, 12, 512, 896),
+    # ... and a decode step's 64 slots at the floor: all of its pairs
+    (64, 8, 384, 12, 512, 704),
+    (16, 8, 384, 12, 128, 320),
+    # LFM2 holds all 64: every pair, whatever the call (0 says "all" too)
+    (2048, 4, 64, 64, 8192, 16384),
+    (2048, 4, 64, 0, 8192, 16384),
+    (128, 4, 64, 0, 512, 1536),
+    # half of the experts: the headroom is all of the pairs
+    (4096, 2, 8, 4, 8192, 8704),
+], ids=lambda v: str(v))
+def test_pair_bound_follows_the_share_of_the_experts_held(
+        rows, top_k, n_experts, held, bound, layout):
+    """The pairs one grouped layout is made for: the held share of the
+    call's pairs times the headroom, at least the floor, at most all; and
+    the static rows of that layout at the call's tile."""
+    from nvme_strom_tpu.models.moe import pair_bound
+    from nvme_strom_tpu.ops import moe as ops
+    cfg = _routed(n_experts, held, top_k)
+    assert pair_bound(rows * top_k, cfg) == bound
+    tm = ops.tile_rows(rows * top_k, n_experts)
+    assert ops.padded_rows(bound, cfg.experts_local, tm) == layout
+
+
+def test_add_load_sums_a_calls_rounds_beside_its_rows():
+    from nvme_strom_tpu.models import moe
+    cfg = _routed(16, 4, 4)
+    calls = [(jnp.asarray([3, 0, 5, 1]), jnp.asarray([48, 2])),
+             (jnp.asarray([0, 0, 0, 0]), jnp.asarray([0, 1]))]
+    got = moe.add_load(moe.add_load(moe.load_counters(cfg), calls), calls)
+    assert moe.SUMS == ("experts_touched", "rows_computed", "load_max",
+                        "rounds")
+    np.testing.assert_array_equal(got["load"], [[6, 0, 10, 2], [0, 0, 0, 0]])
+    np.testing.assert_array_equal(got["sums"], [[6, 96, 10, 4], [0, 0, 0, 2]])

@@ -290,6 +290,8 @@ def test_server_stats_gauges(setup):
              "moe_layers": 0, "moe_calls": 0, "moe_pairs": 0,
              "moe_pairs_routed": 0, "moe_rows_computed": 0,
              "moe_experts_touched": 0, "moe_load_max": 0,
+             "moe_rounds": 0, "moe_calls_prefill": 0,
+             "moe_rounds_prefill": 0,
              # nor a share of a deployment's experts, nor a latent pool
              # (tests/test_mla.py has both)
              "experts_held": 0, "latent_bytes_per_token": 0}
