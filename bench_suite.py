@@ -1630,7 +1630,7 @@ def bench_serving(device=None) -> tuple[float, str]:
            f"lookahead={lookahead}; phases(last run "
            f"{ts[-1]:.2f}s): admit={tm['admit_s']:.2f}s "
            f"dispatch={tm['dispatch_s']:.2f}s "
-           f"readback={tm['readback_s']:.2f}s({tm['readbacks']}x) "
+           f"readback={tm['readback_s']:.2f}s "
            f"sched={other:.2f}s, steps={tm['steps']}")
     if paged:
         tag += (f" paged={total_blocks}x{block_len} "

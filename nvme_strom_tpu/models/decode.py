@@ -182,6 +182,19 @@ def decode_step(params: Dict, token: jax.Array, cfg: TransformerConfig,
     return lm_logits(params, cfg, x), cache
 
 
+#: A layer's mixer by the scopes around its kernel's own: (its norm and input
+#: projection, its output projection and residual add).  With ``strom.embed``,
+#: ``strom.mlp`` (norm and residual add included) and ``strom.head`` they put
+#: every operation of ``block_step`` and of the serving step
+#: (``serving.paged_logits``) under one family — the first ``strom.<family>``
+#: of its scope path, which the benchmark reads device time by
+#: (docs/OBSERVABILITY.md has the table).  A scope is metadata on the lowered
+#: program and costs nothing at run time.
+MIXER_SCOPES = {"attention": ("strom.attn.proj", "strom.attn.out"),
+                "mamba": ("strom.ssm.proj", "strom.ssm.out"),
+                "conv": ("strom.conv", "strom.conv")}
+
+
 def cache_attention(q, ck, cv, limit, cfg: TransformerConfig):
     """Masked attention of an m-row query block over a live KV cache —
     the ONE dense cache-attention implementation (block_step, and the
@@ -234,19 +247,23 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     """
     b, m = tokens.shape
     pos = cache["pos"]
-    x = embed_tokens(params, cfg, tokens)
-    positions = pos.astype(jnp.float32) + jnp.arange(m, dtype=jnp.float32)
-    # row t sees cache positions <= pos + t (same limit for every row)
-    limit = jnp.broadcast_to(pos + jnp.arange(m), (b, m))
+    with jax.named_scope("strom.embed"):
+        x = embed_tokens(params, cfg, tokens)
+        positions = (pos.astype(jnp.float32)
+                     + jnp.arange(m, dtype=jnp.float32))
+        # row t sees cache positions <= pos + t (same limit for every row)
+        limit = jnp.broadcast_to(pos + jnp.arange(m), (b, m))
+        valid = (valid_rows(n_valid, b, m)
+                 if n_valid is not None and cfg.expert_layers else None)
     ssm = cache.get("ssm")
     states, tails = (list(ssm["s"]), list(ssm["conv"])) if ssm else ([], [])
-    valid = (valid_rows(n_valid, b, m)
-             if n_valid is not None and cfg.expert_layers else None)
     calls = []            # the expert layers' (counts, work)
     ai = mi = ti = 0      # this layer's place among its kind's caches
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
-        h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
+        before, after = MIXER_SCOPES[cfg.mixer(i)]
+        with jax.named_scope(before):
+            h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
         if cfg.is_mamba_layer(i):
             from nvme_strom_tpu.models.ssm import mamba_block
             a, states[mi], tails[ti] = mamba_block(
@@ -267,35 +284,45 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
                     (ai, 0, 0, pos, 0))
                 a = _mla.attend(q, cache["k"][ai, :, 0], pos, params, L,
                                 cfg)
-            a = a @ wmat(params, L + "wo", a.dtype)
+            with jax.named_scope(after):
+                a = a @ wmat(params, L + "wo", a.dtype)
             ai += 1
         else:
-            q, k, v = qkv_project(h, params, L, cfg, positions=positions)
-            cache["k"] = lax.dynamic_update_slice(
-                cache["k"], k[None].astype(cfg.dtype), (ai, 0, 0, pos, 0))
-            cache["v"] = lax.dynamic_update_slice(
-                cache["v"], v[None].astype(cfg.dtype), (ai, 0, 0, pos, 0))
+            with jax.named_scope(before):
+                q, k, v = qkv_project(h, params, L, cfg, positions=positions)
             with jax.named_scope("strom.attn.paged"):
+                cache["k"] = lax.dynamic_update_slice(
+                    cache["k"], k[None].astype(cfg.dtype),
+                    (ai, 0, 0, pos, 0))
+                cache["v"] = lax.dynamic_update_slice(
+                    cache["v"], v[None].astype(cfg.dtype),
+                    (ai, 0, 0, pos, 0))
                 a = cache_attention(q, cache["k"][ai], cache["v"][ai],
                                     limit, cfg)
-            a = a.transpose(0, 2, 1, 3).reshape(b, m, -1)
-            a = a @ wmat(params, L + "wo", a.dtype)
+            with jax.named_scope(after):
+                a = a.transpose(0, 2, 1, 3).reshape(b, m, -1)
+                a = a @ wmat(params, L + "wo", a.dtype)
             ai += 1
-        x = add_residual(x, a, cfg)
-        h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
+        with jax.named_scope(after):
+            x = add_residual(x, a, cfg)
         with jax.named_scope("strom.mlp"):
+            h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
             f = _mlp_block(h, params, L, cfg, valid, calls)
-        x = add_residual(x, f, cfg).astype(cfg.dtype)
-    cache["pos"] = pos + m
+            x = add_residual(x, f, cfg).astype(cfg.dtype)
+    with jax.named_scope("strom.head"):
+        cache["pos"] = pos + m
     if ssm:
         cache["ssm"] = {"s": tuple(states), "conv": tuple(tails)}
     if "moe" in cache and calls:
-        cache["moe"] = _moe.add_load(cache["moe"], calls)
-    if last is not None:
-        rows = jnp.broadcast_to(jnp.asarray(last, jnp.int32), (b,))
-        x = jnp.take_along_axis(x, rows[:, None, None], axis=1)[:, 0]
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return lm_logits(params, cfg, x), cache
+        with jax.named_scope("strom.mlp"):
+            cache["moe"] = _moe.add_load(cache["moe"], calls)
+    with jax.named_scope("strom.head"):
+        if last is not None:
+            rows = jnp.broadcast_to(jnp.asarray(last, jnp.int32), (b,))
+            x = jnp.take_along_axis(x, rows[:, None, None], axis=1)[:, 0]
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = lm_logits(params, cfg, x)
+    return logits, cache
 
 
 def nucleus_truncate(logits, top_p):

@@ -195,22 +195,33 @@ def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
     ``n_valid`` tells it where each prompt ends (``last`` -1: a row that
     holds no prompt, routed nowhere, its state left empty)."""
     b, m = tokens.shape
-    cache = _dec.init_cache(cfg, b, m)
-    if ssm is not None:
-        cache["ssm"] = ssm
-    if moe is not None:
-        cache["moe"] = moe
-    if k_head is not None:
-        cache["k"] = jnp.concatenate(
-            [k_head.astype(cfg.dtype), cache["k"]], axis=3)
-        if v_head is not None:
-            cache["v"] = jnp.concatenate(
-                [v_head.astype(cfg.dtype), cache["v"]], axis=3)
-        cache["pos"] = jnp.asarray(k_head.shape[3], jnp.int32)
+    with jax.named_scope("strom.prefill.gather"):
+        cache = _dec.init_cache(cfg, b, m)
+        if ssm is not None:
+            cache["ssm"] = ssm
+        if moe is not None:
+            cache["moe"] = moe
+        if k_head is not None:
+            cache["k"] = jnp.concatenate(
+                [k_head.astype(cfg.dtype), cache["k"]], axis=3)
+            if v_head is not None:
+                cache["v"] = jnp.concatenate(
+                    [v_head.astype(cfg.dtype), cache["v"]], axis=3)
+            cache["pos"] = jnp.asarray(k_head.shape[3], jnp.int32)
+        n_valid = last + 1
     logits, cache = _dec.block_step(params, tokens, cfg, cache, last=last,
-                                    n_valid=last + 1)
+                                    n_valid=n_valid)
     return (logits, cache["k"], cache["v"], cache.get("ssm"),
             cache.get("moe"))
+
+
+def prefill_program(width: int, suffix: int, cache: int) -> str:
+    """The name of one compiled shape of ``_paged_prefill``: prompts in the
+    group x suffix rows x rows of cache each attends.  The host span
+    ``strom.serve.prefill`` carries it as ``program=`` and every device
+    operation of that shape lies under the scope ``strom.prefill.<name>``,
+    so a slow span and its device time are found by one string."""
+    return f"{width}x{suffix}x{cache}"
 
 
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2, 3, 7))
@@ -240,33 +251,40 @@ def _paged_prefill(params: Dict, cfg: TransformerConfig, k_pool, v_pool,
     bk = k_pool.shape[3]
     b, m = tokens.shape
     ct = blks.shape[1] - m // bk
-    k_head = v_head = None
-    if ct:
-        k_head, v_head = _gather_prefix(k_pool, v_pool, blks[:, :ct])
-    moe = state.get("moe") if state else None
-    logits, k, v, ssm, load = _prefill_rows(
-        params, cfg, tokens, k_head, v_head, last,
-        _ssm.init_state(cfg, b) if cfg.recurrent_layers else None,
-        moe and moe["prefill"])
-    if state is not None:
-        state = dict(state, **{key: tuple(
-            pool.at[slot].set(new.astype(pool.dtype))
-            for pool, new in zip(state[key], ssm[key])) for key in ssm or ()})
-        if moe:
-            state["moe"] = dict(moe, prefill=load)
+    # this compiled shape's label, over every operation of it
+    with jax.named_scope(
+            "strom.prefill." + prefill_program(b, m, blks.shape[1] * bk)):
+        k_head = v_head = None
+        with jax.named_scope("strom.prefill.gather"):
+            if ct:
+                k_head, v_head = _gather_prefix(k_pool, v_pool, blks[:, :ct])
+            ssm0 = _ssm.init_state(cfg, b) if cfg.recurrent_layers else None
+        moe = state.get("moe") if state else None
+        logits, k, v, ssm, load = _prefill_rows(
+            params, cfg, tokens, k_head, v_head, last, ssm0,
+            moe and moe["prefill"])
 
-    def new_rows(dense):                   # → (L, b * (n - ct), nkv, bk, hd)
-        if dense is None:
-            return None
-        L, _, nkv, _, hd = dense.shape
-        if cfg.latent:                     # → (L, b * (n - ct), width, bk)
-            return (dense[:, :, 0, ct * bk:].reshape(L, -1, bk, hd)
-                    .transpose(0, 1, 3, 2))
-        return (dense[:, :, :, ct * bk:].reshape(L, b, nkv, -1, bk, hd)
-                .transpose(0, 1, 3, 2, 4, 5).reshape(L, -1, nkv, bk, hd))
+        def new_rows(dense):               # → (L, b * (n - ct), nkv, bk, hd)
+            if dense is None:
+                return None
+            L, _, nkv, _, hd = dense.shape
+            if cfg.latent:                 # → (L, b * (n - ct), width, bk)
+                return (dense[:, :, 0, ct * bk:].reshape(L, -1, bk, hd)
+                        .transpose(0, 1, 3, 2))
+            return (dense[:, :, :, ct * bk:].reshape(L, b, nkv, -1, bk, hd)
+                    .transpose(0, 1, 3, 2, 4, 5).reshape(L, -1, nkv, bk, hd))
 
-    k_pool, v_pool = _scatter_blocks(k_pool, v_pool, blks[:, ct:].reshape(-1),
-                                     new_rows(k), new_rows(v))
+        with jax.named_scope("strom.prefill.scatter"):
+            if state is not None:
+                state = dict(state, **{key: tuple(
+                    pool.at[slot].set(new.astype(pool.dtype))
+                    for pool, new in zip(state[key], ssm[key]))
+                    for key in ssm or ()})
+                if moe:
+                    state["moe"] = dict(moe, prefill=load)
+            k_pool, v_pool = _scatter_blocks(
+                k_pool, v_pool, blks[:, ct:].reshape(-1), new_rows(k),
+                new_rows(v))
     return logits, k_pool, v_pool, state
 
 
@@ -281,14 +299,14 @@ def _admit_slots(logits, temp, topp, seed, pos, tok, slots, temps, top_ps,
     past it yet), ``tok`` the token entering the cache on the next step.  A
     row that holds no prompt names a slot past the last: dropped.  Returns
     (the b first tokens, each a device scalar, temp, topp, seed, pos, tok)."""
-    first = _sample_slots(logits, temps, top_ps, seeds, lens - 1)
-
     def put(rows, values):
         return rows.at[slots].set(values.astype(rows.dtype), mode="drop")
 
-    return (tuple(first[i] for i in range(first.shape[0])),
-            put(temp, temps), put(topp, top_ps), put(seed, seeds),
-            put(pos, lens), put(tok, first))
+    with jax.named_scope("strom.head"):
+        first = _sample_slots(logits, temps, top_ps, seeds, lens - 1)
+        return (tuple(first[i] for i in range(first.shape[0])),
+                put(temp, temps), put(topp, top_ps), put(seed, seeds),
+                put(pos, lens), put(tok, first))
 
 
 def paged_logits(params: Dict, cfg: TransformerConfig, tok,
@@ -312,20 +330,25 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     from nvme_strom_tpu.ops.paged_attention import (paged_attention,
                                                     write_rows)
     B = tok.shape[0]
-    free = blk == k_pool.shape[1] - 1
-    # a free slot keeps its last pos over a table row of zeros; nobody
-    # reads its output, so to the kernel its history is one row
-    attn_pos = jnp.where(free, 0, pos)
+    with jax.named_scope("strom.embed"):
+        free = blk == k_pool.shape[1] - 1
+        # a free slot keeps its last pos over a table row of zeros; nobody
+        # reads its output, so to the kernel its history is one row
+        attn_pos = jnp.where(free, 0, pos)
+        live = ~free[:, None] if cfg.expert_layers else None
+        x = embed_tokens(params, cfg, tok[:, None])           # (B,1,d)
+        positions = pos.astype(jnp.float32)[:, None]          # (B,1)
     s_pools, tails = ((list(state["s"]), list(state["conv"]))
                       if cfg.recurrent_layers else ([], []))
-    live = ~free[:, None] if cfg.expert_layers else None
     calls = []              # the expert layers' (counts, work)
-    x = embed_tokens(params, cfg, tok[:, None])               # (B,1,d)
-    positions = pos.astype(jnp.float32)[:, None]              # (B,1)
     ai = mi = ti = 0        # attention / mamba / tail-keeping layers so far
+    # every operation under one family of scopes, the same partition as
+    # the prefill's (``decode.MIXER_SCOPES``, docs/OBSERVABILITY.md)
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
-        h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
+        before, after = _dec.MIXER_SCOPES[cfg.mixer(i)]
+        with jax.named_scope(before):
+            h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
         if cfg.is_mamba_layer(i):
             a, s_pools[mi], tails[ti] = _ssm.mamba_step(
                 h, params, L, cfg, s_pools[mi], tails[ti], sidx)
@@ -345,10 +368,12 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
                     _mla.absorb_q(q[:, 0], params, L, cfg), k_pool, table,
                     attn_pos, layer=ai, dc=cfg.kv_lora_rank)
                 a = _mla.unabsorb(a, params, L, cfg)[:, None]
-            a = a @ wmat(params, L + "wo", a.dtype)
+            with jax.named_scope(after):
+                a = a @ wmat(params, L + "wo", a.dtype)
             ai += 1
         else:
-            q, k, v = qkv_project(h, params, L, cfg, positions=positions)
+            with jax.named_scope(before):
+                q, k, v = qkv_project(h, params, L, cfg, positions=positions)
             with jax.named_scope("strom.attn.paged"):
                 # the new rows go into the (donated) pools where they lie
                 # and the kernel reads layer ai of them in place: nothing
@@ -358,21 +383,27 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
                     layer=ai)
                 a = paged_attention(q, k_pool, v_pool, table, attn_pos,
                                     layer=ai, scale=cfg.attn_scale)
-            a = a.transpose(0, 2, 1, 3).reshape(B, 1, -1)
-            a = a @ wmat(params, L + "wo", a.dtype)
+            with jax.named_scope(after):
+                a = a.transpose(0, 2, 1, 3).reshape(B, 1, -1)
+                a = a @ wmat(params, L + "wo", a.dtype)
             ai += 1
-        x = add_residual(x, a, cfg)
-        h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
+        with jax.named_scope(after):
+            x = add_residual(x, a, cfg)
         with jax.named_scope("strom.mlp"):
+            h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
             f = _mlp_block(h, params, L, cfg, live, calls)
-        x = add_residual(x, f, cfg).astype(cfg.dtype)
-    x = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
+            x = add_residual(x, f, cfg).astype(cfg.dtype)
+    with jax.named_scope("strom.head"):
+        x = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
     if state is not None:
         state = dict(state, s=tuple(s_pools), conv=tuple(tails))
         if calls:
-            state["moe"] = dict(state["moe"], decode=_moe.add_load(
-                state["moe"]["decode"], calls))
-    return lm_logits(params, cfg, x), k_pool, v_pool, state
+            with jax.named_scope("strom.mlp"):
+                state["moe"] = dict(state["moe"], decode=_moe.add_load(
+                    state["moe"]["decode"], calls))
+    with jax.named_scope("strom.head"):
+        logits = lm_logits(params, cfg, x)
+    return logits, k_pool, v_pool, state
 
 
 def init_carried(cfg: TransformerConfig, rows: int):
@@ -403,7 +434,8 @@ def _paged_step(params: Dict, cfg: TransformerConfig, tok,
     ignores their outputs — one compiled program for every batch mix."""
     logits, k_pool, v_pool, state = paged_logits(
         params, cfg, tok, k_pool, v_pool, blk, off, table, pos, state, sidx)
-    nxt = _sample_slots(logits, temps, top_ps, seeds, pos)
+    with jax.named_scope("strom.head"):
+        nxt = _sample_slots(logits, temps, top_ps, seeds, pos)
     return nxt, k_pool, v_pool, state
 
 
@@ -586,7 +618,7 @@ class DecodeServer:
         #: bounded layout, ``models/moe.pair_bound``) — sums over the calls
         self.timings: Dict[str, float] = {
             "admit_s": 0.0, "dispatch_s": 0.0, "readback_s": 0.0,
-            "steps": 0, "readbacks": 0,
+            "steps": 0,
             "admits": 0, "queue_wait_s": 0.0, "prefill_s": 0.0,
             "prefill_tokens": 0, "prompt_tokens": 0,
             "prefill_calls": 0, "prefill_rows_dead": 0,
@@ -1073,7 +1105,7 @@ class DecodeServer:
         self.timings["prefill_programs"] = len(self._prefill_shapes)
         t0 = time.monotonic()
         with self._span("strom.serve.prefill", tokens=b * m, useful=useful,
-                        rows=len(group), program=f"{b}x{m}x{n * bk}",
+                        rows=len(group), program=prefill_program(b, m, n * bk),
                         rid=_rids(group)):
             # positional, so that the state pool is donated with the rest
             recur = () if self.state is None else (self.state, slots)
@@ -1781,7 +1813,6 @@ class DecodeServer:
             raise
         self.timings["readback_s"] += time.monotonic() - t0
         self.timings["steps"] += len(toks)
-        self.timings["readbacks"] += 1
         if moe_h:
             self._note_moe(moe_h, len(toks))
         # replay in generation order: deferred first tokens precede

@@ -138,30 +138,32 @@ def mamba_block(h, p: Dict, L: str, cfg: TransformerConfig, s0=None,
     tail)."""
     b, m, _ = h.shape
     k1 = cfg.ssm_conv - 1
-    z, u, dt = _project_in(h, p, L, cfg)
-    if tail is None:
-        tail = jnp.zeros((b, k1, cfg.ssm_conv_dim), u.dtype)
-    if s0 is None:
-        s0 = jnp.zeros((b, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
-                       jnp.float32)
-    window = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
-    w = p[L + "ssm_conv_w"].astype(jnp.float32)
-    conv = sum(w[j] * window[:, j:j + m].astype(jnp.float32)
-               for j in range(cfg.ssm_conv))
-    conv = conv + p[L + "ssm_conv_b"].astype(jnp.float32)
-    x, bm, cm = _split_conv(jax.nn.silu(conv).astype(u.dtype), cfg)
-    valid = None
-    if n_valid is None:
-        new_tail = window[:, m:]
-    else:
-        valid = valid_rows(n_valid, b, m)
-        new_tail = _tail_at(window, n_valid, k1)
-    a = -jnp.exp(p[L + "ssm_A_log"].astype(jnp.float32))
+    with jax.named_scope("strom.ssm.proj"):
+        z, u, dt = _project_in(h, p, L, cfg)
+        if tail is None:
+            tail = jnp.zeros((b, k1, cfg.ssm_conv_dim), u.dtype)
+        if s0 is None:
+            s0 = jnp.zeros((b, cfg.ssm_heads, cfg.ssm_head_dim,
+                            cfg.ssm_state), jnp.float32)
+        window = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+        w = p[L + "ssm_conv_w"].astype(jnp.float32)
+        conv = sum(w[j] * window[:, j:j + m].astype(jnp.float32)
+                   for j in range(cfg.ssm_conv))
+        conv = conv + p[L + "ssm_conv_b"].astype(jnp.float32)
+        x, bm, cm = _split_conv(jax.nn.silu(conv).astype(u.dtype), cfg)
+        valid = None
+        if n_valid is None:
+            new_tail = window[:, m:]
+        else:
+            valid = valid_rows(n_valid, b, m)
+            new_tail = _tail_at(window, n_valid, k1)
+        a = -jnp.exp(p[L + "ssm_A_log"].astype(jnp.float32))
     with jax.named_scope("strom.ssm.scan"):
         y, s = ssm_scan(x, _delta(dt, p, L), a, bm, cm, s0, valid,
                         chunk=cfg.ssm_chunk)
-    return (_project_out(y.astype(jnp.float32), x, z, p, L, cfg), s,
-            new_tail)
+    with jax.named_scope("strom.ssm.out"):
+        out = _project_out(y.astype(jnp.float32), x, z, p, L, cfg)
+    return out, s, new_tail
 
 
 def mamba_step(h, p: Dict, L: str, cfg: TransformerConfig, s_pool,
@@ -170,18 +172,22 @@ def mamba_step(h, p: Dict, L: str, cfg: TransformerConfig, s_pool,
     pools.  h (B, 1, d); s_pool (rows, H, P, N) float32 and tail_pool
     (rows, K−1, inner + 2N), both updated in place when donated; sidx (B,)
     each slot's row.  Returns (out (B, 1, d), s_pool, tail_pool)."""
-    z, u, dt = _project_in(h[:, 0], p, L, cfg)
-    window = jnp.concatenate([tail_pool[sidx].astype(u.dtype), u[:, None]],
-                             axis=1)                         # (B, K, C)
-    tail_pool = tail_pool.at[sidx].set(window[:, 1:].astype(tail_pool.dtype))
-    w = p[L + "ssm_conv_w"].astype(jnp.float32)
-    conv = (jnp.einsum("bkc,kc->bc", window.astype(jnp.float32), w)
-            + p[L + "ssm_conv_b"].astype(jnp.float32))
-    x, bv, cv = _split_conv(jax.nn.silu(conv).astype(u.dtype), cfg)
-    a = -jnp.exp(p[L + "ssm_A_log"].astype(jnp.float32))
+    with jax.named_scope("strom.ssm.proj"):
+        z, u, dt = _project_in(h[:, 0], p, L, cfg)
+        window = jnp.concatenate(
+            [tail_pool[sidx].astype(u.dtype), u[:, None]], axis=1)  # (B,K,C)
+        tail_pool = tail_pool.at[sidx].set(
+            window[:, 1:].astype(tail_pool.dtype))
+        w = p[L + "ssm_conv_w"].astype(jnp.float32)
+        conv = (jnp.einsum("bkc,kc->bc", window.astype(jnp.float32), w)
+                + p[L + "ssm_conv_b"].astype(jnp.float32))
+        x, bv, cv = _split_conv(jax.nn.silu(conv).astype(u.dtype), cfg)
+        a = -jnp.exp(p[L + "ssm_A_log"].astype(jnp.float32))
     with jax.named_scope("strom.ssm.update"):
         y, s_pool = ssm_update(s_pool, sidx, x, _delta(dt, p, L), a, bv, cv)
-    return _project_out(y, x, z, p, L, cfg)[:, None], s_pool, tail_pool
+    with jax.named_scope("strom.ssm.out"):
+        out = _project_out(y, x, z, p, L, cfg)[:, None]
+    return out, s_pool, tail_pool
 
 
 # ------------------------------------------------- the gated short conv
@@ -238,4 +244,5 @@ def conv_step(h, p: Dict, L: str, cfg: TransformerConfig, tail_pool, sidx):
         conv = jnp.einsum("bkc,kc->bc", window.astype(jnp.float32),
                           p[L + "conv_w"].astype(jnp.float32))
         y = (c * conv.astype(c.dtype)) @ wmat(p, L + "conv_out", c.dtype)
-    return y[:, None], tail_pool
+        y = y[:, None]
+    return y, tail_pool

@@ -13,6 +13,15 @@ Where the directory comes from:
   sub-directory (JAX's key already separates backends), nothing derived
   from a temporary name, a pid or the clock.
 
+The cache's key includes the programs' metadata
+(``jax_compilation_cache_include_metadata_in_key``): the serving programs
+name every operation by ``jax.named_scope``s that the profiler's readers go
+by (docs/OBSERVABILITY.md "Scopes on the device"), and under JAX's default
+key a program fetched from the cache carries the names of whichever commit
+compiled it first — ``m7b.flood``'s ``_paged_prefill``, which holds no
+kernel, came back with its parent's scopes (PERF.md §6, PR 37).  The price:
+an edit that shifts a traced line compiles that program again, once.
+
 ``STROM_NO_COMPILE_CACHE=1`` disables.  A backend whose PJRT client
 cannot serialize executables logs a warning and skips caching, so
 enabling is always safe.
@@ -31,10 +40,11 @@ def enable_compile_cache() -> str | None:
     cache directory, or None when disabled via env."""
     if os.environ.get("STROM_NO_COMPILE_CACHE") == "1":
         return None
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env
-    import jax
     os.makedirs(_DEFAULT_DIR, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", _DEFAULT_DIR)
     return _DEFAULT_DIR

@@ -17,6 +17,10 @@ from nvme_strom_tpu.utils.compile_cache import (cache_entries,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT = os.path.join(REPO, ".jax_cache")
+#: the one setting made wherever the cache lives: a program fetched under a
+#: key that ignores metadata brings the scope names of whoever compiled it
+#: first, and the profiler's readers go by those names
+KEY_ON_METADATA = ("jax_compilation_cache_include_metadata_in_key", True)
 
 
 @pytest.fixture()
@@ -35,7 +39,7 @@ def test_env_var_set_sets_no_directory_in_code(monkeypatch,
                                                config_updates, where):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", where)
     assert enable_compile_cache() == where
-    assert config_updates == []
+    assert config_updates == [KEY_ON_METADATA]      # no directory
     assert not os.path.exists(where)       # JAX creates it, not us
 
 
@@ -43,7 +47,8 @@ def test_unset_uses_fixed_path_inside_checkout(monkeypatch,
                                                config_updates):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     assert enable_compile_cache() == DEFAULT == cc._DEFAULT_DIR
-    assert config_updates == [("jax_compilation_cache_dir", DEFAULT)]
+    assert config_updates == [KEY_ON_METADATA,
+                              ("jax_compilation_cache_dir", DEFAULT)]
     # nothing between the checkout and the entries: no sub-directory
     # named after a backend, a host, a pid or a time
     assert os.path.dirname(DEFAULT) == REPO
