@@ -10,9 +10,11 @@ that passes is not a chip run.
 
 Run as a script on the machine with the chip (``python
 tests/test_chip_compile.py``) it compiles the paged decode step for the
-ATTACHED device and applies the same guard as
-``test_step_moves_nothing_pool_sized``: that run, with the layouts the
-device really gives its arrays, is the one that means something.
+ATTACHED device and applies the same guards as
+``test_step_moves_nothing_pool_sized`` and, at m7b's widths for the step
+and a prefill, ``test_projection_weights_read_in_place``: that run, with
+the layouts the device really gives its arrays, is the one that means
+something.
 """
 
 import functools
@@ -73,6 +75,17 @@ def _compile(fn, *specs, **jit_kw):
     return compiled
 
 
+def _array_ops(hlo_text: str):
+    """(opcode, "type[dims]", elements) of every operation of an HLO module
+    whose result is one array."""
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\w+\[([\d,]+)\])\S* "
+                     r"([\w\-]+)\(", line)
+        if m:
+            yield m.group(3), m.group(1), int(np.prod(
+                [int(n) for n in m.group(2).split(",")]))
+
+
 def pool_sized_ops(hlo_text: str, pool_shape) -> list:
     """Operations of an optimised HLO module whose result has as many
     elements as the K/V pool or as one layer of it, as "opcode shape".
@@ -82,14 +95,52 @@ def pool_sized_ops(hlo_text: str, pool_shape) -> list:
     sizes = {int(np.prod(pool_shape)), int(np.prod(pool_shape[1:]))}
     free = {"parameter", "get-tuple-element", "tuple", "bitcast",
             "custom-call"}
-    found = []
-    for line in hlo_text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\w+\[([\d,]+)\])\S* "
-                     r"([\w\-]+)\(", line)
-        if m and m.group(3) not in free and int(np.prod(
-                [int(n) for n in m.group(2).split(",")])) in sizes:
-            found.append(f"{m.group(3)} {m.group(1)}")
-    return found
+    return [f"{op} {shape}" for op, shape, n in _array_ops(hlo_text)
+            if op not in free and n in sizes]
+
+
+def weight_sized_copies(hlo_text: str, shapes) -> list:
+    """Operations of an optimised HLO module that lay a projection weight
+    out again: a ``copy`` or a ``transpose`` whose result has the element
+    count of one of ``shapes`` (a config's ``wq``, ``wk``, ``wv``), as
+    "opcode shape".  A product that reads the parameter where it lies has
+    none.  An asynchronous ``slice-start`` of a weight is no such
+    operation by itself: the compiler prefetches many a weight in pieces
+    straight into its product (``wo`` and ``w_down`` of lfm2's step), moved
+    once; m7b's four ``bf16[1024,1024]`` pieces of ``wk`` a layer cost what
+    they did because they were joined for a ``copy``, which this finds."""
+    sizes = {int(np.prod(shape)) for shape in shapes}
+    return [f"{op} {shape}" for op, shape, n in _array_ops(hlo_text)
+            if op in ("copy", "transpose") and n in sizes]
+
+
+def _dense_programs(cfg, slots, blocks, sharding=None):
+    """The two serving programs of a plain decoder ``cfg`` over a pool of
+    ``blocks`` + 1 blocks of 128 rows, as lowerings nothing has compiled
+    yet: ({"step": ``_paged_step`` of ``slots`` slots, "prefill":
+    ``_paged_prefill`` of one prompt of 512 rows (the bucket
+    ``1x512x512``)}, the pool's shape, the shapes of one layer's ``wq``,
+    ``wk``, ``wv``)."""
+    from nvme_strom_tpu.models import serving
+    from nvme_strom_tpu.models.transformer import init_params
+    spec = functools.partial(_spec, sharding=sharding)
+    params = {k: spec(v.shape, jnp.bfloat16) for k, v in jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)).items()}
+    bk = 128
+    pool = spec((cfg.n_layers, blocks + 1, cfg.n_kv_heads, bk, cfg.head_dim),
+                jnp.bfloat16)
+    vec = lambda dt, n=slots: spec((n,), dt)                # noqa: E731
+    lowered = {
+        "step": lambda: serving._paged_step.lower(
+            params, cfg, vec(jnp.int32), pool, pool, vec(jnp.int32),
+            vec(jnp.int32), spec((slots, cfg.max_seq // bk), jnp.int32),
+            vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
+            vec(jnp.uint32)),
+        "prefill": lambda: serving._paged_prefill.lower(
+            params, cfg, pool, pool, spec((1, 4 * bk), jnp.int32),
+            spec((1, 4), jnp.int32), vec(jnp.int32, 1))}
+    return lowered, pool.shape, [params["layers.0." + w].shape
+                                 for w in ("wq", "wk", "wv")]
 
 
 def _small_step(hd, sharding=None):
@@ -97,23 +148,11 @@ def _small_step(hd, sharding=None):
     pool of 257 blocks of 128 rows (64 MiB a layer at 128: too large for
     the compiler to stage through the chip's fast memory, as it does with
     a pool of a megabyte), 8 slots: (compiled, pool shape)."""
-    from nvme_strom_tpu.models import serving
-    from nvme_strom_tpu.models.transformer import (TransformerConfig,
-                                                   init_params)
+    from nvme_strom_tpu.models.transformer import TransformerConfig
     cfg = TransformerConfig(vocab=512, d_model=8 * hd, n_layers=2, n_heads=8,
                             n_kv_heads=8, d_ff=512, max_seq=512)
-    spec = functools.partial(_spec, sharding=sharding)
-    params = {k: spec(v.shape, jnp.bfloat16) for k, v in jax.eval_shape(
-        lambda: init_params(jax.random.key(0), cfg)).items()}
-    B, bk = 8, 128
-    pool = spec((cfg.n_layers, 257, cfg.n_kv_heads, bk, hd), jnp.bfloat16)
-    vec = lambda dt: spec((B,), dt)                         # noqa: E731
-    compiled = serving._paged_step.lower(
-        params, cfg, vec(jnp.int32), pool, pool, vec(jnp.int32),
-        vec(jnp.int32), spec((B, cfg.max_seq // bk), jnp.int32),
-        vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
-        vec(jnp.uint32)).compile()
-    return compiled, pool.shape
+    lowered, pool_shape, _ = _dense_programs(cfg, 8, 256, sharding)
+    return lowered["step"]().compile(), pool_shape
 
 
 def _paged(topo, slots=16, layers=24, blocks=256, max_len=4096, nh=NH,
@@ -427,6 +466,48 @@ def test_step_moves_nothing_pool_sized(topo, monkeypatch, hd):
     assert m.alias_size_in_bytes >= 2 * np.prod(pool_shape) * 2, m
 
 
+# the attention widths of the benchmark's dense-attention configs — two
+# layers each, because the compiler treats the first layer's weights apart
+# (they are not prefetched) — under an MLP and a head of the config's own
+# widths: (config, slots, pool blocks)
+PROJECTION_CFGS = {
+    "m7b": (dict(vocab=32768, d_model=4096, n_heads=32, n_kv_heads=8,
+                 d_ff=14336, max_seq=4096, rope_theta=1e6), 16, 256),
+    # granite-4.0-h-micro's attention layers: no positional encoding, a
+    # score scale of its own
+    "g4hm": (dict(vocab=100352, d_model=2048, n_heads=32, n_kv_heads=8,
+                  d_ff=8192, max_seq=1280, rope=False, attn_scale=1 / 64,
+                  tie_embed=True), 64, 640),
+    # lfm2-24b-a2b's: per-head q/k norms before the rotation
+    "lfm2": (dict(vocab=65536, d_model=2048, n_heads=32, n_kv_heads=8,
+                  d_ff=11776, max_seq=1280, rope_theta=1e6, qk_norm=True,
+                  tie_embed=True), 128, 1280)}
+
+
+def _projection_programs(name, sharding=None):
+    from nvme_strom_tpu.models.transformer import TransformerConfig
+    kw, slots, blocks = PROJECTION_CFGS[name]
+    lowered, _, shapes = _dense_programs(
+        TransformerConfig(n_layers=2, **kw), slots, blocks, sharding)
+    return lowered, shapes
+
+
+@pytest.mark.parametrize("name,program", [
+    ("m7b", "step"), ("m7b", "prefill"), ("g4hm", "step"), ("lfm2", "step")])
+def test_projection_weights_read_in_place(topo, monkeypatch, name, program):
+    """``qkv_project``'s three products read ``wq``, ``wk`` and ``wv`` in
+    the layout they are stored in: the serving program compiled for a v5e
+    holds no copy or transpose of a weight's size — before PR 38 ``wq`` was
+    copied into a head-major layout on every decode step and every prefill,
+    and ``wk`` fetched in four square pieces for the same (14 % of
+    ``m7b.flood``'s step)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lowered, shapes = _projection_programs(name, _one(topo))
+    found = weight_sized_copies(lowered[program]().compile().as_text(),
+                                shapes)
+    assert not found, found
+
+
 @pytest.mark.parametrize("width,suffix_blocks,blocks", [
     (1, 4, 4), (1, 1, 4), (2, 1, 1)],
     ids=["no_hit", "prefix_hit", "group_of_two"])
@@ -696,10 +777,18 @@ if __name__ == "__main__":
             "pool": list(shape),
             "pool_sized_ops": pool_sized_ops(step.as_text(), shape),
             "kernels": step.as_text().count("tpu_custom_call")}
+    lowered, shapes = _projection_programs("m7b")
+    for program, lower in lowered.items():
+        found[f"m7b_{program}"] = {"weight_sized_copies": weight_sized_copies(
+            lower().compile().as_text(), shapes)}
     ok = (jax.default_backend() == "tpu"
           and all(not f["pool_sized_ops"] and f["kernels"] == 4
-                  for f in found.values()))
-    print(json.dumps({"guard": "paged step moves nothing pool-sized",
+                  for f in (found["hd128"], found["hd64"]))
+          and not any(found[f"m7b_{program}"]["weight_sized_copies"]
+                      for program in lowered))
+    print(json.dumps({"guard": "paged step moves nothing pool-sized; step "
+                               "and prefill lay no projection weight out "
+                               "again",
                       "platform": jax.default_backend(),
                       "device": jax.devices()[0].device_kind, "ok": ok,
                       **found}))

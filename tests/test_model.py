@@ -386,3 +386,86 @@ def test_chunked_xent_matches_full_path(cfg):
     bad = dataclasses.replace(cfg, xent_chunks=5)
     with pytest.raises(ValueError, match="divide"):
         lf(params, tokens, bad)
+
+
+def _plain_qkv(x, p, prefix, cfg, positions=None):
+    """``qkv_project`` as the plain formula: three products, head split,
+    per-head norms, (b, h, s, d), rotation — what it computed before the
+    products were fenced off from the head split (PR 38), and the
+    reference for it since."""
+    from nvme_strom_tpu.models import transformer as T
+    b, s, _ = x.shape
+    hd = cfg.head_dim
+    q, k, v = ((x @ p[prefix + w]).reshape(b, s, -1, hd)
+               for w in ("wq", "wk", "wv"))
+    q, k = T._qk_norm(q, k, p, prefix, cfg)
+    q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+    if cfg.rope:
+        q, k = T._rope(q, k, cfg.rope_theta, positions=positions,
+                       scaling=cfg.rope_scaling_dict)
+    return q, k, v
+
+
+def _qkv_case(b, s, qk_norm, rope, dtype):
+    import dataclasses
+    cfg = dataclasses.replace(tiny_config(), qk_norm=qk_norm, rope=rope,
+                              dtype=dtype)
+    p = {k: v.astype(dtype) for k, v in
+         init_params(jax.random.key(5), cfg).items()}
+    x = jax.random.normal(jax.random.key(6), (b, s, cfg.d_model), dtype)
+    # a decode step hands every row its own position, a prefill none
+    positions = (jnp.arange(b, dtype=jnp.float32)[:, None] + 3
+                 if s == 1 else None)
+    return cfg, p, x, positions
+
+
+@pytest.mark.parametrize("qk_norm,rope", [(False, True), (True, True),
+                                          (False, False), (True, False)])
+@pytest.mark.parametrize("b,s", [(16, 1), (2, 128)])
+def test_qkv_project_equals_plain_formula(b, s, qk_norm, rope):
+    """The serving form of the projections (products materialised before
+    the head split, so that a TPU reads each weight where it lies) is the
+    plain formula: equal bit for bit in float32 AND in bf16 — a product's
+    result was bf16 before the rotation's float32 upcast already — at a
+    decode step's shape and at a prefill's, under jit as the servers
+    run it."""
+    from nvme_strom_tpu.models.transformer import qkv_project
+    for dtype in (jnp.float32, jnp.bfloat16):
+        cfg, p, x, positions = _qkv_case(b, s, qk_norm, rope, dtype)
+        got = jax.jit(lambda x, p: qkv_project(
+            x, p, "layers.0.", cfg, positions))(x, p)
+        want = jax.jit(lambda x, p: _plain_qkv(
+            x, p, "layers.0.", cfg, positions))(x, p)
+        for name, g, w in zip("qkv", got, want):
+            assert g.shape == w.shape and g.dtype == dtype
+            np.testing.assert_array_equal(
+                np.asarray(g, np.float32), np.asarray(w, np.float32),
+                err_msg=f"{name} {dtype.__name__}")
+
+
+def test_attention_grad_through_fenced_projections(monkeypatch):
+    """``jax.grad`` through ``attention`` with an explicit ``attn_fn`` (the
+    path that takes ``qkv_project``) and ``jax.vmap`` over it: the fence
+    around the products is transparent to both — gradients equal the plain
+    formula's."""
+    from nvme_strom_tpu.models import transformer as T
+    cfg, p, x, _ = _qkv_case(2, 32, True, True, jnp.float32)
+
+    def loss(p, x):
+        out = T.attention(x, p, "layers.0.", cfg,
+                          attn_fn=T.dense_causal_attention)
+        return jnp.sum(out * out)
+
+    g_new, gx_new = jax.grad(loss, argnums=(0, 1))(p, x)
+    per_row = jax.vmap(lambda row: loss(p, row[None]))(x)
+    monkeypatch.setattr(T, "qkv_project", _plain_qkv)
+    g_ref, gx_ref = jax.grad(loss, argnums=(0, 1))(p, x)
+    np.testing.assert_allclose(np.asarray(per_row), np.asarray(
+        jnp.stack([loss(p, row[None]) for row in x])), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(gx_new), np.asarray(gx_ref),
+                               rtol=1e-6, atol=1e-6)
+    for k in ("wq", "wk", "wv", "wo", "q_norm", "k_norm"):
+        np.testing.assert_allclose(
+            np.asarray(g_new["layers.0." + k]),
+            np.asarray(g_ref["layers.0." + k]), rtol=1e-6, atol=1e-6,
+            err_msg=k)
