@@ -87,7 +87,9 @@ def prefill_counts(cfg) -> tuple:
                    * (cfg.qk_nope_dim + cfg.v_head_dim)
                    + cfg.n_heads * cfg.v_head_dim * d)
         else:
-            mix = 2 * d * hd * (cfg.n_heads + cfg.n_kv_heads)
+            # wq and wk at the key width, wv and wo at the value width
+            mix = d * ((hd + cfg.v_dim) * cfg.n_heads
+                       + (hd + cfg.v_dim) * cfg.kv_heads(i))
         read += mix
         rowops += mix
         if cfg.mlp_kind(i) == "dense":

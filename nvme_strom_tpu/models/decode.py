@@ -45,9 +45,17 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Dict:
         shape = shape[:2] + (1, max_len, cfg.latent_width)
     cache = {
         "k": jnp.zeros(shape, cfg.dtype),
-        "v": None if cfg.latent else jnp.zeros(shape, cfg.dtype),
+        "v": None if cfg.latent else jnp.zeros(shape[:-1] + (cfg.v_dim,),
+                                               cfg.dtype),
         "pos": jnp.zeros((), jnp.int32),
     }
+    if cfg.window_layers:
+        # a window layer's K/V apart (its own KV-head count), dense like the
+        # rest here: only a server bounds them to a ring (models/serving.py)
+        wshape = (len(cfg.window_layers), batch,
+                  cfg.kv_heads(cfg.window_layers[0]), max_len)
+        cache["wk"] = jnp.zeros(wshape + (cfg.head_dim,), cfg.dtype)
+        cache["wv"] = jnp.zeros(wshape + (cfg.v_dim,), cfg.dtype)
     if cfg.recurrent_layers:
         # K/V for the attention layers only; the recurrent layers carry a
         # fixed-size state instead (models/ssm.py)
@@ -64,6 +72,11 @@ def cache_shardings(mesh, tp_axis: str = "tp", dp_axis: str = "dp"):
         P(None, dp_axis, tp_axis, None, None), mesh))
     return {"k": kv, "v": kv,
             "pos": NamedSharding(mesh, prune_spec(P(), mesh))}
+
+
+#: a dense MLP over more rows than this (one prompt's: the programs the
+#: accepted cells compile hold at most 8,192) runs in chunks of rows
+DENSE_MLP_ROWS, DENSE_MLP_CHUNK = 8192, 4096
 
 
 def mlp_block(h, p, L, cfg, valid=None, calls=None):
@@ -92,6 +105,13 @@ def mlp_block(h, p, L, cfg, valid=None, calls=None):
                 "expert width names its expert layers in mlp_kinds")
         out, _ = _moe.moe_mlp(h, p, L, cfg)
         return out
+    b, m, d = h.shape
+    if m > DENSE_MLP_ROWS and m % DENSE_MLP_CHUNK == 0:
+        # a prompt this long walks the MLP in chunks of rows: its float32
+        # gate alone is m x d_ff x 4 bytes (1 GiB at 16,384 x 16,384)
+        chunks = h.reshape(b, m // DENSE_MLP_CHUNK, DENSE_MLP_CHUNK, d)
+        return lax.map(lambda c: mlp(c, p, L),
+                       chunks.swapaxes(0, 1)).swapaxes(0, 1).reshape(b, m, d)
     return mlp(h, p, L)
 
 
@@ -111,7 +131,8 @@ def prefill(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     them.
     """
     b, s = tokens.shape
-    if cfg.recurrent_layers or cfg.expert_layers or cfg.latent:
+    if (cfg.recurrent_layers or cfg.expert_layers or cfg.latent
+            or cfg.stated_kv):
         # block_step from an empty cache IS the prefill, state included
         # (and it is what tells an expert layer which rows are padding)
         return block_step(params, tokens, cfg, cache,
@@ -191,6 +212,7 @@ def decode_step(params: Dict, token: jax.Array, cfg: TransformerConfig,
 #: (docs/OBSERVABILITY.md has the table).  A scope is metadata on the lowered
 #: program and costs nothing at run time.
 MIXER_SCOPES = {"attention": ("strom.attn.proj", "strom.attn.out"),
+                "window": ("strom.attn.proj", "strom.attn.out"),
                 "mamba": ("strom.ssm.proj", "strom.ssm.out"),
                 "conv": ("strom.conv", "strom.conv")}
 
@@ -216,6 +238,75 @@ def cache_attention(q, ck, cv, limit, cfg: TransformerConfig):
     scores = jnp.where(valid, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(cve.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, cve)
+
+
+#: a block of more rows than this (one prompt's: the accepted cells' hold at
+#: most 8,192) goes through a stated-geometry layer's attention in chunks of
+#: query rows: q alone is m x heads x 192 values in three layouts on its way
+#: to the kernel (1.25 GiB at 16,384 rows)
+ATTN_ROWS, ATTN_CHUNK = 8192, 4096
+
+
+def ring_rows(dense, n, rows: int):
+    """What a window layer's ring keeps of a sequence: ``dense`` (b, nkv, S,
+    d) at positions 0 .. S - 1 and ``n`` (b,) each sequence's length -> (b,
+    nkv, rows, d), ring row r the LAST position <= n - 1 that is r modulo
+    ``rows``.  Only the last ``window`` of them are ever read; what lies
+    below position 0 (a prompt shorter than the ring) is some row of the
+    prompt, masked by the kernel's lower bound."""
+    r = jnp.arange(rows)
+    at = (n[:, None] - 1) - ((n[:, None] - 1 - r[None]) % rows)
+    return jnp.take_along_axis(
+        dense, jnp.clip(at, 0, dense.shape[2] - 1)[:, None, :, None], axis=2)
+
+
+def _blocked_attention(h, params, L, cfg, k_l, v_l, pos, win):
+    """One stated-geometry attention layer of ``block_step``: h (b, m, d),
+    already normed, -> (the layer's output (b, m, d), W_o applied, and the
+    layer's two caches (b, kv_heads, S, hd | vd) with the block's rows at
+    ``pos ..``).  The attention is the blocked kernel
+    (ops/kv_prefill.py): no (heads, m, S) score tensor, a band and the sink
+    for a window layer (``win``).  A long block runs as a scan over chunks
+    of its rows — each chunk's K and V go into the cache, then its queries
+    attend over the cache up to their own rows: the kernel takes the first
+    query row's position as data, so a chunk is a block behind a prefix."""
+    from nvme_strom_tpu.ops.kv_prefill import kv_prefill_attention
+    b, m, d = h.shape
+    before, after = MIXER_SCOPES["attention"]
+    scale = cfg.head_dim ** -0.5 if cfg.attn_scale is None else cfg.attn_scale
+
+    def chunk(carry, hc_first):
+        k_l, v_l = carry
+        hc, first = hc_first
+        rows = hc.shape[1]
+        with jax.named_scope(before):
+            q, k, v = qkv_project(
+                hc, params, L, cfg, positions=first.astype(jnp.float32)
+                + jnp.arange(rows, dtype=jnp.float32))
+        with jax.named_scope("strom.attn.window" if win
+                             else "strom.attn.paged"):
+            k_l = lax.dynamic_update_slice(k_l, k.astype(cfg.dtype),
+                                           (0, 0, first, 0))
+            v_l = lax.dynamic_update_slice(v_l, v.astype(cfg.dtype),
+                                           (0, 0, first, 0))
+            a = kv_prefill_attention(
+                q, k_l, v_l, first, scale=scale,
+                window=cfg.window if win else 0, sink=params.get(L + "sink"))
+        with jax.named_scope(after):
+            a = a.transpose(0, 2, 1, 3).reshape(b, rows, -1)
+            a = a @ wmat(params, L + "wo", a.dtype)
+        return (k_l, v_l), a
+
+    carry = (k_l, v_l)
+    if m > ATTN_ROWS and m % ATTN_CHUNK == 0:
+        n = m // ATTN_CHUNK
+        carry, a = lax.scan(chunk, carry, (
+            h.reshape(b, n, ATTN_CHUNK, d).swapaxes(0, 1),
+            pos + ATTN_CHUNK * jnp.arange(n, dtype=jnp.int32)))
+        a = a.swapaxes(0, 1).reshape(b, m, d)
+    else:
+        carry, a = chunk(carry, (h, pos))
+    return (a,) + carry
 
 
 def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
@@ -258,7 +349,11 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     ssm = cache.get("ssm")
     states, tails = (list(ssm["s"]), list(ssm["conv"])) if ssm else ([], [])
     calls = []            # the expert layers' (counts, work)
-    ai = mi = ti = 0      # this layer's place among its kind's caches
+    ai = mi = ti = wi = 0     # this layer's place among its kind's caches
+    # ``cache["ring_rows"]`` (a server's prefill from an EMPTY cache): the
+    # window layers keep no dense cache, and ``cache["wk"]`` / ``["wv"]``
+    # come back as what their rings hold, (Lw, b, kv_heads, ring_rows, d)
+    ring, rings = cache.get("ring_rows"), []
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
         before, after = MIXER_SCOPES[cfg.mixer(i)]
@@ -287,6 +382,37 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
             with jax.named_scope(after):
                 a = a @ wmat(params, L + "wo", a.dtype)
             ai += 1
+        elif cfg.stated_kv:
+            # a window layer's cache, scope and place apart from a full one's
+            win = cfg.mixer(i) == "window"
+            ck, cv, at = ("wk", "wv", wi) if win else ("k", "v", ai)
+            scope = "strom.attn.window" if win else "strom.attn.paged"
+            if win and ring:
+                # a prompt from an empty cache whose caller keeps rings: the
+                # layer's K and V live for this layer only, and what its
+                # ring holds of them is all that leaves
+                with jax.named_scope(scope):
+                    shape = (b, cfg.kv_heads(i), m)
+                    k_l = jnp.zeros(shape + (cfg.head_dim,), cfg.dtype)
+                    v_l = jnp.zeros(shape + (cfg.v_dim,), cfg.dtype)
+                a, k_l, v_l = _blocked_attention(h, params, L, cfg, k_l, v_l,
+                                                 pos, win)
+                with jax.named_scope(scope):
+                    n = jnp.broadcast_to(jnp.asarray(
+                        m if n_valid is None else n_valid, jnp.int32), (b,))
+                    rings.append((ring_rows(k_l, n, ring),
+                                  ring_rows(v_l, n, ring)))
+            else:
+                with jax.named_scope(scope):
+                    k_l, v_l = cache[ck][at], cache[cv][at]
+                a, k_l, v_l = _blocked_attention(h, params, L, cfg, k_l, v_l,
+                                                 pos, win)
+                with jax.named_scope(scope):
+                    cache[ck] = lax.dynamic_update_slice(
+                        cache[ck], k_l[None], (at, 0, 0, 0, 0))
+                    cache[cv] = lax.dynamic_update_slice(
+                        cache[cv], v_l[None], (at, 0, 0, 0, 0))
+            wi, ai = wi + win, ai + (not win)
         else:
             with jax.named_scope(before):
                 q, k, v = qkv_project(h, params, L, cfg, positions=positions)
@@ -313,6 +439,9 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
         cache["pos"] = pos + m
     if ssm:
         cache["ssm"] = {"s": tuple(states), "conv": tuple(tails)}
+    if rings:
+        with jax.named_scope("strom.attn.window"):
+            cache["wk"], cache["wv"] = (jnp.stack(r) for r in zip(*rings))
     if "moe" in cache and calls:
         with jax.named_scope("strom.mlp"):
             cache["moe"] = _moe.add_load(cache["moe"], calls)

@@ -35,6 +35,14 @@ trash block and the sacrificial state row;
 ``timings["prefill_programs"]`` counts the shapes a server has used.
 The call returns at dispatch: the logits stay on the device.
 
+A config may keep more than K/V pages per sequence, and the same two
+programs carry it, donated (``init_carried``): the recurrent layers' state
+rows, the expert layers' load counters, and for WINDOW layers (a row sees
+its last ``window`` rows only) a ring of ``ring_blocks`` blocks per slot —
+written whole by the slot's prefill from the prompt's last rows, in place by
+the step, never a block of the pool: the allocator hands out the full
+layers' pages alone.
+
 Per-request decoding params: ``max_new``, ``eos_id``, and sampling —
 ``temperature``/``top_p``/``seed`` are per-SLOT vectors (data, like the
 positions), so one compiled step serves any greedy/sampled mix.
@@ -178,7 +186,7 @@ def _rids(group: list) -> str:
 
 
 def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
-                  k_head, v_head, last, ssm=None, moe=None):
+                  k_head, v_head, last, ssm=None, moe=None, ring_rows=0):
     """The admission prefill, traced inside ``_paged_prefill``:
     ``block_step`` of the right-padded suffixes ``tokens`` (b, m) behind
     the cached prefixes ``k_head``/``v_head`` ((L, b, nkv, c, hd), or None
@@ -187,8 +195,10 @@ def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
 
     Returns (logits (b, vocab) f32 at each row's own suffix row ``last``
     (b,), k, v dense (L, b, nkv, c + m, hd), for a config with recurrent
-    layers their state after each row's ``last`` — else None —, and the
-    expert layers' load counters ``moe`` with the group's valid rows added).
+    layers their state after each row's ``last`` — else None —, the
+    expert layers' load counters ``moe`` with the group's valid rows added,
+    and what the window layers' rings keep of their K and V (Lw, b, nkv_w,
+    ring rows, hd | vd) (``decode.ring_rows``), None without such layers).
     The pad rows sit past ``last``: causality keeps them out of the logits,
     and their cache entries are dead — decode overwrites a position before
     its mask exposes it.  A recurrence has no mask to hide behind:
@@ -197,6 +207,12 @@ def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
     b, m = tokens.shape
     with jax.named_scope("strom.prefill.gather"):
         cache = _dec.init_cache(cfg, b, m)
+        if cfg.window_layers:
+            # no prefix (``_req_keys``): the window layers hand back their
+            # rings' rows and keep no dense cache over the prompt
+            assert k_head is None
+            del cache["wk"], cache["wv"]
+            cache["ring_rows"] = ring_rows
         if ssm is not None:
             cache["ssm"] = ssm
         if moe is not None:
@@ -212,7 +228,16 @@ def _prefill_rows(params: Dict, cfg: TransformerConfig, tokens,
     logits, cache = _dec.block_step(params, tokens, cfg, cache, last=last,
                                     n_valid=n_valid)
     return (logits, cache["k"], cache["v"], cache.get("ssm"),
-            cache.get("moe"))
+            cache.get("moe"), (cache.get("wk"), cache.get("wv")))
+
+
+def ring_blocks(cfg: TransformerConfig, block_len: int) -> int:
+    """Blocks of ``block_len`` rows in a window layer's ring: the rows
+    ``pos - window + 1 .. pos`` span at most ``ceil(window / block_len) + 1``
+    blocks wherever ``pos`` lies in its own (two of 128 for a window of
+    128).  Row ``p`` of a sequence lies in its ring's block ``(p //
+    block_len) % ring_blocks`` at offset ``p % block_len``."""
+    return -(-cfg.window // block_len) + 1 if cfg.window_layers else 0
 
 
 def prefill_program(width: int, suffix: int, cache: int) -> str:
@@ -260,9 +285,10 @@ def _paged_prefill(params: Dict, cfg: TransformerConfig, k_pool, v_pool,
                 k_head, v_head = _gather_prefix(k_pool, v_pool, blks[:, :ct])
             ssm0 = _ssm.init_state(cfg, b) if cfg.recurrent_layers else None
         moe = state.get("moe") if state else None
-        logits, k, v, ssm, load = _prefill_rows(
+        ring = ring_blocks(cfg, bk)
+        logits, k, v, ssm, load, wkv = _prefill_rows(
             params, cfg, tokens, k_head, v_head, last, ssm0,
-            moe and moe["prefill"])
+            moe and moe["prefill"], ring * bk)
 
         def new_rows(dense):               # → (L, b * (n - ct), nkv, bk, hd)
             if dense is None:
@@ -282,6 +308,17 @@ def _paged_prefill(params: Dict, cfg: TransformerConfig, k_pool, v_pool,
                     for key in ssm or ()})
                 if moe:
                     state["moe"] = dict(moe, prefill=load)
+                if cfg.window_layers:
+                    # a window layer keeps the prompt's LAST rows only, in
+                    # the admitted slot's ring (never a block of the pool)
+                    ids = (slot[:, None] * ring
+                           + jnp.arange(ring)).reshape(-1)
+                    for key, rows in zip(("wk", "wv"), wkv):
+                        Lw, _, nkv, _, d = rows.shape   # -> blocks of rings
+                        state[key] = state[key].at[:, ids].set(
+                            rows.reshape(Lw, b, nkv, ring, bk, d)
+                            .transpose(0, 1, 3, 2, 4, 5)
+                            .reshape(Lw, b * ring, nkv, bk, d))
             k_pool, v_pool = _scatter_blocks(
                 k_pool, v_pool, blks[:, ct:].reshape(-1), new_rows(k),
                 new_rows(v))
@@ -330,6 +367,8 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     from nvme_strom_tpu.ops.paged_attention import (paged_attention,
                                                     write_rows)
     B = tok.shape[0]
+    bk = k_pool.shape[-1] if cfg.latent else k_pool.shape[3]
+    ring = ring_blocks(cfg, bk)
     with jax.named_scope("strom.embed"):
         free = blk == k_pool.shape[1] - 1
         # a free slot keeps its last pos over a table row of zeros; nobody
@@ -338,10 +377,17 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
         live = ~free[:, None] if cfg.expert_layers else None
         x = embed_tokens(params, cfg, tok[:, None])           # (B,1,d)
         positions = pos.astype(jnp.float32)[:, None]          # (B,1)
+        if ring:
+            # a window layer's cache: the slot's own ring of ``ring`` blocks
+            # (row sidx of the rings; a free slot's is the sacrificial last),
+            # written at the block the position falls in
+            wk_pool, wv_pool = state["wk"], state["wv"]
+            ring_table = sidx[:, None] * ring + jnp.arange(ring)
+            ring_blk = sidx * ring + (pos // bk) % ring
     s_pools, tails = ((list(state["s"]), list(state["conv"]))
                       if cfg.recurrent_layers else ([], []))
     calls = []              # the expert layers' (counts, work)
-    ai = mi = ti = 0        # attention / mamba / tail-keeping layers so far
+    ai = mi = ti = wi = 0   # attention / mamba / tail-keeping / window layers
     # every operation under one family of scopes, the same partition as
     # the prefill's (``decode.MIXER_SCOPES``, docs/OBSERVABILITY.md)
     for i in range(cfg.n_layers):
@@ -372,21 +418,34 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
                 a = a @ wmat(params, L + "wo", a.dtype)
             ai += 1
         else:
+            win = cfg.mixer(i) == "window"
             with jax.named_scope(before):
                 q, k, v = qkv_project(h, params, L, cfg, positions=positions)
-            with jax.named_scope("strom.attn.paged"):
+            with jax.named_scope("strom.attn.window" if win
+                                 else "strom.attn.paged"):
                 # the new rows go into the (donated) pools where they lie
                 # and the kernel reads layer ai of them in place: nothing
-                # pool-sized moves
-                k_pool, v_pool = write_rows(
-                    k_pool, v_pool, k[:, :, 0], v[:, :, 0], blk, off,
-                    layer=ai)
-                a = paged_attention(q, k_pool, v_pool, table, attn_pos,
-                                    layer=ai, scale=cfg.attn_scale)
+                # pool-sized moves.  A window layer's go into its slot's
+                # ring, of which the kernel walks the blocks that hold the
+                # last ``window`` rows: a lower bound beside the upper one
+                if win:
+                    wk_pool, wv_pool = write_rows(
+                        wk_pool, wv_pool, k[:, :, 0], v[:, :, 0], ring_blk,
+                        off, layer=wi, name="strom_window_write")
+                    a = paged_attention(
+                        q, wk_pool, wv_pool, ring_table, attn_pos, layer=wi,
+                        scale=cfg.attn_scale, window=cfg.window,
+                        sink=params.get(L + "sink"))
+                else:
+                    k_pool, v_pool = write_rows(
+                        k_pool, v_pool, k[:, :, 0], v[:, :, 0], blk, off,
+                        layer=ai)
+                    a = paged_attention(q, k_pool, v_pool, table, attn_pos,
+                                        layer=ai, scale=cfg.attn_scale)
             with jax.named_scope(after):
                 a = a.transpose(0, 2, 1, 3).reshape(B, 1, -1)
                 a = a @ wmat(params, L + "wo", a.dtype)
-            ai += 1
+            wi, ai = wi + win, ai + (not win)
         with jax.named_scope(after):
             x = add_residual(x, a, cfg)
         with jax.named_scope("strom.mlp"):
@@ -397,6 +456,8 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
         x = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
     if state is not None:
         state = dict(state, s=tuple(s_pools), conv=tuple(tails))
+        if ring:
+            state.update(wk=wk_pool, wv=wv_pool)
         if calls:
             with jax.named_scope("strom.mlp"):
                 state["moe"] = dict(state["moe"], decode=_moe.add_load(
@@ -406,19 +467,31 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     return logits, k_pool, v_pool, state
 
 
-def init_carried(cfg: TransformerConfig, rows: int):
+def init_carried(cfg: TransformerConfig, rows: int, block_len: int = 128):
     """What the server's two programs carry on the device beside the K/V
     pools, donated and updated in place: the recurrent layers' pools of
-    ``rows`` rows (``models/ssm.init_state``: ``"s"``, ``"conv"``) and, for
-    a config with expert layers, their load counters ``"moe"``
-    (``models/moe.load_counters``), ``"decode"`` and ``"prefill"`` apart.
+    ``rows`` rows (``models/ssm.init_state``: ``"s"``, ``"conv"``), for
+    a config with expert layers their load counters ``"moe"``
+    (``models/moe.load_counters``), ``"decode"`` and ``"prefill"`` apart,
+    and for one with window layers their rings ``"wk"`` / ``"wv"``: (window
+    layers, rows x ``ring_blocks``, window KV heads, block_len, head width)
+    — row r's ring is blocks ``r * ring_blocks ..``, shaped as a pool so
+    that the pool's row writer and kernel serve it.  A per-slot array and
+    not entries of the block table: a ring's blocks never change hands, so
+    there is nothing for an allocator to decide, and a request's 136 table
+    entries would say the same two ids 68 times over.
     None for a plain decoder."""
-    if not (cfg.recurrent_layers or cfg.expert_layers):
+    if not (cfg.recurrent_layers or cfg.expert_layers or cfg.window_layers):
         return None
     state = _ssm.init_state(cfg, rows)
     if cfg.expert_layers:
         state["moe"] = {"decode": _moe.load_counters(cfg),
                         "prefill": _moe.load_counters(cfg)}
+    if cfg.window_layers:
+        shape = (len(cfg.window_layers), rows * ring_blocks(cfg, block_len),
+                 cfg.kv_heads(cfg.window_layers[0]), block_len)
+        state["wk"] = jnp.zeros(shape + (cfg.head_dim,), cfg.dtype)
+        state["wv"] = jnp.zeros(shape + (cfg.v_dim,), cfg.dtype)
     return state
 
 
@@ -520,17 +593,18 @@ class DecodeServer:
         self.max_len = max_len
         if kv_store is not None:
             cfg.require_kv_pages("a kv_store (PrefixStore)")
-        if cfg.recurrent_layers:
+        if cfg.recurrent_layers and kv_store is not None:
             # pages without the state at their boundary are not a prefix,
             # in the store as in the HBM prefix cache (_req_keys)
-            if kv_store is not None:
-                cfg.require_no_recurrent("a kv_store (PrefixStore)")
+            cfg.require_no_recurrent("a kv_store (PrefixStore)")
+        if cfg.recurrent_layers or cfg.stated_kv:
             shardings = {getattr(w, "sharding", None)
                          for w in (params or {}).values()
                          if not isinstance(w, dict)}
             if any(len(getattr(sh, "device_set", ())) > 1
                    for sh in shardings):
-                cfg.require_no_recurrent("a mesh (sharded params)")
+                (cfg.require_no_recurrent if cfg.recurrent_layers
+                 else cfg.require_kv_pages)("a mesh (sharded params)")
         #: load-shedding probe (docs/RESILIENCE.md "failure domains"):
         #: a callable returning True while new prefill admissions should
         #: DEFER (requests wait queued; in-flight decode continues;
@@ -624,6 +698,7 @@ class DecodeServer:
             "prefill_calls": 0, "prefill_rows_dead": 0,
             "prefill_programs": 0, "scan_tokens": 0,
             "attn_blocks_live": 0, "attn_blocks_table": 0,
+            "window_rows_live": 0,
             **{key + sfx: 0 for sfx in ("", "_prefill") for key in (
                 "moe_calls", "moe_pairs", "moe_pairs_routed",
                 "moe_rows_computed", "moe_experts_touched",
@@ -667,15 +742,19 @@ class DecodeServer:
             # (ops/mla_attention.py), and no second pool
             shape = shape[:2] + (cfg.latent_width, self.block_len)
         self.k_pool = jnp.zeros(shape, cfg.dtype)
-        self.v_pool = None if cfg.latent else jnp.zeros(shape, cfg.dtype)
+        self.v_pool = None if cfg.latent else jnp.zeros(
+            shape[:-1] + (cfg.v_dim,), cfg.dtype)
         self._trash = self.total_blocks
         # the second kind of cache: per recurrent layer what its mixer
         # declares per sequence (a Mamba-2 state and conv tail, a short
         # conv's tail) for every slot, +1 sacrificial row that free slots
         # step into (row B, as their K/V goes to the trash block) — and
         # with it the expert layers' load counters, carried by the same
-        # two programs.  None for a plain decoder.
-        self.state = init_carried(cfg, self.B + 1)
+        # two programs — and the THIRD kind: a window layer's ring of its
+        # last rows per slot (``init_carried``), which holds no block of the
+        # pool above, is never on the free list and is overwritten whole by
+        # its slot's next prefill.  None for a plain decoder.
+        self.state = init_carried(cfg, self.B + 1, self.block_len)
         #: host copy of the device's load counters at the last readback
         #: ({"decode" | "prefill": {"load", "sums"}} as uint32: a counter
         #: may wrap, a difference of two readings does not)
@@ -738,10 +817,13 @@ class DecodeServer:
         """The request's chain keys, hashed ONCE — _can_admit runs per
         step while a request queues, and per-wait rehashing of a long
         prompt is O(prompt) host work on the decode path."""
-        if not self.prefix_cache or self.cfg.recurrent_layers:
+        if (not self.prefix_cache or self.cfg.recurrent_layers
+                or self.cfg.window_layers):
             # recurrent layers: a cached page is worthless without the
             # state at its boundary, which nobody keeps — no keys, so no
-            # match (_pc_match) and nothing registered
+            # match (_pc_match) and nothing registered.  Window layers
+            # likewise: a prefix would need their last ``window`` rows at
+            # its boundary, and a ring keeps only its slot's newest
             return []
         if req.chain_keys is None:
             req.chain_keys = self._chain_keys(req.prompt)
@@ -1377,6 +1459,17 @@ class DecodeServer:
         out["latent_bytes_per_token"] = (
             pool.shape[0] * pool.shape[2] * pool.dtype.itemsize
             if self.cfg.latent else 0)
+        # K and V of the layers that keep pages (a token's bytes in the
+        # pool), and a slot's rings of the window layers (bytes that do not
+        # grow with the context)
+        out["kv_bytes_per_token"] = 0 if self.cfg.latent else sum(
+            a.nbytes // (a.shape[1] * a.shape[3])
+            for a in (self.k_pool, self.v_pool))
+        out["window_layers"] = len(self.cfg.window_layers)
+        out["window_bytes_per_slot"] = sum(
+            self.state[key].nbytes // (self.B + 1)
+            for key in ("wk", "wv")) if self.cfg.window_layers else 0
+        out["window_rows_live"] = self.timings["window_rows_live"]
         if self.tenant_sheds:     # key appears only once tenancy acted
             out["tenant_sheds"] = dict(self.tenant_sheds)
         if self._draining:        # and these only once a drain began
@@ -1644,6 +1737,10 @@ class DecodeServer:
             self._pos_h[b] // self.block_len + 1
             for b in range(self.B) if self.slots[b] is not None)
         self.timings["attn_blocks_table"] += self.B * self.max_blocks
+        if self.cfg.window_layers:
+            self.timings["window_rows_live"] += sum(
+                min(self._pos_h[b] + 1, self.cfg.window)
+                for b in range(self.B) if self.slots[b] is not None)
         # an expert layer routes every slot that holds blocks (a free one
         # is told by its trash block)
         self.timings["moe_pairs_routed"] += (

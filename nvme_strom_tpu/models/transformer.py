@@ -67,7 +67,8 @@ class TransformerConfig:
     xent_chunks: int = 0
     # The per-layer description, read off the model's own config
     # (tools/convert_llama.config_from_hf): the mixer of every layer
-    # ("attention", "mamba" or "conv": models/ssm.py) and its MLP
+    # ("attention", "window" — below —, "mamba" or "conv": models/ssm.py)
+    # and its MLP
     # ("dense" at d_ff, or "experts" at d_expert: the exact expert layer
     # of models/moe.py).  Empty layer_kinds == attention in every layer;
     # empty mlp_kinds == dense everywhere, or, with n_experts > 0, the
@@ -121,6 +122,25 @@ class TransformerConfig:
     experts_held: int = 0
     expert_offset: int = 0
     d_shared: int = 0
+    # K/V attention whose geometry the config STATES (all 0 / 1 == absent):
+    # ``qk_head_dim`` the width of a query and key head (0: d_model //
+    # n_heads), ``v_head_dim`` above that of a value head (0: as wide as a
+    # key's); rotary on the first ``rotary_dim`` features of a head only (0:
+    # all of them); values multiplied by ``value_scale`` as they are
+    # projected.  A "window" entry of ``layer_kinds`` is an attention layer
+    # whose row i sees the ``window`` keys i - window < j <= i: it has
+    # ``window_kv_heads`` KV heads (0: n_kv_heads) and rotates at
+    # ``window_rope_theta`` (0: rope_theta); with ``window_sink`` a learned
+    # scalar per query head (leaf "sink") joins its softmax as one more
+    # column that carries no value.  Such a layer keeps a ring of its last
+    # rows per sequence, not pages (models/serving.py).
+    qk_head_dim: int = 0
+    rotary_dim: int = 0
+    value_scale: float = 1.0
+    window: int = 0
+    window_kv_heads: int = 0
+    window_rope_theta: float = 0.0
+    window_sink: bool = False
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -131,10 +151,14 @@ class TransformerConfig:
         object.__setattr__(self, "layer_kinds", kinds)
         if kinds:
             if len(kinds) != self.n_layers or set(kinds) - {
-                    "attention", "mamba", "conv"}:
+                    "attention", "window", "mamba", "conv"}:
                 raise ValueError(
-                    f"layer_kinds must name 'attention', 'mamba' or 'conv' "
-                    f"for each of the {self.n_layers} layers, got {kinds}")
+                    f"layer_kinds must name 'attention', 'window', 'mamba' "
+                    f"or 'conv' for each of the {self.n_layers} layers, got "
+                    f"{kinds}")
+            if "window" in kinds and (self.window < 1 or self.latent):
+                raise ValueError("window layers need window >= 1 and K/V "
+                                 "attention (no kv_lora_rank)")
             if "mamba" in kinds and not (self.ssm_heads and self.ssm_head_dim
                                          and self.ssm_state):
                 raise ValueError("mamba layers need ssm_heads, ssm_head_dim "
@@ -176,11 +200,43 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.qk_head_dim or self.d_model // self.n_heads
+
+    @property
+    def v_dim(self) -> int:
+        """Width of a value head of K/V attention."""
+        return (0 if self.latent else self.v_head_dim) or self.head_dim
 
     def mixer(self, i: int) -> str:
-        """Layer ``i``'s mixer: "attention", "mamba" or "conv"."""
+        """Layer ``i``'s mixer: "attention", "window", "mamba" or "conv"."""
         return self.layer_kinds[i] if self.layer_kinds else "attention"
+
+    def kv_heads(self, i: int) -> int:
+        """KV heads of attention layer ``i``."""
+        return (self.window_kv_heads if self.mixer(i) == "window"
+                else 0) or self.n_kv_heads
+
+    def theta(self, i: int) -> float:
+        """Rotary base of attention layer ``i``."""
+        return (self.window_rope_theta if self.mixer(i) == "window"
+                else 0.0) or self.rope_theta
+
+    @property
+    def window_layers(self) -> tuple:
+        """Indices of the window layers, in layer order: what a server's
+        rings hold a layer of each (``window_layers.index(i)``)."""
+        return tuple(i for i, k in enumerate(self.layer_kinds)
+                     if k == "window")
+
+    @property
+    def stated_kv(self) -> bool:
+        """Whether K/V attention's geometry is the config's own — stated
+        head widths, partial rotary, a value scale or window layers: such a
+        config attends through the blocked kernels (ops/kv_prefill.py) and
+        is served by ``DecodeServer`` alone."""
+        return bool(self.qk_head_dim or self.rotary_dim or self.window_layers
+                    or self.value_scale != 1.0
+                    or (self.v_head_dim and not self.latent))
 
     def mlp_kind(self, i: int) -> str:
         """Layer ``i``'s MLP: "dense", "experts" (the exact expert layer)
@@ -256,6 +312,15 @@ class TransformerConfig:
                 f"store format yet: serve this config from DecodeServer on "
                 f"one device, without a kv_store, a mesh or session "
                 f"hand-off")
+        if self.stated_kv:
+            raise NotImplementedError(
+                f"{what} holds K and V pages of one head width for every "
+                f"layer; this config states its own attention geometry "
+                f"(head widths {self.head_dim}/{self.v_dim}, "
+                f"{len(self.window_layers)} window layers that keep a ring "
+                f"of their last {self.window} rows, not pages): serve it "
+                f"from DecodeServer on one device, without a kv_store, a "
+                f"mesh, session hand-off or a training step")
 
     @property
     def ssm_inner(self) -> int:
@@ -305,8 +370,9 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
     """Parameters as a flat {name: array} dict — the same namespace the
     safetensors lazy loader uses, so checkpoints round-trip by name."""
     keys = iter(jax.random.split(
-        rng, 4 + (16 if cfg.latent or cfg.d_shared else 13) * cfg.n_layers))
-    hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+        rng, 4 + (16 if cfg.latent or cfg.d_shared or cfg.window_layers
+                  else 13) * cfg.n_layers))
+    hd, vd, nh = cfg.head_dim, cfg.v_dim, cfg.n_heads
     dense = dense_init
 
     p = {
@@ -332,13 +398,16 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
             if cfg.qk_norm:
                 p[L + "q_norm"] = jnp.ones((hd,), jnp.float32)
                 p[L + "k_norm"] = jnp.ones((hd,), jnp.float32)
+            nkv = cfg.kv_heads(i)
             p[L + "wq"] = dense(next(keys), cfg.d_model,
                                 (cfg.d_model, nh * hd))
             p[L + "wk"] = dense(next(keys), cfg.d_model,
                                 (cfg.d_model, nkv * hd))
             p[L + "wv"] = dense(next(keys), cfg.d_model,
-                                (cfg.d_model, nkv * hd))
-            p[L + "wo"] = dense(next(keys), nh * hd, (nh * hd, cfg.d_model))
+                                (cfg.d_model, nkv * vd))
+            p[L + "wo"] = dense(next(keys), nh * vd, (nh * vd, cfg.d_model))
+            if cfg.mixer(i) == "window" and cfg.window_sink:
+                p[L + "sink"] = dense(next(keys), 1.0, (nh,))
         p[L + "mlp_norm"] = jnp.ones((cfg.d_model,), jnp.float32)
         if cfg.is_moe_layer(i):
             p.update(_moe.init_moe_params(keys, cfg, L, dense))
@@ -725,7 +794,8 @@ def qkv_project(x, p, prefix, cfg: TransformerConfig, positions=None):
     kv-head width (b, n_kv_heads, s, hd) — pre-GQA-expansion, which is the
     shape the decode KV cache stores (models/decode.py)."""
     b, s, _ = x.shape
-    hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+    layer = int(prefix.split(".")[1])
+    hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.kv_heads(layer)
     # the three products stand before the head split as plain 2-D results:
     # without the barrier the TPU compiler folds the split and the
     # transpose below into each product and carries the head-major layout
@@ -734,13 +804,22 @@ def qkv_project(x, p, prefix, cfg: TransformerConfig, positions=None):
     # v5e (tests/test_chip_compile.py::test_projection_weights_read_in_place)
     q, k, v = jax.lax.optimization_barrier(tuple(
         x @ wmat(p, prefix + name, x.dtype) for name in ("wq", "wk", "wv")))
+    if cfg.value_scale != 1.0:
+        v = v * jnp.asarray(cfg.value_scale, v.dtype)
     q = q.reshape(b, s, nh, hd)
     k = k.reshape(b, s, nkv, hd)
-    v = v.reshape(b, s, nkv, hd)
+    v = v.reshape(b, s, nkv, cfg.v_dim)
     q, k = _qk_norm(q, k, p, prefix, cfg)
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # b h s d
-    if cfg.rope:
-        q, k = _rope(q, k, cfg.rope_theta, positions=positions,
+    if cfg.rope and cfg.rotary_dim:
+        # the first rotary_dim features of a head turn, the rest pass
+        rd = cfg.rotary_dim
+        qr, kr = _rope(q[..., :rd], k[..., :rd], cfg.theta(layer),
+                       positions=positions, scaling=cfg.rope_scaling_dict)
+        q = jnp.concatenate([qr, q[..., rd:]], axis=-1)
+        k = jnp.concatenate([kr, k[..., rd:]], axis=-1)
+    elif cfg.rope:
+        q, k = _rope(q, k, cfg.theta(layer), positions=positions,
                      scaling=cfg.rope_scaling_dict)
     return q, k, v
 
@@ -786,6 +865,7 @@ def attention(x, p, prefix, cfg: TransformerConfig, attn_fn=None,
                 "latent attention has its own inner block (models/mla.py)")
         from nvme_strom_tpu.models import mla
         return mla.self_attention(x, p, prefix, cfg, positions)
+    cfg.require_kv_pages("the training path (transformer.attention)")
     # no rotary, or a config's own score scale: only qkv_project and
     # dense_causal_attention know them
     llama_like = cfg.rope and cfg.attn_scale is None

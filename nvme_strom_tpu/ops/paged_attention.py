@@ -85,15 +85,28 @@ def _kernel_view(pool, lanes: bool):
     return jnp.swapaxes(pool, 3, 4) if lanes else pool
 
 
-def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale, block_k, tok):
+def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *refs, scale,
+                  block_k, tok, tok_v, window=0, sink=False):
     """One (slot, table entry) step: every KV head of the entry's pool
-    block at once.  Entries past the slot's last live block do nothing."""
+    block at once.  Entries past the slot's last live block do nothing.
+    ``tok`` / ``tok_v``: whether K's / V's block lies tokens-on-lanes.  With
+    ``window`` the walk starts at the block that holds the slot's oldest
+    visible row, and with ``sink`` a per-head score (``refs[0]``) joins the
+    last normalisation."""
+    if sink:
+        s_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        o_ref, m_ref, l_ref, acc_ref = refs
     bi = pl.program_id(0)
     ji = pl.program_id(1)
     pos = pos_ref[bi]
+    step = ji
+    if window:
+        # the walk's step is the slot's block lo // block_k + step
+        lo = jnp.maximum(pos - window + 1, 0)
+        ji = lo // block_k + step
 
-    @pl.when(ji == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -109,14 +122,20 @@ def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         # rows past pos carry zero weight, but the block's tail (and a
         # foreign block) may hold garbage and 0·NaN = NaN — zero those V
         # rows outright
-        rows_ok = (ji * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, v.shape, 1 + tok)) <= pos
+        rows = ji * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, v.shape, 1 + tok_v)
+        rows_ok = rows <= pos
+        if window:
+            rows_ok = rows_ok & (rows >= lo)
         v = jnp.where(rows_ok, v, 0.0)
         s = jax.lax.dot_general(q, k, (((2,), (2 - tok,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32)
         cols = ji * block_k + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 2)
-        s = jnp.where(cols <= pos, s, _NEG_INF)          # (nkv, g, bk)
+        seen = cols <= pos
+        if window:
+            seen = seen & (cols >= lo)
+        s = jnp.where(seen, s, _NEG_INF)                 # (nkv, g, bk)
 
         m = m_ref[...]                                   # (nkv, g, 1)
         m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
@@ -125,37 +144,54 @@ def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((2,), (1 + tok,)), ((0,), (0,))),
+            p, v, (((2,), (1 + tok_v,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ji == pl.num_programs(1) - 1)
+    @pl.when(step == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        l = l_ref[...]
+        if sink:
+            # one more column of the softmax, with no value behind it
+            l = l + jnp.exp(s_ref[...] - m_ref[...])
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
-                    scale=None, interpret: bool = None):
+                    scale=None, window: int = 0, sink=None,
+                    interpret: bool = None):
     """q (b, n_heads, 1, d) attends to its block-table history in one
     layer of the pool.
 
     k_pool/v_pool (n_layers, n_blocks, n_kv_heads, block_k, d): the shared
     pool of EVERY layer, read where it lies; ``layer`` (static) picks the
-    one this call attends to.
+    one this call attends to.  A value head may be narrower than a key's
+    (``v_pool`` (..., dv)): the result is then (b, n_heads, 1, dv).
     table (b, max_blocks) int32: slot b's sequence lives in pool blocks
     ``table[b, 0] .. table[b, pos[b] // block_k]``; the entries past that
     are never dereferenced.  pos (b,) int32: index of slot b's newest
     entry in its OWN coordinate space (block j covers positions
     [j·block_k, (j+1)·block_k)).
 
-    Returns (b, n_heads, 1, d).  ``interpret`` defaults to True off-TPU.
+    ``window`` w (static; the kernel is then ``strom_window_attn``): the
+    slot sees its last w rows only, ``pos - w < j <= pos``, and the table is
+    a RING — (b, r) with r >= ceil(w / block_k) + 1 entries, the slot's
+    block j at ``table[b, j % r]`` — of which the walk reads the entries
+    that hold those rows and no more (a lower bound beside the upper one).
+    ``sink`` (n_heads,) or None: a learned score per head that joins the
+    softmax as one more column and carries no value.
+
+    Returns (b, n_heads, 1, dv).  ``interpret`` defaults to True off-TPU.
     """
     if q.ndim != 4 or q.shape[2] != 1:
         raise ValueError(f"expected q (b, h, 1, d), got {q.shape}")
     b, nh, _, d = q.shape
-    if k_pool.ndim != 5 or v_pool.shape != k_pool.shape:
+    if (k_pool.ndim != 5 or v_pool.shape[:-1] != k_pool.shape[:-1]
+            or k_pool.shape[-1] != d):
         raise ValueError("expected pools (layers, blocks, kv_heads, "
-                         f"block, d), got {k_pool.shape}, {v_pool.shape}")
+                         f"block, {d} | dv), got {k_pool.shape}, "
+                         f"{v_pool.shape}")
     n_layers, _, nkv, block_k, _ = k_pool.shape
+    dv = v_pool.shape[-1]
     if not 0 <= layer < n_layers:
         raise ValueError(f"layer {layer} not in a pool of {n_layers}")
     if nh % nkv:
@@ -168,67 +204,132 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
     max_blocks = table.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(d)
-    lanes = _tokens_on_lanes(k_pool.shape)
+    lanes, lanes_v = (_tokens_on_lanes(k_pool.shape),
+                      _tokens_on_lanes(v_pool.shape))
     table = jnp.asarray(table, jnp.int32)
     pos = jnp.asarray(pos, jnp.int32)
-    # the walk is as long as the batch's longest slot, not the table: a
-    # grid bound that is data, so one compiled program for every length
-    n_walk = jnp.clip(jnp.max(pos) // block_k + 1, 1, max_blocks)
+    if window:
+        if max_blocks * block_k < window + block_k - 1:
+            raise ValueError(f"a ring of {max_blocks} blocks of {block_k} "
+                             f"cannot hold a window of {window}")
+        # each slot's walk starts at the block of its oldest visible row
+        first = jnp.maximum(pos - window + 1, 0) // block_k
+        n_walk = jnp.clip(jnp.max(pos // block_k - first) + 1, 1, max_blocks)
 
-    def kv_block(bi, ji, tbl, ps):
-        # past the slot's last live block the index stays where it is: an
-        # unchanged block is not fetched again
-        return (layer, tbl[bi, jnp.minimum(ji, ps[bi] // block_k)], 0, 0, 0)
+        def kv_block(bi, ji, tbl, ps):
+            lo = jnp.maximum(ps[bi] - window + 1, 0) // block_k
+            return (layer, tbl[bi, jnp.minimum(lo + ji, ps[bi] // block_k)
+                               % max_blocks], 0, 0, 0)
+    else:
+        # the walk is as long as the batch's longest slot, not the table: a
+        # grid bound that is data, so one compiled program for every length
+        n_walk = jnp.clip(jnp.max(pos) // block_k + 1, 1, max_blocks)
 
-    kv_spec = pl.BlockSpec(
-        (1, 1, nkv, d, block_k) if lanes else (1, 1, nkv, block_k, d),
-        kv_block)
-    qo_spec = pl.BlockSpec((1, nkv, g, d),
-                           lambda bi, ji, tbl, ps: (bi, 0, 0, 0))
+        def kv_block(bi, ji, tbl, ps):
+            # past the slot's last live block the index stays where it is:
+            # an unchanged block is not fetched again
+            return (layer, tbl[bi, jnp.minimum(ji, ps[bi] // block_k)],
+                    0, 0, 0)
+
+    def kv_spec(width, on_lanes):
+        return pl.BlockSpec((1, 1, nkv, width, block_k) if on_lanes
+                            else (1, 1, nkv, block_k, width), kv_block)
+
+    def qo_spec(width):
+        return pl.BlockSpec((1, nkv, g, width),
+                            lambda bi, ji, tbl, ps: (bi, 0, 0, 0))
+
+    in_specs = [qo_spec(d), kv_spec(d, lanes), kv_spec(dv, lanes_v)]
+    args = [q.reshape(b, nkv, g, d), _kernel_view(k_pool, lanes),
+            _kernel_view(v_pool, lanes_v)]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((nkv, g, 1),
+                                     lambda bi, ji, tbl, ps: (0, 0, 0)))
+        args.append(sink.astype(jnp.float32).reshape(nkv, g, 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, n_walk),
-        in_specs=[qo_spec, kv_spec, kv_spec],
-        out_specs=qo_spec,
+        in_specs=in_specs,
+        out_specs=qo_spec(dv),
         scratch_shapes=[
             pltpu.VMEM((nkv, g, 1), jnp.float32),
             pltpu.VMEM((nkv, g, 1), jnp.float32),
-            pltpu.VMEM((nkv, g, d), jnp.float32),
+            pltpu.VMEM((nkv, g, dv), jnp.float32),
         ],
     )
+    extra = dict(window=int(window), sink=sink is not None) \
+        if window or sink is not None else {}
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=float(scale),
-                          block_k=block_k, tok=int(lanes)),
+                          block_k=block_k, tok=int(lanes),
+                          tok_v=int(lanes_v), **extra),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, g, d), q.dtype),
-        name="strom_paged_attn",
+        out_shape=jax.ShapeDtypeStruct((b, nkv, g, dv), q.dtype),
+        name="strom_window_attn" if window else "strom_paged_attn",
         interpret=_interpret(interpret),
-    )(table, pos, q.reshape(b, nkv, g, d),
-      _kernel_view(k_pool, lanes), _kernel_view(v_pool, lanes))
-    return out.reshape(b, nh, 1, d)
+    )(table, pos, *args)
+    return out.reshape(b, nh, 1, dv)
 
 
 def _write_kernel(blk_ref, off_ref, kn_ref, vn_ref, kp_ref, vp_ref,
-                  ko_ref, vo_ref, *, tile, tok):
-    """One slot: its tile of the pool with the slot's row replaced.  The
-    select runs in float32 (exact both ways for bf16), where every shape of
-    broadcast and compare is at home."""
+                  ko_ref, vo_ref, *, tiles, toks):
+    """One slot: its tile of each pool with the slot's row replaced
+    (``tiles`` / ``toks``: K's and V's tile length and whether it lies
+    tokens-on-lanes).  The select runs in float32 (exact both ways for
+    bf16), where every shape of broadcast and compare is at home."""
     bi = pl.program_id(0)
-    at = off_ref[bi] % tile
-    for new, old, out in ((kn_ref, kp_ref, ko_ref), (vn_ref, vp_ref, vo_ref)):
+    ats = {}
+    for new, old, out, tile, tok in zip((kn_ref, vn_ref), (kp_ref, vp_ref),
+                                        (ko_ref, vo_ref), tiles, toks):
+        if tile not in ats:
+            ats[tile] = off_ref[bi] % tile
         have = old[...].astype(jnp.float32)      # (nkv, tile, d) | (nkv, d, tile)
-        hit = jax.lax.broadcasted_iota(jnp.int32, have.shape, 1 + tok) == at
+        hit = (jax.lax.broadcasted_iota(jnp.int32, have.shape, 1 + tok)
+               == ats[tile])
         row = (new[...].astype(jnp.float32) if tok     # (nkv, d, tile), or
                else new[:, pl.ds(bi, 1), :])           # (nkv, 1, d) of (nkv, b, d)
         out[...] = jnp.where(hit, row, have).astype(out.dtype)
 
 
+def _row_specs(pool, new, layer: int):
+    """How ``write_rows`` hands one pool and its new rows to the kernel:
+    (the rows as the kernel takes them, their BlockSpec, the pool tile's
+    BlockSpec, the tile's length, whether the pool lies tokens-on-lanes)."""
+    _, _, nkv, block, d = pool.shape
+    b = new.shape[0]
+    new = new.astype(pool.dtype)
+    if _tokens_on_lanes(pool.shape):
+        # tokens along the lanes: the tile is a lane row of them, and each
+        # slot's new row comes in already spread along it
+        tile = 128
+        return (jnp.broadcast_to(new[..., None], (b, nkv, d, tile)),
+                pl.BlockSpec((None, nkv, d, tile),
+                             lambda bi, bl, of: (bi, 0, 0, 0)),
+                pl.BlockSpec(
+                    (None, None, nkv, d, tile),
+                    lambda bi, bl, of: (layer, bl[bi], 0, 0, of[bi] // tile)),
+                tile, 1)
+    # tokens along the sublanes: one packed sublane tile of them.  The new
+    # rows stay whole in VMEM, kv-head-major as the projections emit them
+    # (no re-layout between the matmul and this call) and in float32 (exact;
+    # a single row of a packed type cannot be loaded)
+    tile = min(block, 8 * 4 // pool.dtype.itemsize)
+    return (jnp.swapaxes(new, 0, 1).astype(jnp.float32),
+            pl.BlockSpec((nkv, b, d), lambda bi, bl, of: (0, 0, 0)),
+            pl.BlockSpec(
+                (None, None, nkv, tile, d),
+                lambda bi, bl, of: (layer, bl[bi], 0, of[bi] // tile, 0)),
+            tile, 0)
+
+
 def write_rows(k_pool, v_pool, k_new, v_new, blk, off, *, layer: int,
-               interpret: bool = None):
+               name: str = "strom_kv_write", interpret: bool = None):
     """Place one new K and V row per slot in layer ``layer`` of the pools,
     IN the pools: ``pool[layer, blk[b], :, off[b], :] = new[b]``.
 
-    k_pool/v_pool (layers, blocks, kv_heads, block, d), aliased input to
+    k_pool/v_pool (layers, blocks, kv_heads, block, d) — V's ``d`` may
+    differ from K's, and each pool is handed over in the layout the device
+    keeps it in —, aliased input to
     output: under ``jit`` with the pools donated nothing pool-sized is
     copied or re-laid-out (the ``.at[].set`` scatter this replaces had XLA
     transpose the whole pool into the scatter's layout and back, every
@@ -236,49 +337,31 @@ def write_rows(k_pool, v_pool, k_new, v_new, blk, off, *, layer: int,
     step reads the aligned tile that holds its slot's row, replaces the
     row and writes the tile back.  Two slots aimed at one row (free slots
     and the trash block) leave one of their rows there, either one; the
-    slots of live requests never share a block they write.
+    slots of live requests never share a block they write.  ``name`` is the
+    kernel's in a device trace (a window layer's ring writer says so).
 
     Returns (k_pool, v_pool)."""
     n_layers, _, nkv, block, d = k_pool.shape
-    if v_pool.shape != k_pool.shape or not 0 <= layer < n_layers:
+    if v_pool.shape[:-1] != k_pool.shape[:-1] or not 0 <= layer < n_layers:
         raise ValueError(f"layer {layer} of pools {k_pool.shape}, "
                          f"{v_pool.shape}")
     b = k_new.shape[0]
-    if k_new.shape != (b, nkv, d) or v_new.shape != (b, nkv, d):
-        raise ValueError(f"expected new rows ({b}, {nkv}, {d}), got "
-                         f"{k_new.shape}, {v_new.shape}")
-    lanes = _tokens_on_lanes(k_pool.shape)
-    news = [x.astype(pool.dtype) for x, pool in ((k_new, k_pool),
-                                                 (v_new, v_pool))]
-    if lanes:
-        # tokens along the lanes: the tile is a lane row of them, and each
-        # slot's new row comes in already spread along it
-        tile = 128
-        news = [jnp.broadcast_to(x[..., None], (b, nkv, d, tile))
-                for x in news]
-        new_spec = pl.BlockSpec((None, nkv, d, tile),
-                                lambda bi, bl, of: (bi, 0, 0, 0))
-        tile_spec = pl.BlockSpec(
-            (None, None, nkv, d, tile),
-            lambda bi, bl, of: (layer, bl[bi], 0, 0, of[bi] // tile))
-    else:
-        # tokens along the sublanes: one packed sublane tile of them.  The
-        # new rows stay whole in VMEM, kv-head-major as the projections
-        # emit them (no re-layout between the matmul and this call) and in
-        # float32 (exact; a single row of a packed type cannot be loaded)
-        tile = min(block, 8 * 4 // k_pool.dtype.itemsize)
-        news = [jnp.swapaxes(x, 0, 1).astype(jnp.float32) for x in news]
-        new_spec = pl.BlockSpec((nkv, b, d), lambda bi, bl, of: (0, 0, 0))
-        tile_spec = pl.BlockSpec(
-            (None, None, nkv, tile, d),
-            lambda bi, bl, of: (layer, bl[bi], 0, of[bi] // tile, 0))
-    kv, vv = _kernel_view(k_pool, lanes), _kernel_view(v_pool, lanes)
+    if (k_new.shape != (b, nkv, d)
+            or v_new.shape != (b, nkv, v_pool.shape[-1])):
+        raise ValueError(f"expected new rows ({b}, {nkv}, {d} | "
+                         f"{v_pool.shape[-1]}), got {k_new.shape}, "
+                         f"{v_new.shape}")
+    (k_new, kn_spec, kt_spec, k_tile, k_tok), \
+        (v_new, vn_spec, vt_spec, v_tile, v_tok) = (
+            _row_specs(k_pool, k_new, layer), _row_specs(v_pool, v_new, layer))
+    kv, vv = _kernel_view(k_pool, k_tok), _kernel_view(v_pool, v_tok)
     kv, vv = pl.pallas_call(
-        functools.partial(_write_kernel, tile=tile, tok=int(lanes)),
+        functools.partial(_write_kernel, tiles=(k_tile, v_tile),
+                          toks=(k_tok, v_tok)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b,),
-            in_specs=[new_spec, new_spec, tile_spec, tile_spec],
-            out_specs=[tile_spec, tile_spec]),
+            in_specs=[kn_spec, vn_spec, kt_spec, vt_spec],
+            out_specs=[kt_spec, vt_spec]),
         out_shape=[jax.ShapeDtypeStruct(kv.shape, kv.dtype),
                    jax.ShapeDtypeStruct(vv.shape, vv.dtype)],
         # operands 4 and 5 (after the two scalar-prefetch ones) are the
@@ -286,8 +369,8 @@ def write_rows(k_pool, v_pool, k_new, v_new, blk, off, *, layer: int,
         input_output_aliases={4: 0, 5: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        name="strom_kv_write",
+        name=name,
         interpret=_interpret(interpret),
     )(jnp.asarray(blk, jnp.int32), jnp.asarray(off, jnp.int32),
-      *news, kv, vv)
-    return _kernel_view(kv, lanes), _kernel_view(vv, lanes)
+      k_new, v_new, kv, vv)
+    return _kernel_view(kv, k_tok), _kernel_view(vv, v_tok)

@@ -95,6 +95,13 @@ _LAYER_RULES: Tuple[Tuple[str, str, bool], ...] = (
     ("mlp.shared_experts.gate_proj.weight", "shared_w_gate", True),
     ("mlp.shared_experts.up_proj.weight", "shared_w_up", True),
     ("mlp.shared_experts.down_proj.weight", "shared_w_down", True),
+    # mimo_v2: q, k and v as ONE projection (``attention_projection_layout``
+    # fused_qkv; convert() splits "wqkv" by the layer's head counts and
+    # widths) and a window layer's learned sink, one scalar a head.  Both
+    # names and the fused rows' order [q | k | v] are ASSUMED: the model
+    # ships its own modelling file, which could not be read here.
+    ("self_attn.qkv_proj.weight", "wqkv", True),
+    ("self_attn.attention_sink_bias", "sink", False),
 )
 
 #: one expert's matrices: convert() stacks them into moe_w_* (E, in, out)
@@ -304,11 +311,84 @@ def _mla_fields(hf_cfg: dict) -> dict:
         tie_embed=bool(hf_cfg.get("tie_word_embeddings", False)))
 
 
+def _mimo_fields(hf_cfg: dict) -> dict:
+    """The TransformerConfig fields of a ``mimo_v2`` config (MiMo-V2-Flash /
+    V2.5: window and full GQA layers mixed by ``hybrid_layer_pattern``, each
+    kind with its own KV-head count and rotary base, stated head widths with
+    keys wider than values, rotary on the leading ``partial_rotary_factor``
+    of a head, a value scale, a learned sink in the window layers; dense or
+    sigmoid-routed expert MLPs by ``moe_layer_freq``), beyond the dense
+    family's.  A file that states one device's share of a deployment carries
+    ``expert_share`` as ``_mla_fields`` reads it.  Raises on what the model
+    does not implement."""
+    def refuse(what):
+        raise ValueError(f"unsupported mimo_v2 config: {what}")
+    n = hf_cfg["num_hidden_layers"]
+    pattern, freq = hf_cfg["hybrid_layer_pattern"], hf_cfg["moe_layer_freq"]
+    if len(pattern) != n or len(freq) != n or (
+            set(pattern) | set(freq)) - {0, 1}:
+        refuse(f"hybrid_layer_pattern and moe_layer_freq must hold a 0 or a "
+               f"1 for each of the {n} layers")
+    hd, vd = hf_cfg["head_dim"], hf_cfg["v_head_dim"]
+    for key, want in (("swa_num_attention_heads",
+                       hf_cfg["num_attention_heads"]),
+                      ("swa_head_dim", hd), ("swa_v_head_dim", vd)):
+        if hf_cfg.get(key, want) != want:
+            refuse(f"{key}={hf_cfg[key]} (window layers share the full "
+                   f"layers' {want})")
+    if hf_cfg.get("add_full_attention_sink_bias"):
+        refuse("add_full_attention_sink_bias=True (only window layers take "
+               "a sink)")
+    window = hf_cfg["sliding_window"]
+    for key in ("sliding_window_size", "attention_chunk_size"):
+        if hf_cfg.get(key) not in (None, window):
+            refuse(f"{key}={hf_cfg[key]} beside sliding_window={window} "
+                   "(read as one window, no chunked mechanism of its own)")
+    scaling = hf_cfg.get("rope_scaling") or {}
+    if scaling.get("rope_type", scaling.get("type", "default")) != "default":
+        refuse(f"rope_scaling {scaling} (only 'default')")
+    for knob in ("n_group", "topk_group"):
+        if (hf_cfg.get(knob) or 1) > 1:
+            refuse(f"{knob}={hf_cfg[knob]} (no group-limited routing)")
+    if hf_cfg.get("scoring_func", "sigmoid") != "sigmoid" or hf_cfg.get(
+            "topk_method", "noaux_tc") != "noaux_tc":
+        refuse("only sigmoid scores with noaux_tc selection (top-k of score "
+               "+ e_score_correction_bias)")
+    if hf_cfg.get("n_shared_experts"):
+        refuse(f"n_shared_experts={hf_cfg['n_shared_experts']}")
+    rotary = int(hd * hf_cfg.get("partial_rotary_factor", 1.0))
+    if rotary % 2:
+        refuse(f"int(head_dim x partial_rotary_factor) = {rotary} is odd")
+    share = hf_cfg.get("expert_share") or {}
+    held = hf_cfg["n_routed_experts"]
+    routed = share.get("routed", held)
+    return dict(
+        layer_kinds=tuple("window" if w else "attention" for w in pattern),
+        mlp_kinds=tuple("experts" if e else "dense" for e in freq),
+        qk_head_dim=hd, v_head_dim=vd,
+        rotary_dim=rotary if rotary != hd else 0,
+        value_scale=float(hf_cfg.get("attention_value_scale") or 1.0),
+        window=window,
+        window_kv_heads=hf_cfg.get("swa_num_key_value_heads", 0),
+        window_rope_theta=float(hf_cfg.get("swa_rope_theta", 0.0)),
+        window_sink=bool(hf_cfg.get("add_swa_attention_sink_bias", False)),
+        rope_scaling=None,
+        norm_eps=float(hf_cfg["layernorm_epsilon"]),
+        n_experts=routed, expert_top_k=hf_cfg["num_experts_per_tok"],
+        experts_held=held if held != routed else 0,
+        expert_offset=share.get("offset", 0),
+        d_expert=hf_cfg["moe_intermediate_size"],
+        router_kind="sigmoid", router_bias=True,
+        router_norm_topk=bool(hf_cfg.get("norm_topk_prob", True)),
+        router_scale=float(hf_cfg.get("routed_scaling_factor") or 1.0),
+        tie_embed=bool(hf_cfg.get("tie_word_embeddings", False)))
+
+
 def config_from_hf(hf_cfg: dict):
     """HF ``config.json`` → TransformerConfig: the dense Llama family,
     ``model_type`` granitemoehybrid (``_hybrid_fields``), ``lfm2`` /
-    ``lfm2_moe`` (``_lfm2_fields``) and ``deepseek_v3`` / ``kimi_k2``
-    (``_mla_fields``).
+    ``lfm2_moe`` (``_lfm2_fields``), ``deepseek_v3`` / ``kimi_k2``
+    (``_mla_fields``) and ``mimo_v2`` (``_mimo_fields``).
 
     Raises on architecture knobs the model does not implement — silently
     ignoring them (e.g. a non-SiLU activation) would convert into a model
@@ -326,13 +406,16 @@ def config_from_hf(hf_cfg: dict):
     family = (_hybrid_fields(hf_cfg) if model_type == "granitemoehybrid"
               else _lfm2_fields(hf_cfg) if model_type in ("lfm2", "lfm2_moe")
               else _mla_fields(hf_cfg)
-              if model_type in ("deepseek_v3", "kimi_k2") else {})
+              if model_type in ("deepseek_v3", "kimi_k2")
+              else _mimo_fields(hf_cfg) if model_type == "mimo_v2" else {})
     derived_hd = hf_cfg["hidden_size"] // hf_cfg["num_attention_heads"]
     if hf_cfg["hidden_size"] % hf_cfg["num_attention_heads"]:
         raise ValueError("hidden_size not divisible by num_attention_heads")
     # (latent attention states its own head widths: where HF writes a
-    # head_dim there, it is qk_rope_head_dim)
-    if "kv_lora_rank" not in family and hf_cfg.get(
+    # head_dim there, it is qk_rope_head_dim; mimo_v2 states its head_dim
+    # and the model takes it, ``qk_head_dim``)
+    if "kv_lora_rank" not in family and "qk_head_dim" not in family \
+            and hf_cfg.get(
             "head_dim", derived_hd) != derived_hd:
         # recent HF configs may carry an explicit head_dim decoupled from
         # hidden_size/n_heads; TransformerConfig derives it, so a
@@ -412,6 +495,11 @@ def strom_config_dict(cfg) -> dict:
         out.update({k: getattr(cfg, k) for k in (
             "q_lora_rank", "kv_lora_rank", "qk_nope_dim", "qk_rope_dim",
             "v_head_dim", "attn_scale", "tie_embed")})
+    if cfg.stated_kv:
+        out.update({k: getattr(cfg, k) for k in (
+            "qk_head_dim", "v_head_dim", "rotary_dim", "value_scale",
+            "window", "window_kv_heads", "window_rope_theta", "window_sink",
+            "tie_embed")})
     return out
 
 
@@ -508,6 +596,16 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
                 seen.add(e.group(1))
                 emit(e.group(1), np.stack([stack[j] for j in range(
                     cfg.experts_local)]))
+            continue
+        if ours.endswith(".wqkv"):          # (d, q | k | v) → wq, wk, wv
+            i = int(ours.split(".")[1])
+            nq = cfg.n_heads * cfg.head_dim
+            nk = cfg.kv_heads(i) * cfg.head_dim
+            for leaf, part in (("wq", out[:, :nq]), ("wk", out[:, nq:nq + nk]),
+                               ("wv", out[:, nq + nk:])):
+                name = ours[:-len("wqkv")] + leaf
+                seen.add(name)
+                emit(name, np.ascontiguousarray(part))
             continue
         if ours.endswith("w_gate_up"):      # (d, 2 ff) → gate | up
             ff = out.shape[1] // 2

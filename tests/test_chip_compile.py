@@ -114,12 +114,12 @@ def weight_sized_copies(hlo_text: str, shapes) -> list:
             if op in ("copy", "transpose") and n in sizes]
 
 
-def _dense_programs(cfg, slots, blocks, sharding=None):
+def _dense_programs(cfg, slots, blocks, sharding=None, prefill_blocks=4):
     """The two serving programs of a plain decoder ``cfg`` over a pool of
     ``blocks`` + 1 blocks of 128 rows, as lowerings nothing has compiled
     yet: ({"step": ``_paged_step`` of ``slots`` slots, "prefill":
-    ``_paged_prefill`` of one prompt of 512 rows (the bucket
-    ``1x512x512``)}, the pool's shape, the shapes of one layer's ``wq``,
+    ``_paged_prefill`` of one prompt of ``prefill_blocks`` x 128 rows (the
+    bucket ``1x512x512``)}, the pool's shape, the shapes of one layer's ``wq``,
     ``wk``, ``wv``)."""
     from nvme_strom_tpu.models import serving
     from nvme_strom_tpu.models.transformer import init_params
@@ -127,20 +127,29 @@ def _dense_programs(cfg, slots, blocks, sharding=None):
     params = {k: spec(v.shape, jnp.bfloat16) for k, v in jax.eval_shape(
         lambda: init_params(jax.random.key(0), cfg)).items()}
     bk = 128
-    pool = spec((cfg.n_layers, blocks + 1, cfg.n_kv_heads, bk, cfg.head_dim),
-                jnp.bfloat16)
+    pool = spec((len(cfg.attn_layers), blocks + 1, cfg.n_kv_heads, bk,
+                 cfg.head_dim), jnp.bfloat16)
+    v_pool = spec(pool.shape[:-1] + (cfg.v_dim,), jnp.bfloat16)
     vec = lambda dt, n=slots: spec((n,), dt)                # noqa: E731
+    # what a config with window layers carries beside the pools: its rings
+    state = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: serving.init_carried(cfg, slots + 1, bk)))
     lowered = {
         "step": lambda: serving._paged_step.lower(
-            params, cfg, vec(jnp.int32), pool, pool, vec(jnp.int32),
+            params, cfg, vec(jnp.int32), pool, v_pool, vec(jnp.int32),
             vec(jnp.int32), spec((slots, cfg.max_seq // bk), jnp.int32),
             vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
-            vec(jnp.uint32)),
+            vec(jnp.uint32), *(() if state is None
+                               else (state, vec(jnp.int32)))),
         "prefill": lambda: serving._paged_prefill.lower(
-            params, cfg, pool, pool, spec((1, 4 * bk), jnp.int32),
-            spec((1, 4), jnp.int32), vec(jnp.int32, 1))}
-    return lowered, pool.shape, [params["layers.0." + w].shape
-                                 for w in ("wq", "wk", "wv")]
+            params, cfg, pool, v_pool,
+            spec((1, prefill_blocks * bk), jnp.int32),
+            spec((1, prefill_blocks), jnp.int32), vec(jnp.int32, 1),
+            *(() if state is None else (state, vec(jnp.int32, 1))))}
+    return lowered, pool.shape, [
+        params[f"layers.{i}.{w}"].shape for w in ("wq", "wk", "wv")
+        for i in ((0, 1) if cfg.window_layers else (0,))]
 
 
 def _small_step(hd, sharding=None):
@@ -396,6 +405,100 @@ def _latent_write(topo):
     return compiled
 
 
+MIMO_SLOTS, MIMO_BLOCKS = 64, 8704      # the cell mimo.flood16k's server
+#: its pools: the full layers' pages (K 192 wide, which the device keeps
+#: tokens-on-lanes, V 128 wide, which it does not) and the window layers'
+#: rings, two blocks a slot and the sacrificial slot's
+MIMO_K, MIMO_V = (2, MIMO_BLOCKS + 1, 4, 128, 192), (2, MIMO_BLOCKS + 1, 4,
+                                                     128, 128)
+MIMO_WK, MIMO_WV = (5, 2 * 65, 8, 128, 192), (5, 2 * 65, 8, 128, 128)
+
+
+def _paged_k192_v128(topo):
+    """A full layer's decode kernel at the cell ``mimo.flood16k``'s shapes:
+    64 slots of 64 query heads over 4 KV heads, keys 192 and values 128
+    wide — each pool read in the layout the device keeps it in —, a table
+    136 entries wide, the last layer read in place."""
+    from nvme_strom_tpu.ops.paged_attention import paged_attention
+    sh = _one(topo)
+    k, v = (_spec(shape, jnp.bfloat16, sh) for shape in (MIMO_K, MIMO_V))
+    compiled = _compile(
+        functools.partial(paged_attention, layer=1, interpret=False),
+        _spec((MIMO_SLOTS, 64, 1, 192), jnp.bfloat16, sh), k, v,
+        _spec((MIMO_SLOTS, 136), jnp.int32, sh),
+        _spec((MIMO_SLOTS,), jnp.int32, sh))
+    text = compiled.as_text()
+    assert "strom_paged_attn" in text
+    assert not pool_sized_ops(text, k.shape) + pool_sized_ops(text, v.shape)
+    return compiled
+
+
+def _window_attn(topo):
+    """A window layer's: 8 KV heads, the slot's ring of two blocks walked
+    from the block of its oldest visible row, a sink per query head; the
+    kernel's name tells it from a full layer's."""
+    from nvme_strom_tpu.ops.paged_attention import paged_attention
+    sh = _one(topo)
+    k, v = (_spec(shape, jnp.bfloat16, sh) for shape in (MIMO_WK, MIMO_WV))
+    compiled = _compile(
+        lambda q, k, v, table, pos, sink: paged_attention(
+            q, k, v, table, pos, layer=4, window=128, sink=sink,
+            interpret=False),
+        _spec((MIMO_SLOTS, 64, 1, 192), jnp.bfloat16, sh), k, v,
+        _spec((MIMO_SLOTS, 2), jnp.int32, sh),
+        _spec((MIMO_SLOTS,), jnp.int32, sh), _spec((64,), jnp.bfloat16, sh))
+    text = compiled.as_text()
+    assert "strom_window_attn" in text and "strom_paged_attn" not in text
+    assert not pool_sized_ops(text, k.shape) + pool_sized_ops(text, v.shape)
+    return compiled
+
+
+def _window_write(topo):
+    """The row writer on pools of unequal widths and layouts: both rings
+    aliased through the call, nothing else of their size in the program."""
+    from nvme_strom_tpu.ops.paged_attention import write_rows
+    sh = _one(topo)
+    k, v = (_spec(shape, jnp.bfloat16, sh) for shape in (MIMO_WK, MIMO_WV))
+    vec = _spec((MIMO_SLOTS,), jnp.int32, sh)
+    compiled = _compile(
+        functools.partial(write_rows, layer=4, name="strom_window_write",
+                          interpret=False), k, v,
+        _spec((MIMO_SLOTS, 8, 192), jnp.bfloat16, sh),
+        _spec((MIMO_SLOTS, 8, 128), jnp.bfloat16, sh), vec, vec,
+        donate_argnums=(0, 1))
+    text = compiled.as_text()
+    assert "strom_window_write" in text
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= (np.prod(k.shape) + np.prod(v.shape)) * 2
+    assert not pool_sized_ops(text, k.shape) + pool_sized_ops(text, v.shape)
+    return compiled
+
+
+def _kv_prefill(topo, nkv=4, window=0):
+    """The blocked prefill kernel over a 16,384-row prompt at the cell's
+    widths: a full layer's causal walk (16 query heads a KV head in one
+    grid step)..."""
+    from nvme_strom_tpu.ops.kv_prefill import kv_prefill_attention
+    sh, rows = _one(topo), 16384
+    sink = [_spec((64,), jnp.bfloat16, sh)] if window else []
+    compiled = _compile(
+        lambda q, k, v, pos, *s: kv_prefill_attention(
+            q, k, v, pos, scale=192 ** -0.5, window=window,
+            sink=s[0] if s else None, interpret=False),
+        _spec((1, 64, rows, 192), jnp.bfloat16, sh),
+        _spec((1, nkv, rows, 192), jnp.bfloat16, sh),
+        _spec((1, nkv, rows, 128), jnp.bfloat16, sh),
+        _spec((), jnp.int32, sh), *sink)
+    assert ("strom_window_prefill" if window else "strom_kv_prefill") \
+        in compiled.as_text()
+    return compiled
+
+
+def _window_prefill(topo):
+    """...and a window layer's band with the sink column."""
+    return _kv_prefill(topo, nkv=8, window=128)
+
+
 @pytest.mark.parametrize("build", [_paged, _decode, _flash_fwd,
                                    _flash_bwd, _bridge, _ici, _paged_hd64,
                                    _ssm_update, _ssm_scan, _ssm_scan_128,
@@ -403,7 +506,9 @@ def _latent_write(topo):
                                    _moe_gmm_down, _moe_gmm_1024,
                                    _moe_gmm_down_1024, _moe_gmm_7168,
                                    _moe_gmm_down_7168, _mla_attn,
-                                   _latent_write],
+                                   _latent_write, _paged_k192_v128,
+                                   _window_attn, _window_write, _kv_prefill,
+                                   _window_prefill],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_kernel_compiles_for_v5e(topo, build):
     assert build(topo) is not None
@@ -481,19 +586,31 @@ PROJECTION_CFGS = {
     # lfm2-24b-a2b's: per-head q/k norms before the rotation
     "lfm2": (dict(vocab=65536, d_model=2048, n_heads=32, n_kv_heads=8,
                   d_ff=11776, max_seq=1280, rope_theta=1e6, qk_norm=True,
-                  tie_embed=True), 128, 1280)}
+                  tie_embed=True), 128, 1280),
+    # mimo-v2.5's: a full layer (4 KV heads) and a window layer (8, a sink),
+    # heads 192 / 128 wide, rotary on the first 64, values scaled
+    "mimo": (dict(vocab=19072, d_model=4096, n_heads=64, n_kv_heads=4,
+                  d_ff=16384, max_seq=17408, rope_theta=1e7,
+                  layer_kinds=("attention", "window"), qk_head_dim=192,
+                  v_head_dim=128, rotary_dim=64, value_scale=0.707,
+                  window=128, window_kv_heads=8, window_rope_theta=1e4,
+                  window_sink=True), 64, 8704)}
 
 
 def _projection_programs(name, sharding=None):
     from nvme_strom_tpu.models.transformer import TransformerConfig
     kw, slots, blocks = PROJECTION_CFGS[name]
+    # mimo's prompt is 640 rows: at 512 its activations have the element
+    # counts of its window layer's wk and wv (512 x 12288 = 4096 x 1536)
     lowered, _, shapes = _dense_programs(
-        TransformerConfig(n_layers=2, **kw), slots, blocks, sharding)
+        TransformerConfig(n_layers=2, **kw), slots, blocks, sharding,
+        prefill_blocks=5 if name == "mimo" else 4)
     return lowered, shapes
 
 
 @pytest.mark.parametrize("name,program", [
-    ("m7b", "step"), ("m7b", "prefill"), ("g4hm", "step"), ("lfm2", "step")])
+    ("m7b", "step"), ("m7b", "prefill"), ("g4hm", "step"), ("lfm2", "step"),
+    ("mimo", "step"), ("mimo", "prefill")])
 def test_projection_weights_read_in_place(topo, monkeypatch, name, program):
     """``qkv_project``'s three products read ``wq``, ``wk`` and ``wv`` in
     the layout they are stored in: the serving program compiled for a v5e
@@ -746,6 +863,105 @@ def test_k2c_long_prefill_holds_no_score_tensor_over_a_gib(topo,
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= np.prod(pool.shape) * 2
     assert m.temp_size_in_bytes < (15.75 - 9.41 - 1.0) * 2 ** 30, m
+
+
+def _mimo_three_layers(topo):
+    """MiMo-V2.5's widths as the cell serves them, cut to its dense full
+    layer and two window expert layers (16 experts held of 256) for the
+    compiler's sake, as shapes on one described chip: (cfg, sharding,
+    params, the full layer's K and V pools, the carried state with the two
+    window layers' rings)."""
+    from nvme_strom_tpu.models import serving
+    from nvme_strom_tpu.models.transformer import init_params
+    from nvme_strom_tpu.tools.convert_llama import config_from_hf
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2.5.json")) as f:
+        hf = json.load(f)
+    cfg = config_from_hf(dict(
+        hf, num_hidden_layers=3,
+        hybrid_layer_pattern=hf["hybrid_layer_pattern"][:3],
+        moe_layer_freq=hf["moe_layer_freq"][:3]))
+    assert cfg.layer_kinds == ("attention", "window", "window")
+    sh = _one(topo)
+    params = {k: _spec(v.shape, jnp.bfloat16, sh) for k, v in jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)).items()}
+    pools = [_spec((1,) + shape[1:], jnp.bfloat16, sh)
+             for shape in (MIMO_K, MIMO_V)]
+    state = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype, sh),
+        jax.eval_shape(lambda: serving.init_carried(cfg, MIMO_SLOTS + 1)))
+    assert state["wk"].shape == (2,) + MIMO_WK[1:]
+    return cfg, sh, params, pools, state
+
+
+def _mimo_caches(pools, state):
+    return [p.shape for p in pools] + [state["wk"].shape, state["wv"].shape]
+
+
+def test_mimo_step_updates_both_kinds_of_cache_in_place(topo, monkeypatch):
+    """The server's decode step of a window configuration at the cell's
+    widths and 64 slots: the full layer's pages AND the window layers' rings
+    are aliased input to output, nothing of the size of any of the four
+    arrays is copied or transposed (K is 192 wide and lies tokens-on-lanes,
+    V 128 wide and does not), a full layer is ``strom_kv_write`` and
+    ``strom_paged_attn``, a window layer ``strom_window_write`` and
+    ``strom_window_attn`` — a device trace tells them apart by name."""
+    from nvme_strom_tpu.models import serving
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, sh, params, pools, state = _mimo_three_layers(topo)
+    B = MIMO_SLOTS
+    vec = lambda dt: _spec((B,), dt, sh)                    # noqa: E731
+    compiled = serving._paged_step.lower(
+        params, cfg, vec(jnp.int32), *pools, vec(jnp.int32),
+        vec(jnp.int32), _spec((B, 136), jnp.int32, sh), vec(jnp.int32),
+        vec(jnp.float32), vec(jnp.float32), vec(jnp.uint32), state,
+        vec(jnp.int32)).compile()
+    text = compiled.as_text()
+    for name, n in (("strom_kv_write", 1), ("strom_paged_attn", 1),
+                    ("strom_window_write", 2), ("strom_window_attn", 2)):
+        assert text.count(name) >= n, name
+    assert text.count("tpu_custom_call") == 2 * 3 + 2 * 2   # + the gmm's
+    shapes = _mimo_caches(pools, state)
+    for shape in shapes:
+        assert not pool_sized_ops(text, shape), shape
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= sum(np.prod(shape) for shape in shapes) * 2
+
+
+def test_mimo_long_prefill_holds_no_score_tensor_over_a_gib(topo,
+                                                             monkeypatch):
+    """The admission program of one 16,384-row prompt at the cell's widths
+    (the same three layers): it compiles for a v5e, pages and rings are
+    aliased through, a full layer's attention is the blocked kernel
+    ``strom_kv_prefill`` and a window layer's ``strom_window_prefill``, so
+    that no array of the program is larger than 1 GiB — the (64, 16384,
+    16384) float32 score tensor ``cache_attention`` would build is 64 GiB —
+    and its temporaries fit beside the seven-layer model's 12.10 GiB of
+    weights, pages and rings (the seven-layer program's whole need is 14.8
+    of the chip's 15.75 GiB by the compiler's buffer assignment, which the
+    chip bears out — PERF.md section 4)."""
+    from nvme_strom_tpu.models import serving
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, sh, params, pools, state = _mimo_three_layers(topo)
+    rows, bk = 16384, 128
+    vec = _spec((1,), jnp.int32, sh)
+    compiled = serving._paged_prefill.lower(
+        params, cfg, *pools, _spec((1, rows), jnp.int32, sh),
+        _spec((1, rows // bk), jnp.int32, sh), vec, state, vec).compile()
+    text = compiled.as_text()
+    assert "strom_kv_prefill" in text and "strom_window_prefill" in text
+    shapes = _mimo_caches(pools, state)
+    own = {",".join(map(str, shape[skip:])) for shape in shapes
+           for skip in (0, 1)}
+    size = {"f32": 4, "bf16": 2, "s32": 4}
+    largest = max(size[t] * int(np.prod([int(n) for n in dims.split(",")]))
+                  for t, dims in re.findall(r"\b(f32|bf16|s32)\[([\d,]+)\]",
+                                            text) if dims not in own)
+    assert largest <= 2 ** 30, largest / 2 ** 30
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(np.prod(s) for s in shapes) * 2
+    assert m.temp_size_in_bytes < (15.75 - 12.10 - 0.4) * 2 ** 30, m
 
 
 def test_sharded_forward_compiles_for_four_chips(topo):

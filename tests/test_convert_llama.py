@@ -527,3 +527,82 @@ def test_deepseek_logits_match_hf(hf_deepseek, tmp_path):
         ref = model(torch.from_numpy(toks)).logits.float().numpy()
     ours = np.asarray(forward(params, jnp.asarray(toks, jnp.int32), cfg))
     np.testing.assert_allclose(ours, ref, atol=2e-4, rtol=2e-4)
+
+
+# -- mimo_v2: window and full attention mixed, a fused qkv projection --------
+
+def test_mimo_v2_converts_the_fused_projection_by_each_layers_heads(tmp_path):
+    """A mimo_v2 checkpoint under the assumed names: ``qkv_proj`` (rows [q |
+    k | v]) splits into wq, wk, wv by the LAYER's head counts and widths —
+    a full layer's 1 KV head, a window layer's 2, keys 24 and values 16
+    wide —, a window layer's sinks land as ``sink``, the experts stack, the
+    config round-trips through ``strom_config.json``."""
+    import test_swa
+    from nvme_strom_tpu.formats.safetensors import write_safetensors
+    from nvme_strom_tpu.models.transformer import TransformerConfig
+    hf = dict(test_swa.HF, num_hidden_layers=2, hybrid_layer_pattern=[0, 1],
+              moe_layer_freq=[0, 1], expert_share=None)
+    cfg = convert_llama.config_from_hf(hf)
+    d = convert_llama.strom_config_dict(cfg)
+    assert TransformerConfig(**json.loads(json.dumps(d))) == cfg
+    assert (d["qk_head_dim"], d["v_head_dim"], d["rotary_dim"], d["window"],
+            d["window_kv_heads"]) == (24, 16, 8, 16, 2)
+    rng = np.random.default_rng(2)
+    f32 = lambda *s: rng.standard_normal(s).astype(np.float32)   # noqa: E731
+    nq, tensors = 4 * 24, {
+        "model.embed_tokens.weight": f32(96, 64),
+        "model.norm.weight": f32(64), "lm_head.weight": f32(96, 64)}
+    for i, nkv in enumerate((1, 2)):
+        L = f"model.layers.{i}."
+        tensors.update({
+            L + "input_layernorm.weight": f32(64),
+            L + "post_attention_layernorm.weight": f32(64),
+            L + "self_attn.qkv_proj.weight": f32(nq + nkv * (24 + 16), 64),
+            L + "self_attn.o_proj.weight": f32(64, 4 * 16)})
+    tensors.update({
+        "model.layers.0.mlp.gate_proj.weight": f32(128, 64),
+        "model.layers.0.mlp.up_proj.weight": f32(128, 64),
+        "model.layers.0.mlp.down_proj.weight": f32(64, 128),
+        "model.layers.1.self_attn.attention_sink_bias": f32(4),
+        "model.layers.1.mlp.gate.weight": f32(4, 64),
+        "model.layers.1.mlp.gate.e_score_correction_bias": f32(4)})
+    for e in range(4):
+        E = f"model.layers.1.mlp.experts.{e}."
+        tensors.update({E + "gate_proj.weight": f32(32, 64),
+                        E + "up_proj.weight": f32(32, 64),
+                        E + "down_proj.weight": f32(64, 32)})
+    src = tmp_path / "hf"
+    src.mkdir()
+    write_safetensors(str(src / "model.safetensors"), tensors)
+    (src / "config.json").write_text(json.dumps(hf))
+    out = str(tmp_path / "out")
+    assert convert_llama.convert(str(src), out)["skipped"] == []
+    got, params = _load_converted(out, dtype=cfg.dtype)
+    assert got == cfg
+    for i, nkv in enumerate((1, 2)):
+        fused = tensors[f"model.layers.{i}.self_attn.qkv_proj.weight"].T
+        L = f"layers.{i}."
+        assert params[L + "wk"].shape == (64, nkv * 24)
+        assert params[L + "wv"].shape == (64, nkv * 16)
+        np.testing.assert_array_equal(params[L + "wq"], fused[:, :nq])
+        np.testing.assert_array_equal(params[L + "wk"],
+                                      fused[:, nq:nq + nkv * 24])
+        np.testing.assert_array_equal(params[L + "wv"],
+                                      fused[:, nq + nkv * 24:])
+    np.testing.assert_array_equal(
+        params["layers.1.sink"],
+        tensors["model.layers.1.self_attn.attention_sink_bias"])
+    assert params["layers.1.moe_w_gate"].shape == (4, 64, 32)
+    assert "layers.0.sink" not in params
+
+
+@pytest.mark.parametrize("key,value,msg", [
+    ("add_full_attention_sink_bias", True, "only window layers"),
+    ("swa_v_head_dim", 32, "swa_v_head_dim"),
+    ("scoring_func", "softmax", "only sigmoid"),
+    ("moe_layer_freq", [0, 1, 2, 1, 1], "a 0 or a 1"),
+])
+def test_mimo_v2_config_raises_on_what_is_not_implemented(key, value, msg):
+    import test_swa
+    with pytest.raises(ValueError, match=msg):
+        convert_llama.config_from_hf(dict(test_swa.HF, **{key: value}))

@@ -347,27 +347,31 @@ def test_gmm_at_a_deep_contraction_against_an_einsum(gated, monkeypatch):
 # -- (6) the share of a deployment ---------------------------------------------
 
 def _expert_layer(cfg_kw, seed=4):
-    cfg = tr.TransformerConfig(
+    cfg = tr.TransformerConfig(**dict(dict(
         vocab=32, d_model=32, n_layers=1, n_heads=2, n_kv_heads=2, d_ff=64,
         mlp_kinds=("experts",), n_experts=16, expert_top_k=4, d_expert=16,
         d_shared=16, router_kind="sigmoid", router_bias=True,
-        router_scale=2.827, dtype=jnp.float32, **cfg_kw)
+        router_scale=2.827, dtype=jnp.float32), **cfg_kw))
     keys = iter(jax.random.split(jax.random.key(seed), 16))
     p = moe.init_moe_params(keys, cfg, "", tr.dense_init)
     return cfg, p
 
 
-def test_the_shares_of_an_expert_layer_add_up_to_the_whole():
+@pytest.mark.parametrize("shared_expert", [True, False],
+                         ids=["kimi_shared_expert", "mimo_no_shared_expert"])
+def test_the_shares_of_an_expert_layer_add_up_to_the_whole(shared_expert):
     """16 experts over 4 shares of 4: the routed parts all four shares give,
-    plus the shared expert ONCE, equal the uncut layer — the router is
-    whole on every share and the weights are normalised over all 4 selected
-    experts, held or not."""
-    whole_cfg, p = _expert_layer({})
+    plus the shared expert ONCE (where the model has one: Kimi's; MiMo-V2.5
+    has none and no scale), equal the uncut layer — the router is whole on
+    every share and the weights are normalised over all 4 selected experts,
+    held or not."""
+    whole_cfg, p = _expert_layer(
+        {} if shared_expert else dict(d_shared=0, router_scale=1.0))
     p[("router_bias")] = jax.random.normal(jax.random.key(9), (16,)) * 0.2
     x = jax.random.normal(jax.random.key(1), (2, 9, 32), jnp.float32)
     valid = jnp.ones((2, 9), bool).at[1, 6:].set(False)
     want, counts, _ = moe.expert_mlp(x, p, "", whole_cfg, valid)
-    shared = tr.mlp(x, p, "shared_")
+    shared = tr.mlp(x, p, "shared_") if shared_expert else jnp.zeros_like(x)
     total, pairs = jnp.zeros_like(want), 0
     for share in range(4):
         cfg = dataclasses.replace(whole_cfg, experts_held=4,

@@ -58,11 +58,20 @@ def _latent_shared():                 # MLA, a share of the experts + shared
     return _tiny(test_mla, test_mla.WM)
 
 
+def _window_experts():                # full, window, window, full, window
+    import test_swa
+    return _tiny(test_swa, test_swa.WS)
+
+
 MODELS = {"dense": _dense, "hybrid": _hybrid, "conv_experts": _conv_experts,
-          "latent_shared": _latent_shared}
+          "latent_shared": _latent_shared, "window_experts": _window_experts}
 #: the families a kind's programs must show (beside embed, mlp and head)
 MIXERS = {"dense": {"attn"}, "hybrid": {"attn", "ssm"},
-          "conv_experts": {"attn", "conv"}, "latent_shared": {"attn"}}
+          "conv_experts": {"attn", "conv"}, "latent_shared": {"attn"},
+          "window_experts": {"attn"}}
+#: scopes inside a family that tell one kind of layer from another: a window
+#: layer's row writer and kernel from a full layer's
+INNER = {"window_experts": {"strom.attn.window", "strom.attn.paged"}}
 
 
 def _shapes(args):
@@ -160,24 +169,28 @@ def _jaxpr(fn, args):
 @pytest.mark.parametrize("kind", list(MODELS))
 def test_every_operation_lies_under_one_family(kind):
     seen = _served(kind)
-    found = set()
+    found, parts = set(), set()
     for prim, path in _leaves(_jaxpr(serving._paged_step, seen["step"])):
         bucket, family = _family(path)
         assert family in FAMILIES - {"prefill"} and bucket is None, \
             (prim, path)
         found.add(family)
+        parts.update(path)
     assert found == {"embed", "mlp", "head"} | MIXERS[kind]
+    assert INNER.get(kind, set()) <= parts
 
     labels = set()
     for program, args in seen["prefill"]:
-        found = set()
+        found, parts = set(), set()
         for prim, path in _leaves(_jaxpr(serving._paged_prefill, args)):
             bucket, family = _family(path)
             assert family in FAMILIES, (prim, path)
             # ... under the very string the host span was opened with
             assert bucket == program, (prim, path, program)
             found.add(family)
+            parts.update(path)
         assert found == {"embed", "mlp", "head", "prefill"} | MIXERS[kind]
+        assert INNER.get(kind, set()) <= parts
         labels.add(program)
     # a whole prompt and one behind a cached prefix are two compiled shapes
     assert len(labels) >= 2

@@ -294,7 +294,13 @@ def test_server_stats_gauges(setup):
              "moe_rounds_prefill": 0,
              # nor a share of a deployment's experts, nor a latent pool
              # (tests/test_mla.py has both)
-             "experts_held": 0, "latent_bytes_per_token": 0}
+             "experts_held": 0, "latent_bytes_per_token": 0,
+             # K and V of every layer's kv heads, float32 here; no window
+             # layer, so no ring
+             "kv_bytes_per_token": 2 * cfg.n_layers * cfg.n_kv_heads
+             * cfg.head_dim * 4,
+             "window_layers": 0, "window_bytes_per_slot": 0,
+             "window_rows_live": 0}
     assert s0 == want0
     srv.step()
     s1 = srv.stats()
