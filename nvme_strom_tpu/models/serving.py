@@ -675,8 +675,13 @@ class DecodeServer:
         #: server, a climbing count is a shape leak).  Per decode
         #: step, from the host's position mirror: ``attn_blocks_live``
         #: (Σ over active slots of ``pos // block_len + 1``, the table
-        #: entries paged attention has to read) and ``attn_blocks_table``
-        #: (``B × max_blocks``, the entries it would walk unbounded).  Per
+        #: entries paged attention has to read), ``attn_blocks_table``
+        #: (``B × max_blocks``, the entries it would walk unbounded) and
+        #: ``attn_grid_steps`` (the grid steps ONE layer's attention call
+        #: issues: a step a live entry and one for each free slot — the
+        #: latent kernel still walks slots × the longest slot, four
+        #: entries a step; live over steps is the share of steps that
+        #: carry a block).  Per
         #: call of an exact expert layer (one per layer per decode step;
         #: one per layer per prefill program under ``*_prefill``), read
         #: off the device's
@@ -698,7 +703,7 @@ class DecodeServer:
             "prefill_calls": 0, "prefill_rows_dead": 0,
             "prefill_programs": 0, "scan_tokens": 0,
             "attn_blocks_live": 0, "attn_blocks_table": 0,
-            "window_rows_live": 0,
+            "attn_grid_steps": 0, "window_rows_live": 0,
             **{key + sfx: 0 for sfx in ("", "_prefill") for key in (
                 "moe_calls", "moe_pairs", "moe_pairs_routed",
                 "moe_rows_computed", "moe_experts_touched",
@@ -1437,6 +1442,8 @@ class DecodeServer:
             # unbounded walk would have, summed over the decode steps
             "attn_blocks_live": self.timings["attn_blocks_live"],
             "attn_blocks_table": self.timings["attn_blocks_table"],
+            # and the grid steps one layer's attention call issued for them
+            "attn_grid_steps": self.timings["attn_grid_steps"],
         }
         state = jax.tree_util.tree_leaves(
             [self.state[k] for k in ("s", "conv")] if self.state else None)
@@ -1733,10 +1740,19 @@ class DecodeServer:
               if self.blocks[b] else self._trash)
              for b in range(self.B)], jnp.int32)
         off = self.pos % self.block_len
-        self.timings["attn_blocks_live"] += sum(
-            self._pos_h[b] // self.block_len + 1
-            for b in range(self.B) if self.slots[b] is not None)
+        # the table entries each slot's walk reads (a free slot is handed
+        # ``pos`` 0: one entry), and the grid steps a layer's call makes of
+        # them
+        walks = [self._pos_h[b] // self.block_len + 1
+                 if self.slots[b] is not None else 0 for b in range(self.B)]
+        self.timings["attn_blocks_live"] += sum(walks)
         self.timings["attn_blocks_table"] += self.B * self.max_blocks
+        if self.cfg.latent:
+            from nvme_strom_tpu.ops.mla_attention import GROUP
+            self.timings["attn_grid_steps"] += self.B * -(
+                -max(max(walks), 1) // GROUP)
+        else:
+            self.timings["attn_grid_steps"] += sum(max(n, 1) for n in walks)
         if self.cfg.window_layers:
             self.timings["window_rows_live"] += sum(
                 min(self._pos_h[b] + 1, self.cfg.window)

@@ -7,26 +7,31 @@ its history.  Capacity is sized for the TOTAL live tokens, not
 slots × max_len — heterogeneous requests stop paying for the longest
 one's reservation.
 
-Kernel shape (``strom_paged_attn``): the block table and per-slot
-positions ride scalar prefetch (``pltpu.PrefetchScalarGridSpec``), so each
-grid step's K/V BlockSpec ``index_map`` dereferences ``table[b, j]`` and
-the DMA fetches exactly that pool block — the indirection costs nothing
-extra over the contiguous-cache kernel (ops/decode_attention.py), and no
-gathered copy of the cache ever materializes in HBM.  Everything else is
-the same fused position-masked online softmax, in float32.
+Kernel shape (``strom_paged_attn``): a grid step is one LIVE table entry.
+``walk_list`` builds, on the device from ``table`` and ``pos``, the list of
+the (slot, entry) pairs a call has to read, slot-major — per slot the
+entries ``0 .. pos // block`` (a window layer: from the block of its
+oldest visible row) — with each pair's pool block looked up beside it, and
+the grid is ONE axis over that list, as long as the list (a dynamic grid
+bound computed on the device: data, not a compiled shape).  The lists ride
+scalar prefetch (``pltpu.PrefetchScalarGridSpec``): each grid step's K/V
+BlockSpec ``index_map`` reads its pool block from them and the DMA fetches
+exactly that block, q's and the output's read the slot — the indirection
+costs nothing extra over the contiguous-cache kernel
+(ops/decode_attention.py), and no gathered copy of the cache ever
+materializes in HBM.  Everything else is the same fused position-masked
+online softmax, in float32, a slot's blocks in their order.
 
-The grid is ``(slots, blocks)`` and walks only what is live.  One grid
-step covers EVERY KV head of one pool block — for a fixed layer and block
-the heads lie next to each other in the pool, so K and V come in one DMA
-each (256 KiB at 8 heads of 128 under block 128) and scores and ``p·v``
-are one ``dot_general`` batched over the heads; a grid step costs its
-fixed ~0.3 us whatever it carries, so the heads share it.  The block axis
-is as long as the batch's LONGEST slot (``max(pos) // block + 1``, a
-dynamic grid bound computed on the device: data, not a compiled shape),
-and a shorter slot's steps past its own last block do nothing: the index
-map holds the block index where it is (an unchanged index fetches
-nothing) and the body runs under ``pl.when``.  Table entries past a
-slot's last live block are never dereferenced.
+One grid step covers EVERY KV head of one pool block — for a fixed layer
+and block the heads lie next to each other in the pool, so K and V come in
+one DMA each (256 KiB at 8 heads of 128 under block 128) and scores and
+``p·v`` are one ``dot_general`` batched over the heads.  No step is issued
+for a table entry past a slot's last live block, so a short slot beside a
+long one costs its own blocks and nothing more: under the (slots x longest
+slot) grid this replaced, a step that fetched nothing and did nothing still
+cost 0.16 us (64 slots at 2k-17k rows: 4,544 such steps beside 3,968 live
+ones of 0.67 us, PERF.md section 6, PR 40).  Table entries past a slot's
+last live block are never dereferenced: the list does not hold them.
 
 Within a slot's last block the rows past ``pos`` (and whatever block a
 caller's table names there) may hold garbage: their columns are masked,
@@ -85,22 +90,50 @@ def _kernel_view(pool, lanes: bool):
     return jnp.swapaxes(pool, 3, 4) if lanes else pool
 
 
-def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *refs, scale,
-                  block_k, tok, tok_v, window=0, sink=False):
-    """One (slot, table entry) step: every KV head of the entry's pool
-    block at once.  Entries past the slot's last live block do nothing.
-    ``tok`` / ``tok_v``: whether K's / V's block lies tokens-on-lanes.  With
-    ``window`` the walk starts at the block that holds the slot's oldest
-    visible row, and with ``sink`` a per-head score (``refs[0]``) joins the
-    last normalisation."""
+def walk_list(table, pos, block_k: int, window: int = 0):
+    """The grid of one ``paged_attention`` call, from the data: (slot,
+    block, start), all int32.  ``slot[i]`` is the slot grid step ``i``
+    belongs to and ``block[i]`` the pool block it reads, slot-major, a
+    slot's blocks oldest first; ``start[b] .. start[b + 1]`` are slot b's
+    steps, so ``start[-1]`` is the grid's length.  A slot walks the table
+    entries ``first .. pos // block_k`` — ``first`` 0, or under ``window``
+    the block of its oldest visible row, entry j at ``table[b, j % width]``
+    — at least one and at most the table's width.  The lists are as long
+    as the table has entries; past the grid's length they repeat its last
+    step.  No gather but the table's own (and, under ``window``, of the
+    slots' first blocks): a step's slot and its place in the slot are
+    counts over the slots' ends."""
+    b, width = table.shape
+    first = (jnp.maximum(pos - window + 1, 0) // block_k if window
+             else jnp.zeros_like(pos))
+    n = jnp.clip(pos // block_k - first + 1, 1, width)
+    ends = jnp.cumsum(n)
+    i = jnp.minimum(jnp.arange(b * width, dtype=jnp.int32), ends[-1] - 1)
+    done = ends[:, None] <= i[None, :]          # (b, steps): slot b ended
+    slot = jnp.sum(done, axis=0, dtype=jnp.int32)
+    entry = i - jnp.sum(jnp.where(done, n[:, None], 0), axis=0)
+    if window:
+        entry = (entry + first[slot]) % width
+    return (slot, table.reshape(-1)[slot * width + entry],
+            jnp.concatenate([jnp.zeros((1,), jnp.int32), ends]))
+
+
+def _paged_kernel(slot_ref, blk_ref, start_ref, pos_ref, q_ref, k_ref, v_ref,
+                  *refs, scale, block_k, tok, tok_v, window=0, sink=False):
+    """One step of ``walk_list``'s list: every KV head of one live pool
+    block of one slot at once.  ``tok`` / ``tok_v``: whether K's / V's
+    block lies tokens-on-lanes.  With ``window`` the slot's walk starts at
+    the block that holds its oldest visible row, and with ``sink`` a
+    per-head score (``refs[0]``) joins the last normalisation."""
     if sink:
         s_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
         o_ref, m_ref, l_ref, acc_ref = refs
-    bi = pl.program_id(0)
-    ji = pl.program_id(1)
+    i = pl.program_id(0)
+    bi = slot_ref[i]
     pos = pos_ref[bi]
-    step = ji
+    step = i - start_ref[bi]
+    ji = step
     if window:
         # the walk's step is the slot's block lo // block_k + step
         lo = jnp.maximum(pos - window + 1, 0)
@@ -147,7 +180,7 @@ def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *refs, scale,
             p, v, (((2,), (1 + tok_v,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
-    @pl.when(step == pl.num_programs(1) - 1)
+    @pl.when(i == start_ref[bi + 1] - 1)
     def _finish():
         l = l_ref[...]
         if sink:
@@ -208,47 +241,32 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
                       _tokens_on_lanes(v_pool.shape))
     table = jnp.asarray(table, jnp.int32)
     pos = jnp.asarray(pos, jnp.int32)
-    if window:
-        if max_blocks * block_k < window + block_k - 1:
-            raise ValueError(f"a ring of {max_blocks} blocks of {block_k} "
-                             f"cannot hold a window of {window}")
-        # each slot's walk starts at the block of its oldest visible row
-        first = jnp.maximum(pos - window + 1, 0) // block_k
-        n_walk = jnp.clip(jnp.max(pos // block_k - first) + 1, 1, max_blocks)
-
-        def kv_block(bi, ji, tbl, ps):
-            lo = jnp.maximum(ps[bi] - window + 1, 0) // block_k
-            return (layer, tbl[bi, jnp.minimum(lo + ji, ps[bi] // block_k)
-                               % max_blocks], 0, 0, 0)
-    else:
-        # the walk is as long as the batch's longest slot, not the table: a
-        # grid bound that is data, so one compiled program for every length
-        n_walk = jnp.clip(jnp.max(pos) // block_k + 1, 1, max_blocks)
-
-        def kv_block(bi, ji, tbl, ps):
-            # past the slot's last live block the index stays where it is:
-            # an unchanged block is not fetched again
-            return (layer, tbl[bi, jnp.minimum(ji, ps[bi] // block_k)],
-                    0, 0, 0)
+    if window and max_blocks * block_k < window + block_k - 1:
+        raise ValueError(f"a ring of {max_blocks} blocks of {block_k} "
+                         f"cannot hold a window of {window}")
+    # one grid step a live table entry: a grid bound that is data, so one
+    # compiled program for every mix of lengths
+    slot, blocks, start = walk_list(table, pos, block_k, window)
 
     def kv_spec(width, on_lanes):
         return pl.BlockSpec((1, 1, nkv, width, block_k) if on_lanes
-                            else (1, 1, nkv, block_k, width), kv_block)
+                            else (1, 1, nkv, block_k, width),
+                            lambda i, sl, bl, st, ps: (layer, bl[i], 0, 0, 0))
 
     def qo_spec(width):
         return pl.BlockSpec((1, nkv, g, width),
-                            lambda bi, ji, tbl, ps: (bi, 0, 0, 0))
+                            lambda i, sl, bl, st, ps: (sl[i], 0, 0, 0))
 
     in_specs = [qo_spec(d), kv_spec(d, lanes), kv_spec(dv, lanes_v)]
     args = [q.reshape(b, nkv, g, d), _kernel_view(k_pool, lanes),
             _kernel_view(v_pool, lanes_v)]
     if sink is not None:
         in_specs.append(pl.BlockSpec((nkv, g, 1),
-                                     lambda bi, ji, tbl, ps: (0, 0, 0)))
+                                     lambda i, sl, bl, st, ps: (0, 0, 0)))
         args.append(sink.astype(jnp.float32).reshape(nkv, g, 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_walk),
+        num_scalar_prefetch=4,
+        grid=(start[-1],),
         in_specs=in_specs,
         out_specs=qo_spec(dv),
         scratch_shapes=[
@@ -267,7 +285,7 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, dv), q.dtype),
         name="strom_window_attn" if window else "strom_paged_attn",
         interpret=_interpret(interpret),
-    )(table, pos, *args)
+    )(slot, blocks, start, pos, *args)
     return out.reshape(b, nh, 1, dv)
 
 

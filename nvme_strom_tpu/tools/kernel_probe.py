@@ -8,6 +8,13 @@ long-context shape where the O(s²) dense path stops being competitive.
 One JSON line per measurement, each naming ``platform``,
 ``device_kind`` and ``device_count``.
 
+``python -m nvme_strom_tpu.tools.kernel_probe paged`` times the paged
+decode kernels (``ops/paged_attention.py``) alone instead, at the serving
+cells' own shapes and slot mixes (``PAGED_CASES``): a call's time with the
+table entries it reads and the grid steps it issues beside it, so that
+what a grid step costs without a block can be told from what it costs
+with one.
+
 One process, on the chip: without a TPU the probe exits non-zero unless
 the caller set ``JAX_PLATFORMS=cpu`` (mechanics only, tiny shapes; every
 line then says ``"platform": "cpu"``).
@@ -209,6 +216,111 @@ def probe_matmul_roof(dev) -> None:
              f"{' SUSPECT' if 'suspect' in rec else ''}")
 
 
+#: name -> (slots, query heads, KV heads, K width, V width, pool blocks,
+#: table width, calls a step, window, each slot's ``pos`` cycled over the
+#: slots): the shapes of the benchmark's K/V cells, block 128, bf16.  The
+#: first two read the same number of blocks to within 2 % on walks of 63
+#: and 133; ``-1`` is a free slot (handed ``pos`` 0 as the step hands it).
+PAGED_CASES = {
+    "mimo.even": (64, 64, 4, 192, 128, 8705, 136, 2, 0, (8063,)),
+    "mimo.ragged": (64, 64, 4, 192, 128, 8705, 136, 2, 0,
+                    (2100, 4200, 8300, 17000)),
+    "mimo.window": (64, 64, 8, 192, 128, 130, 2, 5, 128,
+                    (2100, 4200, 8300, 17000)),
+    "m7b.flood": (16, 32, 8, 128, 128, 256, 32, 24, 0,
+                  (200, 330, 460, 640)),
+    "m7b.chat": (16, 32, 8, 128, 128, 256, 32, 24, 0, (520,) + (-1,) * 15),
+    "g4hm.flood": (64, 32, 8, 64, 64, 640, 10, 4, 0,
+                   (200, 390, 700, 1150)),
+    "lfm2.flood": (128, 32, 8, 64, 64, 1280, 10, 3, 0,
+                   (200, 390, 700, 1150)),
+}
+
+
+def probe_paged(case: str, repeats: int = 5) -> None:
+    """One line for ``PAGED_CASES[case]``: microseconds a call of
+    ``paged_attention`` — ``calls`` calls of one jitted program on one
+    table and ``pos``, as a decode step makes them on its layers, chained
+    through q and looped on the device so that no dispatch is in the time
+    — with the table entries the call reads (``blocks_live``) and the grid
+    steps of the (slots x longest slot) walk (``steps_rect``)."""
+    import statistics
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from nvme_strom_tpu.ops.paged_attention import paged_attention
+    (b, nh, nkv, d, dv, blocks, width, calls, window,
+     cycle) = PAGED_CASES[case]
+    bk, layers = 128, 2
+    on_cpu = jax.default_backend() != "tpu"
+    if on_cpu:                             # mechanics only
+        b, nh, nkv, d, dv, bk, calls = min(b, 4), 4, 2, 16, 8, 8, 2
+        window = window and 8
+        width = 2 if window else 6
+        blocks = b * width
+        cycle = tuple(-1 if p < 0 else p % (width * bk) for p in cycle)
+    pos = np.array([cycle[i % len(cycle)] for i in range(b)], np.int32)
+    free = pos < 0
+    pos = np.where(free, 0, pos)
+    last = pos // bk
+    first = np.maximum(pos - window + 1, 0) // bk if window else 0 * last
+    n = np.where(free, 1, last - first + 1)
+    table = np.zeros((b, width), np.int32)
+    if window:              # a ring a slot, as the server lays them out
+        table[:] = np.arange(b * width).reshape(b, width)
+    else:
+        ids, at = np.random.default_rng(0).permutation(blocks), 0
+        for i in np.flatnonzero(~free):
+            table[i, :n[i]] = ids[at:at + n[i]]
+            at += n[i]
+    draw = jax.jit(lambda k, shape: (jax.random.normal(
+        k, shape, jnp.float32) * 0.5).astype(jnp.bfloat16), static_argnums=1)
+    keys = jax.random.split(jax.random.key(1), 4)
+    k_pool = draw(keys[0], (layers, blocks + 1, nkv, bk, d))
+    v_pool = draw(keys[1], (layers, blocks + 1, nkv, bk, dv))
+    q = draw(keys[2], (b, nh, 1, d))
+    sink = draw(keys[3], (nh,)) if window else None
+    # ~0.3 s a timed run: a call is milliseconds on a long table
+    rounds = 2 if on_cpu else max(
+        2, int(0.3 / (2e-3 if width > 32 else 1e-4) / calls))
+
+    @jax.jit
+    def run(q, k_pool, v_pool, table, pos):
+        def step(_, carry):
+            # table and pos are the step's own, as a server's are: what
+            # is worked out from them is worked out every round
+            q, table, pos = jax.lax.optimization_barrier(carry)
+            for i in range(calls):
+                out = paged_attention(q, k_pool, v_pool, table, pos,
+                                      layer=i % layers, window=window,
+                                      sink=sink)
+                # the next call waits for this one; the values stay q's
+                q = q + (jnp.max(out) * 1e-30).astype(q.dtype)
+            return q, table, pos
+        return jax.lax.fori_loop(0, rounds, step, (q, table, pos))[0]
+
+    args = (q, k_pool, v_pool, jnp.asarray(table), jnp.asarray(pos))
+    out = run(*args).block_until_ready()
+    ts = []
+    for _ in range(repeats):
+        t0 = time.monotonic()
+        run(*args).block_until_ready()
+        ts.append((time.monotonic() - t0) / (rounds * calls))
+    live = int(n.sum())
+    _emit({"probe": "paged_attention", "case": case, "window": window,
+           "slots": b, "heads": [nh, nkv], "widths": [d, dv],
+           "table": [b, width], "calls_a_step": calls,
+           "blocks_live": live, "steps_rect": int(b * n.max()),
+           "mib_live": round(live * nkv * bk * (d + dv) * 2 / 2 ** 20, 1),
+           "us_a_call": round(statistics.median(ts) * 1e6, 2),
+           "us_a_call_min": round(min(ts) * 1e6, 2),
+           "finite": bool(jnp.isfinite(out.astype(jnp.float32)).all()),
+           "timing": f"{rounds} rounds of {calls} calls on the device, "
+                     f"median of {repeats}"})
+
+
 def main() -> int:
     sys.path.insert(0, REPO)   # direct-script mode: repo root first
     from nvme_strom_tpu.utils.compile_cache import enable_compile_cache
@@ -219,6 +331,11 @@ def main() -> int:
     import jax
     dev = jax.devices()[0]
     _log(f"device = {dev}")
+    if sys.argv[1:2] == ["paged"]:
+        for case in sys.argv[2:] or PAGED_CASES:
+            probe_paged(case)
+        return 0
+
     def roof_guarded():
         # the roof probe must never cost the step its PRIMARY output
         # (the attn tiling rows that feed best_attn_blocks adoption) —

@@ -865,12 +865,14 @@ def test_k2c_long_prefill_holds_no_score_tensor_over_a_gib(topo,
     assert m.temp_size_in_bytes < (15.75 - 9.41 - 1.0) * 2 ** 30, m
 
 
-def _mimo_three_layers(topo):
+def _mimo_layers(topo, pattern=(0, 1, 1), experts=(0, 1, 1), **keys):
     """MiMo-V2.5's widths as the cell serves them, cut to its dense full
     layer and two window expert layers (16 experts held of 256) for the
-    compiler's sake, as shapes on one described chip: (cfg, sharding,
-    params, the full layer's K and V pools, the carried state with the two
-    window layers' rings)."""
+    compiler's sake — or to another ``pattern`` of full (0) and window (1)
+    layers, ``experts`` saying which hold experts, ``keys`` replacing keys
+    of the configuration's file —, as shapes on one described chip: (cfg,
+    sharding, params, the full layers' K and V pools, the carried state with
+    the window layers' rings)."""
     from nvme_strom_tpu.models import serving
     from nvme_strom_tpu.models.transformer import init_params
     from nvme_strom_tpu.tools.convert_llama import config_from_hf
@@ -879,19 +881,20 @@ def _mimo_three_layers(topo):
                            "mimo-v2.5.json")) as f:
         hf = json.load(f)
     cfg = config_from_hf(dict(
-        hf, num_hidden_layers=3,
-        hybrid_layer_pattern=hf["hybrid_layer_pattern"][:3],
-        moe_layer_freq=hf["moe_layer_freq"][:3]))
-    assert cfg.layer_kinds == ("attention", "window", "window")
+        hf, num_hidden_layers=len(pattern),
+        hybrid_layer_pattern=list(pattern), moe_layer_freq=list(experts),
+        **keys))
+    assert cfg.layer_kinds == tuple(
+        "window" if kind else "attention" for kind in pattern)
     sh = _one(topo)
     params = {k: _spec(v.shape, jnp.bfloat16, sh) for k, v in jax.eval_shape(
         lambda: init_params(jax.random.key(0), cfg)).items()}
-    pools = [_spec((1,) + shape[1:], jnp.bfloat16, sh)
+    pools = [_spec((pattern.count(0),) + shape[1:], jnp.bfloat16, sh)
              for shape in (MIMO_K, MIMO_V)]
     state = jax.tree_util.tree_map(
         lambda a: _spec(a.shape, a.dtype, sh),
         jax.eval_shape(lambda: serving.init_carried(cfg, MIMO_SLOTS + 1)))
-    assert state["wk"].shape == (2,) + MIMO_WK[1:]
+    assert state["wk"].shape == (sum(pattern),) + MIMO_WK[1:]
     return cfg, sh, params, pools, state
 
 
@@ -909,7 +912,7 @@ def test_mimo_step_updates_both_kinds_of_cache_in_place(topo, monkeypatch):
     ``strom_window_attn`` — a device trace tells them apart by name."""
     from nvme_strom_tpu.models import serving
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg, sh, params, pools, state = _mimo_three_layers(topo)
+    cfg, sh, params, pools, state = _mimo_layers(topo)
     B = MIMO_SLOTS
     vec = lambda dt: _spec((B,), dt, sh)                    # noqa: E731
     compiled = serving._paged_step.lower(
@@ -929,6 +932,42 @@ def test_mimo_step_updates_both_kinds_of_cache_in_place(topo, monkeypatch):
         >= sum(np.prod(shape) for shape in shapes) * 2
 
 
+def test_mimo_step_builds_each_walk_list_once_a_step(topo, monkeypatch):
+    """The decode step at the cell's attention shapes — 64 slots, a table
+    136 wide, 64 query heads over 4 (full) and 8 (window) KV heads, keys
+    192 and values 128 wide — with TWO full and TWO window layers (dense
+    MLPs cut to 512 for the compiler's sake): ``walk_list``'s lists of the
+    live table entries are built once a step for each kind of cache, not
+    once a layer — one gather of 64 x 136 table entries for both full
+    layers, and for both window layers one of the rings' 64 x 2 and one of
+    the slots' first blocks — ; the four int32 operands the kernel takes on
+    scalar prefetch (two lists of 8,704, the slots' 65 bounds, their 64
+    positions: 70 KiB) fit, or Mosaic would have refused the kernel;
+    nothing of a cache's size is copied."""
+    from nvme_strom_tpu.models import serving
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, sh, params, pools, state = _mimo_layers(
+        topo, pattern=(0, 1, 0, 1), experts=(0, 0, 0, 0),
+        intermediate_size=512)
+    B = MIMO_SLOTS
+    vec = lambda dt: _spec((B,), dt, sh)                    # noqa: E731
+    compiled = serving._paged_step.lower(
+        params, cfg, vec(jnp.int32), *pools, vec(jnp.int32),
+        vec(jnp.int32), _spec((B, 136), jnp.int32, sh), vec(jnp.int32),
+        vec(jnp.float32), vec(jnp.float32), vec(jnp.uint32), state,
+        vec(jnp.int32)).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"= \S+ custom-call\(([^)]*)\), custom_call_target="
+                         r'"tpu_custom_call"[^\n]*strom_(paged|window)_attn',
+                         text)
+    assert sorted(kind for _, kind in kernels) == ["paged"] * 2 + ["window"] * 2
+    gathers = [shape for op, shape, _ in _array_ops(text) if op == "gather"]
+    assert gathers.count(f"s32[{B * 136}]") == 1, gathers
+    assert gathers.count(f"s32[{B * 2}]") == 2, gathers
+    for shape in _mimo_caches(pools, state):
+        assert not pool_sized_ops(text, shape), shape
+
+
 def test_mimo_long_prefill_holds_no_score_tensor_over_a_gib(topo,
                                                              monkeypatch):
     """The admission program of one 16,384-row prompt at the cell's widths
@@ -943,7 +982,7 @@ def test_mimo_long_prefill_holds_no_score_tensor_over_a_gib(topo,
     chip bears out — PERF.md section 4)."""
     from nvme_strom_tpu.models import serving
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg, sh, params, pools, state = _mimo_three_layers(topo)
+    cfg, sh, params, pools, state = _mimo_layers(topo)
     rows, bk = 16384, 128
     vec = _spec((1,), jnp.int32, sh)
     compiled = serving._paged_prefill.lower(

@@ -156,6 +156,10 @@ def test_prefill_then_decode_through_the_latent_pool(model, spy, lookahead):
     assert 0 < t["moe_pairs_prefill"] < t["moe_pairs_routed_prefill"]
     assert t["moe_pairs_routed_prefill"] == sum(
         len(p) for p in prompts.values()) * 4 * 2
+    # the latent kernel keeps the (slots x longest slot) walk, four table
+    # entries a grid step: three slots times one step, or two while "d"
+    # (33 + 6 rows: five blocks of 8) is among them
+    assert 3 * t["steps"] < t["attn_grid_steps"] < 3 * 2 * t["steps"]
 
 
 def test_a_prompt_longer_than_one_query_block(model, spy, monkeypatch):

@@ -2,10 +2,11 @@
 block-gather reference, ragged slot lengths, and the row writer against
 the scatter it replaced; interpret mode on CPU.  The pools are 5-D, every
 layer's in one array: a 4-D pool of the reference is a pool of one layer.
-The kernel's walk ends at a slot's last live block (grid ``(slots, blocks
-of the longest slot)``, every KV head of a block in one grid step): pools
-poisoned outside what a slot owns, free slots and the grid itself are
-pinned below, in both layouts of the pool."""
+The kernel's walk ends at a slot's last live block (one grid step a live
+table entry, ``walk_list``'s list, every KV head of a block in one grid
+step): pools poisoned outside what a slot owns, free slots, a batch as
+ragged as a long-context cell's, the list and the grid itself are pinned
+below, in both layouts of the pool."""
 
 import functools
 
@@ -14,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from nvme_strom_tpu.ops.paged_attention import paged_attention, write_rows
+from nvme_strom_tpu.ops.paged_attention import (paged_attention, walk_list,
+                                                write_rows)
 
 
 def _reference(q, kp, vp, table, pos):
@@ -224,45 +226,144 @@ def _pallas_eqns(jaxpr, found):
     return found
 
 
+# slots' positions (of the block length) -> the grid steps of one call: a
+# slot's live table entries, at least one, at most the table's width
+WALKS = {
+    "all_at_0": (lambda bk: [0, 0, 0], 3),
+    "inside_the_first_block": (lambda bk: [bk - 1, 0, 3], 3),
+    "one_into_its_second_block": (lambda bk: [bk, 0, 3], 4),
+    "one_at_the_tables_end": (
+        lambda bk: [1, MAX_BLOCKS * bk - 1, 0], MAX_BLOCKS + 2),
+    "one_past_the_table": (
+        lambda bk: [1, 9 * MAX_BLOCKS * bk, 0], MAX_BLOCKS + 2),
+}
+
+
 @pytest.mark.parametrize("block_k,d", GEOMETRIES)
-def test_grid_has_no_kv_head_axis_and_ends_with_the_longest_slot(block_k,
-                                                                 d):
-    """The grid read off the traced ``pallas_call``: (slots, blocks) — no
-    axis over the KV heads, one K/V block holds them all — and the block
-    axis is data, the longest slot's live blocks, never more than the
-    table's width."""
+@pytest.mark.parametrize("walk", list(WALKS))
+def test_grid_is_one_axis_over_the_live_entries_with_every_kv_head(walk,
+                                                                   block_k,
+                                                                   d):
+    """The grid read off the traced ``pallas_call``: ONE axis — no axis
+    over the slots, none over the KV heads, one K/V block holds them all —
+    whose bound is data, the sum of the slots' live table entries, never
+    more than the table holds."""
     from nvme_strom_tpu.ops.paged_attention import _tokens_on_lanes
     b, nkv, g, n_pool = 3, 2, 2, 9
     pool = jnp.zeros((2, n_pool, nkv, block_k, d), jnp.float32)
     q = jnp.zeros((b, nkv * g, 1, d), jnp.float32)
     table = jnp.zeros((b, MAX_BLOCKS), jnp.int32)
+    positions, steps = WALKS[walk]
+    pos = jnp.asarray(positions(block_k), jnp.int32)
+    jaxpr = jax.make_jaxpr(functools.partial(paged_attention, layer=1))(
+        q, pool, pool, table, pos)
+    call, = _pallas_eqns(jaxpr.jaxpr, [])
+    mapping = call.params["grid_mapping"]
+    assert call.params["name"] == "strom_paged_attn"
+    assert len(mapping.grid) == 1
+    assert mapping.num_dynamic_grid_bounds == 1
+    kv = tuple(mapping.block_mappings[1].block_shape)
+    heads_and_block = ((nkv, d, block_k) if _tokens_on_lanes(pool.shape)
+                       else (nkv, block_k, d))
+    assert tuple(getattr(n, "block_size", n)
+                 for n in kv[-3:]) == heads_and_block
+    # the bound itself: the call's first operand, computed from pos
+    bound = int(jax.jit(lambda p: jax.core.eval_jaxpr(
+        jaxpr.jaxpr.replace(outvars=[call.invars[0]]), jaxpr.consts,
+        q, pool, pool, table, p)[0])(pos))
+    assert bound == steps <= b * MAX_BLOCKS
 
-    def walk(pos):
-        jaxpr = jax.make_jaxpr(functools.partial(paged_attention, layer=1))(
-            q, pool, pool, table, pos)
-        call, = _pallas_eqns(jaxpr.jaxpr, [])
-        mapping = call.params["grid_mapping"]
-        assert call.params["name"] == "strom_paged_attn"
-        assert len(mapping.grid) == 2 and mapping.grid[0] == b
-        assert mapping.num_dynamic_grid_bounds == 1
-        kv = tuple(mapping.block_mappings[1].block_shape)
-        heads_and_block = ((nkv, d, block_k)
-                           if _tokens_on_lanes(pool.shape)
-                           else (nkv, block_k, d))
-        assert tuple(getattr(n, "block_size", n)
-                     for n in kv[-3:]) == heads_and_block
-        # the bound itself: the call's first operand, computed from pos
-        bound = jax.jit(lambda p: jax.core.eval_jaxpr(
-            jaxpr.jaxpr.replace(outvars=[call.invars[0]]), jaxpr.consts,
-            q, pool, pool, table, p)[0])(pos)
-        return int(bound)
 
-    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
-    assert walk(i32([0, 0, 0])) == 1
-    assert walk(i32([block_k - 1, 0, 3])) == 1
-    assert walk(i32([block_k, 0, 3])) == 2
-    assert walk(i32([1, MAX_BLOCKS * block_k - 1, 0])) == MAX_BLOCKS
-    assert walk(i32([1, 9 * MAX_BLOCKS * block_k, 0])) == MAX_BLOCKS
+@pytest.mark.parametrize("block_k,d", GEOMETRIES)
+def test_a_ragged_batch_equals_each_slot_alone(block_k, d):
+    """Slots of 1, 2 and the table's ``MAX_BLOCKS`` blocks and a free slot
+    in ONE call — as far apart as a long-context cell's slots are — against
+    each slot in a call of its own: bit for bit, so a slot's blocks are
+    visited in the same order whoever its neighbours are; and the dense
+    reference's."""
+    rng = np.random.default_rng([block_k, d, 40])
+    pos = [block_k - 2, MAX_BLOCKS * block_k - 1, 0, block_k + 1]
+    q, kp, vp, table = _slots(rng, block_k, d, pos, MAX_BLOCKS)
+    kp[0] = rng.standard_normal(kp[0].shape)     # a free slot's block 0
+    vp[0] = rng.standard_normal(vp[0].shape)
+    table[2] = 0
+    pools = jnp.asarray(kp)[None], jnp.asarray(vp)[None]
+    got = np.asarray(paged_attention(
+        jnp.asarray(q), *pools, jnp.asarray(table),
+        jnp.asarray(pos, jnp.int32)))
+    assert np.isfinite(got).all()
+    for i in range(len(pos)):
+        alone = np.asarray(paged_attention(
+            jnp.asarray(q[i:i + 1]), *pools, jnp.asarray(table[i:i + 1]),
+            jnp.asarray(pos[i:i + 1], jnp.int32)))
+        np.testing.assert_array_equal(got[i:i + 1], alone)
+    live = [0, 1, 3]
+    np.testing.assert_allclose(
+        got[live], _dense(q[live], kp, vp, table[live], np.array(pos)[live],
+                          block_k), atol=2e-5, rtol=2e-5)
+
+
+def walk_list_by_loop(table, pos, block_k, window=0):
+    """``walk_list`` as a loop over the slots and their entries."""
+    b, width = table.shape
+    slot, block, start = [], [], [0]
+    for i in range(b):
+        last = pos[i] // block_k
+        first = max(pos[i] - window + 1, 0) // block_k if window else 0
+        n = min(max(last - first + 1, 1), width)
+        slot += [i] * n
+        block += [table[i, (first + j) % width] for j in range(n)]
+        start.append(start[-1] + n)
+    return np.array(slot), np.array(block), np.array(start)
+
+
+@pytest.mark.parametrize("block_k,d", GEOMETRIES)
+@pytest.mark.parametrize("window", [0, 1.5], ids=["full", "window"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_built_list_equals_a_numpy_loops(seed, window, block_k, d):
+    """The (slot, block) list of the live table entries and the slots'
+    first steps, for random positions — free slots' 0, a position past the
+    table — over a table of distinct entries, which a window (of a block
+    and a half) reads as a ring from the block of its oldest visible row;
+    past the grid's length the lists repeat its last step."""
+    rng = np.random.default_rng([seed, block_k])
+    window = int(window * block_k)
+    b, width = 7, 5
+    pos = rng.integers(0, width * block_k, b).astype(np.int32)
+    pos[rng.integers(b)] = 0
+    pos[rng.integers(b)] = 3 * width * block_k
+    table = rng.permutation(b * width).astype(np.int32).reshape(b, width)
+    slot, block, start = (np.asarray(a) for a in jax.jit(
+        walk_list, static_argnums=(2, 3))(jnp.asarray(table),
+                                          jnp.asarray(pos), block_k, window))
+    want_slot, want_block, want_start = walk_list_by_loop(table, pos,
+                                                          block_k, window)
+    steps = want_start[-1]
+    assert slot.shape == block.shape == (b * width,) and steps <= b * width
+    np.testing.assert_array_equal(start, want_start)
+    np.testing.assert_array_equal(slot[:steps], want_slot)
+    np.testing.assert_array_equal(block[:steps], want_block)
+    assert (slot[steps:] == want_slot[-1]).all()
+    assert (block[steps:] == want_block[-1]).all()
+
+
+@pytest.mark.parametrize("case", ["mimo.ragged", "mimo.window", "m7b.chat"])
+def test_the_kernel_probe_counts_what_a_call_reads(case, capsys):
+    """``kernel_probe paged`` at its CPU size (mechanics only: no time it
+    prints here is a device's): a finite result, the live entries of the
+    case's slot mix — a free slot is one — and the steps the (slots x
+    longest slot) grid made of them."""
+    import json
+
+    from nvme_strom_tpu.tools import kernel_probe
+    kernel_probe.probe_paged(case, repeats=1)
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line["case"] == case and line["finite"]
+    slots = line["slots"]
+    assert slots <= line["blocks_live"] <= line["steps_rect"] \
+        <= slots * line["table"][1]
+    if case == "m7b.chat":      # one live slot among free ones
+        assert line["steps_rect"] == slots * (line["blocks_live"] - slots + 1)
 
 
 def _pools(rng, shape, dtype):

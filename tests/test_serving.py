@@ -285,6 +285,7 @@ def test_server_stats_gauges(setup):
              "kv_layers": cfg.n_layers, "state_bytes": 0, "state_slots": 0,
              # no decode step yet: paged attention has walked nothing
              "attn_blocks_live": 0, "attn_blocks_table": 0,
+             "attn_grid_steps": 0,
              # no layer's MLP is the exact expert layer here, so nothing is
              # routed (tests/test_lfm2.py has a model that does)
              "moe_layers": 0, "moe_calls": 0, "moe_pairs": 0,
@@ -307,6 +308,8 @@ def test_server_stats_gauges(setup):
     assert s1["slots_busy"] == 2 and s1["queued"] == 0
     # one step, both slots inside their first block of a table 8 wide
     assert (s1["attn_blocks_live"], s1["attn_blocks_table"]) == (2, 16)
+    # and the kernel's grid was those two entries, a step each
+    assert s1["attn_grid_steps"] == 2
     # both prompts, one block each, went through ONE call of the bucket's
     # one program (four wide at four rows a prompt: two rows dead)
     assert s1["prefill_programs"] == 1
@@ -952,7 +955,8 @@ def test_mixed_batch_serves_generates_tokens_and_counts_its_live_blocks(
     say: a request of prompt ``s`` and budget ``m`` takes ``m - 1`` decode
     steps at positions ``s .. s + m - 2`` (its first token is the
     prefill's), each reading ``pos // block + 1`` table entries, where an
-    unbounded walk reads slots x table width at every step."""
+    unbounded walk reads slots x table width at every step; the kernel's
+    grid is a step for each of those entries and one for a free slot."""
     params, cfg, _, _ = _pr26_model(kind)
     bk = 8
     rng = np.random.default_rng(29)
@@ -975,3 +979,8 @@ def test_mixed_batch_serves_generates_tokens_and_counts_its_live_blocks(
     # 58 of the 160 entries ten unbounded steps would have walked
     assert (stats["attn_blocks_live"], stats["attn_blocks_table"]) \
         == (live, 10 * 2 * (64 // bk))
+    # the grid of one layer's call is a step a live entry, and in calls 9
+    # and 10 one more for the free slot 1 (handed ``pos`` 0): 60, where the
+    # (slots x longest slot) grid made 2 x 2 x 4 + 6 x 2 x 5 + 2 x 2 x 1 = 80
+    assert stats["attn_grid_steps"] == 58 + 2 * 1
+    assert stats["attn_blocks_live"] <= stats["attn_grid_steps"]

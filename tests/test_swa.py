@@ -310,25 +310,17 @@ def test_paged_attention_with_values_narrower_than_keys(block, dk, dv):
                                    atol=3e-6)
 
 
-@pytest.mark.parametrize("block,window,ring", [(128, 128, 2), (8, 16, 3),
-                                               (8, 20, 4)])
-def test_window_attention_walks_a_ring(block, window, ring):
-    """A window layer's kernel: slot b's block j lies at ``table[b, j %
-    ring]``; positions before the first wrap, at a block's first and last
-    row, and after several wraps read exactly the last ``window`` rows, and
-    the sink takes its share.  Rows of the ring outside the window hold NaN:
-    none reaches the output."""
-    rng = np.random.default_rng(window)
+def _ring_case(rng, block, window, ring, positions, S):
+    """Slots at ``positions`` over rings of ``ring`` blocks: (q, the dense
+    k and v (slots, nkv, S, d), sink, pos, the ring pools — slot b's block j
+    at pool block ``b * ring + j % ring``, every row outside a slot's window
+    NaN —, the table, the score scale)."""
     dk, dv, nkv, nh = (192, 128, 2, 8) if block == 128 else (24, 16, 2, 4)
-    positions = [3, block - 1, block, 2 * block + 5, 5 * block + block // 2,
-                 7 * block - 1]
     slots = len(positions)
-    S = 8 * block
     k = jnp.asarray(rng.normal(size=(slots, nkv, S, dk)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(slots, nkv, S, dv)), jnp.float32)
     sink = jnp.asarray(rng.normal(size=(nh,)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(slots, nh, 1, dk)), jnp.float32)
-    pos = jnp.asarray(positions, jnp.int32)
     k_pool = np.full((1, slots * ring, nkv, block, dk), np.nan, np.float32)
     v_pool = np.full((1, slots * ring, nkv, block, dv), np.nan, np.float32)
     for b, p in enumerate(positions):
@@ -337,13 +329,87 @@ def test_window_attention_walks_a_ring(block, window, ring):
             k_pool[0, blk, :, row % block] = k[b, :, row]
             v_pool[0, blk, :, row % block] = v[b, :, row]
     table = jnp.arange(slots * ring, dtype=jnp.int32).reshape(slots, ring)
-    got = paged_attention(q, jnp.asarray(k_pool), jnp.asarray(v_pool), table,
-                          pos, layer=0, window=window, sink=sink,
-                          interpret=True)
+    return (q, k, v, sink, jnp.asarray(positions, jnp.int32),
+            (jnp.asarray(k_pool), jnp.asarray(v_pool)), table, dk ** -0.5)
+
+
+@pytest.mark.parametrize("block,window,ring", [(128, 128, 2), (8, 16, 3),
+                                               (8, 20, 4)])
+def test_window_attention_walks_a_ring(block, window, ring):
+    """A window layer's kernel: slot b's block j lies at ``table[b, j %
+    ring]``; positions before the first wrap, at a block's first and last
+    row, and after several wraps read exactly the last ``window`` rows, and
+    the sink takes its share.  Rows of the ring outside the window hold NaN:
+    none reaches the output."""
+    positions = [3, block - 1, block, 2 * block + 5, 5 * block + block // 2,
+                 7 * block - 1]
+    q, k, v, sink, pos, pools, table, scale = _ring_case(
+        np.random.default_rng(window), block, window, ring, positions,
+        8 * block)
+    got = paged_attention(q, *pools, table, pos, layer=0, window=window,
+                          sink=sink, interpret=True)
     want = jnp.concatenate([_dense_attention(
-        q[b:b + 1], k[b:b + 1], v[b:b + 1], pos[b], dk ** -0.5, window, sink)
+        q[b:b + 1], k[b:b + 1], v[b:b + 1], pos[b], scale, window, sink)
+        for b in range(len(positions))])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
+
+
+@pytest.mark.parametrize("block,window,ring", [(128, 128, 2), (8, 16, 3),
+                                               (8, 20, 4)])
+def test_window_slots_of_unequal_walks_equal_each_slot_alone(block, window,
+                                                             ring):
+    """The window call's grid is the full layers': one step a ring entry a
+    slot has to read.  Slots before the window fills (``pos < window``: one
+    entry), across a block boundary (two), after the ring has wrapped and a
+    free slot (``pos`` 0) in ONE call, with the sink, against each slot in a
+    call of its own: bit for bit; and against the dense computation."""
+    positions = [window - 2, 0, 3 * block + 1, (2 * ring + 1) * block - 1,
+                 block // 2]
+    slots = len(positions)
+    q, k, v, sink, pos, pools, table, scale = _ring_case(
+        np.random.default_rng([block, window]), block, window, ring,
+        positions, (2 * ring + 1) * block)
+    got = paged_attention(q, *pools, table, pos, layer=0, window=window,
+                          sink=sink, interpret=True)
+    for b in range(slots):
+        alone = paged_attention(q[b:b + 1], *pools, table[b:b + 1],
+                                pos[b:b + 1], layer=0, window=window,
+                                sink=sink, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got[b:b + 1]),
+                                      np.asarray(alone))
+    want = jnp.concatenate([_dense_attention(
+        q[b:b + 1], k[b:b + 1], v[b:b + 1], pos[b], scale, window, sink)
         for b in range(slots)])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
+
+
+@pytest.mark.parametrize("block,window,ring", [(128, 128, 2), (8, 16, 3),
+                                               (8, 20, 4)])
+def test_window_grid_is_one_axis_over_the_ring_entries_in_the_window(
+        block, window, ring):
+    """The traced window call: ``strom_window_attn``, one grid axis whose
+    bound is data — the ring entries that hold each slot's last ``window``
+    rows, summed over the slots."""
+    positions = [3, block - 1, block, 2 * block + 5, 7 * block - 1]
+    slots = len(positions)
+    k_pool, v_pool = _pools(np.random.default_rng(0), 1, slots * ring, 2,
+                            block, 24, 16)
+    q = jnp.zeros((slots, 4, 1, 24), jnp.float32)
+    table = jnp.arange(slots * ring, dtype=jnp.int32).reshape(slots, ring)
+    pos = jnp.asarray(positions, jnp.int32)
+    fn = lambda p: paged_attention(q, k_pool, v_pool, table, p,  # noqa: E731
+                                   window=window, interpret=True)
+    jaxpr = jax.make_jaxpr(fn)(pos)
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    call, = calls
+    mapping = call.params["grid_mapping"]
+    assert call.params["name"] == "strom_window_attn"
+    assert len(mapping.grid) == 1 and mapping.num_dynamic_grid_bounds == 1
+    bound = int(jax.jit(lambda p: jax.core.eval_jaxpr(
+        jaxpr.jaxpr.replace(outvars=[call.invars[0]]), jaxpr.consts,
+        p)[0])(pos))
+    assert bound == sum(p // block - max(p - window + 1, 0) // block + 1
+                        for p in positions) <= slots * ring
 
 
 def test_a_ring_too_short_for_its_window_is_refused():
