@@ -54,11 +54,15 @@ def experts_cost(hf: dict, rows: float, touched: float) -> tuple:
     and down) over ``rows`` (row, expert) pairs that fall on ``touched``
     experts: each touched expert's three matrices read once, the pairs'
     activations in and out, two operations per multiply-add of every pair
-    (tile padding is the kernel's, not the algorithm's)."""
-    z = sizes(hf)
-    p = param_count(hf)
-    nbytes = (touched * p["expert"] + rows * (2 * z["d"] + 2 * z["fe"])) * 2
-    return nbytes, 2.0 * rows * p["expert"]
+    (tile padding is the kernel's, not the algorithm's).  Needs the two
+    widths only, under the keys every expert configuration publishes them
+    by, so it counts a device that holds a share of the router's experts
+    (``n_routed_experts`` files: the pairs and the touched experts are the
+    held ones') as it counts one that holds all (``num_experts``)."""
+    d, fe = hf["hidden_size"], hf["moe_intermediate_size"]
+    expert = 3 * d * fe
+    nbytes = (touched * expert + rows * (2 * d + 2 * fe)) * 2
+    return nbytes, 2.0 * rows * expert
 
 
 def decode_step_bytes(hf: dict, slots: int, live_tokens: float,

@@ -188,5 +188,11 @@ def print_checks(checks: list) -> bool:
 
 
 def emit(result: dict) -> None:
+    """The numbers compared as the last lines on standard error, then the
+    result as the last line on standard output."""
+    for name, c in result.get("checks", {}).items():
+        print(f"check: {name} = {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
+    sys.stderr.flush()
     sys.stdout.flush()
     print(json.dumps(result), flush=True)

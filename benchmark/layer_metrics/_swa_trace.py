@@ -1,59 +1,23 @@
-"""What the window-attention configuration's readers share: the device
-events of its kernels by their fixed names (``ops/paged_attention.py``: a
-full layer's decode kernel ``strom_paged_attn``, a window layer's
-``strom_window_attn``; ``ops/kv_prefill.py``: the blocked prefill
-``strom_kv_prefill`` and ``strom_window_prefill``) inside the program that
-ran them, the window's counters, and the configuration test.  A program
-without the kernels or the counters (an older commit), or a configuration of
-another family, gives nothing, and the readers return ``None``."""
+"""What is the window-attention configuration's own: its kernels' fixed names
+(``ops/paged_attention.py``: a full layer's decode kernel ``strom_paged_attn``,
+a window layer's ``strom_window_attn``; ``ops/kv_prefill.py``: the blocked
+prefill ``strom_kv_prefill`` and ``strom_window_prefill``), the window's
+counters per decode step, the configuration test and a decode kernel's
+roofline.  The walks are ``_kernel_trace``'s.  A program without the kernels
+or the counters (an older commit), or a configuration of another family,
+gives nothing, and the readers return ``None``."""
 
 from __future__ import annotations
 
-import bisect
-
-from benchmark.layer_metrics._ssm_trace import least_seconds  # noqa: F401
+from benchmark.layer_metrics import _kernel_trace as K
 
 FULL, WINDOW = "strom_paged_attn", "strom_window_attn"
 PREFILL_KERNELS = ("strom_kv_prefill", "strom_window_prefill")
-STEP, PREFILL = "_paged_step", "_paged_prefill"
-
-
-def is_kernel(event_name: str, kernels) -> bool:
-    """Whether a device event IS a call of one of ``kernels``: its own name,
-    left of the ``=``, says so (the operation that consumes the kernel's
-    result names it among its operands)."""
-    own = event_name.split("=", 1)[0]
-    return any(k in own for k in kernels)
 
 
 def is_swa(config: dict) -> bool:
     return bool(config.get("hybrid_layer_pattern")
                 and any(config["hybrid_layer_pattern"]))
-
-
-def runs(trace, program: str, kernels) -> list:
-    """[(device ns of the execution, summed ns of the kernels' calls in it,
-    the calls)] for every execution of ``program`` that ran one of
-    ``kernels``, on the first device plane that did."""
-    from benchmark import xplane
-    if isinstance(kernels, str):
-        kernels = (kernels,)
-    for name, ops in (trace.ops.items() if trace else ()):
-        hits = sorted((s, e) for n, s, e in ops if is_kernel(n, kernels))
-        if not hits:
-            continue
-        starts = [s for s, _ in hits]
-        out = []
-        for mod, s, e in trace.modules.get(name, []):
-            if xplane.program_name(mod) != program:
-                continue
-            inside = hits[bisect.bisect_left(starts, s):
-                          bisect.bisect_left(starts, e)]
-            if inside:
-                out.append((e - s, sum(b - a for a, b in inside),
-                            len(inside)))
-        return out
-    return []
 
 
 def per_step(facts: dict):
@@ -79,20 +43,10 @@ def attn_roofline(ctx, kind: str, kernel: str):
     over Σ of their device time, in percent."""
     from benchmark import costs_swa
     mean = per_step(ctx.facts)
-    got = runs(ctx.trace, STEP, kernel)
-    if not mean or not got or not is_swa(ctx.config):
+    _, spent, calls = K.totals(K.runs(ctx.trace, K.STEP, kernel))
+    if not mean or not calls or not is_swa(ctx.config):
         return None
     rows = mean["live"] if kind == "full" else mean["window_rows"]
-    least = least_seconds(costs_swa.attn_cost(
+    least = K.least_seconds(costs_swa.attn_cost(
         ctx.config, kind, mean["slots"], rows), ctx.peaks)
-    return (100.0 * least * sum(n for _, _, n in got)
-            / (sum(k for _, k, _ in got) / 1e9))
-
-
-def attn_share(ctx, kernel: str):
-    """The kernel's summed device time inside ``_paged_step`` over the
-    summed device time of the steps that ran it, in percent."""
-    got = runs(ctx.trace, STEP, kernel)
-    if not got:
-        return None
-    return 100.0 * sum(k for _, k, _ in got) / sum(ns for ns, _, _ in got)
+    return 100.0 * least * calls / (spent / 1e9)

@@ -7,7 +7,7 @@ device time.  ``decode_step_roofline`` counts a dense decoder (it would read
 7.9 GB for a step that moves 16.3) and does not list a hybrid cell."""
 
 from benchmark import costs_hybrid, xplane
-from benchmark.layer_metrics import _ssm_trace as T
+from benchmark.layer_metrics import _kernel_trace as T
 from benchmark.layer_metrics.decode_step_dev_ms import PROGRAM
 
 

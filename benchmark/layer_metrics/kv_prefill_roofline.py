@@ -9,15 +9,15 @@ part of a score block and a window step's keys outside the band are the
 kernel's time, not the model's work."""
 
 from benchmark import costs_swa
-from benchmark.layer_metrics import _swa_trace as T
+from benchmark.layer_metrics import _kernel_trace as K, _swa_trace as T
 
 
 def read(ctx):
     lengths = ctx.traffic.get("prompts")
-    got = T.runs(ctx.trace, T.PREFILL, T.PREFILL_KERNELS)
+    got = K.runs(ctx.trace, K.PREFILL, T.PREFILL_KERNELS)
     if not got or not lengths or not T.is_swa(ctx.config):
         return None
     ops = sum(sum(costs_swa.prefill_attn_flops(ctx.config, n).values())
               for n in lengths) / len(lengths)
-    return (100.0 * ops * len(got) / (sum(k for _, k, _ in got) / 1e9)
+    return (100.0 * ops * len(got) / (K.totals(got)[1] / 1e9)
             / ctx.peaks["bf16_flops_per_s"])

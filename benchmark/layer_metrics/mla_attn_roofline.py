@@ -8,15 +8,14 @@ time.  The kernel fetches whole blocks of 128 rows; the rows past a slot's
 position are the kernel's, not the algorithm's."""
 
 from benchmark import costs_mla
-from benchmark.layer_metrics import _mla_trace as T
+from benchmark.layer_metrics import _kernel_trace as K, _mla_trace as T
 
 
 def read(ctx):
     live = ctx.facts.get("live_tokens")
-    runs = T.step_runs(ctx.trace)
-    if live is None or not runs or not T.is_latent(ctx.config):
+    _, spent, calls = K.totals(K.runs(ctx.trace, K.STEP, T.KERNEL))
+    if live is None or not calls or not T.is_latent(ctx.config):
         return None
-    least = T.least_seconds(costs_mla.mla_attn_cost(
+    least = K.least_seconds(costs_mla.mla_attn_cost(
         ctx.config, ctx.facts["slots"], live), ctx.peaks)
-    calls = sum(n for _, _, n in runs)
-    return 100.0 * least * calls / (sum(k for _, k, _ in runs) / 1e9)
+    return 100.0 * least * calls / (spent / 1e9)

@@ -6,18 +6,18 @@ or its operations over the bf16 peak, whichever is more — over the step's
 median device time."""
 
 from benchmark import costs_mla, xplane
-from benchmark.layer_metrics import _mla_trace as T
+from benchmark.layer_metrics import _kernel_trace as K, _mla_trace as T
 
 
 def read(ctx):
     live = ctx.facts.get("live_tokens")
     t = ctx.facts.get("timings") or {}
-    ms = xplane.median_program_ms(ctx.trace, T.STEP) if ctx.trace else None
+    ms = xplane.median_program_ms(ctx.trace, K.STEP) if ctx.trace else None
     if (live is None or not ms or not t.get("steps")
             or not T.is_latent(ctx.config)):
         return None
     slots, steps = ctx.facts["slots"], t["steps"]
-    least = T.least_seconds(
+    least = K.least_seconds(
         (costs_mla.decode_step_bytes(
             ctx.config, slots, live, t.get("moe_experts_touched", 0) / steps),
          costs_mla.decode_step_flops(

@@ -5,8 +5,8 @@ window's mean pairs and touched experts per call, from the program's load
 histogram — each touched expert's three matrices once: memory-bound at 128
 rows) over their device time."""
 
-from benchmark.layer_metrics import _moe_trace as T
+from benchmark.layer_metrics import _kernel_trace as K, _moe_trace as T
 
 
 def read(ctx):
-    return T.experts_roofline(ctx, T.STEP, "")
+    return T.experts_roofline(ctx, K.STEP, "")

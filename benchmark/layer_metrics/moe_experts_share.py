@@ -2,12 +2,12 @@
 device time of ``strom_moe_gmm`` inside ``_paged_step`` over the summed
 device time of the steps that ran it."""
 
-from benchmark.layer_metrics import _moe_trace as T
+from benchmark.layer_metrics import _kernel_trace as K, _moe_trace as T
 
 
 def read(ctx):
-    runs = T.runs(ctx.trace, T.STEP)
-    if not runs:
+    got = K.runs(ctx.trace, K.STEP, T.KERNEL)
+    calls, seconds = T.kernel_seconds(got)
+    if not calls:
         return None
-    total = sum(ns for ns, _ in runs)
-    return 100.0 * T.kernel_seconds(ctx.trace, T.STEP)[1] / (total / 1e9)
+    return 100.0 * seconds / (K.totals(got)[0] / 1e9)

@@ -7,11 +7,11 @@ over the steps' device time.  Device operations of one program run one after
 another, so the span is theirs alone.  The weighted un-permute AFTER the
 product is not in it: XLA fuses it with the residual add that follows."""
 
-from benchmark.layer_metrics import _moe_trace as T
+from benchmark.layer_metrics import _kernel_trace as K, _moe_trace as T
 
 
 def read(ctx):
-    runs = T.runs(ctx.trace, T.STEP)
+    runs = K.runs(ctx.trace, K.STEP, T.KERNEL, every=True)
     if not runs or "num_experts" not in ctx.config:
         return None
     scores = f"f32[{ctx.facts['slots']},{ctx.config['num_experts']}]"
@@ -20,7 +20,7 @@ def read(ctx):
         total += ns
         began, calls = None, 0
         for name, s, _e in run:
-            if T.is_kernel(name):
+            if K.is_call(name, T.KERNEL):
                 calls += 1
                 if calls % 2 and began is not None:   # a layer's first call
                     route += s - began

@@ -6,7 +6,7 @@ of the attention layers) over the chip's HBM bandwidth — or its operations
 over the bf16 peak, whichever is more — over the step's median device time."""
 
 from benchmark import costs_moe, xplane
-from benchmark.layer_metrics import _moe_trace as T
+from benchmark.layer_metrics import _kernel_trace as T
 
 
 def read(ctx):

@@ -9,13 +9,13 @@ peak.  What the program computes beside the model's operations (pad rows, the
 masked half of a score block) counts as time, not as work."""
 
 from benchmark import costs_mla, xplane
-from benchmark.layer_metrics import _mla_trace as T
+from benchmark.layer_metrics import _kernel_trace as K, _mla_trace as T
 
 
 def read(ctx):
     t = ctx.facts.get("timings") or {}
     lengths = ctx.traffic.get("prompts")
-    d = xplane.program_durations_ms(ctx.trace, T.PREFILL) if ctx.trace else []
+    d = xplane.program_durations_ms(ctx.trace, K.PREFILL) if ctx.trace else []
     if (not d or not lengths or not t.get("prompt_tokens")
             or "moe_pairs_prefill" not in t or not T.is_latent(ctx.config)):
         return None

@@ -4,13 +4,13 @@ and operations allow (``costs_hybrid.ssm_scan_cost`` at the call's own padded
 row count, read off its result's shape) over the calls' device time."""
 
 from benchmark import costs_hybrid
-from benchmark.layer_metrics import _ssm_trace as T
+from benchmark.layer_metrics import _kernel_trace as T
 
 KERNEL = "strom_ssm_scan"
 
 
 def read(ctx):
-    calls = T.kernel_events(ctx.trace, KERNEL)
+    calls = T.events(ctx.trace, KERNEL)
     if not calls or "mamba_n_heads" not in ctx.config:
         return None
     least = spent = 0.0

@@ -3,13 +3,13 @@ summed device time over the summed device time of program ``_paged_step`` in
 the traced span — how much of a step is the recurrent state's traffic."""
 
 from benchmark import xplane
-from benchmark.layer_metrics import _ssm_trace as T
+from benchmark.layer_metrics import _kernel_trace as T
 from benchmark.layer_metrics.decode_step_dev_ms import PROGRAM
 from benchmark.layer_metrics.ssm_update_roofline import KERNEL
 
 
 def read(ctx):
-    calls = T.kernel_events(ctx.trace, KERNEL)
+    calls = T.events(ctx.trace, KERNEL)
     steps = xplane.program_durations_ms(ctx.trace, PROGRAM) if ctx.trace \
         else []
     if not calls or not steps:

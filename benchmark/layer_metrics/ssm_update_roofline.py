@@ -4,13 +4,13 @@ and operations allow (``costs_hybrid.ssm_update_cost``: the state read and
 written once each — memory-bound) over its device time."""
 
 from benchmark import costs_hybrid
-from benchmark.layer_metrics import _ssm_trace as T
+from benchmark.layer_metrics import _kernel_trace as T
 
 KERNEL = "strom_ssm_update"
 
 
 def read(ctx):
-    calls = T.kernel_events(ctx.trace, KERNEL)
+    calls = T.events(ctx.trace, KERNEL)
     if not calls or "mamba_n_heads" not in ctx.config:
         return None
     least = T.least_seconds(

@@ -11,13 +11,13 @@ masked part of a score block) counts as time, not as work: the whole
 program's share."""
 
 from benchmark import costs_swa, xplane
-from benchmark.layer_metrics import _swa_trace as T
+from benchmark.layer_metrics import _kernel_trace as K, _swa_trace as T
 
 
 def read(ctx):
     t = ctx.facts.get("timings") or {}
     lengths = ctx.traffic.get("prompts")
-    d = xplane.program_durations_ms(ctx.trace, T.PREFILL) if ctx.trace else []
+    d = xplane.program_durations_ms(ctx.trace, K.PREFILL) if ctx.trace else []
     if (not d or not lengths or not t.get("prompt_tokens")
             or "moe_pairs_prefill" not in t or "window_rows_live" not in t
             or not T.is_swa(ctx.config)):

@@ -7,15 +7,15 @@ operations over the bf16 peak, whichever is more — over the step's median
 device time: the whole step's share."""
 
 from benchmark import costs_swa, xplane
-from benchmark.layer_metrics import _swa_trace as T
+from benchmark.layer_metrics import _kernel_trace as K, _swa_trace as T
 
 
 def read(ctx):
     mean = T.per_step(ctx.facts)
-    ms = xplane.median_program_ms(ctx.trace, T.STEP) if ctx.trace else None
+    ms = xplane.median_program_ms(ctx.trace, K.STEP) if ctx.trace else None
     if not mean or not ms or not T.is_swa(ctx.config):
         return None
-    least = T.least_seconds(
+    least = K.least_seconds(
         (costs_swa.decode_step_bytes(
             ctx.config, mean["slots"], mean["live"], mean["touched"],
             mean["window_rows"]),

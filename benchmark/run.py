@@ -109,6 +109,10 @@ def execute(argv=None, test: dict | None = None) -> tuple:
         if value is not None:
             out["metrics"][m["name"]] = {"value": float(value),
                                          "unit": m["unit"]}
+    # what ``correct`` was decided from, last in the line: each number
+    # compared beside its limit
+    out["checks"] = {name: {"value": value, "limit": limit}
+                     for name, value, limit in res["checks"]}
     return out, ctx
 
 

@@ -180,8 +180,8 @@ def test_traced_run_reports_the_cells_per_layer_metrics():
     nothing and the line leaves them out; the counters are there."""
     out, _ = _run(trace=1)
     assert out["correct"] is True
-    assert {"admit_share.g4hm", "prefill_share.g4hm",
-            "prefill_pad_share.g4hm", "compiles_in_window.g4hm"} \
+    assert {"admit_share.flood", "prefill_share.flood",
+            "prefill_pad_share.flood", "compiles_in_window.flood"} \
         <= set(out["metrics"])
     assert "ssm_update_roofline.g4hm" not in out["metrics"]
 

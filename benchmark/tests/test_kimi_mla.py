@@ -188,11 +188,11 @@ def test_latents_not_carried_into_decode_are_not_correct():
 def test_traced_run_reports_the_counters_and_leaves_the_device_out():
     out, _ = _run(trace=1)
     assert out["correct"] is True
-    assert {"admit_share.k2c", "prefill_share.k2c", "prefill_pad_share.k2c",
-            "compiles_in_window.k2c", "prefill_batch_mean.k2c",
-            "moe_local_pair_share.k2c"} <= set(out["metrics"])
+    assert {"admit_share.flood", "prefill_share.flood", "prefill_pad_share.flood",
+            "compiles_in_window.flood", "prefill_batch_mean.flood",
+            "moe_local_pair_share.flood"} <= set(out["metrics"])
     assert "mla_attn_roofline.k2c" not in out["metrics"]
-    assert 5 < out["metrics"]["moe_local_pair_share.k2c"]["value"] < 60
+    assert 5 < out["metrics"]["moe_local_pair_share.flood"]["value"] < 60
 
 
 # -- the new readers ---------------------------------------------------------
@@ -248,13 +248,13 @@ def test_new_readers_on_a_synthetic_trace():
     assert read("mla_step_roofline.k2c") == pytest.approx(
         100 * (step / 819e9) / 10e-3)
     # busy: the step's 4.7 ms of operations and the prefills' 240 ms
-    assert read("prefill_dev_share.k2c") == pytest.approx(
+    assert read("prefill_dev_share.flood") == pytest.approx(
         100 * 240 / 244.7)
     ops = np.mean([costs_mla.prefill_flops(HF, n, 1.0 * n)
                    for n in (1024, 2048, 4096, 8192)])
     assert read("prefill_mfu.k2c") == pytest.approx(
         100 * 2 * ops / 0.24 / 197e12)
-    assert read("moe_local_pair_share.k2c") == pytest.approx(
+    assert read("moe_local_pair_share.flood") == pytest.approx(
         100 * 6_400 / 204_800)
     for name in ("mla_attn_roofline.k2c", "mla_step_roofline.k2c"):
         assert 0 < read(name) < 100, name
@@ -262,7 +262,7 @@ def test_new_readers_on_a_synthetic_trace():
 
 @pytest.mark.parametrize("name", [
     "mla_attn_roofline.k2c", "mla_attn_share.k2c", "mla_step_roofline.k2c",
-    "prefill_mfu.k2c", "moe_local_pair_share.k2c"])
+    "prefill_mfu.k2c", "moe_local_pair_share.flood"])
 def test_new_readers_find_nothing_where_there_is_nothing(name):
     """No trace, a trace without the kernel (the parent's), a program
     without the counters, and a configuration of another family: None,
@@ -276,7 +276,7 @@ def test_new_readers_find_nothing_where_there_is_nothing(name):
     for ctx in (_ctx(None), _ctx(empty, dense, old), _ctx(None, timings=old),
                 _ctx(empty, dense), _ctx(None, dense, old)):
         assert reader.read(ctx) is None
-    assert harness.plugin("layer_metrics", "prefill_dev_share.k2c").read(
+    assert harness.plugin("layer_metrics", "prefill_dev_share.flood").read(
         _ctx(None)) is None
 
 

@@ -215,11 +215,11 @@ def test_traced_run_reports_the_cells_per_layer_metrics():
     nothing and the line leaves them out; the counters are there."""
     out, _ = _run(trace=1)
     assert out["correct"] is True
-    assert {"admit_share.lfm2", "prefill_share.lfm2",
-            "prefill_pad_share.lfm2", "compiles_in_window.lfm2",
+    assert {"admit_share.flood", "prefill_share.flood",
+            "prefill_pad_share.flood", "compiles_in_window.flood",
             "moe_load_max_over_mean.lfm2", "moe_tile_pad_share.lfm2"} \
         <= set(out["metrics"])
-    assert "moe_experts_roofline.lfm2" not in out["metrics"]
+    assert "moe_experts_roofline.flood" not in out["metrics"]
     assert 1.0 <= out["metrics"]["moe_load_max_over_mean.lfm2"]["value"] <= 8
     assert 0 < out["metrics"]["moe_tile_pad_share.lfm2"]["value"] < 100
 
@@ -274,10 +274,10 @@ def test_new_readers_on_a_synthetic_trace():
     nbytes, flops = costs_moe.experts_cost(HF, 512.0, 60.0)
     assert nbytes / 819e9 > flops / 197e12
     # two layers' calls in the step: 2 x least over (2 + 1 + 2 + 1) ms
-    assert read("moe_experts_roofline.lfm2") == pytest.approx(
+    assert read("moe_experts_roofline.flood") == pytest.approx(
         100 * 2 * (nbytes / 819e9) / 6e-3)
     pb, pf = costs_moe.experts_cost(HF, 1920.0, 64.0)
-    assert read("moe_prefill_experts_roofline.lfm2") == pytest.approx(
+    assert read("moe_prefill_experts_roofline.flood") == pytest.approx(
         100 * max(pb / 819e9, pf / 197e12) / 4e-3)
     assert read("moe_experts_share.lfm2") == pytest.approx(100 * 6 / 40)
     # from the first mention of the scores to the layer's first product
@@ -291,14 +291,14 @@ def test_new_readers_on_a_synthetic_trace():
     assert read("moe_tile_pad_share.lfm2") == pytest.approx(
         100 * (1 - 512_000 / 1_040_000))
     # a share of a roofline stays under 100 % for times a chip could give
-    for name in ("moe_experts_roofline.lfm2", "moe_step_roofline.lfm2",
-                 "moe_prefill_experts_roofline.lfm2"):
+    for name in ("moe_experts_roofline.flood", "moe_step_roofline.lfm2",
+                 "moe_prefill_experts_roofline.flood"):
         assert 0 < read(name) < 100, name
 
 
 @pytest.mark.parametrize("name", [
-    "moe_step_roofline.lfm2", "moe_experts_roofline.lfm2",
-    "moe_prefill_experts_roofline.lfm2", "moe_experts_share.lfm2",
+    "moe_step_roofline.lfm2", "moe_experts_roofline.flood",
+    "moe_prefill_experts_roofline.flood", "moe_experts_share.lfm2",
     "moe_route_share.lfm2", "moe_load_max_over_mean.lfm2",
     "moe_tile_pad_share.lfm2"])
 def test_new_readers_find_nothing_where_there_is_nothing(name):

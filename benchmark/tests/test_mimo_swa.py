@@ -248,12 +248,12 @@ def test_a_reference_side_control_lies_outside_the_limits():
 def test_traced_run_reports_the_counters_and_leaves_the_device_out():
     out, _ = _run(trace=1)
     assert out["correct"] is True
-    assert {"admit_share.k2c", "prefill_share.k2c", "prefill_pad_share.k2c",
-            "compiles_in_window.k2c", "prefill_batch_mean.k2c",
-            "moe_local_pair_share.k2c"} <= set(out["metrics"])
+    assert {"admit_share.flood", "prefill_share.flood", "prefill_pad_share.flood",
+            "compiles_in_window.flood", "prefill_batch_mean.flood",
+            "moe_local_pair_share.flood"} <= set(out["metrics"])
     # no device plane on the CPU: nothing a kernel's time would be read from
     assert not {m for m in out["metrics"] if m.endswith(".mimo")}
-    assert 5 < out["metrics"]["moe_local_pair_share.k2c"]["value"] < 60
+    assert 5 < out["metrics"]["moe_local_pair_share.flood"]["value"] < 60
 
 
 # -- the new readers ---------------------------------------------------------

@@ -153,7 +153,10 @@ def test_every_new_metric_is_in_benchmark_json_under_its_layer():
             ("slice_share", "weight restore (parallel/weights.py)"),
             ("retire_wait_share", "bridge (ops/bridge.py)"),
             ("restore_self_share", "weight restore (parallel/weights.py)")):
-        want.update({f"{base}.restore": layer, f"{base}.restore4": layer})
-    assert len(want) == 20
+        want[f"{base}.restore"] = layer       # one entry, both restore cells
+    assert len(want) == 15
     for name, layer in want.items():
         assert per[name]["layer"] == layer and per[name]["better"] == "lower"
+    for base in ("plan_share", "slice_share"):
+        assert per[f"{base}.restore"]["workloads"] == [
+            "m7b.restore", "m7b-tp4.restore4"]
