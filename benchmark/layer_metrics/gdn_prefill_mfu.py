@@ -1,0 +1,32 @@
+"""The admission programs' model operations over what the chip's bf16 peak
+would do in their device time, for a delta-rule configuration:
+``costs_gdn.prefill_flops`` of one prompt (every matrix on every row, the
+pairs computed here from the program's counter, the full layers' causal half
+once, the recurrence's own count in the delta-rule layers) averaged over the
+prompt lengths the traffic offers — every 4 consecutive requests hold each
+once, so any stretch of the window has that mix — times the prompts the
+traced executions of ``_paged_prefill`` held (the window's mean a program,
+``admits`` over ``prefill_calls``), over their summed device time and the
+peak.  What the program computes beside the model's operations (pad and dead
+rows, the chunked scan's extra products, the masked half of a score block)
+counts as time, not as work: the whole program's share."""
+
+from benchmark import costs_gdn, xplane
+from benchmark.layer_metrics import _kernel_trace as K
+from benchmark.layer_metrics.gdn_update_roofline import is_gdn
+
+
+def read(ctx):
+    t = ctx.facts.get("timings") or {}
+    lengths = ctx.traffic.get("prompts")
+    d = xplane.program_durations_ms(ctx.trace, K.PREFILL) if ctx.trace else []
+    if (not d or not lengths or not t.get("prompt_tokens")
+            or not t.get("prefill_calls") or "moe_pairs_prefill" not in t
+            or not is_gdn(ctx.config)):
+        return None
+    pairs_per_row = t["moe_pairs_prefill"] / t["prompt_tokens"]
+    ops = sum(costs_gdn.prefill_flops(ctx.config, n, pairs_per_row * n)
+              for n in lengths) / len(lengths)
+    prompts = len(d) * t["admits"] / t["prefill_calls"]
+    return 100.0 * ops * prompts / (sum(d) / 1e3) \
+        / ctx.peaks["bf16_flops_per_s"]

@@ -1,0 +1,27 @@
+"""A delta-rule configuration's decode step as a share of its roofline: the
+bytes one step must move (``costs_gdn.decode_step_bytes``: the weights
+outside the routed experts once, the experts the load histogram says were
+touched once each, every slot's recurrent state read AND written, the live K
+and V rows of the full layers) over the chip's HBM bandwidth — or its
+operations over the bf16 peak, whichever is more — over the step's median
+device time: the whole step's share."""
+
+from benchmark import costs_gdn, xplane
+from benchmark.layer_metrics import _kernel_trace as K
+from benchmark.layer_metrics.gdn_update_roofline import is_gdn
+
+
+def read(ctx):
+    t = ctx.facts.get("timings") or {}
+    steps, live = t.get("steps"), ctx.facts.get("live_tokens")
+    ms = xplane.median_program_ms(ctx.trace, K.STEP) if ctx.trace else None
+    if not steps or live is None or not ms or not is_gdn(ctx.config):
+        return None
+    slots = ctx.facts["slots"]
+    least = K.least_seconds(
+        (costs_gdn.decode_step_bytes(
+            ctx.config, slots, live, t.get("moe_experts_touched", 0) / steps),
+         costs_gdn.decode_step_flops(
+            ctx.config, slots, live, t.get("moe_pairs", 0) / steps)),
+        ctx.peaks)
+    return 100.0 * least / (ms / 1e3)

@@ -62,10 +62,10 @@ def prefill_counts(cfg) -> tuple:
     """(parameters a prefill program reads, multiply-adds one prompt row
     costs), from the config's own description of its layers.  The head is
     read once and multiplies one row a prompt, so it counts as read only;
-    the embedding is a gather; a Mamba-2 layer's scan costs a row the same
-    at any length and is counted; attention's scores grow with the length
-    (under 2 % of a row at the lengths a block table holds) and are not;
-    norms, biases and conv taps are left out on both sides."""
+    the embedding is a gather; a Mamba-2 or delta-rule layer's scan costs a
+    row the same at any length and is counted; attention's scores grow with
+    the length (under 2 % of a row at the lengths a block table holds) and
+    are not; norms, biases and conv taps are left out on both sides."""
     d, hd = cfg.d_model, cfg.head_dim
     read = rowops = 0
     for i in range(cfg.n_layers):
@@ -80,6 +80,16 @@ def prefill_counts(cfg) -> tuple:
                        * (cfg.ssm_chunk + 2 * cfg.ssm_state))
         elif kind == "conv":
             mix = 4 * d * d
+        elif kind == "gdn":
+            value = cfg.gdn_v_heads * cfg.gdn_v_dim
+            mix = d * (cfg.gdn_conv_dim + value + 2 * cfg.gdn_v_heads) \
+                + value * d
+            # the chunked scan's products a row a value head (ops/gdn.py):
+            # K Kᵀ and Q Kᵀ, the masked product with U, and three products
+            # against the state (W S, Q S, the chunk's own)
+            rowops += cfg.gdn_v_heads * (
+                cfg.gdn_chunk * (2 * cfg.gdn_k_dim + cfg.gdn_v_dim)
+                + 3 * cfg.gdn_k_dim * cfg.gdn_v_dim)
         elif cfg.latent:
             mix = (d * cfg.q_lora_rank + cfg.q_lora_rank * cfg.n_heads
                    * (cfg.qk_nope_dim + cfg.qk_rope_dim)
@@ -87,8 +97,9 @@ def prefill_counts(cfg) -> tuple:
                    * (cfg.qk_nope_dim + cfg.v_head_dim)
                    + cfg.n_heads * cfg.v_head_dim * d)
         else:
-            # wq and wk at the key width, wv and wo at the value width
-            mix = d * ((hd + cfg.v_dim) * cfg.n_heads
+            # wq and wk at the key width, wv and wo at the value width (wq
+            # twice as wide where it brings the output gate)
+            mix = d * ((hd * (1 + cfg.attn_gate) + cfg.v_dim) * cfg.n_heads
                        + (hd + cfg.v_dim) * cfg.kv_heads(i))
         read += mix
         rowops += mix

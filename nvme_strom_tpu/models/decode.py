@@ -25,7 +25,8 @@ from jax import lax
 from nvme_strom_tpu.models.transformer import (
     wmat,
     TransformerConfig, add_residual, attention, embed_tokens,
-    expand_gqa, lm_logits, mlp, qkv_project, rms_norm, valid_rows)
+    expand_gqa, gate_heads, lm_logits, mlp, qkv_project, qkvg_project,
+    rms_norm, valid_rows)
 from nvme_strom_tpu.models import mla as _mla
 from nvme_strom_tpu.models import moe as _moe
 
@@ -214,6 +215,7 @@ def decode_step(params: Dict, token: jax.Array, cfg: TransformerConfig,
 MIXER_SCOPES = {"attention": ("strom.attn.proj", "strom.attn.out"),
                 "window": ("strom.attn.proj", "strom.attn.out"),
                 "mamba": ("strom.ssm.proj", "strom.ssm.out"),
+                "gdn": ("strom.ssm.proj", "strom.ssm.out"),
                 "conv": ("strom.conv", "strom.conv")}
 
 
@@ -280,7 +282,7 @@ def _blocked_attention(h, params, L, cfg, k_l, v_l, pos, win):
         hc, first = hc_first
         rows = hc.shape[1]
         with jax.named_scope(before):
-            q, k, v = qkv_project(
+            q, k, v, g = qkvg_project(
                 hc, params, L, cfg, positions=first.astype(jnp.float32)
                 + jnp.arange(rows, dtype=jnp.float32))
         with jax.named_scope("strom.attn.window" if win
@@ -293,7 +295,7 @@ def _blocked_attention(h, params, L, cfg, k_l, v_l, pos, win):
                 q, k_l, v_l, first, scale=scale,
                 window=cfg.window if win else 0, sink=params.get(L + "sink"))
         with jax.named_scope(after):
-            a = a.transpose(0, 2, 1, 3).reshape(b, rows, -1)
+            a = gate_heads(a.transpose(0, 2, 1, 3).reshape(b, rows, -1), g)
             a = a @ wmat(params, L + "wo", a.dtype)
         return (k_l, v_l), a
 
@@ -349,7 +351,9 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     ssm = cache.get("ssm")
     states, tails = (list(ssm["s"]), list(ssm["conv"])) if ssm else ([], [])
     calls = []            # the expert layers' (counts, work)
-    ai = mi = ti = wi = 0     # this layer's place among its kind's caches
+    # this layer's place among its kind's caches: attention, a recurrent
+    # layer's state matrix, its conv tail, a window layer's ring
+    ai = mi = ti = wi = 0
     # ``cache["ring_rows"]`` (a server's prefill from an EMPTY cache): the
     # window layers keep no dense cache, and ``cache["wk"]`` / ``["wv"]``
     # come back as what their rings hold, (Lw, b, kv_heads, ring_rows, d)
@@ -368,6 +372,12 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
         elif cfg.mixer(i) == "conv":
             from nvme_strom_tpu.models.ssm import conv_block
             a, tails[ti] = conv_block(h, params, L, cfg, tails[ti], n_valid)
+            ti += 1
+        elif cfg.mixer(i) == "gdn":
+            from nvme_strom_tpu.models.ssm import gdn_block
+            a, states[mi], tails[ti] = gdn_block(
+                h, params, L, cfg, states[mi], tails[ti], n_valid)
+            mi += 1
             ti += 1
         elif cfg.latent:
             # the expanded form over the cached latent rows, the block's

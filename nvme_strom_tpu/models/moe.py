@@ -322,7 +322,8 @@ def expert_mlp(x: jax.Array, p: dict, prefix: str, cfg, valid=None) -> tuple:
     by that share (``pair_bound``), and a call whose local pairs exceed it
     takes further rounds.  The weights are normalised over all the selected
     experts, held or not.  A shared expert (``cfg.d_shared``) takes every
-    row beside the routed ones."""
+    row beside the routed ones, under its own sigmoid gate where the config
+    has one (``cfg.shared_gate``)."""
     from nvme_strom_tpu.ops import moe as _ops
     b, s, d = x.shape
     T, k, held = b * s, cfg.expert_top_k, cfg.experts_local
@@ -344,7 +345,15 @@ def expert_mlp(x: jax.Array, p: dict, prefix: str, cfg, valid=None) -> tuple:
     out = out.astype(x.dtype).reshape(b, s, d)
     if cfg.d_shared:
         with jax.named_scope("strom.moe.shared"):
-            out = out + _tr.mlp(x, p, prefix + "shared_")
+            shared = _tr.mlp(x, p, prefix + "shared_")
+            if cfg.shared_gate:
+                # one scalar a row: sigmoid(x . w), float32
+                gate = jax.nn.sigmoid(jnp.einsum(
+                    "bsd,de->bse", x, _tr.wmat(p, prefix + "shared_gate",
+                                               x.dtype),
+                    preferred_element_type=jnp.float32))
+                shared = (shared.astype(jnp.float32) * gate).astype(x.dtype)
+            out = out + shared
     return out, counts, jnp.stack([rows, rounds]).astype(jnp.int32)
 
 
@@ -396,6 +405,8 @@ def init_moe_params(keys, cfg, prefix: str, dense) -> dict:
             prefix + "shared_w_gate": dense(next(keys), dm, (dm, ds)),
             prefix + "shared_w_up": dense(next(keys), dm, (dm, ds)),
             prefix + "shared_w_down": dense(next(keys), ds, (ds, dm))})
+        if cfg.shared_gate:
+            out[prefix + "shared_gate"] = dense(next(keys), dm, (dm, 1))
     return out
 
 

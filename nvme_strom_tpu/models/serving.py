@@ -73,7 +73,7 @@ from nvme_strom_tpu.models import ssm as _ssm
 from nvme_strom_tpu.models.decode import _mlp_block
 from nvme_strom_tpu.models.transformer import (
     TransformerConfig, add_residual, embed_tokens, lm_logits,
-    qkv_project, rms_norm, wmat)
+    gate_heads, qkvg_project, rms_norm, wmat)
 
 
 @dataclass
@@ -387,7 +387,10 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     s_pools, tails = ((list(state["s"]), list(state["conv"]))
                       if cfg.recurrent_layers else ([], []))
     calls = []              # the expert layers' (counts, work)
-    ai = mi = ti = wi = 0   # attention / mamba / tail-keeping / window layers
+    # this layer's place among its kind's caches: attention pages, a
+    # recurrent layer's state matrix (mamba, gdn), its conv tail (mamba,
+    # gdn, conv), a window layer's ring
+    ai = mi = ti = wi = 0
     # every operation under one family of scopes, the same partition as
     # the prefill's (``decode.MIXER_SCOPES``, docs/OBSERVABILITY.md)
     for i in range(cfg.n_layers):
@@ -402,6 +405,11 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
             ti += 1
         elif cfg.mixer(i) == "conv":
             a, tails[ti] = _ssm.conv_step(h, params, L, cfg, tails[ti], sidx)
+            ti += 1
+        elif cfg.mixer(i) == "gdn":
+            a, s_pools[mi], tails[ti] = _ssm.gdn_step(
+                h, params, L, cfg, s_pools[mi], tails[ti], sidx)
+            mi += 1
             ti += 1
         elif cfg.latent:
             with jax.named_scope("strom.attn.mla"):
@@ -420,7 +428,8 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
         else:
             win = cfg.mixer(i) == "window"
             with jax.named_scope(before):
-                q, k, v = qkv_project(h, params, L, cfg, positions=positions)
+                q, k, v, g = qkvg_project(h, params, L, cfg,
+                                          positions=positions)
             with jax.named_scope("strom.attn.window" if win
                                  else "strom.attn.paged"):
                 # the new rows go into the (donated) pools where they lie
@@ -443,7 +452,7 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
                     a = paged_attention(q, k_pool, v_pool, table, attn_pos,
                                         layer=ai, scale=cfg.attn_scale)
             with jax.named_scope(after):
-                a = a.transpose(0, 2, 1, 3).reshape(B, 1, -1)
+                a = gate_heads(a.transpose(0, 2, 1, 3).reshape(B, 1, -1), g)
                 a = a @ wmat(params, L + "wo", a.dtype)
             wi, ai = wi + win, ai + (not win)
         with jax.named_scope(after):
@@ -1449,6 +1458,10 @@ class DecodeServer:
             [self.state[k] for k in ("s", "conv")] if self.state else None)
         out["state_bytes"] = sum(a.nbytes for a in state)
         out["state_slots"] = self.B + 1 if state else 0
+        # one sequence's share of it, whatever its length, and the layers
+        # that carry it (beside ``kv_layers``, the layers that keep pages)
+        out["state_bytes_per_slot"] = out["state_bytes"] // (self.B + 1)
+        out["state_layers"] = len(self.cfg.recurrent_layers)
         # layers whose MLP is the exact expert layer, and what they routed
         # (decode steps; the prefill's own under *_prefill in timings)
         out["moe_layers"] = len(self.cfg.expert_layers)

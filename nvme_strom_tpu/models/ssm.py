@@ -1,5 +1,6 @@
-"""The recurrent mixers of a hybrid decoder — Mamba-2 and the gated short
-convolution — and the state a sequence carries through them.
+"""The recurrent mixers of a hybrid decoder — Mamba-2, the gated short
+convolution and the gated delta rule — and the state a sequence carries
+through them.
 
 Mamba-2:
 
@@ -20,16 +21,34 @@ operator, ``conv_block`` / ``conv_step`` at the end of this file):
                                            no bias, no activation
     out  = (C ⊙ c) W_out
 
-What a sequence carries from one call to the next is, for Mamba-2, ``S`` and
-the last K−1 rows of ``u`` before the conv; for the short conv the last K−1
-rows of ``v``.  ``init_state`` holds both kinds: ``"s"`` one array per
-Mamba-2 layer, ``"conv"`` one tail per recurrent layer of either kind, in
-layer order.  ``mamba_block`` runs a block of rows
-(prefill, a full forward) through ``ops.ssm.ssm_scan``; ``mamba_step`` runs
-one token of every serving slot through ``ops.ssm.ssm_update`` against the
-server's state pool.  Parameter leaves of layer ``L``: ``ssm_in`` (d, 2·inner
+The gated delta rule (``layer_kinds`` "gdn"; the Qwen3-Next family's linear
+layer, ``gdn_block`` / ``gdn_step`` at the end of this file): Hk key heads
+and Hv value heads, value head j reading key head j // (Hv / Hk):
+
+    [q, k, v, z] = split(h W_in)           widths Hk·dk | Hk·dk | Hv·dv | Hv·dv
+    [b, a] = split(h W_ba)                 Hv | Hv
+    [q, k, v]_t = silu(Σ_j w_conv[j] ⊙ [q, k, v]_{t-K+1+j})   depthwise,
+                                           causal, no bias
+    q̃ = q / sqrt(Σq² + 1e-6) / sqrt(dk)   k̃ = k / sqrt(Σk² + 1e-6)   per head
+    β_t = sigmoid(b_t)   α_t = exp(−exp(A_log) · softplus(a_t + dt_bias))
+    S ← α_t S;  u = β_t (v_t − Sᵀ k̃_t);  S ← S + k̃_t ⊗ u;  o_t = Sᵀ q̃_t
+                                           S: (Hv, dk, dv) float32
+    out  = (g_norm ⊙ o / sqrt(mean o² + eps) ⊙ silu(z)) W_out   per head
+
+What a sequence carries from one call to the next is, for Mamba-2 and the
+delta rule, ``S`` and the last K−1 rows of what their conv sees (``u``; q | k
+| v); for the short conv the last K−1 rows of ``v``.  ``init_state`` holds
+every kind: ``"s"`` one array per Mamba-2 or delta-rule layer, ``"conv"``
+one tail per recurrent layer of any kind, in layer order.  ``mamba_block``
+runs a block of rows (prefill, a full forward) through
+``ops.ssm.ssm_scan``; ``mamba_step`` runs one token of every serving slot
+through ``ops.ssm.ssm_update`` against the server's state pool
+(``gdn_block`` / ``gdn_step``: ``ops.gdn.gdn_scan`` / ``gdn_update``).  Parameter leaves of layer ``L``: ``ssm_in`` (d, 2·inner
 + 2N + H), ``ssm_conv_w`` (K, inner + 2N), ``ssm_conv_b``, ``ssm_dt_bias``,
-``ssm_A_log``, ``ssm_D`` (H,), ``ssm_norm`` (inner,), ``ssm_out`` (inner, d).
+``ssm_A_log``, ``ssm_D`` (H,), ``ssm_norm`` (inner,), ``ssm_out`` (inner, d);
+of a delta-rule layer ``gdn_in`` (d, 2·Hk·dk + 2·Hv·dv), ``gdn_ba`` (d, 2·Hv),
+``gdn_conv_w`` (K, 2·Hk·dk + Hv·dv), ``gdn_dt_bias``, ``gdn_A_log`` (Hv,),
+``gdn_norm`` (dv,), ``gdn_out`` (Hv·dv, d).
 """
 
 from __future__ import annotations
@@ -42,6 +61,7 @@ import numpy as np
 
 from nvme_strom_tpu.models.transformer import (TransformerConfig, rms_norm,
                                                valid_rows, wmat)
+from nvme_strom_tpu.ops.gdn import gdn_scan, gdn_update
 from nvme_strom_tpu.ops.ssm import ssm_scan, ssm_update
 
 
@@ -69,20 +89,24 @@ def init_mamba_params(keys, cfg: TransformerConfig, L: str, dense) -> Dict:
 
 def init_state(cfg: TransformerConfig, rows: int) -> Dict:
     """Zeroed recurrent state for ``rows`` sequences, whatever each mixer
-    declares per sequence: per mamba layer one ``S`` (rows, H, P, N) float32
-    under ``"s"``, and per recurrent layer of either kind one conv tail under
-    ``"conv"`` — (rows, K−1, inner + 2N) for Mamba-2, (rows, conv_taps − 1,
-    d_model) for the short conv — in layer order.  Tuples of per-layer
+    declares per sequence.  Under ``"s"`` one state per layer that keeps one
+    (``cfg.state_layers``), float32: (rows, H, P, N) for Mamba-2, (rows, Hv,
+    dk, dv) for the delta rule.  Under ``"conv"`` one conv tail per
+    recurrent layer of any kind: (rows, K−1, inner + 2N) for Mamba-2, (rows,
+    gdn_conv − 1, 2·Hk·dk + Hv·dv) for the delta rule, (rows, conv_taps − 1,
+    d_model) for the short conv.  Both in layer order.  Tuples of per-layer
     arrays, never one stacked array: each is donated to the step and updated
     in place, and indexing a stacked one by layer would copy the lot (the KV
     pool's copies in PERF.md §5)."""
-    s = (rows, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
-    tails = [(rows, cfg.ssm_conv - 1, cfg.ssm_conv_dim)
-             if cfg.is_mamba_layer(i) else (rows, cfg.conv_taps - 1,
-                                            cfg.d_model)
-             for i in cfg.recurrent_layers]
-    return {"s": tuple(jnp.zeros(s, jnp.float32) for _ in cfg.mamba_layers),
-            "conv": tuple(jnp.zeros(t, cfg.dtype) for t in tails)}
+    s = {"mamba": (rows, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+         "gdn": (rows, cfg.gdn_v_heads, cfg.gdn_k_dim, cfg.gdn_v_dim)}
+    tail = {"mamba": (rows, cfg.ssm_conv - 1, cfg.ssm_conv_dim),
+            "gdn": (rows, cfg.gdn_conv - 1, cfg.gdn_conv_dim),
+            "conv": (rows, cfg.conv_taps - 1, cfg.d_model)}
+    return {"s": tuple(jnp.zeros(s[cfg.mixer(i)], jnp.float32)
+                       for i in cfg.state_layers),
+            "conv": tuple(jnp.zeros(tail[cfg.mixer(i)], cfg.dtype)
+                          for i in cfg.recurrent_layers)}
 
 
 def _tail_at(window, n_valid, k1: int):
@@ -246,3 +270,128 @@ def conv_step(h, p: Dict, L: str, cfg: TransformerConfig, tail_pool, sidx):
         y = (c * conv.astype(c.dtype)) @ wmat(p, L + "conv_out", c.dtype)
         y = y[:, None]
     return y, tail_pool
+
+
+# -------------------------------------------------- the gated delta rule
+
+def init_gdn_params(keys, cfg: TransformerConfig, L: str, dense) -> Dict:
+    """A and Δ's bias as Mamba-2 initialises them (``init_mamba_params``)."""
+    d, H = cfg.d_model, cfg.gdn_v_heads
+    conv, value = cfg.gdn_conv_dim, cfg.gdn_v_heads * cfg.gdn_v_dim
+    dt = jnp.exp(jax.random.uniform(next(keys), (H,), jnp.float32,
+                                    np.log(1e-3), np.log(1e-1)))
+    return {
+        L + "gdn_in": dense(next(keys), d, (d, conv + value)),
+        L + "gdn_ba": dense(next(keys), d, (d, 2 * H)),
+        L + "gdn_conv_w": dense(next(keys), cfg.gdn_conv,
+                                (cfg.gdn_conv, conv)),
+        L + "gdn_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        L + "gdn_A_log": jnp.log(jax.random.uniform(
+            next(keys), (H,), jnp.float32, 1.0, 16.0)),
+        L + "gdn_norm": jnp.ones((cfg.gdn_v_dim,), jnp.float32),
+        L + "gdn_out": dense(next(keys), value, (value, d)),
+    }
+
+
+def _gdn_project(h, p, L, cfg):
+    """h (..., d) -> (u (..., conv) what the conv sees: q | k | v, z (...,
+    Hv, dv), β and log α (..., Hv) float32)."""
+    conv = cfg.gdn_conv_dim
+    uz = h @ wmat(p, L + "gdn_in", h.dtype)
+    ba = (h @ wmat(p, L + "gdn_ba", h.dtype)).astype(jnp.float32)
+    H = cfg.gdn_v_heads
+    beta = jax.nn.sigmoid(ba[..., :H])
+    # the decay by its logarithm: a head that forgets at once has α = 0 in
+    # float32, and the scan works in differences of log α
+    log_alpha = -jnp.exp(p[L + "gdn_A_log"].astype(jnp.float32)) \
+        * jax.nn.softplus(ba[..., H:]
+                          + p[L + "gdn_dt_bias"].astype(jnp.float32))
+    z = uz[..., conv:].reshape(*uz.shape[:-1], H, cfg.gdn_v_dim)
+    return uz[..., :conv], z, beta, log_alpha
+
+
+def _gdn_heads(u, cfg):
+    """conv output (..., conv) float32, after its silu -> q̃, k̃ (..., Hv,
+    dk) — L2-normalised per KEY head, q scaled, each repeated over the value
+    heads that read it — and v (..., Hv, dv), all float32."""
+    hk, dk = cfg.gdn_k_heads, cfg.gdn_k_dim
+    key = hk * dk
+    lead = u.shape[:-1]
+
+    def unit(t):
+        t = t.reshape(*lead, hk, dk)
+        return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+
+    rep = cfg.gdn_v_heads // hk
+    q = jnp.repeat(unit(u[..., :key]) * dk ** -0.5, rep, axis=-2)
+    k = jnp.repeat(unit(u[..., key:2 * key]), rep, axis=-2)
+    v = u[..., 2 * key:].reshape(*lead, cfg.gdn_v_heads, cfg.gdn_v_dim)
+    return q, k, v
+
+
+def _gdn_out(o, z, p, L, cfg):
+    """o (..., Hv, dv) from the recurrence -> the mixer's output: the
+    per-head RMS norm (its weight as stored), silu(z)'s gate, W_out."""
+    zf = z.astype(jnp.float32)
+    y = rms_norm(o.astype(jnp.float32), p[L + "gdn_norm"], cfg.norm_eps)
+    y = (y * (zf * jax.nn.sigmoid(zf))).astype(z.dtype)
+    y = y.reshape(*y.shape[:-2], -1)
+    return y @ wmat(p, L + "gdn_out", y.dtype)
+
+
+def gdn_block(h, p: Dict, L: str, cfg: TransformerConfig, s0=None,
+              tail=None, n_valid=None):
+    """A block of rows through the delta-rule mixer.  h (b, m, d)
+    post-norm; s0 (b, Hv, dk, dv) float32 and tail (b, K−1, conv): what the
+    sequences carried in (None: zeros); n_valid as in ``mamba_block``.
+    Returns (out (b, m, d), S, tail)."""
+    b, m, _ = h.shape
+    k1 = cfg.gdn_conv - 1
+    with jax.named_scope("strom.ssm.proj"):
+        u, z, beta, log_alpha = _gdn_project(h, p, L, cfg)
+        if tail is None:
+            tail = jnp.zeros((b, k1, cfg.gdn_conv_dim), u.dtype)
+        if s0 is None:
+            s0 = jnp.zeros((b, cfg.gdn_v_heads, cfg.gdn_k_dim,
+                            cfg.gdn_v_dim), jnp.float32)
+        window = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+        w = p[L + "gdn_conv_w"].astype(jnp.float32)
+        conv = sum(w[j] * window[:, j:j + m].astype(jnp.float32)
+                   for j in range(cfg.gdn_conv))
+        # the scan's products take the activations' type on the MXU
+        q, k, v = (t.astype(h.dtype)
+                   for t in _gdn_heads(jax.nn.silu(conv), cfg))
+        valid = None
+        if n_valid is None:
+            new_tail = window[:, m:]
+        else:
+            valid = valid_rows(n_valid, b, m)
+            new_tail = _tail_at(window, n_valid, k1)
+    with jax.named_scope("strom.ssm.scan"):
+        o, s = gdn_scan(q, k, v, log_alpha, beta, s0, valid,
+                        chunk=cfg.gdn_chunk)
+    with jax.named_scope("strom.ssm.out"):
+        out = _gdn_out(o, z, p, L, cfg)
+    return out, s, new_tail
+
+
+def gdn_step(h, p: Dict, L: str, cfg: TransformerConfig, s_pool, tail_pool,
+             sidx):
+    """One token of every slot through the delta-rule mixer, against the
+    server's pools.  h (B, 1, d); s_pool (rows, Hv, dk, dv) float32 and
+    tail_pool (rows, K−1, conv), both updated in place when donated; sidx
+    (B,) each slot's row.  Returns (out (B, 1, d), s_pool, tail_pool)."""
+    with jax.named_scope("strom.ssm.proj"):
+        u, z, beta, log_alpha = _gdn_project(h[:, 0], p, L, cfg)
+        window = jnp.concatenate(
+            [tail_pool[sidx].astype(u.dtype), u[:, None]], axis=1)  # (B,K,C)
+        tail_pool = tail_pool.at[sidx].set(
+            window[:, 1:].astype(tail_pool.dtype))
+        conv = jnp.einsum("bkc,kc->bc", window.astype(jnp.float32),
+                          p[L + "gdn_conv_w"].astype(jnp.float32))
+        q, k, v = _gdn_heads(jax.nn.silu(conv), cfg)
+    with jax.named_scope("strom.ssm.update"):
+        o, s_pool = gdn_update(s_pool, sidx, q, k, v, log_alpha, beta)
+    with jax.named_scope("strom.ssm.out"):
+        out = _gdn_out(o, z, p, L, cfg)[:, None]
+    return out, s_pool, tail_pool

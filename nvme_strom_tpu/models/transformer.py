@@ -22,6 +22,12 @@ import numpy as np
 from nvme_strom_tpu.models import moe as _moe
 
 
+#: what ``layer_kinds`` may name, and those of them that carry a state per
+#: sequence other than K/V (models/ssm.py)
+MIXER_KINDS = ("attention", "window", "mamba", "conv", "gdn")
+RECURRENT_KINDS = ("mamba", "conv", "gdn")
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 32000
@@ -67,8 +73,8 @@ class TransformerConfig:
     xent_chunks: int = 0
     # The per-layer description, read off the model's own config
     # (tools/convert_llama.config_from_hf): the mixer of every layer
-    # ("attention", "window" — below —, "mamba" or "conv": models/ssm.py)
-    # and its MLP
+    # ("attention", "window" — below —, "mamba", "conv" or "gdn":
+    # models/ssm.py) and its MLP
     # ("dense" at d_ff, or "experts" at d_expert: the exact expert layer
     # of models/moe.py).  Empty layer_kinds == attention in every layer;
     # empty mlp_kinds == dense everywhere, or, with n_experts > 0, the
@@ -141,6 +147,23 @@ class TransformerConfig:
     window_kv_heads: int = 0
     window_rope_theta: float = 0.0
     window_sink: bool = False
+    # A "gdn" entry of ``layer_kinds`` is a gated-delta-rule layer
+    # (models/ssm.py): ``gdn_k_heads`` key heads of ``gdn_k_dim`` and
+    # ``gdn_v_heads`` value heads of ``gdn_v_dim`` (value head j reads key
+    # head j // (gdn_v_heads // gdn_k_heads)), a causal conv of ``gdn_conv``
+    # taps over q | k | v, the prefill's scan in chunks of ``gdn_chunk`` rows.
+    gdn_k_heads: int = 0
+    gdn_v_heads: int = 0
+    gdn_k_dim: int = 0
+    gdn_v_dim: int = 0
+    gdn_conv: int = 4
+    gdn_chunk: int = 64
+    # ``attn_gate``: an attention layer's query projection is twice as wide,
+    # a head at a time (q | g), and sigmoid(g) multiplies the heads' output
+    # before W_o.  ``shared_gate``: the shared expert's output is multiplied
+    # by sigmoid(x . w), one scalar a row (leaf "shared_gate", (d, 1)).
+    attn_gate: bool = False
+    shared_gate: bool = False
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -150,12 +173,10 @@ class TransformerConfig:
         kinds = tuple(self.layer_kinds)
         object.__setattr__(self, "layer_kinds", kinds)
         if kinds:
-            if len(kinds) != self.n_layers or set(kinds) - {
-                    "attention", "window", "mamba", "conv"}:
+            if len(kinds) != self.n_layers or set(kinds) - set(MIXER_KINDS):
                 raise ValueError(
-                    f"layer_kinds must name 'attention', 'window', 'mamba' "
-                    f"or 'conv' for each of the {self.n_layers} layers, got "
-                    f"{kinds}")
+                    f"layer_kinds must name one of {MIXER_KINDS} for each of "
+                    f"the {self.n_layers} layers, got {kinds}")
             if "window" in kinds and (self.window < 1 or self.latent):
                 raise ValueError("window layers need window >= 1 and K/V "
                                  "attention (no kv_lora_rank)")
@@ -163,6 +184,13 @@ class TransformerConfig:
                                          and self.ssm_state):
                 raise ValueError("mamba layers need ssm_heads, ssm_head_dim "
                                  "and ssm_state")
+            if "gdn" in kinds and not (
+                    self.gdn_k_heads and self.gdn_k_dim and self.gdn_v_dim
+                    and self.gdn_v_heads
+                    and self.gdn_v_heads % self.gdn_k_heads == 0):
+                raise ValueError("gdn layers need gdn_k_heads, gdn_k_dim, "
+                                 "gdn_v_dim and gdn_v_heads, a multiple of "
+                                 "gdn_k_heads")
         mlps = tuple(self.mlp_kinds)
         object.__setattr__(self, "mlp_kinds", mlps)
         if mlps:
@@ -208,7 +236,7 @@ class TransformerConfig:
         return (0 if self.latent else self.v_head_dim) or self.head_dim
 
     def mixer(self, i: int) -> str:
-        """Layer ``i``'s mixer: "attention", "window", "mamba" or "conv"."""
+        """Layer ``i``'s mixer, one of ``MIXER_KINDS``."""
         return self.layer_kinds[i] if self.layer_kinds else "attention"
 
     def kv_heads(self, i: int) -> int:
@@ -231,11 +259,12 @@ class TransformerConfig:
     @property
     def stated_kv(self) -> bool:
         """Whether K/V attention's geometry is the config's own — stated
-        head widths, partial rotary, a value scale or window layers: such a
+        head widths, partial rotary, a value scale, an output gate or window
+        layers: such a
         config attends through the blocked kernels (ops/kv_prefill.py) and
         is served by ``DecodeServer`` alone."""
         return bool(self.qk_head_dim or self.rotary_dim or self.window_layers
-                    or self.value_scale != 1.0
+                    or self.value_scale != 1.0 or self.attn_gate
                     or (self.v_head_dim and not self.latent))
 
     def mlp_kind(self, i: int) -> str:
@@ -263,10 +292,18 @@ class TransformerConfig:
     @property
     def recurrent_layers(self) -> tuple:
         """Indices of the layers that carry something per sequence other
-        than K/V — a Mamba-2 state and conv tail, a short conv's tail — in
-        layer order: what ``models/ssm.init_state`` holds a row of."""
+        than K/V — a Mamba-2 or delta-rule state and conv tail, a short
+        conv's tail — in layer order: what ``models/ssm.init_state`` holds a
+        row of."""
         return tuple(i for i, k in enumerate(self.layer_kinds)
-                     if k in ("mamba", "conv"))
+                     if k in RECURRENT_KINDS)
+
+    @property
+    def state_layers(self) -> tuple:
+        """Indices of the recurrent layers that keep a state matrix beside
+        their conv tail (``init_state``'s ``"s"``), in layer order."""
+        return tuple(i for i, k in enumerate(self.layer_kinds)
+                     if k in ("mamba", "gdn"))
 
     @property
     def expert_layers(self) -> tuple:
@@ -331,12 +368,18 @@ class TransformerConfig:
         """Width of what the causal conv sees: x | B | C."""
         return self.ssm_inner + 2 * self.ssm_state
 
+    @property
+    def gdn_conv_dim(self) -> int:
+        """Width of what a delta-rule layer's causal conv sees: q | k | v."""
+        return (2 * self.gdn_k_heads * self.gdn_k_dim
+                + self.gdn_v_heads * self.gdn_v_dim)
+
     def require_no_recurrent(self, what: str) -> None:
         """The one message of everything that holds K/V pages only."""
         if self.recurrent_layers:
             raise NotImplementedError(
-                f"{what} does not carry the recurrent state of mamba or "
-                f"conv layers (layer_kinds has "
+                f"{what} does not carry the recurrent state of "
+                f"{' or '.join(RECURRENT_KINDS)} layers (layer_kinds has "
                 f"{len(self.recurrent_layers)}); serve "
                 f"this config from DecodeServer on one device, "
                 f"without a kv_store, a mesh or session hand-off")
@@ -391,6 +434,9 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
         elif cfg.mixer(i) == "conv":
             from nvme_strom_tpu.models.ssm import init_conv_params
             p.update(init_conv_params(keys, cfg, L, dense))
+        elif cfg.mixer(i) == "gdn":
+            from nvme_strom_tpu.models.ssm import init_gdn_params
+            p.update(init_gdn_params(keys, cfg, L, dense))
         elif cfg.latent:
             from nvme_strom_tpu.models.mla import init_mla_params
             p.update(init_mla_params(keys, cfg, L, dense))
@@ -399,8 +445,9 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
                 p[L + "q_norm"] = jnp.ones((hd,), jnp.float32)
                 p[L + "k_norm"] = jnp.ones((hd,), jnp.float32)
             nkv = cfg.kv_heads(i)
+            # with an output gate a head's columns are (q | g)
             p[L + "wq"] = dense(next(keys), cfg.d_model,
-                                (cfg.d_model, nh * hd))
+                                (cfg.d_model, nh * hd * (1 + cfg.attn_gate)))
             p[L + "wk"] = dense(next(keys), cfg.d_model,
                                 (cfg.d_model, nkv * hd))
             p[L + "wv"] = dense(next(keys), cfg.d_model,
@@ -793,6 +840,13 @@ def qkv_project(x, p, prefix, cfg: TransformerConfig, positions=None):
     """Shared QKV projection + RoPE.  Returns q (b, nh, s, hd) and k/v at
     kv-head width (b, n_kv_heads, s, hd) — pre-GQA-expansion, which is the
     shape the decode KV cache stores (models/decode.py)."""
+    return qkvg_project(x, p, prefix, cfg, positions)[:3]
+
+
+def qkvg_project(x, p, prefix, cfg: TransformerConfig, positions=None):
+    """``qkv_project`` and, for a config with ``attn_gate``, the output
+    gate's logits g (b, s, nh * hd) that the query projection brings beside
+    each head's query (None without): (q, k, v, g)."""
     b, s, _ = x.shape
     layer = int(prefix.split(".")[1])
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.kv_heads(layer)
@@ -806,6 +860,10 @@ def qkv_project(x, p, prefix, cfg: TransformerConfig, positions=None):
         x @ wmat(p, prefix + name, x.dtype) for name in ("wq", "wk", "wv")))
     if cfg.value_scale != 1.0:
         v = v * jnp.asarray(cfg.value_scale, v.dtype)
+    g = None
+    if cfg.attn_gate:
+        q = q.reshape(b, s, nh, 2 * hd)
+        q, g = q[..., :hd], q[..., hd:].reshape(b, s, nh * hd)
     q = q.reshape(b, s, nh, hd)
     k = k.reshape(b, s, nkv, hd)
     v = v.reshape(b, s, nkv, cfg.v_dim)
@@ -821,7 +879,16 @@ def qkv_project(x, p, prefix, cfg: TransformerConfig, positions=None):
     elif cfg.rope:
         q, k = _rope(q, k, cfg.theta(layer), positions=positions,
                      scaling=cfg.rope_scaling_dict)
-    return q, k, v
+    return q, k, v, g
+
+
+def gate_heads(a, g):
+    """The heads' output a (..., nh * vd) under its output gate: a ⊙
+    sigmoid(g), in float32; ``g`` None (no ``attn_gate``): a as it is."""
+    if g is None:
+        return a
+    return (a.astype(jnp.float32)
+            * jax.nn.sigmoid(g.astype(jnp.float32))).astype(a.dtype)
 
 
 def qkv_project_bshd(x, p, prefix, cfg: TransformerConfig,
@@ -927,6 +994,9 @@ def forward_hidden(params: Dict, tokens: jax.Array,
         elif cfg.mixer(i) == "conv":
             from nvme_strom_tpu.models.ssm import conv_block
             h = conv_block(h, p, L, cfg)[0]
+        elif cfg.mixer(i) == "gdn":
+            from nvme_strom_tpu.models.ssm import gdn_block
+            h = gdn_block(h, p, L, cfg)[0]
         else:
             h = attention(h, p, L, cfg, attn_fn)
         x = add_residual(x, h, cfg)

@@ -25,6 +25,12 @@ slot writes a sacrificial row the way a free slot's K/V write lands in the
 trash block.
 
 Both run in interpret mode off the TPU like the repo's other kernels.
+
+The gated delta rule — the other recurrence with a state matrix, ``S ← αS; S
+← S + k ⊗ β(v − Sᵀk)``, which reads the state through a key before it writes
+it — has its own pair of kernels of the same two shapes in ``ops/gdn.py``
+(``strom_gdn_scan``, ``strom_gdn_update``); ``_heads_per_step`` and
+``_interpret`` below serve both files.
 """
 
 from __future__ import annotations

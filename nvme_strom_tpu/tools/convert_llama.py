@@ -102,7 +102,35 @@ _LAYER_RULES: Tuple[Tuple[str, str, bool], ...] = (
     # ships its own modelling file, which could not be read here.
     ("self_attn.qkv_proj.weight", "wqkv", True),
     ("self_attn.attention_sink_bias", "sink", False),
+    # qwen3_next: the gated-delta-rule mixer (models/ssm.py gdn_block).
+    # in_proj_qkvz and in_proj_ba interleave their output rows by KEY head
+    # ([q | k | v | z] and [b | a] a head): convert() de-interleaves
+    # "gdn_in" / "gdn_ba" (``_deinterleave_gdn``).  conv1d.weight is
+    # (channels, 1, K) over q | k | v in that order, no bias.  q_proj holds
+    # the output gate beside each head's query, (q | g) a head, as
+    # ``qkvg_project`` reads it.  Every norm but linear_attn.norm is
+    # zero-centred: convert() stores 1 + w (``_ZERO_CENTRED``).  The names
+    # are transformers' qwen3_next's as remembered: ASSUMED, no checkpoint
+    # was there to check.
+    ("linear_attn.in_proj_qkvz.weight", "gdn_in", True),
+    ("linear_attn.in_proj_ba.weight", "gdn_ba", True),
+    ("linear_attn.conv1d.weight", "gdn_conv_w", True),
+    ("linear_attn.dt_bias", "gdn_dt_bias", False),
+    ("linear_attn.A_log", "gdn_A_log", False),
+    ("linear_attn.norm.weight", "gdn_norm", False),
+    ("linear_attn.out_proj.weight", "gdn_out", True),
+    ("self_attn.q_norm.weight", "q_norm", False),
+    ("self_attn.k_norm.weight", "k_norm", False),
+    ("mlp.shared_expert.gate_proj.weight", "shared_w_gate", True),
+    ("mlp.shared_expert.up_proj.weight", "shared_w_up", True),
+    ("mlp.shared_expert.down_proj.weight", "shared_w_down", True),
+    ("mlp.shared_expert_gate.weight", "shared_gate", True),
 )
+
+#: the norms a qwen3_next checkpoint stores zero-centred (the model computes
+#: x̂ ⊙ (1 + w)); this model multiplies by the stored weight, so convert()
+#: stores 1 + w, in float32
+_ZERO_CENTRED = ("attn_norm", "mlp_norm", "final_norm", "q_norm", "k_norm")
 
 #: one expert's matrices: convert() stacks them into moe_w_* (E, in, out)
 _EXPERT_RE = re.compile(r"^(?:feed_forward|mlp)\.experts\.(\d+)\."
@@ -384,11 +412,73 @@ def _mimo_fields(hf_cfg: dict) -> dict:
         tie_embed=bool(hf_cfg.get("tie_word_embeddings", False)))
 
 
+def _qwen3_next_fields(hf_cfg: dict) -> dict:
+    """The TransformerConfig fields of a ``qwen3_next`` config (Qwen3-Next:
+    gated-delta-rule layers with every ``full_attention_interval``-th layer
+    full GQA attention under an output gate, a stated head width with
+    rotary on its leading ``partial_rotary_factor``, q/k head norms,
+    zero-centred norms — stored as 1 + w by ``convert`` —, and in every
+    layer softmax-routed experts beside one shared expert under a sigmoid
+    gate), beyond the dense family's.  A file that states one device's
+    share of a deployment carries ``expert_share`` as ``_mla_fields`` reads
+    it, beside ``num_experts``, which then counts the experts held.  Raises
+    on what the model does not implement."""
+    def refuse(what):
+        raise ValueError(f"unsupported qwen3_next config: {what}")
+    n = hf_cfg["num_hidden_layers"]
+    every = hf_cfg["full_attention_interval"]
+    types = hf_cfg.get("layer_types") or [
+        "linear_attention" if (i + 1) % every else "full_attention"
+        for i in range(n)]
+    if len(types) != n or set(types) - {"linear_attention",
+                                        "full_attention"}:
+        refuse(f"layer_types {sorted(set(types))} for {n} layers")
+    if hf_cfg.get("decoder_sparse_step", 1) != 1 or hf_cfg.get(
+            "mlp_only_layers"):
+        refuse("decoder_sparse_step != 1 or mlp_only_layers (every layer "
+               "holds experts)")
+    if hf_cfg.get("use_sliding_window"):
+        refuse("use_sliding_window=True")
+    if hf_cfg.get("rope_scaling"):
+        refuse(f"rope_scaling {hf_cfg['rope_scaling']}")
+    hd = hf_cfg["head_dim"]
+    rotary = int(hd * hf_cfg.get("partial_rotary_factor", 1.0))
+    if rotary % 2:
+        refuse(f"int(head_dim x partial_rotary_factor) = {rotary} is odd")
+    hk, hv = hf_cfg["linear_num_key_heads"], hf_cfg["linear_num_value_heads"]
+    if hv % hk:
+        refuse(f"linear_num_value_heads {hv} is no multiple of "
+               f"linear_num_key_heads {hk}")
+    share = hf_cfg.get("expert_share") or {}
+    held = hf_cfg["num_experts"]
+    routed = share.get("routed", held)
+    return dict(
+        layer_kinds=tuple("gdn" if t == "linear_attention" else "attention"
+                          for t in types),
+        mlp_kinds=("experts",) * n,
+        gdn_k_heads=hk, gdn_v_heads=hv,
+        gdn_k_dim=hf_cfg["linear_key_head_dim"],
+        gdn_v_dim=hf_cfg["linear_value_head_dim"],
+        gdn_conv=hf_cfg["linear_conv_kernel_dim"],
+        qk_head_dim=hd, rotary_dim=rotary if rotary != hd else 0,
+        qk_norm=True, attn_gate=True, rope_scaling=None,
+        n_experts=routed, expert_top_k=hf_cfg["num_experts_per_tok"],
+        experts_held=held if held != routed else 0,
+        expert_offset=share.get("offset", 0),
+        d_expert=hf_cfg["moe_intermediate_size"],
+        d_shared=hf_cfg.get("shared_expert_intermediate_size", 0),
+        shared_gate=bool(hf_cfg.get("shared_expert_intermediate_size", 0)),
+        router_kind="softmax", router_bias=False,
+        router_norm_topk=bool(hf_cfg.get("norm_topk_prob", True)),
+        tie_embed=bool(hf_cfg.get("tie_word_embeddings", False)))
+
+
 def config_from_hf(hf_cfg: dict):
     """HF ``config.json`` → TransformerConfig: the dense Llama family,
     ``model_type`` granitemoehybrid (``_hybrid_fields``), ``lfm2`` /
     ``lfm2_moe`` (``_lfm2_fields``), ``deepseek_v3`` / ``kimi_k2``
-    (``_mla_fields``) and ``mimo_v2`` (``_mimo_fields``).
+    (``_mla_fields``), ``mimo_v2`` (``_mimo_fields``) and ``qwen3_next``
+    (``_qwen3_next_fields``).
 
     Raises on architecture knobs the model does not implement — silently
     ignoring them (e.g. a non-SiLU activation) would convert into a model
@@ -407,13 +497,15 @@ def config_from_hf(hf_cfg: dict):
               else _lfm2_fields(hf_cfg) if model_type in ("lfm2", "lfm2_moe")
               else _mla_fields(hf_cfg)
               if model_type in ("deepseek_v3", "kimi_k2")
-              else _mimo_fields(hf_cfg) if model_type == "mimo_v2" else {})
+              else _mimo_fields(hf_cfg) if model_type == "mimo_v2"
+              else _qwen3_next_fields(hf_cfg) if model_type == "qwen3_next"
+              else {})
     derived_hd = hf_cfg["hidden_size"] // hf_cfg["num_attention_heads"]
     if hf_cfg["hidden_size"] % hf_cfg["num_attention_heads"]:
         raise ValueError("hidden_size not divisible by num_attention_heads")
     # (latent attention states its own head widths: where HF writes a
-    # head_dim there, it is qk_rope_head_dim; mimo_v2 states its head_dim
-    # and the model takes it, ``qk_head_dim``)
+    # head_dim there, it is qk_rope_head_dim; mimo_v2 and qwen3_next state
+    # their head_dim and the model takes it, ``qk_head_dim``)
     if "kv_lora_rank" not in family and "qk_head_dim" not in family \
             and hf_cfg.get(
             "head_dim", derived_hd) != derived_hd:
@@ -470,6 +562,21 @@ def _deinterleave_rope(w: np.ndarray, cfg, per_head: bool) -> np.ndarray:
     return out
 
 
+def _deinterleave_gdn(w: np.ndarray, cfg, parts: tuple) -> np.ndarray:
+    """A qwen3_next delta-rule projection (in, out) whose output columns lie
+    interleaved by KEY head — each head's ``parts`` (widths) side by side,
+    [q | k | v | z] for in_proj_qkvz, [b | a] for in_proj_ba — into the order
+    ``models/ssm.py`` splits: all heads' first part, then all heads' second,
+    ...  (HF's ``fix_query_key_value_ordering`` does the same to the
+    activations at every call.)"""
+    per = w.reshape(w.shape[0], cfg.gdn_k_heads, sum(parts))
+    out, at = [], 0
+    for width in parts:
+        out.append(per[:, :, at:at + width].reshape(w.shape[0], -1))
+        at += width
+    return np.ascontiguousarray(np.concatenate(out, axis=1))
+
+
 def strom_config_dict(cfg) -> dict:
     """``strom_config.json`` of a converted checkpoint: the
     TransformerConfig keys the serving/training entry points rebuild the
@@ -483,13 +590,15 @@ def strom_config_dict(cfg) -> dict:
         out.update({k: getattr(cfg, k) for k in (
             "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_conv", "ssm_chunk",
             "embed_mult", "residual_mult", "logits_div", "attn_scale", "rope",
-            "tie_embed", "conv_taps", "qk_norm")},
+            "tie_embed", "conv_taps", "qk_norm", "gdn_k_heads",
+            "gdn_v_heads", "gdn_k_dim", "gdn_v_dim", "gdn_conv",
+            "gdn_chunk")},
             layer_kinds=list(cfg.layer_kinds))
     if cfg.mlp_kinds:       # the per-layer MLPs and the exact layer's router
         out.update({k: getattr(cfg, k) for k in (
             "n_experts", "expert_top_k", "d_expert", "router_kind",
             "router_bias", "router_norm_topk", "router_scale",
-            "experts_held", "expert_offset", "d_shared")},
+            "experts_held", "expert_offset", "d_shared", "shared_gate")},
             mlp_kinds=list(cfg.mlp_kinds))
     if cfg.latent:
         out.update({k: getattr(cfg, k) for k in (
@@ -499,7 +608,7 @@ def strom_config_dict(cfg) -> dict:
         out.update({k: getattr(cfg, k) for k in (
             "qk_head_dim", "v_head_dim", "rotary_dim", "value_scale",
             "window", "window_kv_heads", "window_rope_theta", "window_sink",
-            "tie_embed")})
+            "tie_embed", "attn_gate", "qk_norm")})
     return out
 
 
@@ -543,6 +652,7 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
     with open(os.path.join(hf_dir, "config.json")) as f:
         hf_cfg = json.load(f)
     cfg = config_from_hf(hf_cfg)
+    zero_centred = hf_cfg.get("model_type") == "qwen3_next"
 
     pending: Dict[str, np.ndarray] = {}
     pending_bytes = 0
@@ -588,6 +698,14 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
             embed = arr
         if cfg.latent and ours.endswith((".wq_b", ".wkv_a")):
             out = _deinterleave_rope(out, cfg, ours.endswith(".wq_b"))
+        if ours.endswith((".gdn_in", ".gdn_ba")):
+            rep = cfg.gdn_v_heads // cfg.gdn_k_heads
+            out = _deinterleave_gdn(
+                out, cfg, (rep, rep) if ours.endswith(".gdn_ba") else
+                (cfg.gdn_k_dim, cfg.gdn_k_dim, rep * cfg.gdn_v_dim,
+                 rep * cfg.gdn_v_dim))
+        if zero_centred and ours.rsplit(".", 1)[-1] in _ZERO_CENTRED:
+            out = 1.0 + out.astype(np.float32)
         e = re.fullmatch(r"(.*\.moe_w_(?:gate|up|down))\.(\d+)", ours)
         if e:                               # one expert's slice: held until
             experts.setdefault(e.group(1), {})[int(e.group(2))] = out

@@ -606,3 +606,162 @@ def test_mimo_v2_config_raises_on_what_is_not_implemented(key, value, msg):
     import test_swa
     with pytest.raises(ValueError, match=msg):
         convert_llama.config_from_hf(dict(test_swa.HF, **{key: value}))
+
+
+# -- qwen3_next: gated-delta-rule layers, gated attention, a gated shared ----
+# -- expert, zero-centred norms ---------------------------------------------
+
+#: the catalog row's ``config`` (model-configs/architectures.jsonl,
+#: Qwen3-Next-80B-A3B-Instruct), key for key
+QWEN3_NEXT_ROW = dict(
+    decoder_sparse_step=1, full_attention_interval=4, head_dim=256,
+    hidden_act="silu", hidden_size=2048, intermediate_size=5120,
+    linear_conv_kernel_dim=4, linear_key_head_dim=128,
+    linear_num_key_heads=16, linear_num_value_heads=32,
+    linear_value_head_dim=128, max_position_embeddings=262144,
+    mlp_only_layers=[], model_type="qwen3_next", moe_intermediate_size=512,
+    norm_topk_prob=True, num_attention_heads=16, num_experts=512,
+    num_experts_per_tok=10, num_hidden_layers=48, num_key_value_heads=2,
+    partial_rotary_factor=0.25, rms_norm_eps=1e-06, rope_scaling=None,
+    rope_theta=10000000, shared_expert_intermediate_size=512,
+    tie_word_embeddings=False, use_sliding_window=False, vocab_size=151936)
+
+
+def test_qwen3_next_config_from_the_catalog_rows_keys():
+    """The published keys give the published model: 36 delta-rule layers and
+    12 full ones 3 : 1, 16 / 32 heads of 128 and 4 taps, 16 / 2 heads of 256
+    with rotary on 64, gate, q/k norms, 512 softmax-routed experts of 512
+    top-10 beside a gated shared expert of 512; a file that states a share
+    holds its experts and keeps the router whole; the config round-trips
+    through ``strom_config.json``."""
+    from nvme_strom_tpu.models.transformer import TransformerConfig
+    cfg = convert_llama.config_from_hf(QWEN3_NEXT_ROW)
+    assert cfg.layer_kinds == ("gdn", "gdn", "gdn", "attention") * 12
+    assert cfg.mlp_kinds == ("experts",) * 48
+    assert (cfg.gdn_k_heads, cfg.gdn_v_heads, cfg.gdn_k_dim, cfg.gdn_v_dim,
+            cfg.gdn_conv, cfg.gdn_conv_dim) == (16, 32, 128, 128, 4, 8192)
+    assert (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.v_dim,
+            cfg.rotary_dim) == (16, 2, 256, 256, 64)
+    assert cfg.attn_gate and cfg.qk_norm and cfg.stated_kv
+    assert cfg.rope_theta == 1e7 and cfg.norm_eps == 1e-6
+    assert (cfg.n_experts, cfg.expert_top_k, cfg.d_expert, cfg.d_shared,
+            cfg.experts_held) == (512, 10, 512, 512, 0)
+    assert cfg.router_kind == "softmax" and cfg.router_norm_topk \
+        and not cfg.router_bias and cfg.shared_gate and not cfg.tie_embed
+    assert len(cfg.recurrent_layers) == len(cfg.state_layers) == 36
+    assert len(cfg.attn_layers) == 12
+    d = convert_llama.strom_config_dict(cfg)
+    assert TransformerConfig(**json.loads(json.dumps(d))) == cfg
+    share = convert_llama.config_from_hf(dict(
+        QWEN3_NEXT_ROW, num_hidden_layers=16, num_experts=32,
+        expert_share={"routed": 512, "offset": 64, "chips": 16}))
+    assert (share.n_experts, share.experts_held, share.expert_offset,
+            share.experts_local) == (512, 32, 64, 32)
+    assert share.layer_kinds == ("gdn", "gdn", "gdn", "attention") * 4
+
+
+@pytest.mark.parametrize("key,value,msg", [
+    ("decoder_sparse_step", 2, "every layer holds experts"),
+    ("mlp_only_layers", [0], "every layer holds experts"),
+    ("use_sliding_window", True, "use_sliding_window"),
+    ("rope_scaling", {"rope_type": "yarn", "factor": 4.0}, "rope_scaling"),
+    ("linear_num_value_heads", 24, "no multiple"),
+    ("layer_types", ["sliding_attention"] * 48, "layer_types"),
+    ("hidden_act", "gelu", "hidden_act"),
+])
+def test_qwen3_next_config_raises_on_what_is_not_implemented(key, value, msg):
+    with pytest.raises(ValueError, match=msg):
+        convert_llama.config_from_hf(dict(QWEN3_NEXT_ROW, **{key: value}))
+
+
+def test_qwen3_next_deinterleave_on_a_hand_built_tensor():
+    """``in_proj_qkvz`` lies [q 2 | k 2 | v 3x2 | z 3x2] a key head (2 key
+    heads of 2, 6 value heads of 2: three a key head) and ``in_proj_ba`` [b
+    3 | a 3] a key head; the converter leaves all q, then all k, all v, all
+    z — each column told by a number that says (part, key head, place)."""
+    from nvme_strom_tpu.models.transformer import TransformerConfig
+    cfg = TransformerConfig(n_layers=1, layer_kinds=("gdn",), gdn_k_heads=2,
+                            gdn_v_heads=6, gdn_k_dim=2, gdn_v_dim=2)
+    parts = (("q", 2), ("k", 2), ("v", 6), ("z", 6))
+    code = {"q": 1000, "k": 2000, "v": 3000, "z": 4000, "b": 5000, "a": 6000}
+    cols = [code[p] + 100 * head + j for head in range(2)
+            for p, width in parts for j in range(width)]
+    w = np.stack([np.asarray(cols), -np.asarray(cols)]).astype(np.float32)
+    out = convert_llama._deinterleave_gdn(w, cfg, (2, 2, 6, 6))
+    want = [code[p] + 100 * head + j for p, width in parts
+            for head in range(2) for j in range(width)]
+    np.testing.assert_array_equal(out[0], want)
+    np.testing.assert_array_equal(out[1], -np.asarray(want))
+    # value head 4 = key head 1's second: columns 2..3 of its v part
+    np.testing.assert_array_equal(out[0, 8 + 4 * 2:8 + 5 * 2], [3102, 3103])
+    ba = np.asarray([[code[p] + 100 * head + j for head in range(2)
+                      for p in "ba" for j in range(3)]], np.float32)
+    np.testing.assert_array_equal(
+        convert_llama._deinterleave_gdn(ba, cfg, (3, 3))[0],
+        [5000, 5001, 5002, 5100, 5101, 5102,
+         6000, 6001, 6002, 6100, 6101, 6102])
+
+
+@pytest.fixture(scope="module")
+def hf_qwen3_next(tmp_path_factory):
+    if not hasattr(transformers, "Qwen3NextForCausalLM"):
+        pytest.skip("this transformers has no Qwen3Next")
+    d = tmp_path_factory.mktemp("hf_qwen3_next")
+    cfg = transformers.Qwen3NextConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=32, partial_rotary_factor=0.25, rope_theta=50000.0,
+        max_position_embeddings=128, rms_norm_eps=1e-6,
+        full_attention_interval=4, linear_conv_kernel_dim=4,
+        linear_key_head_dim=16, linear_value_head_dim=16,
+        linear_num_key_heads=2, linear_num_value_heads=4,
+        decoder_sparse_step=1, moe_intermediate_size=32,
+        shared_expert_intermediate_size=32, num_experts_per_tok=3,
+        num_experts=8, norm_topk_prob=True, mlp_only_layers=[],
+        attention_bias=False, tie_word_embeddings=False)
+    torch.manual_seed(0)
+    model = transformers.Qwen3NextForCausalLM(cfg).eval()
+    with torch.no_grad():       # every norm, decay and gate off its init
+        for name, p in model.named_parameters():
+            if name.endswith(("norm.weight", "layernorm.weight", "dt_bias")):
+                p.add_(0.3 * torch.randn_like(p))
+            elif name.endswith("A_log"):
+                p.copy_(torch.log(torch.rand_like(p) * 4 + 0.05))
+            elif name.endswith(("shared_expert_gate.weight",
+                                "in_proj_ba.weight")):
+                p.mul_(20.0)
+    model.save_pretrained(d, safe_serialization=True)
+    return str(d), model
+
+
+def test_qwen3_next_logits_match_hf(hf_qwen3_next, tmp_path):
+    """Converted qwen3_next weights through ``decode.block_step`` — the
+    delta rule through the chunked scan kernel in interpret mode over a
+    prompt of 150 rows (three chunks, the last ragged), gated attention
+    through the blocked kernel — against transformers' own forward: the
+    tensor names, ``in_proj_qkvz`` / ``in_proj_ba`` de-interleaved by key
+    head, the conv's channel order, the L2 norms and q's scale, β and the
+    decay, the gated per-head norm, the (q | gate) halves of ``q_proj``,
+    rotary on the first 8 of 32, every zero-centred norm stored as 1 + w,
+    top-3 of 8 softmax scores renormalised, the gated shared expert and the
+    untied head all line up."""
+    import jax
+    import jax.numpy as jnp
+    from nvme_strom_tpu.models import decode
+    hf_dir, model = hf_qwen3_next
+    out = str(tmp_path / "converted")
+    summary = convert_llama.convert(hf_dir, out)
+    assert summary["skipped"] == []
+    cfg, params = _load_converted(out)
+    assert cfg.layer_kinds == ("gdn", "gdn", "gdn", "attention")
+    assert cfg.attn_gate and cfg.shared_gate and cfg.rotary_dim == 8
+    assert params["layers.0.gdn_in"].shape == (64, 2 * 32 + 2 * 64)
+    assert params["layers.3.wq"].shape == (64, 4 * 2 * 32)
+    assert params["layers.1.shared_gate"].shape == (64, 1)
+    toks = np.random.default_rng(0).integers(0, 256, (2, 150))
+    with torch.no_grad():
+        ref = model(torch.from_numpy(toks)).logits.float().numpy()
+    with jax.default_matmul_precision("highest"):
+        ours, _ = decode.block_step(params, jnp.asarray(toks, jnp.int32),
+                                    cfg, decode.init_cache(cfg, 2, 160))
+    np.testing.assert_allclose(np.asarray(ours), ref, atol=3e-4, rtol=3e-4)

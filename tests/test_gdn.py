@@ -1,0 +1,488 @@
+"""Gated-delta-rule layers beside gated full attention, every MLP routed
+experts beside a gated shared expert, through the program at a tiny size on
+the CPU in float32: the two kernels in interpret mode against the recurrence
+a token at a time; the paged server — compiled prefill through the chunked
+scan, then decode through BOTH caches, the full layers' pages and the state
+pools — against the benchmark's plain reference
+(``benchmark/reference/qwen3_next.py``, which imports nothing of the
+program) on the benchmark's seeded weights, in logits; each mechanism against
+the reference with it switched off; the shares of a deployment adding up;
+what a freed slot leaves behind; what such a configuration refuses."""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import weights_gdn as WG                         # noqa: E402
+from benchmark.reference import qwen3_next as ref               # noqa: E402
+from nvme_strom_tpu.models import decode, moe, serving, ssm     # noqa: E402
+from nvme_strom_tpu.models import transformer as tr             # noqa: E402
+from nvme_strom_tpu.models.serving import DecodeServer          # noqa: E402
+from nvme_strom_tpu.ops.gdn import gdn_scan, gdn_update         # noqa: E402
+from nvme_strom_tpu.tools.convert_llama import config_from_hf   # noqa: E402
+
+#: Qwen3-Next's keys at a tiny size: one period of 3 delta-rule layers and
+#: a full one; 2 key heads and 4 value heads of 16; 4 query heads over 2 KV
+#: heads of 32 with rotary on the first 8; the router scores 16 experts
+#: top-3 and this device holds 4 of them (4..7) beside the shared expert
+HF = dict(
+    model_type="qwen3_next", hidden_size=64, vocab_size=96,
+    num_hidden_layers=4, full_attention_interval=4, num_attention_heads=4,
+    num_key_value_heads=2, head_dim=32, partial_rotary_factor=0.25,
+    rope_theta=10000, rope_scaling=None, linear_conv_kernel_dim=4,
+    linear_key_head_dim=16, linear_value_head_dim=16, linear_num_key_heads=2,
+    linear_num_value_heads=4, intermediate_size=128,
+    moe_intermediate_size=32, shared_expert_intermediate_size=32,
+    num_experts=4, expert_share={"routed": 16, "offset": 4},
+    num_experts_per_tok=3, norm_topk_prob=True, decoder_sparse_step=1,
+    mlp_only_layers=[], rms_norm_eps=1e-6, hidden_act="silu",
+    use_sliding_window=False, tie_word_embeddings=False,
+    max_position_embeddings=256)
+SEED = 31
+BLOCK = 8
+#: float32 on both sides; what is left is the order of the sums (the chunked
+#: scan's products against the recurrence's, the blocked softmax, the grouped
+#: expert product) through 4 layers: a few 1e-5 on logits of size ~4
+ATOL = 3e-4
+
+
+def _model(hf=HF):
+    cfg = dataclasses.replace(config_from_hf(hf), dtype=jnp.float32,
+                              gdn_chunk=16)
+    params = {k: v.astype(jnp.float32)
+              for k, v in WG.make_params(hf, SEED).items()}
+    return cfg, params
+
+
+@pytest.fixture(scope="module")
+def model():
+    return _model()
+
+
+def _server(model, slots=3, **kw):
+    cfg, params = model
+    return DecodeServer(params, cfg, max_batch=slots, max_len=128,
+                        total_blocks=48, block_len=BLOCK, **kw)
+
+
+def _prompt(n, salt=0):
+    return np.random.default_rng([n, salt]).integers(
+        0, HF["vocab_size"], n).tolist()
+
+
+def _reference(prompt, tokens, hf=HF, low=None):
+    """Reference logits (len(tokens), vocab) at the positions that predict
+    each served token, teacher-forced on them."""
+    seq = np.asarray([prompt + tokens], np.int32)
+    at = len(prompt) - 1 + np.arange(len(tokens))[None]
+    return np.asarray(ref.logits_at(hf, SEED, seq, at, low=low)[0])
+
+
+@pytest.fixture
+def spy(monkeypatch):
+    """Record the logits every token of every request was sampled from: the
+    prefill's (``_admit_first``), then each decode step's (``paged_logits``
+    compiled as the step compiles it, minus the donation)."""
+    rows = {}
+    step_logits = jax.jit(serving.paged_logits, static_argnums=(1,))
+
+    def run(srv, lookahead=1):
+        first = srv._admit_first
+
+        def first_spy(group, logits):
+            for i, plan in enumerate(group):
+                rows.setdefault(plan["req"].rid, []).append(
+                    np.asarray(logits[i]))
+            return first(group, logits)
+
+        def step_spy(params, cfg, tok, k_pool, v_pool, blk, off, table,
+                     pos, temps, top_ps, seeds, *recur):
+            logits, k_pool, v_pool, state = step_logits(
+                params, cfg, tok, k_pool, v_pool, blk, off, table, pos,
+                *recur)
+            for b, req in enumerate(srv.slots):
+                if req is not None:
+                    rows[req.rid].append(np.asarray(logits[b]))
+            nxt = serving._sample_slots(logits, temps, top_ps, seeds, pos)
+            return nxt, k_pool, v_pool, state
+
+        srv._admit_first = first_spy
+        monkeypatch.setattr(serving, "_paged_step", step_spy)
+        out = srv.run(lookahead=lookahead)
+        return {rid: (toks, np.stack(rows[rid][:len(toks)]))
+                for rid, toks in out.items()}
+    return run
+
+
+# -- (1) the kernels against the recurrence a token at a time ---------------
+
+def _recurrence(q, k, v, alpha, beta, s0, valid=None):
+    """q, k (b, m, H, dk), v (b, m, H, dv), log alpha and beta (b, m, H), s0
+    (b, H, dk, dv) -> (o (b, m, H, dv), S after the last VALID row),
+    float32."""
+    b, m = k.shape[:2]
+    alpha = jnp.exp(alpha)
+    valid = jnp.ones((b, m), bool) if valid is None else valid
+
+    def step(s, x):
+        q, k, v, a, bt, ok = x
+        s1 = a[..., None, None] * s
+        u = bt[..., None] * (v - jnp.einsum("bhkv,bhk->bhv", s1, k))
+        s1 = s1 + k[..., :, None] * u[..., None, :]
+        o = jnp.einsum("bhkv,bhk->bhv", s1, q)
+        return jnp.where(ok[:, None, None, None], s1, s), o
+
+    xs = tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, alpha, beta, valid))
+    s, o = jax.lax.scan(step, s0, xs)
+    return jnp.moveaxis(o, 0, 1), s
+
+
+def _draw(b, m, H=4, dk=16, dv=32, seed=0):
+    rng = np.random.default_rng([seed, b, m])
+    q = rng.normal(size=(b, m, H, dk))
+    k = rng.normal(size=(b, m, H, dk))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * np.sqrt(dk)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    # log-decays from a few tokens to thousands — some so steep that α is 0
+    # in float32 — and some β near 1
+    alpha = -np.exp(2.0 * rng.normal(size=(b, m, H)) - 3.0)
+    alpha[:, m // 3, 0] = -200.0
+    beta = 1 / (1 + np.exp(-2.0 * rng.normal(size=(b, m, H))))
+    return tuple(jnp.asarray(t, jnp.float32) for t in (
+        q, k, rng.normal(size=(b, m, H, dv)), alpha, beta,
+        rng.normal(size=(b, H, dk, dv))))
+
+
+@pytest.mark.parametrize("m,n_valid,chunk", [
+    (64, (64, 64), 64),         # one whole chunk
+    (128, (128, 91), 64),       # two, one sequence ragged
+    (100, (100, 37), 64),       # no multiple of the chunk
+    (200, (1, 200), 64),        # one valid row beside four chunks
+    (7, (7, 3), 64),            # shorter than a chunk: one of 8 rows
+    (96, (96, 0), 32),          # a row that holds no prompt
+    (48, (48, 20), 16),
+])
+def test_gdn_scan_matches_the_recurrence(m, n_valid, chunk):
+    """The chunked form — the triangular solve by forward substitution, the
+    products, the state carried between chunks — equals the recurrence row
+    by row at lengths that are and are not multiples of the chunk; rows past
+    ``n_valid`` leave the state where the last valid row left it, and a
+    sequence with none keeps the state it came in with."""
+    q, k, v, alpha, beta, s0 = _draw(2, m)
+    valid = jnp.arange(m)[None] < jnp.asarray(n_valid)[:, None]
+    o, s = gdn_scan(q, k, v, alpha, beta, s0, valid, chunk=chunk)
+    want_o, want_s = _recurrence(q, k, v, alpha, beta, s0, valid)
+    np.testing.assert_allclose(
+        np.where(valid[..., None, None], o, 0),
+        np.where(valid[..., None, None], want_o, 0), atol=2e-5)
+    np.testing.assert_allclose(s, want_s, atol=2e-5)
+    if 0 in n_valid:
+        np.testing.assert_array_equal(s[1], s0[1])
+
+
+def test_gdn_scan_carries_its_state_across_two_calls():
+    """A prompt in two calls — the second starting from the state the first
+    left — gives the rows and the state of one call over the whole."""
+    q, k, v, alpha, beta, s0 = _draw(1, 150, seed=3)
+    o, s = gdn_scan(q, k, v, alpha, beta, s0, chunk=32)
+    o1, s1 = gdn_scan(q[:, :70], k[:, :70], v[:, :70], alpha[:, :70],
+                      beta[:, :70], s0, chunk=32)
+    o2, s2 = gdn_scan(q[:, 70:], k[:, 70:], v[:, 70:], alpha[:, 70:],
+                      beta[:, 70:], s1, chunk=32)
+    np.testing.assert_allclose(jnp.concatenate([o1, o2], 1), o, atol=2e-5)
+    np.testing.assert_allclose(s2, s, atol=2e-5)
+
+
+def test_gdn_scan_with_keys_that_repeat():
+    """Every row of a chunk with the SAME key and β = 1: the matrix the
+    chunk solves is all ones under its diagonal, whose powers grow like
+    binomials before they cancel — the forward substitution does not care."""
+    q, k, v, alpha, beta, s0 = _draw(1, 64, seed=5)
+    k = jnp.broadcast_to(k[:, :1], k.shape)
+    beta, alpha = jnp.ones_like(beta), jnp.full_like(alpha, -0.001)
+    o, s = gdn_scan(q, k, v, alpha, beta, s0)
+    want_o, want_s = _recurrence(q, k, v, alpha, beta, s0)
+    np.testing.assert_allclose(o, want_o, atol=5e-5)
+    np.testing.assert_allclose(s, want_s, atol=5e-5)
+
+
+def test_gdn_update_is_one_step_in_place():
+    """One token of five slots against a pool of seven rows: the slots' rows
+    move as the recurrence says, free slots (rows 5 and 6 named twice) touch
+    the sacrificial rows only, and nobody else's row changes."""
+    q, k, v, alpha, beta, s0 = _draw(5, 1, H=32, seed=7)
+    pool = jnp.concatenate([s0, 1.0 + jnp.zeros((2,) + s0.shape[1:])])
+    sidx = jnp.asarray([3, 0, 6, 1, 6], jnp.int32)
+    o, new = jax.jit(gdn_update, donate_argnums=(0,))(
+        jnp.array(pool), sidx, q[:, 0], k[:, 0], v[:, 0], alpha[:, 0],
+        beta[:, 0])
+    want_o, want_s = _recurrence(q, k, v, alpha, beta, pool[sidx])
+    np.testing.assert_allclose(o, want_o[:, 0], atol=1e-5)
+    live = np.asarray([0, 1, 3])                  # slots 0, 1, 3 of sidx
+    np.testing.assert_allclose(new[sidx[live]], want_s[live], atol=1e-5)
+    for untouched in (2, 4, 5):
+        np.testing.assert_array_equal(new[untouched], pool[untouched])
+
+
+# -- (2) the program against the reference -------------------------------------
+
+@pytest.mark.parametrize("lookahead", [1, 3])
+def test_prefill_then_decode_through_both_caches(model, spy, lookahead):
+    """Mixed prompt lengths — under a chunk of 16, over several, no multiple
+    of chunk or block — and more requests than slots so that slots free and
+    refill: every token's logits are the reference's full forward pass,
+    prefill's and decode's alike."""
+    srv = _server(model, slots=3)
+    prompts = {"a": _prompt(10), "b": _prompt(37), "c": _prompt(3),
+               "d": _prompt(64), "e": _prompt(50)}
+    budgets = {"a": 9, "b": 7, "c": 14, "d": 11, "e": 6}
+    for rid, p in prompts.items():
+        srv.submit(rid, p, budgets[rid])
+    out = spy(srv, lookahead)
+    assert set(out) == set(prompts)
+    for rid, (toks, logits) in out.items():
+        assert len(toks) == budgets[rid]
+        np.testing.assert_allclose(logits, _reference(prompts[rid], toks),
+                                   atol=ATOL, err_msg=rid)
+    st = srv.stats()
+    # 3 delta-rule layers: S (4, 16, 16) float32 and 3 rows of 2·32 + 64
+    # conv channels; 1 full layer: K and V of 2 KV heads of 32
+    assert st["state_layers"] == 3 and st["kv_layers"] == 1
+    assert st["state_bytes_per_slot"] == 3 * (4 * 16 * 16 + 3 * 128) * 4
+    assert st["state_slots"] == 4
+    assert st["kv_bytes_per_token"] == 2 * 2 * 32 * 4
+    assert srv.state["s"][0].shape == (4, 4, 16, 16)
+    assert srv.state["conv"][2].shape == (4, 3, 128)
+    t = srv.timings
+    assert t["scan_tokens"] == sum(len(p) for p in prompts.values())
+    assert 0 < t["moe_pairs"] < t["moe_pairs_routed"]
+    assert st["blocks_free"] == st["blocks_total"]
+    assert st["prefix_hits"] == 0 and st["prefix_cached_blocks"] == 0
+
+
+def test_generate_is_the_servers_tokens(model):
+    """``decode.generate`` (dense caches, every step a block of one row
+    through the scan kernel) and the server (pages and state pools, the
+    update kernel) produce the same greedy tokens."""
+    cfg, params = model
+    prompt = _prompt(29)
+    want = np.asarray(decode.generate(
+        params, jnp.asarray([prompt], jnp.int32), cfg, 12))[0]
+    srv = _server(model, slots=1)
+    srv.submit("g", prompt, 12)
+    assert srv.run()["g"] == want.tolist()
+
+
+def test_alone_and_among_three_others_gives_the_same_logits(model, spy):
+    """No slot reads another's row of the state pools: a request served
+    alone and served in a full batch has the same logits."""
+    prompt = _prompt(23, salt=9)
+    alone = _server(model, slots=1)
+    alone.submit("r", prompt, 8)
+    _, want = spy(alone)["r"]
+    srv = _server(model, slots=4)
+    for i in range(3):
+        srv.submit(i, _prompt(23, salt=i), 10)
+    srv.submit("r", prompt, 8)
+    _, got = spy(srv)["r"]
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_a_released_slot_answers_as_a_fresh_server(model):
+    """Releasing a slot clears nothing; the next prompt's prefill overwrites
+    its state and tail whole, so a second request gives what a fresh server
+    gives."""
+    srv = _server(model, slots=1)
+    srv.submit("first", _prompt(2, salt=1), 6)
+    srv.run()
+    prompt = _prompt(2, salt=2)         # shorter than the conv's reach
+    srv.submit("second", prompt, 7)
+    fresh = _server(model, slots=1)
+    fresh.submit("second", prompt, 7)
+    assert srv.run()["second"] == fresh.run()["second"]
+
+
+def test_pad_rows_leave_state_and_conv_tail_untouched(model):
+    """A right-padded block: state and tail are those of the valid rows
+    alone, whatever the padding holds."""
+    cfg, params = model
+    h = jax.random.normal(jax.random.key(2), (2, 24, 64), jnp.float32)
+    n = jnp.asarray([24, 9])
+    out, s, tail = ssm.gdn_block(h, params, "layers.0.", cfg, n_valid=n)
+    o9, s9, tail9 = ssm.gdn_block(h[1:, :9], params, "layers.0.", cfg)
+    np.testing.assert_allclose(s[1], s9[0], atol=1e-5)
+    np.testing.assert_allclose(tail[1], tail9[0], atol=1e-6)
+    np.testing.assert_allclose(out[1, :9], o9[0], atol=1e-5)
+
+
+# -- (3) each mechanism against the reference with it switched off -------------
+
+CONTROLS = {
+    "beta": dict(low="beta1"),
+    "decay": dict(low="alpha1"),
+    "correction": dict(low="no_correction"),
+    "l2_norms": dict(low="no_l2"),
+    "zero_centred_norm": dict(low="w_norm"),
+    "attention_gate": dict(low="no_attn_gate"),
+    "shared_gate": dict(low="no_shared_gate"),
+    "weights_over_all_selected": dict(low="norm_held"),
+    # rotary on all 32 features of a head, not the first 8
+    "partial_rotary": dict(low="rotary_all"),
+}
+
+
+@pytest.fixture(scope="module")
+def served(model):
+    """One request served without the spy's patches: (prompt, tokens, the
+    program's logits at every served token, teacher-forced through
+    ``block_step`` — prefill and decode agree with it by the tests above)."""
+    cfg, params = model
+    prompt = _prompt(45, salt=5)
+    srv = _server(model, slots=1)
+    srv.submit("r", prompt, 10)
+    toks = srv.run()["r"]
+    cache = decode.init_cache(cfg, 1, 64)
+    logits, _ = decode.block_step(
+        params, jnp.asarray([prompt + toks[:-1]], jnp.int32), cfg, cache)
+    return prompt, toks, np.asarray(logits[0, len(prompt) - 1:])
+
+
+@pytest.mark.parametrize("name", list(CONTROLS))
+def test_each_mechanism_is_in_the_program(served, name):
+    """The program agrees with the sound reference and NOT with the
+    reference that lacks the mechanism."""
+    prompt, toks, logits = served
+    np.testing.assert_allclose(logits, _reference(prompt, toks), atol=ATOL)
+    off = _reference(prompt, toks, **CONTROLS[name])
+    assert np.abs(logits - off).max() > 30 * ATOL, name
+
+
+# -- (4) the share of a deployment --------------------------------------------------
+
+def test_the_shares_of_an_expert_layer_add_up_to_the_whole():
+    """64 small experts over 16 shares of 4, top-6 by softmax: the routed
+    parts all sixteen shares give, plus the GATED shared expert once, equal
+    the uncut layer — the router is whole on every share and the weights are
+    normalised over all 6 selected experts, held or not."""
+    whole = tr.TransformerConfig(
+        vocab=32, d_model=32, n_layers=1, n_heads=2, n_kv_heads=2, d_ff=64,
+        mlp_kinds=("experts",), n_experts=64, expert_top_k=6, d_expert=8,
+        d_shared=8, shared_gate=True, router_kind="softmax",
+        dtype=jnp.float32)
+    keys = iter(jax.random.split(jax.random.key(4), 16))
+    p = moe.init_moe_params(keys, whole, "", tr.dense_init)
+    p["router"] = p["router"] * 4.0            # scores well apart
+    p["shared_gate"] = p["shared_gate"] * 8.0  # a gate that spans (0, 1)
+    x = jax.random.normal(jax.random.key(1), (2, 9, 32), jnp.float32)
+    valid = jnp.ones((2, 9), bool).at[1, 6:].set(False)
+    want, counts, _ = moe.expert_mlp(x, p, "", whole, valid)
+    gate = jax.nn.sigmoid(x @ p["shared_gate"])
+    assert float(gate.min()) < 0.2 and float(gate.max()) > 0.8
+    shared = gate * tr.mlp(x, p, "shared_")
+    total, pairs = jnp.zeros_like(want), 0
+    for share in range(16):
+        cfg = dataclasses.replace(whole, experts_held=4,
+                                  expert_offset=4 * share)
+        ps = dict(p, **{k: p[k][4 * share:4 * share + 4]
+                        for k in ("moe_w_gate", "moe_w_up", "moe_w_down")})
+        out, c, _ = moe.expert_mlp(x, ps, "", cfg, valid)
+        np.testing.assert_array_equal(c, counts[4 * share:4 * share + 4])
+        total = total + (out - shared)
+        pairs += int(c.sum())
+    np.testing.assert_allclose(np.asarray(total + shared), np.asarray(want),
+                               atol=2e-5)
+    assert pairs == int(counts.sum()) == 15 * 6        # 15 valid rows, k 6
+    # a pad row is routed nowhere and comes out as the gated shared expert
+    np.testing.assert_allclose(np.asarray(want[1, 6:]),
+                               np.asarray(shared[1, 6:]), atol=1e-6)
+    # and the uncut layer is the reference's, equation for equation
+    hf = dict(HF, hidden_size=32, moe_intermediate_size=8,
+              shared_expert_intermediate_size=8, num_experts=64,
+              num_experts_per_tok=6, expert_share=None)
+    w = {"router": p["router"], "shared_gate": p["shared_gate"],
+         **{k: p[k] for k in ("shared_w_gate", "shared_w_up",
+                              "shared_w_down")}}
+    with jax.default_matmul_precision("highest"):
+        plain = ref.moe(x[:1], w, hf, lambda e: (
+            p["moe_w_gate"][e], p["moe_w_up"][e], p["moe_w_down"][e]))
+    np.testing.assert_allclose(np.asarray(want[:1]), np.asarray(plain),
+                               atol=2e-5)
+
+
+# -- (5) the configuration --------------------------------------------------------------
+
+def test_config_from_hf_reads_the_benchmarks_file():
+    from benchmark import harness
+    hf = harness.load_json("benchmark", "configs", "qwen3-next-80b-a3b.json")
+    cfg = config_from_hf(hf)
+    assert cfg.layer_kinds == ("gdn", "gdn", "gdn", "attention") * 4
+    assert (cfg.d_model, cfg.vocab, cfg.max_seq) == (2048, 18992, 5120)
+    assert (cfg.gdn_k_heads, cfg.gdn_v_heads, cfg.gdn_k_dim,
+            cfg.gdn_v_dim, cfg.gdn_conv) == (16, 32, 128, 128, 4)
+    assert (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.rotary_dim,
+            cfg.attn_gate, cfg.qk_norm) == (16, 2, 256, 64, True, True)
+    assert (cfg.n_experts, cfg.experts_held, cfg.expert_offset,
+            cfg.expert_top_k, cfg.d_expert, cfg.d_shared,
+            cfg.shared_gate) == (512, 32, 0, 10, 512, 512, True)
+    shapes = jax.eval_shape(lambda: ssm.init_state(cfg, 129))
+    assert [a.shape for a in shapes["s"]] == [(129, 32, 128, 128)] * 12
+    assert [a.shape for a in shapes["conv"]] == [(129, 3, 8192)] * 12
+    assert shapes["s"][0].dtype == jnp.float32
+    # the generator's tensors are the program's leaves, shape for shape
+    want = jax.eval_shape(lambda: tr.init_params(jax.random.key(0), cfg))
+    got = dict(WG.tensor_specs(hf))
+    assert set(got) == set(want)
+    assert all(tuple(want[k].shape) == tuple(got[k]) for k in got)
+
+
+def test_gdn_layers_need_their_sizes():
+    with pytest.raises(ValueError, match="gdn layers need gdn_k_heads"):
+        tr.TransformerConfig(n_layers=2, layer_kinds=("attention", "gdn"))
+    with pytest.raises(ValueError, match="a multiple of gdn_k_heads"):
+        tr.TransformerConfig(n_layers=1, layer_kinds=("gdn",), gdn_k_heads=4,
+                             gdn_v_heads=6, gdn_k_dim=8, gdn_v_dim=8)
+    with pytest.raises(ValueError, match="'conv', 'gdn'"):
+        tr.TransformerConfig(n_layers=1, layer_kinds=("delta",))
+
+
+# -- (6) what such a configuration refuses --------------------------------------------
+
+def test_what_cannot_hold_the_state_refuses(model):
+    """No prefix store, hand-off, mesh or training step: one plain sentence
+    each, naming the kinds of layer that carry a state."""
+    cfg, params = model
+
+    class Store:
+        page_tokens = BLOCK
+
+    with pytest.raises(NotImplementedError, match="kv_store"):
+        _server(model, kv_store=Store())
+    srv = _server(model)
+    with pytest.raises(NotImplementedError,
+                       match="mamba or conv or gdn layers"):
+        srv.export_sessions()
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
+    sharded = dict(params)
+    sharded["layers.0.gdn_out"] = jax.device_put(
+        params["layers.0.gdn_out"], NamedSharding(mesh, P(None, "tp")))
+    with pytest.raises(NotImplementedError, match="mesh"):
+        DecodeServer(sharded, cfg, max_batch=2, max_len=64,
+                     total_blocks=8, block_len=BLOCK)
+    toks = jnp.zeros((1, 8), jnp.int32)
+    for what in (lambda: tr.forward(params, toks, cfg),
+                 lambda: tr.loss_fn(params, toks, cfg)):
+        with pytest.raises(NotImplementedError, match="training step"):
+            what()
+    # and no prefix keys: a page without the state at its boundary is not one
+    srv.submit("x", _prompt(30), 2)
+    assert srv._req_keys(srv.queue[0]) == []

@@ -231,3 +231,35 @@ def test_add_load_sums_a_calls_rounds_beside_its_rows():
                         "rounds")
     np.testing.assert_array_equal(got["load"], [[6, 0, 10, 2], [0, 0, 0, 0]])
     np.testing.assert_array_equal(got["sums"], [[6, 96, 10, 4], [0, 0, 0, 2]])
+
+
+def test_shared_experts_gate_scales_the_shared_part_alone():
+    """``shared_gate``: the shared expert's output times sigmoid(x . w), one
+    scalar a row — a gate driven far negative leaves the routed part alone,
+    one driven far positive is the ungated layer, and in between the layer
+    is routed + gate x shared, row by row."""
+    import dataclasses
+    from nvme_strom_tpu.models import moe
+    from nvme_strom_tpu.models import transformer as tr
+    cfg = tr.TransformerConfig(
+        vocab=32, d_model=32, n_layers=1, n_heads=2, n_kv_heads=2, d_ff=64,
+        mlp_kinds=("experts",), n_experts=8, expert_top_k=3, d_expert=16,
+        d_shared=16, shared_gate=True, dtype=jnp.float32)
+    keys = iter(jax.random.split(jax.random.key(3), 16))
+    p = moe.init_moe_params(keys, cfg, "", tr.dense_init)
+    assert p["shared_gate"].shape == (32, 1)
+    x = jax.random.normal(jax.random.key(5), (2, 7, 32), jnp.float32)
+    plain = dataclasses.replace(cfg, shared_gate=False)
+    routed = moe.expert_mlp(x, p, "", dataclasses.replace(
+        plain, d_shared=0))[0]
+    ungated = moe.expert_mlp(x, p, "", plain)[0]
+    shared = ungated - routed
+    got = moe.expert_mlp(x, p, "", cfg)[0]
+    gate = jax.nn.sigmoid(x @ p["shared_gate"])
+    np.testing.assert_allclose(got, routed + gate * shared, atol=1e-5)
+    # the bias-free gate's two ends, by scaling its weight
+    far = dict(p, shared_gate=p["shared_gate"] * 1e4)
+    sign = np.asarray(x @ p["shared_gate"]) > 0
+    want = np.where(sign, np.asarray(ungated), np.asarray(routed))
+    np.testing.assert_allclose(moe.expert_mlp(x, far, "", cfg)[0], want,
+                               atol=1e-5)
