@@ -1462,6 +1462,13 @@ class DecodeServer:
         # that carry it (beside ``kv_layers``, the layers that keep pages)
         out["state_bytes_per_slot"] = out["state_bytes"] // (self.B + 1)
         out["state_layers"] = len(self.cfg.recurrent_layers)
+        # the Mamba-2 heads a lane row of the state pool holds side by side
+        # (1: nothing is packed)
+        out["state_heads_per_lane_row"] = 1
+        if self.cfg.mamba_layers:
+            from nvme_strom_tpu.ops.ssm import heads_per_lane_row
+            out["state_heads_per_lane_row"] = heads_per_lane_row(
+                self.cfg.ssm_heads, self.cfg.ssm_head_dim)
         # layers whose MLP is the exact expert layer, and what they routed
         # (decode steps; the prefill's own under *_prefill in timings)
         out["moe_layers"] = len(self.cfg.expert_layers)

@@ -8,8 +8,8 @@ Mamba-2:
     u_t  = silu(Σ_j w_conv[j] · u_{t-K+1+j} + b_conv)      depthwise, causal
     [x, B, C] = split(u_t)                 x: (H, P);  B, C: (N,), one group
     Δ_t  = softplus(dt_t + dt_bias)        A = −exp(A_log)        (per head)
-    S_t  = exp(Δ_t A) · S_{t−1} + Δ_t · x_t ⊗ B_t          S: (H, P, N) f32
-    y_t  = S_t C_t + D ⊙ x_t
+    S_t  = exp(Δ_t A) · S_{t−1} + Δ_t · x_t ⊗ B_t          S: (H, P, N) f32,
+    y_t  = S_t C_t + D ⊙ x_t                   kept as (H/g, N, g·P): ops/ssm.py
     out  = RMSNorm(y ⊙ silu(z); g_norm over all of inner) W_out
 
 The gated short convolution (``layer_kinds`` "conv"; the LFM2 family's
@@ -62,7 +62,7 @@ import numpy as np
 from nvme_strom_tpu.models.transformer import (TransformerConfig, rms_norm,
                                                valid_rows, wmat)
 from nvme_strom_tpu.ops.gdn import gdn_scan, gdn_update
-from nvme_strom_tpu.ops.ssm import ssm_scan, ssm_update
+from nvme_strom_tpu.ops.ssm import pool_shape, ssm_scan, ssm_update
 
 
 def init_mamba_params(keys, cfg: TransformerConfig, L: str, dense) -> Dict:
@@ -90,16 +90,21 @@ def init_mamba_params(keys, cfg: TransformerConfig, L: str, dense) -> Dict:
 def init_state(cfg: TransformerConfig, rows: int) -> Dict:
     """Zeroed recurrent state for ``rows`` sequences, whatever each mixer
     declares per sequence.  Under ``"s"`` one state per layer that keeps one
-    (``cfg.state_layers``), float32: (rows, H, P, N) for Mamba-2, (rows, Hv,
-    dk, dv) for the delta rule.  Under ``"conv"`` one conv tail per
+    (``cfg.state_layers``), float32: (rows, H/g, N, g·P) for Mamba-2 —
+    state-major, the g = ``ops.ssm.heads_per_lane_row`` heads that fill the
+    128 lanes side by side (two of 64; one of 128 or more), the form both
+    of its kernels and the server's scatter take it in —, (rows, Hv, dk, dv)
+    for the delta rule.  Under ``"conv"`` one conv tail per
     recurrent layer of any kind: (rows, K−1, inner + 2N) for Mamba-2, (rows,
     gdn_conv − 1, 2·Hk·dk + Hv·dv) for the delta rule, (rows, conv_taps − 1,
     d_model) for the short conv.  Both in layer order.  Tuples of per-layer
     arrays, never one stacked array: each is donated to the step and updated
     in place, and indexing a stacked one by layer would copy the lot (the KV
     pool's copies in PERF.md §5)."""
-    s = {"mamba": (rows, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
-         "gdn": (rows, cfg.gdn_v_heads, cfg.gdn_k_dim, cfg.gdn_v_dim)}
+    s = {"gdn": (rows, cfg.gdn_v_heads, cfg.gdn_k_dim, cfg.gdn_v_dim)}
+    if cfg.mamba_layers:                   # a head width to pack by
+        s["mamba"] = pool_shape(rows, cfg.ssm_heads, cfg.ssm_head_dim,
+                                cfg.ssm_state)
     tail = {"mamba": (rows, cfg.ssm_conv - 1, cfg.ssm_conv_dim),
             "gdn": (rows, cfg.gdn_conv - 1, cfg.gdn_conv_dim),
             "conv": (rows, cfg.conv_taps - 1, cfg.d_model)}
@@ -154,8 +159,9 @@ def mamba_block(h, p: Dict, L: str, cfg: TransformerConfig, s0=None,
                 tail=None, n_valid=None):
     """A block of rows through the mixer.
 
-    h (b, m, d) post-norm; s0 (b, H, P, N) float32 and tail (b, K−1, inner
-    + 2N): what the sequences carried in (None: nothing yet, zeros);
+    h (b, m, d) post-norm; s0 (b, H/g, N, g·P) float32, ``init_state``'s
+    form, and tail (b, K−1, inner + 2N): what the sequences carried in
+    (None: nothing yet, zeros);
     n_valid, () or one count a sequence (b,): rows past it are right padding
     — they leave state and tail as the last valid row left them (a sequence
     with none keeps what it came in with).  Returns (out (b, m, d), S,
@@ -167,8 +173,8 @@ def mamba_block(h, p: Dict, L: str, cfg: TransformerConfig, s0=None,
         if tail is None:
             tail = jnp.zeros((b, k1, cfg.ssm_conv_dim), u.dtype)
         if s0 is None:
-            s0 = jnp.zeros((b, cfg.ssm_heads, cfg.ssm_head_dim,
-                            cfg.ssm_state), jnp.float32)
+            s0 = jnp.zeros(pool_shape(b, cfg.ssm_heads, cfg.ssm_head_dim,
+                                      cfg.ssm_state), jnp.float32)
         window = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
         w = p[L + "ssm_conv_w"].astype(jnp.float32)
         conv = sum(w[j] * window[:, j:j + m].astype(jnp.float32)
@@ -193,7 +199,7 @@ def mamba_block(h, p: Dict, L: str, cfg: TransformerConfig, s0=None,
 def mamba_step(h, p: Dict, L: str, cfg: TransformerConfig, s_pool,
                tail_pool, sidx):
     """One token of every slot through the mixer, against the server's
-    pools.  h (B, 1, d); s_pool (rows, H, P, N) float32 and tail_pool
+    pools.  h (B, 1, d); s_pool (rows, H/g, N, g·P) float32 and tail_pool
     (rows, K−1, inner + 2N), both updated in place when donated; sidx (B,)
     each slot's row.  Returns (out (B, 1, d), s_pool, tail_pool)."""
     with jax.named_scope("strom.ssm.proj"):

@@ -15,6 +15,15 @@ table entries it reads and the grid steps it issues beside it, so that
 what a grid step costs without a block can be told from what it costs
 with one.
 
+``python -m nvme_strom_tpu.tools.kernel_probe ssm`` times the two state
+updates alone, each checked against one step of its recurrence first:
+``strom_ssm_update`` (``ops/ssm.py``) at ``g4hm.flood``'s shape and
+``strom_gdn_update`` (``ops/gdn.py``) at ``q3n.flood4k``'s, the yardstick
+the first is read against — the same walk over a float32 pool of 2 MiB
+rows, with more arithmetic an element.  What it read when the Mamba-2 pool
+went state-major (the old form, the new one, the yardstick): PERF.md §6,
+PR 44.
+
 One process, on the chip: without a TPU the probe exits non-zero unless
 the caller set ``JAX_PLATFORMS=cpu`` (mechanics only, tiny shapes; every
 line then says ``"platform": "cpu"``).
@@ -22,6 +31,7 @@ line then says ``"platform": "cpu"``).
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -321,6 +331,128 @@ def probe_paged(case: str, repeats: int = 5) -> None:
                      f"median of {repeats}"})
 
 
+#: HBM bytes/s by ``device_kind`` (``benchmark/peaks.json``'s number)
+HBM_GB_S = {"TPU v5 lite": 819.0}
+
+#: (slots, heads, head width, state width) of ``g4hm.flood``'s 36 Mamba-2
+#: layers and (slots, value heads, dk, dv) of ``q3n.flood4k``'s 12
+#: delta-rule layers: pools of 65 and 129 rows of 2 MiB
+SSM_SHAPE, SSM_CALLS = (64, 64, 64, 128), 36
+GDN_SHAPE, GDN_CALLS = (128, 32, 128, 128), 12
+
+
+def _emit_update(kernel, shape, pool, nbytes, errs, update, calls, repeats):
+    """Time ``update(pool) -> (out, pool)`` — ``calls`` calls a round as a
+    step's layers make them, looped on the device over the donated pool so
+    that no dispatch is in the time — and print the kernel's line."""
+    import statistics
+
+    import jax
+    import jax.numpy as jnp
+
+    on_cpu = jax.default_backend() != "tpu"
+    rounds = 2 if on_cpu else max(2, int(0.4 / 5e-4 / calls))
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def run(pool):
+        def body(_, carry):
+            pool, out = carry
+            for _ in range(calls):
+                out, pool = update(pool)
+            return pool, out
+        out = jax.eval_shape(update, pool)[0]
+        return jax.lax.fori_loop(
+            0, rounds, body, (pool, jnp.zeros(out.shape, out.dtype)))
+
+    pool, out = run(pool)
+    out.block_until_ready()
+    ts = []
+    for _ in range(repeats):
+        t0 = time.monotonic()
+        pool, out = run(pool)
+        out.block_until_ready()
+        ts.append((time.monotonic() - t0) / (rounds * calls))
+    t = statistics.median(ts)
+    rec = {"probe": "state_update", "kernel": kernel, "shape": list(shape),
+           "pool": list(pool.shape), "calls_a_step": calls,
+           "mib_a_call": round(nbytes / 2 ** 20, 1),
+           "us_a_call": round(t * 1e6, 2),
+           "us_a_call_min": round(min(ts) * 1e6, 2),
+           "gb_s": round(nbytes / t / 1e9, 1), **errs,
+           "timing": f"{rounds} rounds of {calls} calls on the device, "
+                     f"median of {repeats}"}
+    peak = HBM_GB_S.get(_DEVICE.get("device_kind"))
+    if peak:
+        rec["bytes_roofline_pct"] = round(100 * nbytes / t / 1e9 / peak, 2)
+    _emit(rec)
+
+
+def probe_ssm(repeats: int = 5) -> None:
+    """Two lines: ``strom_ssm_update`` and ``strom_gdn_update`` alone, every
+    slot on a row of its own (``sidx`` a permutation), bytes as
+    ``benchmark/costs_hybrid.ssm_update_cost`` / ``costs_gdn.update_cost``
+    count them: the state in and out and the call's vectors."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from nvme_strom_tpu.ops import gdn, ssm
+    on_cpu = jax.default_backend() != "tpu"
+    f32, bf = jnp.float32, jnp.bfloat16
+    hi = jax.lax.Precision.HIGHEST
+
+    def rel(got, want):
+        return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+    # ---- Mamba-2
+    B, H, P, N = (4, 4, 16, 16) if on_cpu else SSM_SHAPE
+    ks = jax.random.split(jax.random.key(1), 6)
+    sidx = jnp.asarray(np.random.default_rng(3).permutation(B), jnp.int32)
+    x = jax.random.normal(ks[0], (B, H, P), f32).astype(bf)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, H), f32) * 1.5 - 4.0)
+    a = -jnp.exp(jax.random.normal(ks[2], (H,), f32) + 1.0)
+    b, c = (jax.random.normal(k, (B, N), f32).astype(bf) for k in ks[3:5])
+    pool = ssm.pack_state(jax.random.normal(ks[5], (B + 1, H, P, N), f32))
+    s0 = ssm.unpack_state(pool[sidx], H)
+    want_s = (s0 * jnp.exp(dt * a)[..., None, None]
+              + (dt[..., None] * x.astype(f32))[..., None]
+              * b.astype(f32)[:, None, None, :])
+    want_y = jnp.einsum("bhpn,bn->bhp", want_s, c.astype(f32), precision=hi)
+    step = jax.jit(ssm.ssm_update, donate_argnums=0)
+    y, pool = step(pool, sidx, x, dt, a, b, c)
+    errs = {"rel_err_y": rel(y, want_y),
+            "rel_err_s": rel(ssm.unpack_state(pool[sidx], H), want_s)}
+    nbytes = 2 * B * H * P * N * 4 + B * (3 * H * P + 2 * N) * 4
+    _emit_update("strom_ssm_update", (B, H, P, N), pool, nbytes, errs,
+                 lambda pool: ssm.ssm_update(pool, sidx, x, dt, a, b, c),
+                 2 if on_cpu else SSM_CALLS, repeats)
+    del pool, s0, want_s
+
+    # ---- the gated delta rule
+    B, H, dk, dv = (4, 4, 16, 16) if on_cpu else GDN_SHAPE
+    ks = jax.random.split(jax.random.key(2), 6)
+    sidx = jnp.asarray(np.random.default_rng(4).permutation(B), jnp.int32)
+    q, k = (jax.random.normal(key, (B, H, dk), f32) for key in ks[:2])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / dk ** 0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (B, H, dv), f32)
+    g = -jnp.exp(2.0 * jax.random.normal(ks[3], (B, H), f32) - 4.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, H), f32))
+    pool = jax.random.normal(ks[5], (B + 1, H, dk, dv), f32)
+    s = jnp.exp(g)[..., None, None] * pool[sidx]
+    u = beta[..., None] * (v - jnp.einsum("bhkv,bhk->bhv", s, k,
+                                          precision=hi))
+    want_s = s + k[..., :, None] * u[..., None, :]
+    want_o = jnp.einsum("bhkv,bhk->bhv", want_s, q, precision=hi)
+    step = jax.jit(gdn.gdn_update, donate_argnums=0)
+    o, pool = step(pool, sidx, q, k, v, g, beta)
+    errs = {"rel_err_y": rel(o, want_o), "rel_err_s": rel(pool[sidx], want_s)}
+    nbytes = 2 * B * H * dk * dv * 4 + B * H * (3 * dk + 3 * dv) * 4
+    _emit_update("strom_gdn_update", (B, H, dk, dv), pool, nbytes, errs,
+                 lambda pool: gdn.gdn_update(pool, sidx, q, k, v, g, beta),
+                 2 if on_cpu else GDN_CALLS, repeats)
+
+
 def main() -> int:
     sys.path.insert(0, REPO)   # direct-script mode: repo root first
     from nvme_strom_tpu.utils.compile_cache import enable_compile_cache
@@ -334,6 +466,9 @@ def main() -> int:
     if sys.argv[1:2] == ["paged"]:
         for case in sys.argv[2:] or PAGED_CASES:
             probe_paged(case)
+        return 0
+    if sys.argv[1:2] == ["ssm"]:
+        probe_ssm()
         return 0
 
     def roof_guarded():

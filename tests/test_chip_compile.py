@@ -282,9 +282,10 @@ def _kv_write_hd64(topo):
 def _ssm_update(topo):
     """The state update: the pool (65 rows of 2 MiB) is aliased through the
     call — no second copy of it is ever live."""
-    from nvme_strom_tpu.ops.ssm import ssm_update
+    from nvme_strom_tpu.ops.ssm import pool_shape, ssm_update
     sh = _one(topo)
-    pool = _spec((SSM_B + 1, SSM_H, SSM_P, SSM_N), jnp.float32, sh)
+    # state-major, two heads of 64 on a lane row: (65, 32, 128, 128)
+    pool = _spec(pool_shape(SSM_B + 1, SSM_H, SSM_P, SSM_N), jnp.float32, sh)
     compiled = _compile(
         functools.partial(ssm_update, interpret=False), pool,
         _spec((SSM_B,), jnp.int32, sh),
@@ -299,7 +300,7 @@ def _ssm_update(topo):
 
 
 def _ssm_scan(topo, rows=1024):
-    from nvme_strom_tpu.ops.ssm import ssm_scan
+    from nvme_strom_tpu.ops.ssm import pool_shape, ssm_scan
     sh = _one(topo)
     return _compile(
         functools.partial(ssm_scan, chunk=256, interpret=False),
@@ -308,7 +309,7 @@ def _ssm_scan(topo, rows=1024):
         _spec((SSM_H,), jnp.float32, sh),
         _spec((1, rows, SSM_N), jnp.bfloat16, sh),
         _spec((1, rows, SSM_N), jnp.bfloat16, sh),
-        _spec((1, SSM_H, SSM_P, SSM_N), jnp.float32, sh),
+        _spec(pool_shape(1, SSM_H, SSM_P, SSM_N), jnp.float32, sh),
         _spec((1, rows), jnp.bool_, sh))
 
 
@@ -737,7 +738,7 @@ def test_hybrid_step_updates_both_caches_in_place(topo, monkeypatch):
     assert m.alias_size_in_bytes >= donated, m
     # and no operation of the step copies a state array (65 x 2 MiB)
     assert not [line for line in text.splitlines()
-                if " copy(" in line and "= f32[65,64,64,128]" in line]
+                if " copy(" in line and "= f32[65,32,128,128]" in line]
 
 
 def _lfm2_five_layers(topo):

@@ -23,7 +23,9 @@ from benchmark import weights_hybrid as WH                      # noqa: E402
 from benchmark.reference import granite_hybrid as ref           # noqa: E402
 from nvme_strom_tpu.models import admission, serving            # noqa: E402
 from nvme_strom_tpu.models.serving import DecodeServer        # noqa: E402
-from nvme_strom_tpu.ops.ssm import ssm_scan, ssm_update         # noqa: E402
+from nvme_strom_tpu.ops.ssm import (heads_per_lane_row,         # noqa: E402
+                                    pack_state, pool_shape, ssm_scan,
+                                    ssm_update, unpack_state)
 from nvme_strom_tpu.tools.convert_llama import config_from_hf   # noqa: E402
 
 #: granite-4.0-h-micro's keys at a tiny size: kinds m, m, a, m
@@ -179,8 +181,10 @@ def test_ssm_scan_matches_the_recurrence(m, n_valid, chunk):
     last valid row did."""
     t = _scan_inputs(np.random.default_rng(m), 2, m)
     valid = np.broadcast_to(np.arange(m) < n_valid, (2, m))
-    y, s = ssm_scan(*(jnp.asarray(t[k]) for k in "x dt a b c s0".split()),
-                    jnp.asarray(valid), chunk=chunk)
+    y, s = ssm_scan(*(jnp.asarray(t[k]) for k in "x dt a b c".split()),
+                    pack_state(jnp.asarray(t["s0"])), jnp.asarray(valid),
+                    chunk=chunk)
+    s = unpack_state(s, t["x"].shape[2])
     cut = {k: (v[:, :n_valid] if k in ("x", "dt", "b", "c") else v)
            for k, v in t.items()}
     y_ref, s_ref = _recurrence(**cut)
@@ -200,8 +204,8 @@ def test_pad_rows_leave_state_and_conv_tail_untouched(model):
     h = rng.normal(size=(1, 16, cfg.d_model)).astype(np.float32)
     junk = h.copy()
     junk[:, 11:] = 1e3 * rng.normal(size=junk[:, 11:].shape)
-    s0 = rng.normal(size=(1, cfg.ssm_heads, cfg.ssm_head_dim,
-                          cfg.ssm_state)).astype(np.float32)
+    s0 = np.asarray(pack_state(jnp.asarray(rng.normal(size=(
+        1, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)), jnp.float32)))
     tail = rng.normal(size=(1, cfg.ssm_conv - 1,
                             cfg.ssm_conv_dim)).astype(np.float32)
     out, s, t = mamba_block(jnp.asarray(h[:, :11]), params, "layers.0.",
@@ -222,20 +226,29 @@ def test_pad_rows_leave_state_and_conv_tail_untouched(model):
 
 # -- (3) the update kernel --------------------------------------------------
 
-def test_ssm_update_is_one_step_in_place():
-    """One step of the recurrence for the slots named; every other row of
-    the pool bit for bit as it was; a free slot writes the sacrificial row
-    alone."""
+#: (H, P) → g, the heads on a lane row: 128 // P of them where that divides H
+PACKINGS = [(4, 16, 4), (6, 64, 2), (3, 64, 1), (2, 128, 1)]
+
+
+@pytest.mark.parametrize("H,P,g", PACKINGS)
+def test_ssm_update_is_one_step_in_place(H, P, g):
+    """One step of the recurrence for the slots named, on the pool as it is
+    kept (state-major, g heads on a lane row); every other row of the pool
+    bit for bit as it was; a free slot writes the sacrificial row alone."""
     rng = np.random.default_rng(5)
-    B, H, P, N = 3, 4, 16, 16
+    B, N = 3, 16
     t = _scan_inputs(rng, B, 1, H, P, N)
     pool = rng.normal(size=(B + 2, H, P, N)).astype(np.float32)
     trash = B + 1
     sidx = np.asarray([2, trash, 0], np.int32)     # slot 1 is free
-    y, new = ssm_update(jnp.asarray(pool), sidx, jnp.asarray(t["x"][:, 0]),
+    packed = pack_state(jnp.asarray(pool))
+    assert packed.shape == pool_shape(B + 2, H, P, N) == (B + 2, H // g, N,
+                                                          g * P)
+    y, new = ssm_update(packed, sidx, jnp.asarray(t["x"][:, 0]),
                         jnp.asarray(t["dt"][:, 0]), jnp.asarray(t["a"]),
                         jnp.asarray(t["b"][:, 0]), jnp.asarray(t["c"][:, 0]))
-    new = np.asarray(new)
+    assert new.shape == packed.shape
+    new = np.asarray(unpack_state(new, H))
     for b, row in enumerate(sidx):
         y_ref, s_ref = _recurrence(t["x"][b:b + 1], t["dt"][b:b + 1], t["a"],
                                    t["b"][b:b + 1], t["c"][b:b + 1],
@@ -248,6 +261,69 @@ def test_ssm_update_is_one_step_in_place():
         np.testing.assert_array_equal(new[row], pool[row])
 
 
+@pytest.mark.parametrize("H,P,g", PACKINGS + [(64, 64, 2), (5, 32, 1)])
+def test_pack_and_unpack_are_each_other_s_inverse(H, P, g):
+    """Lane j·P + p of packed head hp is element p of head hp·g + j, and
+    the way back is the identity: nothing is rounded, nothing is lost."""
+    assert heads_per_lane_row(H, P) == g
+    s = np.random.default_rng(H * P).normal(size=(2, H, P, 8)).astype(
+        np.float32)
+    packed = np.asarray(pack_state(jnp.asarray(s)))
+    assert packed.shape == pool_shape(2, H, P, 8)
+    for hp, j, p in [(0, 0, 0), (H // g - 1, g - 1, P - 1), (0, g - 1, 3)]:
+        np.testing.assert_array_equal(packed[:, hp, :, j * P + p],
+                                      s[:, hp * g + j, p, :])
+    np.testing.assert_array_equal(
+        np.asarray(unpack_state(jnp.asarray(packed), H)), s)
+
+
+@pytest.mark.parametrize("H,P,g", PACKINGS)
+def test_scan_hands_the_update_the_state_it_keeps(H, P, g):
+    """A prompt through the scan, its state written to a pool row as the
+    server writes it, then two tokens through the update: the recurrence
+    run straight through — the hand-over's layout is the pool's."""
+    rng = np.random.default_rng(11)
+    B, m, N = 2, 11, 16
+    t = _scan_inputs(rng, B, m + 2, H, P, N)
+    ins = [jnp.asarray(t[k][:, :m]) for k in "x dt".split()] + [
+        jnp.asarray(t["a"])] + [jnp.asarray(t[k][:, :m]) for k in "b c".split()]
+    _, s = ssm_scan(*ins, pack_state(jnp.asarray(t["s0"])), chunk=8)
+    pool = jnp.zeros(pool_shape(B + 1, H, P, N), jnp.float32)
+    sidx = np.asarray([1, 0], np.int32)
+    for b, row in enumerate(sidx):
+        pool = pool.at[row].set(s[b])              # serving's scatter
+    ys = []
+    for i in (m, m + 1):
+        y, pool = ssm_update(pool, sidx, jnp.asarray(t["x"][:, i]),
+                             jnp.asarray(t["dt"][:, i]), jnp.asarray(t["a"]),
+                             jnp.asarray(t["b"][:, i]),
+                             jnp.asarray(t["c"][:, i]))
+        ys.append(np.asarray(y))
+    y_ref, s_ref = _recurrence(**t)
+    np.testing.assert_allclose(np.stack(ys, 1), y_ref[:, m:], rtol=0,
+                               atol=3e-5 * np.abs(y_ref).max())
+    np.testing.assert_allclose(np.asarray(unpack_state(pool, H))[sidx], s_ref,
+                               rtol=0, atol=3e-5 * np.abs(s_ref).max())
+
+
+def test_the_kernel_probe_checks_both_updates_before_it_times_them(capsys):
+    """``kernel_probe ssm`` at its CPU size (mechanics only: no time it
+    prints here is a device's): one line a kernel, Mamba-2's on the pool as
+    it is kept and the delta rule's beside it, each within rounding of one
+    step of its recurrence."""
+    from nvme_strom_tpu.tools import kernel_probe
+    kernel_probe.probe_ssm(repeats=1)
+    lines = [json.loads(line)
+             for line in capsys.readouterr().out.splitlines()]
+    assert [line["kernel"] for line in lines] == ["strom_ssm_update",
+                                                  "strom_gdn_update"]
+    b, h, p, n = lines[0]["shape"]
+    assert tuple(lines[0]["pool"]) == pool_shape(b + 1, h, p, n)
+    for line in lines:
+        assert line["rel_err_y"] < 1e-5 and line["rel_err_s"] < 1e-5
+        assert line["us_a_call"] > 0 and "bytes_roofline_pct" not in line
+
+
 def test_free_slots_step_into_the_sacrificial_row_only(model):
     """Through the server: while one request decodes, the three free slots
     compute too — the state rows of slots 1..3 stay as they were."""
@@ -258,8 +334,13 @@ def test_free_slots_step_into_the_sacrificial_row_only(model):
     for a, b in zip(srv.state["s"], before):
         np.testing.assert_array_equal(np.asarray(a)[1:4], b[1:4])
         assert np.abs(np.asarray(a)[0]).max() > 0       # slot 0 was used
+    cfg = model[0]
+    for a in srv.state["s"]:                   # the kept form: 8 heads of 16
+        assert a.shape == pool_shape(5, cfg.ssm_heads, cfg.ssm_head_dim,
+                                     cfg.ssm_state) == (5, 1, 16, 128)
     st = srv.stats()
     assert st["state_slots"] == 5 and st["kv_layers"] == 1
+    assert st["state_heads_per_lane_row"] == 8
     assert st["state_bytes"] == sum(
         a.nbytes for a in srv.state["s"] + srv.state["conv"])
 

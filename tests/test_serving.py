@@ -284,6 +284,7 @@ def test_server_stats_gauges(setup):
              # layer carries a recurrent state (tests/test_hybrid.py)
              "kv_layers": cfg.n_layers, "state_bytes": 0, "state_slots": 0,
              "state_bytes_per_slot": 0, "state_layers": 0,
+             "state_heads_per_lane_row": 1,
              # no decode step yet: paged attention has walked nothing
              "attn_blocks_live": 0, "attn_blocks_table": 0,
              "attn_grid_steps": 0,
