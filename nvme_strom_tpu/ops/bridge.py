@@ -11,12 +11,18 @@ pipelining the reference gets from N in-flight DMA requests (SURVEY.md §3.4).
 
 The staging buffer is released back to the pool only after
 ``block_until_ready`` confirms the device transfer consumed it.
+
+``PutStage`` is the caller-side stage of a weight restore: the reading
+thread hands each chunk over and a worker a device gathers and puts it,
+so reading and transferring overlap without changing what a put is.
 """
 
 from __future__ import annotations
 
 import functools
 import os
+import queue
+import threading
 from typing import Callable, Iterator, Optional, Sequence
 
 import numpy as np
@@ -273,7 +279,13 @@ class StagingRetirePool:
     block-per-batch behavior — the safe fallback when the engine's
     staging pool is too small to also hold deferred entries (callers
     must budget: reads in flight + deferred entries < pool buffers, or
-    a deferred submit can wait on a buffer only this pool can free)."""
+    a deferred submit can wait on a buffer only this pool can free).
+
+    Who owns one: the stream that pushes to it, and only that stream —
+    the pool takes no lock.  The format readers, the SQL scans and the
+    loader each keep a pool on their one consuming thread; a weight
+    restore's pool belongs to its ``PutStage`` (below), which pushes
+    from whichever worker ends a chunk last, under the stage's lock."""
 
     def __init__(self, depth: int = 3):
         self.depth = max(0, depth)
@@ -317,6 +329,191 @@ class StagingRetirePool:
         """Retire everything (end of stream, or error-path cleanup)."""
         while self._q:
             self._block_oldest()
+
+
+class Once:
+    """A value that the first of several threads to ask computes and
+    the others wait for (a chunk's column gather that several of
+    ``PutStage``'s workers need)."""
+
+    __slots__ = ("_lock", "_value")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._value = None
+
+    def get(self, compute: Callable):
+        with self._lock:
+            if self._value is None:
+                self._value = compute()
+            return self._value
+
+
+class PutStage:
+    """Bounded transfer stage between a thread that reads and the
+    devices it feeds (``LazyCheckpoint.load_sharded``; PERF.md §3).
+
+    One FIFO worker a device.  The reading thread hands each completed
+    chunk to :meth:`put` as ``(release, [(dev, job)])`` and goes on to
+    its next read; device ``dev``'s worker calls ``job()`` — the host
+    gather and the ``host_to_device`` calls of that device's share —
+    and collects the device arrays it returns.  Both leave Python
+    (numpy's copy loop, PJRT's ``BufferFromHostBuffer``), so they run
+    beside the reader's planning and waiting.  :meth:`then` queues a
+    callable behind a device's puts (a tensor's ``jnp.concatenate``).
+
+    What it holds:
+
+    * a chunk's ``release`` fires only after EVERY array put out of the
+      chunk reports ready: the worker that finishes a chunk last pushes
+      it, with all devices' arrays, to the stage's one
+      ``StagingRetirePool`` (under a lock; ``StagingRetirePool``'s rule,
+      across threads);
+    * one worker a device, so a device's jobs run in the order they
+      were handed in;
+    * at most ``depth`` chunks are in the stage (handed in, not yet
+      pushed to the pool): :meth:`put` waits for room, under the span
+      ``strom.restore.put_wait``.  The caller budgets ``depth`` and
+      ``retire_depth`` against the engine's staging pool;
+    * ``depth`` 0 starts no thread: :meth:`put` and :meth:`then` run
+      their jobs on the calling thread — the synchronous path of a pool
+      too small for a queue, and of a single tensor's load;
+    * an exception in a job stops the stage: the jobs still queued are
+      dropped (their chunks released once what was put out of them is
+      ready) and the next :meth:`put`, :meth:`then` or :meth:`close`
+      raises it on the calling thread.  :meth:`close` always leaves
+      every buffer released and no worker alive.
+
+    ``engine.stats`` counts the arrays put by a worker
+    (``restore_puts_staged``) and on the calling thread
+    (``restore_puts_inline``).
+    """
+
+    def __init__(self, engine: StromEngine, depth: int, retire_depth: int):
+        self.engine = engine
+        self.depth = max(0, depth)
+        self._retire = StagingRetirePool(retire_depth)
+        self._retire_lock = threading.Lock()
+        self._error: Optional[BaseException] = None
+        self._room = threading.Semaphore(self.depth)
+        self._queues: dict = {}      # dev -> its worker's queue
+        self._workers: list = []
+
+    def _queue(self, dev):
+        """``dev``'s queue; its worker starts with its first job (only
+        the calling thread asks, so no two start one)."""
+        q = self._queues.get(dev)
+        if q is None:
+            q = self._queues[dev] = queue.SimpleQueue()
+            t = threading.Thread(target=self._work, args=(q,),
+                                 name=f"strom-put-{len(self._workers)}",
+                                 daemon=True)
+            self._workers.append(t)
+            t.start()
+        return q
+
+    # -- the calling thread ------------------------------------------------
+
+    def put(self, release, jobs: Sequence[tuple]) -> None:
+        """Hand one chunk over: ``release`` its staging release (None:
+        host-owned memory), ``jobs`` a ``(dev, job)`` for each device
+        with a share of it, ``job() -> [device arrays]`` put out of the
+        chunk's view."""
+        chunk = _Chunk(release, len(jobs))
+        if not self.depth:
+            try:
+                for _, job in jobs:
+                    chunk.arrays.extend(job())
+            finally:
+                self._retire_chunk(chunk, "restore_puts_inline")
+            return
+        with self.engine.tracer.span("strom.restore.put_wait",
+                                     "strom.restore"):
+            self._room.acquire()
+        if self._error is not None:
+            self._room.release()
+            if release is not None:
+                release()               # nothing was put out of it
+            raise self._error
+        for dev, job in jobs:
+            self._queue(dev).put((chunk, job))
+
+    def then(self, dev, fn: Callable) -> None:
+        """Run ``fn()`` after everything handed in for ``dev`` so far."""
+        if not self.depth:
+            fn()
+        elif self._error is not None:
+            raise self._error
+        else:
+            self._queue(dev).put((None, fn))
+
+    def close(self) -> None:
+        """Wait for every job handed in, release every buffer, end the
+        workers; raises what a job raised."""
+        for q in self._queues.values():
+            q.put(None)
+        for t in self._workers:
+            t.join()
+        self._workers = []
+        self._queues = {}
+        with self.engine.tracer.span("strom.restore.retire",
+                                     "strom.restore"):
+            self._retire.flush()
+        if self._error is not None:
+            err, self._error = self._error, None
+            raise err
+
+    # -- a worker ------------------------------------------------------------
+
+    def _work(self, q) -> None:
+        while True:
+            item = q.get()
+            if item is None:
+                return
+            chunk, job = item
+            try:
+                if self._error is None:
+                    if chunk is None:
+                        job()
+                    else:
+                        chunk.arrays.extend(job())
+            except BaseException as e:
+                self._error = self._error or e
+            if chunk is not None and chunk.done_one():
+                try:
+                    self._retire_chunk(chunk, "restore_puts_staged")
+                except BaseException as e:  # a transfer that failed
+                    self._error = self._error or e
+                self._room.release()
+            del item, chunk, job    # hold no array while the queue is idle
+
+    def _retire_chunk(self, chunk, counter: str) -> None:
+        """Every job of ``chunk`` has ended: count its puts and hand its
+        release to the pool, behind the arrays put out of it."""
+        self.engine.stats.add(**{counter: len(chunk.arrays)})
+        with self.engine.tracer.span("strom.restore.retire",
+                                     "strom.restore"):
+            with self._retire_lock:
+                self._retire.push(chunk.release, chunk.arrays)
+
+
+class _Chunk:
+    """One chunk in a ``PutStage``: its release, the arrays put out of
+    it so far, and how many devices' jobs are still to end."""
+
+    __slots__ = ("release", "arrays", "_left", "_lock")
+
+    def __init__(self, release, n_jobs: int):
+        self.release = release
+        self.arrays: list = []
+        self._left = n_jobs
+        self._lock = threading.Lock()
+
+    def done_one(self) -> bool:
+        """One job ended; True for the one that ends last."""
+        with self._lock:
+            self._left -= 1
+            return self._left == 0
 
 
 class DeviceStream:

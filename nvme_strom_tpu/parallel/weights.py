@@ -17,8 +17,10 @@ SURVEY.md §5; the write side is ``ops.bridge.write_from_device`` /
 
 from __future__ import annotations
 
+import collections
 import json
 import os
+from functools import partial
 from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
@@ -55,6 +57,45 @@ def _normalize_index(idx, shape):
             raise ValueError("strided sharding is not supported")
         tail.append(slice(start, stop))
     return (r0, r1), tuple(tail)
+
+
+def _stage_depths(eng, staged: bool) -> tuple:
+    """(chunks a ``PutStage`` may hold, entries its retire pool defers)
+    for a load over ``eng``, budgeted against the engine's staging pool:
+    ``_stream_span`` keeps up to ``stream_depth`` reads in flight, the
+    stage holds chunks handed over and the pool holds retired-pending
+    entries, and their sum must leave a free buffer or a deferred
+    submit could wait on memory only this consumer can release
+    (deadlock).  A pool with no room for a queue gives stage depth 0 —
+    the puts run on the reading thread — and a tiny one retire depth 0,
+    the old block-per-chunk behavior; ``staged=False`` asks for that
+    synchronous path whatever the pool."""
+    half = eng.config.queue_depth // 2
+    spare = eng.n_buffers - max(2, half) - 1
+    stage = max(0, min(half, spare // 2)) if staged else 0
+    return stage, max(0, min(half, spare - stage))
+
+
+class _IssuedTensor:
+    """A tensor whose chunks are with a ``PutStage``: ``arrays[dev]`` is
+    set by ``dev``'s worker when it has joined that device's parts."""
+
+    def __init__(self, gshape: tuple, sharding, devices: list):
+        self.gshape = gshape
+        self.sharding = sharding
+        self.devices = devices
+        self.arrays: Dict[object, object] = {}
+
+    def ready(self) -> bool:
+        return len(self.arrays) == len(self.devices)
+
+    def assemble(self, eng):
+        import jax
+        arrays = [self.arrays[d] for d in self.devices]
+        with eng.tracer.span("strom.restore.join", _CAT,
+                             parts=len(arrays)):
+            return jax.make_array_from_single_device_arrays(
+                self.gshape, self.sharding, arrays)
 
 
 class LazyCheckpoint:
@@ -143,10 +184,15 @@ class LazyCheckpoint:
                 klass="restore")
             if served is not None:
                 eng = served
+        from nvme_strom_tpu.ops.bridge import PutStage
         out: Dict[str, object] = {}
+        # the one transfer stage of this load (ops/bridge.PutStage): the
+        # workers live from here to the finally below, never longer
+        stage = PutStage(eng, *_stage_depths(eng, staged=True))
         try:
             with eng.tracer.span("strom.restore.load", _CAT,
                                  tensors=len(self._by_name)):
+                issued: collections.deque = collections.deque()
                 for name in self.keys():
                     get = (shardings.get if isinstance(shardings, dict)
                            else None)
@@ -154,20 +200,46 @@ class LazyCheckpoint:
                           else shardings(name, self.shape(name)))
                     if sh is None:
                         raise KeyError(f"no sharding for tensor {name}")
-                    out[name] = self._load_tensor(eng, name, sh)
+                    issued.append((name, self._issue_tensor(
+                        eng, name, sh, "restore", stage)))
+                    # assemble what the workers have joined meanwhile
+                    while issued and issued[0][1].ready():
+                        done, tensor = issued.popleft()
+                        out[done] = tensor.assemble(eng)
+                stage.close()
+                for done, tensor in issued:
+                    out[done] = tensor.assemble(eng)
                 if dtype is not None:
                     cast = jax.jit(lambda x: x.astype(dtype),
                                    out_shardings=None)
                     out = {n: cast(a) for n, a in out.items()}
             return out
         finally:
+            stage.close()       # a no-op after the close above
             if own:
                 eng.close_all()
 
     def _load_tensor(self, eng: StromEngine, name: str, sharding,
                      klass: str = "restore"):
-        """One tensor → its global array, under ``strom.restore.tensor``
-        (the spans of docs/OBSERVABILITY.md's restore rows nest in it)."""
+        """One tensor → its global array, its puts on the calling thread
+        (a ``PutStage`` of depth 0: the cold-start lanes call this from
+        two threads at once, a tensor at a time, and a demand fault
+        waits for nothing but its own transfers)."""
+        from nvme_strom_tpu.ops.bridge import PutStage
+        stage = PutStage(eng, *_stage_depths(eng, staged=False))
+        try:
+            tensor = self._issue_tensor(eng, name, sharding, klass, stage)
+        finally:
+            stage.close()
+        return tensor.assemble(eng)
+
+    def _issue_tensor(self, eng: StromEngine, name: str, sharding,
+                      klass: str, stage) -> "_IssuedTensor":
+        """Read one tensor and hand its chunks to ``stage``, under
+        ``strom.restore.tensor`` (the reading thread's spans of
+        docs/OBSERVABILITY.md's restore rows nest in it).  The result
+        assembles the global array once the stage has run what this
+        call handed it."""
         sf = self._by_name[name]
         info = sf.tensors[name]
         gshape = tuple(info["shape"])
@@ -175,13 +247,12 @@ class LazyCheckpoint:
         nbytes = int(np.prod(gshape, dtype=np.int64)) * np_dt.itemsize
         with eng.tracer.span("strom.restore.tensor", _CAT, tensor=name,
                              bytes=nbytes):
-            return self._load_tensor_inner(eng, sf, name, gshape, np_dt,
-                                           sharding, klass)
+            return self._issue_tensor_inner(eng, sf, name, gshape, np_dt,
+                                            sharding, klass, stage)
 
-    def _load_tensor_inner(self, eng: StromEngine, sf, name: str,
-                           gshape: tuple, np_dt, sharding, klass: str):
-        import jax
-
+    def _issue_tensor_inner(self, eng: StromEngine, sf, name: str,
+                            gshape: tuple, np_dt, sharding, klass: str,
+                            stage) -> "_IssuedTensor":
         idx_map = sharding.addressable_devices_indices_map(gshape)
 
         # Group devices by ROW SPAN only: rows are contiguous on disk, so a
@@ -197,10 +268,14 @@ class LazyCheckpoint:
         for dev, idx in idx_map.items():
             (r0, r1), tail = _normalize_index(
                 idx if idx is not None else (), gshape)
-            spans.setdefault((r0, r1), []).append((dev, tail))
+            if not any((s.start, s.stop) != (0, d)
+                       for s, d in zip(tail, gshape[1:])):
+                tail = ()           # whole rows: nothing to gather
+            # hashable key: slice objects only hash on 3.12+
+            tkey = tuple((s.start, s.stop) for s in tail)
+            spans.setdefault((r0, r1), []).append((dev, tail, tkey))
 
-        from nvme_strom_tpu.ops.bridge import (StagingRetirePool,
-                                               host_to_device)
+        from nvme_strom_tpu.ops.bridge import Once, host_to_device
         from nvme_strom_tpu.utils.checksum import (ChecksumError,
                                                    VerifyPolicy, crc32c)
         # read-side integrity (STROM_VERIFY): a span covering the WHOLE
@@ -223,28 +298,51 @@ class LazyCheckpoint:
                 stamps = sf._strom_crcs = tensor_checksums(sf)
             stamp = stamps.get(name)
         span = eng.tracer.span
+        tensor = _IssuedTensor(gshape, sharding, list(idx_map))
+
+        def gather(view, tail):
+            cut = view[(slice(None),) + tail]
+            # strided column shard: host gather copies
+            with span("strom.restore.slice", _CAT, bytes=int(cut.nbytes)):
+                sub = np.ascontiguousarray(cut)
+            eng.stats.add(bounce_bytes=int(sub.nbytes))
+            return sub
+
+        def put_share(view, dev, tail, gathered, parts):
+            """Device ``dev``'s share of one chunk, on its worker: the
+            host gather where its columns are strided, then the put."""
+            if not tail:
+                sub = view
+            elif gathered is None:
+                sub = gather(view, tail)
+            else:       # several devices' columns: the first one gathers
+                sub = gathered.get(partial(gather, view, tail))
+            arr = host_to_device(eng, sub, dev)
+            parts.append(arr)
+            return (arr,)
+
+        def join(dev, parts):
+            """Behind ``dev``'s last put of a span, on its worker."""
+            with span("strom.restore.join", _CAT, parts=len(parts)):
+                tensor.arrays[dev] = (parts[0] if len(parts) == 1
+                                      else jnp.concatenate(parts))
+            parts.clear()
+
+        # The staging buffers are the stage's from the hand-over on: it
+        # releases a chunk's once every array put out of it is ready
+        # (its one StagingRetirePool).  This thread only reads: plan,
+        # wait, the CRC pass, and the hand-over (strom.restore.put_wait
+        # is its wait for room in the stage).
         fh = eng.open(sf.path)
-        device_arrays = {}
-        # Deferred staging release (shared DeviceStream discipline):
-        # the per-chunk block_until_ready this replaces paid one link
-        # round trip per weight chunk — on a high-latency link that
-        # serialized the whole load.  Budgeted against the engine's
-        # staging pool: _stream_span keeps up to stream_depth reads in
-        # flight, the pool holds retired-pending entries, and their sum
-        # must leave a free buffer or a deferred submit could wait on
-        # memory only this consumer can release (deadlock).  Tiny pools
-        # degrade to depth 0 = the old block-per-chunk behavior.
-        stream_depth = max(2, eng.config.queue_depth // 2)
-        retire = StagingRetirePool(
-            max(0, min(eng.config.queue_depth // 2,
-                       eng.n_buffers - stream_depth - 1)))
         try:
             for (r0, r1), devs in spans.items():
                 full_span = (r0, r1) == (0, gshape[0] if gshape else 1)
                 check = (stamp is not None and full_span
                          and policy.want())
                 crc = 0
-                parts: Dict[object, list] = {dev: [] for dev, _ in devs}
+                parts: Dict[object, list] = {dev: [] for dev, _, _ in devs}
+                tkeys = [tkey for _, _, tkey in devs if tkey]
+                shared = {k for k in tkeys if tkeys.count(k) > 1}
                 for view, release in self._stream_span(
                         eng, fh, sf, name, r0, r1, np_dt, gshape,
                         klass=klass):
@@ -253,54 +351,24 @@ class LazyCheckpoint:
                                   bytes=int(view.nbytes)):
                             crc = crc32c(view, crc)
                         eng.stats.add(bytes_verified=int(view.nbytes))
-                    cache: Dict[tuple, np.ndarray] = {}
-                    put = []
-                    for dev, tail in devs:
-                        # hashable key: slice objects only hash on
-                        # 3.12+, and devs sharing a column shard must
-                        # share the gathered sub-array
-                        tkey = tuple((s.start, s.stop) for s in tail)
-                        sub = cache.get(tkey)
-                        if sub is None:
-                            sub = view
-                            if tail and any(
-                                    (s.start, s.stop) != (0, d)
-                                    for s, d in zip(tail, gshape[1:])):
-                                cut = view[(slice(None),) + tail]
-                                # strided column shard: host gather copies
-                                with span("strom.restore.slice", _CAT,
-                                          bytes=int(cut.nbytes)):
-                                    sub = np.ascontiguousarray(cut)
-                                eng.stats.add(
-                                    bounce_bytes=int(sub.nbytes))
-                            cache[tkey] = sub
-                        arr = host_to_device(eng, sub, dev)
-                        parts[dev].append(arr)
-                        put.append(arr)
-                    with span("strom.restore.retire", _CAT):
-                        retire.push(release, put)
+                    # devs sharing a column shard share the gathered
+                    # sub-array: the first worker to want it makes it
+                    gathers = {tkey: Once() for tkey in shared}
+                    stage.put(release, [
+                        (dev, partial(put_share, view, dev, tail,
+                                      gathers.get(tkey), parts[dev]))
+                        for dev, tail, tkey in devs])
                 if check and crc != stamp:
                     eng.stats.add(checksum_failures=1)
                     raise ChecksumError(
                         f"tensor {name} of {sf.path} fails its stamped "
                         f"CRC32C ({crc:#010x} != {stamp:#010x}) — "
                         f"corrupt weights must not reach the model")
-                with span("strom.restore.join", _CAT,
-                          parts=sum(len(ps) for ps in parts.values())):
-                    for dev, _ in devs:
-                        ps = parts[dev]
-                        device_arrays[dev] = (
-                            ps[0] if len(ps) == 1
-                            else jnp.concatenate(ps))
+                for dev, _, _ in devs:
+                    stage.then(dev, partial(join, dev, parts[dev]))
         finally:
-            with span("strom.restore.retire", _CAT):
-                retire.flush()
             eng.close(fh)
-
-        arrays = [device_arrays[d] for d in idx_map]
-        with span("strom.restore.join", _CAT, parts=len(arrays)):
-            return jax.make_array_from_single_device_arrays(
-                gshape, sharding, arrays)
+        return tensor
 
     def _stream_span(self, eng, fh, sf, name, r0, r1, np_dt, gshape,
                      klass: str = "restore"):

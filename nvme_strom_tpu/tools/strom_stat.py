@@ -60,7 +60,7 @@ _BATCH_COUNTERS = (
 #: it must be visible here, not only in a flamegraph
 _ENGINE_COUNTERS = (
     "submit_enters", "arena_fallbacks", "overlap_chunks",
-    "overlap_bytes",
+    "overlap_bytes", "restore_puts_staged", "restore_puts_inline",
 )
 
 #: QoS scheduler counters (io/sched.py over the multi-ring engine —

@@ -27,8 +27,28 @@ in two parts:
    staging < heap would indicate PJRT exploits the pinned/aligned
    source directly (true DMA).
 
+3. **The host→device ceiling (``--sizes``).**  For every size and thread
+   count: ``threads`` Python threads, each with a staging view of its
+   own, thread *t* putting onto device ``t % devices``.  First each put
+   is blocked on (``return_us``: the call's median time to return,
+   ``ready_us``: to ``block_until_ready``); then every thread issues
+   ``repeats`` puts back to back and blocks on them all (``gib_s``: all
+   threads' bytes over the wall time to the last one ready — what a
+   pipelined consumer such as ``load_sharded`` can reach;
+   ``gib_s_blocking``: the same for the put-and-wait loop).  What a
+   second thread adds onto ONE device says how much of a put is
+   serialised under the GIL or inside the client; the 64 MiB row is
+   the link.  One JSON line a (size, threads) pair, ``"sweep": true``.
+   With ``--gil-seconds S`` each pair also runs its threads for S
+   seconds, 8 puts in flight each, beside a thread that only counts in
+   Python: ``puts_per_s``, and ``gil_held_share`` — the part of its
+   solo rate the counting thread lost, which is the time the putting
+   threads held the GIL (the switch interval is 0.1 ms meanwhile).
+
 Usage: python -m nvme_strom_tpu.tools.transfer_diag [--bytes N]
-Prints one JSON line with the alias verdict and the three medians.
+           [--sizes N,N,... [--threads 1,2,4] [--devices D]]
+Prints one JSON line with the alias verdict and the three medians, then
+the sweep's lines.
 """
 
 from __future__ import annotations
@@ -105,15 +125,180 @@ def run(nbytes: int, repeats: int = 5) -> dict:
         os.unlink(path)
 
 
+def _threads_run(n: int, work) -> float:
+    """Run ``work(t)`` on ``n`` threads released together; the wall
+    seconds from the release to the last one's return."""
+    import threading
+
+    gate = threading.Barrier(n + 1)
+    errs: list = []
+
+    def body(t):
+        gate.wait()
+        try:
+            work(t)
+        except BaseException as e:   # surfaced below, never swallowed
+            errs.append(e)
+
+    ths = [threading.Thread(target=body, args=(t,), daemon=True)
+           for t in range(n)]
+    for th in ths:
+        th.start()
+    gate.wait()
+    t0 = time.monotonic()
+    for th in ths:
+        th.join()
+    wall = time.monotonic() - t0
+    if errs:
+        raise errs[0]
+    return wall
+
+
+def _gil_probe(n: int, put, seconds: float) -> dict:
+    """``n`` threads calling ``put(t)`` for ``seconds`` (8 results in
+    flight each) beside a thread that only counts: the puts a second,
+    and the share of its solo rate the counting thread lost."""
+    import collections
+    import threading
+
+    def count_for(stop) -> float:
+        n_loops, t0 = 0, time.monotonic()
+        while not stop.is_set():
+            n_loops += 1
+        return n_loops / (time.monotonic() - t0)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        stop = threading.Event()
+        threading.Timer(seconds / 2, stop.set).start()
+        solo = count_for(stop)
+        stop = threading.Event()
+        puts = [0] * n
+        beside: list = []
+        spinner = threading.Thread(
+            target=lambda: beside.append(count_for(stop)), daemon=True)
+
+        def work(t):
+            live: collections.deque = collections.deque()
+            end = time.monotonic() + seconds
+            while time.monotonic() < end:
+                live.append(put(t))
+                puts[t] += 1
+                if len(live) > 8:
+                    live.popleft().block_until_ready()
+            for a in live:
+                a.block_until_ready()
+
+        spinner.start()
+        wall = _threads_run(n, work)
+        stop.set()
+        spinner.join()
+    finally:
+        sys.setswitchinterval(old)
+    return {"puts_per_s": round(sum(puts) / wall, 1),
+            "gil_held_share": round(max(0.0, 1 - beside[0] / solo), 4)}
+
+
+def _measure(n: int, put, repeats: int, size: int,
+             gil_seconds: float) -> dict:
+    """One (size, thread count) of the sweep: ``put(t)`` is thread
+    ``t``'s ``device_put``."""
+    ret: list = []
+    rdy: list = []
+
+    def blocking(t):
+        for _ in range(repeats):
+            t0 = time.monotonic()
+            a = put(t)
+            t1 = time.monotonic()
+            a.block_until_ready()
+            ret.append(t1 - t0)
+            rdy.append(time.monotonic() - t0)
+
+    def pipelined(t):
+        for a in [put(t) for _ in range(repeats)]:
+            a.block_until_ready()
+
+    wall_b = _threads_run(n, blocking)
+    wall_p = _threads_run(n, pipelined)
+    gib = n * repeats * size / 2**30
+    return {"return_us": round(statistics.median(ret) * 1e6, 1),
+            "ready_us": round(statistics.median(rdy) * 1e6, 1),
+            "gib_s": round(gib / wall_p, 3),
+            "gib_s_blocking": round(gib / wall_b, 3),
+            **(_gil_probe(n, put, gil_seconds) if gil_seconds > 0 else {})}
+
+
+def sweep(sizes, threads=(1,), n_devices: int = 1,
+          repeats: int = 8, gil_seconds: float = 0.0) -> list:
+    """The host→device ceiling: one dict a (size, thread count), see the
+    module docstring's part 3.  Every put's source is an engine staging
+    view (one a thread), as ``load_sharded``'s puts are."""
+    import jax
+    from nvme_strom_tpu.io.engine import StromEngine
+    from nvme_strom_tpu.utils.config import EngineConfig
+
+    devs = jax.devices()[:max(1, n_devices)]
+    base = EngineConfig()
+    rows = []
+    for size in sizes:
+        size = -(-int(size) // base.alignment) * base.alignment
+        n_max = max(threads)
+        cfg = EngineConfig(chunk_bytes=size, buffer_pool_bytes=max(
+            base.buffer_pool_bytes, 2 * n_max * size))
+        with tempfile.NamedTemporaryFile(delete=False) as f:
+            f.write(os.urandom(size))
+            path = f.name
+        try:
+            with StromEngine(cfg) as eng:
+                fh = eng.open(path)
+                reads = [eng.submit_read(fh, 0, size) for _ in range(n_max)]
+                views = [pr.wait() for pr in reads]
+                jax.device_put(views[0], devs[0]).block_until_ready()
+                def put(t):
+                    return jax.device_put(views[t], devs[t % len(devs)])
+
+                for n in threads:
+                    rows.append({
+                        "sweep": True, "bytes": size, "threads": n,
+                        "devices": len(devs), "repeats": repeats,
+                        "platform": devs[0].platform,
+                        **_measure(n, put, repeats, size, gil_seconds)})
+                for pr in reads:
+                    pr.release()
+                eng.close(fh)
+        finally:
+            os.unlink(path)
+    return rows
+
+
+def _ints(text: str) -> list:
+    return [int(x) for x in text.split(",") if x.strip()]
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="transfer_diag",
         description="zero-copy boundary evidence (alias proof + timing)")
     ap.add_argument("--bytes", type=int, default=4 << 20)
     ap.add_argument("--repeats", type=int, default=5)
+    ap.add_argument("--sizes", type=_ints, default=[],
+                    help="bytes, comma-separated: sweep puts of these "
+                         "sizes out of staging views")
+    ap.add_argument("--threads", type=_ints, default=[1],
+                    help="thread counts of the sweep (1,2,4)")
+    ap.add_argument("--devices", type=int, default=1,
+                    help="devices the sweep's threads put onto")
+    ap.add_argument("--gil-seconds", type=float, default=0.0,
+                    help="seconds a (size, threads) pair puts beside a "
+                         "counting thread (0: not measured)")
     args = ap.parse_args(argv)
     res = run(args.bytes, args.repeats)
-    print(json.dumps(res))
+    print(json.dumps(res), flush=True)
+    for row in sweep(args.sizes, args.threads, args.devices,
+                     max(args.repeats, 2), args.gil_seconds):
+        print(json.dumps(row), flush=True)
     return 0 if res.get("view_in_pool") else 1
 
 

@@ -97,6 +97,13 @@ class StromStats:
     # as zeros next to a busy stream
     overlap_chunks: int = 0
     overlap_bytes: int = 0
+    # device puts of a weight restore (ops/bridge.PutStage): issued by
+    # one of the stage's workers beside the reading thread / issued on
+    # the reading thread itself (a staging pool with no room for a
+    # queue, a single tensor's load).  load_sharded over a default
+    # pool counts staged only
+    restore_puts_staged: int = 0
+    restore_puts_inline: int = 0
     # -- resilience counters (io/faults.py, io/resilient.py) --------------
     # faults injected by an active FaultPlan (test/chaos runs; 0 in prod)
     faults_injected: int = 0

@@ -416,3 +416,281 @@ def test_tpu_transfer_failure_raises_instead_of_degrading(
     assert engine.stats.bytes_to_device == 0
     info = engine.pool_info()
     assert info["free_buffers"] == info["n_buffers"]
+
+
+# ---------------------------------------------------------------------------
+# PutStage: the transfer stage between load_sharded's reading thread and
+# the devices (PERF.md §3, §6 PR 45)
+# ---------------------------------------------------------------------------
+
+def _within(seconds):
+    """Per-test timeout (no pytest-timeout here): the body runs on a
+    thread of its own; one that has not ended in ``seconds`` fails the
+    test instead of hanging the run."""
+    import functools
+    import threading
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            box = []
+
+            def body():
+                try:
+                    fn(*args, **kwargs)
+                except BaseException as e:
+                    box.append(e)
+
+            t = threading.Thread(target=body, daemon=True,
+                                 name="test-body")
+            t.start()
+            t.join(seconds)
+            assert not t.is_alive(), f"{fn.__name__}: over {seconds}s"
+            if box:
+                raise box[0]
+        return wrapper
+    return deco
+
+
+def _stage_threads():
+    import threading
+    return sorted(t.name for t in threading.enumerate()
+                  if t.name.startswith("strom-put"))
+
+
+class _GatedArray:
+    """A transfer whose readiness the test controls."""
+
+    def __init__(self):
+        import threading
+        self.gate = threading.Event()
+
+    def is_ready(self):
+        return self.gate.is_set()
+
+    def block_until_ready(self):
+        assert self.gate.wait(20), "the test never made this array ready"
+        return self
+
+
+class _Buffers:
+    """Staging buffers of a fake reader: ``release(i)`` records the
+    release and what the arrays put out of buffer ``i`` said then."""
+
+    def __init__(self):
+        self.arrays = {}            # chunk -> [its _GatedArray]
+        self.released = []          # chunk ids, in release order
+        self.live_at_release = []   # chunks released under a live array
+
+    def job(self, i, ready=False, log=None, dev=None, delay=0.0):
+        def put():
+            import time
+            time.sleep(delay)
+            arr = _GatedArray()
+            if ready:
+                arr.gate.set()
+            self.arrays.setdefault(i, []).append(arr)
+            if log is not None:
+                log[dev].append(i)
+            return [arr]
+        return put
+
+    def release(self, i):
+        def rel():
+            if not all(a.is_ready() for a in self.arrays.get(i, ())):
+                self.live_at_release.append(i)
+            self.released.append(i)
+        return rel
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+@_within(60)
+def test_put_stage_releases_only_after_every_array_is_ready(engine, n_dev):
+    """Rule 1: a staging buffer is never recycled under a live
+    transfer — with one device and with four putting out of it."""
+    import time
+    from nvme_strom_tpu.ops.bridge import PutStage
+    before = _stage_threads()
+    bufs = _Buffers()
+    stage = PutStage(engine, depth=2, retire_depth=0)
+    devs = [f"dev{k}" for k in range(n_dev)]
+    stage.put(bufs.release(0), [(d, bufs.job(0)) for d in devs])
+    while len(bufs.arrays.get(0, ())) < n_dev:      # every share was put
+        time.sleep(0.001)
+    assert bufs.released == []
+    for arr in bufs.arrays[0][:-1]:                 # all but one ready
+        arr.gate.set()
+    time.sleep(0.05)
+    assert bufs.released == []
+    bufs.arrays[0][-1].gate.set()
+    stage.close()
+    assert bufs.released == [0] and not bufs.live_at_release
+    assert _stage_threads() == before
+    assert engine.stats.restore_puts_staged == n_dev
+    assert engine.stats.restore_puts_inline == 0
+
+
+@_within(60)
+def test_put_stage_keeps_chunk_order_under_uneven_workers(engine):
+    """Rule 2: each device's jobs run in the order they were handed in,
+    whatever the other workers do, and ``then`` runs behind them; rule
+    3's bound: never more than ``depth`` chunks in the stage."""
+    from nvme_strom_tpu.ops.bridge import PutStage
+    bufs = _Buffers()
+    devs = [f"dev{k}" for k in range(4)]
+    log = {d: [] for d in devs}
+    stage = PutStage(engine, depth=3, retire_depth=2)
+    most = 0
+    for i in range(24):
+        # worker k sleeps on chunks i % 4 == k: they finish out of order
+        stage.put(bufs.release(i), [
+            (d, bufs.job(i, ready=True, log=log, dev=d,
+                         delay=0.004 if i % 4 == k else 0.0))
+            for k, d in enumerate(devs)])
+        handed, out = i + 1, len(bufs.released)
+        most = max(most, handed - out)
+        if i % 8 == 7:
+            for d in devs:
+                stage.then(d, lambda d=d, i=i: log[d].append(("join", i)))
+    stage.close()
+    want = []
+    for i in range(24):
+        want.append(i)
+        if i % 8 == 7:
+            want.append(("join", i))
+    assert all(log[d] == want for d in devs)
+    assert sorted(bufs.released) == list(range(24))
+    assert not bufs.live_at_release
+    assert most <= 3 + 2 + 1        # depth + retire depth + the one in hand
+    assert _stage_threads() == []
+
+
+@pytest.mark.parametrize("who", ["worker", "reader"])
+@_within(60)
+def test_put_stage_failure_reaches_the_caller_and_frees_everything(
+        engine, who):
+    """Rule 4: a worker's exception and the reader's both reach the
+    caller, every buffer handed in is released, no worker is left."""
+    from nvme_strom_tpu.ops.bridge import PutStage
+    before = _stage_threads()
+    bufs = _Buffers()
+    devs = ["dev0", "dev1"]
+
+    def boom():
+        raise RuntimeError("put failed")
+
+    handed = []
+    with pytest.raises(RuntimeError, match="put failed|reader failed"):
+        stage = PutStage(engine, depth=2, retire_depth=2)
+        try:
+            for i in range(40):
+                jobs = [(d, bufs.job(i, ready=True)) for d in devs]
+                if who == "worker" and i == 5:
+                    jobs[1] = ("dev1", boom)
+                if who == "reader" and i == 7:
+                    raise RuntimeError("reader failed")
+                handed.append(i)
+                stage.put(bufs.release(i), jobs)
+        finally:
+            stage.close()
+    assert sorted(bufs.released) == handed and handed
+    assert not bufs.live_at_release
+    assert _stage_threads() == before
+    if who == "worker":
+        assert len(handed) < 40         # the reader was stopped early
+
+
+@_within(60)
+def test_put_stage_depth_zero_runs_on_the_calling_thread(engine):
+    import threading
+    from nvme_strom_tpu.ops.bridge import PutStage
+    bufs = _Buffers()
+    ran_on = []
+    stage = PutStage(engine, depth=0, retire_depth=0)
+
+    def job():
+        ran_on.append(threading.current_thread())
+        return bufs.job(0, ready=True)()
+
+    stage.put(bufs.release(0), [("dev0", job), ("dev1", job)])
+    stage.then("dev0", lambda: ran_on.append(threading.current_thread()))
+    assert bufs.released == [0]         # retire depth 0: block per chunk
+    stage.close()
+    assert set(ran_on) == {threading.current_thread()}
+    assert _stage_threads() == []
+    assert engine.stats.restore_puts_inline == 2
+    assert engine.stats.restore_puts_staged == 0
+
+
+def _column_checkpoint(tmp_path, rows=96, cols=256):
+    from nvme_strom_tpu.formats import write_safetensors
+    rng = np.random.default_rng(45)
+    tensors = {f"w{i}": rng.standard_normal((rows, cols)).astype(np.float32)
+               for i in range(3)}
+    tensors["bias"] = rng.standard_normal((cols,)).astype(np.float32)
+    path = tmp_path / "stage.safetensors"
+    write_safetensors(path, tensors)
+    return path, tensors
+
+
+def _tp4_shardings(tensors):
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(jax.devices()[:4]), ("tp",))
+    return {n: NamedSharding(mesh, P(None, "tp") if t.ndim == 2 else P())
+            for n, t in tensors.items()}
+
+
+@pytest.mark.parametrize("pool", ["smallest", "default"])
+@_within(120)
+def test_load_sharded_stages_by_the_pools_size(tmp_path, pool):
+    """Rule 3: a pool too small for any queue (the smallest the engine
+    accepts: two buffers) takes the synchronous path and counts
+    ``restore_puts_inline``; the default pool counts only
+    ``restore_puts_staged``.  Either way the bytes are the file's."""
+    from nvme_strom_tpu.parallel.weights import (LazyCheckpoint,
+                                                 _stage_depths)
+    path, tensors = _column_checkpoint(tmp_path)
+    cfg = (EngineConfig(chunk_bytes=1 << 16, queue_depth=4,
+                        buffer_pool_bytes=1 << 16)
+           if pool == "smallest" else EngineConfig())
+    with StromEngine(cfg, stats=StromStats()) as eng:
+        stage_depth, retire_depth = _stage_depths(eng, staged=True)
+        if pool == "smallest":
+            assert eng.n_buffers == 2 and (stage_depth, retire_depth) == (0, 0)
+        else:
+            assert (eng.n_buffers, stage_depth, retire_depth) == (64, 8, 8)
+        half = eng.config.queue_depth // 2
+        assert max(2, half) + stage_depth + retire_depth < eng.n_buffers \
+            or stage_depth == retire_depth == 0
+        params = LazyCheckpoint(path).load_sharded(_tp4_shardings(tensors),
+                                                   engine=eng)
+        for name, ref in tensors.items():
+            np.testing.assert_array_equal(np.asarray(params[name]), ref)
+        staged = eng.stats.restore_puts_staged
+        inline = eng.stats.restore_puts_inline
+        info = eng.pool_info()
+        assert info["free_buffers"] == info["n_buffers"]
+    assert staged + inline > 0
+    assert (staged == 0) if pool == "smallest" else (inline == 0)
+    assert _stage_threads() == []
+
+
+@_within(240)
+def test_two_hundred_loads_back_to_back_do_not_deadlock(tmp_path):
+    from nvme_strom_tpu.parallel.weights import LazyCheckpoint
+    path, tensors = _column_checkpoint(tmp_path)
+    shardings = _tp4_shardings(tensors)
+    cfg = EngineConfig(chunk_bytes=1 << 14, queue_depth=8,
+                       buffer_pool_bytes=16 << 14)
+    with StromEngine(cfg, stats=StromStats()) as eng:
+        ck = LazyCheckpoint(path)
+        for i in range(200):
+            params = ck.load_sharded(shardings, engine=eng)
+            if i % 50 == 0:
+                for name, ref in tensors.items():
+                    np.testing.assert_array_equal(
+                        np.asarray(params[name]), ref)
+        assert eng.stats.restore_puts_inline == 0
+        assert eng.stats.restore_puts_staged > 200 * 4
+    assert _stage_threads() == []

@@ -29,8 +29,12 @@ SERVE_SPANS = ("strom.serve.step", "strom.serve.plan",
                "strom.serve.readback", "strom.serve.replay")
 RESTORE_SPANS = ("strom.restore.load", "strom.restore.tensor",
                  "strom.restore.plan", "strom.restore.read_wait",
-                 "strom.restore.slice", "strom.h2d",
-                 "strom.restore.retire", "strom.restore.join")
+                 "strom.restore.put_wait", "strom.restore.slice",
+                 "strom.h2d", "strom.restore.retire", "strom.restore.join")
+#: the restore's spans that a ``PutStage`` worker opens (PR 45): they lie
+#: on the workers' lines, beside the reading thread's and not inside them
+WORKER_SPANS = ("strom.h2d", "strom.restore.slice", "strom.restore.retire",
+                "strom.restore.join")
 BLOCK = 8
 #: a prompt that pads (11 -> 16) and one of whole blocks (16 -> 16)
 PROMPT_LENS = (11, 16)
@@ -183,14 +187,31 @@ def test_no_program_span_has_a_benchmark_phase_name(traced):
     ("strom.serve.readback", "strom.serve.step"),
     ("strom.serve.replay", "strom.serve.step"),
     ("strom.restore.read_wait", "strom.restore.tensor"),
-    ("strom.h2d", "strom.restore.tensor"),
-    ("strom.restore.retire", "strom.restore.tensor"),
-    ("strom.restore.slice", "strom.restore.tensor"),
-    ("strom.restore.join", "strom.restore.tensor"),
+    ("strom.restore.plan", "strom.restore.tensor"),
+    ("strom.restore.put_wait", "strom.restore.tensor"),
     ("strom.restore.tensor", "strom.restore.load"),
 ])
 def test_spans_nest_as_the_code_nests(traced, inner, outer):
     assert _lies_inside(traced["threads"], inner, outer)
+
+
+@pytest.mark.parametrize("name", WORKER_SPANS)
+def test_restore_worker_spans_lie_beside_the_reading_thread(traced, name):
+    """The gathers, puts, pushes and joins of a restore are the stage's
+    workers': on lines without ``strom.restore.load``, and in time inside
+    the load (``close`` ends the workers before the load's span does)."""
+    loads = [(s, e) for evs in traced["threads"] for n, s, e in evs
+             if n == "strom.restore.load"]
+    (load,) = loads
+    on_workers = 0
+    for evs in traced["threads"]:
+        reader = any(n == "strom.restore.load" for n, _, _ in evs)
+        for n, s, e in evs:
+            if n != name or not (load[0] <= s <= load[1]):
+                continue            # (the serving half's own strom.h2d)
+            assert e <= load[1]
+            on_workers += not reader
+    assert on_workers > 0
 
 
 def test_admission_counters(traced):

@@ -129,6 +129,35 @@ def test_transfer_diag_alias_proof(capsys):
     assert res["t_staging_s"] > 0 and res["t_copy_heap_s"] > 0
 
 
+def test_transfer_diag_sweep_json_lines(capsys):
+    """``--sizes`` / ``--threads`` / ``--devices``: one JSON line a
+    (size, thread count) after the alias line, each with the put's
+    time to return, time to ready and GiB/s to ready, sources in the
+    staging pool (the host→HBM ceiling of PERF.md §5; here the CPU
+    platform, mechanics only)."""
+    import json
+    from nvme_strom_tpu.tools import transfer_diag
+    rc = transfer_diag.main(["--bytes", "65536", "--repeats", "2",
+                             "--sizes", "65536,100000", "--threads", "1,2",
+                             "--devices", "2", "--gil-seconds", "0.05"])
+    assert rc == 0
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    assert lines[0]["view_in_pool"] is True and "sweep" not in lines[0]
+    rows = lines[1:]
+    # 100000 rounds up to the engine's alignment
+    assert [(r["bytes"], r["threads"]) for r in rows] == [
+        (65536, 1), (65536, 2), (102400, 1), (102400, 2)]
+    for r in rows:
+        assert r["sweep"] is True and r["platform"] == "cpu"
+        assert r["devices"] == 2 and r["repeats"] == 2
+        assert 0 < r["return_us"] <= r["ready_us"]
+        assert r["gib_s"] > 0 and r["gib_s_blocking"] > 0
+        assert r["puts_per_s"] > 0 and 0 <= r["gil_held_share"] <= 1
+    # without --gil-seconds the probe's keys are left out, not zero
+    (row,) = transfer_diag.sweep([65536], threads=(1,), repeats=2)
+    assert "gil_held_share" not in row and "puts_per_s" not in row
+
+
 def test_strom_stat_renders_member_bytes(capsys):
     """Per-member attribution shows up in the CLI render with shares."""
     from nvme_strom_tpu.tools.strom_stat import render

@@ -211,3 +211,109 @@ def test_lazy_load_zero_size_tensor(mesh8, engine, tmp_path):
     assert np.asarray(params["empty"]).shape == (4, 0)
     np.testing.assert_array_equal(np.asarray(params["real"]),
                                   np.ones((4, 4), np.float32))
+
+
+# ---------------------------------------------------------------------------
+# load_sharded over the transfer stage (ops/bridge.PutStage, PR 45)
+# ---------------------------------------------------------------------------
+
+def _staged_engine():
+    """Sixteen buffers of 64 KiB: room for a queue, so the loads below run
+    their gathers and puts on the stage's workers, several chunks a
+    tensor."""
+    return StromEngine(EngineConfig(chunk_bytes=1 << 16, queue_depth=8,
+                                    buffer_pool_bytes=16 << 16),
+                       stats=StromStats())
+
+
+def _stage_threads():
+    import threading
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith("strom-put")]
+
+
+def _staged_sharding(layout):
+    import jax
+    from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+    if layout == "single":
+        return SingleDeviceSharding(jax.devices()[0])
+    mesh = Mesh(np.array(jax.devices()[:4]), ("x",))
+    return NamedSharding(mesh, {"rows": P("x", None), "cols": P(None, "x"),
+                                "replicated": P()}[layout])
+
+
+@pytest.mark.parametrize("verify", ["off", "full"])
+@pytest.mark.parametrize("layout", ["single", "rows", "cols", "replicated"])
+def test_staged_load_equals_the_file_bit_for_bit(tmp_path, monkeypatch,
+                                                 layout, verify):
+    monkeypatch.setenv("STROM_VERIFY", verify)
+    rng = np.random.default_rng(7)
+    tensors = {"big": rng.standard_normal((512, 256)).astype(np.float32),
+               "small": rng.standard_normal((8, 64)).astype(np.float32)}
+    path = tmp_path / "m.safetensors"
+    write_safetensors(path, tensors)
+    sh = _staged_sharding(layout)
+    with _staged_engine() as eng:
+        params = LazyCheckpoint(path).load_sharded(
+            {name: sh for name in tensors}, engine=eng)
+        for name, ref in tensors.items():
+            assert params[name].sharding.is_equivalent_to(sh, ref.ndim)
+            assert np.asarray(params[name]).tobytes() == ref.tobytes()
+            for shard in params[name].addressable_shards:
+                assert np.asarray(shard.data).tobytes() \
+                    == ref[shard.index].tobytes()
+        # big: 512 KiB in 64 KiB chunks, every device a share of each
+        # chunk it reads; the strided shares are host gathers
+        assert eng.stats.restore_puts_inline == 0
+        assert eng.stats.restore_puts_staged == \
+            {"single": 9, "rows": 12, "cols": 36, "replicated": 36}[layout]
+        gathered = eng.stats.snapshot()["bounce_bytes"]
+        if layout == "cols":        # (the CPU platform's copies besides)
+            assert gathered >= sum(t.nbytes for t in tensors.values())
+        # a row shard's span is not the whole tensor: no stamp covers it
+        assert eng.stats.bytes_verified == (
+            sum(t.nbytes for t in tensors.values())
+            if verify == "full" and layout != "rows" else 0)
+        info = eng.pool_info()
+        assert info["free_buffers"] == info["n_buffers"]
+    assert not _stage_threads()
+
+
+@pytest.mark.parametrize("layout", ["single", "cols"])
+def test_corrupted_stamp_still_raises_before_anything_is_returned(
+        tmp_path, monkeypatch, layout):
+    from nvme_strom_tpu.utils.checksum import ChecksumError
+    monkeypatch.setenv("STROM_VERIFY", "full")
+    rng = np.random.default_rng(8)
+    tensors = {"first": rng.standard_normal((64, 64)).astype(np.float32),
+               "big": rng.standard_normal((512, 256)).astype(np.float32),
+               "last": rng.standard_normal((64, 64)).astype(np.float32)}
+    path = tmp_path / "m.safetensors"
+    write_safetensors(path, tensors)
+    sh = _staged_sharding(layout)
+    lc = LazyCheckpoint(path)
+    off = lc.files[0].tensors["big"]["offset"] + 300_000
+    with open(path, "r+b") as f:    # one flipped bit inside ``big``
+        f.seek(off)
+        b = f.read(1)
+        f.seek(off)
+        f.write(bytes([b[0] ^ 0x10]))
+    with _staged_engine() as eng:
+        got = None
+        with pytest.raises(ChecksumError, match="corrupt weights"):
+            got = LazyCheckpoint(path).load_sharded(
+                {name: sh for name in tensors}, engine=eng)
+        assert got is None
+        assert eng.stats.checksum_failures == 1
+        info = eng.pool_info()
+        assert info["free_buffers"] == info["n_buffers"]
+        # every staging buffer came back: the same engine loads again
+        monkeypatch.setenv("STROM_VERIFY", "off")
+        again = LazyCheckpoint(path).load_sharded(
+            {name: sh for name in tensors}, engine=eng)
+        assert np.asarray(again["last"]).tobytes() \
+            == tensors["last"].tobytes()
+        info = eng.pool_info()
+        assert info["free_buffers"] == info["n_buffers"]
+    assert not _stage_threads()
