@@ -641,8 +641,19 @@ int main(int argc, char **argv) {
       usleep(5000);
       strom_set_ring_stall(eng, 1, 1);
       usleep(3000);
-      int64_t rc = strom_ring_restart(eng, 1, 500000000ull);
-      if (rc < 0 && rc != -EBUSY) fail("sqpoll-phase ring_restart");
+      /* -EBUSY: the churn thread's open/close holds the restart lock
+       * for its slot-table update.  The restart is what disarms the
+       * stall, so one refused attempt must not be the last: the parked
+       * requests would wait for ever. */
+      int64_t rc = -EBUSY;
+      for (int i = 0; i < 1000 && rc == -EBUSY; i++) {
+        rc = strom_ring_restart(eng, 1, 500000000ull);
+        if (rc == -EBUSY) usleep(1000);
+      }
+      if (rc < 0) {
+        fail("sqpoll-phase ring_restart");
+        strom_set_ring_stall(eng, 1, 0);   /* let the phase end */
+      }
     });
     for (auto &t : ts) t.join();
     stop.store(true, std::memory_order_release);

@@ -132,6 +132,16 @@ struct io_uring_files_update_ {
   uint64_t fds;   /* pointer to int32_t fds */
 };
 
+inline uint64_t now_ns() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (uint64_t)ts.tv_sec * 1000000000ull + (uint64_t)ts.tv_nsec;
+}
+
+/* How long a submission waits on a full SQ under SQPOLL for the kernel's
+ * SQ thread to consume an entry before the request fails with -EBUSY. */
+static constexpr uint64_t kSqFullWaitNs = 2000000000ull;
+
 struct Uring {
   int fd = -1;
   uint32_t *sq_head = nullptr, *sq_tail = nullptr, *sq_mask = nullptr;
@@ -349,16 +359,29 @@ struct Uring {
              bool flush_now = true, uint8_t sqe_flags = 0) {
     uint32_t tail = *sq_tail;
     uint32_t head = __atomic_load_n(sq_head, __ATOMIC_ACQUIRE);
-    if (tail - head >= sq_entries) {
+    if (tail - head >= sq_entries && sqpoll) {
+      /* SQ full under SQPOLL: only the kernel's SQ thread consumes
+       * entries, and it does so as soon as it RUNS — a full queue means
+       * it has not been scheduled since the last sq_entries submissions
+       * (a busy or virtualised host; sanitizer slowdown on this side).
+       * Spinning on the doorbell does not run it any sooner: wake it if
+       * it sleeps, try a few times for a poller that is mid-drain on
+       * another core, then give up the CPU between checks until the
+       * deadline.  These polls are not elided doorbells. */
+      const uint64_t deadline = now_ns() + kSqFullWaitNs;
+      for (int i = 0; tail - head >= sq_entries; i++) {
+        if (i >= 64) {
+          if (now_ns() >= deadline) return -EBUSY;
+          usleep(50);
+        }
+        sqpoll_kick(/*count_elide=*/false);
+        head = __atomic_load_n(sq_head, __ATOMIC_ACQUIRE);
+      }
+    } else if (tail - head >= sq_entries) {
       /* SQ full: nudge the kernel and spin-wait (bounded by in-flight I/O). */
       for (int i = 0; i < 100000 && tail - head >= sq_entries; i++) {
-        if (sqpoll) sqpoll_kick(/*count_elide=*/false);  /* poller
-                                          drains the SQ; spin polls are
-                                          not elided doorbells */
-        else {
-          flush();
-          syscall(__NR_io_uring_enter, fd, 0, 0, 0, nullptr, 0);
-        }
+        flush();
+        syscall(__NR_io_uring_enter, fd, 0, 0, 0, nullptr, 0);
         head = __atomic_load_n(sq_head, __ATOMIC_ACQUIRE);
       }
       if (tail - head >= sq_entries) return -EBUSY;
@@ -435,12 +458,6 @@ struct FileEnt {
 };
 
 enum class ReqState { kInflight, kDone };
-
-inline uint64_t now_ns() {
-  struct timespec ts;
-  clock_gettime(CLOCK_MONOTONIC, &ts);
-  return (uint64_t)ts.tv_sec * 1000000000ull + (uint64_t)ts.tv_nsec;
-}
 
 /* Is [offset, offset+len) fully resident in the page cache?  The
  * reference's kernel module checks this per block and returns resident
@@ -857,8 +874,11 @@ void RingCtx::complete_locked(Req *r) {
 
 /* Hand a buffer-holding request to the backend. The ring mutex must be
  * held (files_mu is a leaf lock and may be taken under it).
- * Submissions never block: if the ring is jammed (practically impossible —
- * we drain the SQ on every enter) the request fails with -EBUSY.
+ * A submission waits only on a full SQ: without SQPOLL it enters the
+ * kernel, which drains the SQ; under SQPOLL it sleeps in 50 us steps until
+ * the kernel's SQ thread has run (common on a busy or virtualised host,
+ * where that thread is not scheduled for milliseconds).  A queue still
+ * full after kSqFullWaitNs fails the request with -EBUSY.
  * ``flush_now = false`` defers the uring doorbell (vectored submit:
  * the caller flushes once for the whole batch). */
 void RingCtx::dispatch_locked(Req *r, bool flush_now) {

@@ -71,8 +71,7 @@ typedef struct strom_stats_blk {
                                     io_uring_enter submit/wakeup calls on
                                     the uring backend, dispatch wakeups on
                                     the worker pool.  enters/GiB is the
-                                    steady-state submission-syscall rate
-                                    bench.py's overlap scenario prices;
+                                    steady-state submission-syscall rate;
                                     SQPOLL drives it toward zero.           */
 } strom_stats_blk;
 
@@ -267,8 +266,9 @@ int strom_get_ring_info(strom_engine *eng, uint32_t ring,
  *      dispatch in order: consumers see one longer wait, never an
  *      error.
  * Returns the number of requests cancelled for requeue (>= 0), or
- * -EINVAL / -EBUSY (restart already running) / -ETIMEDOUT /
- * -ECANCELED (engine stopping). */
+ * -EINVAL / -EBUSY (another restart, or an open/close updating the
+ * registered-file table, holds the restart lock: nothing was done, try
+ * again) / -ETIMEDOUT / -ECANCELED (engine stopping). */
 int64_t strom_ring_restart(strom_engine *eng, uint32_t ring,
                            uint64_t drain_timeout_ns);
 

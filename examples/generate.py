@@ -164,7 +164,7 @@ def main(argv=None) -> int:
         dt = time.monotonic() - t0
         # single cold run: the time INCLUDES XLA compilation of the
         # prefill and per-layer segments — not comparable to the dense
-        # branch's warm number (bench_suite config 10 measures warm)
+        # branch's warm number
         print(f"offloaded decode: window={ocfg.window} "
               f"quant={args.offload_quant or 'off'} "
               f"(cold timing, includes compile)")

@@ -1052,7 +1052,7 @@ class CheckpointManager:
         fh = eng.open(path)
         pend: list = []
         try:
-            # the planner owns the chunk split (ledger-tuned size) and
+            # the planner owns the chunk split (the engine's chunk) and
             # the whole tile submits as ONE vectored batch — the engine
             # defers reads past its pool without blocking, and this
             # loop releases oldest-first, so the batch cannot deadlock

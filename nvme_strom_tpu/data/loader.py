@@ -34,7 +34,6 @@ from nvme_strom_tpu.io.engine import StromEngine, wait_exact
 from nvme_strom_tpu.io.plan import plan_and_submit
 from nvme_strom_tpu.parallel.mesh import batch_sharding
 from nvme_strom_tpu.utils.config import EngineConfig, LoaderConfig
-from nvme_strom_tpu.utils.tuning import tuned_chunk_bytes
 
 _SENTINEL = object()
 _log = logging.getLogger(__name__)
@@ -608,13 +607,9 @@ class ShardedLoader:
                                                          rshape):
                 raise ValueError(
                     f"{ix.path}: record layout differs from {idxs[0].path}")
-        # split size: the ledger-tuned chunk (planner default), floored
-        # to whole records so every piece reshapes cleanly; fall back to
-        # the engine's full buffer when a record outgrows the tuned size
-        split_src = tuned_chunk_bytes(eng)
-        if split_src < rec_bytes:
-            split_src = eng.config.chunk_bytes
-        max_read = (split_src // rec_bytes) * rec_bytes
+        # split size: the engine's chunk (the planner's default),
+        # floored to whole records so every piece reshapes cleanly
+        max_read = (eng.config.chunk_bytes // rec_bytes) * rec_bytes
         if max_read == 0:
             raise ValueError(
                 f"record ({rec_bytes}B) exceeds engine chunk_bytes "
@@ -662,7 +657,6 @@ class ShardedLoader:
             exts = [(fhs[si], off, nb)
                     for si, off, nb in row_spans(r0, r1)]
             parts = plan_and_submit(eng, exts, split_unit=rec_bytes,
-                                    chunk_bytes=split_src,
                                     klass="prefetch")
             return [p for pieces in parts for p in pieces]
 
@@ -924,7 +918,7 @@ class ShardedLoader:
         gshape = (self.global_batch, mlen)
         dev_spans, lo = self._device_row_spans(sharding, gshape)
         n_batches = self._count_batches(len(recs))
-        chunk = tuned_chunk_bytes(eng)   # planner split size (≤ buffer)
+        chunk = eng.config.chunk_bytes   # the planner's split size
         fhs = [eng.open(p) for p in order]
 
         # Span coalescing (window-9): tar members of one fixed payload

@@ -52,7 +52,7 @@ Every action is accounted: ``breaker_trips`` / ``ring_restarts`` /
 ``extents_requeued`` / ``degraded_reads`` / ``degraded_bytes`` /
 ``degraded_probes`` counters and the ``ring_health`` /
 ``engine_degraded`` gauges flow through StromStats → ``strom_stat``'s
-health block → watchdog dumps → bench.py JSON.
+health block → watchdog dumps.
 """
 
 from __future__ import annotations

@@ -15,11 +15,12 @@ submit path is bit-for-bit the pre-cache code).
              identity captured at ``StromEngine.open`` — a file
              modified between opens gets a NEW key, so stale lines can
              never serve (they age out of the budget instead).  The
-             line size adopts the ledger-tuned chunk
-             (``utils.tuning.tuned_chunk_bytes``) unless pinned by
-             ``STROM_HOSTCACHE_LINE_BYTES``.  A line may hold a VALID
-             PREFIX shorter than the line (EOF tails, partial fills) —
-             hits are served only inside the valid prefix.
+             line size is the ``chunk_bytes`` of the first engine
+             that touches the tier, rounded down to a power of two,
+             unless pinned by ``STROM_HOSTCACHE_LINE_BYTES``.  A line
+             may hold a VALID PREFIX shorter than the line (EOF tails,
+             partial fills) — hits are served only inside the valid
+             prefix.
   admission  frequency-based, via a ghost list (second-chance sketch):
              a line key is admitted only when it was ALREADY missed
              recently — one-shot streaming scans never pollute the
@@ -53,8 +54,7 @@ read consumers get the tier transparently.  Hit spans NEVER enter
 ``FaultyEngine``/``ResilientEngine`` — a DRAM read needs no retry or
 hedge budget.  Every decision is counted (``StromStats.cache_*``,
 ``bytes_served_cache``, per-class hit rates in ``class_stats``) and
-rendered by ``strom_stat``'s "host cache" block, watchdog dumps, and
-``bench.py``'s ``hostcache`` scenario.
+rendered by ``strom_stat``'s "host cache" block and watchdog dumps.
 """
 
 from __future__ import annotations
@@ -1073,15 +1073,13 @@ def parse_class_quotas(spec: str) -> Optional[Dict[str, float]]:
 
 
 def _default_line_bytes(engine) -> int:
-    """Auto line size: the ledger-tuned chunk of the first engine that
-    touches the tier, rounded down to a power of two (cheap aligned
-    arithmetic), floored at 64 KiB so a tiny probe engine cannot shred
-    the arena into confetti lines."""
-    try:
-        from nvme_strom_tpu.utils.tuning import tuned_chunk_bytes
-        ck = int(tuned_chunk_bytes(engine))
-    except Exception:
-        ck = 4 << 20
+    """Auto line size: the ``chunk_bytes`` of the first engine that
+    touches the tier (4 MiB when the tier is built without one),
+    rounded down to a power of two (cheap aligned arithmetic), floored
+    at 64 KiB so a tiny probe engine cannot shred the arena into
+    confetti lines."""
+    config = getattr(engine, "config", None)
+    ck = int(getattr(config, "chunk_bytes", 4 << 20))
     p = 4096
     while p * 2 <= ck:
         p *= 2

@@ -15,9 +15,9 @@ both problems are solved:
              views instead of memcpy'ing: slicing a numpy view costs
              nothing).  Overlapping/duplicate extents dedupe into one
              read the same way.  Cross-file extents never coalesce.
-  split      extents larger than the split size (the ledger-tuned
-             chunk from ``utils/tuning.tuned_chunk_bytes``, capped at
-             the engine's staging-buffer capacity) break into pieces —
+  split      extents larger than the split size (the engine's
+             ``chunk_bytes``, its staging-buffer capacity, unless the
+             caller pins a smaller one) break into pieces —
              replacing the near-identical hard-coded loops each
              consumer carried.  ``split_unit`` keeps piece boundaries
              on record boundaries (fixedrec) — pieces of one extent
@@ -29,9 +29,8 @@ both problems are solved:
 
 Accounting: every merged extent counts ``StromStats.spans_coalesced``;
 the C engine counts ``submit_batches`` / ``submit_syscalls_saved`` at
-the vectored boundary.  ``bench.py`` reports the resulting coalesce
-ratio and syscalls/GiB next to the throughput headline; thresholds and
-semantics are documented in docs/PERF.md.
+the vectored boundary.  Thresholds and semantics are documented in
+docs/PERF.md.
 
 The planner composes with the resilience stack unchanged: a
 ``ResilientEngine`` submits the batch through the wrapped engine and
@@ -423,10 +422,9 @@ def plan_and_submit(engine, extents: Sequence[Tuple[int, int, int]], *,
     list of :class:`SpanView` pieces (one piece unless the extent was
     split; empty list for zero-length extents).
 
-    The split size defaults to the ledger-tuned chunk
-    (``utils.tuning.tuned_chunk_bytes``); pass ``chunk_bytes`` to pin
-    it (must be ≤ the engine's staging capacity).  Coalescing counts
-    into ``StromStats.spans_coalesced``.
+    The split size defaults to the engine's ``chunk_bytes`` (its
+    staging capacity); pass ``chunk_bytes`` to pin a smaller one.
+    Coalescing counts into ``StromStats.spans_coalesced``.
 
     ``klass`` is the batch's latency class (see :func:`submit_spans`) —
     the one knob consumers use to tag their traffic for the QoS
@@ -449,8 +447,7 @@ def plan_and_submit(engine, extents: Sequence[Tuple[int, int, int]], *,
     the tier off it changes nothing.
     """
     if chunk_bytes is None:
-        from nvme_strom_tpu.utils.tuning import tuned_chunk_bytes
-        chunk_bytes = tuned_chunk_bytes(engine)
+        chunk_bytes = engine.config.chunk_bytes
     if split_unit == 1:
         from nvme_strom_tpu.io import hostcache
         cache = hostcache.get_cache(engine)

@@ -46,8 +46,8 @@ the same class names.
 
 Every decision is accounted: ``StromStats.sched_*`` counters, per-class
 dispatch/queue-wait tallies (``class_stats`` in the export), and
-per-ring depth gauges — rendered by ``strom_stat``'s scheduler block,
-watchdog dumps, and bench.py's mixed-workload scenario.
+per-ring depth gauges — rendered by ``strom_stat``'s scheduler block
+and watchdog dumps.
 
 Failure domains (io/health.py, docs/RESILIENCE.md): the ``ring_free``
 callback the engine binds here is supervision-aware — a ring whose

@@ -855,7 +855,7 @@ def qkvg_project(x, p, prefix, cfg: TransformerConfig, positions=None):
     # transpose below into each product and carries the head-major layout
     # back into the WEIGHT, which it then transposes (and stages) again on
     # every call — a decode step of 17.1 ms for 16.4 at hidden 4096 on a
-    # v5e (tests/test_chip_compile.py::test_projection_weights_read_in_place)
+    # v5e (tests/test_chip_compile_*.py::test_projection_weights_read_in_place)
     q, k, v = jax.lax.optimization_barrier(tuple(
         x @ wmat(p, prefix + name, x.dtype) for name in ("wq", "wk", "wv")))
     if cfg.value_scale != 1.0:

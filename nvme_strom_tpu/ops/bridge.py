@@ -59,7 +59,7 @@ def _pallas_h2d(dev):
 
     The operand ref is declared in ``pltpu.HOST``: Mosaic refuses a
     ``pinned_host`` operand behind ``pl.ANY`` ("Failed to convert a
-    memory space to MLIR"; tests/test_chip_compile.py holds the
+    memory space to MLIR"; tests/test_chip_compile_kernels.py holds the
     compile for v5e)."""
     fn = _H2D_DMA_CACHE.get(dev)
     if fn is not None:

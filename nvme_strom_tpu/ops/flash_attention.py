@@ -305,10 +305,8 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
     ``interpret`` defaults to True off-TPU so CPU tests and virtual meshes
     run the identical kernel in the Pallas interpreter.
 
-    ``block_q``/``block_k`` default to the ledgered kernel-probe best
-    for the nearest probed shape (utils/tuning.best_attn_blocks; the
-    window-7 sweep measured the 128x128 fallback at ~1.8x the tuned
-    tiling's step time), else 128x128.
+    ``block_q``/``block_k`` default to 128; either way the block used is
+    the largest divisor of its sequence no larger (``_pick_block``).
 
     K/V may have a different sequence length than Q when ``causal=False``
     (blockwise/ring combines, cross-attention); causal masking assumes
@@ -333,13 +331,10 @@ def _prep(q, k, causal, scale, block_q, block_k, interpret):
         scale = 1.0 / np.sqrt(q.shape[-1])
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if block_q is None or block_k is None:
-        from nvme_strom_tpu.utils.tuning import best_attn_blocks
-        tuned = best_attn_blocks(s, skv) or (128, 128)
-        block_q = tuned[0] if block_q is None else block_q
-        block_k = tuned[1] if block_k is None else block_k
-    return (float(scale), _pick_block(s, block_q),
-            _pick_block(skv, block_k), bool(causal), bool(interpret))
+    return (float(scale),
+            _pick_block(s, 128 if block_q is None else block_q),
+            _pick_block(skv, 128 if block_k is None else block_k),
+            bool(causal), bool(interpret))
 
 
 def flash_attention_lse(q, k, v, *, causal: bool = True, scale: float = None,

@@ -287,7 +287,7 @@ def _read_share(engine, paths: Sequence[str], fhs: Sequence[int],
                 units, row_bytes: int, klass: str) -> np.ndarray:
     """One host's share row: its assigned ``(file_idx, offset, length)``
     units read through the ordinary planner path (coalesced, split at
-    the ledger-tuned chunk, ``restore``-class — scheduler, breakers and
+    the engine's chunk, ``restore``-class — scheduler, breakers and
     hostcache all apply) and packed in unit order."""
     from nvme_strom_tpu.io.engine import wait_exact
     from nvme_strom_tpu.io.plan import plan_and_submit

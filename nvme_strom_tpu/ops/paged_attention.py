@@ -77,8 +77,8 @@ def _tokens_on_lanes(pool_shape) -> bool:
     ``(…, 64, 64)`` as they read).  A Mosaic kernel takes its operands
     row-major, so the kernels here see such a pool through
     ``_kernel_view``.  A wrong answer costs copies, never results: XLA
-    then transposes for real (tests/test_chip_compile.py pins both cells'
-    shapes)."""
+    then transposes for real (tests/test_chip_compile_kernels.py pins
+    both cells' shapes)."""
     block, d = pool_shape[-2:]
     return d % 128 != 0 and block % 128 == 0
 

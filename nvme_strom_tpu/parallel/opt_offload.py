@@ -379,11 +379,11 @@ class OffloadedAdam:
     def _group_ranges(self, names) -> tuple[list, list]:
         """Chunk-split (offset, length) ranges covering each slot of the
         group, plus per-slot chunk counts for device-side reassembly.
-        The split rule and size come from the shared planner
-        (``io.plan.split_spans`` via the ledger-tuned chunk); the
-        ranges then ride ``DeviceStream``'s vectored submission."""
-        from nvme_strom_tpu.utils.tuning import tuned_chunk_bytes
-        chunk = tuned_chunk_bytes(self.engine)
+        The split rule comes from the shared planner
+        (``io.plan.split_spans``) and the size is the engine's
+        ``chunk_bytes``; the ranges then ride ``DeviceStream``'s
+        vectored submission."""
+        chunk = self.engine.config.chunk_bytes
         ranges: list[tuple[int, int]] = []
         counts: list[int] = []      # chunks per slot, m then v, slot order
         for n in names:

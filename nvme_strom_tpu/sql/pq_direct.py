@@ -1386,17 +1386,15 @@ def read_plain_columns_to_device(scanner, columns: Sequence[str],
     import jax.numpy as jnp
     import numpy as np
     from nvme_strom_tpu.ops.bridge import DeviceStream
-    from nvme_strom_tpu.utils.tuning import tuned_stream_params
 
     if nulls not in ("forbid", "mask"):
         raise ValueError(f"bad nulls={nulls!r}")
     dev = device or jax.local_devices()[0]
     plans = plans or plan_columns(scanner, columns,
                                   allow_nulls=nulls == "mask")
-    depth, drain = tuned_stream_params(scanner.engine)
-    ds = DeviceStream(scanner.engine, device=dev, depth=depth,
-                      klass=SCAN_CLASS,
-                      drain=drain)
+    ds = DeviceStream(scanner.engine, device=dev,
+                      depth=max(2, scanner.engine.config.queue_depth),
+                      klass=SCAN_CLASS, drain="ready")
     out = {}
     meta = scanner.metadata
     name_to_ci = {meta.schema.column(i).name: i
@@ -1703,21 +1701,15 @@ def iter_plain_row_groups_to_device(scanner, columns: Sequence[str],
     64-group × 2-column scan paid ~128 of them."""
     import jax
     from nvme_strom_tpu.ops.bridge import DeviceStream
-    from nvme_strom_tpu.utils.tuning import tuned_stream_params
 
     if nulls not in ("forbid", "mask"):
         raise ValueError(f"bad nulls={nulls!r}")
     dev = device or jax.local_devices()[0]
     plans = plans or plan_columns(scanner, columns,
                                   allow_nulls=nulls == "mask")
-    # probe-tuned operating point, same as bench.py's headline stream:
-    # the raw engine default (depth=queue_depth=16, ready) ledgered
-    # 0.37 of ceiling in the window-7 sweep while depth 4-8 rode the
-    # identical link at 0.88-0.91
-    depth, drain = tuned_stream_params(scanner.engine)
-    ds = DeviceStream(scanner.engine, device=dev, depth=depth,
-                      klass=SCAN_CLASS,
-                      drain=drain)
+    ds = DeviceStream(scanner.engine, device=dev,
+                      depth=max(2, scanner.engine.config.queue_depth),
+                      klass=SCAN_CLASS, drain="ready")
     fh = scanner.engine.open(scanner.path)
     try:
         groups = (range(scanner.metadata.num_row_groups)

@@ -18,9 +18,9 @@ Streaming Framework in PAPERS.md motivate the same shape on NVMe):
 2. **Partition-parallel execution** (:func:`iter_scan_columns`):
    surviving row groups are windowed by the SAME rule the serial scan
    uses (``pq_direct._split_windows``) and fanned across a worker pool
-   (``STROM_SQL_WORKERS``; 0 = auto from the ledger-tuned operating
-   point, ``utils.tuning.tuned_sql_workers``).  Each worker owns a
-   ``DeviceStream`` and submits its windows' column-chunk spans through
+   (``STROM_SQL_WORKERS``; 0 = auto, half the CPUs and at most four:
+   :func:`sql_workers`).  Each worker owns a ``DeviceStream`` and
+   submits its windows' column-chunk spans through
    the engine at the dedicated ``scan`` QoS class — so
    ``strom_submit_readv`` batching, the QoS scheduler's fair-share, the
    per-ring breakers, and the hostcache tier all govern analytics reads
@@ -78,16 +78,16 @@ def pushdown_enabled() -> bool:
 
 def sql_workers() -> int:
     """Partition-parallel scan width.  STROM_SQL_WORKERS: explicit
-    N >= 1 pins the pool; 0 (default) adopts the ledger-tuned width
-    (``utils.tuning.tuned_sql_workers`` — config 23's best credible
-    row, else a CPU-derived default).  1 = the serial scan."""
+    N >= 1 pins the pool; 0 (default) takes half the CPUs, at least one
+    and at most four — enough workers to keep several QoS-class streams
+    in flight without oversubscribing the submission path on a small
+    box.  1 = the serial scan."""
     v = int(os.environ.get("STROM_SQL_WORKERS", "0") or "0")
     if v < 0:
         raise ValueError(f"STROM_SQL_WORKERS ({v}) must be >= 0")
     if v:
         return v
-    from nvme_strom_tpu.utils.tuning import tuned_sql_workers
-    return tuned_sql_workers()
+    return max(1, min(4, (os.cpu_count() or 2) // 2))
 
 
 @dataclass(frozen=True)
@@ -274,20 +274,19 @@ def _pool_workers(engine, workers: int, n_windows: int) -> int:
 
 
 def _worker_stream(scanner, dev, workers: int = 1):
-    """One worker's DeviceStream at the scan class, probe-tuned like
-    the serial path's — depth divided across the pool so the sum of
-    worst-case per-worker staging holdings (2x depth each, see
-    :func:`_pool_workers`) leaves spare buffers for whichever worker
-    must make progress."""
+    """One worker's DeviceStream at the scan class, at the engine's
+    queue depth like the serial path's (floor 2) — divided across the
+    pool so the sum of worst-case per-worker staging holdings (2x depth
+    each, see :func:`_pool_workers`) leaves spare buffers for whichever
+    worker must make progress."""
     from nvme_strom_tpu.ops.bridge import DeviceStream
     from nvme_strom_tpu.sql.pq_direct import SCAN_CLASS
-    from nvme_strom_tpu.utils.tuning import tuned_stream_params
-    depth, drain = tuned_stream_params(scanner.engine)
+    depth = max(2, scanner.engine.config.queue_depth)
     if workers > 1:
         depth = max(2, min(
             depth, (scanner.engine.n_buffers - 2) // (2 * workers)))
     return DeviceStream(scanner.engine, device=dev, depth=depth,
-                        klass=SCAN_CLASS, drain=drain)
+                        klass=SCAN_CLASS, drain="ready")
 
 
 def _iter_windows_parallel(scanner, columns, plans, windows, dev,

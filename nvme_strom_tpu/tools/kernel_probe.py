@@ -1,15 +1,11 @@
 #!/usr/bin/env python
-"""Pallas kernel autotune probe: flash-attention block sizes on the chip.
-
-This probe measures, on the chip, the fused flash-attention kernel's fwd
-and fwd+bwd step time across (block_q, block_k) tilings — against the
-XLA dense-attention baseline — at the train bench's shape and at a
-long-context shape where the O(s²) dense path stops being competitive.
-One JSON line per measurement, each naming ``platform``,
-``device_kind`` and ``device_count``.
+"""Kernel probes: a kernel of the serving cells alone on the chip, one
+JSON line per measurement, each naming ``platform``, ``device_kind`` and
+``device_count``.  Two modes; called with neither it names them and exits
+non-zero.
 
 ``python -m nvme_strom_tpu.tools.kernel_probe paged`` times the paged
-decode kernels (``ops/paged_attention.py``) alone instead, at the serving
+decode kernels (``ops/paged_attention.py``) alone, at the serving
 cells' own shapes and slot mixes (``PAGED_CASES``): a call's time with the
 table entries it reads and the grid steps it issues beside it, so that
 what a grid step costs without a block can be told from what it costs
@@ -49,181 +45,6 @@ _DEVICE: dict = {}     # platform / device_kind / device_count, set by main
 
 def _emit(obj: dict) -> None:
     print(json.dumps({**obj, **_DEVICE}), flush=True)
-
-
-def _time_step(fn, q, k, v, chain: int = 8, repeats: int = 3) -> float:
-    """Seconds per call: MEDIAN over ``repeats`` CHAINED windows of
-    ``chain`` data-dependent calls, each bracketed by host reads:
-    call ``i+1`` consumes call ``i``'s output, the pre-clock
-    float() pins the timeline start, and the final float() cannot
-    produce bytes until the whole chain has executed — the
-    bench_suite._train_variant discipline applied to kernels.  The
-    median across windows keeps one mid-chain link stall from
-    mis-ranking a tiling (the suspect gate only catches impossibly
-    FAST rates, never slow outliers)."""
-    import statistics
-
-    import jax.numpy as jnp
-
-    def head(out):
-        x = out[0] if isinstance(out, tuple) else out
-        return x.astype(q.dtype) if x.dtype != q.dtype else x
-
-    x = head(fn(q, k, v))              # compile
-    float(jnp.sum(x[..., :1, :1]))
-    ts = []
-    for _ in range(repeats):
-        x = q
-        float(jnp.sum(x[..., :1, :1]))  # host round-trip: window start
-        t0 = time.monotonic()
-        for _ in range(chain):
-            x = head(fn(x, k, v))
-        float(jnp.sum(x[..., :1, :1]))
-        ts.append((time.monotonic() - t0) / chain)
-    return statistics.median(ts)
-
-
-def probe_shape(b: int, h: int, s: int, d: int, dev) -> tuple[int, int]:
-    """Sweep one shape; returns (honest, suspect) timed-row counts so
-    the caller can void an all-lying step."""
-    import jax
-    import jax.numpy as jnp
-    from nvme_strom_tpu.models.transformer import dense_causal_attention
-    from nvme_strom_tpu.ops.flash_attention import flash_attention
-
-    kq, kk, kv = jax.random.split(jax.random.key(0), 3)
-    q = jax.device_put(jax.random.normal(kq, (b, h, s, d), jnp.bfloat16),
-                       dev)
-    k = jax.device_put(jax.random.normal(kk, (b, h, s, d), jnp.bfloat16),
-                       dev)
-    v = jax.device_put(jax.random.normal(kv, (b, h, s, d), jnp.bfloat16),
-                       dev)
-
-    # the baseline is the MODEL's dense path (bf16 matmuls, f32 score
-    # accumulation) — a hand-rolled f32 version would inflate dense
-    # times and steer the flash-vs-dense choice wrong
-    dense = dense_causal_attention
-
-    def bwd_of(fn):
-        def loss(q, k, v):
-            return fn(q, k, v).astype(jnp.float32).sum()
-        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-
-    shape = f"b{b}h{h}s{s}d{d}"
-    # causal attention FLOPs (half the score matrix), fwd+bwd ≈ 3.5x
-    # the QK+PV forward pair — the sanity denominator for the lying-
-    # runtime gate below
-    flops_fwdbwd = 3.5 * 4 * b * h * s * s * d * 0.5
-
-    counts = [0, 0]          # [honest, suspect] timed rows
-
-    def row(impl, t_fwd, t_bwd):
-        tf = flops_fwdbwd / max(t_bwd, 1e-9) / 1e12
-        rec = {"probe": "attn", "shape": shape, "impl": impl,
-               "fwd_ms": round(t_fwd * 1e3, 3),
-               "fwdbwd_ms": round(t_bwd * 1e3, 3),
-               "tflops": round(tf, 1), "timing": "chained"}
-        if tf > 300:           # v5e peak 197: physically impossible
-            rec["suspect"] = "rate above device peak"
-        counts[1 if "suspect" in rec else 0] += 1
-        _emit(rec)
-        _log(f"{shape} {impl} fwd={t_fwd * 1e3:.2f}ms "
-             f"fwd+bwd={t_bwd * 1e3:.2f}ms ({tf:.0f} TF/s"
-             f"{' SUSPECT' if 'suspect' in rec else ''})")
-        return rec
-
-    try:
-        t_fwd = _time_step(jax.jit(dense), q, k, v)
-        t_bwd = _time_step(bwd_of(dense), q, k, v)
-        row("dense-xla", t_fwd, t_bwd)
-    except Exception as e:  # noqa: BLE001 — OOM at long s is expected
-        _emit({"probe": "attn", "shape": shape, "impl": "dense-xla",
-               "error": f"{type(e).__name__}: {str(e)[:120]}"})
-
-    best = None
-    for bq in (128, 256, 512):
-        for bk in (128, 256, 512):
-            if bq > s or bk > s:
-                continue
-            fl = jax.jit(lambda q, k, v, bq=bq, bk=bk: flash_attention(
-                q, k, v, block_q=bq, block_k=bk))
-            fb = bwd_of(lambda q, k, v, bq=bq, bk=bk: flash_attention(
-                q, k, v, block_q=bq, block_k=bk))
-            try:
-                t_fwd = _time_step(fl, q, k, v)
-                t_bwd = _time_step(fb, q, k, v)
-            except Exception as e:  # noqa: BLE001
-                _emit({"probe": "attn", "shape": shape,
-                       "impl": f"flash-{bq}x{bk}",
-                       "error": f"{type(e).__name__}: {str(e)[:120]}"})
-                continue
-            rec = row(f"flash-{bq}x{bk}", t_fwd, t_bwd)
-            # a suspect point must not become the adopted tiling
-            if "suspect" not in rec and (best is None or t_bwd < best[0]):
-                best = (t_bwd, bq, bk)
-    if best is not None:
-        _emit({"probe": "attn_best", "shape": shape,
-               "block_q": best[1], "block_k": best[2],
-               "fwdbwd_ms": round(best[0] * 1e3, 3),
-               "timing": "chained"})
-    return counts[0], counts[1]
-
-
-def probe_matmul_roof(dev) -> None:
-    """Pure bf16 matmul chain — the chip's ACHIEVABLE matmul rate as
-    this runtime exposes it, i.e. the honest MFU denominator.
-
-    If a train step's big matmul fusions sit far below the nominal
-    197 TFLOP/s and a bare square-matmul chain caps at the same rate,
-    the ceiling is the device as exposed; if the chain runs well above
-    them, the program leaves real headroom.  Same chained
-    data-dependent timing as the attention rows."""
-    import statistics
-
-    import jax
-    import jax.numpy as jnp
-
-    sizes = (4096, 8192) if dev.platform == "tpu" else (256,)
-    for n in sizes:
-        kx, kw = jax.random.split(jax.random.key(1))
-        x = jax.device_put(jax.random.normal(kx, (n, n), jnp.bfloat16),
-                           dev)
-        w = jax.device_put(jax.random.normal(kw, (n, n), jnp.bfloat16),
-                           dev)
-
-        @jax.jit
-        def step(x, w, n=n):
-            # 1/sqrt(n) keeps the chain's variance at 1 so bf16 never
-            # saturates; the scale fuses into the matmul epilogue
-            return (x @ w) * (1.0 / float(n) ** 0.5)
-
-        chain, repeats = 8, 3
-        y = step(x, w)
-        float(jnp.sum(y[:1, :1]))          # compile + settle
-        ts = []
-        for _ in range(repeats):
-            y = x
-            float(jnp.sum(y[:1, :1]))      # host round-trip: win start
-            t0 = time.monotonic()
-            for _ in range(chain):
-                y = step(y, w)
-            float(jnp.sum(y[:1, :1]))
-            ts.append((time.monotonic() - t0) / chain)
-        t = statistics.median(ts)
-        tf = 2 * n ** 3 / t / 1e12
-        rec = {"probe": "matmul_roof", "n": n,
-               "ms": round(t * 1e3, 3), "tflops": round(tf, 1),
-               "timing": "chained"}
-        reasons = []
-        if tf > 300:                       # v5e peak 197
-            reasons.append("rate above device peak")
-        if not bool(jnp.isfinite(y).all()):
-            reasons.append("non-finite chain output")
-        if reasons:
-            rec["suspect"] = "; ".join(reasons)
-        _emit(rec)
-        _log(f"matmul_roof n={n}: {t * 1e3:.2f} ms = {tf:.0f} TF/s"
-             f"{' SUSPECT' if 'suspect' in rec else ''}")
 
 
 #: name -> (slots, query heads, KV heads, K width, V width, pool blocks,
@@ -453,47 +274,27 @@ def probe_ssm(repeats: int = 5) -> None:
                  2 if on_cpu else GDN_CALLS, repeats)
 
 
+MODES = ("paged", "ssm")
+
+
 def main() -> int:
+    mode = sys.argv[1] if len(sys.argv) > 1 else None
+    if mode not in MODES:
+        print("usage: python -m nvme_strom_tpu.tools.kernel_probe "
+              "paged [case ...] | ssm", file=sys.stderr)
+        return 2
     sys.path.insert(0, REPO)   # direct-script mode: repo root first
     from nvme_strom_tpu.utils.compile_cache import enable_compile_cache
     enable_compile_cache()
     from nvme_strom_tpu.utils.device import require_tpu
     _DEVICE.update(require_tpu("kernel_probe"))
-    on_cpu = _DEVICE["platform"] != "tpu"
     import jax
-    dev = jax.devices()[0]
-    _log(f"device = {dev}")
-    if sys.argv[1:2] == ["paged"]:
+    _log(f"device = {jax.devices()[0]}")
+    if mode == "paged":
         for case in sys.argv[2:] or PAGED_CASES:
             probe_paged(case)
-        return 0
-    if sys.argv[1:2] == ["ssm"]:
+    else:
         probe_ssm()
-        return 0
-
-    def roof_guarded():
-        # the roof probe must never cost the step its PRIMARY output
-        # (the attn tiling rows that feed best_attn_blocks adoption) —
-        # exception-guarded AND ordered LAST, so a hang in it burns
-        # only the tail of the step budget, never the tiling rows
-        try:
-            probe_matmul_roof(dev)
-        except Exception as e:  # noqa: BLE001 — device/alloc flake
-            _emit({"probe": "matmul_roof",
-                   "error": f"{type(e).__name__}: {str(e)[:120]}"})
-
-    if on_cpu:
-        roof_guarded()                        # tiny-n mechanics
-        probe_shape(1, 2, 256, 64, dev)       # mechanics only
-        return 0
-    h1, s1 = probe_shape(8, 16, 1024, 128, dev)   # config-7 train shape
-    h2, s2 = probe_shape(2, 16, 4096, 128, dev)   # long context
-    roof_guarded()                            # MFU denominator
-    if (s1 + s2) and not (h1 + h2):
-        # every timed row was impossibly fast: say so in a marker row
-        # rather than let a reader cite a step the probe disbelieved
-        _emit({"metric": "kernel_probe: SUSPECT-TIMING "
-                         "(every tiling above device peak)"})
     return 0
 
 

@@ -48,7 +48,10 @@ in two parts:
 Usage: python -m nvme_strom_tpu.tools.transfer_diag [--bytes N]
            [--sizes N,N,... [--threads 1,2,4] [--devices D]]
 Prints one JSON line with the alias verdict and the three medians, then
-the sweep's lines.
+the sweep's lines; every line names ``platform``, ``device_kind`` and
+``device_count``.  A measuring command: without a TPU it exits non-zero
+and prints nothing, unless the caller set ``JAX_PLATFORMS=cpu`` (the
+mechanics only; every line then says ``"platform": "cpu"``).
 """
 
 from __future__ import annotations
@@ -294,11 +297,13 @@ def main(argv=None) -> int:
                     help="seconds a (size, threads) pair puts beside a "
                          "counting thread (0: not measured)")
     args = ap.parse_args(argv)
+    from nvme_strom_tpu.utils.device import require_tpu
+    device = require_tpu("transfer_diag")   # exits where there is no TPU
     res = run(args.bytes, args.repeats)
-    print(json.dumps(res), flush=True)
+    print(json.dumps({**res, **device}), flush=True)
     for row in sweep(args.sizes, args.threads, args.devices,
                      max(args.repeats, 2), args.gil_seconds):
-        print(json.dumps(row), flush=True)
+        print(json.dumps({**row, **device}), flush=True)
     return 0 if res.get("view_in_pool") else 1
 
 

@@ -1,6 +1,6 @@
 """Persistent XLA compilation cache, placed from outside the program.
 
-Every process that compiles (entry points, benchmarks, ``chip_smoke.py``)
+Every process that compiles (entry points, the benchmark, the probes)
 calls ``enable_compile_cache()`` before its first compile so a later
 process loads the serialized executable instead of compiling again.
 

@@ -148,9 +148,9 @@ class HostCacheConfig:
     #: the planner's submit path is then bit-for-bit the pre-cache code
     budget_mb: int = field(
         default_factory=lambda: _env_int("STROM_HOSTCACHE_MB", 0))
-    #: cache-line size override in bytes (0 = adopt the ledger-tuned
-    #: chunk from utils/tuning.tuned_chunk_bytes of the first engine
-    #: that touches the tier); must be a power of two >= 4096
+    #: cache-line size override in bytes (0 = the ``chunk_bytes`` of
+    #: the first engine that touches the tier, rounded down to a power
+    #: of two); must be a power of two >= 4096
     line_bytes: int = field(
         default_factory=lambda: _env_int("STROM_HOSTCACHE_LINE_BYTES", 0))
     #: "decode=8,restore=4,prefetch=2,scan=2,scrub=1" — per-QoS-class residency

@@ -137,7 +137,7 @@ class StromStats:
     # failed/short writes resubmitted by ResilientEngine's write mirror
     write_retries: int = 0
     # payload bytes checksummed on the read path (STROM_VERIFY) — the
-    # integrity tax, priced by bench.py's verify rows
+    # integrity tax
     bytes_verified: int = 0
     # stamped-checksum mismatches detected (each is a silent corruption
     # that would otherwise have flowed into training state)
@@ -149,7 +149,7 @@ class StromStats:
     cache_hits: int = 0
     cache_misses: int = 0
     # payload bytes served straight from the pinned arena — the repeat
-    # traffic that no longer pays SSD latency (bench.py "hostcache")
+    # traffic that no longer pays SSD latency
     bytes_served_cache: int = 0
     # fills accepted by the ghost-list admission gate / misses the gate
     # refused to admit (one-shot streaming scans land here, by design)
@@ -866,9 +866,9 @@ class MetricsSnapshotter:
     """Periodic snapshotter: every ``interval_s`` it snapshots a
     StromStats block into an in-memory series (bounded) and, when
     ``path`` is set, rewrites the OpenMetrics textfile — the time-series
-    half of the registry (bench.py emits the series; a fleet scraper
-    tails the file).  Daemon thread; ``close()`` (or the context
-    manager) takes a final snapshot so short runs never export empty."""
+    half of the registry (a fleet scraper tails the file).  Daemon
+    thread; ``close()`` (or the context manager) takes a final snapshot
+    so short runs never export empty."""
 
     def __init__(self, stats: StromStats, interval_s: float = 10.0,
                  path: Optional[str] = None, keep: int = 512,

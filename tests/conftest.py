@@ -64,6 +64,19 @@ def tmp_data_file(tmp_path):
     return path, payload
 
 
+def evict_file(path) -> None:
+    """Drop the file's pages from the page cache, as far as the kernel
+    will: fsync first (only clean pages can be evicted), then
+    POSIX_FADV_DONTNEED.  A failed eviction shows as ``bytes_resident``
+    in the engine's stats."""
+    fd = os.open(path, os.O_RDONLY)
+    try:
+        os.fsync(fd)
+        os.posix_fadvise(fd, 0, 0, os.POSIX_FADV_DONTNEED)
+    finally:
+        os.close(fd)
+
+
 def mesh_for(axes):
     """Mesh from ((name, size), ...), skipping when devices are short.
     Shared helper for the parallelism suites (pipeline, ulysses, ...)."""

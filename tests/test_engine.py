@@ -357,6 +357,7 @@ def test_residency_planned_reads(tmp_path):
     bytes_resident, not a rescue); an evicted span goes O_DIRECT."""
     import os
 
+    from conftest import evict_file
     from nvme_strom_tpu.io.engine import StromEngine
     from nvme_strom_tpu.utils.config import EngineConfig
     from nvme_strom_tpu.utils.stats import StromStats
@@ -383,9 +384,7 @@ def test_residency_planned_reads(tmp_path):
 
         # Evict (clean, synced pages) and read again: the probe must now
         # say non-resident and the read go O_DIRECT.
-        with open(path, "rb+") as f:
-            os.fsync(f.fileno())   # only clean pages can be evicted
-            os.posix_fadvise(f.fileno(), 0, 0, os.POSIX_FADV_DONTNEED)
+        evict_file(path)
         p = eng.submit_read(fh, 0, len(data))
         v = p.wait()
         assert bytes(v) == data

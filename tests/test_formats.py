@@ -237,8 +237,8 @@ def test_pread_nopollute_drops_pages(tmp_path):
     p = tmp_path / "f.bin"
     payload = os.urandom(32768)
     p.write_bytes(payload)
-    import bench
-    bench.evict_file(str(p))
+    from conftest import evict_file
+    evict_file(p)
 
     def resident_pages() -> int:
         size = os.path.getsize(p)
@@ -281,9 +281,9 @@ def test_arrow_multichunk_device_assembly(engine, tmp_path):
     with pa.OSFile(str(path), "wb") as f:
         with pa.ipc.new_file(f, batch.schema) as w:
             w.write_batch(batch)
-    import bench
+    from conftest import evict_file
     r = ArrowFileReader(path)        # footer read while file is warm
-    bench.evict_file(str(path))      # cold payload: direct reads, so
+    evict_file(path)                 # cold payload: direct reads, so
     engine.sync_stats()              # bounce is alias copies alone
     pre = engine.stats.snapshot()["bounce_bytes"]
     cols = r.read_columns_to_device(engine, columns=["a", "b"])

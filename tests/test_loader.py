@@ -472,7 +472,7 @@ def test_wds_index_cached_and_no_cache_poisoning(tmp_path, monkeypatch):
     (the window-7 wds_raw rows bounced their full payload because the
     walk's 4 MiB windows flipped every member read to the buffered
     path)."""
-    import bench
+    from conftest import evict_file
     import jax
     from jax.sharding import Mesh
     from nvme_strom_tpu.io.engine import StromEngine
@@ -497,7 +497,7 @@ def test_wds_index_cached_and_no_cache_poisoning(tmp_path, monkeypatch):
                            engine=eng) as loader:
             for _ in range(2):
                 for p in paths:
-                    bench.evict_file(p)
+                    evict_file(p)
                 assert len(list(loader)) == 2
         eng.sync_stats()
     assert sorted(built) == sorted(str(p) for p in paths)

@@ -130,18 +130,6 @@ def test_weights_roundtrip_through_lazy_loader(cfg, mesh8, tmp_path):
                                rtol=1e-5, atol=1e-5)
 
 
-def test_graft_entry_contract():
-    import __graft_entry__ as g
-    fn, args = g.entry()
-    out = jax.jit(fn)(*args)
-    assert out.ndim == 3 and bool(jnp.isfinite(out).all())
-
-
-def test_graft_dryrun_multichip():
-    import __graft_entry__ as g
-    g.dryrun_multichip(8)
-
-
 def test_remat_matches_dense_grads():
     """cfg.remat trades FLOPs for memory; math must be identical."""
     import numpy as np

@@ -554,67 +554,6 @@ def test_arena_emits_occupancy_counter(tmp_path, monkeypatch):
     assert cs[0]["args"].get("carved_staging", 0) >= 8192
 
 
-# -- bench gate ---------------------------------------------------------------
-
-def test_bench_gate_compare_and_formats(tmp_path):
-    from nvme_strom_tpu.tools import bench_gate
-    base = {"metric": "x", "platform": "cpu", "value": 1.0,
-            "verify_overhead_pct": 5.0,
-            "observability": {"flight_overhead_pct": 1.0}}
-    good = {"metric": "x", "platform": "cpu", "value": 0.9,
-            "verify_overhead_pct": 6.0,
-            "observability": {"flight_overhead_pct": 1.5}}
-    bad = {"metric": "x", "platform": "cpu", "value": 0.4,
-           "verify_overhead_pct": 50.0,
-           "observability": {"flight_overhead_pct": 9.0}}
-    _res, regs = bench_gate.compare(base, good)
-    assert not regs
-    _res, regs = bench_gate.compare(base, bad)
-    names = {r["metric"] for r in regs}
-    assert "value" in names
-    assert "verify_overhead_pct" in names
-    assert "observability.flight_overhead_pct" in names
-
-    bpath = tmp_path / "BENCH_r01.json"
-    bpath.write_text(json.dumps(
-        {"n": 1, "tail": "noise\n" + json.dumps(base)}))   # wrapper form
-    npath = tmp_path / "new.json"
-    npath.write_text(json.dumps(good))
-    rc = bench_gate.main([str(npath), "--root", str(tmp_path)])
-    assert rc == 0
-    npath.write_text(json.dumps(bad))
-    rc = bench_gate.main([str(npath), "--root", str(tmp_path),
-                          "--json"])
-    assert rc == 1
-    # platform mismatch: incomparable, refuses to judge (0 unless strict)
-    npath.write_text(json.dumps({**good, "platform": "tpu"}))
-    assert bench_gate.main([str(npath), "--root", str(tmp_path)]) == 0
-    assert bench_gate.main([str(npath), "--root", str(tmp_path),
-                            "--strict"]) == 1
-
-
-def test_bench_gate_latest_baseline_picks_newest_that_parses(tmp_path):
-    """No datapoint ships with the tree (a baseline is a chip run's
-    output): the gate finds none there, and in a directory that has
-    some it takes the newest by name that parses."""
-    import os
-    from nvme_strom_tpu.tools.bench_gate import (latest_baseline,
-                                                 load_bench_json)
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    assert latest_baseline(root) is None
-    for name, body in (
-            ("BENCH_a.json", {"metric": "x", "platform": "tpu",
-                              "value": 1.0}),
-            ("BENCH_b.json", {"metric": "x", "platform": "cpu",
-                              "value": 2.0})):
-        (tmp_path / name).write_text(json.dumps(body))
-    (tmp_path / "BENCH_c.json").write_text("not json")
-    path = latest_baseline(str(tmp_path))
-    assert os.path.basename(path) == "BENCH_b.json"
-    doc = load_bench_json(path)
-    assert doc["platform"] == "cpu" and doc["value"] == 2.0
-
-
 # -- flight recorder: attribution summary in dumps ---------------------------
 
 def test_flight_dump_embeds_attrib_summary(tmp_path):

@@ -628,361 +628,3 @@ def test_pending_first_restored_on_readback_failure(setup, monkeypatch):
         got.update(srv.step_many(4))
     assert got["one"] == _solo(params, cfg, p0, 1)
     assert got["more"] == _solo(params, cfg, p1, 1)
-
-
-# -- admission's prefill as one compiled program ---------------------------
-
-@pytest.mark.parametrize("pool,prompt_lens,shared,programs", [
-    # no hit: one block of 128 (slots) / two blocks of 8 (shared); 128 rows
-    # are past this model's break-even on the CPU (35), so width 1; the
-    # program of 16 rows holds two prompts
-    ("slots", (9, 16), 0, {(1, 128, 128)}),
-    ("shared", (9, 16), 0, {(2, 16, 16)}),
-    # HBM prefix-cache hit: the second prompt shares two full blocks and
-    # prefills its last block only, against the same 24-row cache
-    ("shared", (20, 19), 16, {(1, 24, 24), (4, 8, 24)}),
-])
-def test_served_tokens_match_generate_through_the_prefill_program(
-        setup, pool, prompt_lens, shared, programs):
-    """Greedy tokens out of the compiled admission are ``generate()``'s,
-    and the program is keyed on (width, padded suffix, cache) alone, the
-    width following from the suffix: prompts of different lengths inside
-    one bucket build ONE program — the true last row, the slot and the
-    block ids do not retrace."""
-    cfg, params = setup
-    rng = np.random.default_rng(31)
-    head = rng.integers(0, cfg.vocab, shared).tolist()
-    prompts = [head + rng.integers(0, cfg.vocab, n - shared).tolist()
-               for n in prompt_lens]
-    from nvme_strom_tpu.models import serving
-    fn = serving._paged_prefill
-    fn.clear_cache()
-    srv = _server(pool, params, cfg)
-    for i, p in enumerate(prompts):
-        srv.submit(i, p, 5)
-        assert srv.run()[i] == _solo(params, cfg, p, 5)
-    assert srv._prefill_shapes == programs
-    assert srv.timings["prefill_programs"] == len(programs)
-    assert fn._cache_size() == len(programs)
-    if shared:
-        assert srv.stats()["prefix_hits"] == 1
-    # a second server of the same shapes compiles nothing new
-    srv = _server(pool, params, cfg)
-    srv.submit("again", prompts[0], 2)
-    srv.run()
-    assert fn._cache_size() == len(programs)
-    assert srv.timings["prefill_programs"] == 1
-
-
-@pytest.mark.parametrize("pool", ["slots", "shared"])
-def test_admission_reads_nothing_back(setup, pool, monkeypatch):
-    """A store-less admission is dispatches only: no ``device_get`` and
-    no host conversion of any device array (the logits stay on the
-    device; the first token rides ``step_many``'s one readback)."""
-    cfg, params = setup
-    srv = _server(pool, params, cfg)
-    srv.submit("warm", [1, 2, 3], 2)        # compile outside the guard
-    srv.run()
-    pulled = []
-
-    def pull(*a, **k):
-        pulled.append(a)
-        raise AssertionError("admission read back from the device")
-
-    arr, to_numpy = type(srv.pos), np.asarray
-
-    def asarray(a, *args, **kw):
-        # numpy reads a CPU device array through the buffer protocol,
-        # past every attribute a test can patch
-        return (pull(a) if isinstance(a, jax.Array)
-                else to_numpy(a, *args, **kw))
-
-    srv.submit("r", [5, 6, 7, 8, 9], 4)
-    with monkeypatch.context() as m:
-        m.setattr(jax, "device_get", pull)
-        m.setattr(np, "asarray", asarray)
-        m.setattr(arr, "_value", property(pull))  # int(), .tolist(), ...
-        for plan in srv._plan_admissions():
-            srv._finish_traced([plan], {})
-    assert not pulled and len(srv._pending_first) == 1
-    assert srv.run()["r"] == _solo(params, cfg, [5, 6, 7, 8, 9], 4)
-
-
-# -- one server: the pool it works out (PR 28) -------------------------------
-
-def _Page(page_tokens):
-    """A prefix store as far as the constructor looks."""
-    import types
-    return types.SimpleNamespace(page_tokens=page_tokens)
-
-
-@pytest.mark.parametrize("max_len,kw,page,block_len,total_blocks", [
-    (64, {}, None, 128, 3 * 1),                 # no store: blocks of 128
-    (100, {"block_len": 16}, None, 16, 3 * 7),  # ceil(100 / 16) a slot
-    (64, {}, 8, 8, 3 * 8),                      # the store's page is the block
-    (64, {"total_blocks": 5}, 8, 8, 5),         # a named pool stays as named
-    (64, {"block_len": 8}, 8, 8, 3 * 8),
-], ids=["default", "block_len", "store_page", "named_pool", "page_agrees"])
-def test_pool_sizes_are_worked_out(setup, max_len, kw, page, block_len,
-                                   total_blocks):
-    """``block_len`` and ``total_blocks`` left out are worked out, not
-    options: the store's page (else 128), and every slot's worst case — the
-    capacity fixed slots had."""
-    cfg, params = setup
-    store = None if page is None else _Page(page)
-    srv = DecodeServer(params, cfg, max_batch=3, max_len=max_len,
-                       kv_store=store, **kw)
-    assert (srv.block_len, srv.total_blocks) == (block_len, total_blocks)
-    assert srv.max_blocks == -(-max_len // block_len)
-    assert srv.k_pool.shape[1:4:2] == (total_blocks + 1, block_len)
-    st = srv.stats()
-    assert (st["blocks_total"], st["blocks_free"]) == (total_blocks,) * 2
-
-
-def test_pool_sizes_that_cannot_hold_refuse(setup):
-    cfg, params = setup
-    with pytest.raises(ValueError, match="must equal block_len"):
-        DecodeServer(params, cfg, 2, 64, block_len=16, kv_store=_Page(8))
-    with pytest.raises(ValueError, match=">= 1"):
-        DecodeServer(params, cfg, 2, 64, total_blocks=0)
-    with pytest.raises(ValueError, match=">= 1"):
-        DecodeServer(params, cfg, 2, 64, block_len=0)
-
-
-@pytest.mark.parametrize("shared_head", [0, 16], ids=["distinct", "shared"])
-def test_derived_pool_never_defers_for_blocks(setup, shared_head):
-    """A server that was given no pool admits ``max_batch`` worst-case
-    requests (prompt + budget = max_len) at once, and every later step
-    admits as many queued requests as it has free slots: admission never
-    waits for a block, with prompts that share cached blocks or not."""
-    cfg, params = setup
-    rng = np.random.default_rng(28)
-    head = rng.integers(0, cfg.vocab, shared_head).tolist()
-    reqs = {i: head + rng.integers(0, cfg.vocab, 30 - shared_head).tolist()
-            for i in range(7)}
-    srv = DecodeServer(params, cfg, max_batch=3, max_len=40, block_len=8)
-    assert srv.total_blocks == 3 * 5
-    for i, p in reqs.items():
-        srv.submit(i, p, 10 if i % 2 else 3)     # 30 + 10 = max_len
-    got, steps = {}, 0
-    while not srv.idle:
-        due = min(len(srv.queue), sum(r is None for r in srv.slots))
-        before = srv.timings["admits"]
-        got.update(srv.step_many(2))
-        assert srv.timings["admits"] - before == due
-        steps += 1
-        assert steps < 100
-    assert srv.timings["admits"] == len(reqs)
-    for i, p in reqs.items():
-        assert got[i] == _solo(params, cfg, p, 10 if i % 2 else 3), i
-    cached = [e["blk"] for e in srv._pc.values()]
-    assert sorted(srv.free + cached) == list(range(15))     # none leaked
-
-
-def test_build_server_without_a_pool_serves_generates_tokens(setup):
-    """``examples/serve.build_server(paged=0)``: the one class over the
-    pool it works out, serving ``generate()``'s greedy tokens."""
-    from examples.serve import build_server
-    cfg, params = setup
-    srv = build_server(params, cfg, slots=2, max_len=48, paged=0,
-                       block_len=16)
-    assert type(srv) is DecodeServer
-    assert (srv.block_len, srv.total_blocks) == (16, 2 * 3)
-    named = build_server(params, cfg, slots=2, max_len=48, paged=4,
-                         block_len=16)
-    assert (named.block_len, named.total_blocks) == (16, 4)
-    rng = np.random.default_rng(5)
-    reqs = {f"p{i}": (rng.integers(0, cfg.vocab, 4 + 3 * i).tolist(), 5)
-            for i in range(3)}
-    for rid, (p, m) in reqs.items():
-        srv.submit(rid, p, m)
-    got = srv.run(lookahead=2)
-    for rid, (p, m) in reqs.items():
-        assert got[rid] == _solo(params, cfg, p, m), rid
-
-
-def test_serve_example_runs_without_paged(tmp_path, capsys):
-    """``examples/serve.py`` with no ``--paged`` end to end, from a
-    converted checkpoint directory: the tokens are ``generate()``'s on the
-    weights as loaded, and an explicit pool serves the same."""
-    import json
-
-    from examples import serve
-    from nvme_strom_tpu.formats.safetensors import write_safetensors
-    from nvme_strom_tpu.tools.convert_llama import strom_config_dict
-    cfg = tiny_config()
-    params = init_params(jax.random.key(4), cfg)
-    write_safetensors(str(tmp_path / "model.safetensors"),
-                      {k: np.asarray(v) for k, v in params.items()})
-    with open(tmp_path / "strom_config.json", "w") as f:
-        json.dump(strom_config_dict(cfg), f)
-    argv = ["--weights", str(tmp_path), "--slots", "2", "--max-len", "32",
-            "--request", "5,6,7:8", "--request", "9,1:5",
-            "--request", "3:4"]
-
-    def served(extra):
-        assert serve.main(argv + extra) == 0
-        out = capsys.readouterr().out
-        assert "served 3 requests / 17 tokens" in out
-        return {ln.split(":")[0]: [int(t) for t in
-                                   ln.split(":")[1].split(",")]
-                for ln in out.splitlines() if ln[:1] == "r"}
-
-    got = served([])
-    cfg = serve.read_config(str(tmp_path))
-    for rid, p, m in (("r0", [5, 6, 7], 8), ("r1", [9, 1], 5),
-                      ("r2", [3], 4)):
-        assert got[rid] == _solo(params, cfg, p, m), rid
-    assert served(["--paged", "3", "--block-len", "16"]) == got
-    with pytest.raises(SystemExit):
-        serve.main(argv + ["--pallas"])         # the flag is gone
-
-
-# -- the decode step writes the pool in place (PR 27) ------------------------
-
-#: what the server below served at PR 26 (commit 254ed1e: rows scattered
-#: into the pool with ``.at[].set`` and one layer sliced out for the kernel),
-#: recorded on this CPU; the step that writes the donated pool in place and
-#: reads it where it lies has to serve the very same tokens
-SERVED_AT_PR26 = {
-    "plain_f32": {
-        "r0": [42, 68, 50, 44, 54, 19, 40, 26, 58],
-        "r1": [75, 6, 45, 70, 68, 39],
-        "r2": [49, 123, 79, 117, 90, 58, 100, 67, 19, 40, 26],
-        "r3": [48, 99, 57, 49, 98],
-        "r4": [100, 125, 3, 107, 94, 26, 58, 100],
-    },
-    "plain_bf16": {
-        "r0": [42, 68, 50, 44, 54, 19, 40, 26, 58],
-        "r1": [75, 6, 53, 108, 40, 39],
-        "r2": [49, 123, 79, 117, 90, 58, 100, 67, 19, 40, 26],
-        "r3": [48, 99, 57, 49, 98],
-        "r4": [100, 125, 3, 107, 94, 26, 58, 100],
-    },
-    "hybrid": {
-        "r0": [93, 6, 5, 93, 72, 31, 5, 5, 27],
-        "r1": [12, 18, 41, 41, 73, 41],
-        "r2": [36, 88, 74, 83, 31, 93, 66, 62, 79, 3, 33],
-        "r3": [79, 26, 76, 43, 12],
-        "r4": [57, 79, 32, 93, 65, 6, 63, 53],
-    },
-}
-
-
-def _pr26_model(kind):
-    """(params, cfg, block_len, total_blocks) of the recorded runs: the tiny
-    decoder of this file in float32 and in bfloat16, and test_hybrid.py's
-    hybrid (its attention layer keeps K/V, its three mamba layers state)."""
-    if kind == "hybrid":
-        import dataclasses
-
-        import test_hybrid as H
-        cfg = dataclasses.replace(H.config_from_hf(H.HF), dtype=jnp.float32)
-        params = {k: v.astype(jnp.float32)
-                  for k, v in H.WH.make_params(H.HF, H.SEED).items()}
-        return params, cfg, 8, 12
-    dtype = jnp.float32 if kind == "plain_f32" else jnp.bfloat16
-    cfg = TransformerConfig(**{**tiny_config().__dict__, "dtype": dtype})
-    params = {k: v.astype(dtype)
-              for k, v in init_params(jax.random.key(0), cfg).items()}
-    return params, cfg, 4, 24
-
-
-@pytest.mark.parametrize("kind", sorted(SERVED_AT_PR26))
-def test_paged_server_serves_what_it_served_before_the_in_place_step(kind):
-    """Five requests behind one shared head on two slots, lookahead 2:
-    every slot is freed and admitted again, the plain decoder's later
-    admissions hit the prefix cache (a hybrid has no prefix reuse), free
-    slots write the trash block meanwhile — token for token what the
-    scatter-and-slice step served."""
-    params, cfg, bk, blocks = _pr26_model(kind)
-    rng = np.random.default_rng(27)
-    shared = rng.integers(0, cfg.vocab, 3 * bk + 1).tolist()
-    reqs = [(f"r{i}", shared + rng.integers(0, cfg.vocab, n).tolist(), m)
-            for i, (n, m) in enumerate([(2, 9), (5, 6), (1, 11), (7, 5),
-                                        (3, 8)])]
-    srv = DecodeServer(params, cfg, max_batch=2, max_len=64,
-                       total_blocks=blocks, block_len=bk)
-    for rid, prompt, budget in reqs:
-        srv.submit(rid, prompt, budget)
-    assert srv.run(lookahead=2) == SERVED_AT_PR26[kind]
-    assert srv.timings["admits"] == 5               # 2 slots: 3 re-admitted
-    assert srv.stats()["prefix_hits"] == (0 if kind == "hybrid" else 3)
-
-
-def _pool_sized_eqns(jaxpr, sizes, found):
-    """Equations outside the kernels that make something pool-sized."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            continue
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            _pool_sized_eqns(sub, sizes, found)
-        if any(int(np.prod(v.aval.shape)) in sizes for v in eqn.outvars
-               if hasattr(v.aval, "shape")):
-            found.append(eqn.primitive.name)
-    return found
-
-
-@pytest.mark.parametrize("kind", ["plain_f32", "hybrid"])
-def test_decode_step_traces_no_pool_sized_op_outside_the_kernels(kind):
-    """The structure of the step (``paged_logits``) on any platform: nothing
-    but the two kernels produces a value of the pool's size or of one
-    layer's — no scatter, no slice, no gather.  What the TPU's compiler
-    makes of it is pinned by tests/test_chip_compile.py."""
-    from nvme_strom_tpu.models import serving, ssm
-    params, cfg, bk, blocks = _pr26_model(kind)
-    B, L = 2, len(cfg.attn_layers)
-    pool = jnp.zeros((L, blocks + 1, cfg.n_kv_heads, bk, cfg.head_dim),
-                     cfg.dtype)
-    state = ssm.init_state(cfg, B + 1) if cfg.mamba_layers else None
-    i32 = jnp.zeros((B,), jnp.int32)
-    jaxpr = jax.make_jaxpr(
-        lambda *a: serving.paged_logits(params, cfg, *a))(
-        i32, pool, pool, i32, i32, jnp.zeros((B, 64 // bk), jnp.int32), i32,
-        state, i32)
-    assert str(jaxpr).count("pallas_call") >= 2 * L
-    assert not _pool_sized_eqns(jaxpr.jaxpr, {pool.size, pool.size // L}, [])
-
-
-# -- paged attention walks only what is live --------------------------------
-
-@pytest.mark.parametrize("kind", ["plain_f32", "hybrid"])
-def test_mixed_batch_serves_generates_tokens_and_counts_its_live_blocks(
-        kind):
-    """Short and long prompts on two slots of a table 8 blocks wide, one
-    step a call: slot 0 finishes twice and is admitted again while slot 1's
-    long request runs on, and at the end slot 1 lies free (a stale ``pos``
-    over a table row of zeros) beside the last request.  Tokens are
-    ``generate()``'s, and the two counters are what the prompts' lengths
-    say: a request of prompt ``s`` and budget ``m`` takes ``m - 1`` decode
-    steps at positions ``s .. s + m - 2`` (its first token is the
-    prefill's), each reading ``pos // block + 1`` table entries, where an
-    unbounded walk reads slots x table width at every step; the kernel's
-    grid is a step for each of those entries and one for a free slot."""
-    params, cfg, _, _ = _pr26_model(kind)
-    bk = 8
-    rng = np.random.default_rng(29)
-    reqs = {f"r{i}": (rng.integers(0, cfg.vocab, s).tolist(), m)
-            for i, (s, m) in enumerate([(3, 4), (30, 9), (17, 6), (5, 3)])}
-    srv = DecodeServer(params, cfg, max_batch=2, max_len=64,
-                       total_blocks=12, block_len=bk)
-    for rid, (prompt, budget) in reqs.items():
-        srv.submit(rid, prompt, budget)
-    got = srv.run()
-    for rid, (prompt, budget) in reqs.items():
-        assert got[rid] == _solo(params, cfg, prompt, budget), rid
-    # r0 holds slot 0 for calls 1-3, r2 for 4-8, r3 for 9-10; r1 slot 1 for
-    # calls 1-8: ten steps, the last two with slot 1 free
-    assert srv.timings["steps"] == 10 and srv.timings["admits"] == 4
-    live = sum(pos // bk + 1 for prompt, budget in reqs.values()
-               for pos in range(len(prompt), len(prompt) + budget - 1))
-    assert live == 3 * 1 + (2 * 4 + 6 * 5) + 5 * 3 + 2 * 1 == 58
-    stats = srv.stats()
-    # 58 of the 160 entries ten unbounded steps would have walked
-    assert (stats["attn_blocks_live"], stats["attn_blocks_table"]) \
-        == (live, 10 * 2 * (64 // bk))
-    # the grid of one layer's call is a step a live entry, and in calls 9
-    # and 10 one more for the free slot 1 (handed ``pos`` 0): 60, where the
-    # (slots x longest slot) grid made 2 x 2 x 4 + 6 x 2 x 5 + 2 x 2 x 1 = 80
-    assert stats["attn_grid_steps"] == 58 + 2 * 1
-    assert stats["attn_blocks_live"] <= stats["attn_grid_steps"]

@@ -36,8 +36,10 @@ def test_stress_plain(tmp_path):
 
 def test_stress_tsan(tmp_path):
     binary = _build("stress_test_tsan")
+    # a healthy run takes 1.5-3 s, on a loaded machine too; a hang costs
+    # a worker two minutes, not ten
     r = subprocess.run([str(binary), "60", "3", str(tmp_path)],
-                       capture_output=True, text=True, timeout=600,
+                       capture_output=True, text=True, timeout=120,
                        env={"PATH": "/usr/bin:/bin",
                             "TSAN_OPTIONS": "halt_on_error=0 exitcode=66"})
     assert r.returncode == 0, r.stderr[-3000:]

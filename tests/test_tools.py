@@ -240,181 +240,3 @@ def test_watchdog_dump_carries_kv_serving_line():
         assert ("kv serving:" in dump) is expect, dump
 
 
-def test_profile_classify_first_match_wins():
-    """A matmul fusion must land in the matmul bucket even though its
-    name also says "fusion" — the bucket order IS the precedence."""
-    from nvme_strom_tpu.tools.profile_report import classify
-    assert classify("%convolution_reduce_fusion = f32[] fusion(...)") \
-        == "matmul"
-    assert classify("%dot.54") == "matmul"
-    assert classify("%tpu_custom_call.3") == "attention-kernel"
-    assert classify("%copy-start.1") == "copy"
-    assert classify("%add_multiply_fusion.2") == "elementwise-fusion"
-    # a bare fusion name carries no constituent evidence: its own
-    # bucket, never a claim of elementwise (nor matmul) work
-    assert classify("%fusion.212") == "unnamed-fusion"
-    assert classify("%while.7") == "other"
-
-
-def test_profile_classify_ignores_operands():
-    """Classification must come from the op's own identity, never its
-    operand list — the 2026-07-31 window ledgered '69% copy' because a
-    matmul fusion consuming %transpose operands keyword-matched copy."""
-    from nvme_strom_tpu.tools.profile_report import classify, event_bucket
-    # full HLO line: dot op with a transposed operand — matmul, not copy
-    assert classify("%f.1 = bf16[8,16]{1,0} dot(%transpose.5, %p.2), "
-                    "lhs_contracting_dims={1}") == "matmul"
-    # explicit copy op with a dot-named operand — copy, not matmul
-    assert classify("%copy.9 = bf16[8]{0} copy(%dot.3)") == "copy"
-    # bare fusion: falls back to the lhs name's constituents
-    assert classify("%multiply_reduce_fusion.38 = f32[] fusion("
-                    "%custom-call.2), kind=kOutput") == "reduce"
-
-    class Ev:          # xprof's own category stat wins when present
-        name = "%fusion.212 = bf16[] fusion(%transpose.1)"
-        stats = [("hlo_category", "convolution fusion")]
-    assert event_bucket(Ev()) == "matmul"
-
-    class Ev2:         # no stat → name path
-        name = "%fusion.7 = bf16[] fusion(%p)"
-        stats = []
-    assert event_bucket(Ev2()) == "unnamed-fusion"
-
-
-def test_profile_fusion_map_resolves_buckets(tmp_path):
-    """The dumped post-optimization HLO resolves bare %fusion.NN events
-    to their constituent opcodes: a dot-containing output fusion is MXU
-    work, a reduce-calling loop fusion is reduction work — the exact
-    attribution the bare name ('unnamed-fusion', ~70% of device time in
-    the valid window-7 parses) cannot provide."""
-    hlo = """HloModule jit_train_step
-
-%fused_computation.1 (p0: bf16[8,128]) -> bf16[8,128] {
-  %p0 = bf16[8,128]{1,0} parameter(0)
-  %p1 = bf16[128,128]{1,0} parameter(1)
-  ROOT %dot.3 = bf16[8,128]{1,0} dot(%p0, %p1), lhs_contracting_dims={1}
-}
-
-%fused_computation.2 (p0: f32[8,128]) -> f32[8] {
-  %p0 = f32[8,128]{1,0} parameter(0)
-  %c = f32[] constant(0)
-  ROOT %reduce.1 = f32[8]{0} reduce(%p0, %c), dimensions={1}
-}
-
-ENTRY %main.9 (a: bf16[8,128]) -> f32[8] {
-  %fusion.10 = bf16[8,128]{1,0} fusion(%a), kind=kOutput, calls=%fused_computation.1
-  ROOT %fusion.11 = f32[8]{0} fusion(%fusion.10), kind=kLoop, calls=%fused_computation.2
-}
-"""
-    (tmp_path / "optimized_hlo.txt").write_text(hlo)
-    from nvme_strom_tpu.tools import profile_report
-    fmap = profile_report.load_fusion_map(str(tmp_path))
-    # sigil-less keys: TPU device planes log "%fusion.NN", CPU host
-    # planes "fusion.NN" — the map matches both
-    assert fmap["fusion.10"] == "matmul-fusion"
-    assert fmap["fusion.11"] == "reduce-fusion"
-
-    class Ev:    # resolved map beats both the stat and the bare name
-        name = "%fusion.10 = bf16[8,128]{1,0} fusion(%a), kind=kOutput"
-        stats = [("hlo_category", "loop fusion")]
-    assert profile_report.event_bucket(Ev(), fmap) == "matmul-fusion"
-    # no map → empty dict → unchanged fallback behavior
-    assert profile_report.load_fusion_map("/nonexistent-dir") == {}
-
-    # MXU-efficiency half: the dot inside %fused_computation.1 is
-    # (8,128)@(128,128) → 2·(8·128)·128 FLOPs, attributed to the
-    # calling %fusion.10; the reduce-only fusion gets no entry
-    flops = profile_report.load_fusion_flops(str(tmp_path))
-    assert flops["fusion.10"] == 2 * (8 * 128) * 128
-    assert "fusion.11" not in flops
-    assert profile_report.load_fusion_flops("/nonexistent-dir") == {}
-
-
-def test_profile_matmul_flops_batched_conv_and_malformed():
-    """2·|out|·K is exact for batched dots (batch dims ride the output
-    product) and for XLA's matmul-as-convolution spelling; malformed
-    lines read as 0, never a wrong estimate."""
-    from nvme_strom_tpu.tools import profile_report
-    line = ("%dot.7 = bf16[4,256,512]{2,1,0:T(8,128)(2,1)} "
-            "dot(bf16[4,256,64]{2,1,0} %a, bf16[4,64,512]{2,1,0} %b), "
-            "lhs_batch_dims={0}, lhs_contracting_dims={2}, "
-            "rhs_batch_dims={0}, rhs_contracting_dims={1}")
-    assert (profile_report._matmul_flops(line, "dot", {})
-            == 2 * (4 * 256 * 512) * 64)
-    # optimized modules spell dW = x^T @ dy as a convolution with
-    # dim_labels=fb_io->bf: K = lhs 'f' dim (the contracted batch)
-    conv = ("ROOT %convolution.5 = bf16[256,512]{1,0:T(8,128)(2,1)} "
-            "convolution(%a, %b), dim_labels=fb_io->bf")
-    defs = {"a": [128, 256], "b": [128, 512]}
-    assert (profile_report._matmul_flops(conv, "convolution", defs)
-            == 2 * (256 * 512) * 128)
-    assert profile_report._matmul_flops("%dot.8 = garbage", "dot", {}) == 0
-
-
-def test_profile_hlo_param_names_scoped_per_computation(tmp_path):
-    """Computation-header/parameter names (p0, param_0) repeat across
-    fused computations; a module-wide defs map let a LATER computation's
-    same-named param overwrite an earlier one and mis-size K for
-    operands without inline shapes (round-4 advisor).  Here two fusions
-    both name their param %p0 with different K dims — each dot must be
-    sized by ITS OWN computation's p0."""
-    hlo = """HloModule jit_scoped
-
-%fused_computation.1 (p0: bf16[8,64]) -> bf16[8,32] {
-  %p0 = bf16[8,64]{1,0} parameter(0)
-  %w1 = bf16[64,32]{1,0} parameter(1)
-  ROOT %dot.1 = bf16[8,32]{1,0} dot(%p0, %w1), lhs_contracting_dims={1}
-}
-
-%fused_computation.2 (p0: bf16[8,4096]) -> bf16[8,32] {
-  %p0 = bf16[8,4096]{1,0} parameter(0)
-  %w2 = bf16[4096,32]{1,0} parameter(1)
-  ROOT %dot.2 = bf16[8,32]{1,0} dot(%p0, %w2), lhs_contracting_dims={1}
-}
-
-ENTRY %main.9 (a: bf16[8,64], b: bf16[8,4096]) -> bf16[8,32] {
-  %fusion.1 = bf16[8,32]{1,0} fusion(%a), kind=kOutput, calls=%fused_computation.1
-  ROOT %fusion.2 = bf16[8,32]{1,0} fusion(%b), kind=kOutput, calls=%fused_computation.2
-}
-"""
-    (tmp_path / "optimized_hlo.txt").write_text(hlo)
-    from nvme_strom_tpu.tools import profile_report
-    flops = profile_report.load_fusion_flops(str(tmp_path))
-    # fusion.1's dot contracts K=64, fusion.2's K=4096 — the flat-map
-    # bug sized BOTH by the last-seen p0 (K=4096)
-    assert flops["fusion.1"] == 2 * (8 * 32) * 64
-    assert flops["fusion.2"] == 2 * (8 * 32) * 4096
-
-
-def test_profile_report_capture_and_parse(capsys, monkeypatch):
-    """End-to-end on the CPU backend: trace a tiny train variant, parse
-    the xplane protobuf, and emit the one-line breakdown the watcher
-    ledgers (verdict #3's profile-attribution evidence path)."""
-    monkeypatch.setenv("STROM_SUITE_TINY_COMPUTE", "1")
-    from nvme_strom_tpu.tools import profile_report
-    rc = profile_report.main(["--batch", "2", "--seq", "64"])
-    out = capsys.readouterr().out
-    assert rc == 0
-    rec = json.loads(out.strip().splitlines()[-1])
-    assert rec["metric"] == "config7:profile-breakdown"
-    assert rec["device_busy_ms"] > 0
-    assert rec["tflops"] > 0
-    fracs = rec["category_frac"]
-    assert abs(sum(fracs.values()) - 1.0) < 1e-3
-    assert rec["top_ops_ms"]          # non-empty attribution
-    assert "matmul" in rec["category_ms"] or "other" in rec["category_ms"]
-    # the capture step dumps the optimized HLO next to the trace, so
-    # the parse resolves fusion constituents (0 only if the dump was
-    # unavailable, which the CPU backend always serves) — and the
-    # resolution must have APPLIED to traced time, not just loaded
-    assert rec["fusions_resolved"] > 0
-    assert rec["fusion_resolved_ms"] > 0
-
-
-def test_profile_report_missing_dir():
-    """--dir on an empty directory fails loudly, not with a zero row."""
-    from nvme_strom_tpu.tools import profile_report
-    import tempfile
-    with tempfile.TemporaryDirectory() as d:
-        with pytest.raises(FileNotFoundError):
-            profile_report.parse_trace(d)

@@ -167,7 +167,7 @@ def test_header_parse_no_residency_pollution(mesh8, engine, tmp_path):
     to the buffered path for every small early tensor (the wds index
     walk measured 100% fallback+bounce from the same class of
     pollution)."""
-    import bench
+    from conftest import evict_file
     from jax.sharding import NamedSharding, PartitionSpec as P
     rng = np.random.default_rng(3)
     # many small tensors early in the file: a buffered header parse's
@@ -184,7 +184,7 @@ def test_header_parse_no_residency_pollution(mesh8, engine, tmp_path):
     # __init__, and the assertion must see their pollution, not a
     # pre-evicted cache (verified: the old buffered parse leaves
     # 16 KiB planned resident under exactly this ordering)
-    bench.evict_file(str(path))
+    evict_file(path)
     ckpt = LazyCheckpoint([path])
     sh = NamedSharding(mesh8, P())
     params = ckpt.load_sharded(lambda name, shape: sh, engine=engine)
