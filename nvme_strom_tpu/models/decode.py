@@ -25,8 +25,8 @@ from jax import lax
 from nvme_strom_tpu.models.transformer import (
     wmat,
     TransformerConfig, add_residual, attention, embed_tokens,
-    expand_gqa, gate_heads, lm_logits, mlp, qkv_project, qkvg_project,
-    rms_norm, valid_rows)
+    expand_gqa, gate_heads, lm_logits, mlp, norm_in, norm_out, qkv_project,
+    qkvg_project, rms_norm, valid_rows)
 from nvme_strom_tpu.models import mla as _mla
 from nvme_strom_tpu.models import moe as _moe
 
@@ -143,17 +143,18 @@ def prefill(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     positions = jnp.arange(s, dtype=jnp.float32)
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
-        h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
+        h = norm_in(x, params[L + "attn_norm"], cfg)
         a, k, v = attention(h, params, L, cfg, positions=positions,
                             return_kv=True)
         cache["k"] = lax.dynamic_update_slice(
             cache["k"], k[None].astype(cfg.dtype), (i, 0, 0, 0, 0))
         cache["v"] = lax.dynamic_update_slice(
             cache["v"], v[None].astype(cfg.dtype), (i, 0, 0, 0, 0))
-        x = add_residual(x, a, cfg)
-        h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
-        x = add_residual(x, _mlp_block(h, params, L, cfg),
-                         cfg).astype(cfg.dtype)
+        x = add_residual(x, norm_out(a, params[L + "attn_norm"], cfg), cfg)
+        h = norm_in(x, params[L + "mlp_norm"], cfg)
+        f = norm_out(_mlp_block(h, params, L, cfg), params[L + "mlp_norm"],
+                     cfg)
+        x = add_residual(x, f, cfg).astype(cfg.dtype)
     cache["pos"] = jnp.asarray(s, jnp.int32)
     x = rms_norm(x[:, s - 1 if last is None else last],
                  params["final_norm"], cfg.norm_eps)
@@ -184,7 +185,7 @@ def decode_step(params: Dict, token: jax.Array, cfg: TransformerConfig,
     positions = pos.astype(jnp.float32)[None]
     for i in range(cfg.n_layers):
         L = f"layers.{i}."
-        h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
+        h = norm_in(x, params[L + "attn_norm"], cfg)
         q, k, v = qkv_project(h, params, L, cfg,       # (b, nkv, 1, hd)
                               positions=positions)
         cache["k"] = lax.dynamic_update_slice(
@@ -195,10 +196,12 @@ def decode_step(params: Dict, token: jax.Array, cfg: TransformerConfig,
         # group maps to its kv head inside (no expanded HBM copy)
         a = cache_attn(q, cache["k"][i], cache["v"][i], pos)
         a = a.transpose(0, 2, 1, 3).reshape(b, 1, -1)
-        x = add_residual(x, a @ wmat(params, L + "wo", a.dtype), cfg)
-        h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
-        x = add_residual(x, _mlp_block(h, params, L, cfg),
-                         cfg).astype(cfg.dtype)
+        a = a @ wmat(params, L + "wo", a.dtype)
+        x = add_residual(x, norm_out(a, params[L + "attn_norm"], cfg), cfg)
+        h = norm_in(x, params[L + "mlp_norm"], cfg)
+        f = norm_out(_mlp_block(h, params, L, cfg), params[L + "mlp_norm"],
+                     cfg)
+        x = add_residual(x, f, cfg).astype(cfg.dtype)
     cache["pos"] = pos + 1
     x = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
     return lm_logits(params, cfg, x), cache
@@ -362,7 +365,7 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
         L = f"layers.{i}."
         before, after = MIXER_SCOPES[cfg.mixer(i)]
         with jax.named_scope(before):
-            h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
+            h = norm_in(x, params[L + "attn_norm"], cfg)
         if cfg.is_mamba_layer(i):
             from nvme_strom_tpu.models.ssm import mamba_block
             a, states[mi], tails[ti] = mamba_block(
@@ -440,11 +443,13 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
                 a = a @ wmat(params, L + "wo", a.dtype)
             ai += 1
         with jax.named_scope(after):
-            x = add_residual(x, a, cfg)
+            x = add_residual(x, norm_out(a, params[L + "attn_norm"], cfg),
+                             cfg)
         with jax.named_scope("strom.mlp"):
-            h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
+            h = norm_in(x, params[L + "mlp_norm"], cfg)
             f = _mlp_block(h, params, L, cfg, valid, calls)
-            x = add_residual(x, f, cfg).astype(cfg.dtype)
+            x = add_residual(x, norm_out(f, params[L + "mlp_norm"], cfg),
+                             cfg).astype(cfg.dtype)
     with jax.named_scope("strom.head"):
         cache["pos"] = pos + m
     if ssm:

@@ -230,6 +230,7 @@ class PagedKVCache:
     def __init__(self, cfg: TransformerConfig, ocfg: OffloadConfig,
                  engine: StromEngine, batch: int, device=None):
         cfg.require_kv_pages("PagedKVCache (models/kv_offload.py)")
+        cfg.require_pre_norm("PagedKVCache (models/kv_offload.py)")
         self.cfg = cfg
         self.ocfg = ocfg
         self.engine = engine

@@ -73,7 +73,7 @@ from nvme_strom_tpu.models import ssm as _ssm
 from nvme_strom_tpu.models.decode import _mlp_block
 from nvme_strom_tpu.models.transformer import (
     TransformerConfig, add_residual, embed_tokens, lm_logits,
-    gate_heads, qkvg_project, rms_norm, wmat)
+    gate_heads, norm_in, norm_out, qkvg_project, rms_norm, wmat)
 
 
 @dataclass
@@ -397,7 +397,7 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
         L = f"layers.{i}."
         before, after = _dec.MIXER_SCOPES[cfg.mixer(i)]
         with jax.named_scope(before):
-            h = rms_norm(x, params[L + "attn_norm"], cfg.norm_eps)
+            h = norm_in(x, params[L + "attn_norm"], cfg)
         if cfg.is_mamba_layer(i):
             a, s_pools[mi], tails[ti] = _ssm.mamba_step(
                 h, params, L, cfg, s_pools[mi], tails[ti], sidx)
@@ -456,11 +456,13 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
                 a = a @ wmat(params, L + "wo", a.dtype)
             wi, ai = wi + win, ai + (not win)
         with jax.named_scope(after):
-            x = add_residual(x, a, cfg)
+            x = add_residual(x, norm_out(a, params[L + "attn_norm"], cfg),
+                             cfg)
         with jax.named_scope("strom.mlp"):
-            h = rms_norm(x, params[L + "mlp_norm"], cfg.norm_eps)
+            h = norm_in(x, params[L + "mlp_norm"], cfg)
             f = _mlp_block(h, params, L, cfg, live, calls)
-            x = add_residual(x, f, cfg).astype(cfg.dtype)
+            x = add_residual(x, norm_out(f, params[L + "mlp_norm"], cfg),
+                             cfg).astype(cfg.dtype)
     with jax.named_scope("strom.head"):
         x = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
     if state is not None:
@@ -1462,13 +1464,17 @@ class DecodeServer:
         # that carry it (beside ``kv_layers``, the layers that keep pages)
         out["state_bytes_per_slot"] = out["state_bytes"] // (self.B + 1)
         out["state_layers"] = len(self.cfg.recurrent_layers)
-        # the Mamba-2 heads a lane row of the state pool holds side by side
-        # (1: nothing is packed)
+        # the Mamba-2 or delta-rule heads a lane row of the state pool
+        # holds side by side (1: nothing is packed)
         out["state_heads_per_lane_row"] = 1
         if self.cfg.mamba_layers:
             from nvme_strom_tpu.ops.ssm import heads_per_lane_row
             out["state_heads_per_lane_row"] = heads_per_lane_row(
                 self.cfg.ssm_heads, self.cfg.ssm_head_dim)
+        elif "gdn" in self.cfg.layer_kinds:
+            from nvme_strom_tpu.ops.gdn import heads_per_lane_row
+            out["state_heads_per_lane_row"] = heads_per_lane_row(
+                self.cfg.gdn_v_heads, self.cfg.gdn_v_dim)
         # layers whose MLP is the exact expert layer, and what they routed
         # (decode steps; the prefill's own under *_prefill in timings)
         out["moe_layers"] = len(self.cfg.expert_layers)

@@ -30,9 +30,11 @@ and Hv value heads, value head j reading key head j // (Hv / Hk):
     [q, k, v]_t = silu(Σ_j w_conv[j] ⊙ [q, k, v]_{t-K+1+j})   depthwise,
                                            causal, no bias
     q̃ = q / sqrt(Σq² + 1e-6) / sqrt(dk)   k̃ = k / sqrt(Σk² + 1e-6)   per head
-    β_t = sigmoid(b_t)   α_t = exp(−exp(A_log) · softplus(a_t + dt_bias))
+    β_t = sigmoid(b_t), or 2·sigmoid(b_t) with ``gdn_neg_eigval``
+    α_t = exp(−exp(A_log) · softplus(a_t + dt_bias))
     S ← α_t S;  u = β_t (v_t − Sᵀ k̃_t);  S ← S + k̃_t ⊗ u;  o_t = Sᵀ q̃_t
-                                           S: (Hv, dk, dv) float32
+                                           S: (Hv, dk, dv) float32, kept as
+                                           (Hv/g, dk, g·dv): ops/gdn.py
     out  = (g_norm ⊙ o / sqrt(mean o² + eps) ⊙ silu(z)) W_out   per head
 
 What a sequence carries from one call to the next is, for Mamba-2 and the
@@ -61,6 +63,7 @@ import numpy as np
 
 from nvme_strom_tpu.models.transformer import (TransformerConfig, rms_norm,
                                                valid_rows, wmat)
+from nvme_strom_tpu.ops import gdn as _gdn
 from nvme_strom_tpu.ops.gdn import gdn_scan, gdn_update
 from nvme_strom_tpu.ops.ssm import pool_shape, ssm_scan, ssm_update
 
@@ -93,15 +96,18 @@ def init_state(cfg: TransformerConfig, rows: int) -> Dict:
     (``cfg.state_layers``), float32: (rows, H/g, N, g·P) for Mamba-2 —
     state-major, the g = ``ops.ssm.heads_per_lane_row`` heads that fill the
     128 lanes side by side (two of 64; one of 128 or more), the form both
-    of its kernels and the server's scatter take it in —, (rows, Hv, dk, dv)
-    for the delta rule.  Under ``"conv"`` one conv tail per
+    of its kernels and the server's scatter take it in —, (rows, Hv/g, dk,
+    g·dv) for the delta rule, g = ``ops.gdn.heads_per_lane_row`` heads side
+    by side where dv alone is no multiple of 128 (two of 192; one of 128).
+    Under ``"conv"`` one conv tail per
     recurrent layer of any kind: (rows, K−1, inner + 2N) for Mamba-2, (rows,
     gdn_conv − 1, 2·Hk·dk + Hv·dv) for the delta rule, (rows, conv_taps − 1,
     d_model) for the short conv.  Both in layer order.  Tuples of per-layer
     arrays, never one stacked array: each is donated to the step and updated
     in place, and indexing a stacked one by layer would copy the lot (the KV
     pool's copies in PERF.md §5)."""
-    s = {"gdn": (rows, cfg.gdn_v_heads, cfg.gdn_k_dim, cfg.gdn_v_dim)}
+    s = {"gdn": _gdn.pool_shape(rows, cfg.gdn_v_heads, cfg.gdn_k_dim,
+                                cfg.gdn_v_dim)}
     if cfg.mamba_layers:                   # a head width to pack by
         s["mamba"] = pool_shape(rows, cfg.ssm_heads, cfg.ssm_head_dim,
                                 cfg.ssm_state)
@@ -307,6 +313,8 @@ def _gdn_project(h, p, L, cfg):
     ba = (h @ wmat(p, L + "gdn_ba", h.dtype)).astype(jnp.float32)
     H = cfg.gdn_v_heads
     beta = jax.nn.sigmoid(ba[..., :H])
+    if cfg.gdn_neg_eigval:
+        beta = 2.0 * beta
     # the decay by its logarithm: a head that forgets at once has α = 0 in
     # float32, and the scan works in differences of log α
     log_alpha = -jnp.exp(p[L + "gdn_A_log"].astype(jnp.float32)) \
@@ -347,10 +355,10 @@ def _gdn_out(o, z, p, L, cfg):
 
 def gdn_block(h, p: Dict, L: str, cfg: TransformerConfig, s0=None,
               tail=None, n_valid=None):
-    """A block of rows through the delta-rule mixer.  h (b, m, d)
-    post-norm; s0 (b, Hv, dk, dv) float32 and tail (b, K−1, conv): what the
-    sequences carried in (None: zeros); n_valid as in ``mamba_block``.
-    Returns (out (b, m, d), S, tail)."""
+    """A block of rows through the delta-rule mixer.  h (b, m, d), what
+    the mixer takes; s0 (b, Hv/g, dk, g·dv) float32, ``init_state``'s form,
+    and tail (b, K−1, conv): what the sequences carried in (None: zeros);
+    n_valid as in ``mamba_block``.  Returns (out (b, m, d), S, tail)."""
     b, m, _ = h.shape
     k1 = cfg.gdn_conv - 1
     with jax.named_scope("strom.ssm.proj"):
@@ -358,8 +366,8 @@ def gdn_block(h, p: Dict, L: str, cfg: TransformerConfig, s0=None,
         if tail is None:
             tail = jnp.zeros((b, k1, cfg.gdn_conv_dim), u.dtype)
         if s0 is None:
-            s0 = jnp.zeros((b, cfg.gdn_v_heads, cfg.gdn_k_dim,
-                            cfg.gdn_v_dim), jnp.float32)
+            s0 = jnp.zeros(_gdn.pool_shape(b, cfg.gdn_v_heads, cfg.gdn_k_dim,
+                                           cfg.gdn_v_dim), jnp.float32)
         window = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
         w = p[L + "gdn_conv_w"].astype(jnp.float32)
         conv = sum(w[j] * window[:, j:j + m].astype(jnp.float32)
@@ -374,8 +382,10 @@ def gdn_block(h, p: Dict, L: str, cfg: TransformerConfig, s0=None,
             valid = valid_rows(n_valid, b, m)
             new_tail = _tail_at(window, n_valid, k1)
     with jax.named_scope("strom.ssm.scan"):
-        o, s = gdn_scan(q, k, v, log_alpha, beta, s0, valid,
+        o, s = gdn_scan(q, k, v, log_alpha, beta,
+                        _gdn.unpack_state(s0, cfg.gdn_v_heads), valid,
                         chunk=cfg.gdn_chunk)
+        s = _gdn.pack_state(s)
     with jax.named_scope("strom.ssm.out"):
         out = _gdn_out(o, z, p, L, cfg)
     return out, s, new_tail
@@ -384,7 +394,7 @@ def gdn_block(h, p: Dict, L: str, cfg: TransformerConfig, s0=None,
 def gdn_step(h, p: Dict, L: str, cfg: TransformerConfig, s_pool, tail_pool,
              sidx):
     """One token of every slot through the delta-rule mixer, against the
-    server's pools.  h (B, 1, d); s_pool (rows, Hv, dk, dv) float32 and
+    server's pools.  h (B, 1, d); s_pool (rows, Hv/g, dk, g·dv) float32 and
     tail_pool (rows, K−1, conv), both updated in place when donated; sidx
     (B,) each slot's row.  Returns (out (B, 1, d), s_pool, tail_pool)."""
     with jax.named_scope("strom.ssm.proj"):

@@ -164,6 +164,17 @@ class TransformerConfig:
     # by sigmoid(x . w), one scalar a row (leaf "shared_gate", (d, 1)).
     attn_gate: bool = False
     shared_gate: bool = False
+    # ``post_norm``: a block normalises what a sub-layer GIVES and nothing
+    # it takes (the Olmo 2/3 order: x + N(f(x)), ``norm_in`` / ``norm_out``
+    # below); the leaves keep their names, "attn_norm" the mixer's norm and
+    # "mlp_norm" the MLP's.  ``qk_norm_whole``: the q/k norm (``qk_norm``)
+    # runs over ALL of a projection's features before the split into heads,
+    # its weights n_heads·head_dim and n_kv_heads·head_dim wide.
+    # ``gdn_neg_eigval``: a delta-rule layer's β is 2·sigmoid(b), in (0, 2),
+    # so that I − β k kᵀ may have an eigenvalue in (−1, 0).
+    post_norm: bool = False
+    qk_norm_whole: bool = False
+    gdn_neg_eigval: bool = False
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -374,6 +385,18 @@ class TransformerConfig:
         return (2 * self.gdn_k_heads * self.gdn_k_dim
                 + self.gdn_v_heads * self.gdn_v_dim)
 
+    def require_pre_norm(self, what: str) -> None:
+        """The one message of the layer loops that keep their own copy of
+        the pre-norm block, or split a projection's features over devices."""
+        if self.post_norm or self.qk_norm_whole:
+            raise NotImplementedError(
+                f"{what} normalises what a sub-layer takes and q and k a "
+                f"head at a time; this config has post-norm blocks "
+                f"(post_norm {self.post_norm}) and a q/k norm over the "
+                f"whole projection (qk_norm_whole {self.qk_norm_whole}): "
+                f"serve it from DecodeServer on one device, or run "
+                f"transformer.forward")
+
     def require_no_recurrent(self, what: str) -> None:
         """The one message of everything that holds K/V pages only."""
         if self.recurrent_layers:
@@ -441,10 +464,13 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
             from nvme_strom_tpu.models.mla import init_mla_params
             p.update(init_mla_params(keys, cfg, L, dense))
         else:
-            if cfg.qk_norm:
-                p[L + "q_norm"] = jnp.ones((hd,), jnp.float32)
-                p[L + "k_norm"] = jnp.ones((hd,), jnp.float32)
             nkv = cfg.kv_heads(i)
+            if cfg.qk_norm:
+                whole = cfg.qk_norm_whole
+                p[L + "q_norm"] = jnp.ones((nh * hd if whole else hd,),
+                                           jnp.float32)
+                p[L + "k_norm"] = jnp.ones((nkv * hd if whole else hd,),
+                                           jnp.float32)
             # with an output gate a head's columns are (q | g)
             p[L + "wq"] = dense(next(keys), cfg.d_model,
                                 (cfg.d_model, nh * hd * (1 + cfg.attn_gate)))
@@ -481,6 +507,27 @@ def rms_norm(x, weight, eps):
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     return (xf * jax.lax.rsqrt(var + eps) * weight).astype(x.dtype)
+
+
+# --- the block's order -----------------------------------------------------
+#
+# The ONE statement of where a block's two norms stand, read by every layer
+# loop (forward_hidden here, models/decode.py, models/serving.py):
+#
+#     x = x + norm_out(f(norm_in(x, w)), w)
+#
+# Pre-norm (the default) normalises what a sub-layer takes; ``post_norm``
+# what it gives.  Each is the identity where the other acts, so a loop traces
+# exactly one norm a sub-layer.
+
+def norm_in(x, w, cfg: "TransformerConfig"):
+    """What a sub-layer takes: N(x), or x itself under ``post_norm``."""
+    return x if cfg.post_norm else rms_norm(x, w, cfg.norm_eps)
+
+
+def norm_out(f, w, cfg: "TransformerConfig"):
+    """What a sub-layer adds to the stream: f, or N(f) under ``post_norm``."""
+    return rms_norm(f, w, cfg.norm_eps) if cfg.post_norm else f
 
 
 def _llama3_scale_freqs(freqs, scaling: dict):
@@ -828,10 +875,16 @@ dense_causal_attention_grouped.defvjp(_grouped_attn_fwd,
 
 
 def _qk_norm(q, k, p, prefix, cfg: TransformerConfig):
-    """Per-head RMS norm of q and k (..., heads, head_dim) before rotary,
-    for the families that have one (``cfg.qk_norm``)."""
+    """RMS norm of q and k (..., heads, head_dim) before rotary, for the
+    families that have one (``cfg.qk_norm``): a head at a time, or, with
+    ``qk_norm_whole``, over all the heads' features as one row."""
     if not cfg.qk_norm:
         return q, k
+    if cfg.qk_norm_whole:
+        def whole(t, w):
+            flat = t.reshape(*t.shape[:-2], t.shape[-2] * t.shape[-1])
+            return rms_norm(flat, w, cfg.norm_eps).reshape(t.shape)
+        return whole(q, p[prefix + "q_norm"]), whole(k, p[prefix + "k_norm"])
     return (rms_norm(q, p[prefix + "q_norm"], cfg.norm_eps),
             rms_norm(k, p[prefix + "k_norm"], cfg.norm_eps))
 
@@ -987,7 +1040,7 @@ def forward_hidden(params: Dict, tokens: jax.Array,
 
     def layer_body(p, x, i):
         L = f"layers.{i}."
-        h = rms_norm(x, p[L + "attn_norm"], cfg.norm_eps)
+        h = norm_in(x, p[L + "attn_norm"], cfg)
         if cfg.is_mamba_layer(i):
             from nvme_strom_tpu.models.ssm import mamba_block
             h = mamba_block(h, p, L, cfg)[0]
@@ -999,8 +1052,8 @@ def forward_hidden(params: Dict, tokens: jax.Array,
             h = gdn_block(h, p, L, cfg)[0]
         else:
             h = attention(h, p, L, cfg, attn_fn)
-        x = add_residual(x, h, cfg)
-        h = rms_norm(x, p[L + "mlp_norm"], cfg.norm_eps)
+        x = add_residual(x, norm_out(h, p[L + "attn_norm"], cfg), cfg)
+        h = norm_in(x, p[L + "mlp_norm"], cfg)
         a = jnp.zeros((), jnp.float32)
         kind = cfg.mlp_kind(i)
         if kind == "gshard":
@@ -1009,7 +1062,7 @@ def forward_hidden(params: Dict, tokens: jax.Array,
             h = _moe.expert_mlp(h, p, L, cfg)[0]
         else:
             h = mlp(h, p, L)
-        return add_residual(x, h, cfg), a
+        return add_residual(x, norm_out(h, p[L + "mlp_norm"], cfg), cfg), a
 
     def one_layer(x, i):
         return layer_body(params, x, i)

@@ -4,9 +4,11 @@ A value head keeps a state ``S`` (dk, dv) float32 and reads it THROUGH its
 key before it writes it, a token at a time:
 
     S ← α_t S                               α_t in (0, 1): the head's decay
-    u  = β_t (v_t − Sᵀ k_t)                 β_t in (0, 1): how much of the
-    S ← S + k_t ⊗ u                         value the key's slot takes
-    o_t = Sᵀ q_t
+    u  = β_t (v_t − Sᵀ k_t)                 β_t in (0, 1), or (0, 2) where the
+    S ← S + k_t ⊗ u                         model lets I − β k kᵀ turn a key's
+    o_t = Sᵀ q_t                            slot over: how much of the value
+                                            the slot takes (β is data to both
+                                            kernels; nothing here bounds it)
 
 (q and k come in L2-normalised and scaled, β as a number and the decay as
 its logarithm g = log α ≤ 0 — a head that forgets at once has α = 0 in
@@ -21,7 +23,13 @@ multiplied by k, corrected, multiplied by q and written back once — 2 x 64
 KiB of traffic a head at 128 x 128 and nothing more.  The two products are
 broadcast multiplies and sublane sums on the VPU over the sixteen vector
 registers a head's state fills; everything is float32.  ``sidx`` picks each
-slot's pool row, so a free slot writes the sacrificial row.
+slot's pool row, so a free slot writes the sacrificial row.  Where dv is no
+multiple of the 128 lanes the pool keeps g = ``heads_per_lane_row`` heads
+side by side on them — (rows, H/g, dk, g·dv), two heads of 192 on 384 —
+so that no row of the state is stored or moved padded (a 192-wide row alone
+would take 256 lanes, a third more bytes on the step's largest term); the
+kernel then spreads a head's k, q and α over its own dv lanes and the
+sublane sums serve the g heads at once (``pack_state`` / ``unpack_state``).
 
 ``gdn_scan`` (``strom_gdn_scan``) — a right-padded prompt in chunks of C
 rows.  With g the running sum of log α inside the chunk, Γ[t, s] = exp(g_t −
@@ -66,17 +74,67 @@ _UPDATE_HEADS = 16
 _SCAN_HEADS = 4
 
 
+def heads_per_lane_row(n_heads: int, dv: int) -> int:
+    """``g``: the heads whose states the pool keeps side by side on the
+    lanes — the fewest (a divisor of ``n_heads``, at most 4) that make g·dv a
+    multiple of 128; 1 where dv is one already, or where none does."""
+    for g in (1, 2, 4):
+        if n_heads % g == 0 and (g * dv) % 128 == 0:
+            return g
+    return 1
+
+
+def pool_shape(rows: int, n_heads: int, dk: int, dv: int) -> tuple:
+    """The state of ``rows`` sequences as it is kept: (rows, H/g, dk, g·dv)."""
+    g = heads_per_lane_row(n_heads, dv)
+    return rows, n_heads // g, dk, g * dv
+
+
+def pack_state(s):
+    """(b, H, dk, dv) → the kept form (b, H/g, dk, g·dv); s itself at g = 1."""
+    b, n_heads, dk, dv = s.shape
+    g = heads_per_lane_row(n_heads, dv)
+    if g == 1:
+        return s
+    return (s.reshape(b, n_heads // g, g, dk, dv).transpose(0, 1, 3, 2, 4)
+            .reshape(b, n_heads // g, dk, g * dv))
+
+
+def unpack_state(s, n_heads: int):
+    """The kept form (b, H/g, dk, g·dv) → (b, H, dk, dv)."""
+    b, packs, dk, lanes = s.shape
+    g = n_heads // packs
+    if g == 1:
+        return s
+    return (s.reshape(b, packs, dk, g, lanes // g).transpose(0, 1, 3, 2, 4)
+            .reshape(b, n_heads, dk, lanes // g))
+
+
 # ---------------------------------------------------------------- update
 
 def _update_kernel(sidx_ref, s_ref, cols_ref, rows_ref, o_ref, s_out_ref, *,
-                   hb):
+                   hb, g=1):
     del sidx_ref                           # used by the index maps only
-    cols = cols_ref[0, 0]                  # (dk, 3 hb): k | q | α a column
-    rows = rows_ref[0, 0]                  # (2 hb, dv): β v | β a row
+    cols = cols_ref[0, 0]                  # (dk, 3 n): k | q | α a column
+    rows = rows_ref[0, 0]                  # (2 hb, g dv): β v | β a row
+    n = hb * g                             # heads of this step, g a pack
+    if g > 1:
+        dk, lanes = s_ref.shape[2:]
+        head = jax.lax.broadcasted_iota(jnp.int32, (dk, lanes), 1) \
+            // (lanes // g)
+
+    def spread(c):
+        """Pack h's g columns from ``c`` on, each over its own head's dv
+        lanes: (dk, g dv) — the one column itself at g = 1."""
+        out = cols[:, c:c + 1]
+        for j in range(1, g):
+            out = jnp.where(head >= j, cols[:, c + j:c + j + 1], out)
+        return out
+
     for h in range(hb):
-        k = cols[:, h:h + 1]                               # (dk, 1)
-        q = cols[:, hb + h:hb + h + 1]
-        s = s_ref[0, h] * cols[:, 2 * hb + h:2 * hb + h + 1]   # α S
+        k = spread(h * g)                                  # (dk, 1 | g dv)
+        q = spread(n + h * g)
+        s = s_ref[0, h] * spread(2 * n + h * g)            # α S
         u = rows[h:h + 1] - rows[hb + h:hb + h + 1] * jnp.sum(
             s * k, axis=0, keepdims=True)                  # β (v − Sᵀ k)
         s = s + k * u
@@ -87,51 +145,57 @@ def _update_kernel(sidx_ref, s_ref, cols_ref, rows_ref, o_ref, s_out_ref, *,
 def gdn_update(s_pool, sidx, q, k, v, g, beta, *, interpret=None):
     """One step of the recurrence for every slot, the pool updated in place.
 
-    s_pool (rows, H, dk, dv) float32 — donate it: the result aliases it;
+    s_pool (rows, H/g, dk, g·dv) float32, ``pool_shape``'s form — donate
+    it: the result aliases it;
     sidx (B,) int32, slot b's row of the pool (free slots: the sacrificial
     row); q, k (B, H, dk) — one a VALUE head, normalised and scaled; v (B,
     H, dv); g (B, H) float32, the decay's logarithm; beta (B, H) float32.
     Returns (o (B, H, dv) float32, s_pool)."""
     bsz, n_heads, dk = k.shape
     dv = v.shape[-1]
-    hb = _heads_per_step(n_heads, _UPDATE_HEADS)
-    nh = n_heads // hb
+    packs = s_pool.shape[1]
+    pk = n_heads // packs                  # heads a pack (1: none packed)
+    hb = _heads_per_step(packs, max(1, _UPDATE_HEADS // pk))
+    nh = packs // hb
+    lanes = pk * dv                        # a pack's lanes
     f32 = jnp.float32
     alpha, beta = jnp.exp(g.astype(f32)), beta.astype(f32)
 
-    def cols(t):                           # (B, H, dk) → (B, nh, dk, hb)
-        return t.astype(f32).reshape(bsz, nh, hb, dk).transpose(0, 1, 3, 2)
+    def cols(t):                           # (B, H, dk) → (B, nh, dk, hb pk)
+        return t.astype(f32).reshape(bsz, nh, hb * pk, dk).transpose(
+            0, 1, 3, 2)
 
     col = jnp.concatenate(
         [cols(k), cols(q),
          cols(jnp.broadcast_to(alpha[..., None], (bsz, n_heads, dk)))],
         axis=-1)                                            # (B, nh, dk, 3hb)
     row = jnp.concatenate(
-        [(beta[..., None] * v.astype(f32)).reshape(bsz, nh, hb, dv),
+        [(beta[..., None] * v.astype(f32)).reshape(bsz, nh, hb, lanes),
          jnp.broadcast_to(beta[..., None],
-                          (bsz, n_heads, dv)).reshape(bsz, nh, hb, dv)],
-        axis=2)                                             # (B, nh, 2hb, dv)
+                          v.shape).reshape(bsz, nh, hb, lanes)],
+        axis=2)                                          # (B, nh, 2hb, lanes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bsz, nh),
         in_specs=[
-            pl.BlockSpec((1, hb, dk, dv),
+            pl.BlockSpec((1, hb, dk, lanes),
                          lambda bi, hi, sx: (sx[bi], hi, 0, 0)),
-            pl.BlockSpec((1, 1, dk, 3 * hb),
+            pl.BlockSpec((1, 1, dk, 3 * hb * pk),
                          lambda bi, hi, sx: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, 2 * hb, dv),
+            pl.BlockSpec((1, 1, 2 * hb, lanes),
                          lambda bi, hi, sx: (bi, hi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, hb, dv), lambda bi, hi, sx: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, hb, dk, dv),
+            pl.BlockSpec((1, 1, hb, lanes),
+                         lambda bi, hi, sx: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, hb, dk, lanes),
                          lambda bi, hi, sx: (sx[bi], hi, 0, 0)),
         ],
     )
     o, s_pool = pl.pallas_call(
-        functools.partial(_update_kernel, hb=hb),
+        functools.partial(_update_kernel, hb=hb, g=pk),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((bsz, nh, hb, dv), f32),
+        out_shape=[jax.ShapeDtypeStruct((bsz, nh, hb, lanes), f32),
                    jax.ShapeDtypeStruct(s_pool.shape, f32)],
         # operand 1 (after the scalar prefetch) is the pool; result 1 is too
         input_output_aliases={1: 1},

@@ -380,6 +380,7 @@ def make_pp_forward_with_aux(cfg: TransformerConfig, mesh,
     pipelined manual region.  MoE configs pipeline as super-layers with
     experts sharded over ``ep_axis`` (see split_layer_stack).
     """
+    cfg.require_pre_norm("the pipeline (parallel/pipeline.py)")
     n_pp = _axis_size(mesh, pp_axis)
     tp_size = _axis_size(mesh, tp_axis)
     sp_size = _axis_size(mesh, sp_axis)

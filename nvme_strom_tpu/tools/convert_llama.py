@@ -125,7 +125,33 @@ _LAYER_RULES: Tuple[Tuple[str, str, bool], ...] = (
     ("mlp.shared_expert.up_proj.weight", "shared_w_up", True),
     ("mlp.shared_expert.down_proj.weight", "shared_w_down", True),
     ("mlp.shared_expert_gate.weight", "shared_gate", True),
+    # olmo_hybrid: Olmo 2/3's block (its two norms stand AFTER the
+    # sub-layers: ``_OLMO_NORMS`` renames them for that model type) and FLA's
+    # GatedDeltaNet, which keeps q, k, v, the gate, a and b as six
+    # projections and three depthwise convs: convert() lays them side by
+    # side into "gdn_in" [q | k | v | g], "gdn_ba" [b | a] and "gdn_conv_w"
+    # (q | k | v) (``_GDN_PARTS``).  The names are FLA's and Olmo3's:
+    # ASSUMED, no checkpoint was there to check.
+    ("linear_attn.q_proj.weight", "gdn_in.0", True),
+    ("linear_attn.k_proj.weight", "gdn_in.1", True),
+    ("linear_attn.v_proj.weight", "gdn_in.2", True),
+    ("linear_attn.g_proj.weight", "gdn_in.3", True),
+    ("linear_attn.b_proj.weight", "gdn_ba.0", True),
+    ("linear_attn.a_proj.weight", "gdn_ba.1", True),
+    ("linear_attn.q_conv1d.weight", "gdn_conv_w.0", True),
+    ("linear_attn.k_conv1d.weight", "gdn_conv_w.1", True),
+    ("linear_attn.v_conv1d.weight", "gdn_conv_w.2", True),
+    ("linear_attn.o_norm.weight", "gdn_norm", False),
+    ("linear_attn.o_proj.weight", "gdn_out", True),
+    ("post_feedforward_layernorm.weight", "mlp_norm", False),
 )
+
+#: leaves convert() assembles from parts "leaf.j", side by side along the
+#: output columns, once all of them are in: leaf -> number of parts
+_GDN_PARTS = {"gdn_in": 4, "gdn_ba": 2, "gdn_conv_w": 3}
+#: olmo_hybrid (post-norm): the norm after attention is the MIXER's, where
+#: the Llama family's tensor of that name is the MLP's (HF suffix -> leaf)
+_OLMO_NORMS = {"post_attention_layernorm.weight": "attn_norm"}
 
 #: the norms a qwen3_next checkpoint stores zero-centred (the model computes
 #: x̂ ⊙ (1 + w)); this model multiplies by the stored weight, so convert()
@@ -473,12 +499,53 @@ def _qwen3_next_fields(hf_cfg: dict) -> dict:
         tie_embed=bool(hf_cfg.get("tie_word_embeddings", False)))
 
 
+def _olmo_hybrid_fields(hf_cfg: dict) -> dict:
+    """The TransformerConfig fields of an ``olmo_hybrid`` config (Olmo 2/3's
+    post-norm block around, by ``layer_types`` read as given, FLA's gated
+    delta rule or full attention with q/k norms over the whole projection; a
+    dense MLP), beyond the dense family's.  ``rope_parameters.rope_theta``
+    null is read as NO rotary (the conv and the recurrence carry position:
+    ASSUMED).  Raises on what the model does not implement."""
+    def refuse(what):
+        raise ValueError(f"unsupported olmo_hybrid config: {what}")
+    types = hf_cfg.get("layer_types") or ()
+    n = hf_cfg["num_hidden_layers"]
+    if len(types) != n or set(types) - {"linear_attention",
+                                        "full_attention"}:
+        refuse(f"layer_types {sorted(set(types))} over {len(types)} entries "
+               f"for {n} layers")
+    rope = hf_cfg.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default":
+        refuse(f"rope_type {rope.get('rope_type')!r} (only 'default')")
+    if hf_cfg.get("sliding_window"):
+        refuse(f"sliding_window={hf_cfg['sliding_window']}")
+    hk, hv = hf_cfg["linear_num_key_heads"], hf_cfg["linear_num_value_heads"]
+    if hv % hk:
+        refuse(f"linear_num_value_heads {hv} is no multiple of "
+               f"linear_num_key_heads {hk}")
+    theta = rope.get("rope_theta")
+    out = dict(
+        layer_kinds=tuple("gdn" if t == "linear_attention" else "attention"
+                          for t in types),
+        post_norm=True, qk_norm=True, qk_norm_whole=True,
+        gdn_k_heads=hk, gdn_v_heads=hv,
+        gdn_k_dim=hf_cfg["linear_key_head_dim"],
+        gdn_v_dim=hf_cfg["linear_value_head_dim"],
+        gdn_conv=hf_cfg["linear_conv_kernel_dim"],
+        gdn_neg_eigval=bool(hf_cfg.get("linear_allow_neg_eigval", False)),
+        rope=theta is not None, rope_scaling=None,
+        tie_embed=bool(hf_cfg.get("tie_word_embeddings", False)))
+    if theta is not None:
+        out["rope_theta"] = float(theta)
+    return out
+
+
 def config_from_hf(hf_cfg: dict):
     """HF ``config.json`` → TransformerConfig: the dense Llama family,
     ``model_type`` granitemoehybrid (``_hybrid_fields``), ``lfm2`` /
     ``lfm2_moe`` (``_lfm2_fields``), ``deepseek_v3`` / ``kimi_k2``
-    (``_mla_fields``), ``mimo_v2`` (``_mimo_fields``) and ``qwen3_next``
-    (``_qwen3_next_fields``).
+    (``_mla_fields``), ``mimo_v2`` (``_mimo_fields``), ``qwen3_next``
+    (``_qwen3_next_fields``) and ``olmo_hybrid`` (``_olmo_hybrid_fields``).
 
     Raises on architecture knobs the model does not implement — silently
     ignoring them (e.g. a non-SiLU activation) would convert into a model
@@ -499,6 +566,8 @@ def config_from_hf(hf_cfg: dict):
               if model_type in ("deepseek_v3", "kimi_k2")
               else _mimo_fields(hf_cfg) if model_type == "mimo_v2"
               else _qwen3_next_fields(hf_cfg) if model_type == "qwen3_next"
+              else _olmo_hybrid_fields(hf_cfg)
+              if model_type == "olmo_hybrid"
               else {})
     derived_hd = hf_cfg["hidden_size"] // hf_cfg["num_attention_heads"]
     if hf_cfg["hidden_size"] % hf_cfg["num_attention_heads"]:
@@ -592,7 +661,7 @@ def strom_config_dict(cfg) -> dict:
             "embed_mult", "residual_mult", "logits_div", "attn_scale", "rope",
             "tie_embed", "conv_taps", "qk_norm", "gdn_k_heads",
             "gdn_v_heads", "gdn_k_dim", "gdn_v_dim", "gdn_conv",
-            "gdn_chunk")},
+            "gdn_chunk", "gdn_neg_eigval", "post_norm", "qk_norm_whole")},
             layer_kinds=list(cfg.layer_kinds))
     if cfg.mlp_kinds:       # the per-layer MLPs and the exact layer's router
         out.update({k: getattr(cfg, k) for k in (
@@ -653,6 +722,7 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
         hf_cfg = json.load(f)
     cfg = config_from_hf(hf_cfg)
     zero_centred = hf_cfg.get("model_type") == "qwen3_next"
+    olmo = hf_cfg.get("model_type") == "olmo_hybrid"
 
     pending: Dict[str, np.ndarray] = {}
     pending_bytes = 0
@@ -678,6 +748,7 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
 
     skipped = []
     experts: Dict[str, Dict[int, np.ndarray]] = {}
+    parts: Dict[str, Dict[int, np.ndarray]] = {}
     for hf_name, arr in _iter_hf_tensors(hf_dir):
         mapped = map_name(hf_name)
         if mapped is None:
@@ -690,6 +761,9 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
             skipped.append(hf_name)
             continue
         ours, transpose = mapped
+        renamed = olmo and _OLMO_NORMS.get(hf_name.split(".", 3)[-1])
+        if renamed:
+            ours = ours.rsplit(".", 1)[0] + "." + renamed
         if arr.ndim == 3:       # depthwise conv (channels, 1, taps)
             arr = arr.reshape(arr.shape[0], arr.shape[2])
         # bf16 fields load as uint16 views via numpy; keep raw dtype
@@ -698,6 +772,16 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
             embed = arr
         if cfg.latent and ours.endswith((".wq_b", ".wkv_a")):
             out = _deinterleave_rope(out, cfg, ours.endswith(".wq_b"))
+        e = re.fullmatch(r"(.*\.(gdn_in|gdn_ba|gdn_conv_w))\.(\d)", ours)
+        if e:                               # one part of a leaf laid side
+            got = parts.setdefault(e.group(1), {})          # by side
+            got[int(e.group(3))] = out
+            if len(got) == _GDN_PARTS[e.group(2)]:
+                seen.add(e.group(1))
+                emit(e.group(1), np.ascontiguousarray(np.concatenate(
+                    [got[j] for j in range(len(got))], axis=1)))
+                del parts[e.group(1)]
+            continue
         if ours.endswith((".gdn_in", ".gdn_ba")):
             rep = cfg.gdn_v_heads // cfg.gdn_k_heads
             out = _deinterleave_gdn(
@@ -738,6 +822,9 @@ def convert(hf_dir: str, out_dir: str, shard_bytes: int = 1 << 30,
     if experts:
         raise ValueError(f"expert matrices missing: {sorted(experts)} hold "
                          f"fewer than {cfg.experts_local} experts")
+    if parts:
+        raise ValueError(f"delta-rule projections missing: "
+                         f"{sorted(parts)} lack parts")
     if cfg.tie_embed:
         seen.add("lm_head")     # the head IS tok_embed: nothing to write
     if "lm_head" not in seen:
