@@ -38,13 +38,29 @@ A = tril(diag(β) (K Kᵀ ⊙ Γ), −1), the chunk's corrections solve
 
     (I + A) [W | U'] = [β e^g ⊙ K | β ⊙ V]        U = U' − W S₀
 
-by FORWARD SUBSTITUTION in float32 on the VPU — C rank-one updates of the
-right-hand side, row r final before it is used — and not by the nilpotent
-product Π (I + (−A)^(2^i)): that form squares A five times, and with keys
-that repeat (|k_s · k_r| near 1, which trained keys do and random ones do
-not) its powers grow before they cancel, in float32.  The substitution is
-the recurrence's own arithmetic order.  Then, on the MXU with float32
-accumulation (operands in the activations' type):
+by FORWARD SUBSTITUTION in float32, in blocks of R = 16 rows
+(``solve_unit_lower``).  Inside a block, on the VPU: R − 1 rank-one updates
+of the block's own (R, dk + dv) rows — four vector registers at 128 + 128,
+six at 96 + 192 — row r final before it is used.  Between blocks, on the
+MXU: every later row takes a finished block's correction at once,
+X[later] −= A[later, block] X[block], three small products a chunk of 64 —
+of float32 operands at float32's full precision (``Precision.HIGHEST``),
+never in the activations' type: these rows are what the rest of the
+substitution is built on, and one bfloat16 pass there leaves 1e-2 where the
+substitution leaves 1e-6.  (Row by row over the whole chunk the same solve
+was C − 1 updates of all C rows, sixteen registers and eight lane broadcasts
+of a column of A each.)  What is left is a chain — a step is a row's
+broadcast, a multiply and a subtract that wait for one another, a coupling a
+product's way through the MXU and back — so the heads of a grid step are
+solved as ONE stacked array, each step one expression over all of them: step
+j of every head comes before step j + 1 of any, and one head's wait is
+another's work.  And still not the nilpotent product
+Π (I + (−A)^(2^i)): that form squares A five times, and with keys that
+repeat (|k_s · k_r| near 1, which trained keys do and random ones do not)
+its powers grow before they cancel, in float32.  Here a row is only ever
+corrected by FINISHED rows and A is never multiplied by itself: blocked or
+not, it is the recurrence's own arithmetic order.  Then, on the MXU with
+float32 accumulation (operands in the activations' type):
 
     O  = e^g ⊙ (Q S₀) + tril(Q Kᵀ ⊙ Γ) U
     S₁ = e^{g_C} S₀ + (K ⊙ e^{g_C − g})ᵀ U
@@ -69,9 +85,12 @@ from nvme_strom_tpu.ops.ssm import _heads_per_step, _interpret
 
 #: value heads per grid step.  Update: a (16, 128, 128) float32 block of the
 #: pool is 1 MiB, in and out and double-buffered 4 MiB of VMEM.  Scan: the
-#: heads of a step share its launch; each is a loop iteration.
+#: heads of a step share its launch and are solved as one stacked array.
 _UPDATE_HEADS = 16
 _SCAN_HEADS = 4
+#: rows of a block of the scan's solve: two sublane tiles of float32, so a
+#: rank-one update inside a block touches (dk + dv) / 64 vector registers
+SCAN_SOLVE_ROWS = 16
 
 
 def heads_per_lane_row(n_heads: int, dv: int) -> int:
@@ -209,6 +228,38 @@ def gdn_update(s_pool, sidx, q, k, v, g, beta, *, interpret=None):
 
 # ------------------------------------------------------------------ scan
 
+def solve_unit_lower(a, x, block):
+    """(I + A) X = B for a stack of systems — a (h, c, c), each strictly
+    lower; x (h, c, n), the right-hand sides; float32 — in blocks of
+    ``block`` rows (the last may be shorter).  Inside a block the forward
+    substitution: row j, final, leaves the rows under it (a column of A is 0
+    down to its diagonal, so the rows above lose nothing).  Then every later
+    row takes the block's correction at once, as one product of float32
+    operands at float32's own precision.  A row is only ever corrected by
+    finished rows, and no power of A is formed.
+
+    The h systems are one array and each step is one expression over it:
+    a step waits for the one before it (a row's broadcast, a multiply, a
+    subtract; a product's way through the MXU), the units keep their
+    operations in the order they were written, and written so step j of
+    every system comes before step j + 1 of any — one system's wait is
+    another's work."""
+    c = a.shape[1]
+    done, rest = [], x
+    for lo in range(0, c, block):
+        hi = min(lo + block, c)
+        xb = rest[:, :hi - lo]
+        for j in range(hi - lo - 1):
+            xb = xb - a[:, lo:hi, lo + j:lo + j + 1] * xb[:, j:j + 1, :]
+        done.append(xb)
+        if hi < c:
+            rest = rest[:, hi - lo:] - jnp.einsum(
+                "hrb,hbn->hrn", a[:, hi:, lo:hi], xb,
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
+    return jnp.concatenate(done, axis=1)
+
+
 def _scan_kernel(q_ref, k_ref, kt_ref, v_ref, g_col_ref, g_row_ref, b_ref,
                  s0_ref, o_ref, s_ref, *, hb, c):
     ci = pl.program_id(2)
@@ -222,9 +273,8 @@ def _scan_kernel(q_ref, k_ref, kt_ref, v_ref, g_col_ref, g_row_ref, b_ref,
     rows = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
 
-    def head(h, carry):
-        q, k, kt, v = q_ref[0, h, 0], k_ref[0, h, 0], kt_ref[0, h, 0], \
-            v_ref[0, h, 0]                 # (c, dk) (c, dk) (dk, c) (c, dv)
+    def system(h):
+        k, kt, v = k_ref[0, h, 0], kt_ref[0, h, 0], v_ref[0, h, 0]
         g_col = g_col_ref[0, h, 0]         # (c, 1) running Σ log α, float32
         g_row = g_row_ref[0, h, 0]         # (1, c)
         beta = b_ref[0, h, 0]              # (c, 1)
@@ -235,10 +285,12 @@ def _scan_kernel(q_ref, k_ref, kt_ref, v_ref, g_col_ref, g_row_ref, b_ref,
             k, kt, preferred_element_type=f32), 0.0)       # strictly lower
         x = jnp.concatenate([beta * eg * k.astype(f32),
                              beta * v.astype(f32)], axis=1)  # (c, dk + dv)
-        # forward substitution: row r is final when its turn comes
-        for r in range(c - 1):
-            x = x - a[:, r:r + 1] * x[r:r + 1, :]
-        dk = k.shape[1]
+        return gam, eg, a, x
+
+    def finish(h, gam, eg, x):
+        q, kt = q_ref[0, h, 0], kt_ref[0, h, 0]    # (c, dk) (dk, c)
+        g_row = g_row_ref[0, h, 0]
+        dk = q.shape[1]
         w, u = x[:, :dk], x[:, dk:]
         s0 = s_ref[0, h]                   # (dk, dv): the carried state
         s0m = s0.astype(mm)
@@ -253,9 +305,12 @@ def _scan_kernel(q_ref, k_ref, kt_ref, v_ref, g_col_ref, g_row_ref, b_ref,
         s_ref[0, h] = jnp.exp(last) * s0 + jnp.dot(
             (kt.astype(f32) * jnp.exp(last - g_row)).astype(mm), um,
             preferred_element_type=f32)
-        return carry
 
-    jax.lax.fori_loop(0, hb, head, 0)
+    # the step's heads stacked, not one after the other: see the solve
+    gam, eg, a, x = zip(*[system(h) for h in range(hb)])
+    x = solve_unit_lower(jnp.stack(a), jnp.stack(x), SCAN_SOLVE_ROWS)
+    for h in range(hb):
+        finish(h, gam[h], eg[h], x[h])
 
 
 def gdn_scan(q, k, v, g, beta, s0, valid=None, *, chunk: int = 64,

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Kernel probes: a kernel of the serving cells alone on the chip, one
 JSON line per measurement, each naming ``platform``, ``device_kind`` and
-``device_count``.  Two modes; called with neither it names them and exits
-non-zero.
+``device_count``.  Three modes; called with none of them it names them and
+exits non-zero.
 
 ``python -m nvme_strom_tpu.tools.kernel_probe paged`` times the paged
 decode kernels (``ops/paged_attention.py``) alone, at the serving
@@ -19,6 +19,19 @@ the first is read against — the same walk over a float32 pool of 2 MiB
 rows, with more arithmetic an element.  What it read when the Mamba-2 pool
 went state-major (the old form, the new one, the yardstick): PERF.md §6,
 PR 44.
+
+``python -m nvme_strom_tpu.tools.kernel_probe gdn_scan`` times
+``strom_gdn_scan`` (``ops/gdn.py``) alone at the shapes of the two cells
+whose admissions run it (``GDN_SCAN_CASES``: ``q3n.flood4k``'s 32 heads of
+128 x 128 over four prompts of 512 rows and one of 4,096, ``olmoh.flood-cot``'s
+30 heads of 96 x 192 over one of 1,024 with β drawn in (0, 2)), the first
+256 rows checked against the recurrence a token at a time in float32 before
+it is timed, with the operands as served and in float32, and the chunk's
+solve alone against a float64 solve (``solve_rel_err``): the CPU's
+interpret mode multiplies exactly and cannot see how many passes the chip
+gives a float32 product, and on the chip the whole scan's error cannot
+either.  What it read when the solve went from rows to blocks of rows:
+PERF.md §6, PR 48.
 
 One process, on the chip: without a TPU the probe exits non-zero unless
 the caller set ``JAX_PLATFORMS=cpu`` (mechanics only, tiny shapes; every
@@ -274,14 +287,149 @@ def probe_ssm(repeats: int = 5) -> None:
                  2 if on_cpu else GDN_CALLS, repeats)
 
 
-MODES = ("paged", "ssm")
+#: name -> (value heads, dk, dv, β's range, (prompts, rows) ...): the scan
+#: at the shapes of ``q3n.flood4k``'s and ``olmoh.flood-cot``'s admissions
+GDN_SCAN_CASES = {
+    "q3n": (32, 128, 128, 1.0, ((4, 512), (1, 4096))),
+    "olmoh": (30, 96, 192, 2.0, ((1, 1024),)),
+}
+
+
+def probe_gdn_scan(case: str, repeats: int = 5) -> None:
+    """One line a prompt shape of ``GDN_SCAN_CASES[case]``: milliseconds a
+    call of ``gdn_scan`` in bfloat16 — the host's clock around ``calls``
+    calls of one jitted program ending in ``block_until_ready``; a call is
+    milliseconds — with the (head, chunk) solves it makes and the bytes it
+    moves as the kernel takes them (q, k a VALUE head, v in and o out in
+    bfloat16, g and β a row in float32, the state in and out a prompt), and
+    the error of its first rows against the recurrence."""
+    import statistics
+
+    import jax
+    import jax.numpy as jnp
+
+    from nvme_strom_tpu.ops.gdn import gdn_scan
+    H, dk, dv, beta_max, shapes = GDN_SCAN_CASES[case]
+    on_cpu = jax.default_backend() != "tpu"
+    if on_cpu:                  # mechanics only: an eighth of everything
+        H, dk, dv = H // 8, dk // 8, dv // 8
+        shapes = tuple((b, rows // 8 + 8) for b, rows in shapes)
+    f32, bf = jnp.float32, jnp.bfloat16
+    check = 32 if on_cpu else 256          # the first rows, checked
+    calls = 2 if on_cpu else 20
+
+    def draw(seed, bsz, m):                # float32
+        ks = jax.random.split(jax.random.key(seed), 5)
+        q, k = (jax.random.normal(ki, (bsz, m, H, dk), f32) for ki in ks[:2])
+        q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / dk ** 0.5
+        k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+        v = jax.random.normal(ks[2], (bsz, m, H, dv), f32)
+        # decays from a few tokens to thousands, as the cells' weights give
+        g = -jnp.exp(2.0 * jax.random.normal(ks[3], (bsz, m, H), f32) - 4.0)
+        beta = beta_max * jax.nn.sigmoid(
+            jax.random.normal(ks[4], (bsz, m, H), f32))
+        return q, k, v, g, beta
+
+    @jax.jit
+    def recurrence(q, k, v, g, beta, s0):
+        def step(s, x):
+            q, k, v, g, b = x
+            s = jnp.exp(g)[..., None, None] * s
+            u = b[..., None] * (v - jnp.einsum("bhkv,bhk->bhv", s, k))
+            s = s + k[..., :, None] * u[..., None, :]
+            return s, jnp.einsum("bhkv,bhk->bhv", s, q)
+        xs = tuple(jnp.moveaxis(t[:, :check].astype(f32), 1, 0)
+                   for t in (q, k, v, g, beta))
+        return jnp.moveaxis(jax.lax.scan(step, s0, xs)[1], 0, 1)
+
+    solve_err = _solve_error(dk, dv, beta_max, interpret=on_cpu)
+    scan = jax.jit(gdn_scan)
+    for bsz, m in shapes:
+        s0 = jnp.zeros((bsz, H, dk, dv), f32)
+        exact = draw(10 + bsz, bsz, m)
+        args = tuple(t.astype(bf) for t in exact[:3]) + exact[3:]  # served
+        errs = {}
+        for tag, operands in (("rel_err_rows", args),
+                              ("rel_err_rows_f32", exact)):
+            with jax.default_matmul_precision("highest"):
+                want = recurrence(*operands, s0)
+            got = scan(*operands, s0)[0][:, :check].astype(f32)
+            errs[tag] = float(jnp.abs(got - want).max()
+                              / jnp.abs(want).max())
+        ts = []
+        for _ in range(repeats):
+            t0 = time.monotonic()
+            for _ in range(calls):
+                o, _ = scan(*args, s0)
+            o.block_until_ready()
+            ts.append((time.monotonic() - t0) / calls)
+        t = statistics.median(ts)
+        solves = bsz * H * -(-m // 64)     # gdn_scan's chunk is 64 rows
+        nbytes = (bsz * m * H * ((2 * dk + 2 * dv) * 2 + 2 * 4)
+                  + 2 * bsz * H * dk * dv * 4)
+        rec = {"probe": "gdn_scan", "kernel": "strom_gdn_scan", "case": case,
+               "heads": H, "widths": [dk, dv], "beta_max": beta_max,
+               "prompts": bsz, "rows": m, "rows_checked": check,
+               "head_chunks": solves, "mib_a_call": round(nbytes / 2 ** 20, 2),
+               "ms_a_call": round(t * 1e3, 4),
+               "ms_a_call_min": round(min(ts) * 1e3, 4),
+               "us_a_row": round(t * 1e6 / (bsz * m), 4),
+               "us_a_head_chunk": round(t * 1e6 / solves, 4), **errs,
+               "solve_rel_err": solve_err,
+               "timing": f"host clock around {calls} calls, "
+                         f"median of {repeats}"}
+        peak = HBM_GB_S.get(_DEVICE.get("device_kind"))
+        if peak:
+            rec["bytes_roofline_pct"] = round(
+                100 * nbytes / t / 1e9 / peak, 2)
+        _emit(rec)
+
+
+def _solve_error(dk: int, dv: int, beta_max: float, interpret: bool) -> float:
+    """The chunk's solve ALONE, in a kernel of its own: ``(I + A) X = B`` for
+    two drawn chunks of 64 rows side by side, as a grid step holds its heads
+    — ``A = tril(β (K Kᵀ ⊙ Γ), −1)`` from unit keys ``dk`` wide, ``B`` ``dk +
+    dv`` wide — against numpy's float64 solve, largest error over largest
+    entry.  The whole scan's error cannot show it on the chip: the products
+    around the solve take one bfloat16 pass there whatever their operands
+    (PERF.md §6, PR 48: 4e-3 with the coupling at either precision); this
+    reads 1e-6 only while the coupling's product keeps float32's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import pallas as pl
+
+    from nvme_strom_tpu.ops import gdn
+    h, c = 2, 64
+    rng = np.random.default_rng(5)
+    k = rng.normal(size=(h, c, dk))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    g = np.cumsum(-np.exp(2.0 * rng.normal(size=(h, c)) - 4.0), axis=1)
+    a = np.tril(rng.uniform(0, beta_max, (h, c, 1)) * (k @ k.swapaxes(1, 2))
+                * np.exp(g[:, :, None] - g[:, None]), -1).astype(np.float32)
+    b = rng.normal(size=(h, c, dk + dv)).astype(np.float32)
+
+    def kernel(a_ref, b_ref, x_ref):
+        x_ref[...] = gdn.solve_unit_lower(a_ref[...], b_ref[...],
+                                          gdn.SCAN_SOLVE_ROWS)
+
+    got = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(b.shape, jnp.float32),
+        interpret=interpret)(jnp.asarray(a), jnp.asarray(b))
+    want = np.linalg.solve(np.eye(c) + a.astype(np.float64),
+                           b.astype(np.float64))
+    return float(np.abs(np.asarray(got) - want).max() / np.abs(want).max())
+
+
+MODES = ("paged", "ssm", "gdn_scan")
 
 
 def main() -> int:
     mode = sys.argv[1] if len(sys.argv) > 1 else None
     if mode not in MODES:
         print("usage: python -m nvme_strom_tpu.tools.kernel_probe "
-              "paged [case ...] | ssm", file=sys.stderr)
+              "paged [case ...] | ssm | gdn_scan [case ...]",
+              file=sys.stderr)
         return 2
     sys.path.insert(0, REPO)   # direct-script mode: repo root first
     from nvme_strom_tpu.utils.compile_cache import enable_compile_cache
@@ -293,8 +441,11 @@ def main() -> int:
     if mode == "paged":
         for case in sys.argv[2:] or PAGED_CASES:
             probe_paged(case)
-    else:
+    elif mode == "ssm":
         probe_ssm()
+    else:
+        for case in sys.argv[2:] or GDN_SCAN_CASES:
+            probe_gdn_scan(case)
     return 0
 
 

@@ -29,6 +29,7 @@ from delta_rule_helpers import (                                # noqa: E402,F40
 from benchmark.reference import qwen3_next as ref               # noqa: E402
 from nvme_strom_tpu.models import decode, serving, ssm          # noqa: E402
 from nvme_strom_tpu.models.serving import DecodeServer          # noqa: E402
+from nvme_strom_tpu.ops import gdn                              # noqa: E402
 from nvme_strom_tpu.ops.gdn import gdn_scan, gdn_update         # noqa: E402
 from nvme_strom_tpu.tools.convert_llama import config_from_hf   # noqa: E402
 
@@ -115,11 +116,16 @@ def _draw(b, m, H=4, dk=16, dv=32, seed=0):
     (7, (7, 3), 64),            # shorter than a chunk: one of 8 rows
     (96, (96, 0), 32),          # a row that holds no prompt
     (48, (48, 20), 16),
+    (20, (20, 11), 64),         # one chunk of 24 rows: a block and a half
+    (40, (40, 33), 64),         # of 40: two blocks and a half
+    (56, (56, 17), 64),         # of 56: three and a half
+    (144, (144, 130), 64),      # three chunks, the last of one block
 ])
 def test_gdn_scan_matches_the_recurrence(m, n_valid, chunk):
-    """The chunked form — the triangular solve by forward substitution, the
+    """The chunked form — the triangular solve in blocks of rows, the
     products, the state carried between chunks — equals the recurrence row
-    by row at lengths that are and are not multiples of the chunk; rows past
+    by row at lengths that are and are not multiples of the chunk, and at
+    chunks the solve's blocks do and do not divide; rows past
     ``n_valid`` leave the state where the last valid row left it, and a
     sequence with none keeps the state it came in with."""
     q, k, v, alpha, beta, s0 = _draw(2, m)
@@ -147,17 +153,91 @@ def test_gdn_scan_carries_its_state_across_two_calls():
     np.testing.assert_allclose(s2, s, atol=2e-5)
 
 
-def test_gdn_scan_with_keys_that_repeat():
+@pytest.mark.parametrize("chunk", [16, 32, 64])
+def test_gdn_scan_with_keys_that_repeat(chunk):
     """Every row of a chunk with the SAME key and β = 1: the matrix the
     chunk solves is all ones under its diagonal, whose powers grow like
-    binomials before they cancel — the forward substitution does not care."""
+    binomials before they cancel — the substitution does not care, in one
+    block (chunk 16) or with one block's correction handed to the next
+    three (chunk 64)."""
     q, k, v, alpha, beta, s0 = _draw(1, 64, seed=5)
     k = jnp.broadcast_to(k[:, :1], k.shape)
     beta, alpha = jnp.ones_like(beta), jnp.full_like(alpha, -0.001)
-    o, s = gdn_scan(q, k, v, alpha, beta, s0)
+    o, s = gdn_scan(q, k, v, alpha, beta, s0, chunk=chunk)
     want_o, want_s = _recurrence(q, k, v, alpha, beta, s0)
     np.testing.assert_allclose(o, want_o, atol=5e-5)
     np.testing.assert_allclose(s, want_s, atol=5e-5)
+
+
+@pytest.mark.parametrize("c", [8, 24, 64])
+@pytest.mark.parametrize("block", [8, 16])
+def test_the_blocked_solve_is_the_substitution_row_by_row(block, c):
+    """(I + A) X = B for a drawn A = tril(β (K Kᵀ ⊙ Γ), −1) — unit keys, β
+    in (0, 2), decays — and B: a block's rows by substitution and every
+    later row by one product equal the substitution a row at a time — at
+    blocks that divide the chunk, that do not (24 by 16) and that hold it
+    whole (8 by 16)."""
+    rng = np.random.default_rng([c, block])
+    k = rng.normal(size=(c, 16))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    g = np.cumsum(-np.exp(2.0 * rng.normal(size=c) - 3.0))
+    a = np.tril(rng.uniform(0, 2, (c, 1)) * (k @ k.T)
+                * np.exp(g[:, None] - g[None]), -1).astype(np.float32)
+    b = rng.normal(size=(c, 40)).astype(np.float32)
+    want = b.copy()
+    for r in range(c - 1):
+        want = want - a[:, r:r + 1] * want[r:r + 1, :]
+    got, = gdn.solve_unit_lower(jnp.asarray(a)[None], jnp.asarray(b)[None],
+                                block)
+    np.testing.assert_allclose(got, want, atol=1e-6 * np.abs(want).max())
+    # and it IS a solve, against float64
+    np.testing.assert_allclose(
+        (np.eye(c) + a.astype(np.float64)) @ np.asarray(got, np.float64), b,
+        atol=1e-5 * np.abs(want).max())
+
+
+def test_the_solves_couplings_ask_for_float32s_own_precision():
+    """Every product the solve makes has float32 operands and asks for
+    ``Precision.HIGHEST``: interpret mode multiplies exactly whatever a
+    product asks for, so only its request shows here that the chip will not
+    run a coupling in one bfloat16 pass (on the chip: ``kernel_probe
+    gdn_scan``'s ``solve_rel_err``)."""
+    jaxpr = jax.make_jaxpr(lambda a, x: gdn.solve_unit_lower(a, x, 16))(
+        jnp.zeros((3, 64, 64)), jnp.zeros((3, 64, 40)))
+    dots = [e for e in jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 3                  # one a block but the last
+    for e in dots:
+        assert {v.aval.dtype for v in e.invars} == {jnp.dtype("float32")}
+        assert set(e.params["precision"]) == {jax.lax.Precision.HIGHEST}
+        assert e.params["preferred_element_type"] == jnp.float32
+
+
+@pytest.mark.parametrize("case", ["q3n", "olmoh"])
+def test_the_kernel_probe_counts_what_a_scan_solves(case, capsys):
+    """``kernel_probe gdn_scan`` at its CPU size (mechanics only: no time it
+    prints here is a device's): one line a prompt shape, the (head, chunk)
+    solves of the call and the bytes of its operands, and the first rows
+    within rounding of the recurrence — bfloat16's as served, float32's
+    with float32 operands, where interpret mode's products are exact — and
+    the chunk's solve alone within float32's rounding of a float64 solve."""
+    import json
+
+    from nvme_strom_tpu.tools import kernel_probe
+    kernel_probe.probe_gdn_scan(case, repeats=1)
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    H, dk, dv, beta_max, shapes = kernel_probe.GDN_SCAN_CASES[case]
+    assert [ln["prompts"] for ln in lines] == [b for b, _ in shapes]
+    for ln in lines:
+        assert ln["kernel"] == "strom_gdn_scan" and ln["beta_max"] == beta_max
+        h, (k, v), b, m = ln["heads"], ln["widths"], ln["prompts"], ln["rows"]
+        assert (H % h, dk / k, dv / v) == (0, 8, 8)
+        assert ln["head_chunks"] == b * h * -(-m // 64)
+        assert ln["mib_a_call"] == round(
+            (b * m * h * (4 * (k + v) + 8) + 8 * b * h * k * v) / 2 ** 20, 2)
+        assert ln["rows_checked"] <= m
+        assert ln["rel_err_rows"] < 2e-2 and ln["rel_err_rows_f32"] < 2e-6
+        assert ln["solve_rel_err"] < 2e-6
+        assert ln["ms_a_call"] > 0 and "bytes_roofline_pct" not in ln
 
 
 def test_gdn_update_is_one_step_in_place():

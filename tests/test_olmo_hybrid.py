@@ -165,13 +165,15 @@ def test_gdn_scan_at_the_cells_heads_matches_the_recurrence(m, n_valid,
     np.testing.assert_allclose(s, want_s, atol=3e-5)
 
 
-def test_gdn_scan_with_keys_that_repeat_under_beta_two():
+@pytest.mark.parametrize("m", [64, 24])
+def test_gdn_scan_with_keys_that_repeat_under_beta_two(m):
     """Every row of a chunk with the SAME key and β = 2: each write turns the
     key's slot over (I − 2 k kᵀ has the eigenvalue −1 there), the matrix the
     chunk solves is 2 everywhere under its diagonal, whose powers grow like
-    2^r binomials before they cancel — the forward substitution does not
-    care; a head with α = 0 beside it."""
-    q, k, v, alpha, beta, s0 = _draw(1, 64, H=4, seed=5)
+    2^r binomials before they cancel — the substitution does not care, in
+    four blocks of 16 rows (m = 64) or in one and a half (m = 24); a head
+    with α = 0 beside it."""
+    q, k, v, alpha, beta, s0 = _draw(1, m, H=4, seed=5)
     k = jnp.broadcast_to(k[:, :1], k.shape)
     beta = jnp.full_like(beta, 2.0)
     alpha = jnp.full_like(alpha, -0.001).at[:, :, 2].set(-300.0)

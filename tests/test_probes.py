@@ -1,8 +1,8 @@
 """The measuring commands that stay — ``kernel_probe paged``, ``kernel_probe
-ssm`` and ``transfer_diag`` — measure on the chip or not at all: without a
-TPU they exit non-zero and print no result, unless the caller asked for the
-CPU by name (``utils/device.require_tpu``), and then every line they print
-says which platform it was.
+ssm``, ``kernel_probe gdn_scan`` and ``transfer_diag`` — measure on the chip
+or not at all: without a TPU they exit non-zero and print no result, unless
+the caller asked for the CPU by name (``utils/device.require_tpu``), and then
+every line they print says which platform it was.
 """
 
 import json
@@ -17,6 +17,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PROBES = {
     "paged": ["nvme_strom_tpu.tools.kernel_probe", "paged", "m7b.chat"],
     "ssm": ["nvme_strom_tpu.tools.kernel_probe", "ssm"],
+    "gdn_scan": ["nvme_strom_tpu.tools.kernel_probe", "gdn_scan", "olmoh"],
     "transfer_diag": ["nvme_strom_tpu.tools.transfer_diag",
                       "--bytes", "65536", "--repeats", "2",
                       "--sizes", "65536", "--threads", "1,2",
@@ -58,10 +59,10 @@ def test_probe_on_the_cpu_by_name_says_so_on_every_line(probe, monkeypatch,
 
 @pytest.mark.parametrize("argv", [[], ["attn"], ["roof"]],
                          ids=["no_mode", "attn", "roof"])
-def test_kernel_probe_names_its_two_modes(argv, monkeypatch, capsys):
+def test_kernel_probe_names_its_modes(argv, monkeypatch, capsys):
     """Called with no mode, or with one it does not have, it says what it
     has and exits non-zero before it touches a device."""
     rc = _main(["nvme_strom_tpu.tools.kernel_probe", *argv], monkeypatch)
     out, err = capsys.readouterr()
     assert rc == 2 and out == ""
-    assert "paged" in err and "ssm" in err
+    assert "paged" in err and "ssm" in err and "gdn_scan" in err
