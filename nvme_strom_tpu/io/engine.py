@@ -788,6 +788,13 @@ class StromEngine:
             # strom_stat/watchdog warnings for private-stats engines
             # (first engine wins on a shared tracer)
             self.tracer.stats = self.stats
+        #: host buffers a weight restore assembled column shards in
+        #: (ops/bridge.HostAssembly), kept between loads: every one is
+        #: ``ASSEMBLY_BYTES`` long and on its way to no device.  A fresh
+        #: buffer's pages cost more to touch than to copy into (PERF.md
+        #: §5), so a load leaves them for the next; ``close_all`` drops
+        #: them.
+        self.spare_host_buffers: list = []
         self._lib = _load_lib()
         c = self.config
         n_buffers = max(
@@ -1442,6 +1449,7 @@ class StromEngine:
         self.sync_stats()  # drains counters and exports the final snapshot
         self._lib.strom_engine_destroy(self._h)
         self._closed = True
+        self.spare_host_buffers.clear()
         if self._pool_slab is not None:
             # the staging carve returns to the arena only AFTER destroy
             # drained every in-flight DMA targeting it

@@ -15,10 +15,14 @@ The staging buffer is released back to the pool only after
 ``PutStage`` is the caller-side stage of a weight restore: the reading
 thread hands each chunk over and a worker a device gathers and puts it,
 so reading and transferring overlap without changing what a put is.
+A column shard, which the host has to gather anyway, is gathered into a
+``HostAssembly`` — one reused host buffer a (tensor, device) — and
+crosses to its device in one put.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import os
 import queue
@@ -378,15 +382,20 @@ class PutStage:
     * ``depth`` 0 starts no thread: :meth:`put` and :meth:`then` run
       their jobs on the calling thread — the synchronous path of a pool
       too small for a queue, and of a single tensor's load;
+    * a chunk nothing was put out of — every device's job gathered its
+      share into a :class:`HostAssembly` (:meth:`assemble`) — is
+      released when its last job ends: it waits behind no transfer;
     * an exception in a job stops the stage: the jobs still queued are
       dropped (their chunks released once what was put out of them is
-      ready) and the next :meth:`put`, :meth:`then` or :meth:`close`
-      raises it on the calling thread.  :meth:`close` always leaves
-      every buffer released and no worker alive.
+      ready), a worker waiting for a host buffer wakes, and the next
+      :meth:`put`, :meth:`then` or :meth:`close` raises it on the
+      calling thread.  :meth:`close` always leaves every buffer, staging
+      and host, released and no worker alive.
 
-    ``engine.stats`` counts the arrays put by a worker
-    (``restore_puts_staged``) and on the calling thread
-    (``restore_puts_inline``).
+    ``engine.stats`` counts the arrays put out of staging views by a
+    worker (``restore_puts_staged``) and on the calling thread
+    (``restore_puts_inline``), and the puts of assembled host buffers,
+    whichever thread made them (``restore_puts_assembled``).
     """
 
     def __init__(self, engine: StromEngine, depth: int, retire_depth: int):
@@ -398,6 +407,10 @@ class PutStage:
         self._room = threading.Semaphore(self.depth)
         self._queues: dict = {}      # dev -> its worker's queue
         self._workers: list = []
+        # host buffers of the assemblies: a ring a group of devices, and
+        # the condition its hand-overs (and a failure) are told under
+        self._rings: dict = {}       # devs -> the group's last segments
+        self._handover = threading.Condition()
 
     def _queue(self, dev):
         """``dev``'s queue; its worker starts with its first job (only
@@ -438,6 +451,18 @@ class PutStage:
         for dev, job in jobs:
             self._queue(dev).put((chunk, job))
 
+    def assemble(self, devs: Sequence, rows: int, row_shape: tuple,
+                 dtype) -> "HostAssembly":
+        """The assembly of one column shard, ``rows`` rows of
+        ``row_shape``, that every device of ``devs`` takes; its host
+        buffers come out of that group's ring.  Called on the thread
+        that calls :meth:`put`, before it hands in the shard's first
+        chunk: the order of these calls is the order the ring's buffers
+        pass from one shard to the next in."""
+        ring = self._rings.setdefault(
+            tuple(devs), collections.deque(maxlen=ASSEMBLY_SLOTS))
+        return HostAssembly(self, ring, tuple(devs), rows, row_shape, dtype)
+
     def then(self, dev, fn: Callable) -> None:
         """Run ``fn()`` after everything handed in for ``dev`` so far."""
         if not self.depth:
@@ -459,6 +484,7 @@ class PutStage:
         with self.engine.tracer.span("strom.restore.retire",
                                      "strom.restore"):
             self._retire.flush()
+            self._keep_host_buffers()
         if self._error is not None:
             err, self._error = self._error, None
             raise err
@@ -478,18 +504,48 @@ class PutStage:
                     else:
                         chunk.arrays.extend(job())
             except BaseException as e:
-                self._error = self._error or e
+                self._fail(e)
             if chunk is not None and chunk.done_one():
                 try:
                     self._retire_chunk(chunk, "restore_puts_staged")
                 except BaseException as e:  # a transfer that failed
-                    self._error = self._error or e
+                    self._fail(e)
                 self._room.release()
             del item, chunk, job    # hold no array while the queue is idle
 
+    def _keep_host_buffers(self) -> None:
+        """Leave the rings' buffers with the engine for its next load,
+        each once what was put out of it has landed; one whose shard
+        was not put by every device, or whose transfer failed, is
+        dropped (the array on its way holds it: nothing is rewritten)."""
+        rings, self._rings = self._rings, {}
+        for ring in rings.values():
+            for seg in ring:
+                if seg.raw is None or seg.left \
+                        or seg.raw.nbytes != ASSEMBLY_BYTES:
+                    continue
+                try:
+                    for arr in seg.arrays:
+                        arr.block_until_ready()
+                except Exception:
+                    continue
+                self.engine.spare_host_buffers.append(seg.raw)
+
+    def _fail(self, e: BaseException) -> None:
+        """Keep the first failure and wake whoever waits for a host
+        buffer: the put it waits for may never come."""
+        with self._handover:
+            self._error = self._error or e
+            self._handover.notify_all()
+
     def _retire_chunk(self, chunk, counter: str) -> None:
         """Every job of ``chunk`` has ended: count its puts and hand its
-        release to the pool, behind the arrays put out of it."""
+        release to the pool, behind the arrays put out of it — or call
+        it here, where nothing was put out of the chunk."""
+        if not chunk.arrays:
+            if chunk.release is not None:
+                chunk.release()
+            return
         self.engine.stats.add(**{counter: len(chunk.arrays)})
         with self.engine.tracer.span("strom.restore.retire",
                                      "strom.restore"):
@@ -514,6 +570,161 @@ class _Chunk:
         with self._lock:
             self._left -= 1
             return self._left == 0
+
+
+#: the most bytes one assembled put carries, and the size of every host
+#: buffer of the assemblies (its pages are touched only as far as a
+#: shard reaches).  A put's per-call cost is what a restore pays for
+#: (PERF.md §5: ~0.3–0.5 ms whatever its size, serialised across
+#: threads); at 64 MiB it is a twentieth of the put's time on the link,
+#: and mistral-7b's largest column shard under tp=4 (``tok_embed``,
+#: ``lm_head``) is one put.  A larger shard crosses in several such
+#: puts, joined on the device.
+ASSEMBLY_BYTES = 64 << 20
+#: host buffers a group of devices gathers into in turn: one being
+#: filled while the one before it is on its way to the devices
+ASSEMBLY_SLOTS = 2
+
+
+class _Segment:
+    """The rows ``[start, end)`` of a ``HostAssembly`` that cross in one
+    put a device, and the host buffer they are gathered into.  ``prev``
+    is the segment that had the buffer ``ASSEMBLY_SLOTS`` segments
+    earlier in the same ring: this one takes the buffer over once every
+    device of the group has put ``prev`` and those arrays are ready."""
+
+    __slots__ = ("start", "end", "prev", "raw", "arrays", "left", "_host")
+
+    def __init__(self, start: int, end: int, prev, n_devs: int):
+        self.start = start
+        self.end = end
+        self.prev = prev
+        self.raw = None             # the uint8 buffer, once taken
+        self.arrays: list = []      # device arrays put out of it
+        self.left = n_devs          # devices still to put it
+        self._host = Once()
+
+
+class HostAssembly:
+    """One column shard on its way to the devices that take it, gathered
+    on the host chunk by chunk into a reused buffer and put whole — one
+    ``device_put`` a device in place of one a chunk and a concatenate
+    (``PutStage.assemble`` makes one; PERF.md §3, §5).
+
+    A shard of more than ``ASSEMBLY_BYTES`` crosses in segments of that
+    size.  Each segment's buffer comes out of the group's ring of
+    ``ASSEMBLY_SLOTS``: while the ring is young it is one the engine
+    kept from an earlier load (``spare_host_buffers``) or a new one,
+    afterwards the buffer of the segment ``ASSEMBLY_SLOTS`` earlier —
+    never rewritten before every array put out of it reports ready
+    (``StagingRetirePool``'s rule, for host buffers); the wait lies
+    under the span ``strom.restore.retire``.  ``PutStage.close`` leaves
+    the buffers with the engine.
+
+    :meth:`gather` and :meth:`put` run inside the stage's jobs: a device's
+    worker gathers its chunk (or waits for the worker that does, where
+    several devices take the same shard) and then puts whatever became
+    complete.  A device's jobs run in order, so after its gather of rows
+    up to ``r`` every row below ``r`` is in place.
+    """
+
+    def __init__(self, stage: "PutStage", ring, devs: tuple, rows: int,
+                 row_shape: tuple, dtype):
+        self.stage = stage
+        self.rows = rows
+        self.row_shape = tuple(row_shape)
+        self.dtype = np.dtype(dtype)
+        row_bytes = int(np.prod(self.row_shape, dtype=np.int64)) \
+            * self.dtype.itemsize
+        self.row_bytes = row_bytes
+        self.seg_rows = max(1, ASSEMBLY_BYTES // row_bytes if row_bytes
+                            else rows)
+        self.segments = []
+        for start in range(0, rows, self.seg_rows):
+            prev = ring[0] if len(ring) == ring.maxlen else None
+            seg = _Segment(start, min(rows, start + self.seg_rows), prev,
+                           len(devs))
+            ring.append(seg)
+            self.segments.append(seg)
+        self._next = {dev: 0 for dev in devs}   # dev -> segment to put
+
+    def _host(self, seg: _Segment) -> np.ndarray:
+        """``seg``'s host array; the first thread to ask takes the
+        buffer, the others wait for it."""
+        return seg._host.get(functools.partial(self._take_buffer, seg))
+
+    def _take_buffer(self, seg: _Segment) -> np.ndarray:
+        """``seg``'s host array, on the buffer of the segment that had
+        it before (once that one has been put and has landed) or on a
+        new one."""
+        stage = self.stage
+        prev, seg.prev = seg.prev, None
+        need = (seg.end - seg.start) * self.row_bytes
+        raw = None
+        if prev is not None:
+            with stage.engine.tracer.span("strom.restore.retire",
+                                          "strom.restore"):
+                with stage._handover:
+                    stage._handover.wait_for(
+                        lambda: prev.left == 0 or stage._error is not None)
+                    if prev.left:
+                        raise stage._error
+                for arr in prev.arrays:
+                    arr.block_until_ready()
+            prev.arrays = []
+            raw, prev.raw = prev.raw, None
+        if raw is None or raw.nbytes < need:
+            spares = stage.engine.spare_host_buffers
+            if raw is not None:
+                spares.append(raw)
+            if need > ASSEMBLY_BYTES:   # one row over the cap: its own
+                raw = np.empty(need, dtype=np.uint8)
+            else:
+                try:
+                    raw = spares.pop()
+                except IndexError:
+                    raw = np.empty(ASSEMBLY_BYTES, dtype=np.uint8)
+        seg.raw = raw
+        return raw[:need].view(self.dtype).reshape(
+            (seg.end - seg.start,) + self.row_shape)
+
+    def gather(self, row0: int, cut: np.ndarray) -> bool:
+        """Copy ``cut`` — the shard's columns of one chunk, strided in
+        the chunk's view — into rows ``[row0, row0 + len(cut))``."""
+        eng = self.stage.engine
+        done, n = 0, cut.shape[0]
+        while done < n:
+            seg = self.segments[(row0 + done) // self.seg_rows]
+            host = self._host(seg)
+            at = row0 + done - seg.start
+            take = min(n - done, seg.end - seg.start - at)
+            piece = cut[done:done + take]
+            with eng.tracer.span("strom.restore.slice", "strom.restore",
+                                 bytes=int(piece.nbytes)):
+                np.copyto(host[at:at + take], piece)
+            done += take
+        eng.stats.add(bounce_bytes=int(cut.nbytes))
+        return True
+
+    def put(self, dev, rows_done: int) -> list:
+        """On ``dev``'s thread, its gather of the rows below
+        ``rows_done`` behind it: put every segment that became complete
+        and return the arrays."""
+        stage = self.stage
+        out = []
+        while self._next[dev] < len(self.segments) \
+                and self.segments[self._next[dev]].end <= rows_done:
+            seg = self.segments[self._next[dev]]
+            self._next[dev] += 1
+            host = self._host(seg)
+            arr = host_to_device(stage.engine, host, dev)
+            with stage._handover:
+                seg.arrays.append(arr)
+                seg.left -= 1
+                stage._handover.notify_all()
+            stage.engine.stats.add(restore_puts_assembled=1)
+            out.append(arr)
+        return out
 
 
 class DeviceStream:

@@ -78,7 +78,8 @@ def _stage_depths(eng, staged: bool) -> tuple:
 
 class _IssuedTensor:
     """A tensor whose chunks are with a ``PutStage``: ``arrays[dev]`` is
-    set by ``dev``'s worker when it has joined that device's parts."""
+    set by ``dev``'s worker when it has joined that device's parts, or
+    put the column shard it assembled."""
 
     def __init__(self, gshape: tuple, sharding, devices: list):
         self.gshape = gshape
@@ -253,6 +254,16 @@ class LazyCheckpoint:
     def _issue_tensor_inner(self, eng: StromEngine, sf, name: str,
                             gshape: tuple, np_dt, sharding, klass: str,
                             stage) -> "_IssuedTensor":
+        """Read each row span of the tensor once and hand every chunk of
+        it to ``stage`` with one job a device.  What a device's job does
+        with the chunk follows from the sharding alone: a device that
+        takes whole rows gets them put out of the staging view, a put a
+        chunk, joined on the device behind the span's last chunk; a
+        device that takes a column shard gets its columns gathered into
+        the shard's ``HostAssembly`` (``stage.assemble``: one reused
+        host buffer for the devices that take that shard) and the
+        buffer put whole behind the gather of its last rows — that
+        array IS the device's part of the tensor."""
         idx_map = sharding.addressable_devices_indices_map(gshape)
 
         # Group devices by ROW SPAN only: rows are contiguous on disk, so a
@@ -300,26 +311,29 @@ class LazyCheckpoint:
         span = eng.tracer.span
         tensor = _IssuedTensor(gshape, sharding, list(idx_map))
 
-        def gather(view, tail):
-            cut = view[(slice(None),) + tail]
-            # strided column shard: host gather copies
-            with span("strom.restore.slice", _CAT, bytes=int(cut.nbytes)):
-                sub = np.ascontiguousarray(cut)
-            eng.stats.add(bounce_bytes=int(sub.nbytes))
-            return sub
-
-        def put_share(view, dev, tail, gathered, parts):
-            """Device ``dev``'s share of one chunk, on its worker: the
-            host gather where its columns are strided, then the put."""
+        def put_share(view, dev, tail, parts, asm, row0, gathered):
+            """Device ``dev``'s share of one chunk, on its worker.  Whole
+            rows are put out of the staging view as they lie.  A column
+            shard is strided there and the host has to gather it: into
+            the shard's assembly, which crosses in one put behind the
+            gather of its last rows — nothing is put out of the view."""
             if not tail:
-                sub = view
-            elif gathered is None:
-                sub = gather(view, tail)
+                arr = host_to_device(eng, view, dev)
+                parts.append(arr)
+                return (arr,)
+            cut = view[(slice(None),) + tail]
+            if gathered is None:
+                asm.gather(row0, cut)
             else:       # several devices' columns: the first one gathers
-                sub = gathered.get(partial(gather, view, tail))
-            arr = host_to_device(eng, sub, dev)
-            parts.append(arr)
-            return (arr,)
+                gathered.get(partial(asm.gather, row0, cut))
+            end = row0 + cut.shape[0]
+            parts.extend(asm.put(dev, end))
+            if end == asm.rows:
+                if len(parts) == 1:
+                    tensor.arrays[dev] = parts.pop()
+                else:           # a shard of several assembled puts
+                    join(dev, parts)
+            return ()
 
         def join(dev, parts):
             """Behind ``dev``'s last put of a span, on its worker."""
@@ -330,9 +344,10 @@ class LazyCheckpoint:
 
         # The staging buffers are the stage's from the hand-over on: it
         # releases a chunk's once every array put out of it is ready
-        # (its one StagingRetirePool).  This thread only reads: plan,
-        # wait, the CRC pass, and the hand-over (strom.restore.put_wait
-        # is its wait for room in the stage).
+        # (its one StagingRetirePool) — at once where every device
+        # gathered its share.  This thread only reads: plan, wait, the
+        # CRC pass, and the hand-over (strom.restore.put_wait is its
+        # wait for room in the stage).
         fh = eng.open(sf.path)
         try:
             for (r0, r1), devs in spans.items():
@@ -341,8 +356,19 @@ class LazyCheckpoint:
                          and policy.want())
                 crc = 0
                 parts: Dict[object, list] = {dev: [] for dev, _, _ in devs}
-                tkeys = [tkey for _, _, tkey in devs if tkey]
-                shared = {k for k in tkeys if tkeys.count(k) > 1}
+                # a column shard is assembled on the host, once for the
+                # devices that take the same one
+                takers: Dict[tuple, list] = {}
+                for dev, tail, tkey in devs:
+                    if tail:
+                        takers.setdefault(tkey, []).append((dev, tail))
+                asms = {
+                    tkey: stage.assemble(
+                        [dev for dev, _ in group], r1 - r0,
+                        tuple(s.stop - s.start for s in group[0][1]), np_dt)
+                    for tkey, group in takers.items()}
+                shared = [k for k, group in takers.items() if len(group) > 1]
+                row0 = 0
                 for view, release in self._stream_span(
                         eng, fh, sf, name, r0, r1, np_dt, gshape,
                         klass=klass):
@@ -356,16 +382,19 @@ class LazyCheckpoint:
                     gathers = {tkey: Once() for tkey in shared}
                     stage.put(release, [
                         (dev, partial(put_share, view, dev, tail,
-                                      gathers.get(tkey), parts[dev]))
+                                      parts[dev], asms.get(tkey), row0,
+                                      gathers.get(tkey)))
                         for dev, tail, tkey in devs])
+                    row0 += view.shape[0] if view.ndim else 1
                 if check and crc != stamp:
                     eng.stats.add(checksum_failures=1)
                     raise ChecksumError(
                         f"tensor {name} of {sf.path} fails its stamped "
                         f"CRC32C ({crc:#010x} != {stamp:#010x}) — "
                         f"corrupt weights must not reach the model")
-                for dev, _, _ in devs:
-                    stage.then(dev, partial(join, dev, parts[dev]))
+                for dev, tail, _ in devs:
+                    if not tail:
+                        stage.then(dev, partial(join, dev, parts[dev]))
         finally:
             eng.close(fh)
         return tensor

@@ -61,6 +61,7 @@ _BATCH_COUNTERS = (
 _ENGINE_COUNTERS = (
     "submit_enters", "arena_fallbacks", "overlap_chunks",
     "overlap_bytes", "restore_puts_staged", "restore_puts_inline",
+    "restore_puts_assembled",
 )
 
 #: QoS scheduler counters (io/sched.py over the multi-ring engine —

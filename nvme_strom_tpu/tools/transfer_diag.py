@@ -44,9 +44,18 @@ in two parts:
    Python: ``puts_per_s``, and ``gil_held_share`` — the part of its
    solo rate the counting thread lost, which is the time the putting
    threads held the GIL (the switch interval is 0.1 ms meanwhile).
+   ``--sources staging,heap,batched`` repeats every pair out of other
+   memory (``"source"`` on each line): ``heap`` — a plain numpy buffer a
+   thread, allocated once and reused, as the restore's assembly buffers
+   are (``ops/bridge.HostAssembly``; ``first_touch_us`` is the first
+   copy into the fresh buffer, ``copy_us`` the second) — and
+   ``batched``, ONE thread making one ``jax.make_array_from_callback``
+   call over all the devices out of those heap buffers (``bytes`` a
+   device; the call's ``return_us`` stands against ``devices`` puts).
 
 Usage: python -m nvme_strom_tpu.tools.transfer_diag [--bytes N]
-           [--sizes N,N,... [--threads 1,2,4] [--devices D]]
+           [--sizes N,N,... [--threads 1,2,4] [--devices D]
+            [--sources staging,heap,batched]]
 Prints one JSON line with the alias verdict and the three medians, then
 the sweep's lines; every line names ``platform``, ``device_kind`` and
 ``device_count``.  A measuring command: without a TPU it exits non-zero
@@ -234,10 +243,14 @@ def _measure(n: int, put, repeats: int, size: int,
 
 
 def sweep(sizes, threads=(1,), n_devices: int = 1,
-          repeats: int = 8, gil_seconds: float = 0.0) -> list:
-    """The host→device ceiling: one dict a (size, thread count), see the
-    module docstring's part 3.  Every put's source is an engine staging
-    view (one a thread), as ``load_sharded``'s puts are."""
+          repeats: int = 8, gil_seconds: float = 0.0,
+          sources=("staging",)) -> list:
+    """The host→device ceiling: one dict a (source, size, thread count),
+    see the module docstring's part 3.  A ``staging`` put's source is an
+    engine staging view (one a thread), as ``load_sharded``'s whole-row
+    puts are; a ``heap`` put's a reused numpy buffer, as its assembled
+    puts are; ``batched`` is one call for all the devices."""
+    import numpy as np
     import jax
     from nvme_strom_tpu.io.engine import StromEngine
     from nvme_strom_tpu.utils.config import EngineConfig
@@ -259,15 +272,51 @@ def sweep(sizes, threads=(1,), n_devices: int = 1,
                 reads = [eng.submit_read(fh, 0, size) for _ in range(n_max)]
                 views = [pr.wait() for pr in reads]
                 jax.device_put(views[0], devs[0]).block_until_ready()
-                def put(t):
-                    return jax.device_put(views[t], devs[t % len(devs)])
+                touch: dict = {}
+                heaps: list = []
+                if set(sources) - {"staging"}:
+                    for v in views:
+                        t0 = time.monotonic()
+                        h = np.empty(size, np.uint8)
+                        h[:] = v
+                        t1 = time.monotonic()
+                        h[:] = v
+                        touch = {"first_touch_us": round((t1 - t0) * 1e6, 1),
+                                 "copy_us": round(
+                                     (time.monotonic() - t1) * 1e6, 1)}
+                        heaps.append(h)
+                sharding = jax.sharding.NamedSharding(
+                    jax.sharding.Mesh(np.array(devs), ("d",)),
+                    jax.sharding.PartitionSpec("d"))
+                by_dev = {d: heaps[k % len(heaps)]
+                          for k, d in enumerate(devs)} if heaps else {}
 
-                for n in threads:
-                    rows.append({
-                        "sweep": True, "bytes": size, "threads": n,
-                        "devices": len(devs), "repeats": repeats,
-                        "platform": devs[0].platform,
-                        **_measure(n, put, repeats, size, gil_seconds)})
+                def put_from(bufs):
+                    return lambda t: jax.device_put(bufs[t],
+                                                    devs[t % len(devs)])
+
+                def put_batched(_t):
+                    # one call, every device's buffer handed over whole
+                    return jax.make_array_from_callback(
+                        (len(devs) * size,), sharding,
+                        lambda idx: by_dev[devs[
+                            (idx[0].start or 0) // size]])
+
+                for source in sources:
+                    put = {"staging": put_from(views),
+                           "heap": put_from(heaps),
+                           "batched": put_batched}[source]
+                    for n in ((1,) if source == "batched" else threads):
+                        rows.append({
+                            "sweep": True, "source": source, "bytes": size,
+                            "threads": n, "devices": len(devs),
+                            "repeats": repeats,
+                            "platform": devs[0].platform,
+                            **(touch if source == "heap" else {}),
+                            **_measure(n, put, repeats,
+                                       size * (len(devs)
+                                               if source == "batched"
+                                               else 1), gil_seconds)})
                 for pr in reads:
                     pr.release()
                 eng.close(fh)
@@ -293,6 +342,11 @@ def main(argv=None) -> int:
                     help="thread counts of the sweep (1,2,4)")
     ap.add_argument("--devices", type=int, default=1,
                     help="devices the sweep's threads put onto")
+    ap.add_argument("--sources", default="staging",
+                    type=lambda text: [x for x in text.split(",") if x],
+                    help="memory the sweep's puts read: staging, heap "
+                         "(a reused numpy buffer), batched (one call "
+                         "for all the devices out of heap buffers)")
     ap.add_argument("--gil-seconds", type=float, default=0.0,
                     help="seconds a (size, threads) pair puts beside a "
                          "counting thread (0: not measured)")
@@ -302,7 +356,8 @@ def main(argv=None) -> int:
     res = run(args.bytes, args.repeats)
     print(json.dumps({**res, **device}), flush=True)
     for row in sweep(args.sizes, args.threads, args.devices,
-                     max(args.repeats, 2), args.gil_seconds):
+                     max(args.repeats, 2), args.gil_seconds,
+                     args.sources):
         print(json.dumps({**row, **device}), flush=True)
     return 0 if res.get("view_in_pool") else 1
 

@@ -101,9 +101,13 @@ class StromStats:
     # one of the stage's workers beside the reading thread / issued on
     # the reading thread itself (a staging pool with no room for a
     # queue, a single tensor's load).  load_sharded over a default
-    # pool counts staged only
+    # pool counts no inline put.  Both count arrays put out of staging
+    # views (whole rows); a column shard gathered into a host buffer
+    # (ops/bridge.HostAssembly) crosses in one put of that buffer,
+    # counted once, here, whichever thread made it
     restore_puts_staged: int = 0
     restore_puts_inline: int = 0
+    restore_puts_assembled: int = 0
     # -- resilience counters (io/faults.py, io/resilient.py) --------------
     # faults injected by an active FaultPlan (test/chaos runs; 0 in prod)
     faults_injected: int = 0

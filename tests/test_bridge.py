@@ -692,5 +692,338 @@ def test_two_hundred_loads_back_to_back_do_not_deadlock(tmp_path):
                     np.testing.assert_array_equal(
                         np.asarray(params[name]), ref)
         assert eng.stats.restore_puts_inline == 0
-        assert eng.stats.restore_puts_staged > 200 * 4
+        # a load: ``bias`` whole onto four devices out of its one chunk,
+        # three column-sharded tensors in one assembled put a device
+        assert eng.stats.restore_puts_staged == 200 * 4
+        assert eng.stats.restore_puts_assembled == 200 * 3 * 4
     assert _stage_threads() == []
+
+
+# ---------------------------------------------------------------------------
+# HostAssembly: a column shard gathered into a reused host buffer and put
+# whole (PERF.md §3, §6 PR 49)
+# ---------------------------------------------------------------------------
+
+class _HostPuts:
+    """Stands in for ``host_to_device``: every put keeps the host array
+    it was given, a copy of what that held then, and a gate."""
+
+    def __init__(self, ready=True):
+        self.ready = ready
+        self.puts = []
+
+    def __call__(self, engine, host, dev, alias_safe=False):
+        arr = _GatedArray()
+        arr.dev, arr.host, arr.seen = dev, host, host.copy()
+        if self.ready:
+            arr.gate.set()
+        self.puts.append(arr)
+        return arr
+
+
+def _assembly_job(asm, dev, row0, cut, gathered=None):
+    """What ``_issue_tensor_inner`` hands the stage for a column shard:
+    gather, then put what became complete; nothing out of the chunk."""
+    def job():
+        if gathered is None:
+            asm.gather(row0, cut)
+        else:
+            gathered.get(lambda: asm.gather(row0, cut))
+        asm.put(dev, row0 + len(cut))
+        return ()
+    return job
+
+
+def _shard(t, rows=8, cols=4):
+    return (np.arange(rows * cols, dtype=np.float32).reshape(rows, cols)
+            + 1000 * t)
+
+
+@pytest.mark.parametrize("depth", [0, 2])
+@_within(60)
+def test_assembly_buffer_is_not_rewritten_under_a_live_put(
+        engine, monkeypatch, depth):
+    """More shards than ring buffers: the third waits for the first's
+    array — held back here — before it writes a byte over it."""
+    import threading
+    import time
+    from nvme_strom_tpu.ops import bridge
+    h2d = _HostPuts(ready=depth == 0)
+    monkeypatch.setattr(bridge, "host_to_device", h2d)
+    bufs = _Buffers()
+    stage = bridge.PutStage(engine, depth=depth, retire_depth=2)
+
+    def hand_in():
+        for t in range(5):
+            asm = stage.assemble(["dev0"], 8, (4,), np.float32)
+            for c in range(2):
+                stage.put(bufs.release(2 * t + c), [("dev0", _assembly_job(
+                    asm, "dev0", 4 * c, _shard(t)[4 * c:4 * c + 4]))])
+        stage.close()
+
+    feeder = threading.Thread(target=hand_in, daemon=True)
+    feeder.start()
+    if depth:
+        while len(h2d.puts) < bridge.ASSEMBLY_SLOTS:
+            time.sleep(0.001)
+        time.sleep(0.1)
+        # both buffers are on their way; the third shard's gather waits
+        assert len(h2d.puts) == bridge.ASSEMBLY_SLOTS
+        for t, arr in enumerate(h2d.puts):
+            np.testing.assert_array_equal(arr.host, _shard(t))
+        # ... with its chunk in hand, and the reader behind it
+        assert len(bufs.released) <= 2 * bridge.ASSEMBLY_SLOTS
+        h2d.ready = True
+        for arr in list(h2d.puts):
+            arr.gate.set()
+    feeder.join(30)
+    assert not feeder.is_alive()
+    assert [a.dev for a in h2d.puts] == ["dev0"] * 5
+    for t, arr in enumerate(h2d.puts):
+        np.testing.assert_array_equal(arr.seen, _shard(t))
+    # two buffers, used in turn
+    for t, arr in enumerate(h2d.puts[bridge.ASSEMBLY_SLOTS:]):
+        assert np.shares_memory(arr.host, h2d.puts[t].host)
+    assert not np.shares_memory(h2d.puts[0].host, h2d.puts[1].host)
+    assert sorted(bufs.released) == list(range(10))
+    assert engine.stats.restore_puts_assembled == 5
+    assert engine.stats.restore_puts_staged == 0
+    assert engine.stats.restore_puts_inline == 0
+    assert _stage_threads() == []
+
+
+@_within(60)
+def test_a_gathered_chunk_is_released_behind_no_transfer(engine,
+                                                         monkeypatch):
+    """Nothing is put out of a gathered chunk's view: its buffer goes
+    back when its last device has gathered it, whatever the retire pool
+    still holds and whether or not the assembled put has landed."""
+    import time
+    from nvme_strom_tpu.ops import bridge
+    h2d = _HostPuts(ready=False)
+    monkeypatch.setattr(bridge, "host_to_device", h2d)
+    bufs = _Buffers()
+    stage = bridge.PutStage(engine, depth=2, retire_depth=4)
+    devs = ["dev0", "dev1"]
+    stage.put(bufs.release("rows"), [(d, bufs.job("rows")) for d in devs])
+    asms = {d: stage.assemble([d], 8, (4,), np.float32) for d in devs}
+    for c in range(2):
+        stage.put(bufs.release(c), [
+            (d, _assembly_job(asms[d], d, 4 * c, _shard(k)[4 * c:4 * c + 4]))
+            for k, d in enumerate(devs)])
+    while len(h2d.puts) < 2:
+        time.sleep(0.001)
+    time.sleep(0.05)
+    assert bufs.released == [0, 1]      # not "rows": its arrays are live
+    for arr in bufs.arrays["rows"] + h2d.puts:
+        arr.gate.set()
+    stage.close()
+    assert bufs.released == [0, 1, "rows"] and not bufs.live_at_release
+    assert engine.stats.restore_puts_staged == 2
+    assert engine.stats.restore_puts_assembled == 2
+
+
+@_within(60)
+def test_devices_that_take_the_same_shard_share_its_gather_and_buffer(
+        engine, monkeypatch):
+    """dp x tp: one gather a chunk for the group, a put a device, and
+    the buffer waits for every device's array."""
+    import time
+    from nvme_strom_tpu.ops import bridge
+    h2d = _HostPuts(ready=False)
+    monkeypatch.setattr(bridge, "host_to_device", h2d)
+    gathers = []
+    real = bridge.HostAssembly.gather
+    monkeypatch.setattr(
+        bridge.HostAssembly, "gather",
+        lambda self, row0, cut: gathers.append(row0) or real(self, row0, cut))
+    bufs = _Buffers()
+    stage = bridge.PutStage(engine, depth=4, retire_depth=2)
+    devs = ["dev0", "dev4"]
+    n = bridge.ASSEMBLY_SLOTS + 1
+    for t in range(n):
+        asm = stage.assemble(devs, 8, (4,), np.float32)
+        for c in range(2):
+            once = bridge.Once()
+            stage.put(bufs.release(2 * t + c), [
+                (d, _assembly_job(asm, d, 4 * c, _shard(t)[4 * c:4 * c + 4],
+                                  once)) for d in devs])
+    while len(h2d.puts) < 2 * bridge.ASSEMBLY_SLOTS:
+        time.sleep(0.001)
+    # the first shard's buffer: ready on one device, live on the other
+    first = [a for a in h2d.puts if a.seen[0, 0] == 0]
+    assert sorted(a.dev for a in first) == devs
+    assert first[0].host is first[1].host
+    first[0].gate.set()
+    time.sleep(0.1)
+    assert len(h2d.puts) == 2 * bridge.ASSEMBLY_SLOTS
+    np.testing.assert_array_equal(first[1].host, _shard(0))
+    h2d.ready = True
+    for arr in list(h2d.puts):
+        arr.gate.set()
+    stage.close()
+    assert len(gathers) == 2 * n            # once a chunk, not a device
+    assert len(h2d.puts) == 2 * n
+    for arr in h2d.puts:
+        np.testing.assert_array_equal(arr.seen, _shard(arr.seen[0, 0] // 1000))
+    assert engine.stats.restore_puts_assembled == 2 * n
+    assert sorted(bufs.released) == list(range(2 * n))
+
+
+@_within(60)
+def test_a_shard_over_the_cap_crosses_in_segments(engine, monkeypatch):
+    """A chunk may straddle two segments; each is put when its last row
+    is in place, in order."""
+    from nvme_strom_tpu.ops import bridge
+    monkeypatch.setattr(bridge, "ASSEMBLY_BYTES", 5 * 16)   # 5 rows
+    h2d = _HostPuts()
+    monkeypatch.setattr(bridge, "host_to_device", h2d)
+    stage = bridge.PutStage(engine, depth=2, retire_depth=2)
+    asm = stage.assemble(["dev0"], 12, (4,), np.float32)
+    data = _shard(3, rows=12)
+    got = []
+    for r in range(0, 12, 4):
+        stage.put(None, [("dev0", lambda r=r: (
+            asm.gather(r, data[r:r + 4]),
+            got.append(len(asm.put("dev0", r + 4))))[:0])])
+    stage.close()
+    assert got == [0, 1, 2]                 # rows 0-4, 5-9, 10-11
+    assert [a.seen.shape for a in h2d.puts] == [(5, 4), (5, 4), (2, 4)]
+    np.testing.assert_array_equal(
+        np.concatenate([a.seen for a in h2d.puts]), data)
+    assert np.shares_memory(h2d.puts[2].host, h2d.puts[0].host)
+    assert engine.stats.restore_puts_assembled == 3
+
+
+@pytest.mark.parametrize("failing", ["gather", "put"])
+@_within(60)
+def test_assembly_failure_wakes_the_worker_that_waits_for_a_buffer(
+        engine, monkeypatch, failing):
+    """Two devices take the same shards; one's job fails while the other
+    waits for a buffer that only the failed one's put would free: the
+    waiter wakes, the caller gets the failure, every staging buffer is
+    released and no worker is left."""
+    import threading
+    import time
+    from nvme_strom_tpu.ops import bridge
+    h2d = _HostPuts()
+    monkeypatch.setattr(bridge, "host_to_device", h2d)
+    bufs = _Buffers()
+    go = threading.Event()
+    devs = ["dev0", "dev4"]
+
+    def failing_job(asm):
+        def job():
+            assert go.wait(20)
+            if failing == "gather":     # rows of another width
+                asm.gather(0, np.zeros((8, 3), np.float32))
+            raise RuntimeError("put failed")
+        return job
+
+    before = _stage_threads()
+    handed = []
+    with pytest.raises((RuntimeError, ValueError)):
+        stage = bridge.PutStage(engine, depth=8, retire_depth=2)
+        try:
+            for t in range(bridge.ASSEMBLY_SLOTS + 1):
+                asm = stage.assemble(devs, 8, (4,), np.float32)
+                jobs = [(d, _assembly_job(asm, d, 0, _shard(t)))
+                        for d in devs]
+                if t == 0:
+                    jobs[1] = ("dev4", failing_job(asm))
+                handed.append(t)
+                stage.put(bufs.release(t), jobs)
+            # dev0 has put two shards and waits for the first's buffer
+            while len(h2d.puts) < bridge.ASSEMBLY_SLOTS:
+                time.sleep(0.001)
+            time.sleep(0.05)
+            assert len(h2d.puts) == bridge.ASSEMBLY_SLOTS
+            go.set()
+        finally:
+            stage.close()
+    assert sorted(bufs.released) == handed
+    assert _stage_threads() == before
+    assert stage._rings == {}
+
+
+@_within(240)
+def test_shared_assemblies_under_a_short_switch_interval(tmp_path):
+    """Stress: eight workers (dp x tp: every column shard shared by two
+    devices, its gathers raced for chunk by chunk), tiny chunks, a
+    thread switch every 10 us — every load equals the file."""
+    import sys
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from nvme_strom_tpu.parallel.weights import LazyCheckpoint
+    path, tensors = _column_checkpoint(tmp_path)
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("dp", "tp"))
+    shardings = {n: NamedSharding(mesh, P(None, "tp") if t.ndim == 2 else P())
+                 for n, t in tensors.items()}
+    cfg = EngineConfig(chunk_bytes=1 << 13, queue_depth=8,
+                       buffer_pool_bytes=16 << 13)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with StromEngine(cfg, stats=StromStats()) as eng:
+            ck = LazyCheckpoint(path)
+            for i in range(40):
+                params = ck.load_sharded(shardings, engine=eng)
+                for name, ref in tensors.items():
+                    assert np.asarray(params[name]).tobytes() == ref.tobytes()
+                    for shard in params[name].addressable_shards:
+                        assert np.asarray(shard.data).tobytes() \
+                            == ref[shard.index].tobytes()
+            assert eng.stats.restore_puts_assembled == 40 * 3 * 8
+            assert eng.stats.restore_puts_inline == 0
+            info = eng.pool_info()
+            assert info["free_buffers"] == info["n_buffers"]
+    finally:
+        sys.setswitchinterval(old)
+    assert _stage_threads() == []
+
+
+@_within(60)
+def test_host_buffers_pass_to_the_next_load_once_their_puts_have_landed(
+        engine, monkeypatch):
+    """``close`` leaves a stage's host buffers with the engine — after
+    the arrays put out of them are ready, so the next stage may write
+    into them at once — and the next stage takes them in place of new
+    ones; one whose shard a device never put is dropped."""
+    import threading
+    import time
+    from nvme_strom_tpu.ops import bridge
+    h2d = _HostPuts(ready=False)
+    monkeypatch.setattr(bridge, "host_to_device", h2d)
+    stage = bridge.PutStage(engine, depth=2, retire_depth=2)
+    for t in range(2):
+        asm = stage.assemble(["dev0"], 8, (4,), np.float32)
+        stage.put(None, [("dev0", _assembly_job(asm, "dev0", 0, _shard(t)))])
+    half = stage.assemble(["dev1", "dev5"], 8, (4,), np.float32)
+    stage.put(None, [("dev1", _assembly_job(half, "dev1", 0, _shard(9)))])
+    closer = threading.Thread(target=stage.close, daemon=True)
+    closer.start()
+    while len(h2d.puts) < 3:
+        time.sleep(0.001)
+    time.sleep(0.05)
+    assert closer.is_alive() and engine.spare_host_buffers == []
+    for arr in h2d.puts:
+        arr.gate.set()
+    closer.join(20)
+    assert not closer.is_alive()
+    kept = list(engine.spare_host_buffers)
+    assert len(kept) == 2 and all(b.nbytes == bridge.ASSEMBLY_BYTES
+                                  for b in kept)
+    for arr in h2d.puts:    # dev1's shard still waits for dev5's put
+        assert any(np.shares_memory(b, arr.host) for b in kept) \
+            == (arr.dev == "dev0")
+    # the next load gathers into the same memory
+    h2d.ready = True
+    stage = bridge.PutStage(engine, depth=0, retire_depth=0)
+    asm = stage.assemble(["dev0"], 8, (4,), np.float32)
+    stage.put(None, [("dev0", _assembly_job(asm, "dev0", 0, _shard(3)))])
+    assert len(engine.spare_host_buffers) == 1
+    assert any(np.shares_memory(b, h2d.puts[-1].host) for b in kept)
+    stage.close()
+    assert len(engine.spare_host_buffers) == 2
+    engine.close_all()
+    assert engine.spare_host_buffers == []

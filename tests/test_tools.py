@@ -158,6 +158,41 @@ def test_transfer_diag_sweep_json_lines(capsys):
     assert "gil_held_share" not in row and "puts_per_s" not in row
 
 
+def test_transfer_diag_sweep_reads_heap_and_batched_sources():
+    """``--sources``: the same pairs out of a reused numpy buffer a
+    thread (what an assembled put reads) and as one batched call over
+    all the devices; the default is staging views alone."""
+    from nvme_strom_tpu.tools import transfer_diag
+    rows = transfer_diag.sweep([65536], threads=(1, 2), n_devices=2,
+                               repeats=2,
+                               sources=("staging", "heap", "batched"))
+    assert [(r["source"], r["threads"]) for r in rows] == [
+        ("staging", 1), ("staging", 2), ("heap", 1), ("heap", 2),
+        ("batched", 1)]
+    for r in rows:
+        assert r["bytes"] == 65536 and r["devices"] == 2
+        assert 0 < r["return_us"] <= r["ready_us"] and r["gib_s"] > 0
+        assert ("first_touch_us" in r) == (r["source"] == "heap")
+    (row,) = transfer_diag.sweep([65536], threads=(1,), repeats=2)
+    assert row["source"] == "staging"
+
+
+def test_strom_stat_renders_a_restores_puts_by_kind():
+    """The engine block lists a weight restore's ``device_put``s: out of
+    staging views by a worker / on the reading thread, and of assembled
+    column shards (ops/bridge.HostAssembly)."""
+    from nvme_strom_tpu.tools.strom_stat import render
+    from nvme_strom_tpu.utils.stats import StromStats
+    stats = StromStats()
+    stats.add(restore_puts_staged=1412, restore_puts_assembled=648)
+    out = render(stats.snapshot())
+    for name, value in (("restore_puts_staged", "1412"),
+                        ("restore_puts_inline", "0"),
+                        ("restore_puts_assembled", "648")):
+        (line,) = [ln for ln in out.splitlines() if name in ln]
+        assert line.split()[-1] == value
+
+
 def test_strom_stat_renders_member_bytes(capsys):
     """Per-member attribution shows up in the CLI render with shares."""
     from nvme_strom_tpu.tools.strom_stat import render

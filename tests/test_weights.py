@@ -264,10 +264,13 @@ def test_staged_load_equals_the_file_bit_for_bit(tmp_path, monkeypatch,
                 assert np.asarray(shard.data).tobytes() \
                     == ref[shard.index].tobytes()
         # big: 512 KiB in 64 KiB chunks, every device a share of each
-        # chunk it reads; the strided shares are host gathers
+        # chunk it reads; the strided shares are host gathers, into one
+        # buffer a (tensor, device) that crosses in one put
         assert eng.stats.restore_puts_inline == 0
         assert eng.stats.restore_puts_staged == \
-            {"single": 9, "rows": 12, "cols": 36, "replicated": 36}[layout]
+            {"single": 9, "rows": 12, "cols": 0, "replicated": 36}[layout]
+        assert eng.stats.restore_puts_assembled == \
+            (8 if layout == "cols" else 0)
         gathered = eng.stats.snapshot()["bounce_bytes"]
         if layout == "cols":        # (the CPU platform's copies besides)
             assert gathered >= sum(t.nbytes for t in tensors.values())
@@ -316,4 +319,223 @@ def test_corrupted_stamp_still_raises_before_anything_is_returned(
             == tensors["last"].tobytes()
         info = eng.pool_info()
         assert info["free_buffers"] == info["n_buffers"]
+    assert not _stage_threads()
+
+
+# ---------------------------------------------------------------------------
+# a column shard crosses once a tensor (ops/bridge.HostAssembly, PR 49)
+# ---------------------------------------------------------------------------
+
+def _span_names(eng):
+    """Every span the load ends, by name, as the tracer's sinks see them."""
+    seen = []
+    eng.tracer.add_sink(lambda ev: seen.append((ev["name"],
+                                                ev.get("args", {}))))
+    return seen
+
+
+def _assert_equals_file(params, tensors, shardings):
+    for name, ref in tensors.items():
+        got = params[name]
+        assert got.sharding.is_equivalent_to(shardings[name], ref.ndim)
+        assert got.shape == ref.shape and got.dtype == ref.dtype
+        assert np.asarray(got).tobytes() == ref.tobytes()
+        for shard in got.addressable_shards:
+            assert np.asarray(shard.data).tobytes() \
+                == ref[shard.index].tobytes()
+
+
+def test_column_shard_crosses_in_one_put_a_device_and_no_concatenate(
+        tmp_path):
+    rng = np.random.default_rng(49)
+    tensors = {"w": rng.standard_normal((512, 256)).astype(np.float32)}
+    path = tmp_path / "m.safetensors"
+    write_safetensors(path, tensors)
+    sh = {"w": _staged_sharding("cols")}
+    with _staged_engine() as eng:
+        seen = _span_names(eng)
+        params = LazyCheckpoint(path).load_sharded(sh, engine=eng)
+        _assert_equals_file(params, tensors, sh)
+        # 512 KiB in eight chunks, four devices: four puts, of 128 KiB
+        assert eng.stats.restore_puts_assembled == 4
+        assert eng.stats.restore_puts_staged == 0
+        assert eng.stats.restore_puts_inline == 0
+        puts = [a for n, a in seen if n == "strom.h2d"]
+        assert sorted(a["bytes"] for a in puts) == [128 << 10] * 4
+        # the only join is the assembly of the four devices' arrays
+        joins = [a for n, a in seen if n == "strom.restore.join"]
+        assert joins == [{"parts": 4}]
+        gathers = [a for n, a in seen if n == "strom.restore.slice"]
+        assert len(gathers) == 8 * 4
+        assert sum(a["bytes"] for a in gathers) == tensors["w"].nbytes
+        info = eng.pool_info()
+        assert info["free_buffers"] == info["n_buffers"]
+    assert not _stage_threads()
+
+
+def _mixed_checkpoint(tmp_path):
+    rng = np.random.default_rng(50)
+    f32 = lambda *shape: rng.standard_normal(shape).astype(np.float32)
+    tensors = {"embed": f32(384, 128), "wq": f32(128, 128),
+               "wo": f32(128, 128), "w_up": f32(128, 448),
+               "w_down": f32(448, 128), "norm": f32(128),
+               "cube": f32(96, 8, 64), "scalar": np.float32(2.5).reshape(()),
+               "none_wide": np.zeros((4, 0), np.float32),
+               "lm_head": f32(128, 384)}
+    path = tmp_path / "mixed.safetensors"
+    write_safetensors(path, tensors)
+    return path, tensors
+
+
+def _mixed_shardings(mesh, tp="x"):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    spec = {"embed": P(None, tp), "wq": P(None, tp), "wo": P(tp, None),
+            "w_up": P(None, tp), "w_down": P(tp, None), "norm": P(),
+            "cube": P(None, None, tp), "scalar": P(), "none_wide": P(),
+            "lm_head": P(None, tp)}
+    return {n: NamedSharding(mesh, s) for n, s in spec.items()}
+
+
+@pytest.mark.parametrize("verify", ["off", "full"])
+def test_a_mix_of_layouts_equals_the_file(tmp_path, monkeypatch, verify):
+    """Column-sharded (2-D and 3-D), row-sharded, replicated, scalar and
+    zero-size tensors in one load, more of them than ring buffers."""
+    import jax
+    from jax.sharding import Mesh
+    monkeypatch.setenv("STROM_VERIFY", verify)
+    path, tensors = _mixed_checkpoint(tmp_path)
+    sh = _mixed_shardings(Mesh(np.array(jax.devices()[:4]), ("x",)))
+    with _staged_engine() as eng:
+        kept = []
+        for _ in range(2):
+            params = LazyCheckpoint(path).load_sharded(sh, engine=eng)
+            _assert_equals_file(params, tensors, sh)
+            # two host buffers a device, the engine's between loads
+            kept.append(sorted(id(b) for b in eng.spare_host_buffers))
+        assert len(kept[0]) == 8 and kept[0] == kept[1]
+        # five column-sharded tensors, a put a device each, a load
+        assert eng.stats.restore_puts_assembled == 2 * 5 * 4
+        assert eng.stats.restore_puts_inline == 0
+        info = eng.pool_info()
+        assert info["free_buffers"] == info["n_buffers"]
+    assert not _stage_threads()
+
+
+def test_dp_by_tp_shares_a_column_shard_between_devices(tmp_path):
+    """Columns cut over ``tp`` and replicated over ``dp``: two devices
+    take each shard — one gather a chunk, a put a device."""
+    import jax
+    from jax.sharding import Mesh
+    path, tensors = _mixed_checkpoint(tmp_path)
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("dp", "tp"))
+    sh = _mixed_shardings(mesh, tp="tp")
+    with _staged_engine() as eng:
+        seen = _span_names(eng)
+        params = LazyCheckpoint(path).load_sharded(sh, engine=eng)
+        _assert_equals_file(params, tensors, sh)
+        assert eng.stats.restore_puts_assembled == 5 * 8
+        column = [t for n, t in tensors.items()
+                  if n in ("embed", "wq", "w_up", "cube", "lm_head")]
+        gathered = sum(a["bytes"] for n, a in seen
+                       if n == "strom.restore.slice")
+        assert gathered == sum(t.nbytes for t in column)    # once, not twice
+    assert not _stage_threads()
+
+
+@pytest.mark.parametrize("layout", ["cols", "rows", "replicated"])
+def test_load_tensor_at_depth_zero_gives_the_same_array(tmp_path, layout):
+    """The cold-start lanes' path: one tensor a call, its gathers and
+    puts on the calling thread, two lanes at once."""
+    import threading
+    rng = np.random.default_rng(51)
+    tensors = {f"w{i}": rng.standard_normal((512, 256)).astype(np.float32)
+               for i in range(4)}
+    path = tmp_path / "m.safetensors"
+    write_safetensors(path, tensors)
+    sh = _staged_sharding(layout)
+    with _staged_engine() as eng:
+        ck = LazyCheckpoint(path)
+        staged = ck.load_sharded({n: sh for n in tensors}, engine=eng)
+        before = eng.stats.snapshot()
+        got, errs = {}, []
+
+        def lane(names):
+            try:
+                for n in names:
+                    got[n] = ck._load_tensor(eng, n, sh)
+            except BaseException as e:
+                errs.append(e)
+
+        lanes = [threading.Thread(target=lane, args=(names,))
+                 for names in (["w0", "w2"], ["w1", "w3"])]
+        for t in lanes:
+            t.start()
+        for t in lanes:
+            t.join(60)
+        assert not errs and not any(t.is_alive() for t in lanes)
+        _assert_equals_file(got, tensors, {n: sh for n in tensors})
+        for n in tensors:
+            assert np.asarray(got[n]).tobytes() \
+                == np.asarray(staged[n]).tobytes()
+        after = eng.stats.snapshot()
+        assert after["restore_puts_staged"] == before["restore_puts_staged"]
+        assert (after["restore_puts_assembled"]
+                - before["restore_puts_assembled"]) \
+            == (16 if layout == "cols" else 0)
+        assert (after["restore_puts_inline"] > 0) == (layout != "cols")
+        info = eng.pool_info()
+        assert info["free_buffers"] == info["n_buffers"]
+    assert not _stage_threads()
+
+
+def test_a_gather_that_raises_reaches_the_caller_and_frees_everything(
+        tmp_path, monkeypatch):
+    from nvme_strom_tpu.ops import bridge
+    path, tensors = _mixed_checkpoint(tmp_path)
+    import jax
+    from jax.sharding import Mesh
+    sh = _mixed_shardings(Mesh(np.array(jax.devices()[:4]), ("x",)))
+    real = bridge.HostAssembly.gather
+    calls = []
+
+    def failing(self, row0, cut):
+        calls.append(row0)
+        if len(calls) == 11:
+            raise MemoryError("gather failed")
+        return real(self, row0, cut)
+
+    with _staged_engine() as eng:
+        monkeypatch.setattr(bridge.HostAssembly, "gather", failing)
+        got = None
+        with pytest.raises(MemoryError, match="gather failed"):
+            got = LazyCheckpoint(path).load_sharded(sh, engine=eng)
+        assert got is None
+        info = eng.pool_info()
+        assert info["free_buffers"] == info["n_buffers"]
+        assert not _stage_threads()
+        # every buffer came back: the same engine loads again
+        monkeypatch.setattr(bridge.HostAssembly, "gather", real)
+        again = LazyCheckpoint(path).load_sharded(sh, engine=eng)
+        _assert_equals_file(again, tensors, sh)
+    assert not _stage_threads()
+
+
+def test_a_shard_over_the_cap_is_joined_from_a_few_large_puts(
+        tmp_path, monkeypatch):
+    from nvme_strom_tpu.ops import bridge
+    monkeypatch.setattr(bridge, "ASSEMBLY_BYTES", 40 << 10)
+    rng = np.random.default_rng(52)
+    tensors = {"w": rng.standard_normal((512, 256)).astype(np.float32)}
+    path = tmp_path / "m.safetensors"
+    write_safetensors(path, tensors)
+    sh = {"w": _staged_sharding("cols")}
+    with _staged_engine() as eng:
+        seen = _span_names(eng)
+        params = LazyCheckpoint(path).load_sharded(sh, engine=eng)
+        _assert_equals_file(params, tensors, sh)
+        # a device's 128 KiB in segments of 40 KiB (160 rows): 4 puts
+        assert eng.stats.restore_puts_assembled == 4 * 4
+        joins = sorted(a["parts"] for n, a in seen
+                       if n == "strom.restore.join")
+        assert joins == [4] * 5     # a device's four, and the assembly
     assert not _stage_threads()
