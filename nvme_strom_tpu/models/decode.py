@@ -172,6 +172,7 @@ def decode_step(params: Dict, token: jax.Array, cfg: TransformerConfig,
     fused Pallas kernel); it receives the cache at kv-head width.
     Default is a masked dense einsum over the GQA-expanded cache.
     """
+    cfg.require_causal("decode_step (one token a step)")
     if cache_attn is None:
         # the dense path IS block_step with m=1 — one masked-attention
         # implementation to maintain
@@ -276,6 +277,7 @@ def _blocked_attention(h, params, L, cfg, k_l, v_l, pos, win):
     attend over the cache up to their own rows: the kernel takes the first
     query row's position as data, so a chunk is a block behind a prefix."""
     from nvme_strom_tpu.ops.kv_prefill import kv_prefill_attention
+    cfg.require_causal("the blocked prefill kernel (ops/kv_prefill.py)")
     b, m, d = h.shape
     before, after = MIXER_SCOPES["attention"]
     scale = cfg.head_dim ** -0.5 if cfg.attn_scale is None else cfg.attn_scale
@@ -323,8 +325,12 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     Row t of the block attends to the whole cache up to pos+t (causal
     within the block, full history before it) — the verify forward of
     speculative decoding, and the general "ingest a block mid-stream"
-    primitive.  Returns (logits (b, m, vocab) f32, cache with
-    pos += m).  Contract: pos + m <= max_len.
+    primitive.  Under ``cfg.diffusion_block`` Bl the mask is BLOCK-causal:
+    row t sees up to the end of its own diffusion block, ``((pos + t) // Bl
+    + 1) * Bl - 1``, blocks counted from position 0 (the later rows of its
+    block too; with the rows of a half-filled last block as right padding,
+    the whole blocks before it see none of them).  Returns (logits (b, m,
+    vocab) f32, cache with pos += m).  Contract: pos + m <= max_len.
 
     ``last``: project lm_head at only this row → logits (b, vocab) —
     admission-style callers that need one next-token distribution skip
@@ -349,6 +355,10 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
                      + jnp.arange(m, dtype=jnp.float32))
         # row t sees cache positions <= pos + t (same limit for every row)
         limit = jnp.broadcast_to(pos + jnp.arange(m), (b, m))
+        if cfg.diffusion_block:
+            # ... or, block-causal, up to the end of its diffusion block
+            limit = (limit // cfg.diffusion_block + 1) \
+                * cfg.diffusion_block - 1
         valid = (valid_rows(n_valid, b, m)
                  if n_valid is not None and cfg.expert_layers else None)
     ssm = cache.get("ssm")
@@ -395,7 +405,7 @@ def block_step(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
             with jax.named_scope(after):
                 a = a @ wmat(params, L + "wo", a.dtype)
             ai += 1
-        elif cfg.stated_kv:
+        elif cfg.stated_kv and not cfg.diffusion_block:
             # a window layer's cache, scope and place apart from a full one's
             win = cfg.mixer(i) == "window"
             ck, cv, at = ("wk", "wv", wi) if win else ("k", "v", ai)
@@ -516,6 +526,7 @@ def generate(params: Dict, prompt: jax.Array, cfg: TransformerConfig,
     top_p AND cache_attn — a function is not a jax type) or wrap them
     all in a partial.  After ``eos_id`` a sequence emits ``pad_id``
     forever (static shapes; no early exit under jit)."""
+    cfg.require_causal("decode.generate")
     b, s = prompt.shape
     if rng is None:
         rng = jax.random.key(0)

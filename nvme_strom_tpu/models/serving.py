@@ -86,6 +86,9 @@ class _Request:
     top_p: float = 1.0
     seed: int = 0
     out: List[int] = field(default_factory=list)
+    # a diffusion server: the denoising step each token of ``out`` was
+    # committed at (handed on at retirement, ``request_metrics``)
+    steps: List[int] = field(default_factory=list)
     chain_keys: object = None     # paged prefix-cache memo
     store_keys: object = None     # NVMe prefix-store memo (may differ:
     #                               store page size vs HBM block size)
@@ -354,6 +357,13 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     v_pool, state) — ``state`` the recurrent layers' pool after the step,
     None for a plain decoder.
 
+    ``tok`` (B,) is one row a slot at ``pos``.  ``tok`` (B, R) is R rows a
+    slot, the slot's current diffusion block at positions ``pos .. pos + R -
+    1`` (``cfg.diffusion_block``): their K and V go where they will lie (``blk``
+    / ``off`` name the FIRST row's place; a block never straddles a pool
+    block), every one of them sees the history up to ``pos + R - 1`` — its
+    whole block — and the logits are (B, R, vocab).
+
     blk/off (B,) int32: each slot's write target (block id in the pool,
     row offset inside it); table (B, max_blocks) int32 + pos (B,) feed
     the paged-attention kernel.  state/sidx: the recurrent layers' pool
@@ -367,6 +377,8 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     from nvme_strom_tpu.ops.paged_attention import (paged_attention,
                                                     write_rows)
     B = tok.shape[0]
+    blocks = tok.ndim == 2              # R rows a slot (R may be 1)
+    R = tok.shape[1] if blocks else 1
     bk = k_pool.shape[-1] if cfg.latent else k_pool.shape[3]
     ring = ring_blocks(cfg, bk)
     with jax.named_scope("strom.embed"):
@@ -375,8 +387,17 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
         # reads its output, so to the kernel its history is one row
         attn_pos = jnp.where(free, 0, pos)
         live = ~free[:, None] if cfg.expert_layers else None
-        x = embed_tokens(params, cfg, tok[:, None])           # (B,1,d)
-        positions = pos.astype(jnp.float32)[:, None]          # (B,1)
+        if not blocks:
+            x = embed_tokens(params, cfg, tok[:, None])       # (B,1,d)
+            positions = pos.astype(jnp.float32)[:, None]      # (B,1)
+        else:
+            # every row of a block sees the whole block
+            attn_pos = jnp.where(free, 0, pos + R - 1)
+            if cfg.expert_layers:
+                live = jnp.broadcast_to(live, (B, R))
+            x = embed_tokens(params, cfg, tok)                # (B,R,d)
+            positions = (pos[:, None]
+                         + jnp.arange(R)).astype(jnp.float32)  # (B,R)
         if ring:
             # a window layer's cache: the slot's own ring of ``ring`` blocks
             # (row sidx of the rings; a free slot's is the sacrificial last),
@@ -447,12 +468,13 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
                         sink=params.get(L + "sink"))
                 else:
                     k_pool, v_pool = write_rows(
-                        k_pool, v_pool, k[:, :, 0], v[:, :, 0], blk, off,
-                        layer=ai)
+                        k_pool, v_pool, *((k, v) if blocks else
+                                          (k[:, :, 0], v[:, :, 0])),
+                        blk, off, layer=ai)
                     a = paged_attention(q, k_pool, v_pool, table, attn_pos,
                                         layer=ai, scale=cfg.attn_scale)
             with jax.named_scope(after):
-                a = gate_heads(a.transpose(0, 2, 1, 3).reshape(B, 1, -1), g)
+                a = gate_heads(a.transpose(0, 2, 1, 3).reshape(B, R, -1), g)
                 a = a @ wmat(params, L + "wo", a.dtype)
             wi, ai = wi + win, ai + (not win)
         with jax.named_scope(after):
@@ -464,7 +486,8 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
             x = add_residual(x, norm_out(f, params[L + "mlp_norm"], cfg),
                              cfg).astype(cfg.dtype)
     with jax.named_scope("strom.head"):
-        x = rms_norm(x[:, 0], params["final_norm"], cfg.norm_eps)
+        x = rms_norm(x if blocks else x[:, 0], params["final_norm"],
+                     cfg.norm_eps)
     if state is not None:
         state = dict(state, s=tuple(s_pools), conv=tuple(tails))
         if ring:
@@ -506,16 +529,122 @@ def init_carried(cfg: TransformerConfig, rows: int, block_len: int = 128):
     return state
 
 
+#: the phase of a slot at one forward of a diffusion server, as the step
+#: reports it (``bd_select``'s ``info``): holding position (free, or past its last
+#: block), denoising, writing a finished block's clean K/V
+BD_HOLD, BD_DENOISE, BD_WRITE = 0, 1, 2
+
+
+def bd_state(cfg: TransformerConfig, slots: int) -> dict:
+    """What a diffusion server's step carries a slot beside ``pos`` (the
+    first position of the slot's current block) and ``tok`` (slots, Bl, the
+    block's tokens): ``masked`` (slots, Bl) — which of them are still to be
+    made; a FLAG, not the mask token's id, since an arg-max may be that id
+    and a commit must stay one —, ``cstep`` (slots, Bl) the denoising step
+    each was committed at (-1: given by the prompt), ``step`` the block's
+    denoising forwards so far, ``n0`` its masked positions at its start,
+    ``end`` the position the slot's last block ends before, and the
+    request's rule: ``T`` denoising steps a block (0: one position at
+    least a forward) and ``tau`` the confidence that commits by itself
+    (inf: none does)."""
+    Bl = cfg.diffusion_block
+    return {"masked": jnp.zeros((slots, Bl), bool),
+            "cstep": jnp.full((slots, Bl), -1, jnp.int32),
+            "step": jnp.zeros((slots,), jnp.int32),
+            "n0": jnp.zeros((slots,), jnp.int32),
+            "end": jnp.zeros((slots,), jnp.int32),
+            "T": jnp.ones((slots,), jnp.int32),
+            "tau": jnp.full((slots,), jnp.inf, jnp.float32)}
+
+
+def bd_select(logits, tok, pos, bd: dict, hold):
+    """The tail of a diffusion step: confidence, selection and every slot's
+    block state after the forward.  logits (B, R, vocab) f32 of the blocks
+    as they went in; ``hold`` (B,) bool the slots that did not take part.
+
+    A slot with a masked position DENOISES: at each masked position the
+    candidate is the arg-max token and its confidence that token's softmax
+    probability; the ``n`` most confident masked positions (ties to the
+    lower position) take their candidates, and so does every one whose
+    confidence passes ``tau`` — ``n`` is ``n0 // T`` and one more in the
+    first ``n0 % T`` steps, or 1 where ``T`` is 0.  A slot with nothing
+    masked has just written its clean block's K/V: the block is FINISHED,
+    and the slot moves on to the next, all masked.
+
+    Returns (info (B, 3 + 2 R) int32 — ``pos`` before the forward, the
+    phase (``BD_HOLD`` | ``BD_DENOISE`` | ``BD_WRITE``), the positions
+    committed by it, then the block's R tokens and their R commit steps
+    after it (a finished block's: as written) —, tok, pos, bd after)."""
+    B, R, _ = logits.shape
+    masked, cstep, step = bd["masked"], bd["cstep"], bd["step"]
+    with jax.named_scope("strom.bd.select"):
+        best = jnp.max(logits, axis=-1)
+        cand = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        conf = 1.0 / jnp.sum(jnp.exp(logits - best[..., None]), axis=-1)
+        conf = jnp.where(masked, conf, -1.0)
+        # rank among the slot's masked positions: more confident first,
+        # then the lower position
+        r = jnp.arange(R)
+        ahead = (conf[:, None, :] > conf[:, :, None]) | (
+            (conf[:, None, :] == conf[:, :, None])
+            & (r[None, None, :] < r[None, :, None]))
+        rank = jnp.sum(ahead & masked[:, None, :], axis=-1)
+        T = jnp.maximum(bd["T"], 1)
+        n = jnp.where(bd["T"] > 0,
+                      bd["n0"] // T + (step < bd["n0"] % T), 1)
+        unfinished = jnp.any(masked, axis=-1)
+        denoise, write = unfinished & ~hold, ~unfinished & ~hold
+        commit = masked & denoise[:, None] & (
+            (rank < n[:, None]) | (conf > bd["tau"][:, None]))
+        tok = jnp.where(commit, cand, tok)
+        cstep = jnp.where(commit, step[:, None], cstep)
+        info = jnp.concatenate([
+            pos[:, None],
+            (denoise * BD_DENOISE + write * BD_WRITE)[:, None],
+            jnp.sum(commit, axis=-1, dtype=jnp.int32)[:, None],
+            tok, cstep], axis=1).astype(jnp.int32)
+        w = write[:, None]
+        bd = dict(bd, masked=(masked & ~commit) | w,
+                  cstep=jnp.where(w, -1, cstep),
+                  step=jnp.where(write, 0, step + denoise),
+                  n0=jnp.where(write, R, bd["n0"]))
+        return info, tok, jnp.where(write, pos + R, pos), bd
+
+
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(3, 4, 12))
 def _paged_step(params: Dict, cfg: TransformerConfig, tok,
                 k_pool, v_pool, blk, off, table, pos, temps, top_ps,
-                seeds, state=None, sidx=None):
+                seeds, state=None, sidx=None, bd=None):
     """One decode step for every slot: ``paged_logits`` then the per-slot
     sampler.  tok, pos (B,) int32 → (next_tok (B,), k_pool, v_pool,
     state); ``state`` (donated, every layer's array updated in place) is
     None for a plain decoder.  Free slots compute too, but their writes
     land in the trash block (and the sacrificial state row) and the host
-    ignores their outputs — one compiled program for every batch mix."""
+    ignores their outputs — one compiled program for every batch mix.
+
+    With ``bd`` (``bd_state``; ``cfg.diffusion_block`` R) the same program
+    forwards R rows a slot — ``tok`` (B, R) the slot's current block,
+    ``pos`` its first position — and ends in ``bd_select`` in place of the
+    sampler.  The phase of a slot is data: it denoises, writes its finished
+    block or holds position (a free slot, told by ``blk``, the trash block;
+    a slot past its last block, ``pos >= bd["end"]``: both write to the
+    trash block, are routed nowhere and change nothing), so that k
+    sub-steps chain on the device without a readback.  The write target is
+    read off the table here (``blk`` says free or not, ``off`` is unread).
+    Returns (info (B, 3 + 2 R), k_pool, v_pool, state, tok, pos, bd)."""
+    if bd is not None:
+        with jax.named_scope("strom.embed"):
+            trash, bk = k_pool.shape[1] - 1, k_pool.shape[3]
+            hold = (blk == trash) | (pos >= bd["end"])
+            entry = jnp.minimum(pos // bk, table.shape[1] - 1)
+            blk = jnp.where(hold, trash, jnp.take_along_axis(
+                table, entry[:, None], axis=1)[:, 0])
+            rows = jnp.where(bd["masked"], cfg.mask_token_id, tok)
+        logits, k_pool, v_pool, state = paged_logits(
+            params, cfg, rows, k_pool, v_pool, blk, pos % bk, table, pos,
+            state, sidx)
+        info, tok, pos, bd = bd_select(logits, tok, pos, bd, hold)
+        return info, k_pool, v_pool, state, tok, pos, bd
     logits, k_pool, v_pool, state = paged_logits(
         params, cfg, tok, k_pool, v_pool, blk, off, table, pos, state, sidx)
     with jax.named_scope("strom.head"):
@@ -523,14 +652,50 @@ def _paged_step(params: Dict, cfg: TransformerConfig, tok,
     return nxt, k_pool, v_pool, state
 
 
+@jax.jit
+def _admit_blocks(pos, tok, bd, slots, starts, toks, given, end, T, tau):
+    """The tail of a diffusion group's admission, one program: the
+    server's per-slot arrays with the group's rows set at ``slots`` (b,) —
+    ``starts`` each prompt's first block that is not whole (where
+    generation begins), ``toks`` (b, R) that block as the prompt gives it
+    (its remainder, then anything), ``given`` how many of its rows that
+    is, ``end`` / ``T`` / ``tau`` as ``bd_state`` has them.  A row that
+    holds no prompt names a slot past the last: dropped.  An admission
+    yields no token.  Returns (pos, tok, bd)."""
+    def put(old, new):
+        return old.at[slots].set(new.astype(old.dtype), mode="drop")
+
+    R = toks.shape[1]
+    return put(pos, starts), put(tok, toks), {
+        "masked": put(bd["masked"], jnp.arange(R)[None, :] >= given[:, None]),
+        "cstep": put(bd["cstep"], jnp.full(toks.shape, -1)),
+        "step": put(bd["step"], jnp.zeros_like(given)),
+        "n0": put(bd["n0"], R - given), "end": put(bd["end"], end),
+        "T": put(bd["T"], T), "tau": put(bd["tau"], tau)}
+
+
 class DecodeServer:
     """Continuous batching over a SHARED block pool (paged attention).
 
     ``submit`` enqueues (optionally with per-request ``temperature``/
     ``top_p``/``seed`` — greedy by default); ``step`` admits waiting
-    requests into free slots, advances every active slot one token,
-    and returns requests that finished this step ({request_id: token
-    list}).  ``run`` drains everything.
+    requests into free slots, advances every active slot one forward —
+    one token, or, for a config that generates by diffusion over blocks
+    (``cfg.diffusion_block`` R), the R rows of the slot's current block:
+    0 to R tokens — and returns requests that finished this step
+    ({request_id: token list}).  ``run`` drains everything.
+
+    A diffusion config is served by the same two programs: the admission
+    forwards a prompt's whole blocks under the block-causal mask and
+    yields no token; a step forwards R rows a slot and commits the most
+    confident masked positions — ``cfg.diffusion_steps`` denoising
+    forwards a block under the static rule, every position whose
+    confidence passes ``cfg.diffusion_threshold`` (and one at least)
+    under the dynamic one, server-wide —, then once more the finished
+    block, clean, to write its K/V (``_paged_step``, ``_step_blocks``).
+    Greedy only; a finished request's ``request_metrics`` entry holds the
+    denoising step each of its tokens was committed at
+    (``"commit_steps"``, a byte a token).
 
     Capacity is ``total_blocks × block_len`` tokens across ALL slots —
     sized for expected live tokens, so short requests stop paying for the
@@ -572,6 +737,14 @@ class DecodeServer:
             raise ValueError(
                 f"kv_store.page_tokens ({kv_store.page_tokens}) must "
                 f"equal block_len ({block_len})")
+        #: rows a slot forwards a step: 1, or a diffusion config's block
+        self.R = cfg.diffusion_block or 1
+        if block_len % self.R or max_len % self.R:
+            # a diffusion block never straddles a pool block, and a budget
+            # rounded up to whole blocks stays inside max_len
+            raise ValueError(
+                f"block_len {block_len} and max_len {max_len} must be "
+                f"multiples of the config's diffusion_block {self.R}")
         self.block_len = block_len
         self.total_blocks = total_blocks
         self.max_blocks = -(-max_len // block_len)
@@ -603,6 +776,7 @@ class DecodeServer:
         self.B = max_batch
         self.max_len = max_len
         if kv_store is not None:
+            cfg.require_causal("a kv_store (PrefixStore)")
             cfg.require_kv_pages("a kv_store (PrefixStore)")
         if cfg.recurrent_layers and kv_store is not None:
             # pages without the state at their boundary are not a prefix,
@@ -645,6 +819,13 @@ class DecodeServer:
         self.kv_store = kv_store
         self.pos = jnp.zeros((max_batch,), jnp.int32)
         self.tok = jnp.zeros((max_batch,), jnp.int32)
+        #: a diffusion server's block state a slot (``bd_state``; ``tok`` is
+        #: then (slots, R), the slots' current blocks, and ``pos`` their
+        #: first positions), None for one that makes a token a step
+        self.bd = None
+        if cfg.diffusion_block:
+            self.tok = jnp.zeros((max_batch, self.R), jnp.int32)
+            self.bd = bd_state(cfg, max_batch)
         # per-slot decoding params (DATA, not shapes: any greedy/
         # sampled mix runs the same compiled step)
         self.temp = jnp.zeros((max_batch,), jnp.float32)
@@ -705,7 +886,14 @@ class DecodeServer:
         #: (experts with at least one row), ``moe_load_max`` (the
         #: busiest expert's rows) and ``moe_rounds`` (layouts the calls
         #: took: ``moe_calls`` unless a call's local pairs overflowed its
-        #: bounded layout, ``models/moe.pair_bound``) — sums over the calls
+        #: bounded layout, ``models/moe.pair_bound``) — sums over the calls.
+        #: A diffusion server counts its slot-forwards by phase, read off
+        #: the steps' reports at each readback (a slot that holds a request:
+        #: ``bd_forwards_denoise``, ``bd_forwards_write`` — a finished
+        #: block's clean K/V and no token —, ``bd_forwards_hold`` — past its
+        #: last block until the host retires it), ``bd_tokens`` (positions
+        #: committed) and ``bd_rows`` (rows forwarded by those slots, R a
+        #: forward)
         self.timings: Dict[str, float] = {
             "admit_s": 0.0, "dispatch_s": 0.0, "readback_s": 0.0,
             "steps": 0,
@@ -715,6 +903,8 @@ class DecodeServer:
             "prefill_programs": 0, "scan_tokens": 0,
             "attn_blocks_live": 0, "attn_blocks_table": 0,
             "attn_grid_steps": 0, "window_rows_live": 0,
+            "bd_forwards_denoise": 0, "bd_forwards_write": 0,
+            "bd_forwards_hold": 0, "bd_tokens": 0, "bd_rows": 0,
             **{key + sfx: 0 for sfx in ("", "_prefill") for key in (
                 "moe_calls", "moe_pairs", "moe_pairs_routed",
                 "moe_rows_computed", "moe_experts_touched",
@@ -912,6 +1102,10 @@ class DecodeServer:
                              f"{temperature}")
         if not 0.0 < top_p <= 1.0:
             raise ValueError(f"top_p must be in (0, 1], got {top_p}")
+        if temperature > 0:
+            self.cfg.require_causal("sampling (temperature > 0)")
+        # (a diffusion server generates whole blocks: max_len is a multiple
+        # of the block, so a budget that fits does so rounded up too)
         if len(prompt_ids) + max_new > self.max_len:
             raise ValueError(
                 f"prompt {len(prompt_ids)} + max_new {max_new} exceeds "
@@ -1040,7 +1234,10 @@ class DecodeServer:
             logits = self._admit_prefill(group, restored)
             for plan in group:
                 self._admit_finish(plan, restored)
-            self._admit_first(group, logits)
+            if self.bd is None:
+                self._admit_first(group, logits)
+            else:
+                self._admit_block_state(group, logits.shape[0])
         for req in traced[1:]:
             self._tracer().add_span(
                 "strom.serve.admit", t0_ns, time.monotonic_ns(),
@@ -1237,7 +1434,8 @@ class DecodeServer:
             if self.kv_store is not None:
                 self._store_put(req, slot, ct)
         self.slots[slot] = req
-        self._pos_h[slot] = len(req.prompt)
+        # (a diffusion server: where the prompt's last whole block ends)
+        self._pos_h[slot] = len(req.prompt) // self.R * self.R
 
     def _admit_first(self, group: list, logits) -> None:
         """The group's first tokens and decoding state, one program
@@ -1266,6 +1464,39 @@ class DecodeServer:
             t_admit = time.monotonic()
             for plan, tok in zip(group, first):
                 self._pending_first.append((plan["slot"], tok))
+                plan["req"].t_admit = t_admit
+
+    def _admit_block_state(self, group: list, b: int) -> None:
+        """A diffusion group's block state, one program (``_admit_blocks``)
+        and nothing read back: the admission has forwarded each prompt's
+        whole blocks under the block-causal mask and yields NO token — the
+        prompt's remainder joins the first block as given, the rest of it
+        masked, and the first token is that block's first commit.  The
+        server's ``diffusion_steps`` / ``diffusion_threshold`` ride as
+        per-slot data (``bd_state``)."""
+        import numpy as np
+        R, cfg = self.R, self.cfg
+        slots = np.full((b,), self.B, np.int32)     # a dead row: dropped
+        starts, given, end = (np.zeros((b,), np.int32) for _ in range(3))
+        toks = np.zeros((b, R), np.int32)
+        for i, plan in enumerate(group):
+            req = plan["req"]
+            P = len(req.prompt)
+            slots[i], starts[i], given[i] = plan["slot"], P // R * R, P % R
+            toks[i, :P % R] = req.prompt[P // R * R:]
+            end[i] = -(-(P + req.max_new) // R) * R
+        dynamic = cfg.diffusion_threshold > 0
+        T = np.full((b,), 0 if dynamic else cfg.diffusion_steps or R,
+                    np.int32)
+        tau = np.full((b,), cfg.diffusion_threshold if dynamic else np.inf,
+                      np.float32)
+        with self._span("strom.serve.first_token", rows=len(group),
+                        rid=_rids(group)):
+            self.pos, self.tok, self.bd = _admit_blocks(
+                self.pos, self.tok, self.bd, slots, starts, toks, given,
+                end, T, tau)
+            t_admit = time.monotonic()
+            for plan in group:
                 plan["req"].t_admit = t_admit
 
     def _store_put(self, req: _Request, slot: int, have: int) -> None:
@@ -1373,6 +1604,10 @@ class DecodeServer:
         self.request_metrics[req.rid] = {
             "ttft_ms": round(ttft_ms, 3),
             "admit_wait_ms": round(wait_ms, 3)}
+        if self.bd is not None:
+            # the denoising step each token of the answer was committed at,
+            # a byte a token
+            self.request_metrics[req.rid]["commit_steps"] = bytes(req.steps)
         while len(self.request_metrics) > self._metrics_keep:
             self.request_metrics.pop(next(iter(self.request_metrics)))
         agg = self._metrics_agg
@@ -1503,6 +1738,14 @@ class DecodeServer:
             self.state[key].nbytes // (self.B + 1)
             for key in ("wk", "wv")) if self.cfg.window_layers else 0
         out["window_rows_live"] = self.timings["window_rows_live"]
+        # generation by diffusion over blocks: the block's length (0: a
+        # token a step) and the tokens a slot-forward has committed so far
+        out["diffusion_block"] = self.cfg.diffusion_block
+        forwards = sum(self.timings["bd_forwards_" + phase]
+                       for phase in ("denoise", "write", "hold"))
+        out["bd_tokens_per_forward"] = (
+            round(self.timings["bd_tokens"] / forwards, 4) if forwards
+            else 0.0)
         if self.tenant_sheds:     # key appears only once tenancy acted
             out["tenant_sheds"] = dict(self.tenant_sheds)
         if self._draining:        # and these only once a drain began
@@ -1587,6 +1830,8 @@ class DecodeServer:
         ``pop`` removes exported sessions so the retiring server can
         reach ``idle`` — their results are now the replacement's to
         deliver."""
+        self.cfg.require_causal("export_sessions (a session inside a "
+                                "block would resume mid-denoising)")
         self.cfg.require_no_recurrent("export_sessions (the hand-off "
                                       "bundle holds K/V page keys only)")
         self.cfg.require_kv_pages("export_sessions (the hand-off bundle)")
@@ -1798,6 +2043,106 @@ class DecodeServer:
             self.seed, *recur)
         return nxt
 
+    def _step_blocks(self, k_steps: int, active_slots: List[int],
+                     finished: dict) -> dict:
+        """``_step_many`` past its admissions on a diffusion server: up to
+        ``k_steps`` forwards of R rows a slot back to back, ONE readback of
+        their reports (``bd_select``'s ``info``), and the host's replay.
+
+        A forward yields 0 to R tokens a slot, in no left-to-right order
+        inside the block, so what the host counts down is FORWARDS: a slot
+        has at most (blocks left) x (denoising steps + 1) to go, and the
+        batch is as long as the slot with most.  The device tells the
+        phases apart itself (``_paged_step``): a slot past its last block
+        holds position — it writes to the trash block and changes nothing —
+        so positions never pass ``ceil((prompt + max_new) / R) * R``, which
+        the admission's reservation ``ceil((prompt + max_new) / block)``
+        covers (R divides the block).  A finished block reaches
+        ``req.out`` whole, at the readback after its cache-writing forward,
+        cut at ``max_new`` and at an EOS; ``t_first`` is the first readback
+        that shows a commit."""
+        import numpy as np
+        R, bk = self.R, self.block_len
+        per_block = (R if self.cfg.diffusion_threshold > 0
+                     else min(self.cfg.diffusion_steps or R, R)) + 1
+        left = max(-(-(len(self.slots[b].prompt) + self.slots[b].max_new)
+                     // R) - self._pos_h[b] // R for b in active_slots)
+        k_eff = max(1, min(k_steps, left * per_block))
+        # an expert layer routes every slot that takes part; a free slot is
+        # told by its trash block (the step reads the others' off the table)
+        blk = jnp.asarray([0 if self.blocks[b] else self._trash
+                           for b in range(self.B)], jnp.int32)
+        infos = []
+        t0 = time.monotonic()
+        with self._span("strom.serve.dispatch", steps=k_eff, rows=R):
+            for _ in range(k_eff):
+                # (``state``: the expert layers' counters, donated; no layer
+                # of such a config keeps a row a slot, so ``sidx`` is unread)
+                (info, self.k_pool, self.v_pool, self.state, self.tok,
+                 self.pos, self.bd) = _paged_step(
+                    self.params, self.cfg, self.tok, self.k_pool,
+                    self.v_pool, blk, blk, self._table(), self.pos,
+                    self.temp, self.topp, self.seed, self.state, blk,
+                    bd=self.bd)
+                infos.append(info)
+        self.timings["dispatch_s"] += time.monotonic() - t0
+        t0 = time.monotonic()
+        with self._span("strom.serve.readback", steps=k_eff, first=0):
+            info_h, moe_h = jax.device_get((      # the ONE readback
+                infos, self.state.get("moe") if self.state else None))
+        self.timings["readback_s"] += time.monotonic() - t0
+        self.timings["steps"] += k_eff
+        if moe_h:
+            self._note_moe(moe_h, k_eff)
+        with self._span("strom.serve.replay") as replay_span:
+            t_now = time.monotonic()
+            info_h = np.stack(info_h)[:, active_slots]    # (k, slots, 3+2R)
+            pos, phase, new = (info_h[..., i] for i in range(3))
+            busy = phase != BD_HOLD
+            n_busy = int(busy.sum())
+            counts = {"bd_forwards_denoise": int((phase == BD_DENOISE).sum()),
+                      "bd_forwards_write": int((phase == BD_WRITE).sum()),
+                      "bd_forwards_hold": busy.size - n_busy,
+                      "bd_tokens": int(new.sum()),
+                      "bd_rows": R * n_busy,
+                      # the table entries each taking slot's walk reads, the
+                      # grid steps a layer's call makes of them (one for a
+                      # slot that holds or is free), the pairs routed
+                      "attn_blocks_live": int(
+                          ((pos + R - 1) // bk + 1)[busy].sum()),
+                      "attn_blocks_table": k_eff * self.B * self.max_blocks,
+                      "moe_pairs_routed": R * n_busy
+                      * self.cfg.expert_top_k * len(self.cfg.expert_layers)}
+            counts["attn_grid_steps"] = (counts["attn_blocks_live"]
+                                         + k_eff * self.B - n_busy)
+            for key, n in counts.items():
+                self.timings[key] += n
+            for i, slot in enumerate(active_slots):
+                req = self.slots[slot]
+                if req.t_first is None and new[:, i].any():
+                    req.t_first = t_now     # first commit DELIVERED
+                P = len(req.prompt)
+                for j in np.nonzero(phase[:, i] == BD_WRITE)[0]:
+                    if self.slots[slot] is None:
+                        break       # retired at an earlier sub-step: its
+                                    # surplus blocks are discarded
+                    at = int(pos[j, i])
+                    self._pos_h[slot] = at + R
+                    for r in range(max(P - at, 0), R):
+                        req.out.append(int(info_h[j, i, 3 + r]))
+                        req.steps.append(int(info_h[j, i, 3 + R + r]))
+                        ret = self._retire_or_keep(slot)
+                        if ret:
+                            finished[ret[0]] = ret[1]
+                            break
+            replay_span.set_metadata(
+                finished=len(finished), tokens=counts["bd_tokens"],
+                rows=counts["bd_rows"],
+                denoise=counts["bd_forwards_denoise"],
+                write=counts["bd_forwards_write"],
+                hold=counts["bd_forwards_hold"])
+        return finished
+
     def _note_moe(self, moe_h: dict, steps: int) -> None:
         """The expert layers' device counters, as read back with a batch's
         tokens, into ``timings`` and ``moe_load``: what was added since the
@@ -1840,7 +2185,9 @@ class DecodeServer:
 
     def step_many(self, k_steps: int) -> Dict[object, List[int]]:
         """Admit → up to ``k_steps`` batched decode steps → ONE host
-        readback → retire finished.
+        readback → retire finished.  (A step is a forward of every active
+        slot: one token a slot, or on a diffusion server R rows a slot and
+        0 to R tokens — ``_step_blocks`` has what differs there.)
 
         The lookahead exists for high-latency links: the round-3
         on-silicon row served 43.6 tok/s against a 6,826 tok/s decode
@@ -1899,6 +2246,8 @@ class DecodeServer:
                             if r is not None]
             if not active_slots:
                 return finished
+            if self.bd is not None:
+                return self._step_blocks(k_steps, active_slots, finished)
             # steps each slot may still take: positions must never pass
             # the s + max_new blocks admission reserved.  A deferred
             # first token counts against max_new; a first-token EOS

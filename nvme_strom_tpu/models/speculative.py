@@ -124,6 +124,8 @@ def _validate_spec(max_new_tokens: int, k: int, b: int) -> None:
 def _setup_caches(draft_params, target_params, prompt, cfg, dcfg,
                   max_new_tokens: int, k: int, st: SpecStats):
     """Prefill both models → (target logits, t_cache, d_cache)."""
+    for c in (cfg, dcfg):
+        c.require_causal("speculative decoding (models/speculative.py)")
     b, s = prompt.shape
     cap = s + max_new_tokens + k + 1
     t_cache = _dec.init_cache(cfg, b, cap)

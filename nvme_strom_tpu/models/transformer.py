@@ -175,6 +175,22 @@ class TransformerConfig:
     post_norm: bool = False
     qk_norm_whole: bool = False
     gdn_neg_eigval: bool = False
+    # Generation by diffusion over blocks (``diffusion_block`` Bl; 0: none,
+    # tokens are made one a step, left to right).  The attention mask is
+    # BLOCK-causal: position i sees position j iff j // Bl <= i // Bl,
+    # blocks counted from position 0 — its whole block, the later rows of
+    # it too, and every earlier block (``decode.block_step``).  A serving
+    # step forwards the Bl rows of a slot's current block, the still-masked
+    # ones as ``mask_token_id``, and commits the most confident
+    # (``serving._paged_step``).  ``diffusion_steps`` T and
+    # ``diffusion_threshold`` tau are a server's defaults, not the model's:
+    # a block's masked positions are committed over T denoising forwards
+    # (0: one a position), the most confident first; with tau > 0 every
+    # position whose confidence passes it, and at least one a forward.
+    diffusion_block: int = 0
+    mask_token_id: int = 0
+    diffusion_steps: int = 0
+    diffusion_threshold: float = 0.0
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -224,6 +240,16 @@ class TransformerConfig:
             raise ValueError(
                 f"{self.experts_held} experts from {self.expert_offset} on "
                 f"held of {self.n_experts} routed")
+        if self.diffusion_block and (
+                self.diffusion_block < 1 or kinds or self.latent
+                or not 0 <= self.mask_token_id < self.vocab
+                or self.diffusion_steps < 0
+                or not 0.0 <= self.diffusion_threshold < 1.0):
+            raise ValueError(
+                "generation by diffusion needs diffusion_block >= 1, "
+                "K/V attention in every layer (no layer_kinds, no "
+                "kv_lora_rank), a mask_token_id inside the vocabulary, "
+                "diffusion_steps >= 0 and diffusion_threshold in [0, 1)")
         if self.latent and (kinds or not (
                 self.q_lora_rank and self.qk_nope_dim and self.qk_rope_dim
                 and self.v_head_dim and self.attn_scale)):
@@ -396,6 +422,18 @@ class TransformerConfig:
                 f"whole projection (qk_norm_whole {self.qk_norm_whole}): "
                 f"serve it from DecodeServer on one device, or run "
                 f"transformer.forward")
+
+    def require_causal(self, what: str) -> None:
+        """The one message of everything that makes tokens one a step, left
+        to right, under the causal mask."""
+        if self.diffusion_block:
+            raise NotImplementedError(
+                f"{what} is causal and makes one token a step; this config "
+                f"generates by diffusion over blocks of "
+                f"{self.diffusion_block} under a block-causal mask "
+                f"(diffusion_block): serve it from DecodeServer on one "
+                f"device, without a kv_store, a mesh, session hand-off, "
+                f"speculative decoding or a training step")
 
     def require_no_recurrent(self, what: str) -> None:
         """The one message of everything that holds K/V pages only."""
@@ -985,6 +1023,7 @@ def attention(x, p, prefix, cfg: TransformerConfig, attn_fn=None,
                 "latent attention has its own inner block (models/mla.py)")
         from nvme_strom_tpu.models import mla
         return mla.self_attention(x, p, prefix, cfg, positions)
+    cfg.require_causal("the training path (transformer.attention)")
     cfg.require_kv_pages("the training path (transformer.attention)")
     # no rotary, or a config's own score scale: only qkv_project and
     # dense_causal_attention know them

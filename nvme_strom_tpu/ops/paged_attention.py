@@ -192,8 +192,12 @@ def _paged_kernel(slot_ref, blk_ref, start_ref, pos_ref, q_ref, k_ref, v_ref,
 def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
                     scale=None, window: int = 0, sink=None,
                     interpret: bool = None):
-    """q (b, n_heads, 1, d) attends to its block-table history in one
-    layer of the pool.
+    """q (b, n_heads, R, d) attends to its block-table history in one
+    layer of the pool: R = 1, a decode step's one row a slot, or the R
+    rows of a slot's current diffusion block, which ALL see the slot's
+    history up to ``pos`` (no mask among them) — they ride as R times the
+    query heads of each KV group over one walk of the slot's blocks with
+    one limit, so a pool block is fetched once for all of them.
 
     k_pool/v_pool (n_layers, n_blocks, n_kv_heads, block_k, d): the shared
     pool of EVERY layer, read where it lies; ``layer`` (static) picks the
@@ -203,7 +207,7 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
     ``table[b, 0] .. table[b, pos[b] // block_k]``; the entries past that
     are never dereferenced.  pos (b,) int32: index of slot b's newest
     entry in its OWN coordinate space (block j covers positions
-    [j·block_k, (j+1)·block_k)).
+    [j·block_k, (j+1)·block_k)) — with R rows, the last of them.
 
     ``window`` w (static; the kernel is then ``strom_window_attn``): the
     slot sees its last w rows only, ``pos - w < j <= pos``, and the table is
@@ -213,11 +217,14 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
     ``sink`` (n_heads,) or None: a learned score per head that joins the
     softmax as one more column and carries no value.
 
-    Returns (b, n_heads, 1, dv).  ``interpret`` defaults to True off-TPU.
+    Returns (b, n_heads, R, dv).  ``interpret`` defaults to True off-TPU.
     """
-    if q.ndim != 4 or q.shape[2] != 1:
-        raise ValueError(f"expected q (b, h, 1, d), got {q.shape}")
-    b, nh, _, d = q.shape
+    if q.ndim != 4:
+        raise ValueError(f"expected q (b, h, rows, d), got {q.shape}")
+    b, nh, rows, d = q.shape
+    if rows != 1 and (window or sink is not None):
+        raise ValueError(f"a window or a sink takes one query row a slot, "
+                         f"got q {q.shape}")
     if (k_pool.ndim != 5 or v_pool.shape[:-1] != k_pool.shape[:-1]
             or k_pool.shape[-1] != d):
         raise ValueError("expected pools (layers, blocks, kv_heads, "
@@ -233,7 +240,8 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
     if table.shape[0] != b or table.ndim != 2:
         raise ValueError(f"table must be ({b}, max_blocks), "
                          f"got {table.shape}")
-    g = nh // nkv
+    # a KV head's query rows: its group's heads, each with its R rows
+    g = nh // nkv * rows
     max_blocks = table.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(d)
@@ -286,12 +294,13 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
         name="strom_window_attn" if window else "strom_paged_attn",
         interpret=_interpret(interpret),
     )(slot, blocks, start, pos, *args)
-    return out.reshape(b, nh, 1, dv)
+    return out.reshape(b, nh, rows, dv)
 
 
 def _write_kernel(blk_ref, off_ref, kn_ref, vn_ref, kp_ref, vp_ref,
-                  ko_ref, vo_ref, *, tiles, toks):
-    """One slot: its tile of each pool with the slot's row replaced
+                  ko_ref, vo_ref, *, tiles, toks, rows=1):
+    """One slot: its tile of each pool with the slot's row replaced — or
+    its ``rows`` rows from ``off`` on, which lie in one tile
     (``tiles`` / ``toks``: K's and V's tile length and whether it lies
     tokens-on-lanes).  The select runs in float32 (exact both ways for
     bf16), where every shape of broadcast and compare is at home."""
@@ -302,11 +311,17 @@ def _write_kernel(blk_ref, off_ref, kn_ref, vn_ref, kp_ref, vp_ref,
         if tile not in ats:
             ats[tile] = off_ref[bi] % tile
         have = old[...].astype(jnp.float32)      # (nkv, tile, d) | (nkv, d, tile)
-        hit = (jax.lax.broadcasted_iota(jnp.int32, have.shape, 1 + tok)
-               == ats[tile])
-        row = (new[...].astype(jnp.float32) if tok     # (nkv, d, tile), or
-               else new[:, pl.ds(bi, 1), :])           # (nkv, 1, d) of (nkv, b, d)
-        out[...] = jnp.where(hit, row, have).astype(out.dtype)
+        at = jax.lax.broadcasted_iota(jnp.int32, have.shape, 1 + tok)
+        if rows == 1:
+            hit = at == ats[tile]
+            row = (new[...].astype(jnp.float32) if tok  # (nkv, d, tile), or
+                   else new[:, pl.ds(bi, 1), :])        # (nkv, 1, d) of (nkv, b, d)
+            out[...] = jnp.where(hit, row, have).astype(out.dtype)
+            continue
+        for r in range(rows):               # (nkv, 1, d) of (nkv, b * rows, d)
+            have = jnp.where(at == ats[tile] + r,
+                             new[:, pl.ds(bi * rows + r, 1), :], have)
+        out[...] = have.astype(out.dtype)
 
 
 def _row_specs(pool, new, layer: int):
@@ -316,6 +331,23 @@ def _row_specs(pool, new, layer: int):
     _, _, nkv, block, d = pool.shape
     b = new.shape[0]
     new = new.astype(pool.dtype)
+    if new.ndim == 4:
+        # R rows a slot (b, nkv, R, d), kv-head-major as the projections
+        # emit them: slot b's row r is row b * R + r of (nkv, b * R, d)
+        rows = new.shape[2]
+        tile = min(block, 8 * 4 // pool.dtype.itemsize)
+        if _tokens_on_lanes(pool.shape) or tile % rows:
+            raise NotImplementedError(
+                f"{rows} rows a slot into a pool {pool.shape}: they must "
+                f"divide its sublane tile of {tile} tokens, the head's "
+                f"features along the lanes")
+        return (new.transpose(1, 0, 2, 3).reshape(nkv, b * rows, d)
+                .astype(jnp.float32),
+                pl.BlockSpec((nkv, b * rows, d), lambda bi, bl, of: (0, 0, 0)),
+                pl.BlockSpec(
+                    (None, None, nkv, tile, d),
+                    lambda bi, bl, of: (layer, bl[bi], 0, of[bi] // tile, 0)),
+                tile, 0)
     if _tokens_on_lanes(pool.shape):
         # tokens along the lanes: the tile is a lane row of them, and each
         # slot's new row comes in already spread along it
@@ -343,7 +375,11 @@ def _row_specs(pool, new, layer: int):
 def write_rows(k_pool, v_pool, k_new, v_new, blk, off, *, layer: int,
                name: str = "strom_kv_write", interpret: bool = None):
     """Place one new K and V row per slot in layer ``layer`` of the pools,
-    IN the pools: ``pool[layer, blk[b], :, off[b], :] = new[b]``.
+    IN the pools: ``pool[layer, blk[b], :, off[b], :] = new[b]`` — or, with
+    k_new/v_new (b, kv_heads, R, d), a slot's R rows of one diffusion block:
+    ``pool[layer, blk[b], :, off[b] + r, :] = new[b, :, r]``, ``off`` a
+    multiple of R and R a divisor of the pool's sublane tile, so that the R
+    rows lie in the one tile the slot's grid step patches.
 
     k_pool/v_pool (layers, blocks, kv_heads, block, d) — V's ``d`` may
     differ from K's, and each pool is handed over in the layout the device
@@ -364,18 +400,20 @@ def write_rows(k_pool, v_pool, k_new, v_new, blk, off, *, layer: int,
         raise ValueError(f"layer {layer} of pools {k_pool.shape}, "
                          f"{v_pool.shape}")
     b = k_new.shape[0]
-    if (k_new.shape != (b, nkv, d)
-            or v_new.shape != (b, nkv, v_pool.shape[-1])):
-        raise ValueError(f"expected new rows ({b}, {nkv}, {d} | "
+    rows = k_new.shape[2:-1]             # () or (R,)
+    if (k_new.shape != (b, nkv) + rows + (d,)
+            or v_new.shape != (b, nkv) + rows + (v_pool.shape[-1],)):
+        raise ValueError(f"expected new rows ({b}, {nkv}, [R,] {d} | "
                          f"{v_pool.shape[-1]}), got {k_new.shape}, "
                          f"{v_new.shape}")
+    extra = {"rows": rows[0]} if rows else {}
     (k_new, kn_spec, kt_spec, k_tile, k_tok), \
         (v_new, vn_spec, vt_spec, v_tile, v_tok) = (
             _row_specs(k_pool, k_new, layer), _row_specs(v_pool, v_new, layer))
     kv, vv = _kernel_view(k_pool, k_tok), _kernel_view(v_pool, v_tok)
     kv, vv = pl.pallas_call(
         functools.partial(_write_kernel, tiles=(k_tile, v_tile),
-                          toks=(k_tok, v_tok)),
+                          toks=(k_tok, v_tok), **extra),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b,),
             in_specs=[kn_spec, vn_spec, kt_spec, vt_spec],

@@ -18,6 +18,7 @@ from nvme_strom_tpu.models.transformer import TransformerConfig
 
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, P]:
+    cfg.require_causal("a mesh (parallel/shardings.param_specs)")
     cfg.require_no_recurrent("a mesh (parallel/shardings.param_specs)")
     cfg.require_kv_pages("a mesh (parallel/shardings.param_specs)")
     cfg.require_pre_norm("a mesh (parallel/shardings.param_specs)")

@@ -90,6 +90,8 @@ _LAYER_RULES: Tuple[Tuple[str, str, bool], ...] = (
     ("self_attn.kv_a_proj_with_mqa.weight", "wkv_a", True),
     ("self_attn.kv_a_layernorm.weight", "kv_a_norm", False),
     ("self_attn.kv_b_proj.weight", "wkv_b", True),
+    # (``mlp.gate.weight`` and ``mlp.experts.E.*_proj.weight`` are Qwen3-MoE's
+    # too, which sdar_moe is ASSUMED to keep: it ships its own modelling file)
     ("mlp.gate.weight", "router", True),
     ("mlp.gate.e_score_correction_bias", "router_bias", False),
     ("mlp.shared_experts.gate_proj.weight", "shared_w_gate", True),
@@ -540,12 +542,64 @@ def _olmo_hybrid_fields(hf_cfg: dict) -> dict:
     return out
 
 
+#: what an ``sdar_moe`` file may leave unsaid (``_sdar_fields``)
+SDAR_BLOCK_LENGTH, SDAR_MASK_TOKEN_ID = 4, 151669
+
+
+def _sdar_fields(hf_cfg: dict) -> dict:
+    """The TransformerConfig fields of an ``sdar_moe`` config (SDAR-MoE: a
+    Qwen3-MoE decoder — a stated head width, q/k norms a head at a time,
+    softmax-routed experts in every layer with the chosen weights
+    renormalised, no shared expert — that generates by diffusion over
+    blocks under a block-causal mask), beyond the dense family's.  The
+    published config states neither the block length nor the mask token:
+    a file gives them, and a server's denoising steps and commit rule,
+    under ``serving.diffusion`` (``block_length``, ``mask_token_id``,
+    ``denoising_steps``, ``remasking``, ``threshold``); absent, the block
+    is ``SDAR_BLOCK_LENGTH`` long, the released default of the family's
+    ``-Chat`` checkpoints, and the mask ``SDAR_MASK_TOKEN_ID`` (both
+    ASSUMED: no checkpoint was there to check).  Raises on what the model
+    does not implement."""
+    def refuse(what):
+        raise ValueError(f"unsupported sdar_moe config: {what}")
+    if hf_cfg.get("decoder_sparse_step", 1) != 1 or hf_cfg.get(
+            "mlp_only_layers"):
+        refuse("decoder_sparse_step != 1 or mlp_only_layers (every layer "
+               "holds experts)")
+    if hf_cfg.get("use_sliding_window") or hf_cfg.get("sliding_window"):
+        refuse("a sliding window")
+    if hf_cfg.get("rope_scaling"):
+        refuse(f"rope_scaling {hf_cfg['rope_scaling']}")
+    bd = (hf_cfg.get("serving") or {}).get("diffusion") or {}
+    rule = bd.get("remasking", "low_confidence_static")
+    if rule not in ("low_confidence_static", "low_confidence_dynamic"):
+        refuse(f"remasking {rule!r} (low_confidence_static | "
+               "low_confidence_dynamic)")
+    dynamic = rule == "low_confidence_dynamic"
+    if dynamic and not 0.0 < bd.get("threshold", 0.0) < 1.0:
+        refuse("low_confidence_dynamic needs a threshold in (0, 1)")
+    return dict(
+        mlp_kinds=("experts",) * hf_cfg["num_hidden_layers"],
+        qk_head_dim=hf_cfg["head_dim"], qk_norm=True, rope_scaling=None,
+        n_experts=hf_cfg["num_experts"],
+        expert_top_k=hf_cfg["num_experts_per_tok"],
+        d_expert=hf_cfg["moe_intermediate_size"],
+        router_kind="softmax", router_bias=False,
+        router_norm_topk=bool(hf_cfg.get("norm_topk_prob", True)),
+        tie_embed=bool(hf_cfg.get("tie_word_embeddings", False)),
+        diffusion_block=int(bd.get("block_length", SDAR_BLOCK_LENGTH)),
+        mask_token_id=int(bd.get("mask_token_id", SDAR_MASK_TOKEN_ID)),
+        diffusion_steps=0 if dynamic else int(bd.get("denoising_steps", 0)),
+        diffusion_threshold=float(bd["threshold"]) if dynamic else 0.0)
+
+
 def config_from_hf(hf_cfg: dict):
     """HF ``config.json`` → TransformerConfig: the dense Llama family,
     ``model_type`` granitemoehybrid (``_hybrid_fields``), ``lfm2`` /
     ``lfm2_moe`` (``_lfm2_fields``), ``deepseek_v3`` / ``kimi_k2``
     (``_mla_fields``), ``mimo_v2`` (``_mimo_fields``), ``qwen3_next``
-    (``_qwen3_next_fields``) and ``olmo_hybrid`` (``_olmo_hybrid_fields``).
+    (``_qwen3_next_fields``), ``olmo_hybrid`` (``_olmo_hybrid_fields``) and
+    ``sdar_moe`` (``_sdar_fields``).
 
     Raises on architecture knobs the model does not implement — silently
     ignoring them (e.g. a non-SiLU activation) would convert into a model
@@ -568,6 +622,7 @@ def config_from_hf(hf_cfg: dict):
               else _qwen3_next_fields(hf_cfg) if model_type == "qwen3_next"
               else _olmo_hybrid_fields(hf_cfg)
               if model_type == "olmo_hybrid"
+              else _sdar_fields(hf_cfg) if model_type == "sdar_moe"
               else {})
     derived_hd = hf_cfg["hidden_size"] // hf_cfg["num_attention_heads"]
     if hf_cfg["hidden_size"] % hf_cfg["num_attention_heads"]:
@@ -678,6 +733,10 @@ def strom_config_dict(cfg) -> dict:
             "qk_head_dim", "v_head_dim", "rotary_dim", "value_scale",
             "window", "window_kv_heads", "window_rope_theta", "window_sink",
             "tie_embed", "attn_gate", "qk_norm")})
+    if cfg.diffusion_block:
+        out.update({k: getattr(cfg, k) for k in (
+            "diffusion_block", "mask_token_id", "diffusion_steps",
+            "diffusion_threshold")})
     return out
 
 

@@ -107,3 +107,32 @@ def dot_census(lowered):
     bad = [(a, b) for a, b in dots
            if not (a.endswith("bf16") and b.endswith("bf16"))]
     return dots, bad
+
+
+def within(seconds):
+    """Per-test timeout (no pytest-timeout here): the body runs on a
+    thread of its own; one that has not ended in ``seconds`` fails the
+    test instead of hanging the run."""
+    import functools
+    import threading
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            box = []
+
+            def body():
+                try:
+                    fn(*args, **kwargs)
+                except BaseException as e:
+                    box.append(e)
+
+            t = threading.Thread(target=body, daemon=True,
+                                 name="test-body")
+            t.start()
+            t.join(seconds)
+            assert not t.is_alive(), f"{fn.__name__}: over {seconds}s"
+            if box:
+                raise box[0]
+        return wrapper
+    return deco

@@ -423,33 +423,7 @@ def test_tpu_transfer_failure_raises_instead_of_degrading(
 # the devices (PERF.md §3, §6 PR 45)
 # ---------------------------------------------------------------------------
 
-def _within(seconds):
-    """Per-test timeout (no pytest-timeout here): the body runs on a
-    thread of its own; one that has not ended in ``seconds`` fails the
-    test instead of hanging the run."""
-    import functools
-    import threading
-
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            box = []
-
-            def body():
-                try:
-                    fn(*args, **kwargs)
-                except BaseException as e:
-                    box.append(e)
-
-            t = threading.Thread(target=body, daemon=True,
-                                 name="test-body")
-            t.start()
-            t.join(seconds)
-            assert not t.is_alive(), f"{fn.__name__}: over {seconds}s"
-            if box:
-                raise box[0]
-        return wrapper
-    return deco
+from conftest import within as _within     # noqa: E402
 
 
 def _stage_threads():

@@ -82,9 +82,14 @@ def test_validation():
         paged_attention(q, kp, kp, jnp.zeros((3, 2), jnp.int32),
                         jnp.zeros((2,), jnp.int32))
     with pytest.raises(ValueError, match="q"):
-        paged_attention(jnp.zeros((2, 4, 2, 8)), kp, kp,
+        paged_attention(jnp.zeros((2, 4, 8)), kp, kp,
                         jnp.zeros((2, 2), jnp.int32),
                         jnp.zeros((2,), jnp.int32))
+    # several query rows a slot share one limit: not under a window
+    with pytest.raises(ValueError, match="one query row a slot"):
+        paged_attention(jnp.zeros((2, 4, 2, 8)), kp, kp,
+                        jnp.zeros((2, 2), jnp.int32),
+                        jnp.zeros((2,), jnp.int32), window=4)
     with pytest.raises(ValueError, match="pools"):       # a layer's slice
         paged_attention(q, kp[0], kp[0], jnp.zeros((2, 2), jnp.int32),
                         jnp.zeros((2,), jnp.int32))
