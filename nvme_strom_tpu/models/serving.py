@@ -357,12 +357,17 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     v_pool, state) — ``state`` the recurrent layers' pool after the step,
     None for a plain decoder.
 
-    ``tok`` (B,) is one row a slot at ``pos``.  ``tok`` (B, R) is R rows a
-    slot, the slot's current diffusion block at positions ``pos .. pos + R -
-    1`` (``cfg.diffusion_block``): their K and V go where they will lie (``blk``
-    / ``off`` name the FIRST row's place; a block never straddles a pool
-    block), every one of them sees the history up to ``pos + R - 1`` — its
-    whole block — and the logits are (B, R, vocab).
+    ``tok`` (B,) is one row a slot at ``pos``.  ``tok`` (B, 2 R) is two
+    diffusion blocks a slot (``cfg.diffusion_block`` R; ``bd_rows``): the
+    block the slot has just finished, clean, at ``pos - R .. pos - 1`` and
+    its current block at ``pos .. pos + R - 1``.  Each half's K and V go
+    where they will lie — ``blk`` / ``off`` are then (B, 2) and name each
+    half's FIRST row's place (a block never straddles a pool block, the two
+    may lie in two) —, every row sees the history up to the end of its OWN
+    block (``paged_attention``'s ``lag``), and the logits are the current
+    block's: (B, R, vocab).  A half aimed at the trash block is dead — routed
+    to no expert, its output unread: the first where the slot owes no
+    finished block, both where it holds position or is free.
 
     blk/off (B,) int32: each slot's write target (block id in the pool,
     row offset inside it); table (B, max_blocks) int32 + pos (B,) feed
@@ -377,12 +382,14 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
     from nvme_strom_tpu.ops.paged_attention import (paged_attention,
                                                     write_rows)
     B = tok.shape[0]
-    blocks = tok.ndim == 2              # R rows a slot (R may be 1)
-    R = tok.shape[1] if blocks else 1
+    blocks = tok.ndim == 2              # two blocks of R rows a slot
+    rows = tok.shape[1] if blocks else 1
+    R = rows // 2
     bk = k_pool.shape[-1] if cfg.latent else k_pool.shape[3]
     ring = ring_blocks(cfg, bk)
     with jax.named_scope("strom.embed"):
-        free = blk == k_pool.shape[1] - 1
+        dead = blk == k_pool.shape[1] - 1       # (B,), or (B, 2) a half
+        free = dead[:, 1] if blocks else dead
         # a free slot keeps its last pos over a table row of zeros; nobody
         # reads its output, so to the kernel its history is one row
         attn_pos = jnp.where(free, 0, pos)
@@ -391,13 +398,14 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
             x = embed_tokens(params, cfg, tok[:, None])       # (B,1,d)
             positions = pos.astype(jnp.float32)[:, None]      # (B,1)
         else:
-            # every row of a block sees the whole block
+            # every row of a block sees its whole block: the current one
+            # up to pos + R - 1, the finished one R short of that
             attn_pos = jnp.where(free, 0, pos + R - 1)
             if cfg.expert_layers:
-                live = jnp.broadcast_to(live, (B, R))
-            x = embed_tokens(params, cfg, tok)                # (B,R,d)
-            positions = (pos[:, None]
-                         + jnp.arange(R)).astype(jnp.float32)  # (B,R)
+                live = jnp.repeat(~dead, R, axis=1)           # (B,2R)
+            x = embed_tokens(params, cfg, tok)                # (B,2R,d)
+            positions = (pos[:, None] - R
+                         + jnp.arange(rows)).astype(jnp.float32)  # (B,2R)
         if ring:
             # a window layer's cache: the slot's own ring of ``ring`` blocks
             # (row sidx of the rings; a free slot's is the sacrificial last),
@@ -466,15 +474,27 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
                         q, wk_pool, wv_pool, ring_table, attn_pos, layer=wi,
                         scale=cfg.attn_scale, window=cfg.window,
                         sink=params.get(L + "sink"))
+                elif blocks:
+                    # a call a half: the finished block's tile and the
+                    # current one's may be one, and a grid step patches
+                    # the tile as it was before the call
+                    for j in range(2):
+                        k_pool, v_pool = write_rows(
+                            k_pool, v_pool, k[:, :, j * R:(j + 1) * R],
+                            v[:, :, j * R:(j + 1) * R], blk[:, j],
+                            off[:, j], layer=ai)
+                    a = paged_attention(q, k_pool, v_pool, table, attn_pos,
+                                        layer=ai, scale=cfg.attn_scale,
+                                        lag=R)
                 else:
                     k_pool, v_pool = write_rows(
-                        k_pool, v_pool, *((k, v) if blocks else
-                                          (k[:, :, 0], v[:, :, 0])),
-                        blk, off, layer=ai)
+                        k_pool, v_pool, k[:, :, 0], v[:, :, 0], blk, off,
+                        layer=ai)
                     a = paged_attention(q, k_pool, v_pool, table, attn_pos,
                                         layer=ai, scale=cfg.attn_scale)
             with jax.named_scope(after):
-                a = gate_heads(a.transpose(0, 2, 1, 3).reshape(B, R, -1), g)
+                a = gate_heads(a.transpose(0, 2, 1, 3).reshape(B, rows, -1),
+                               g)
                 a = a @ wmat(params, L + "wo", a.dtype)
             wi, ai = wi + win, ai + (not win)
         with jax.named_scope(after):
@@ -486,7 +506,8 @@ def paged_logits(params: Dict, cfg: TransformerConfig, tok,
             x = add_residual(x, norm_out(f, params[L + "mlp_norm"], cfg),
                              cfg).astype(cfg.dtype)
     with jax.named_scope("strom.head"):
-        x = rms_norm(x if blocks else x[:, 0], params["final_norm"],
+        # (the finished block's rows were forwarded for their K/V alone)
+        x = rms_norm(x[:, R:] if blocks else x[:, 0], params["final_norm"],
                      cfg.norm_eps)
     if state is not None:
         state = dict(state, s=tuple(s_pools), conv=tuple(tails))
@@ -530,9 +551,14 @@ def init_carried(cfg: TransformerConfig, rows: int, block_len: int = 128):
 
 
 #: the phase of a slot at one forward of a diffusion server, as the step
-#: reports it (``bd_select``'s ``info``): holding position (free, or past its last
-#: block), denoising, writing a finished block's clean K/V
-BD_HOLD, BD_DENOISE, BD_WRITE = 0, 1, 2
+#: reports it (``bd_select``'s ``info``): holding position (free, or past its
+#: last block) or denoising.  No forward only WRITES a finished block's K/V:
+#: those rows ride the next block's first denoising forward (``info``'s
+#: ``fused`` flag), and a request's last block is never written — nothing
+#: reads it
+BD_HOLD, BD_DENOISE = 0, 1
+#: ``info``'s columns before the block's R tokens and R commit steps
+BD_INFO = 5
 
 
 def bd_state(cfg: TransformerConfig, slots: int) -> dict:
@@ -543,38 +569,67 @@ def bd_state(cfg: TransformerConfig, slots: int) -> dict:
     and a commit must stay one —, ``cstep`` (slots, Bl) the denoising step
     each was committed at (-1: given by the prompt), ``step`` the block's
     denoising forwards so far, ``n0`` its masked positions at its start,
-    ``end`` the position the slot's last block ends before, and the
-    request's rule: ``T`` denoising steps a block (0: one position at
-    least a forward) and ``tau`` the confidence that commits by itself
-    (inf: none does)."""
+    ``prev`` (slots, Bl) the block the slot finished last and ``pending``
+    whether that block's clean K/V are still owed to the cache (an
+    admission owes none: the prefill wrote the prompt's blocks), ``end`` the
+    position the slot's last block ends before, and the request's rule:
+    ``T`` denoising steps a block (0: one position at least a forward) and
+    ``tau`` the confidence that commits by itself (inf: none does)."""
     Bl = cfg.diffusion_block
     return {"masked": jnp.zeros((slots, Bl), bool),
             "cstep": jnp.full((slots, Bl), -1, jnp.int32),
             "step": jnp.zeros((slots,), jnp.int32),
             "n0": jnp.zeros((slots,), jnp.int32),
+            "prev": jnp.zeros((slots, Bl), jnp.int32),
+            "pending": jnp.zeros((slots,), bool),
             "end": jnp.zeros((slots,), jnp.int32),
             "T": jnp.ones((slots,), jnp.int32),
             "tau": jnp.full((slots,), jnp.inf, jnp.float32)}
 
 
+def bd_rows(cfg: TransformerConfig, tok, pos, bd: dict, hold, table,
+            trash: int, bk: int):
+    """What a diffusion step forwards, from the slots' state: (rows (B, 2 R)
+    — the block each slot finished last, clean, then its current block with
+    the mask token at its masked positions —, blk (B, 2), off (B, 2): where
+    each half's K/V rows go).  The current block's go to the table's entry
+    of ``pos``, the finished one's to that of ``pos - R`` — the same pool
+    block or the one before — where they are still owed (``bd["pending"]``);
+    a half that owes nothing, and both of a slot that holds position
+    (``hold`` (B,) bool), go to the ``trash`` block."""
+    R = tok.shape[1]
+    at = jnp.stack([pos - R, pos], axis=1)                   # (B, 2)
+    entry = jnp.clip(at // bk, 0, table.shape[1] - 1)
+    dead = hold[:, None] | jnp.stack(
+        [~bd["pending"], jnp.zeros_like(hold)], axis=1)
+    blk = jnp.where(dead, trash, jnp.take_along_axis(table, entry, axis=1))
+    rows = jnp.concatenate(
+        [bd["prev"], jnp.where(bd["masked"], cfg.mask_token_id, tok)], axis=1)
+    return rows, blk, at % bk
+
+
 def bd_select(logits, tok, pos, bd: dict, hold):
     """The tail of a diffusion step: confidence, selection and every slot's
-    block state after the forward.  logits (B, R, vocab) f32 of the blocks
-    as they went in; ``hold`` (B,) bool the slots that did not take part.
+    block state after the forward.  logits (B, R, vocab) f32 of the slots'
+    current blocks as they went in; ``hold`` (B,) bool the slots that did not
+    take part.
 
-    A slot with a masked position DENOISES: at each masked position the
-    candidate is the arg-max token and its confidence that token's softmax
+    A slot that takes part DENOISES: at each masked position the candidate
+    is the arg-max token and its confidence that token's softmax
     probability; the ``n`` most confident masked positions (ties to the
     lower position) take their candidates, and so does every one whose
     confidence passes ``tau`` — ``n`` is ``n0 // T`` and one more in the
-    first ``n0 % T`` steps, or 1 where ``T`` is 0.  A slot with nothing
-    masked has just written its clean block's K/V: the block is FINISHED,
-    and the slot moves on to the next, all masked.
+    first ``n0 % T`` steps, or 1 where ``T`` is 0.  The forward that commits
+    a block's last masked position FINISHES the block: the slot moves on to
+    the next in that same forward, all masked, and keeps the finished block
+    (``prev``) to forward it once more, clean, beside the next block's first
+    denoising forward — which writes its K/V (``pending``; ``bd_rows``).
 
-    Returns (info (B, 3 + 2 R) int32 — ``pos`` before the forward, the
-    phase (``BD_HOLD`` | ``BD_DENOISE`` | ``BD_WRITE``), the positions
-    committed by it, then the block's R tokens and their R commit steps
-    after it (a finished block's: as written) —, tok, pos, bd after)."""
+    Returns (info (B, BD_INFO + 2 R) int32 — ``pos`` before the forward, the
+    phase (``BD_HOLD`` | ``BD_DENOISE``), the positions committed by it,
+    whether it finished the block, whether its first R rows wrote the block
+    before (``fused``), then the block's R tokens and their R commit steps
+    after it —, tok, pos, bd after)."""
     B, R, _ = logits.shape
     masked, cstep, step = bd["masked"], bd["cstep"], bd["step"]
     with jax.named_scope("strom.bd.select"):
@@ -592,23 +647,26 @@ def bd_select(logits, tok, pos, bd: dict, hold):
         T = jnp.maximum(bd["T"], 1)
         n = jnp.where(bd["T"] > 0,
                       bd["n0"] // T + (step < bd["n0"] % T), 1)
-        unfinished = jnp.any(masked, axis=-1)
-        denoise, write = unfinished & ~hold, ~unfinished & ~hold
+        denoise = ~hold
         commit = masked & denoise[:, None] & (
             (rank < n[:, None]) | (conf > bd["tau"][:, None]))
         tok = jnp.where(commit, cand, tok)
         cstep = jnp.where(commit, step[:, None], cstep)
+        done = denoise & ~jnp.any(masked & ~commit, axis=-1)
         info = jnp.concatenate([
-            pos[:, None],
-            (denoise * BD_DENOISE + write * BD_WRITE)[:, None],
+            pos[:, None], (denoise * BD_DENOISE)[:, None],
             jnp.sum(commit, axis=-1, dtype=jnp.int32)[:, None],
+            done[:, None], (bd["pending"] & denoise)[:, None],
             tok, cstep], axis=1).astype(jnp.int32)
-        w = write[:, None]
-        bd = dict(bd, masked=(masked & ~commit) | w,
-                  cstep=jnp.where(w, -1, cstep),
-                  step=jnp.where(write, 0, step + denoise),
-                  n0=jnp.where(write, R, bd["n0"]))
-        return info, tok, jnp.where(write, pos + R, pos), bd
+        d = done[:, None]
+        bd = dict(bd, masked=(masked & ~commit) | d,
+                  cstep=jnp.where(d, -1, cstep),
+                  step=jnp.where(done, 0, step + denoise),
+                  n0=jnp.where(done, R, bd["n0"]),
+                  prev=jnp.where(d, tok, bd["prev"]),
+                  # a slot that held wrote nothing: it owes what it owed
+                  pending=jnp.where(hold, bd["pending"], done))
+        return info, tok, jnp.where(done, pos + R, pos), bd
 
 
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(3, 4, 12))
@@ -623,26 +681,27 @@ def _paged_step(params: Dict, cfg: TransformerConfig, tok,
     ignores their outputs — one compiled program for every batch mix.
 
     With ``bd`` (``bd_state``; ``cfg.diffusion_block`` R) the same program
-    forwards R rows a slot — ``tok`` (B, R) the slot's current block,
-    ``pos`` its first position — and ends in ``bd_select`` in place of the
-    sampler.  The phase of a slot is data: it denoises, writes its finished
-    block or holds position (a free slot, told by ``blk``, the trash block;
-    a slot past its last block, ``pos >= bd["end"]``: both write to the
-    trash block, are routed nowhere and change nothing), so that k
-    sub-steps chain on the device without a readback.  The write target is
-    read off the table here (``blk`` says free or not, ``off`` is unread).
-    Returns (info (B, 3 + 2 R), k_pool, v_pool, state, tok, pos, bd)."""
+    forwards 2 R rows a slot (``bd_rows``) — ``tok`` (B, R) the slot's
+    current block, ``pos`` its first position, before it the block the slot
+    finished last, whose clean K/V rows this forward writes if they are
+    still owed — and ends in ``bd_select`` over the current block's logits
+    in place of the sampler.  What a slot does is data: it denoises, with
+    or without a finished block's rows riding along, or holds position (a
+    free slot, told by ``blk``, the trash block; a slot past its last block,
+    ``pos >= bd["end"]``: both write to the trash block, are routed nowhere
+    and change nothing), so that k sub-steps chain on the device without a
+    readback.  The write targets are read off the table here (``blk`` says
+    free or not, ``off`` is unread).
+    Returns (info (B, BD_INFO + 2 R), k_pool, v_pool, state, tok, pos, bd)."""
     if bd is not None:
         with jax.named_scope("strom.embed"):
             trash, bk = k_pool.shape[1] - 1, k_pool.shape[3]
             hold = (blk == trash) | (pos >= bd["end"])
-            entry = jnp.minimum(pos // bk, table.shape[1] - 1)
-            blk = jnp.where(hold, trash, jnp.take_along_axis(
-                table, entry[:, None], axis=1)[:, 0])
-            rows = jnp.where(bd["masked"], cfg.mask_token_id, tok)
+            rows, blk, off = bd_rows(cfg, tok, pos, bd, hold, table, trash,
+                                     bk)
         logits, k_pool, v_pool, state = paged_logits(
-            params, cfg, rows, k_pool, v_pool, blk, pos % bk, table, pos,
-            state, sidx)
+            params, cfg, rows, k_pool, v_pool, blk, off, table, pos, state,
+            sidx)
         info, tok, pos, bd = bd_select(logits, tok, pos, bd, hold)
         return info, k_pool, v_pool, state, tok, pos, bd
     logits, k_pool, v_pool, state = paged_logits(
@@ -666,12 +725,15 @@ def _admit_blocks(pos, tok, bd, slots, starts, toks, given, end, T, tau):
         return old.at[slots].set(new.astype(old.dtype), mode="drop")
 
     R = toks.shape[1]
-    return put(pos, starts), put(tok, toks), {
-        "masked": put(bd["masked"], jnp.arange(R)[None, :] >= given[:, None]),
-        "cstep": put(bd["cstep"], jnp.full(toks.shape, -1)),
-        "step": put(bd["step"], jnp.zeros_like(given)),
-        "n0": put(bd["n0"], R - given), "end": put(bd["end"], end),
-        "T": put(bd["T"], T), "tau": put(bd["tau"], tau)}
+    return put(pos, starts), put(tok, toks), dict(
+        bd,
+        masked=put(bd["masked"], jnp.arange(R)[None, :] >= given[:, None]),
+        cstep=put(bd["cstep"], jnp.full(toks.shape, -1)),
+        step=put(bd["step"], jnp.zeros_like(given)),
+        n0=put(bd["n0"], R - given),
+        # the prefill wrote the prompt's whole blocks: nothing is owed
+        pending=put(bd["pending"], jnp.zeros_like(given)),
+        end=put(bd["end"], end), T=put(bd["T"], T), tau=put(bd["tau"], tau))
 
 
 class DecodeServer:
@@ -691,8 +753,9 @@ class DecodeServer:
     confident masked positions — ``cfg.diffusion_steps`` denoising
     forwards a block under the static rule, every position whose
     confidence passes ``cfg.diffusion_threshold`` (and one at least)
-    under the dynamic one, server-wide —, then once more the finished
-    block, clean, to write its K/V (``_paged_step``, ``_step_blocks``).
+    under the dynamic one, server-wide —, and the finished block rides
+    the next block's first forward once more, clean, to write its K/V: 2 R
+    rows a slot a forward (``_paged_step``, ``_step_blocks``).
     Greedy only; a finished request's ``request_metrics`` entry holds the
     denoising step each of its tokens was committed at
     (``"commit_steps"``, a byte a token).
@@ -889,11 +952,13 @@ class DecodeServer:
         #: bounded layout, ``models/moe.pair_bound``) — sums over the calls.
         #: A diffusion server counts its slot-forwards by phase, read off
         #: the steps' reports at each readback (a slot that holds a request:
-        #: ``bd_forwards_denoise``, ``bd_forwards_write`` — a finished
-        #: block's clean K/V and no token —, ``bd_forwards_hold`` — past its
-        #: last block until the host retires it), ``bd_tokens`` (positions
-        #: committed) and ``bd_rows`` (rows forwarded by those slots, R a
-        #: forward)
+        #: ``bd_forwards_denoise``, ``bd_forwards_write`` — a forward that
+        #: ONLY writes a finished block's clean K/V: none does, the key
+        #: stays for its readers —, ``bd_forwards_hold`` — past its last
+        #: block until the host retires it), ``bd_writes_fused`` (the
+        #: denoising forwards whose first R rows wrote the block finished
+        #: before), ``bd_tokens`` (positions committed) and ``bd_rows`` (live
+        #: rows forwarded by those slots: R a forward, 2 R a fused one)
         self.timings: Dict[str, float] = {
             "admit_s": 0.0, "dispatch_s": 0.0, "readback_s": 0.0,
             "steps": 0,
@@ -904,7 +969,8 @@ class DecodeServer:
             "attn_blocks_live": 0, "attn_blocks_table": 0,
             "attn_grid_steps": 0, "window_rows_live": 0,
             "bd_forwards_denoise": 0, "bd_forwards_write": 0,
-            "bd_forwards_hold": 0, "bd_tokens": 0, "bd_rows": 0,
+            "bd_forwards_hold": 0, "bd_writes_fused": 0, "bd_tokens": 0,
+            "bd_rows": 0,
             **{key + sfx: 0 for sfx in ("", "_prefill") for key in (
                 "moe_calls", "moe_pairs", "moe_pairs_routed",
                 "moe_rows_computed", "moe_experts_touched",
@@ -1746,6 +1812,8 @@ class DecodeServer:
         out["bd_tokens_per_forward"] = (
             round(self.timings["bd_tokens"] / forwards, 4) if forwards
             else 0.0)
+        # ... and the forwards that wrote a finished block's K/V on the way
+        out["bd_writes_fused"] = self.timings["bd_writes_fused"]
         if self.tenant_sheds:     # key appears only once tenancy acted
             out["tenant_sheds"] = dict(self.tenant_sheds)
         if self._draining:        # and these only once a drain began
@@ -2051,20 +2119,24 @@ class DecodeServer:
 
         A forward yields 0 to R tokens a slot, in no left-to-right order
         inside the block, so what the host counts down is FORWARDS: a slot
-        has at most (blocks left) x (denoising steps + 1) to go, and the
+        has at most (blocks left) x (denoising steps) to go — a finished
+        block's clean K/V rows ride the next block's first forward, and a
+        request's last block is never written: nothing reads it (the
+        prefix cache and the store hold prompt blocks only) —, and the
         batch is as long as the slot with most.  The device tells the
-        phases apart itself (``_paged_step``): a slot past its last block
+        slots apart itself (``_paged_step``): a slot past its last block
         holds position — it writes to the trash block and changes nothing —
         so positions never pass ``ceil((prompt + max_new) / R) * R``, which
         the admission's reservation ``ceil((prompt + max_new) / block)``
         covers (R divides the block).  A finished block reaches
-        ``req.out`` whole, at the readback after its cache-writing forward,
-        cut at ``max_new`` and at an EOS; ``t_first`` is the first readback
-        that shows a commit."""
+        ``req.out`` whole, at the readback of the forward that committed
+        its last position, cut at ``max_new`` and at an EOS; ``t_first`` is
+        the first readback that shows a commit.  ``bd_writes_fused`` counts
+        the slot-forwards whose first R rows wrote a finished block."""
         import numpy as np
         R, bk = self.R, self.block_len
         per_block = (R if self.cfg.diffusion_threshold > 0
-                     else min(self.cfg.diffusion_steps or R, R)) + 1
+                     else min(self.cfg.diffusion_steps or R, R))
         left = max(-(-(len(self.slots[b].prompt) + self.slots[b].max_new)
                      // R) - self._pos_h[b] // R for b in active_slots)
         k_eff = max(1, min(k_steps, left * per_block))
@@ -2074,7 +2146,7 @@ class DecodeServer:
                            for b in range(self.B)], jnp.int32)
         infos = []
         t0 = time.monotonic()
-        with self._span("strom.serve.dispatch", steps=k_eff, rows=R):
+        with self._span("strom.serve.dispatch", steps=k_eff, rows=2 * R):
             for _ in range(k_eff):
                 # (``state``: the expert layers' counters, donated; no layer
                 # of such a config keeps a row a slot, so ``sidx`` is unread)
@@ -2096,22 +2168,25 @@ class DecodeServer:
             self._note_moe(moe_h, k_eff)
         with self._span("strom.serve.replay") as replay_span:
             t_now = time.monotonic()
-            info_h = np.stack(info_h)[:, active_slots]    # (k, slots, 3+2R)
-            pos, phase, new = (info_h[..., i] for i in range(3))
+            info_h = np.stack(info_h)[:, active_slots]    # (k, slots, 5+2R)
+            pos, phase, new, done, fused = (info_h[..., i]
+                                            for i in range(BD_INFO))
             busy = phase != BD_HOLD
-            n_busy = int(busy.sum())
-            counts = {"bd_forwards_denoise": int((phase == BD_DENOISE).sum()),
-                      "bd_forwards_write": int((phase == BD_WRITE).sum()),
+            n_busy, n_fused = int(busy.sum()), int(fused.sum())
+            counts = {"bd_forwards_denoise": n_busy,
+                      # (no forward only writes a finished block)
+                      "bd_forwards_write": 0,
                       "bd_forwards_hold": busy.size - n_busy,
+                      "bd_writes_fused": n_fused,
                       "bd_tokens": int(new.sum()),
-                      "bd_rows": R * n_busy,
+                      "bd_rows": R * (n_busy + n_fused),
                       # the table entries each taking slot's walk reads, the
                       # grid steps a layer's call makes of them (one for a
                       # slot that holds or is free), the pairs routed
                       "attn_blocks_live": int(
                           ((pos + R - 1) // bk + 1)[busy].sum()),
                       "attn_blocks_table": k_eff * self.B * self.max_blocks,
-                      "moe_pairs_routed": R * n_busy
+                      "moe_pairs_routed": R * (n_busy + n_fused)
                       * self.cfg.expert_top_k * len(self.cfg.expert_layers)}
             counts["attn_grid_steps"] = (counts["attn_blocks_live"]
                                          + k_eff * self.B - n_busy)
@@ -2122,15 +2197,15 @@ class DecodeServer:
                 if req.t_first is None and new[:, i].any():
                     req.t_first = t_now     # first commit DELIVERED
                 P = len(req.prompt)
-                for j in np.nonzero(phase[:, i] == BD_WRITE)[0]:
+                for j in np.nonzero(done[:, i])[0]:
                     if self.slots[slot] is None:
                         break       # retired at an earlier sub-step: its
                                     # surplus blocks are discarded
                     at = int(pos[j, i])
                     self._pos_h[slot] = at + R
                     for r in range(max(P - at, 0), R):
-                        req.out.append(int(info_h[j, i, 3 + r]))
-                        req.steps.append(int(info_h[j, i, 3 + R + r]))
+                        req.out.append(int(info_h[j, i, BD_INFO + r]))
+                        req.steps.append(int(info_h[j, i, BD_INFO + R + r]))
                         ret = self._retire_or_keep(slot)
                         if ret:
                             finished[ret[0]] = ret[1]
@@ -2140,7 +2215,8 @@ class DecodeServer:
                 rows=counts["bd_rows"],
                 denoise=counts["bd_forwards_denoise"],
                 write=counts["bd_forwards_write"],
-                hold=counts["bd_forwards_hold"])
+                hold=counts["bd_forwards_hold"],
+                writes_fused=counts["bd_writes_fused"])
         return finished
 
     def _note_moe(self, moe_h: dict, steps: int) -> None:

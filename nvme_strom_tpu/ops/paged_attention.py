@@ -119,12 +119,15 @@ def walk_list(table, pos, block_k: int, window: int = 0):
 
 
 def _paged_kernel(slot_ref, blk_ref, start_ref, pos_ref, q_ref, k_ref, v_ref,
-                  *refs, scale, block_k, tok, tok_v, window=0, sink=False):
+                  *refs, scale, block_k, tok, tok_v, window=0, sink=False,
+                  lag=0, q_rows=1):
     """One step of ``walk_list``'s list: every KV head of one live pool
     block of one slot at once.  ``tok`` / ``tok_v``: whether K's / V's
     block lies tokens-on-lanes.  With ``window`` the slot's walk starts at
     the block that holds its oldest visible row, and with ``sink`` a
-    per-head score (``refs[0]``) joins the last normalisation."""
+    per-head score (``refs[0]``) joins the last normalisation.  With
+    ``lag`` the first ``lag`` of each head's ``q_rows`` query rows see the
+    columns up to ``pos - lag``, the rest up to ``pos``."""
     if sink:
         s_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -166,6 +169,11 @@ def _paged_kernel(slot_ref, blk_ref, start_ref, pos_ref, q_ref, k_ref, v_ref,
         cols = ji * block_k + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 2)
         seen = cols <= pos
+        if lag:
+            # a KV head's query rows are (head in group) x q_rows + r
+            r = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, s.shape, 1),
+                            q_rows)
+            seen = cols <= pos - jnp.where(r < lag, lag, 0)
         if window:
             seen = seen & (cols >= lo)
         s = jnp.where(seen, s, _NEG_INF)                 # (nkv, g, bk)
@@ -190,14 +198,18 @@ def _paged_kernel(slot_ref, blk_ref, start_ref, pos_ref, q_ref, k_ref, v_ref,
 
 
 def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
-                    scale=None, window: int = 0, sink=None,
+                    scale=None, window: int = 0, sink=None, lag: int = 0,
                     interpret: bool = None):
     """q (b, n_heads, R, d) attends to its block-table history in one
     layer of the pool: R = 1, a decode step's one row a slot, or the R
     rows of a slot's current diffusion block, which ALL see the slot's
     history up to ``pos`` (no mask among them) — they ride as R times the
-    query heads of each KV group over one walk of the slot's blocks with
-    one limit, so a pool block is fetched once for all of them.
+    query heads of each KV group over one walk of the slot's blocks, so a
+    pool block is fetched once for all of them.  ``lag`` (static, < R): the
+    slot's first ``lag`` rows see up to ``pos - lag`` and the others up to
+    ``pos`` — a finished diffusion block's rows beside the next block's
+    (``lag`` the block's length, R twice that), one limit a row on the
+    same walk.  Without it the kernel and its program are what they were.
 
     k_pool/v_pool (n_layers, n_blocks, n_kv_heads, block_k, d): the shared
     pool of EVERY layer, read where it lies; ``layer`` (static) picks the
@@ -225,6 +237,8 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
     if rows != 1 and (window or sink is not None):
         raise ValueError(f"a window or a sink takes one query row a slot, "
                          f"got q {q.shape}")
+    if not 0 <= lag < rows:
+        raise ValueError(f"lag {lag} of {rows} query rows a slot")
     if (k_pool.ndim != 5 or v_pool.shape[:-1] != k_pool.shape[:-1]
             or k_pool.shape[-1] != d):
         raise ValueError("expected pools (layers, blocks, kv_heads, "
@@ -285,6 +299,8 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, layer: int = 0,
     )
     extra = dict(window=int(window), sink=sink is not None) \
         if window or sink is not None else {}
+    if lag:
+        extra.update(lag=int(lag), q_rows=rows)
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=float(scale),
                           block_k=block_k, tok=int(lanes),

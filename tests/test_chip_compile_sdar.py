@@ -1,9 +1,11 @@
 """SDAR-30B-A3B-Chat's serving programs compile for a TPU v5e at the cell's
-pools: the step at FOUR rows a slot — ``strom_kv_write`` placing a slot's
-four rows in one tile, ``strom_paged_attn`` running them as 32 query rows a
-KV head, 512 rows through 128 experts, confidence and selection over 512 x
-151,936 float32 logits — with the pages updated in place, and the
-block-causal admission beside them (``tests/chip_compile.py``).
+pools: the step at EIGHT rows a slot, the block a slot finished last beside
+its current one — ``strom_kv_write`` placing each half's four rows in its
+tile (a call a half), ``strom_paged_attn`` running them as 64 query rows a
+KV head with a limit a row (``lag`` 4), 1,024 rows through 128 experts,
+confidence and selection over the current blocks' 512 x 151,936 float32
+logits — with the pages updated in place, and the block-causal admission
+beside them (``tests/chip_compile.py``).
 """
 
 import jax
@@ -39,12 +41,14 @@ def _sdar_layers(topo):
     return cfg, sh, params, pools, state, bd
 
 
-def test_sdar_step_forwards_four_rows_a_slot_in_place(topo, monkeypatch):
-    """The server's step at the cell's widths, 128 slots and four rows a
-    slot: both kernels and the grouped expert product are there by name,
-    the pages are aliased input to output, nothing of the pool's size is
-    copied, and the step's temporaries — 512 x 151,936 float32 logits and
-    what the selection makes of them — stay under 1.5 GiB."""
+def test_sdar_step_forwards_eight_rows_a_slot_in_place(topo, monkeypatch):
+    """The server's step at the cell's widths, 128 slots and eight rows a
+    slot: both kernels — the writer twice a layer — and the grouped expert
+    product are there by name, the pages are aliased input to output,
+    nothing of the pool's size is copied, and the step's temporaries — the
+    head sees the current blocks' 512 rows only: 512 x 151,936 float32
+    logits and what the selection makes of them — stay under the 1.5 GiB
+    the four-row step was held to."""
     from nvme_strom_tpu.models import serving
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg, sh, params, pools, state, bd = _sdar_layers(topo)
@@ -56,7 +60,7 @@ def test_sdar_step_forwards_four_rows_a_slot_in_place(topo, monkeypatch):
         vec(jnp.float32), vec(jnp.float32), vec(jnp.uint32), state,
         vec(jnp.int32), bd=bd).compile()
     text = compiled.as_text()
-    for name, n in (("strom_kv_write", SDAR_LAYERS),
+    for name, n in (("strom_kv_write", 2 * SDAR_LAYERS),
                     ("strom_paged_attn", SDAR_LAYERS),
                     ("strom_moe_gmm", 2 * SDAR_LAYERS)):
         assert text.count(name) >= n, name

@@ -90,6 +90,10 @@ def test_validation():
         paged_attention(jnp.zeros((2, 4, 2, 8)), kp, kp,
                         jnp.zeros((2, 2), jnp.int32),
                         jnp.zeros((2,), jnp.int32), window=4)
+    with pytest.raises(ValueError, match="lag 2 of 2"):  # rows left of it
+        paged_attention(jnp.zeros((2, 4, 2, 8)), kp, kp,
+                        jnp.zeros((2, 2), jnp.int32),
+                        jnp.zeros((2,), jnp.int32), lag=2)
     with pytest.raises(ValueError, match="pools"):       # a layer's slice
         paged_attention(q, kp[0], kp[0], jnp.zeros((2, 2), jnp.int32),
                         jnp.zeros((2,), jnp.int32))
@@ -220,6 +224,78 @@ def test_free_slot_is_finite_and_leaves_the_live_slots_alone(stale, block_k,
     np.testing.assert_allclose(
         alone, _dense(q[live], kp, vp, table[live], np.array(pos)[live],
                       block_k), atol=2e-5, rtol=2e-5)
+
+
+# the slots' limits (of the block length and the rows R a half) under a lag
+# of R: both halves inside one pool block, the later half the first rows of
+# a pool block (the earlier one's limit the end of the block before), a slot
+# whose earlier half sees nothing at all, the table's last rows
+LAGGED = {
+    "one_pool_block": lambda bk, R: [2 * R - 1, bk + 2 * R - 1, 3 * R - 1],
+    "two_pool_blocks": lambda bk, R: [bk + R - 1, 2 * bk + R - 1, R - 1],
+    "nothing_before": lambda bk, R: [R - 1, R - 1, bk + R - 1],
+    "tables_end": lambda bk, R: [MAX_BLOCKS * bk - 1, R - 1, bk - 1],
+}
+
+
+@pytest.mark.parametrize("block_k,d", GEOMETRIES)
+@pytest.mark.parametrize("where", list(LAGGED))
+def test_a_lag_gives_a_slots_first_rows_an_earlier_limit(where, block_k, d):
+    """2 R query rows a slot with ``lag`` R: a head's first R rows see the
+    columns up to ``pos - R``, its last R up to ``pos`` — a dense float32
+    softmax with a limit a row — over one walk of the slot's blocks; rows
+    that see nothing come out finite, and without the lag every row sees up
+    to ``pos``: another answer."""
+    R, nkv, g = 4, 2, 2
+    rng = np.random.default_rng(7)
+    pos = np.asarray(LAGGED[where](block_k, R), np.int32)
+    b = len(pos)
+    n_pool = b * MAX_BLOCKS + 1
+    kp, vp = (rng.standard_normal((2, n_pool, nkv, block_k, d))
+              .astype(np.float32) for _ in range(2))
+    table = rng.permutation(n_pool - 1)[:b * MAX_BLOCKS].reshape(
+        b, MAX_BLOCKS).astype(np.int32)
+    q = rng.standard_normal((b, nkv * g, 2 * R, d)).astype(np.float32)
+    got = np.asarray(paged_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(table),
+        jnp.asarray(pos), layer=1, lag=R))
+    flat = np.asarray(paged_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(table),
+        jnp.asarray(pos), layer=1))
+    assert np.isfinite(got).all()
+    limit = pos[:, None] - np.where(np.arange(2 * R) < R, R, 0)   # (b, 2R)
+    for bi in range(b):
+        ks, vs = (np.concatenate([p[1, t] for t in table[bi]], axis=1)
+                  for p in (kp, vp))                        # (nkv, S, d)
+        qf = q[bi].reshape(nkv, g, 2 * R, d)
+        s = np.einsum("kgrd,ksd->kgrs", qf, ks) / np.sqrt(d)
+        seen = np.arange(ks.shape[1])[None, :] <= limit[bi][:, None]
+        s = np.where(seen[None, None], s, -1e30)
+        w = np.exp(s - s.max(-1, keepdims=True))
+        want = np.einsum("kgrs,ksd->kgrd", w / w.sum(-1, keepdims=True),
+                         vs).reshape(nkv * g, 2 * R, d)
+        rows = limit[bi] >= 0               # the others see nothing: unread
+        np.testing.assert_allclose(got[bi][:, rows], want[:, rows],
+                                   atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(got[bi][:, R:], flat[bi][:, R:],
+                                   atol=2e-5, rtol=2e-5)
+        if rows[0]:
+            assert np.abs(got[bi][:, :R] - flat[bi][:, :R]).max() > 1e-3
+
+
+def test_without_a_lag_the_kernel_is_what_it_was():
+    """``lag`` absent or 0 changes nothing of the call: the same kernel
+    under the same name with the same parameters, whatever the rows."""
+    pool = jnp.zeros((1, 5, 2, 16, 128), jnp.float32)
+    table, pos = jnp.zeros((3, 4), jnp.int32), jnp.zeros((3,), jnp.int32)
+    for rows in (1, 4):
+        q = jnp.zeros((3, 4, rows, 128), jnp.float32)
+        plain, zero, lagged = (
+            str(jax.make_jaxpr(functools.partial(paged_attention, **kw))(
+                q, pool, pool, table, pos))
+            for kw in ({}, {"lag": 0}, {"lag": rows // 2}))
+        assert plain == zero
+        assert (plain == lagged) == (rows == 1)
 
 
 def _pallas_eqns(jaxpr, found):

@@ -1,8 +1,9 @@
 """SDAR-MoE's mechanisms through the program at a tiny size on the CPU in
 float32: generation by diffusion over blocks of four — the admission under
-the block-causal mask, then a step that forwards four rows a slot through the
-paged cache with no mask inside the block, commits the most confident and
-writes a finished block's clean K/V once — over softmax-routed experts.  The
+the block-causal mask, then a step that forwards a slot's current four rows
+through the paged cache with no mask inside the block and commits the most
+confident, the block finished before riding along, clean, to write its K/V
+once (eight rows a slot, a limit a row) — over softmax-routed experts.  The
 paged server against the benchmark's plain reference
 (``benchmark/reference/sdar_bd.py``, which imports nothing of the program and
 has no cache) on the benchmark's seeded weights: in logits at every (block,
@@ -98,37 +99,49 @@ def _admit(srv):
 
 def _program_forwards(model, prompt, budget, broken=None):
     """Every denoising forward's logits [(block start, step, (Bl, vocab))] of
-    one request: the server's own admission, then ``paged_logits`` and
-    ``bd_select`` by hand — the step's two halves, with the logits between
-    them in the open.  ``broken`` switches a mechanism off."""
+    one request: the server's own admission, then ``bd_rows``,
+    ``paged_logits`` and ``bd_select`` by hand — the step's parts, with the
+    logits between them in the open.  ``broken`` switches a mechanism off:
+    ``"no_clean_write"`` sends the finished block's rows to the trash block
+    (the pages stay as the block's last denoising forward wrote them),
+    ``"no_lag"`` lets them see the block after them."""
+    from nvme_strom_tpu.ops import paged_attention as pa
     cfg, params = model
     srv = _server(model, slots=1)
     srv.submit(0, prompt, budget)
     _admit(srv)
     table, trash = srv._table(), srv._trash
+    attend = pa.paged_attention
 
-    @jax.jit
-    def forward(rows, k, v, pos, state):
-        blk = jnp.take_along_axis(table, (pos // BLOCK)[:, None], 1)[:, 0]
-        return serving.paged_logits(params, cfg, rows, k, v, blk,
-                                    pos % BLOCK, table, pos, state, blk)
+    def forward(tok, k, v, pos, bd, state):
+        hold = pos >= bd["end"]
+        rows, blk, off = serving.bd_rows(cfg, tok, pos, bd, hold, table,
+                                         trash, BLOCK)
+        if broken == "no_clean_write":
+            blk = blk.at[:, 0].set(trash)
+        return serving.paged_logits(params, cfg, rows, k, v, blk, off,
+                                    table, pos, state, blk[:, 1])
 
     out = []
     tok, pos, bd = srv.tok, srv.pos, srv.bd
     k, v, state = srv.k_pool, srv.v_pool, srv.state
     hold = jnp.zeros((1,), bool)
-    while int(pos[0]) < int(bd["end"][0]):
-        rows = jnp.where(bd["masked"], cfg.mask_token_id, tok)
-        writing = not bool(bd["masked"].any())
-        if writing and broken == "no_clean_forward":
-            # the pages stay as the last denoising forward wrote them
-            logits = jnp.zeros((1, BL, cfg.vocab), jnp.float32)
-        else:
-            logits, k, v, state = forward(rows, k, v, pos, state)
-        if not writing:
+    try:
+        if broken == "no_lag":
+            pa.paged_attention = lambda *a, lag, **kw: attend(*a, **kw)
+        forward = jax.jit(forward)
+        fused = 0
+        while int(pos[0]) < int(bd["end"][0]):
+            assert bool(bd["masked"].any())     # no forward only writes
+            fused += bool(bd["pending"][0])
+            logits, k, v, state = forward(tok, k, v, pos, bd, state)
             out.append((int(pos[0]), int(bd["step"][0]),
                         np.asarray(logits[0])))
-        _, tok, pos, bd = serving.bd_select(logits, tok, pos, bd, hold)
+            _, tok, pos, bd = serving.bd_select(logits, tok, pos, bd, hold)
+    finally:
+        pa.paged_attention = attend
+    # every block but the last was written, by the forward after it
+    assert fused == len({at for at, _, _ in out}) - 1
     assert trash not in np.asarray(table)[0, :2]
     return out
 
@@ -174,10 +187,12 @@ def test_bfloat16_fails_the_tolerance():
     assert worst > 20 * ATOL, worst
 
 
-@pytest.mark.parametrize("broken", ["no_clean_forward"])
+@pytest.mark.parametrize("broken", ["no_clean_write", "no_lag"])
 def test_a_finished_blocks_pages_come_from_its_clean_tokens(model, broken):
-    """Without the cache-writing forward the second block reads the first as
-    its LAST denoising forward left it, half of it masks: other logits."""
+    """Without the clean rows' write the second block reads the first as its
+    LAST denoising forward left it, half of it masks; with the clean rows
+    allowed to see the block after them the first block's pages hold what no
+    forward of the reference computes: other logits, either way."""
     prompt = _prompt(16)
     want, _, _ = _reference_forwards(prompt, 8)
     got = _program_forwards(model, prompt, 8, broken=broken)
@@ -216,14 +231,24 @@ def test_static_rule_serves_the_references_tokens_and_steps(model, lookahead):
         assert got[i] == (toks, steps), i
         assert len(toks) == budget and set(steps) <= {0, 1}
     t = srv.timings
-    # T = 2: two tokens a denoising forward; a third of the forwards that
-    # did anything only wrote a finished block
-    assert t["bd_forwards_write"] < t["bd_forwards_denoise"] \
-        <= 2 * t["bd_forwards_write"]
+    # T = 2: two forwards a block and none that only writes one — every
+    # block but a request's last is written by the forward after it (a
+    # block that starts with fewer than two masked positions takes one)
+    blocks = [-(-(P + budget) // BL) - P // BL for P, budget in REQUESTS]
+    assert t["bd_forwards_write"] == 0
+    assert t["bd_writes_fused"] == sum(blocks) - len(REQUESTS)
+    short = sum(P % BL == BL - 1 for P, _ in REQUESTS)
+    assert t["bd_forwards_denoise"] == 2 * sum(blocks) - short
     assert t["bd_rows"] == BL * (t["bd_forwards_denoise"]
-                                 + t["bd_forwards_write"])
-    assert srv.stats()["diffusion_block"] == BL
-    assert 0 < srv.stats()["bd_tokens_per_forward"] <= BL / 3
+                                 + t["bd_writes_fused"])
+    stats = srv.stats()
+    assert stats["diffusion_block"] == BL
+    assert stats["bd_writes_fused"] == t["bd_writes_fused"]
+    assert t["bd_tokens"] == BL * sum(blocks) - sum(P % BL
+                                                    for P, _ in REQUESTS)
+    # (the forwards a slot holds position for count below the line)
+    assert 0 < stats["bd_tokens_per_forward"] <= BL / 2 \
+        < 1.5 * t["bd_tokens"] / t["bd_forwards_denoise"]
 
 
 @within(180)
@@ -281,9 +306,10 @@ def test_a_commit_that_is_the_mask_id_stays_a_commit(model):
 @within(180)
 def test_slots_in_different_phases_of_one_step_many(model):
     """Three requests admitted at three calls, two forwards apart: at every
-    sub-step of the ``step_many(8)`` that follows one slot denoises while
-    another writes its block — the phase is data, the program one — and each
-    answer is what the request gets when served alone."""
+    sub-step of the ``step_many(8)`` that follows one slot's forward carries
+    the block it has just finished while another's carries none — what a
+    slot's first four rows do is data, the program one — and each answer is
+    what the request gets when served alone."""
     cfg, params = model
     srv = _server(model)
     reqs = [(9, 12), (16, 8), (6, 11)]
@@ -292,10 +318,14 @@ def test_slots_in_different_phases_of_one_step_many(model):
     for i, (_, budget) in enumerate(reqs):
         srv.submit(i, prompts[i], budget)
         out.update(srv.step_many(1 + i))
-    phases = np.asarray(srv.bd["step"]), np.asarray(srv.bd["masked"].sum(1))
-    assert len({(int(s), int(m)) for s, m in zip(*phases)}) > 1, phases
+    phases = np.asarray(srv.bd["step"]), np.asarray(srv.bd["pending"])
+    assert len({(int(s), bool(m)) for s, m in zip(*phases)}) > 1, phases
+    fused = srv.timings["bd_writes_fused"]
     while not srv.idle:
         out.update(srv.step_many(8))
+    blocks = [-(-(P + budget) // BL) - P // BL for P, budget in reqs]
+    assert 0 < fused < srv.timings["bd_writes_fused"] \
+        == sum(blocks) - len(reqs)
     for i, (_, budget) in enumerate(reqs):
         alone = _server(model, slots=1)
         alone.submit(0, prompts[i], budget)
@@ -422,6 +452,74 @@ def test_paged_kernel_at_four_rows_a_slot(nkv, g, hd):
                                    atol=2e-5, rtol=0)
 
 
+@pytest.mark.parametrize("nkv,g,hd", [(2, 2, 32), (4, 8, 128)])
+def test_paged_kernel_with_a_finished_block_beside_the_current(nkv, g, hd):
+    """Eight rows a slot, ``[finished block | current block]``: a
+    ``strom_kv_write`` call a half — the halves in one sublane tile, in two
+    tiles of one pool block, in two pool blocks (``pos % block == 0``), the
+    first half of a slot that owes nothing in the trash block — and
+    ``strom_paged_attn`` with ``lag`` 4 (64 query rows a KV head at the
+    cell's group of 8): ``cache_attention`` with each row seeing up to the
+    end of its OWN block, the pages outside the written rows untouched."""
+    from nvme_strom_tpu.models.transformer import TransformerConfig
+    from nvme_strom_tpu.ops.paged_attention import (paged_attention,
+                                                    write_rows)
+    B, R, bk = 5, BL, 16
+    cfg = TransformerConfig(n_heads=nkv * g, n_kv_heads=nkv,
+                            d_model=nkv * g * hd, dtype=jnp.float32)
+    rng = np.random.default_rng(6)
+    # the current blocks' starts: same tile as the block before (f32: a
+    # tile of 8 tokens), the next tile, the next pool block, nothing owed,
+    # a free slot
+    pos = np.asarray([20, 40, 32, 28, 0], np.int32)
+    owed = np.asarray([True, True, True, False, False])
+    table = np.asarray([[3, 5, 0, 0], [1, 2, 6, 0], [4, 7, 9, 0],
+                        [10, 11, 0, 0], [0, 0, 0, 0]], np.int32)
+    trash = 12
+    k_pool = jnp.asarray(rng.normal(size=(2, trash + 1, nkv, bk, hd)),
+                         jnp.float32)
+    v_pool = jnp.asarray(rng.normal(size=k_pool.shape), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, nkv * g, 2 * R, hd)), jnp.float32)
+    kn = jnp.asarray(rng.normal(size=(B, nkv, 2 * R, hd)), jnp.float32)
+    vn = jnp.asarray(rng.normal(size=(B, nkv, 2 * R, hd)), jnp.float32)
+    at = np.stack([pos - R, pos], axis=1)
+    live = np.stack([owed, np.arange(B) < 4], axis=1)
+    blk = np.where(live, np.take_along_axis(table, np.maximum(at, 0) // bk,
+                                            axis=1), trash).astype(np.int32)
+    assert blk[2, 0] != blk[2, 1] and (blk[:2, 0] == blk[:2, 1]).all()
+    k2, v2 = k_pool, v_pool
+    for j in range(2):
+        k2, v2 = write_rows(k2, v2, kn[:, :, j * R:(j + 1) * R],
+                            vn[:, :, j * R:(j + 1) * R], blk[:, j],
+                            at[:, j] % bk, layer=1)
+    for pool, new, old in ((k2, kn, k_pool), (v2, vn, v_pool)):
+        written = 0
+        for b, j in zip(*np.nonzero(live)):
+            o = int(at[b, j] % bk)
+            np.testing.assert_array_equal(
+                np.asarray(pool[1, blk[b, j], :, o:o + R]),
+                np.asarray(new[b, :, j * R:(j + 1) * R]))
+            written += nkv * R * hd
+        changed = np.asarray(pool != old)
+        assert changed[0].sum() == 0
+        assert changed[1, :trash].sum() == written
+    limit = np.where(np.arange(B) < 4, pos + R - 1, 0).astype(np.int32)
+    got = paged_attention(q, k2, v2, table, limit, layer=1, lag=R)
+    assert got.shape == (B, nkv * g, 2 * R, hd)
+    assert np.isfinite(np.asarray(got)).all()
+    for b in range(4):
+        n = int(limit[b]) + 1
+        dense = [jnp.concatenate([p[1, j] for j in table[b]], axis=1)[:, :n]
+                 for p in (k2, v2)]
+        sees = jnp.asarray([[n - 1 - R] * R + [n - 1] * R])
+        want = decode.cache_attention(q[b:b + 1], dense[0][None],
+                                      dense[1][None], sees, cfg)
+        rows = slice(0, 2 * R) if owed[b] else slice(R, 2 * R)
+        np.testing.assert_allclose(np.asarray(got[b][:, rows]),
+                                   np.asarray(want[0][:, rows]),
+                                   atol=2e-5, rtol=0)
+
+
 def test_rows_that_do_not_fit_a_tile_are_refused():
     from nvme_strom_tpu.ops.paged_attention import write_rows
     pool = jnp.zeros((1, 3, 2, 16, 32), jnp.float32)
@@ -432,12 +530,16 @@ def test_rows_that_do_not_fit_a_tile_are_refused():
 
 
 def test_expert_counters_count_four_rows_a_slot(model):
-    """Every row of a slot's block is routed: the device's pair count is the
-    host's, R x top-k x expert layers a slot-forward that takes part, and a
-    slot that holds position is routed nowhere."""
+    """Every live row of a slot's forward is routed: the device's pair count
+    is the host's, R x top-k x expert layers a slot-forward that takes part
+    and as much again where a finished block rides along; a finished block's
+    rows with nothing owed and a slot that holds position are routed
+    nowhere."""
     srv, _, _ = _serve(model, REQUESTS[:4], lookahead=8)
     t = srv.timings
-    assert t["bd_forwards_hold"] > 0
+    assert t["bd_forwards_hold"] > 0 and t["bd_writes_fused"] > 0
+    assert t["bd_rows"] == BL * (t["bd_forwards_denoise"]
+                                 + t["bd_writes_fused"])
     assert t["moe_pairs"] == t["moe_pairs_routed"] == t["bd_rows"] * 2 * 2
     assert t["attn_grid_steps"] > t["attn_blocks_live"] > 0
 

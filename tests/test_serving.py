@@ -305,7 +305,8 @@ def test_server_stats_gauges(setup):
              "window_layers": 0, "window_bytes_per_slot": 0,
              "window_rows_live": 0,
              # a token a step: no diffusion blocks, no forwards counted
-             "diffusion_block": 0, "bd_tokens_per_forward": 0.0}
+             "diffusion_block": 0, "bd_tokens_per_forward": 0.0,
+             "bd_writes_fused": 0}
     assert s0 == want0
     srv.step()
     s1 = srv.stats()
